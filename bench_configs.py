@@ -489,6 +489,11 @@ def main():
     p.add_argument("--write", metavar="PATH", default=None,
                    help="write the results as the new artifact (JSON lines)")
     a = p.parse_args()
+    from dst_libp2p_test_node_tpu.runtime.compile_cache import (
+        enable_compile_cache,
+    )
+
+    enable_compile_cache()
     runs = [a.only] if a.only else (
         [1, 2, 3, 4, 5, 7] if a.all else [1, 2, 3, 4])
     if a.attack and not a.only:

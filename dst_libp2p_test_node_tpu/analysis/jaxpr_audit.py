@@ -34,8 +34,8 @@ from .contracts import EntrypointContract, TraceSpec
 from .report import Violation
 
 CALLBACK_PRIMS = {
-    "pure_callback", "io_callback", "debug_callback", "infeed", "outfeed",
-    "host_callback_call",
+    "pure_callback", "io_callback", "debug_callback", "debug_print",
+    "infeed", "outfeed", "host_callback_call",
 }
 X64_DTYPES = {"float64", "int64", "uint64", "complex128"}
 
@@ -47,13 +47,13 @@ _SCAN_BODY_PARAM = "jaxpr"                   # scan (when primitive is scan)
 
 def _subjaxprs(eqn):
     """Yield (closed_jaxpr, enters_loop_body) for every sub-jaxpr of eqn."""
-    import jax
+    from jax.extend.core import ClosedJaxpr
 
     for key, val in eqn.params.items():
         vals = val if isinstance(val, (tuple, list)) else [val]
         for v in vals:
             inner = None
-            if isinstance(v, jax.core.ClosedJaxpr):
+            if isinstance(v, ClosedJaxpr):
                 inner = v.jaxpr
             elif hasattr(v, "eqns"):
                 inner = v

@@ -1,4 +1,4 @@
-"""Pallas fused scoring-update kernel, behind a runtime capability probe.
+"""Pallas fused scoring-update kernel.
 
 The heartbeat scan defers the per-round counter decay into two carried
 scalars and materializes it once post-scan (ops/heartbeat._apply_decay on
@@ -8,15 +8,14 @@ round-trip for a few flops. This kernel fuses the two: one pass over the
 row blocks applies both decays (with the flush-to-zero floor) AND emits the
 weighted score, so the counters stream through VMEM exactly once.
 
-Same discipline as native/vmem_gather.py, the first kernel behind this
-pattern: whether the Mosaic toolchain compiles THIS formulation is decided
-at runtime by `score_kernel_available()` — a one-shot cached probe that
-compiles a miniature instance on the real backend and compares it against
-the plain-XLA reference (`score_update_xla`, which is bit-for-bit the
-heartbeat/_apply_decay + SimState.score composition). Any failure makes the
-probe False and callers keep the XLA formulation, so CPU CI and older
-toolchains stay green by construction. `DST_PALLAS_SCORE=0` forces the
-kernel off; `=1` forces the probe to raise instead of degrade.
+Routing is static: `score_update_best` takes the kernel on a TPU backend
+and the plain-XLA reference (`score_update_xla`, bit-for-bit the
+heartbeat/_apply_decay + SimState.score composition) elsewhere. There is no
+compile-and-see probe and no environment switch: a kernel that is routed
+and does not build raises what Pallas/Mosaic raises. That the kernel
+compiles for a v5e at the 100k-peer shape is checked without a chip in
+tests/test_chip_compile.py; no user-facing path calls `score_update_best`
+yet (runtime/microbench.py and the audit registry time and trace it).
 
 CPU correctness of the kernel body itself is tested with `interpret=True`
 (tests/test_score_kernel.py), which runs the Pallas program without Mosaic.
@@ -27,14 +26,11 @@ The row-block size consults the microbench autotuner's tuned.json
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
 
 from .tuned import tuned_block_rows
-
-_ENV = "DST_PALLAS_SCORE"
 
 # three f32 (block, C) tiles live per grid step (two counters in, score
 # out, counters updated in place of their input tiles); 512 rows x 64
@@ -60,7 +56,7 @@ def _compiled(n_rows: int, cap: int, fmd_weight: float, slow_weight: float,
               fmd_cap: float, decay_to_zero: float, interpret: bool,
               block_rows: int | None = None):
     """Build the pallas_call for one (rows, cap) shape + weight constants.
-    Raises whatever Pallas/Mosaic raises — callers go through the probe.
+    Raises whatever Pallas/Mosaic raises.
     `block_rows` overrides the tuned/heuristic block (the microbench
     sweep's knob); it must tile n_rows exactly."""
     from jax.experimental import pallas as pl
@@ -119,17 +115,24 @@ def score_update(fmd, slow_penalty, f_scale, s_scale, params, *,
     )(scales, fmd.astype(jnp.float32), slow_penalty.astype(jnp.float32))
 
 
+def score_kernel_routed() -> bool:
+    """The whole routing rule: the kernel exists to exploit TPU VMEM, so it
+    is taken on a TPU backend and nowhere else (interpret mode on CPU is a
+    test vehicle, not a win)."""
+    return jax.default_backend() == "tpu"
+
+
 def score_update_best(fmd, slow_penalty, f_scale, s_scale, params):
-    """The dispatch point consumers call (parallel/exchange._src_gather's
-    routing pattern): the Pallas kernel when the one-shot capability probe
-    passes on this backend, the plain-XLA formulation everywhere else."""
-    if score_kernel_available():
+    """The dispatch point for consumers: the Pallas kernel where
+    `score_kernel_routed()`, the plain-XLA formulation elsewhere. A routed
+    kernel that fails to build is an error, never a silent XLA run."""
+    if score_kernel_routed():
         return score_update(fmd, slow_penalty, f_scale, s_scale, params)
     return score_update_xla(fmd, slow_penalty, f_scale, s_scale, params)
 
 
 def score_update_xla(fmd, slow_penalty, f_scale, s_scale, params):
-    """The plain-XLA reference and fallback: literally the
+    """The plain-XLA reference and the off-TPU formulation: literally the
     ops/heartbeat._apply_decay composition followed by SimState.score, so
     the kernel's correctness target IS the production formula."""
     f = fmd * f_scale
@@ -139,51 +142,3 @@ def score_update_xla(fmd, slow_penalty, f_scale, s_scale, params):
     score = (params.fmd_weight * jnp.minimum(f, params.fmd_cap)
              + params.slow_weight * s)
     return f, s, score
-
-
-def _probe() -> bool:
-    """Compile + run a miniature instance on the real backend and check it
-    against the XLA reference. True only if everything compiles AND the
-    counters match bitwise (the score read carries an ulp-level FMA
-    tolerance)."""
-    if jax.default_backend() != "tpu":
-        # the kernel exists to exploit TPU VMEM; interpret mode on CPU is
-        # a test vehicle, not a win
-        return False
-    try:
-        from ..ops.state import SimParams
-
-        n, c = 256, 8
-        params = SimParams(n=n, capacity=c, slow_weight=-10.0)
-        fmd = (jnp.arange(n * c, dtype=jnp.float32).reshape(n, c) % 13) * 0.3
-        slow = (jnp.arange(n * c, dtype=jnp.float32).reshape(n, c) % 7) * 0.2
-        want = score_update_xla(fmd, slow, 0.9, 0.8, params)
-        got = jax.jit(functools.partial(score_update, params=params))(
-            fmd, slow, 0.9, 0.8)
-        # the carried counters must come back bit-for-bit; the weighted
-        # score read tolerates a few ulp of FMA contraction — the same
-        # class of difference XLA's own fusion choices introduce between
-        # jitted and eager evaluations of the reference formula
-        if not (bool(jnp.all(got[0] == want[0]))
-                and bool(jnp.all(got[1] == want[1]))):
-            return False
-        return bool(jnp.allclose(got[2], want[2], rtol=1e-5, atol=1e-6))
-    except Exception:  # noqa: BLE001 - ANY failure means "not available"
-        return False
-
-
-@functools.cache
-def score_kernel_available() -> bool:
-    """One-shot cached capability verdict. Env override DST_PALLAS_SCORE:
-    "0" forces off, "1" runs the probe but RAISES on failure (so a
-    toolchain where the kernel should work can't silently degrade)."""
-    env = os.environ.get(_ENV, "")
-    if env == "0":
-        return False
-    ok = _probe()
-    if env == "1" and not ok:
-        raise RuntimeError(
-            "DST_PALLAS_SCORE=1 but the scoring-update probe failed "
-            "(backend not TPU, Mosaic rejected the kernel, or numerics "
-            "mismatched)")
-    return ok

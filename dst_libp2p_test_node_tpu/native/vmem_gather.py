@@ -1,42 +1,33 @@
-"""Pallas VMEM-gather kernel for the receiver-side fixpoint — the PARITY
-"Known gaps" retry, behind a runtime capability probe.
+"""Pallas VMEM-gather kernel for the receiver-side fixpoint. NOT ROUTED:
+parallel/exchange._src_gather is the plain XLA gather.
 
 The hot gather of the receiver-side formulation (parallel/exchange._inc_from)
 is `t_all[src]`: an (N, C) int32 index into the (N,) f32 arrival-time vector,
-once per fixpoint iteration. XLA lowers it as a generic dynamic-gather that
-re-streams from HBM; the whole t vector is tiny (400 KB at 100k peers, 4 MB
-at 1M — comfortably inside one core's ~16 MB VMEM), so the kernel here pins
-it VMEM-resident for the entire row sweep and gathers each row block against
-it with a single vectorized take.
+once per fixpoint iteration. The whole t vector is tiny (400 KB at 100k
+peers, 4 MB at 1M), so the kernel here pins it VMEM-resident for the entire
+row sweep and gathers each row block against it with one vectorized take.
 
-An earlier attempt (PARITY "Known gaps") was blocked by the then-current
-Mosaic toolchain: no vectorized VMEM gather, and the scalar-store/scalar-loop
-workarounds crashed the compiler. Whether THIS formulation compiles is
-therefore decided at runtime by `gather_kernel_available()`: a one-shot
-cached probe that compiles and runs a miniature instance (including under
-vmap — the fragment axis vmaps the callers) and compares it against the
-plain-XLA reference. Any failure — import error, Mosaic rejection, wrong
-numerics — makes the probe False and callers keep the receiver-side
-constant formulation unchanged, so CPU CI and older toolchains stay green
-by construction. `DST_PALLAS_GATHER=0` forces the kernel off (bench A/B
-isolation); `=1` forces the probe to raise instead of degrade (debugging a
-toolchain where it SHOULD work).
-
-CPU correctness of the kernel body itself is tested with `interpret=True`
-(tests/test_exact_prefix.py), which runs the Pallas program without Mosaic.
+The installed toolchain (jax 0.9.0 / libtpu 0.0.34) refuses it when asked
+to compile for a v5e: `NotImplementedError: Only 2D gather is supported`
+(the 1-D `jnp.take` below), at every shape tried, and under vmap the (n_src,)
+block additionally fails the (8, 128) block-shape rule. Mosaic's 2-D gather
+takes indices of the operand's own shape along one axis, which is not an
+(N, C)-index lookup into an N-vector. So nothing routes here: there is no
+capability probe and no environment switch, and a caller that wants the
+kernel calls `vmem_gather` itself and gets the compiler's error. What stays
+is the kernel body, for the interpret-mode test (tests/test_exact_prefix.py)
+and the microbench block sweep, until ROADMAP speed item 3 / design item 5
+either gives it a formulation Mosaic lowers or deletes it.
 """
 
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
 
 from .tuned import tuned_block_rows
-
-_ENV = "DST_PALLAS_GATHER"
 
 # largest row-block whose int32 index + f32 output tiles stay a small
 # fraction of VMEM next to the resident t vector (8 * C * 8 bytes per
@@ -49,8 +40,8 @@ def _block_rows(n_rows: int) -> int:
     entry (native/tuned.py), else the largest power-of-two row block
     <= _MAX_BLOCK dividing n_rows (grid steps must tile the array exactly;
     every simulator shape is a round number, and a worst-case odd N just
-    runs block=1 under interpret in tests — the probe rejects it for the
-    real kernel)."""
+    runs block=1 under interpret in tests — _compiled rejects it for the
+    compiled kernel)."""
     tuned = tuned_block_rows("vmem_gather", n_rows, _MAX_BLOCK)
     if tuned is not None:
         return tuned
@@ -64,7 +55,7 @@ def _block_rows(n_rows: int) -> int:
 def _compiled(n_rows: int, cap: int, n_src: int, interpret: bool,
               block_rows: int | None = None):
     """Build the pallas_call for one (rows, cap, src-len) shape. Raises
-    whatever Pallas/Mosaic raises — callers go through the probe.
+    whatever Pallas/Mosaic raises.
     `block_rows` overrides the tuned/heuristic block (the microbench
     sweep's knob); it must tile n_rows exactly."""
     from jax.experimental import pallas as pl
@@ -104,52 +95,10 @@ def vmem_gather(t_all: jnp.ndarray, src: jnp.ndarray, *,
                 interpret: bool = False,
                 block_rows: int | None = None) -> jnp.ndarray:
     """out[q, j] = t_all[max(src[q, j], 0)] via the VMEM-resident kernel.
-    Same clip-negative-to-0 convention as the XLA fallback (pad slots are
+    Same clip-negative-to-0 convention as exchange._src_gather (pad slots are
     masked by the caller's validity flags, so row 0's value is dead
     there). `block_rows` is the microbench sweep's explicit row-block
     override; production callers leave it None (tuned.json/heuristic)."""
     idx = jnp.clip(src, 0)
     return _compiled(src.shape[0], src.shape[1], t_all.shape[0],
                      interpret, block_rows)(t_all.astype(jnp.float32), idx)
-
-
-def _probe() -> bool:
-    """Compile + run a miniature instance on the real backend (plus one
-    vmapped application — the fragment axis vmaps the callers) and check
-    it against plain XLA. True only if everything compiles AND matches."""
-    if jax.default_backend() != "tpu":
-        # the kernel exists to exploit TPU VMEM; interpret mode on CPU is
-        # a test vehicle, not a win
-        return False
-    try:
-        n, c = 256, 8
-        t = jnp.arange(n, dtype=jnp.float32) * 0.5
-        src = (jnp.arange(n * c, dtype=jnp.int32).reshape(n, c) * 7) % n
-        src = src.at[0, 0].set(-1)
-        want = t[jnp.clip(src, 0)]
-        got = jax.jit(vmem_gather)(t, src)
-        if not bool(jnp.all(got == want)):
-            return False
-        got_v = jax.jit(jax.vmap(vmem_gather, in_axes=(None, 0)))(
-            t, jnp.stack([src, (src + 1) % n]))
-        want_v = jnp.stack([want, t[(src + 1) % n]])
-        return bool(jnp.all(got_v == want_v))
-    except Exception:  # noqa: BLE001 - ANY failure means "not available"
-        return False
-
-
-@functools.cache
-def gather_kernel_available() -> bool:
-    """One-shot cached capability verdict. Env override DST_PALLAS_GATHER:
-    "0" forces off, "1" runs the probe but RAISES on failure (so a
-    toolchain where the kernel should work can't silently degrade)."""
-    env = os.environ.get(_ENV, "")
-    if env == "0":
-        return False
-    ok = _probe()
-    if env == "1" and not ok:
-        raise RuntimeError(
-            "DST_PALLAS_GATHER=1 but the VMEM-gather probe failed "
-            "(backend not TPU, Mosaic rejected the kernel, or numerics "
-            "mismatched)")
-    return ok

@@ -861,7 +861,7 @@ def run_adaptive_heartbeats(
     if telemetry is not None and not telemetry.enabled:
         telemetry = None
     if ctrl is None:
-        ctrl = init_adaptive_ctrl(params.n)
+        ctrl = init_adaptive_ctrl(params.n, like=attacker)
     if repair_inert(params):
         state, saved = strip_repair(state)
         (out, ctrl), obs = _run_adaptive_heartbeats(

@@ -324,6 +324,27 @@ def _next_heartbeat(t, phase, hb_ms):
     return (jnp.floor((t - phase) / hb_ms) + 1.0) * hb_ms + phase
 
 
+def fixpoint_formulation(conns_shape, fragments: int = 1, mesh=None) -> str:
+    """Which formulation of the arrival-time fixpoint `disseminate` traces
+    for this shape — decided at trace time from the mesh and the row-gather
+    memory budget (ops/pull.exceeds_budget), nothing else:
+
+      "recv_sharded"  parallel/exchange.converge_sharded: receiver-side
+                      constants under shard_map, one all-gather of t per
+                      iteration, then exchange._src_gather
+      "recv"          parallel/exchange.converge_recv: the same expression
+                      on one device (the row-gather intermediate would not
+                      fit the budget — the 1M-peer class)
+      "row_pull"      sender-side offers + ops/pull.reciprocal_pull_min's
+                      whole-row gather per iteration (one device, in budget)
+    """
+    if mesh is not None:
+        return "recv_sharded"
+    if exceeds_budget(jnp.float32, conns_shape, fragments):
+        return "recv"
+    return "row_pull"
+
+
 @partial(
     jax.jit,
     static_argnames=("params", "payload_bytes", "fragments", "with_gossip",
@@ -894,6 +915,8 @@ def disseminate(
             cand = jnp.minimum(cand, ga)
         return cand
 
+    formulation = fixpoint_formulation(conns.shape, fragments, mesh)
+
     def pull(cand):
         """incoming[q, j] = offer made to q by the neighbor in its slot j
         (row-gather + fused slot select; see ops/pull.py for why). Runs
@@ -927,7 +950,7 @@ def disseminate(
         ld = _ld_mesh(frag_idx)
         deliver = send_mask if sv is None else send_mask & sv
         g_deliver = g_tgt if sv is None else g_tgt & sv
-        if mesh is not None:
+        if formulation == "recv_sharded":
             # sharded: receiver-local constants, one (N,) all-gather + one
             # psum per iteration over ICI (parallel/exchange.py)
             c = build_recv_constants(
@@ -938,7 +961,7 @@ def disseminate(
                 packed=params.packed_state,
             )
             return converge_sharded(t0, c, params.max_relax_iters, mesh)
-        if exceeds_budget(jnp.float32, conns.shape, fragments):
+        if formulation == "recv":
             # large N (1M-peer class): the row-gather pull would blow the
             # memory budget and its 2-index fallback costs ~0.7 s/iteration —
             # switch to the receiver-side constant formulation: per-edge
@@ -1009,15 +1032,14 @@ def disseminate(
         sv = _frag_slice(survive, frag_idx)
         ld = _ld_mesh(frag_idx)
         deliver = send_mask if sv is None else send_mask & sv
-        if mesh is not None or exceeds_budget(jnp.float32, conns.shape,
-                                              fragments):
+        if formulation != "row_pull":
             c = build_recv_constants(
                 conns, rev, lat_edge, tx_ms, rank, k_p, frag_idx, deliver,
                 can_send, g_tgt, g_off, hb_phase, uplink, rx_const,
                 params.proc_delay_ms, params.heartbeat_ms, False,
                 lat_deliver=ld, packed=params.packed_state,
             )
-            if mesh is not None:
+            if formulation == "recv_sharded":
                 t_rx, _, _ = converge_sharded(
                     t0, c, params.max_relax_iters, mesh, g_floor=g_floor)
             else:
@@ -1500,9 +1522,7 @@ def disseminate(
         # "serial" (the reference engine the prefix path is pinned
         # against), the global-sort pipeline runs as before.
         use_prefix = (params.answer_queue_mode == "parallel_prefix"
-                      and mesh is None
-                      and not exceeds_budget(jnp.float32, conns.shape,
-                                             fragments))
+                      and formulation == "row_pull")
 
         def _serial_all(seed):
             outs = [phases_serial(frag_ids[i], t_pubs[i], seed[i])

@@ -195,7 +195,9 @@ def heartbeat_step(
     # either way (k_graft was split above).
     need = jnp.where(deg < params.d_low, params.d - deg, 0)
 
-    zeros_n = jnp.zeros((n,), jnp.int32)
+    # built from deg so it varies over whatever manual axes deg does: a
+    # cond under shard_map needs both branches to agree on them
+    zeros_n = jnp.zeros_like(deg, dtype=jnp.int32)
 
     def do_graft(mesh):
         eligible = (valid & ~mesh & (state.backoff_until <= t)

@@ -416,13 +416,17 @@ class AdaptiveCtrl:
     throttled_hb: jnp.ndarray  # (N,) i32: rounds spent duty-cycled OFF
 
 
-def init_adaptive_ctrl(n: int) -> AdaptiveCtrl:
-    """Zeroed controller carry for a fresh trial window."""
+def init_adaptive_ctrl(n: int, like=None) -> AdaptiveCtrl:
+    """Zeroed controller carry for a fresh trial window. `like`: an (n,)
+    array of the trial the carry joins; inside a shard_map body the zeros
+    then vary over the same manual axes it does, which a scan carry needs."""
+    if like is None:
+        like = jnp.zeros((n,), dtype=jnp.int32)
     return AdaptiveCtrl(
-        viol_est=jnp.zeros((n,), dtype=jnp.float32),
-        regrafts=jnp.zeros((n,), dtype=jnp.int32),
-        px_injected=jnp.zeros((n,), dtype=jnp.int32),
-        throttled_hb=jnp.zeros((n,), dtype=jnp.int32),
+        viol_est=jnp.zeros_like(like, dtype=jnp.float32),
+        regrafts=jnp.zeros_like(like, dtype=jnp.int32),
+        px_injected=jnp.zeros_like(like, dtype=jnp.int32),
+        throttled_hb=jnp.zeros_like(like, dtype=jnp.int32),
     )
 
 

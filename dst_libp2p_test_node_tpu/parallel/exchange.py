@@ -35,7 +35,7 @@ import jax.numpy as jnp
 from flax import struct
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .sharding import shard_map as _shard_map  # jax-version compat resolver
+from .sharding import shard_map as _shard_map
 
 INF = jnp.float32(3.4e38)
 
@@ -167,17 +167,17 @@ def build_recv_constants(
     )
 
 
-def _src_gather(t_all: jnp.ndarray, src: jnp.ndarray) -> jnp.ndarray:
-    """The fixpoint's hot gather: t of every slot's sender. Routed through
-    the Pallas VMEM-resident kernel when the one-shot capability probe
-    passes on this backend (native/vmem_gather.py — the t vector stays
-    VMEM-pinned across the row sweep instead of re-streaming per block);
-    otherwise the plain XLA gather. Negative src marks pad slots; both
-    paths clip them to row 0, whose value is dead behind the flag masks."""
-    from ..native.vmem_gather import gather_kernel_available, vmem_gather
+# What `_src_gather` lowers to, stated once (chip_smoke.py prints it): the
+# plain XLA gather, on every backend. The Pallas VMEM kernel that used to
+# be probed for here does not compile on the installed toolchain
+# (native/vmem_gather.py has the compiler's message).
+SRC_GATHER = "xla"
 
-    if gather_kernel_available():
-        return vmem_gather(t_all, src)
+
+def _src_gather(t_all: jnp.ndarray, src: jnp.ndarray) -> jnp.ndarray:
+    """The fixpoint's hot gather: t of every slot's sender. Negative src
+    marks pad slots; they clip to row 0, whose value is dead behind the
+    flag masks."""
     return t_all[jnp.clip(src, 0)]
 
 
@@ -293,9 +293,12 @@ def converge_sharded(
                 jnp.any(t_new < t_l).astype(jnp.int32), axis_name) > 0
             return t_new, inc, changed, it + 1
 
+        # the body returns inc varying over the peer axis, so the carry
+        # has to start that way (shard_map checks varying manual axes)
+        inc0 = jax.lax.pcast(
+            jnp.full(src.shape, INF), (axis_name,), to="varying")
         t_l, inc_l, changed, _ = jax.lax.while_loop(
-            cond, body,
-            (t0_l, jnp.full(src.shape, INF), jnp.bool_(True), jnp.int32(0)))
+            cond, body, (t0_l, inc0, jnp.bool_(True), jnp.int32(0)))
         return t_l, inc_l, ~changed
 
     fn = _shard_map(

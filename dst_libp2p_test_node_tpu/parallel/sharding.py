@@ -21,20 +21,7 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# shard_map graduated from jax.experimental to the jax namespace in 0.6;
-# resolve whichever this environment ships so the sharded paths run on both
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:  # pragma: no cover - version-dependent import path
-    from functools import partial as _partial
-
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    # the 0.4.x experimental shard_map has no replication rule for
-    # while_loop (the dissemination fixpoint carries one): disable the rep
-    # check — out_specs still declare what is replicated, and the psums
-    # inside the mapped bodies are what actually replicate it
-    shard_map = _partial(_exp_shard_map, check_rep=False)
+shard_map = jax.shard_map
 
 
 def initialize_multihost(

@@ -622,6 +622,22 @@ def _protocol_window_runner(protocol: str, runner: str):
     return getattr(spec, runner)
 
 
+def _vary_over_trials(*shared_arrays):
+    """Inside the trial-only shard_map body: give the replicated epoch-graph
+    arrays the trial axis before the window reads them. vmap turns a `cond`
+    on a per-trial predicate into a select over both branches and re-binds
+    their equations with every operand trial-varying; constants the branch
+    compared against an unvarying graph array then no longer match
+    (shard_map's varying-axes check). Varying from the start, the trace
+    records the casts it needs."""
+    import jax
+
+    from ..parallel.sharding import TRIAL_AXIS
+
+    return tuple(jax.lax.pcast(x, (TRIAL_AXIS,), to="varying")
+                 for x in shared_arrays)
+
+
 def sharded_attack_window(stacked, shared: dict, attackers, params, adv,
                           steps: int, trial_mesh, local_trials: int,
                           nested: bool = True, telemetry=None,
@@ -672,6 +688,8 @@ def sharded_attack_window(stacked, shared: dict, attackers, params, adv,
     t, r = P(TRIAL_AXIS), P()
 
     def group(st, at, cn, rv, om):
+        cn, rv, om = _vary_over_trials(cn, rv, om)
+
         def one(s, a):
             return run_win(
                 s, cn, rv, om, a, params, adv, steps,
@@ -749,6 +767,8 @@ def sharded_recovery_window(stacked, shared: dict, attackers, rparams,
     t, r = P(TRIAL_AXIS), P()
 
     def group(st, at, cn, rv, om):
+        cn, rv, om = _vary_over_trials(cn, rv, om)
+
         def one(s, a):
             return run_recovery_heartbeats(
                 s, cn, rv, om, a, rparams, steps, publisher=publisher,

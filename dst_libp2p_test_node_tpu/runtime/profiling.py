@@ -149,31 +149,24 @@ def lower_spec(spec, return_dynamic: bool = False,
 
 def entrypoint_cost(contract) -> dict:
     """{flops, hbm_bytes, peak_memory_bytes} for the contract's
-    representative program, from XLA's compile-time analyses. Fields the
-    backend/version does not expose come back None (strict-JSON null)."""
+    representative program, from XLA's compile-time analyses. A count the
+    backend's cost analysis does not report comes back None (strict-JSON
+    null); an analysis call that fails raises."""
     compiled = lower_spec(contract.build()).compile()
-    out: dict = {"flops": None, "hbm_bytes": None, "peak_memory_bytes": None}
-    try:
-        ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0] if ca else {}
-        if ca:
-            flops = ca.get("flops")
-            if flops is not None and float(flops) >= 0:
-                out["flops"] = float(flops)
-            hbm = ca.get("bytes accessed")
-            if hbm is not None and float(hbm) >= 0:
-                out["hbm_bytes"] = float(hbm)
-    except Exception:
-        pass
-    try:
-        ma = compiled.memory_analysis()
-        peak = (int(ma.argument_size_in_bytes) + int(ma.output_size_in_bytes)
-                + int(ma.temp_size_in_bytes) - int(ma.alias_size_in_bytes))
-        out["peak_memory_bytes"] = peak
-    except Exception:
-        pass
-    return out
+    ca = compiled.cost_analysis() or {}
+
+    def count(key):
+        v = ca.get(key)
+        return float(v) if v is not None and float(v) >= 0 else None
+
+    ma = compiled.memory_analysis()
+    return {
+        "flops": count("flops"),
+        "hbm_bytes": count("bytes accessed"),
+        "peak_memory_bytes": (
+            int(ma.argument_size_in_bytes) + int(ma.output_size_in_bytes)
+            + int(ma.temp_size_in_bytes) - int(ma.alias_size_in_bytes)),
+    }
 
 
 def measure_retraces(contract) -> int:

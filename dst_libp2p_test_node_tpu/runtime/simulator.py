@@ -133,6 +133,13 @@ class ExperimentConfig:
     msgid_mode: str = "nim"
 
 
+def graph_capacity(cfg: ExperimentConfig) -> int:
+    """Neighbor-table width C of the (N, C) arrays a Simulator builds for
+    `cfg`: MAXCONNECTIONS, or the dial-count slack that keeps rejections
+    rare, whichever is smaller."""
+    return min(cfg.max_connections, max(4 * cfg.connect_to, 16))
+
+
 def drain_heartbeat_carry(carry_ms: float, ms: float, hb_ms: float):
     """Advance a fractional-heartbeat accumulator: returns (whole heartbeat
     steps due, new carry). Shared by every runtime that steps simulated time
@@ -170,6 +177,7 @@ def record_from_result(
         # is 0.0 anyway
         answer_wait_max_ms=float(np.asarray(
             getattr(res, "answer_wait_max_ms", 0.0))),
+        converged=bool(np.asarray(getattr(res, "converged", True))),
     )
 
 
@@ -206,6 +214,10 @@ class MessageRecord:
     # per-hop arrival-time error bar — max time any requested gossip
     # answer waited queued. 0.0 in the exact default mode.
     answer_wait_max_ms: float = 0.0
+    # DisseminationResult.converged: False when an iteration cap cut one of
+    # the fixpoints this record rode (not checkpointed; views that carry no
+    # bit read True)
+    converged: bool = True
 
     @property
     def receivers(self) -> np.ndarray:
@@ -246,7 +258,7 @@ class Simulator:
             n,
             cfg.connect_to,
             seed=cfg.seed,
-            max_degree=min(cfg.max_connections, max(4 * cfg.connect_to, 16)),
+            max_degree=graph_capacity(cfg),
         )
         proc_ms = MUXER_PROC_MS.get(cfg.topo.muxer.lower(), 2.0)
         self.params = SimParams.from_gossipsub(

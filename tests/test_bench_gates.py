@@ -183,8 +183,9 @@ def test_bench_tripwire_parses_committed_artifacts(tmp_path):
     # must dig it out of the live artifacts and out of a synthetic wrapper,
     # and skip unparseable files instead of crashing
     bench = _load_bench()
-    best = bench.best_committed_peer_rounds()
-    assert best is not None and best > 25e6  # the r04 31.4M record
+    # live artifacts: r06/r07, the 2,000-peer CPU smokes (the r01-r05
+    # records of the retired device stack were deleted with PR 24)
+    assert bench.best_committed_peer_rounds() == 34479.0   # BENCH_r07
     assert bench.best_committed_peer_rounds(str(tmp_path)) is None
     (tmp_path / "BENCH_r01.json").write_text(json.dumps(
         {"n": 1, "rc": 0, "tail": "WARNING: noise\n"
@@ -201,11 +202,31 @@ def test_bench_tripwire_is_keyed_per_config(tmp_path):
     # so the heavy config's best is the r05 record, not the global 31.4M
     # (which would perpetually trip >20% "regressions" on heavy runs)
     bench = _load_bench()
+
+    def wrapper(value, detail=None):
+        rec = {"metric": "simulated_peer_rounds_per_sec", "value": value}
+        if detail is not None:
+            rec["detail"] = detail
+        return json.dumps({"n": 1, "rc": 0, "tail": json.dumps(rec)})
+
+    # the shapes of the deleted r04 (no key fields: the legacy light bucket)
+    # and r05 (delivery_mode + workload shape, no explicit key) records
+    shapes = tmp_path / "shapes"
+    shapes.mkdir()
+    (shapes / "BENCH_r04.json").write_text(wrapper(31.43e6))
+    (shapes / "BENCH_r05.json").write_text(wrapper(14.08e6, {
+        "delivery_mode": "bounded", "n_peers": 100000, "rounds": 300,
+        "timed_messages": 3}))
     heavy = bench.best_committed_peer_rounds(
-        config_key="n100000-r300-m3-bounded")
-    assert heavy is not None and 10e6 < heavy < 25e6  # the r05 14.08M row
-    light = bench.best_committed_peer_rounds(config_key="pre-r5-light")
-    assert light is not None and light > 25e6  # r01-r04 bucket keeps 31.4M
+        str(shapes), config_key="n100000-r300-m3-bounded")
+    assert heavy == 14.08e6
+    light = bench.best_committed_peer_rounds(
+        str(shapes), config_key="pre-r5-light")
+    assert light == 31.43e6  # the light bucket keeps its own best
+    # live: each committed smoke sits in the bucket its explicit key names
+    assert bench.best_committed_peer_rounds(
+        config_key="n2000-r30-m3-exact-dht-svc-batched-adaptive-fused"
+    ) == 31736.0   # BENCH_r06
     # the live bench emits its key explicitly, and explicit beats derived.
     # Workload-identity changes ride the key: the exact-default flip added
     # the mode suffix, the cross-protocol DHT probe the -dht suffix, and

@@ -1,0 +1,149 @@
+"""The main path's device programs, compiled for a described TPU v5e.
+
+The chip's compiler is installed here and compiles for a chip that is
+described, not attached (no device executes anything): what Mosaic or XLA:TPU
+would refuse on the machine with the chip, it refuses in this file, at no
+chip time. Shapes are the 100,000-peer headline config's
+(`chip_smoke.py` phase 2). Cases:
+
+  - native/score_update.py — the Pallas kernel `score_update_best` routes to
+    on a TPU backend — at (N, capacity);
+  - the gather `parallel/exchange._src_gather` lowers to (the plain XLA
+    gather: `exchange.SRC_GATHER`), also in the vmapped fragment form;
+  - the sharded fixpoint `converge_sharded` on a 4-chip peer mesh: the
+    collective the design rests on is in the HLO and the per-device memory
+    fits a v5e.
+
+Everything that touches the topology lives in fixtures of THIS file (one
+xdist worker loads the TPU library, only after a test here has started);
+the persistent compilation cache is off around the compiles, since an entry
+written for a described chip cannot be read back without one.
+"""
+
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+N = 100_000
+V5E_HBM_BYTES = 16e9
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure to describe = skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _no_persistent_cache():
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", True)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def capacity():
+    from dst_libp2p_test_node_tpu.runtime.simulator import (
+        ExperimentConfig, graph_capacity)
+
+    return graph_capacity(ExperimentConfig())   # connect-to 10, as the CLI
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    sharding = SingleDeviceSharding(topo.devices[0])
+    return lambda shape, dtype: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=sharding)
+
+
+@pytest.fixture(scope="module")
+def peer_mesh(topo):
+    return Mesh(np.array(topo.devices[:4]), ("peers",))
+
+
+def _device_bytes(compiled) -> int:
+    ma = compiled.memory_analysis()
+    return (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+
+
+def test_score_update_kernel_compiles_for_v5e(one_chip, capacity):
+    import functools
+
+    from dst_libp2p_test_node_tpu.native.score_update import score_update
+    from dst_libp2p_test_node_tpu.ops.state import SimParams
+
+    params = SimParams(n=N, capacity=capacity, slow_weight=-10.0)
+    compiled = jax.jit(functools.partial(score_update, params=params)).lower(
+        one_chip((N, capacity), jnp.float32),
+        one_chip((N, capacity), jnp.float32),
+        one_chip((), jnp.float32), one_chip((), jnp.float32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert _device_bytes(compiled) < V5E_HBM_BYTES
+
+
+@pytest.mark.parametrize("fragments", [None, 4])
+def test_src_gather_formulation_compiles_for_v5e(one_chip, capacity,
+                                                 fragments):
+    from dst_libp2p_test_node_tpu.parallel import exchange
+
+    assert exchange.SRC_GATHER == "xla"
+    src = one_chip((N, capacity), jnp.int32)
+    if fragments is None:
+        fn, t = exchange._src_gather, one_chip((N,), jnp.float32)
+    else:   # the fragment axis vmaps the fixpoint over a shared src table
+        fn = jax.vmap(exchange._src_gather, in_axes=(0, None))
+        t = one_chip((fragments, N), jnp.float32)
+    text = jax.jit(fn).lower(t, src).compile().as_text()
+    assert "gather" in text and "tpu_custom_call" not in text
+
+
+def test_sharded_fixpoint_compiles_for_four_chips(peer_mesh, capacity):
+    from dst_libp2p_test_node_tpu.parallel.exchange import (
+        RecvConstants, converge_sharded)
+
+    rows = NamedSharding(peer_mesh, P("peers"))
+    everywhere = NamedSharding(peer_mesh, P())
+
+    def edge(dtype):
+        return jax.ShapeDtypeStruct((N, capacity), dtype, sharding=rows)
+
+    def peer(dtype):
+        return jax.ShapeDtypeStruct((N,), dtype, sharding=rows)
+
+    scalar = jax.ShapeDtypeStruct((), jnp.float32, sharding=everywhere)
+    c = RecvConstants(
+        src=edge(jnp.int32), a_ms=edge(jnp.float32), g_ms=edge(jnp.float32),
+        g_off=edge(jnp.float32), phase=edge(jnp.float32),
+        u_ms=edge(jnp.float32), flags=edge(jnp.int8),
+        rx_c=peer(jnp.float32), proc_ms=scalar, hb_ms=scalar)
+    lowered = jax.jit(
+        lambda t0, c: converge_sharded(t0, c, 64, peer_mesh)
+    ).lower(peer(jnp.float32), c)
+    # the design's one per-iteration exchange: the (N,) t vector
+    assert "all-gather" in lowered.as_text(dialect="hlo")
+    compiled = lowered.compile()
+    # ... which the v5e compiler may keep, or rewrite as an all-reduce over
+    # a padded buffer (it does at this size); either way it crosses chips
+    text = compiled.as_text()
+    assert "all-gather" in text or "all-reduce" in text
+    assert "f32[25000]" in text     # rows really are N/4 per device
+    assert _device_bytes(compiled) < V5E_HBM_BYTES
+    t_out, inc_out, _ = compiled.output_shardings
+    assert t_out.spec == P("peers") and inc_out.spec == P("peers")

@@ -6,7 +6,7 @@ The parallel-prefix answer-queue refinement (SimParams.answer_queue_mode
 on the integer counters and delivery masks, to float tolerance on arrival
 times, with the exactness certificate (converged=True) and a bounded pass
 count. The packed dissemination state (SimParams.packed_state) and the
-Pallas VMEM-gather capability probe (native/vmem_gather.py) are the two
+Pallas VMEM-gather kernel body (native/vmem_gather.py) are the two
 satellite fronts pinned here too.
 """
 
@@ -81,7 +81,9 @@ def _pin_engines_equal(res_p, res_s, *, delay_rtol=1e-6):
 @pytest.mark.parametrize("kw,over", [
     ({}, {}),
     ({"fragments": 4}, {}),
-    ({}, {"flood_publish": False, "d_lazy": 12}),
+    # publisher 3: on jax 0.9.0's random streams the default publisher's
+    # draw forms no answer queue in this scenario (refine_passes == 0)
+    ({"publisher": 3}, {"flood_publish": False, "d_lazy": 12}),
     ({"fragments": 3}, {"flood_publish": False, "d_lazy": 12}),
 ], ids=["mesh", "mesh-frag4", "gossip-heavy", "gossip-heavy-frag3"])
 def test_prefix_matches_serial_engine(kw, over):
@@ -302,32 +304,26 @@ def test_vmem_gather_interpret_matches_reference():
         np.testing.assert_array_equal(np.asarray(got), want)
 
 
-def test_gather_probe_is_false_off_tpu_and_env_gated(monkeypatch):
+def test_src_gather_is_statically_the_xla_gather(monkeypatch):
+    # the gather is decided by what exchange.py states, not by a probe or
+    # an environment switch: no pallas_call in its trace, whatever the
+    # retired DST_PALLAS_GATHER says, and no capability function left
     from dst_libp2p_test_node_tpu.native import vmem_gather as vg
+    from dst_libp2p_test_node_tpu.parallel import exchange
 
-    vg.gather_kernel_available.cache_clear()
-    try:
-        # CI runs CPU: the capability probe must refuse without trying to
-        # compile Mosaic (the kernel exists to exploit TPU VMEM)
-        monkeypatch.delenv("DST_PALLAS_GATHER", raising=False)
-        assert vg.gather_kernel_available() is False
-        # "0" forces off regardless of backend
-        vg.gather_kernel_available.cache_clear()
-        monkeypatch.setenv("DST_PALLAS_GATHER", "0")
-        assert vg.gather_kernel_available() is False
-        # "1" must RAISE rather than silently degrade when the probe fails
-        vg.gather_kernel_available.cache_clear()
-        monkeypatch.setenv("DST_PALLAS_GATHER", "1")
-        with pytest.raises(RuntimeError, match="probe failed"):
-            vg.gather_kernel_available()
-    finally:
-        vg.gather_kernel_available.cache_clear()
+    assert exchange.SRC_GATHER == "xla"
+    assert not hasattr(vg, "gather_kernel_available")
+    t = jnp.zeros((128,), jnp.float32)
+    src = jnp.zeros((128, 6), jnp.int32)
+    for env in ("0", "1"):
+        monkeypatch.setenv("DST_PALLAS_GATHER", env)
+        text = str(jax.make_jaxpr(exchange._src_gather)(t, src))
+        assert "pallas_call" not in text and "gather" in text
 
 
-def test_src_gather_falls_back_to_xla_off_tpu():
-    # the exchange fixpoint's hot gather must keep the receiver-side
-    # constant formulation wherever the kernel is unavailable — same
-    # values as the plain clipped gather, inside a jit
+def test_src_gather_matches_clipped_gather():
+    # the exchange fixpoint's hot gather: same values as the plain clipped
+    # numpy gather, inside a jit
     from dst_libp2p_test_node_tpu.parallel.exchange import _src_gather
 
     rng = np.random.default_rng(1)

@@ -20,6 +20,7 @@ import os
 import re
 
 import numpy as np
+import pytest
 
 from dst_libp2p_test_node_tpu.runtime.metrics import (
     Registry, _escape_label_value, _fmt_labels, _fmt_value,
@@ -154,6 +155,63 @@ def test_logemit_fast_paths_byte_identical():
     if native_logemit.ensure_built():  # toolchain-gated native path
         native = native_logemit.format_block(msg_id, peers, linenos, delays)
         assert native == ref
+
+
+@pytest.fixture
+def logemit_sandbox(tmp_path, monkeypatch):
+    """native_logemit pointed at a private copy of logemit.cpp, unloaded."""
+    import shutil
+
+    from dst_libp2p_test_node_tpu.runtime import native_logemit as nl
+
+    if shutil.which("g++") is None:
+        pytest.skip("no g++: the native emitter cannot be built here")
+    shutil.copy(nl._SRC, tmp_path / "logemit.cpp")
+    monkeypatch.setattr(nl, "_NATIVE_DIR", str(tmp_path))
+    monkeypatch.setattr(nl, "_SRC", str(tmp_path / "logemit.cpp"))
+    monkeypatch.setattr(nl, "_native", None)
+    monkeypatch.setattr(nl, "_native_tried", False)
+    return nl, tmp_path
+
+
+def _block_args(n=5000):
+    peers = np.arange(n, dtype=np.int64)
+    return 99, peers, np.ones(n, dtype=np.int64), peers % 977
+
+
+def test_logemit_is_built_from_the_source_never_a_stale_binary(
+        logemit_sandbox, monkeypatch):
+    nl, d = logemit_sandbox
+    # a binary left over from some other version of the source, under the
+    # old fixed name and older than logemit.cpp: never what runs
+    (d / "liblogemit.so").write_bytes(b"not a library")
+    os.utime(d / "liblogemit.so", (0, 0))
+    assert nl.ensure_built()
+    built = nl.lib_path()
+    assert os.path.exists(built) and built != str(d / "liblogemit.so")
+    before = nl.native_blocks
+    native = nl.format_block(*_block_args())
+    assert nl.native_blocks == before + 1
+    assert native == nl.format_block(*_block_args(), force_python=True)
+    # a changed source is a different library name: rebuilt, not reused
+    with open(d / "logemit.cpp", "a") as f:
+        f.write("\n// edited\n")
+    monkeypatch.setattr(nl, "_native", None)
+    monkeypatch.setattr(nl, "_native_tried", False)
+    assert nl.lib_path() != built and not os.path.exists(nl.lib_path())
+    assert nl.ensure_built() and os.path.exists(nl.lib_path())
+
+
+def test_logemit_build_failure_is_reported_once_and_bytes_stay_identical(
+        logemit_sandbox, capfd):
+    nl, d = logemit_sandbox
+    (d / "logemit.cpp").write_text("this is not C++\n")
+    py = nl.format_block(*_block_args(), force_python=True)
+    assert nl.format_block(*_block_args()) == py
+    assert nl.format_block(*_block_args()) == py
+    assert not nl.ensure_built()
+    err = capfd.readouterr().err
+    assert err.count("native log emitter unavailable") == 1
 
 
 def test_latencies_writer_matches_parser():

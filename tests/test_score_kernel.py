@@ -1,15 +1,14 @@
 """Pallas fused scoring-update kernel + autotuned block table contracts.
 
-native/score_update.py follows the vmem_gather discipline: interpret mode
+native/score_update.py: interpret mode
 is the CPU correctness vehicle for the kernel body (counters bitwise
 against `score_update_xla` — which IS the heartbeat _apply_decay +
 SimState.score composition — and the weighted score to ulp-level FMA
 tolerance, the same class of difference XLA's own fusion choices introduce
-between jitted and eager evaluations of the reference formula), the
-one-shot capability probe refuses off-TPU, the env gate
-forces off ("0") or raises on failure ("1"), and the `score_update_best`
-dispatcher keeps every consumer on the XLA formulation wherever the kernel
-is unavailable. The block chooser consults the microbench autotuner's
+between jitted and eager evaluations of the reference formula); routing
+is a static rule on the backend with no probe and no env switch, a routed
+kernel that cannot build raises, and off TPU the `score_update_best`
+dispatcher IS the XLA formulation. The block chooser consults the microbench autotuner's
 tuned.json (native/tuned.py) before the power-of-two heuristic — a
 malformed or non-tiling entry is ignored, never an invalid grid.
 """
@@ -80,30 +79,38 @@ def test_block_rows_override_validation():
         sk._compiled(12, 8, 1.0, -10.0, 100.0, 0.01, False, 4)
 
 
-def test_probe_false_off_tpu_and_env_gated(monkeypatch):
-    sk.score_kernel_available.cache_clear()
-    try:
-        # CI runs CPU: the probe must refuse (the kernel exists to exploit
-        # TPU VMEM; interpret mode is a test vehicle, not a win)
-        monkeypatch.delenv("DST_PALLAS_SCORE", raising=False)
-        assert sk.score_kernel_available() is False
-        # "0" forces off regardless of backend
-        sk.score_kernel_available.cache_clear()
-        monkeypatch.setenv("DST_PALLAS_SCORE", "0")
-        assert sk.score_kernel_available() is False
-        # "1" must RAISE rather than silently degrade when the probe fails
-        sk.score_kernel_available.cache_clear()
-        monkeypatch.setenv("DST_PALLAS_SCORE", "1")
-        with pytest.raises(RuntimeError, match="probe failed"):
-            sk.score_kernel_available()
-    finally:
-        sk.score_kernel_available.cache_clear()
+def test_routing_is_static_and_env_free(monkeypatch):
+    # CI runs CPU: the kernel is not routed here, and the retired
+    # DST_PALLAS_SCORE switch changes nothing — the rule is the backend
+    assert not hasattr(sk, "score_kernel_available")
+    for env in (None, "0", "1"):
+        if env is None:
+            monkeypatch.delenv("DST_PALLAS_SCORE", raising=False)
+        else:
+            monkeypatch.setenv("DST_PALLAS_SCORE", env)
+        assert sk.score_kernel_routed() is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert sk.score_kernel_routed() is True
 
 
-def test_dispatcher_falls_back_to_xla_off_tpu():
-    # score_update_best inside a jit must keep the XLA formulation where
-    # the probe fails — same values as calling the reference directly
-    sk.score_kernel_available.cache_clear()
+def test_routed_kernel_that_fails_to_build_raises(monkeypatch):
+    # a backend that claims to be a TPU routes the kernel; here it cannot
+    # build (no Mosaic on the CPU backend), and that must surface as an
+    # error — never as a silent run of the XLA formulation
+    params = _params(128, 6)
+    fmd, slow = _counters(128, 6, seed=2)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(Exception) as err:
+        jax.block_until_ready(jax.jit(
+            lambda f, s: sk.score_update_best(f, s, 0.9, 0.8, params)
+        )(fmd, slow))
+    assert "interpret" in str(err.value).lower() or "pallas" in str(
+        err.value).lower()
+
+
+def test_dispatcher_is_the_xla_formulation_off_tpu():
+    # score_update_best inside a jit is the XLA formulation where the
+    # kernel is not routed — same values as calling the reference directly
     params = _params(128, 6)
     fmd, slow = _counters(128, 6, seed=1)
     got = jax.jit(
