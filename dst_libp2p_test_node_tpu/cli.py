@@ -250,93 +250,122 @@ def cmd_run(argv: list[str]) -> int:
                     f"(mix-d={a.mix_d}, publisher inside mix range or "
                     f"rotation on), got {a.num_mix}")
 
+    from .runtime.profiling import span, turn
     from .runtime.simulator import ExperimentConfig, Simulator
     from .runtime.summarize import report
 
     topo = _topo_from_fields(vars(a), muxer=a.muxer)
-    if a.gml:
-        # run an existing experiment dir: link properties come from the GML
-        # (stage latencies/bandwidths), peers/messages from the positionals
-        t = Topology.from_gml(a.gml, network_size=topo.network_size,
-                              params=topo)
-        topo = t.params
-    elif a.resume:
-        # the checkpoint embeds its topology; do NOT overwrite the
-        # experiment dir's artifacts before (or after) validating it
-        t = None
-    else:
-        t = Topology.build(topo)
-        t.write_gml(a.out_prefix + "network_topology.gml")
-        t.write_shadow_yaml(a.out_prefix + "shadow.yaml")
-
-    large = topo.msg_size_bytes >= 1000
+    t = None
     for i in range(1, int(a.runs) + 1):
-        print(f"Running for turn {i}")
-        cfg = ExperimentConfig(
-            topo=topo,
-            connect_to=a.connect_to,
-            # the reference nodes read GOSSIPSUB_* inside the simulation, so
-            # the driver honors the same env surface (main.nim:252-306)
-            gossipsub=gossipsub_params_from_env(),
-            publisher_id=int(a.publisher_id),
-            publisher_rotation=bool(int(a.publisher_rotation)),
-            warmup_s=a.warmup_s,
-            seed=a.seed + i - 1,
-            with_gossip=not a.no_gossip,
-            churn_down_per_hb=a.churn,
-            churn_up_per_hb=a.churn / 2 if a.churn else 0.0,
-            uses_mix=a.use_mix,
-            num_mix=a.num_mix,
-            mix_d=a.mix_d,
-            msgid_mode=a.msgid_mode,
-            loss_mode=a.loss_mode,
-            serialize_answers=(a.delivery_mode == "exact"),
-        )
-        t0 = time.time()
-        if a.resume:
-            from .runtime.checkpoint import load_checkpoint
+        with turn(seed=a.seed + i - 1, turn=i) as spans:
+            if i == 1 and a.gml:
+                # run an existing experiment dir: link properties come from
+                # the GML (stage latencies/bandwidths), peers/messages from
+                # the positionals
+                with span("run/topology"):
+                    t = Topology.from_gml(
+                        a.gml, network_size=topo.network_size, params=topo)
+                topo = t.params
+            elif i == 1 and not a.resume:
+                # (a resumed run: the checkpoint embeds its topology; do NOT
+                # overwrite the experiment dir's artifacts before, or after,
+                # validating it)
+                with span("run/topology"):
+                    t = Topology.build(topo)
+                with span("run/write_gml"):
+                    t.write_gml(a.out_prefix + "network_topology.gml")
+                with span("run/write_yaml"):
+                    t.write_shadow_yaml(a.out_prefix + "shadow.yaml")
+            large = topo.msg_size_bytes >= 1000
+            print(f"Running for turn {i}")
+            cfg = ExperimentConfig(
+                topo=topo,
+                connect_to=a.connect_to,
+                # the reference nodes read GOSSIPSUB_* inside the simulation,
+                # so the driver honors the same env surface
+                # (main.nim:252-306)
+                gossipsub=gossipsub_params_from_env(),
+                publisher_id=int(a.publisher_id),
+                publisher_rotation=bool(int(a.publisher_rotation)),
+                warmup_s=a.warmup_s,
+                seed=a.seed + i - 1,
+                with_gossip=not a.no_gossip,
+                churn_down_per_hb=a.churn,
+                churn_up_per_hb=a.churn / 2 if a.churn else 0.0,
+                uses_mix=a.use_mix,
+                num_mix=a.num_mix,
+                mix_d=a.mix_d,
+                msgid_mode=a.msgid_mode,
+                loss_mode=a.loss_mode,
+                serialize_answers=(a.delivery_mode == "exact"),
+            )
+            with span("run/simulator_init"):
+                if a.resume:
+                    from .runtime.checkpoint import load_checkpoint
 
-            sim = load_checkpoint(a.resume)
-            if sim.cfg != cfg:
-                p.error(
-                    "--resume checkpoint was created with a different "
-                    "configuration than these arguments; re-run with the "
-                    "original parameters"
+                    sim = load_checkpoint(a.resume)
+                    if sim.cfg != cfg:
+                        p.error(
+                            "--resume checkpoint was created with a "
+                            "different configuration than these arguments; "
+                            "re-run with the original parameters"
+                        )
+                else:
+                    sim = Simulator(cfg, topology=t)
+            with span("run/simulate"):
+                sim.run(checkpoint_path=a.checkpoint,
+                        checkpoint_every=a.checkpoint_every)
+            # the program's one clock: build + run, from the spans
+            wall = (spans.seconds("run/simulator_init")
+                    + spans.seconds("run/simulate"))
+            with span("run/write_latencies"):
+                n_lines = sim.write_latencies(f"{a.out_prefix}latencies{i}")
+            with span("run/write_shadowlog"):
+                # run.sh:60 artifact
+                sim.write_shadowlog(f"{a.out_prefix}shadowlog{i}")
+            with span("run/summary"):
+                s = sim.summary(large)
+            with span("run/report"):
+                print(f"Summary for turn {i}")
+                print(report(s, large=large), end="")
+                # summary_shadowlog.awk (run.sh:70-74)
+                print(sim.bandwidth_report(), end="")
+                print(
+                    f"[tpu backend] wall={wall:.2f}s "
+                    f"peers*rounds/s={sim.peer_rounds_per_sec(wall):.0f} "
+                    f"lines={n_lines}"
                 )
-        else:
-            sim = Simulator(cfg, topology=t)
-        sim.run(checkpoint_path=a.checkpoint,
-                checkpoint_every=a.checkpoint_every)
-        wall = time.time() - t0
-        n_lines = sim.write_latencies(f"{a.out_prefix}latencies{i}")
-        sim.write_shadowlog(f"{a.out_prefix}shadowlog{i}")  # run.sh:60 artifact
-        s = sim.summary(large)
-        print(f"Summary for turn {i}")
-        print(report(s, large=large), end="")
-        print(sim.bandwidth_report(), end="")  # summary_shadowlog.awk (run.sh:70-74)
-        print(
-            f"[tpu backend] wall={wall:.2f}s "
-            f"peers*rounds/s={sim.peer_rounds_per_sec(wall):.0f} "
-            f"lines={n_lines}"
-        )
-        if a.stats_json:
-            from .runtime.summarize import sanitize_nonfinite
+            if a.stats_json:
+                from .runtime.summarize import sanitize_nonfinite
 
-            with open(f"{a.out_prefix}stats{i}.json", "w") as f:
-                json.dump(
-                    sanitize_nonfinite({
-                        "network_size": s.network_size,
-                        "coverage": s.coverage(),
-                        "max_latency_ms": s.max_latency_ms,
-                        "avg_latency_ms": s.avg_latency_ms,
-                        "avg_max_latency_ms": s.avg_max_latency_ms,
-                        "wall_s": wall,
-                        "peer_rounds_per_sec": sim.peer_rounds_per_sec(wall),
-                    }),
-                    f,
-                    indent=2,
-                    allow_nan=False,
-                )
+                with span("run/stats_json"), \
+                        open(f"{a.out_prefix}stats{i}.json", "w") as f:
+                    json.dump(
+                        sanitize_nonfinite({
+                            "network_size": s.network_size,
+                            "coverage": s.coverage(),
+                            "max_latency_ms": s.max_latency_ms,
+                            "avg_latency_ms": s.avg_latency_ms,
+                            "avg_max_latency_ms": s.avg_max_latency_ms,
+                            "wall_s": wall,
+                            "peer_rounds_per_sec":
+                                sim.peer_rounds_per_sec(wall),
+                            # per span name: count and total seconds of this
+                            # turn (`run` and `run/stats_json` are still
+                            # open: up to here)
+                            "spans": spans.totals(),
+                            "publishes": [
+                                {"fast_iters": r.fast_iters,
+                                 "refine_passes": r.refine_passes,
+                                 "refined": r.refined,
+                                 "fell_back": r.fell_back,
+                                 "converged": r.converged}
+                                for r in sim.records],
+                        }),
+                        f,
+                        indent=2,
+                        allow_nan=False,
+                    )
     return 0
 
 

@@ -231,6 +231,35 @@ class DisseminationResult:
     #                            pass-count budget of the exactness
     #                            certificate pins this on canonical
     #                            topologies (tests/test_exact_prefix.py).
+    counters: jnp.ndarray      # (5,) int32 — [fast_iters, refine_passes,
+    #                            refined, fell_back, converged]: how much
+    #                            work the publish's fixpoints did and which
+    #                            branches ran, packed so that the host takes
+    #                            them in ONE device->host read
+    #                            (runtime/simulator.record_from_result) and
+    #                            the jit returns one leaf more, not four.
+    #                            The three that are no field of their own
+    #                            are the properties below.
+
+    @property
+    def fast_iters(self):
+        """() int32 — loop iterations of the fast (unserialized) pipeline's
+        fixpoints, summed over its phases, max over fragment lanes; the same
+        count from all three engines (row_pull, converge_recv,
+        converge_sharded)."""
+        return self.counters[..., 0]
+
+    @property
+    def refined(self):
+        """() bool — the serialized-answer repair branch ran (a queued
+        answer could have been a first delivery, or rounds interleaved)."""
+        return self.counters[..., 2] != 0
+
+    @property
+    def fell_back(self):
+        """() bool — inside the repair, the prefix engine's certificate
+        failed and the global-sort pipeline reran all fragments."""
+        return self.counters[..., 3] != 0
 
 
 def _stage_select(stage: jnp.ndarray, n_stages: int, conns: jnp.ndarray,
@@ -428,239 +457,242 @@ def disseminate(
     publisher's subscription (host-side; subscription is publish-path
     static), keeping the subscribed-publisher compile unchanged.
     """
-    n, c = conns.shape
-    extra = (1 if loss_stage is not None else 0) + (1 if with_fanout else 0)
-    keys = jax.random.split(state.key, 3 + extra)
-    key, k_rank, k_gossip = keys[0], keys[1], keys[2]
-    nxt = 3
-    if loss_stage is not None:
-        k_loss = keys[nxt]
-        nxt += 1
-    if with_fanout:
-        k_fan = keys[nxt]
+    # device scopes (jax.named_scope: metadata only, no operation is added):
+    # `sample` is everything drawn or hoisted before the fixpoints run
+    with jax.named_scope("sample"):
+        n, c = conns.shape
+        extra = (1 if loss_stage is not None else 0) + (1 if with_fanout else 0)
+        keys = jax.random.split(state.key, 3 + extra)
+        key, k_rank, k_gossip = keys[0], keys[1], keys[2]
+        nxt = 3
+        if loss_stage is not None:
+            k_loss = keys[nxt]
+            nxt += 1
+        if with_fanout:
+            k_fan = keys[nxt]
 
-    frag_bytes = max(payload_bytes // fragments, 16)
-    tx_ms = (frag_bytes * 8.0) / (bw_up_mbit_per_stage[stage] * 1e6) * 1e3  # (N,)
-    # receiver-side drain time of one copy on each peer's downlink. The
-    # reference topology sets host_bandwidth_down == host_bandwidth_up per
-    # stage (shadow/topogen.py:50-51); pass bw_down_mbit_per_stage to model
-    # asymmetric links.
-    bw_down = (bw_up_mbit_per_stage if bw_down_mbit_per_stage is None
-               else bw_down_mbit_per_stage)
-    rx_ms = (frag_bytes * 8.0) / (bw_down[stage] * 1e6) * 1e3          # (N,)
-    # downlink clamp for THIS message's first delivery: nothing completes at
-    # q before q's downlink drains earlier messages plus this copy
-    rx_const = state.rx_free_ms + rx_ms                                # (N,)
+        frag_bytes = max(payload_bytes // fragments, 16)
+        tx_ms = (frag_bytes * 8.0) / (bw_up_mbit_per_stage[stage] * 1e6) * 1e3  # (N,)
+        # receiver-side drain time of one copy on each peer's downlink. The
+        # reference topology sets host_bandwidth_down == host_bandwidth_up per
+        # stage (shadow/topogen.py:50-51); pass bw_down_mbit_per_stage to model
+        # asymmetric links.
+        bw_down = (bw_up_mbit_per_stage if bw_down_mbit_per_stage is None
+                   else bw_down_mbit_per_stage)
+        rx_ms = (frag_bytes * 8.0) / (bw_down[stage] * 1e6) * 1e3          # (N,)
+        # downlink clamp for THIS message's first delivery: nothing completes at
+        # q before q's downlink drains earlier messages plus this copy
+        rx_const = state.rx_free_ms + rx_ms                                # (N,)
 
-    # per-slot link latency lat[stage[p], stage[conns[p,i]]] (and the loss
-    # contraction when needed): experiment constants — callers that loop
-    # over publishes precompute them via edge_tables(); the fallback here
-    # keeps one-shot calls self-contained. NOTE: the stage pull runs once
-    # at top level, OUTSIDE the fragment vmap — batch_factor stays 1 (the
-    # vmapped pulls below pass fragments).
-    if lat_edge is None or (loss_stage is not None and loss_edge is None):
-        lat_edge_c, loss_edge_c = edge_tables(
-            stage, lat_ms, conns, rev, loss_stage)
-        if lat_edge is None:
-            lat_edge = lat_edge_c                         # (N, C); 0 on pads
-        if loss_edge is None:
-            loss_edge = loss_edge_c
+        # per-slot link latency lat[stage[p], stage[conns[p,i]]] (and the loss
+        # contraction when needed): experiment constants — callers that loop
+        # over publishes precompute them via edge_tables(); the fallback here
+        # keeps one-shot calls self-contained. NOTE: the stage pull runs once
+        # at top level, OUTSIDE the fragment vmap — batch_factor stays 1 (the
+        # vmapped pulls below pass fragments).
+        if lat_edge is None or (loss_stage is not None and loss_edge is None):
+            lat_edge_c, loss_edge_c = edge_tables(
+                stage, lat_ms, conns, rev, loss_stage)
+            if lat_edge is None:
+                lat_edge = lat_edge_c                         # (N, C); 0 on pads
+            if loss_edge is None:
+                loss_edge = loss_edge_c
 
-    # forwarding targets: mesh members; the publisher flood-publishes to every
-    # connected topic peer (main.nim:279). The neighbor alive&subscribed
-    # pull is publish-invariant between membership changes — callers that
-    # loop over publishes precompute it (Simulator/bench maintain it and
-    # invalidate on churn or subscription flips), saving one full
-    # row-gather pass per publish. DYNAMIC-GRAPH CONTRACT: a hoisted
-    # valid_edge (and lat_edge/loss_edge/ans_tables) is a pure function of
-    # conns/rev — if the repair controller's dial path extended the graph
-    # (ops/repair.py), the caller must re-derive all of them against the
-    # mutated arrays (Simulator.rebind_graph) and the warm-start carry in
-    # state.warm_offset_ms must already be INF (repair_round writes it on
-    # any committed dial); passing stale tables here silently publishes
-    # over the pre-repair edge set.
-    has = conns >= 0
-    if valid_edge is not None:
-        valid = valid_edge
-    else:
-        valid = has & neighbor_pull_bool(
-            state.alive & state.subscribed, conns, rev)
-    # v1.1 score thresholds (nim-libp2p defaults; the reference comments the
-    # overrides out, main.nim:276-278). With the default non-negative score
-    # weights no peer can score below any threshold, so the whole block is
-    # statically absent from the compiled step.
-    thresholds_can_bind = params.slow_weight < 0.0 or params.fmd_weight < 0.0
-    if thresholds_can_bind:
-        sc = state.score(params)                       # my score of each nbr
-        pub_ok = sc >= params.publish_threshold        # flood/fanout gate
-        # graylist: the RECEIVER ignores traffic from peers it scores below
-        # the threshold — pulled to the sender side it gates DELIVERY only
-        # (the send still happens and is accounted), which is exactly the
-        # `survive` semantics shared with packet loss below
-        gray_ok = reciprocal_pull_bool(
-            sc >= params.graylist_threshold, conns, rev)
-    if loss_mode not in ("message", "tcp"):
-        raise ValueError(f"unknown loss_mode {loss_mode!r}")
-    retx_ms = None
-    if loss_stage is not None:
-        # one independent draw per (FRAGMENT, directed edge): each fragment
-        # is a distinct GossipSub message upstream (main.nim:177-179 flips
-        # the fragment byte precisely so the msgId hash differs), so its
-        # packets face the lossy link independently — correlated
-        # per-message draws would black out every fragment of a message on
-        # an unlucky edge at once, which no packet-loss process does.
-        # Memory note: the draws (and the derived retx/lat_deliver) are
-        # (F, N, C) and live through the whole fragment vmap — generating
-        # them inside the per-fragment body would not lower the peak,
-        # since vmap batches all lanes anyway. At 1M peers this is
-        # ~0.4 GB per f32 array per fragment; lossy runs at extreme N
-        # should keep FRAGMENTS modest (the five BASELINE configs that
-        # reach 1M are lossless and never allocate any of this).
-        if loss_mode == "tcp":
-            # geometric retransmission count per edge (see the model
-            # constants above): P(j >= k) = p^k via the inverse-CDF
-            # j = floor(log u / log p); j > MAX_RETRIES abandons the copy
-            u = jnp.clip(jax.random.uniform(k_loss, (fragments, n, c)),
-                         1e-12)
-            safe_p = jnp.clip(loss_edge, 1e-9, 1.0 - 1e-9)
-            j = jnp.where(
-                loss_edge > 0.0,
-                jnp.floor(jnp.log(u) / jnp.log(safe_p)),
-                0.0,
-            )
-            j = jnp.minimum(j, float(MAX_RETRIES + 1))
-            survive = j <= float(MAX_RETRIES)
-            rto = jnp.maximum(RTO_MIN_MS, 1.5 * 2.0 * lat_edge)
-            retx_ms = jnp.where(
-                survive & (j > 0.0), rto * (jnp.exp2(j) - 1.0), 0.0)
+        # forwarding targets: mesh members; the publisher flood-publishes to every
+        # connected topic peer (main.nim:279). The neighbor alive&subscribed
+        # pull is publish-invariant between membership changes — callers that
+        # loop over publishes precompute it (Simulator/bench maintain it and
+        # invalidate on churn or subscription flips), saving one full
+        # row-gather pass per publish. DYNAMIC-GRAPH CONTRACT: a hoisted
+        # valid_edge (and lat_edge/loss_edge/ans_tables) is a pure function of
+        # conns/rev — if the repair controller's dial path extended the graph
+        # (ops/repair.py), the caller must re-derive all of them against the
+        # mutated arrays (Simulator.rebind_graph) and the warm-start carry in
+        # state.warm_offset_ms must already be INF (repair_round writes it on
+        # any committed dial); passing stale tables here silently publishes
+        # over the pre-repair edge set.
+        has = conns >= 0
+        if valid_edge is not None:
+            valid = valid_edge
         else:
-            # whole-copy loss (see docstring): `survive` gates DELIVERY
-            # only — a lost copy was still transmitted, so it keeps its
-            # uplink queue slot and its tx-byte accounting; it just never
-            # arrives
-            survive = (jax.random.uniform(k_loss, (fragments, n, c))
-                       >= loss_edge)
-    else:
-        survive = None
-    # keep the loss-only draw separate from the graylist gate: lost_tx
-    # counts copies the NETWORK dropped, and a receiver-side graylist
-    # ignore is not a network loss (the bytes arrived and were discarded
-    # above the transport) — folding gray_ok into the counter inflated
-    # "network-lost" copies whenever the graylist was active
-    survive_loss = survive
-    if thresholds_can_bind:
-        survive = gray_ok if survive is None else survive & gray_ok
-    if censor_edge is not None:
-        # adversarial per-edge DROP mask (ops/adversary.py): an in-mesh
-        # censor silently withholds the copy. Same delivery-only semantics
-        # as the graylist gate — and same exclusion from survive_loss, so
-        # lost_tx keeps counting copies the NETWORK dropped. None (the
-        # default pytree structure) keeps benign traces bit-identical.
-        survive = (~censor_edge if survive is None
-                   else survive & ~censor_edge)
-    is_pub = jnp.arange(n) == publisher
-    if with_fanout:
-        # fanout set: still-valid unexpired members, topped back up to D
-        # with fresh draws from the remaining connected topic peers. Computed
-        # for every row (shape-static) but only the publisher's row is used
-        # or written back.
-        fan_active = (state.fanout_mask & valid
-                      & (state.fanout_expire[:, None] > t0_ms))
+            valid = has & neighbor_pull_bool(
+                state.alive & state.subscribed, conns, rev)
+        # v1.1 score thresholds (nim-libp2p defaults; the reference comments the
+        # overrides out, main.nim:276-278). With the default non-negative score
+        # weights no peer can score below any threshold, so the whole block is
+        # statically absent from the compiled step.
+        thresholds_can_bind = params.slow_weight < 0.0 or params.fmd_weight < 0.0
         if thresholds_can_bind:
-            # the v1.1 heartbeat drops fanout members scoring below
-            # publishThreshold; checking at publish time is equivalent at
-            # the moment it matters (same treatment as replenishment)
-            fan_active = fan_active & pub_ok
-        need_fan = jnp.maximum(
-            float(params.d) - fan_active.sum(axis=-1).astype(jnp.float32), 0.0)
-        fan_cand = valid & ~fan_active
+            sc = state.score(params)                       # my score of each nbr
+            pub_ok = sc >= params.publish_threshold        # flood/fanout gate
+            # graylist: the RECEIVER ignores traffic from peers it scores below
+            # the threshold — pulled to the sender side it gates DELIVERY only
+            # (the send still happens and is accounted), which is exactly the
+            # `survive` semantics shared with packet loss below
+            gray_ok = reciprocal_pull_bool(
+                sc >= params.graylist_threshold, conns, rev)
+        if loss_mode not in ("message", "tcp"):
+            raise ValueError(f"unknown loss_mode {loss_mode!r}")
+        retx_ms = None
+        if loss_stage is not None:
+            # one independent draw per (FRAGMENT, directed edge): each fragment
+            # is a distinct GossipSub message upstream (main.nim:177-179 flips
+            # the fragment byte precisely so the msgId hash differs), so its
+            # packets face the lossy link independently — correlated
+            # per-message draws would black out every fragment of a message on
+            # an unlucky edge at once, which no packet-loss process does.
+            # Memory note: the draws (and the derived retx/lat_deliver) are
+            # (F, N, C) and live through the whole fragment vmap — generating
+            # them inside the per-fragment body would not lower the peak,
+            # since vmap batches all lanes anyway. At 1M peers this is
+            # ~0.4 GB per f32 array per fragment; lossy runs at extreme N
+            # should keep FRAGMENTS modest (the five BASELINE configs that
+            # reach 1M are lossless and never allocate any of this).
+            if loss_mode == "tcp":
+                # geometric retransmission count per edge (see the model
+                # constants above): P(j >= k) = p^k via the inverse-CDF
+                # j = floor(log u / log p); j > MAX_RETRIES abandons the copy
+                u = jnp.clip(jax.random.uniform(k_loss, (fragments, n, c)),
+                             1e-12)
+                safe_p = jnp.clip(loss_edge, 1e-9, 1.0 - 1e-9)
+                j = jnp.where(
+                    loss_edge > 0.0,
+                    jnp.floor(jnp.log(u) / jnp.log(safe_p)),
+                    0.0,
+                )
+                j = jnp.minimum(j, float(MAX_RETRIES + 1))
+                survive = j <= float(MAX_RETRIES)
+                rto = jnp.maximum(RTO_MIN_MS, 1.5 * 2.0 * lat_edge)
+                retx_ms = jnp.where(
+                    survive & (j > 0.0), rto * (jnp.exp2(j) - 1.0), 0.0)
+            else:
+                # whole-copy loss (see docstring): `survive` gates DELIVERY
+                # only — a lost copy was still transmitted, so it keeps its
+                # uplink queue slot and its tx-byte accounting; it just never
+                # arrives
+                survive = (jax.random.uniform(k_loss, (fragments, n, c))
+                           >= loss_edge)
+        else:
+            survive = None
+        # keep the loss-only draw separate from the graylist gate: lost_tx
+        # counts copies the NETWORK dropped, and a receiver-side graylist
+        # ignore is not a network loss (the bytes arrived and were discarded
+        # above the transport) — folding gray_ok into the counter inflated
+        # "network-lost" copies whenever the graylist was active
+        survive_loss = survive
         if thresholds_can_bind:
-            fan_cand = fan_cand & pub_ok  # fanout selection skips low scorers
-        fprio = jnp.where(fan_cand, jax.random.uniform(k_fan, (n, c)), INF)
-        fan_row = fan_active | (
-            fan_cand & (_ranks_f32(fprio) < need_fan[:, None]))
+            survive = gray_ok if survive is None else survive & gray_ok
+        if censor_edge is not None:
+            # adversarial per-edge DROP mask (ops/adversary.py): an in-mesh
+            # censor silently withholds the copy. Same delivery-only semantics
+            # as the graylist gate — and same exclusion from survive_loss, so
+            # lost_tx keeps counting copies the NETWORK dropped. None (the
+            # default pytree structure) keeps benign traces bit-identical.
+            survive = (~censor_edge if survive is None
+                       else survive & ~censor_edge)
+        is_pub = jnp.arange(n) == publisher
+        if with_fanout:
+            # fanout set: still-valid unexpired members, topped back up to D
+            # with fresh draws from the remaining connected topic peers. Computed
+            # for every row (shape-static) but only the publisher's row is used
+            # or written back.
+            fan_active = (state.fanout_mask & valid
+                          & (state.fanout_expire[:, None] > t0_ms))
+            if thresholds_can_bind:
+                # the v1.1 heartbeat drops fanout members scoring below
+                # publishThreshold; checking at publish time is equivalent at
+                # the moment it matters (same treatment as replenishment)
+                fan_active = fan_active & pub_ok
+            need_fan = jnp.maximum(
+                float(params.d) - fan_active.sum(axis=-1).astype(jnp.float32), 0.0)
+            fan_cand = valid & ~fan_active
+            if thresholds_can_bind:
+                fan_cand = fan_cand & pub_ok  # fanout selection skips low scorers
+            fprio = jnp.where(fan_cand, jax.random.uniform(k_fan, (n, c)), INF)
+            fan_row = fan_active | (
+                fan_cand & (_ranks_f32(fprio) < need_fan[:, None]))
 
-    tgt = state.mesh_mask & valid
-    flood_set = valid
-    if thresholds_can_bind:
-        # publish (flood and fanout selection) skips peers the publisher
-        # scores below publishThreshold
-        flood_set = valid & pub_ok
-    if with_fanout:
-        pub_tgt = flood_set if params.flood_publish else fan_row
-        tgt = jnp.where(is_pub[:, None], pub_tgt, tgt)
-    elif params.flood_publish:
-        tgt = jnp.where(is_pub[:, None], flood_set, tgt)
+        tgt = state.mesh_mask & valid
+        flood_set = valid
+        if thresholds_can_bind:
+            # publish (flood and fanout selection) skips peers the publisher
+            # scores below publishThreshold
+            flood_set = valid & pub_ok
+        if with_fanout:
+            pub_tgt = flood_set if params.flood_publish else fan_row
+            tgt = jnp.where(is_pub[:, None], pub_tgt, tgt)
+        elif params.flood_publish:
+            tgt = jnp.where(is_pub[:, None], flood_set, tgt)
 
-    # randomized send order per peer (one draw per message, standing in for
-    # the reference's per-peer queue service order)
-    rprio = jnp.where(tgt, jax.random.uniform(k_rank, (n, c)), INF)
+        # randomized send order per peer (one draw per message, standing in for
+        # the reference's per-peer queue service order)
+        rprio = jnp.where(tgt, jax.random.uniform(k_rank, (n, c)), INF)
 
-    # gossip edge sampling: non-mesh connected topic peers; count =
-    # max(D_lazy, gossip_factor * |candidates|)  (v1.1 heartbeat gossip).
-    # The reference gossips EVERY heartbeat over the mcache history window
-    # (history_gossip rounds, main.nim:259,283): each tick draws a FRESH
-    # sample, so a peer missed in round h can be reached in round h+1 —
-    # that re-sampling is what drives gossip recovery under loss/churn.
-    g_cand = valid & ~tgt
-    if thresholds_can_bind:
-        # no IHAVE to peers scored below gossipThreshold
-        g_cand = g_cand & (sc >= params.gossip_threshold)
-    n_gc = g_cand.sum(axis=-1).astype(jnp.float32)
-    g_count = jnp.maximum(float(params.d_lazy), params.gossip_factor * n_gc)
-    n_rounds = params.history_gossip if with_gossip else 1
-    gkeys = jax.random.split(k_gossip, n_rounds)
-    g_tgt_w = jnp.stack([
-        g_cand & _mask_count_smallest(
-            jnp.where(g_cand, jax.random.uniform(gkeys[h], (n, c)), INF),
-            g_count)
-        for h in range(n_rounds)
-    ])                                                  # (W, N, C)
-    g_tgt = g_tgt_w.any(axis=0)
-    # round offsets grow by a heartbeat each, so only the FIRST round an edge
-    # is sampled can be its min offer — the multi-round term collapses to a
-    # single (N, C) per-edge heartbeat offset inside the fixpoint (the full
-    # per-round sets are still used for IHAVE/IWANT accounting below)
-    g_off = jnp.min(
-        jnp.where(g_tgt_w,
-                  jnp.arange(n_rounds, dtype=jnp.float32)[:, None, None],
-                  jnp.float32(n_rounds)),
-        axis=0) * params.heartbeat_ms
-    # heartbeat phase is a persistent per-NODE property (drawn once per run in
-    # init_state), so gossip-arrival timing is consistent across messages
-    hb_phase = state.hb_phase
+        # gossip edge sampling: non-mesh connected topic peers; count =
+        # max(D_lazy, gossip_factor * |candidates|)  (v1.1 heartbeat gossip).
+        # The reference gossips EVERY heartbeat over the mcache history window
+        # (history_gossip rounds, main.nim:259,283): each tick draws a FRESH
+        # sample, so a peer missed in round h can be reached in round h+1 —
+        # that re-sampling is what drives gossip recovery under loss/churn.
+        g_cand = valid & ~tgt
+        if thresholds_can_bind:
+            # no IHAVE to peers scored below gossipThreshold
+            g_cand = g_cand & (sc >= params.gossip_threshold)
+        n_gc = g_cand.sum(axis=-1).astype(jnp.float32)
+        g_count = jnp.maximum(float(params.d_lazy), params.gossip_factor * n_gc)
+        n_rounds = params.history_gossip if with_gossip else 1
+        gkeys = jax.random.split(k_gossip, n_rounds)
+        g_tgt_w = jnp.stack([
+            g_cand & _mask_count_smallest(
+                jnp.where(g_cand, jax.random.uniform(gkeys[h], (n, c)), INF),
+                g_count)
+            for h in range(n_rounds)
+        ])                                                  # (W, N, C)
+        g_tgt = g_tgt_w.any(axis=0)
+        # round offsets grow by a heartbeat each, so only the FIRST round an edge
+        # is sampled can be its min offer — the multi-round term collapses to a
+        # single (N, C) per-edge heartbeat offset inside the fixpoint (the full
+        # per-round sets are still used for IHAVE/IWANT accounting below)
+        g_off = jnp.min(
+            jnp.where(g_tgt_w,
+                      jnp.arange(n_rounds, dtype=jnp.float32)[:, None, None],
+                      jnp.float32(n_rounds)),
+            axis=0) * params.heartbeat_ms
+        # heartbeat phase is a persistent per-NODE property (drawn once per run in
+        # init_state), so gossip-arrival timing is consistent across messages
+        hb_phase = state.hb_phase
 
-    can_send = state.alive & state.subscribed
-    if with_fanout:
-        # the unsubscribed publisher originates (and gossips about) the
-        # message even though it is not a topic member
-        can_send = can_send | (is_pub & state.alive)
+        can_send = state.alive & state.subscribed
+        if with_fanout:
+            # the unsubscribed publisher originates (and gossips about) the
+            # message even though it is not a topic member
+            can_send = can_send | (is_pub & state.alive)
 
-    # cross-message bandwidth contention: a sender's queue for THIS message
-    # starts no earlier than the time its uplink drains traffic of earlier
-    # messages (state write-back below; reference per-connection queues
-    # serialize all in-flight traffic, main.nim:264-299)
-    uplink = state.uplink_free_ms
+        # cross-message bandwidth contention: a sender's queue for THIS message
+        # starts no earlier than the time its uplink drains traffic of earlier
+        # messages (state write-back below; reference per-connection queues
+        # serialize all in-flight traffic, main.nim:264-299)
+        uplink = state.uplink_free_ms
 
-    # effective per-edge delivery latency: the wire latency, times the TCP
-    # slow-start flight count of the data transfer (tcp_flights above: a
-    # transfer needing F cold-start flights pays F-1 extra RTTs = 2*lat
-    # each), plus (tcp loss mode) the sampled retransmission stall.
-    # Control messages (IHAVE/IWANT/IDONTWANT timing checks) keep the bare
-    # lat_edge — they are single small packets inside the first window.
-    # Mesh fragment f rides a connection the f earlier fragments of the
-    # same back-to-back stream already warmed: its last byte departs in
-    # flight F((f+1)*frag_bytes) of the cold-started stream. A gossip
-    # answer is a single cold transfer — the non-mesh edge idled since the
-    # previous message, so its window restarted. (Retransmission stalls
-    # and flight counts compose additively; a real RTO inside slow start
-    # would also halve the window — a second-order interaction left out.)
-    ss_mesh = tuple(
-        float(tcp_flights((f + 1) * frag_bytes, params) - 1)
-        for f in range(fragments))
-    ss_ans = float(tcp_flights(frag_bytes, params) - 1)
-    ss_scale = jnp.asarray([1.0 + 2.0 * e for e in ss_mesh], jnp.float32)
-    ans_scale = jnp.float32(1.0 + 2.0 * ss_ans)
+        # effective per-edge delivery latency: the wire latency, times the TCP
+        # slow-start flight count of the data transfer (tcp_flights above: a
+        # transfer needing F cold-start flights pays F-1 extra RTTs = 2*lat
+        # each), plus (tcp loss mode) the sampled retransmission stall.
+        # Control messages (IHAVE/IWANT/IDONTWANT timing checks) keep the bare
+        # lat_edge — they are single small packets inside the first window.
+        # Mesh fragment f rides a connection the f earlier fragments of the
+        # same back-to-back stream already warmed: its last byte departs in
+        # flight F((f+1)*frag_bytes) of the cold-started stream. A gossip
+        # answer is a single cold transfer — the non-mesh edge idled since the
+        # previous message, so its window restarted. (Retransmission stalls
+        # and flight counts compose additively; a real RTO inside slow start
+        # would also halve the window — a second-order interaction left out.)
+        ss_mesh = tuple(
+            float(tcp_flights((f + 1) * frag_bytes, params) - 1)
+            for f in range(fragments))
+        ss_ans = float(tcp_flights(frag_bytes, params) - 1)
+        ss_scale = jnp.asarray([1.0 + 2.0 * e for e in ss_mesh], jnp.float32)
+        ans_scale = jnp.float32(1.0 + 2.0 * ss_ans)
 
     def _frag_slice(x, frag_idx):
         """Per-fragment view of a possibly-(F, N, C) array. Loss/retx draws
@@ -699,16 +731,17 @@ def disseminate(
     # publishes precompute it via answer_tables() — the in-call fallback
     # keeps one-shot calls self-contained (same contract as edge_tables).
     if with_gossip:
-        if ans_tables is None:
-            ans_tables = answer_tables(lat_edge, conns)
-        perm_lat = ans_tables.perm_lat                           # (N, C)
-        inv_lat = ans_tables.inv_lat
-        lat_sorted = ans_tables.lat_sorted
-        conns_sorted = ans_tables.conns_sorted
-        gw_sorted = [
-            jnp.take_along_axis(g_tgt_w[h], perm_lat, axis=-1)
-            for h in range(n_rounds)
-        ]
+        with jax.named_scope("sample"):
+            if ans_tables is None:
+                ans_tables = answer_tables(lat_edge, conns)
+            perm_lat = ans_tables.perm_lat                       # (N, C)
+            inv_lat = ans_tables.inv_lat
+            lat_sorted = ans_tables.lat_sorted
+            conns_sorted = ans_tables.conns_sorted
+            gw_sorted = [
+                jnp.take_along_axis(g_tgt_w[h], perm_lat, axis=-1)
+                for h in range(n_rounds)
+            ]
 
     def _sorted_frag(x, frag_idx):
         """Per-fragment slice of a (F/None, N, C) array, in lat order."""
@@ -934,13 +967,14 @@ def disseminate(
         carry) may undershoot and stick; callers verify the returned
         self-consistency certificate (see phases_fast) and fall back cold.
 
-        Returns (t, inc, ok): the fixpoint, the deliver-only incoming-
-        offer matrix of the loop's LAST pass — the no-change confirmation
-        pass evaluates it at the final times, so the matrix the first-
-        sender attribution and the certificate need rides out of the loop
-        for FREE instead of costing another offers()+pull — and the
+        Returns (t, inc, ok, iters): the fixpoint, the deliver-only
+        incoming-offer matrix of the loop's LAST pass — the no-change
+        confirmation pass evaluates it at the final times, so the matrix
+        the first-sender attribution and the certificate need rides out of
+        the loop for FREE instead of costing another offers()+pull — the
         convergence bit (False = the iteration cap cut the loop and `inc`
-        is one pass stale)."""
+        is one pass stale), and the loop's iteration count (all three
+        engines report it: DisseminationResult.fast_iters)."""
         t0 = (jnp.full((n,), INF) if t_init is None else t_init
               ).at[publisher].set(t_pub)
         # arrival times are about DELIVERY: lost copies never relax an edge
@@ -960,7 +994,8 @@ def disseminate(
                 lat_deliver=ld, ld_gossip=_ld_ans(frag_idx),
                 packed=params.packed_state,
             )
-            return converge_sharded(t0, c, params.max_relax_iters, mesh)
+            with jax.named_scope("fixpoint"):
+                return converge_sharded(t0, c, params.max_relax_iters, mesh)
         if formulation == "recv":
             # large N (1M-peer class): the row-gather pull would blow the
             # memory budget and its 2-index fallback costs ~0.7 s/iteration —
@@ -976,7 +1011,8 @@ def disseminate(
                 lat_deliver=ld, ld_gossip=_ld_ans(frag_idx),
                 packed=params.packed_state,
             )
-            return converge_recv(t0, c, params.max_relax_iters)
+            with jax.named_scope("fixpoint"):
+                return converge_recv(t0, c, params.max_relax_iters)
         # single device below the budget: sender-major offers (loop-invariant
         # parts hoisted here), row-gather pull per iteration — ~2.5x the
         # per-iteration speed of a receiver-side index gather (ops/pull.py)
@@ -1017,10 +1053,12 @@ def disseminate(
         # extra warm-up iterations add whole pulls)
         # iteration counter carries a STRONG int32: a Python-int carry is
         # weak-typed and re-promotes on feed-back (graft-audit GA-J002)
-        t_rx, inc, changed, _ = jax.lax.while_loop(
-            cond, body,
-            (t0, jnp.full(conns.shape, INF), jnp.bool_(True), jnp.int32(0)))
-        return t_rx, inc, ~changed
+        with jax.named_scope("fixpoint"):
+            t_rx, inc, changed, it = jax.lax.while_loop(
+                cond, body,
+                (t0, jnp.full(conns.shape, INF), jnp.bool_(True),
+                 jnp.int32(0)))
+        return t_rx, inc, ~changed, it
 
     def _converge_floor(rank, k_p, frag_idx, t_pub, send_mask, g_floor,
                         t_init):
@@ -1040,10 +1078,10 @@ def disseminate(
                 lat_deliver=ld, packed=params.packed_state,
             )
             if formulation == "recv_sharded":
-                t_rx, _, _ = converge_sharded(
+                t_rx, _, _, _ = converge_sharded(
                     t0, c, params.max_relax_iters, mesh, g_floor=g_floor)
             else:
-                t_rx, _, _ = converge_recv(
+                t_rx, _, _, _ = converge_recv(
                     t0, c, params.max_relax_iters, g_floor=g_floor)
             return t_rx
         queue = (rank + 1.0 + frag_idx * k_p[:, None]) * tx_ms[:, None]
@@ -1186,11 +1224,12 @@ def disseminate(
             return (t_new, g_abs, req, drain, mixed,
                     jnp.any(t_new != t_g), it + 1)
 
-        t, g_abs, req, drain, mixed, changed, it = jax.lax.while_loop(
-            cond, body,
-            (t0, jnp.full((n, c), INF), jnp.zeros((n, c), bool),
-             jnp.zeros((n,), jnp.float32), jnp.bool_(False),
-             jnp.bool_(True), jnp.int32(0)))
+        with jax.named_scope("fixpoint"):
+            t, g_abs, req, drain, mixed, changed, it = jax.lax.while_loop(
+                cond, body,
+                (t0, jnp.full((n, c), INF), jnp.zeros((n, c), bool),
+                 jnp.zeros((n,), jnp.float32), jnp.bool_(False),
+                 jnp.bool_(True), jnp.int32(0)))
         return t, g_abs, req, drain, mixed, ~changed, it
 
     def queue_drop(tgt_mask, frag_idx):
@@ -1310,11 +1349,12 @@ def disseminate(
         publish).
 
         Returns (t, rank, k, send_mask, g_abs, req_any, drain, inc, wait,
-        hint, mixed, ok, bad) — `wait` is the fold's max answer-queue wait
-        at the final times (always FINITE; `mixed` separately flags the
+        hint, mixed, ok, bad, iters) — `wait` is the fold's max answer-queue
+        wait at the final times (always FINITE; `mixed` separately flags the
         interleaved-rounds corner where the fold's per-round exactness
         precondition fails), `ok` the fixpoint-convergence bit, `bad` the
-        warm-seed certificate violation."""
+        warm-seed certificate violation, `iters` the loop iterations of the
+        phases' fixpoints, summed."""
         tgt_f = queue_drop(tgt, frag_idx)
         rank1 = _ranks_f32(jnp.where(tgt_f, rprio, INF))
         k1 = tgt_f.sum(axis=-1).astype(jnp.float32)
@@ -1323,8 +1363,8 @@ def disseminate(
             seed = jnp.where(
                 (w < WARM_VALID) & (w[publisher] < WARM_VALID),
                 t_pub + w + w[publisher] + params.heartbeat_ms, INF)
-            t1, inc1, ok1 = _converge_dyn(rank1, k1, frag_idx, t_pub,
-                                          tgt_f, t_init=seed)
+            t1, inc1, ok1, it1 = _converge_dyn(rank1, k1, frag_idx, t_pub,
+                                               tgt_f, t_init=seed)
             supported = jnp.maximum(inc1.min(axis=-1), rx_const)
             # t1 <= supported holds at any loop exit; strict < means the
             # seed undershot and stuck (or a phantom: a finite seed on a
@@ -1333,26 +1373,29 @@ def disseminate(
             # certify either.
             bad = jnp.any((t1 < supported) & (t1 < INF) & ~is_pub) | ~ok1
         else:
-            t1, inc1, ok1 = _converge_dyn(rank1, k1, frag_idx, t_pub, tgt_f)
+            t1, inc1, ok1, it1 = _converge_dyn(rank1, k1, frag_idx, t_pub,
+                                               tgt_f)
             bad = jnp.bool_(False)
         if with_gossip and params.serialize_answers:
-            g1, req1, drain1, mixed1, wait1 = gossip_fold(t1, frag_idx)
+            with jax.named_scope("fold"):
+                g1, req1, drain1, mixed1, wait1 = gossip_fold(t1, frag_idx)
             ga1 = jnp.where(req1, g1, INF)
             if not params.exclude_first_sender:
                 inc2 = pull(offers(t1, rank1, k1, frag_idx, tgt_f,
                                    deliver_only=True, g_abs=ga1))
                 hint = _diverged(t1, inc2, mixed1)
                 return (t1, rank1, k1, tgt_f, g1, req1, drain1, inc2,
-                        wait1, hint, mixed1, ok1, bad)
+                        wait1, hint, mixed1, ok1, bad, it1)
             inc1p = pull(offers(t1, rank1, k1, frag_idx, tgt_f,
                                 deliver_only=True, g_abs=ga1))
             rank2, k2, send_mask = _phase2_masks_from_inc(
                 inc1p, t1, rank1, k1, tgt_f)
             # phase-2 costs are pointwise <= phase-1 (a send slot was
             # removed from every queue), so t1 is a valid warm start
-            t2, _, ok2 = _converge_dyn(rank2, k2, frag_idx, t_pub,
-                                       send_mask, t_init=t1)
-            g2, req2, drain2, mixed2, wait2 = gossip_fold(t2, frag_idx)
+            t2, _, ok2, it2 = _converge_dyn(rank2, k2, frag_idx, t_pub,
+                                            send_mask, t_init=t1)
+            with jax.named_scope("fold"):
+                g2, req2, drain2, mixed2, wait2 = gossip_fold(t2, frag_idx)
             inc2 = pull(offers(t2, rank2, k2, frag_idx, send_mask,
                                deliver_only=True,
                                g_abs=jnp.where(req2, g2, INF)))
@@ -1362,30 +1405,31 @@ def disseminate(
             # t1 fold fed the first-sender attribution)
             return (t2, rank2, k2, send_mask, g2, req2, drain2, inc2,
                     jnp.maximum(wait1, wait2), hint, mixed1 | mixed2,
-                    ok1 & ok2, bad)
+                    ok1 & ok2, bad, it1 + it2)
         # bounded / no-gossip: attribution from the loop's own matrix
         if not params.exclude_first_sender:
-            t_fin, inc_fin, ok = t1, inc1, ok1
+            t_fin, inc_fin, ok, iters = t1, inc1, ok1, it1
             rank_o, k_o, mask_o = rank1, k1, tgt_f
         else:
             rank2, k2, send_mask = _phase2_masks_from_inc(
                 inc1, t1, rank1, k1, tgt_f)
             # t1 is a valid (guaranteed) upper bound for phase 2 — no
             # certificate needed
-            t2, inc2, ok2 = _converge_dyn(rank2, k2, frag_idx, t_pub,
-                                          send_mask, t_init=t1)
-            t_fin, inc_fin, ok = t2, inc2, ok1 & ok2
+            t2, inc2, ok2, it2 = _converge_dyn(rank2, k2, frag_idx, t_pub,
+                                               send_mask, t_init=t1)
+            t_fin, inc_fin, ok, iters = t2, inc2, ok1 & ok2, it1 + it2
             rank_o, k_o, mask_o = rank2, k2, send_mask
         if with_gossip:
-            g_f, req_f, drain_f, mixed_o, wait_o = gossip_fold(
-                t_fin, frag_idx)
+            with jax.named_scope("fold"):
+                g_f, req_f, drain_f, mixed_o, wait_o = gossip_fold(
+                    t_fin, frag_idx)
         else:
             g_f = jnp.zeros((n, c), jnp.float32)
             req_f = jnp.zeros((n, c), bool)
             drain_f = jnp.zeros((n,), jnp.float32)
             mixed_o, wait_o = jnp.bool_(False), jnp.float32(0.0)
         return (t_fin, rank_o, k_o, mask_o, g_f, req_f, drain_f, inc_fin,
-                wait_o, jnp.bool_(False), mixed_o, ok, bad)
+                wait_o, jnp.bool_(False), mixed_o, ok, bad, iters)
 
     def phases_serial(frag_idx, t_pub, t_seed):
         """SERIALIZED pipeline: exact answer queues inside the delivery
@@ -1466,8 +1510,9 @@ def disseminate(
                 conv1 & conv2 & ~mixed1 & ~mixed2, it1 + it2)
 
     # publisher emits fragments back-to-back (main.nim:177-179)
-    frag_ids = jnp.arange(fragments, dtype=jnp.float32)
-    t_pubs = t0_ms + frag_ids * tx_ms[publisher]
+    with jax.named_scope("sample"):
+        frag_ids = jnp.arange(fragments, dtype=jnp.float32)
+        t_pubs = t0_ms + frag_ids * tx_ms[publisher]
 
     def _run_fast(warm):
         if mesh is None:
@@ -1479,18 +1524,24 @@ def disseminate(
                 for i in range(fragments)]
         return tuple(jnp.stack(x) for x in zip(*outs))
 
-    fast = _run_fast(params.warm_start)
-    if params.warm_start:
-        # the warm seed is heuristic: if ANY fragment's certificate flags
-        # an undershoot (or a capped loop), restart the whole fast
-        # pipeline cold. Scalar-predicate cond = a real XLA branch; never
-        # taken when the seed margin holds, so the cold trace costs
-        # compile time only.
-        fast = jax.lax.cond(
-            jnp.any(fast[12]), lambda _: _run_fast(False),
-            lambda f: f, fast)
+    # scope `fast`: the unserialized two-phase pipeline (its loops under
+    # fast/fixpoint, the answer-queue folds under fast/fold)
+    with jax.named_scope("fast"):
+        fast = _run_fast(params.warm_start)
+        if params.warm_start:
+            # the warm seed is heuristic: if ANY fragment's certificate
+            # flags an undershoot (or a capped loop), restart the whole fast
+            # pipeline cold. Scalar-predicate cond = a real XLA branch; never
+            # taken when the seed margin holds, so the cold trace costs
+            # compile time only.
+            fast = jax.lax.cond(
+                jnp.any(fast[12]), lambda _: _run_fast(False),
+                lambda f: f, fast)
     (fast_results, wait_f, hint_f, mixed_f, ok_f) = (
         fast[:8], fast[8], fast[9], fast[10], fast[11])
+    # loop iterations of the kept fast pipeline: summed over its phases
+    # (phases_fast), max over fragment lanes
+    fast_iters = jnp.max(fast[13])
     # bounded-mode error bar: the max time any requested answer waited
     # queued at the final estimates — in exact mode the repair (below)
     # drives the actual delivery error to zero and this reports 0.
@@ -1502,6 +1553,7 @@ def disseminate(
     answer_interleaved = jnp.sum(mixed_f.astype(jnp.int32))
     converged = jnp.all(ok_f)
     refine_passes = jnp.int32(0)
+    refined = fell_back = jnp.bool_(False)
     if with_gossip and params.serialize_answers:
         # serialized-answer repair, decided ONCE per message on a SCALAR
         # predicate (_diverged): the fast pipeline is kept whenever no
@@ -1530,9 +1582,11 @@ def disseminate(
             return tuple(jnp.stack(x) for x in zip(*outs))
 
         def _slow(fr):
+            """The taken branch: fr[:10] refined, then the fell-back bit."""
             t_fast = fr[0]
             if not use_prefix:
-                return _serial_all(t_fast)
+                # the global-sort engine is the one chosen: no fallback
+                return _serial_all(t_fast) + (jnp.bool_(False),)
             outs = [phases_prefix(frag_ids[i], t_pubs[i], t_fast[i])
                     for i in range(fragments)]
             pref = tuple(jnp.stack(x) for x in zip(*outs))
@@ -1545,19 +1599,26 @@ def disseminate(
             # time only (the repo's warm-rerun idiom); its pass count adds
             # to the prefix iterations already spent.
             def _legacy(p):
-                leg = _serial_all(p[0])
-                return leg[:9] + (p[9] + leg[9],)
+                with jax.named_scope("legacy"):
+                    leg = _serial_all(p[0])
+                return leg[:9] + (p[9] + leg[9], jnp.bool_(True))
 
             return jax.lax.cond(
-                jnp.all(pref[8]), lambda p: p, _legacy, pref)
+                jnp.all(pref[8]), lambda p: p + (jnp.bool_(False),),
+                _legacy, pref)
 
         # the convergence bit rides the cond operand so the kept branch's
         # verdict (fast ok / serialized refinement certificate) wins; the
         # pass counter rides alongside (0 when the fast pipeline is kept)
-        fast10 = jax.lax.cond(
-            jnp.any(hint_f), _slow, lambda fr: fr,
-            fast_results + (ok_f, jnp.zeros((fragments,), jnp.int32)))
-        fast_results, conv_f, passes_f = fast10[:8], fast10[8], fast10[9]
+        # scope `refine`: the conditional and both of its branches (the
+        # global-sort rerun of an uncertified prefix result: refine/legacy)
+        refined = jnp.any(hint_f)
+        with jax.named_scope("refine"):
+            kept = jax.lax.cond(
+                refined, _slow, lambda fr: fr + (jnp.bool_(False),),
+                fast_results + (ok_f, jnp.zeros((fragments,), jnp.int32)))
+        fast_results, conv_f, passes_f = kept[:8], kept[8], kept[9]
+        fell_back = kept[10]
         converged = jnp.all(conv_f)
         refine_passes = jnp.max(passes_f)
         # exact mode: the repair drives the delivery error to zero
@@ -1566,10 +1627,12 @@ def disseminate(
     (t_rx_f, rank_f, k_f, smask_f, g_abs_acct, req_acct,
      drain_acct, inc_acct) = fast_results
 
-    received = jnp.all(t_rx_f < INF, axis=0)
-    t_rx = jnp.where(received, t_rx_f.max(axis=0), INF)  # last fragment completes
-    delay = jnp.where(received, t_rx - t0_ms, INF)
-
+    # scope `accounting`: everything after the fixpoints, to the return
+    with jax.named_scope("accounting"):
+        received = jnp.all(t_rx_f < INF, axis=0)
+        # last fragment completes
+        t_rx = jnp.where(received, t_rx_f.max(axis=0), INF)
+        delay = jnp.where(received, t_rx - t0_ms, INF)
 
     # ---- post-fixpoint accounting (bytes, duplicates, gossip, score) -------
     def frag_accounting(frag_idx, t_rx_one, rank, k_p, send_mask,
@@ -1697,113 +1760,117 @@ def disseminate(
         return (sends, copies, ihave_pp, iwant_pp, ihave_rx_pp, iwant_rx_pp,
                 first_slot, slow_inc, arr_t, up_end, lost_pp)
 
-    (sends_f, copies_f, ihave_f, iwant_f, ihave_rx_f, iwant_rx_f,
-     first_slot_f, slow_f, arr_f, up_end_f, lost_f) = jax.vmap(
-        frag_accounting
-    )(frag_ids, t_rx_f, rank_f, k_f, smask_f, g_abs_acct, req_acct,
-      drain_acct, inc_acct)
-    sends = sends_f.sum(axis=0).astype(jnp.int32)
-    lost_tx = lost_f.sum(axis=0).astype(jnp.int32)
-    copies = copies_f.sum(axis=0).astype(jnp.int32)
-    ihave_pp = ihave_f.sum(axis=0).astype(jnp.int32)
-    iwant_pp = iwant_f.sum(axis=0).astype(jnp.int32)
-    ihave_rx_pp = ihave_rx_f.sum(axis=0).astype(jnp.int32)
-    iwant_rx_pp = iwant_rx_f.sum(axis=0).astype(jnp.int32)
+    with jax.named_scope("accounting"):
+        (sends_f, copies_f, ihave_f, iwant_f, ihave_rx_f, iwant_rx_f,
+         first_slot_f, slow_f, arr_f, up_end_f, lost_f) = jax.vmap(
+            frag_accounting
+        )(frag_ids, t_rx_f, rank_f, k_f, smask_f, g_abs_acct, req_acct,
+          drain_acct, inc_acct)
+        sends = sends_f.sum(axis=0).astype(jnp.int32)
+        lost_tx = lost_f.sum(axis=0).astype(jnp.int32)
+        copies = copies_f.sum(axis=0).astype(jnp.int32)
+        ihave_pp = ihave_f.sum(axis=0).astype(jnp.int32)
+        iwant_pp = iwant_f.sum(axis=0).astype(jnp.int32)
+        ihave_rx_pp = ihave_rx_f.sum(axis=0).astype(jnp.int32)
+        iwant_rx_pp = iwant_rx_f.sum(axis=0).astype(jnp.int32)
 
-    # firstMessageDeliveries: credit the edge that delivered fragment 0 first
-    fs = first_slot_f[0]
-    got = received & (jnp.arange(n) != publisher)
-    # one credit at each receiver's first-delivery slot: a row-wise one-hot
-    # add (fused elementwise) — scatters serialize on TPU
-    credit = (jnp.arange(c) == fs[:, None]) & got[:, None]
-    fmd = jnp.minimum(state.fmd + credit.astype(jnp.float32), params.fmd_cap)
+        # firstMessageDeliveries: credit the edge that delivered fragment 0 first
+        fs = first_slot_f[0]
+        got = received & (jnp.arange(n) != publisher)
+        # one credit at each receiver's first-delivery slot: a row-wise one-hot
+        # add (fused elementwise) — scatters serialize on TPU
+        credit = (jnp.arange(c) == fs[:, None]) & got[:, None]
+        fmd = jnp.minimum(state.fmd + credit.astype(jnp.float32), params.fmd_cap)
 
-    # IDONTWANT control-message counters (v1.2, go-test-node/main.go:165):
-    # on first RECEIPT of a large message a peer announces IDONTWANT to its
-    # mesh members except the one that delivered it — once per MESSAGE, not
-    # per fragment; the publisher announces nothing (it received nothing).
-    # The suppression effect rides inside frag_accounting; this is the
-    # announce traffic. `credit` is exactly the first-delivery back-edge.
-    if payload_bytes >= params.idontwant_threshold_bytes:
-        idw_edge = (state.mesh_mask & valid & ~credit
-                    & (got & can_send)[:, None])
-        idw_tx_pp = idw_edge.sum(axis=-1).astype(jnp.int32)
-        idw_rx_pp = reciprocal_pull_bool(
-            idw_edge, conns, rev).sum(axis=-1).astype(jnp.int32)
-    else:
-        idw_tx_pp = jnp.zeros((n,), jnp.int32)
-        idw_rx_pp = jnp.zeros((n,), jnp.int32)
+        # IDONTWANT control-message counters (v1.2, go-test-node/main.go:165):
+        # on first RECEIPT of a large message a peer announces IDONTWANT to its
+        # mesh members except the one that delivered it — once per MESSAGE, not
+        # per fragment; the publisher announces nothing (it received nothing).
+        # The suppression effect rides inside frag_accounting; this is the
+        # announce traffic. `credit` is exactly the first-delivery back-edge.
+        if payload_bytes >= params.idontwant_threshold_bytes:
+            idw_edge = (state.mesh_mask & valid & ~credit
+                        & (got & can_send)[:, None])
+            idw_tx_pp = idw_edge.sum(axis=-1).astype(jnp.int32)
+            idw_rx_pp = reciprocal_pull_bool(
+                idw_edge, conns, rev).sum(axis=-1).astype(jnp.int32)
+        else:
+            idw_tx_pp = jnp.zeros((n,), jnp.int32)
+            idw_rx_pp = jnp.zeros((n,), jnp.int32)
 
-    result = DisseminationResult(
-        t_rx_ms=t_rx,
-        delay_ms=delay,
-        received=received,
-        sends=sends,
-        copies_rx=copies,
-        ihave_sent=ihave_pp,
-        iwant_sent=iwant_pp,
-        lost_tx=lost_tx,
-        answer_wait_max_ms=answer_wait,
-        answer_interleaved=answer_interleaved,
-        converged=converged,
-        refine_passes=refine_passes,
-    )
-    dup = jnp.maximum(copies - fragments, 0)
-    # uplink occupancy write-back: per fragment, frag_accounting computed the
-    # effective drain end — the last mesh slot actually transmitted (IDONTWANT
-    # suppression shortens trailing slots) plus answered-IWANT serializations.
-    # Carried in SimState so the NEXT message's sends queue behind this one.
-    uplink_new = jnp.maximum(uplink, up_end_f.max(axis=0))
-    # downlink occupancy write-back: fold ALL delivered copies (mesh
-    # duplicates + gossip answers, post-suppression) through each receiver's
-    # single-server downlink queue in arrival order. For ascending arrivals
-    # o_1..o_m the completion recurrence busy_j = max(o_j, busy_{j-1} + rx)
-    # unrolls to busy_m = max(rx_free + m*rx, max_j o_j + (m-j)*rx); with d_i
-    # the i-th LARGEST arrival that is max(rx_free + m*rx, max_i d_i + i*rx)
-    # — one sort plus elementwise, order-exact (tied arrivals commute).
-    arr_all = jnp.moveaxis(arr_f, 0, 1).reshape(n, fragments * c)
-    d_sorted = -jnp.sort(-arr_all, axis=-1)
-    m_copies = copies.astype(jnp.float32)
-    pos = jnp.arange(fragments * c, dtype=jnp.float32)
-    fold = jnp.where(pos[None, :] < m_copies[:, None],
-                     d_sorted + pos[None, :] * rx_ms[:, None], -INF)
-    rx_free_new = jnp.maximum(state.rx_free_ms + m_copies * rx_ms,
-                              fold.max(axis=-1))
-    # the counter accrues unweighted; score() applies the (negative) weight
-    slow_penalty = state.slow_penalty + slow_f.sum(axis=0)
-    # cross-publish warm-start carry: this message's arrival OFFSETS seed
-    # the next publish's relaxation (phases_fast re-bases them to the new
-    # publish time). INF where the message never fully arrived; churn and
-    # subscription changes invalidate the carry (heartbeat/simulator).
-    warm_new = jnp.where(received, t_rx - t0_ms, INF)
-    new_state = state.replace(
-        key=key,
-        warm_offset_ms=warm_new,
-        uplink_free_ms=uplink_new,
-        rx_free_ms=rx_free_new,
-        fmd=fmd,
-        slow_penalty=slow_penalty,
-        bytes_tx=state.bytes_tx + sends.astype(jnp.float32) * frag_bytes,
-        bytes_rx=state.bytes_rx + copies.astype(jnp.float32) * frag_bytes,
-        dup_rx=state.dup_rx + dup.astype(jnp.int32),
-        ihave_tx=state.ihave_tx + ihave_pp,
-        iwant_tx=state.iwant_tx + iwant_pp,
-        ihave_rx=state.ihave_rx + ihave_rx_pp,
-        iwant_rx=state.iwant_rx + iwant_rx_pp,
-        idontwant_tx=state.idontwant_tx + idw_tx_pp,
-        idontwant_rx=state.idontwant_rx + idw_rx_pp,
-    )
-    if with_fanout:
-        # persist the publisher's (possibly replenished) fanout set and
-        # restart its TTL from this publish
-        new_state = new_state.replace(
-            fanout_mask=jnp.where(is_pub[:, None], fan_row, state.fanout_mask),
-            fanout_expire=jnp.where(
-                is_pub,
-                jnp.asarray(t0_ms + params.fanout_ttl_ms, jnp.float32),
-                state.fanout_expire,
-            ),
+        result = DisseminationResult(
+            t_rx_ms=t_rx,
+            delay_ms=delay,
+            received=received,
+            sends=sends,
+            copies_rx=copies,
+            ihave_sent=ihave_pp,
+            iwant_sent=iwant_pp,
+            lost_tx=lost_tx,
+            answer_wait_max_ms=answer_wait,
+            answer_interleaved=answer_interleaved,
+            converged=converged,
+            refine_passes=refine_passes,
+            counters=jnp.stack([
+                fast_iters, refine_passes, refined.astype(jnp.int32),
+                fell_back.astype(jnp.int32), converged.astype(jnp.int32)]),
         )
+        dup = jnp.maximum(copies - fragments, 0)
+        # uplink occupancy write-back: per fragment, frag_accounting computed the
+        # effective drain end — the last mesh slot actually transmitted (IDONTWANT
+        # suppression shortens trailing slots) plus answered-IWANT serializations.
+        # Carried in SimState so the NEXT message's sends queue behind this one.
+        uplink_new = jnp.maximum(uplink, up_end_f.max(axis=0))
+        # downlink occupancy write-back: fold ALL delivered copies (mesh
+        # duplicates + gossip answers, post-suppression) through each receiver's
+        # single-server downlink queue in arrival order. For ascending arrivals
+        # o_1..o_m the completion recurrence busy_j = max(o_j, busy_{j-1} + rx)
+        # unrolls to busy_m = max(rx_free + m*rx, max_j o_j + (m-j)*rx); with d_i
+        # the i-th LARGEST arrival that is max(rx_free + m*rx, max_i d_i + i*rx)
+        # — one sort plus elementwise, order-exact (tied arrivals commute).
+        arr_all = jnp.moveaxis(arr_f, 0, 1).reshape(n, fragments * c)
+        d_sorted = -jnp.sort(-arr_all, axis=-1)
+        m_copies = copies.astype(jnp.float32)
+        pos = jnp.arange(fragments * c, dtype=jnp.float32)
+        fold = jnp.where(pos[None, :] < m_copies[:, None],
+                         d_sorted + pos[None, :] * rx_ms[:, None], -INF)
+        rx_free_new = jnp.maximum(state.rx_free_ms + m_copies * rx_ms,
+                                  fold.max(axis=-1))
+        # the counter accrues unweighted; score() applies the (negative) weight
+        slow_penalty = state.slow_penalty + slow_f.sum(axis=0)
+        # cross-publish warm-start carry: this message's arrival OFFSETS seed
+        # the next publish's relaxation (phases_fast re-bases them to the new
+        # publish time). INF where the message never fully arrived; churn and
+        # subscription changes invalidate the carry (heartbeat/simulator).
+        warm_new = jnp.where(received, t_rx - t0_ms, INF)
+        new_state = state.replace(
+            key=key,
+            warm_offset_ms=warm_new,
+            uplink_free_ms=uplink_new,
+            rx_free_ms=rx_free_new,
+            fmd=fmd,
+            slow_penalty=slow_penalty,
+            bytes_tx=state.bytes_tx + sends.astype(jnp.float32) * frag_bytes,
+            bytes_rx=state.bytes_rx + copies.astype(jnp.float32) * frag_bytes,
+            dup_rx=state.dup_rx + dup.astype(jnp.int32),
+            ihave_tx=state.ihave_tx + ihave_pp,
+            iwant_tx=state.iwant_tx + iwant_pp,
+            ihave_rx=state.ihave_rx + ihave_rx_pp,
+            iwant_rx=state.iwant_rx + iwant_rx_pp,
+            idontwant_tx=state.idontwant_tx + idw_tx_pp,
+            idontwant_rx=state.idontwant_rx + idw_rx_pp,
+        )
+        if with_fanout:
+            # persist the publisher's (possibly replenished) fanout set and
+            # restart its TTL from this publish
+            new_state = new_state.replace(
+                fanout_mask=jnp.where(is_pub[:, None], fan_row, state.fanout_mask),
+                fanout_expire=jnp.where(
+                    is_pub,
+                    jnp.asarray(t0_ms + params.fanout_ttl_ms, jnp.float32),
+                    state.fanout_expire,
+                ),
+            )
     if return_plan:
         plan = {
             "tgt": tgt,                 # (N, C) data send set (pre queue-drop)

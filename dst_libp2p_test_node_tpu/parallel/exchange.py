@@ -217,13 +217,14 @@ def converge_recv(
     model (ops/disseminate gossip_serial), already row-minimized. Receiver-
     local, so it joins the row min at zero per-iteration cost.
 
-    Returns (t_rx, inc, converged): the fixpoint, the (N, C) incoming-
-    offer matrix of the loop's LAST pass (the no-change confirmation pass
-    evaluates it at the final times, so it rides out for free — callers
-    reuse it for first-sender attribution and for the warm-start
-    undershoot certificate instead of paying another full pull), and the
-    final change bit inverted (False only when the iteration cap cut the
-    loop, in which case `inc` is one pass stale)."""
+    Returns (t_rx, inc, converged, iters): the fixpoint, the (N, C)
+    incoming-offer matrix of the loop's LAST pass (the no-change
+    confirmation pass evaluates it at the final times, so it rides out for
+    free — callers reuse it for first-sender attribution and for the
+    warm-start undershoot certificate instead of paying another full
+    pull), the final change bit inverted (False only when the iteration cap
+    cut the loop, in which case `inc` is one pass stale), and the loop's
+    iteration count (DisseminationResult.fast_iters)."""
 
     def cond(carry):
         _, _, changed, it = carry
@@ -243,9 +244,9 @@ def converge_recv(
 
     inc0 = jnp.full(c.src.shape, INF)
     # strong int32 counter: a Python-int carry is weak-typed (GA-J002)
-    t_rx, inc, changed, _ = jax.lax.while_loop(
+    t_rx, inc, changed, it = jax.lax.while_loop(
         cond, body, (t0, inc0, jnp.bool_(True), jnp.int32(0)))
-    return t_rx, inc, ~changed
+    return t_rx, inc, ~changed, it
 
 
 def converge_sharded(
@@ -256,8 +257,9 @@ def converge_sharded(
     their shard; each iteration all-gathers the (N,) time vector over ICI
     and psums one convergence bit. Identical results to converge_recv
     (including the optional frozen `g_floor`, which shards with the rows,
-    and the carried-out (inc, converged) pair — inc rows shard like the
-    constants; converged is replicated by the psum).
+    and the carried-out (inc, converged, iters) — inc rows shard like the
+    constants; converged is replicated by the psum, and so is the iteration
+    count, which every shard steps on that bit).
 
     `axis_name`: which mesh axis the rows partition over — PEER_AXIS on the
     1-D simulation mesh, or the peer axis of a nested trials x peers grid
@@ -297,15 +299,15 @@ def converge_sharded(
         # has to start that way (shard_map checks varying manual axes)
         inc0 = jax.lax.pcast(
             jnp.full(src.shape, INF), (axis_name,), to="varying")
-        t_l, inc_l, changed, _ = jax.lax.while_loop(
+        t_l, inc_l, changed, it = jax.lax.while_loop(
             cond, body, (t0_l, inc0, jnp.bool_(True), jnp.int32(0)))
-        return t_l, inc_l, ~changed
+        return t_l, inc_l, ~changed, it
 
     fn = _shard_map(
         local_fix,
         mesh=mesh,
         in_specs=(rows,) * 10,
-        out_specs=(rows, rows, P()),
+        out_specs=(rows, rows, P(), P()),
     )
     return fn(t0, c.src, c.a_ms, c.g_ms, c.g_off, c.phase, c.u_ms,
               c.flags, c.rx_c, g_floor)

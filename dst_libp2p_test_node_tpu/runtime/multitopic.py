@@ -303,6 +303,7 @@ class MultiTopicSimulator:
             # record_from_result's tolerant getattr silently zeroed the bar
             # for every multitopic record
             answer_wait_max_ms = res.answer_wait_max_ms
+            counters = res.counters    # whole-publish scalars too
 
         rec = record_from_result(
             _Blk,

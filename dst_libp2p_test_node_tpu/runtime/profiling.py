@@ -30,13 +30,22 @@ analyses:
   profiler_trace    optional jax.profiler capture around a block (the
                     `trace` CLI's --profile-dir and bench's
                     BENCH_PROFILE_DIR use the same mechanism)
+  span / turn       the program's own host spans: `turn(...)` opens the
+                    recorder of one `run` turn, `span(name)` notes a span
+                    in it and opens a "sim:" TraceAnnotation, so a running
+                    profiler session puts the span on the device trace's
+                    clock; `counters` is the zero-length annotation that
+                    carries a publish's device-side counters
 """
 
 from __future__ import annotations
 
+import contextvars
 import logging
 import os
+import time
 from contextlib import contextmanager
+from dataclasses import dataclass, field
 
 import numpy as np
 
@@ -255,6 +264,106 @@ def profiler_trace(log_dir: str | None):
         return
     with ctx:
         yield
+
+
+# ------------------------------------------------------- host spans
+
+# every annotation of the program starts with this, so a reader of the
+# profile tells the program's spans from anything else on the host plane
+SPAN_PREFIX = "sim:"
+
+
+@dataclass
+class Span:
+    name: str
+    start: float              # time.perf_counter()
+    end: float | None         # None while open
+    parent: int | None        # index of the enclosing span in the turn
+    attrs: dict = field(default_factory=dict)
+
+
+class TurnSpans:
+    """The spans of one `run` turn, in order of opening. One turn owns one
+    of these and drops it when the turn ends, so a process that loops over
+    experiments keeps nothing."""
+
+    def __init__(self, **attrs):
+        self.attrs = attrs    # the turn's identifier, shared by its spans
+        self.spans: list[Span] = []
+        self._open: list[int] = []
+
+    def seconds(self, name: str) -> float:
+        """Summed duration of the closed spans of that name."""
+        return sum(s.end - s.start for s in self.spans
+                   if s.name == name and s.end is not None)
+
+    def totals(self) -> dict:
+        """{name: {"count", "total_s"}}, what `stats<i>.json` "spans" holds;
+        a span still open counts up to now."""
+        now = time.perf_counter()
+        out: dict = {}
+        for s in self.spans:
+            entry = out.setdefault(s.name, {"count": 0, "total_s": 0.0})
+            entry["count"] += 1
+            entry["total_s"] += (now if s.end is None else s.end) - s.start
+        return out
+
+
+_TURN: contextvars.ContextVar[TurnSpans | None] = contextvars.ContextVar(
+    "dst_sim_turn_spans", default=None)
+
+
+@contextmanager
+def span(name: str, **attrs):
+    """One host span of the program. Always a `jax.profiler.TraceAnnotation`
+    named "sim:<name>" (a no-op costing well under a microsecond while no
+    profiler session runs; on the device trace's clock while one does: the
+    session is the switch), carrying the turn's identifier and `attrs`.
+    Inside a `turn()` it is also noted in the turn's recorder; outside one
+    nothing is recorded."""
+    from jax.profiler import TraceAnnotation
+
+    turn_spans = _TURN.get()
+    if turn_spans is None:
+        with TraceAnnotation(SPAN_PREFIX + name, **attrs):
+            yield
+        return
+    index = len(turn_spans.spans)
+    opened = turn_spans._open
+    turn_spans.spans.append(
+        Span(name, 0.0, None, opened[-1] if opened else None, attrs))
+    opened.append(index)
+    with TraceAnnotation(SPAN_PREFIX + name, **turn_spans.attrs, **attrs):
+        turn_spans.spans[index].start = time.perf_counter()
+        try:
+            yield
+        finally:
+            turn_spans.spans[index].end = time.perf_counter()
+            opened.pop()
+
+
+@contextmanager
+def turn(**attrs):
+    """One `run` turn: a fresh recorder, current for the block, under one
+    root span "run". Yields the recorder."""
+    turn_spans = TurnSpans(**attrs)
+    token = _TURN.set(turn_spans)
+    try:
+        with span("run"):
+            yield turn_spans
+    finally:
+        _TURN.reset(token)
+
+
+def counters(name: str, **values) -> None:
+    """A zero-length "sim:<name>" annotation whose attributes are counters,
+    for a reader of the profile; nothing is recorded on the host."""
+    from jax.profiler import TraceAnnotation
+
+    turn_spans = _TURN.get()
+    ident = turn_spans.attrs if turn_spans is not None else {}
+    with TraceAnnotation(SPAN_PREFIX + name, **ident, **values):
+        pass
 
 
 # ------------------------------------------------------- trace export

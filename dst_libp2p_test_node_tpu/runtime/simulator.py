@@ -27,11 +27,12 @@ import numpy as np
 
 from ..config.env import GossipSubParams
 from ..config.topology import Topology, TopoParams
-from ..ops.disseminate import disseminate
+from ..ops.disseminate import disseminate, fixpoint_formulation
 from ..ops.graph import build_connection_graph
 from ..ops.heartbeat import run_heartbeats
 from ..ops.state import SimParams, graph_arrays, init_state
 from .logemit import LatenciesWriter
+from .profiling import counters, span
 from .summarize import LatencySummary, report, summarize
 
 # Steady-state per-hop processing cost by muxer, DERIVED from the transport
@@ -162,6 +163,14 @@ def record_from_result(
     if drop_self is not None:
         received[np.asarray(drop_self)] = False
     delays = np.where(received, delays, np.inf)
+    # the result's scalars come off the device in ONE read (`counters`, see
+    # DisseminationResult). Result views that slice a block out of a bigger
+    # run (multitopic's per-topic projection, a publish_batch column) carry
+    # none: their records read converged and no refinement
+    packed = getattr(res, "counters", None)
+    fast_iters, refine_passes, refined, fell_back, converged = (
+        (0, 0, 0, 0, 1) if packed is None
+        else (int(v) for v in np.asarray(packed)))
     return MessageRecord(
         msg_id=msg_id,
         publisher=publisher,
@@ -172,12 +181,15 @@ def record_from_result(
         copies_rx=np.asarray(res.copies_rx),
         ihave=int(np.asarray(res.ihave_sent).sum()),
         iwant=int(np.asarray(res.iwant_sent).sum()),
-        # result views that slice a block out of a bigger run (multitopic's
-        # per-topic projection) may not carry the scalar; exact mode's bar
-        # is 0.0 anyway
+        # the views above may not carry the scalar; exact mode's bar is 0.0
+        # anyway
         answer_wait_max_ms=float(np.asarray(
             getattr(res, "answer_wait_max_ms", 0.0))),
-        converged=bool(np.asarray(getattr(res, "converged", True))),
+        converged=bool(converged),
+        fast_iters=fast_iters,
+        refine_passes=refine_passes,
+        refined=bool(refined),
+        fell_back=bool(fell_back),
     )
 
 
@@ -218,6 +230,13 @@ class MessageRecord:
     # the fixpoints this record rode (not checkpointed; views that carry no
     # bit read True)
     converged: bool = True
+    # DisseminationResult.fast_iters / refine_passes / refined / fell_back:
+    # how much work the publish's fixpoints did and which branches ran
+    # (`stats<i>.json` "publishes"; not checkpointed, views read 0 / False)
+    fast_iters: int = 0
+    refine_passes: int = 0
+    refined: bool = False
+    fell_back: bool = False
 
     @property
     def receivers(self) -> np.ndarray:
@@ -521,125 +540,145 @@ class Simulator:
         mask (ops/adversary.py censor_mask) threaded to disseminate; None
         (the default) keeps the benign publish trace bit-identical — the
         zero-attacker campaign contract (runtime/campaign.py)."""
-        cfg = self.cfg
-        size = msg_size if msg_size is not None else cfg.topo.msg_size_bytes
-        a = self.arrays
-        t0_ms = float(self.state.t_ms) + self._hb_carry_ms
-        origin = publisher
-        mix_delay = 0.0
-        if self.mix_params is not None:
-            # relay through the mix network first; the exit node publishes
-            # on the origin's behalf (ops/mix.py, README.md:42-46)
-            import jax
-            import jax.numpy as jnp
+        with span("publish", message=len(self.records)):
+            # the host's first wait for the device is the read of t_ms here
+            with span("publish/prepare"):
+                cfg = self.cfg
+                size = msg_size if msg_size is not None else cfg.topo.msg_size_bytes
+                a = self.arrays
+                t0_ms = float(self.state.t_ms) + self._hb_carry_ms
+                origin = publisher
+                mix_delay = 0.0
+                if self.mix_params is not None:
+                    # relay through the mix network first; the exit node publishes
+                    # on the origin's behalf (ops/mix.py, README.md:42-46)
+                    import jax
+                    import jax.numpy as jnp
 
-            from ..ops.mix import eligible_mix_count, mix_route, mix_wire_bytes
+                    from ..ops.mix import eligible_mix_count, mix_route, mix_wire_bytes
 
-            eligible = eligible_mix_count(
-                np.asarray(self.state.alive), publisher,
-                self.params.n, self.mix_params.num_mix,
-            )
-            if eligible < self.mix_params.mix_d:
-                raise MixDegradedError(
-                    f"mix network degraded: {eligible} eligible mix nodes "
-                    f"(alive, mounted, != publisher) < MIXD={self.mix_params.mix_d}"
+                    eligible = eligible_mix_count(
+                        np.asarray(self.state.alive), publisher,
+                        self.params.n, self.mix_params.num_mix,
+                    )
+                    if eligible < self.mix_params.mix_d:
+                        raise MixDegradedError(
+                            f"mix network degraded: {eligible} eligible mix nodes "
+                            f"(alive, mounted, != publisher) < MIXD={self.mix_params.mix_d}"
+                        )
+                    key, k_mix = jax.random.split(self.state.key)
+                    # occupancy-coupled: each hop's Sphinx serialization queues
+                    # behind the sender's in-flight mesh/gossip traffic and is
+                    # written back, so a relay's NEXT mesh forwarding queues behind
+                    # the mix transmission it just made (shared real links)
+                    path, exit_node, path_delay, uplink_new, rx_new = mix_route(
+                        k_mix,
+                        publisher,
+                        self.state.alive,
+                        self._stage,
+                        self._lat,
+                        self._bw,
+                        params=self.mix_params,
+                        n=self.params.n,
+                        payload_bytes=size,
+                        uplink_free_ms=self.state.uplink_free_ms,
+                        rx_free_ms=self.state.rx_free_ms,
+                        t0_ms=t0_ms,
+                    )
+                    mix_delay = float(path_delay)
+                    wire = float(mix_wire_bytes(self.mix_params, size))
+                    # per-hop attribution, both directions (Shadow's counters see
+                    # both ends of every packet): senders are origin + first
+                    # mix_d-1 relays, receivers are the mix_d relays
+                    senders = jnp.concatenate(
+                        [jnp.asarray([origin]), path[:-1]]
+                    )
+                    bytes_tx = self.state.bytes_tx.at[senders].add(wire)
+                    bytes_rx = self.state.bytes_rx.at[path].add(wire)
+                    self.state = self.state.replace(
+                        key=key, bytes_tx=bytes_tx, bytes_rx=bytes_rx,
+                        uplink_free_ms=uplink_new, rx_free_ms=rx_new,
+                    )
+                    publisher = int(exit_node)
+                # strip the mesh-repair leaves around the publish jit when no knob
+                # is armed: disseminate never touches them, and carrying them as
+                # passthrough outputs cost the r05 bench a copy of all 5 buffers
+                # per publish (ops/state.py strip_repair)
+                from ..ops.state import repair_inert, restore_repair, strip_repair
+
+                saved = None
+                if repair_inert(self.params):
+                    self.state, saved = strip_repair(self.state)
+            # enqueue only (trace + compile on a first call)
+            with span("publish/dispatch"):
+                res, self.state = disseminate(
+                    self.state,
+                    a["conns"],
+                    a["rev"],
+                    self._stage,
+                    self._lat,
+                    self._bw,
+                    publisher=publisher,
+                    t0_ms=t0_ms + mix_delay,
+                    params=self.params,
+                    payload_bytes=size,
+                    fragments=cfg.topo.num_frags,
+                    with_gossip=cfg.with_gossip,
+                    mesh=self.mesh,
+                    loss_stage=self._loss,
+                    loss_mode=cfg.loss_mode,
+                    lat_edge=self._lat_edge,
+                    loss_edge=self._loss_edge,
+                    ans_tables=self._ans_tables,
+                    valid_edge=self._valid_edge,
+                    censor_edge=censor_edge,
+                    # unsubscribed publisher -> gossipsub v1.1 fanout publish
+                    with_fanout=not bool(self._subscribed_np[publisher]),
                 )
-            key, k_mix = jax.random.split(self.state.key)
-            # occupancy-coupled: each hop's Sphinx serialization queues
-            # behind the sender's in-flight mesh/gossip traffic and is
-            # written back, so a relay's NEXT mesh forwarding queues behind
-            # the mix transmission it just made (shared real links)
-            path, exit_node, path_delay, uplink_new, rx_new = mix_route(
-                k_mix,
-                publisher,
-                self.state.alive,
-                self._stage,
-                self._lat,
-                self._bw,
-                params=self.mix_params,
-                n=self.params.n,
-                payload_bytes=size,
-                uplink_free_ms=self.state.uplink_free_ms,
-                rx_free_ms=self.state.rx_free_ms,
-                t0_ms=t0_ms,
-            )
-            mix_delay = float(path_delay)
-            wire = float(mix_wire_bytes(self.mix_params, size))
-            # per-hop attribution, both directions (Shadow's counters see
-            # both ends of every packet): senders are origin + first
-            # mix_d-1 relays, receivers are the mix_d relays
-            senders = jnp.concatenate(
-                [jnp.asarray([origin]), path[:-1]]
-            )
-            bytes_tx = self.state.bytes_tx.at[senders].add(wire)
-            bytes_rx = self.state.bytes_rx.at[path].add(wire)
-            self.state = self.state.replace(
-                key=key, bytes_tx=bytes_tx, bytes_rx=bytes_rx,
-                uplink_free_ms=uplink_new, rx_free_ms=rx_new,
-            )
-            publisher = int(exit_node)
-        # strip the mesh-repair leaves around the publish jit when no knob
-        # is armed: disseminate never touches them, and carrying them as
-        # passthrough outputs cost the r05 bench a copy of all 5 buffers
-        # per publish (ops/state.py strip_repair)
-        from ..ops.state import repair_inert, restore_repair, strip_repair
-
-        saved = None
-        if repair_inert(self.params):
-            self.state, saved = strip_repair(self.state)
-        res, self.state = disseminate(
-            self.state,
-            a["conns"],
-            a["rev"],
-            self._stage,
-            self._lat,
-            self._bw,
-            publisher=publisher,
-            t0_ms=t0_ms + mix_delay,
-            params=self.params,
-            payload_bytes=size,
-            fragments=cfg.topo.num_frags,
-            with_gossip=cfg.with_gossip,
-            mesh=self.mesh,
-            loss_stage=self._loss,
-            loss_mode=cfg.loss_mode,
-            lat_edge=self._lat_edge,
-            loss_edge=self._loss_edge,
-            ans_tables=self._ans_tables,
-            valid_edge=self._valid_edge,
-            censor_edge=censor_edge,
-            # unsubscribed publisher -> gossipsub v1.1 fanout publish
-            with_fanout=not bool(self._subscribed_np[publisher]),
-        )
-        if saved is not None:
-            self.state = restore_repair(self.state, saved)
-        if cfg.msgid_mode == "go":
-            # Go/Rust key messages by the embedded LE64 ns timestamp. The
-            # sim clock is float32-coarse, so back-to-back publishes could
-            # collide where real nodes' nanosecond clocks would not —
-            # enforce strict monotonicity the way distinct real publishes
-            # always have distinct timestamps.
-            msg_id = max(int(t0_ms * 1e6), self._last_msg_id + 1)
-            self._last_msg_id = msg_id
-        else:
-            msg_id = int(self._msg_rng.integers(0, 2**63, dtype=np.int64))
-        rec = record_from_result(
-            res,
-            msg_id=msg_id,
-            publisher=origin,
-            t0_ms=t0_ms,
-            extra_delay_ms=mix_delay,
-            # a peer doesn't log its own message when SELFTRIGGER is off, and
-            # never when unsubscribed (no topic handler to fire): the origin
-            # on the fanout path, and a mix exit node publishing on the
-            # origin's behalf while itself unsubscribed
-            drop_self=[
-                p for p in {origin, publisher}
-                if (p == origin and not cfg.self_trigger)
-                or not self._subscribed_np[p]
-            ] or None,
-        )
-        self.records.append(rec)
+                if saved is not None:
+                    self.state = restore_repair(self.state, saved)
+            # every device->host read: the host waits for the publish here
+            with span("publish/read"):
+                if cfg.msgid_mode == "go":
+                    # Go/Rust key messages by the embedded LE64 ns timestamp. The
+                    # sim clock is float32-coarse, so back-to-back publishes could
+                    # collide where real nodes' nanosecond clocks would not —
+                    # enforce strict monotonicity the way distinct real publishes
+                    # always have distinct timestamps.
+                    msg_id = max(int(t0_ms * 1e6), self._last_msg_id + 1)
+                    self._last_msg_id = msg_id
+                else:
+                    msg_id = int(self._msg_rng.integers(0, 2**63, dtype=np.int64))
+                rec = record_from_result(
+                    res,
+                    msg_id=msg_id,
+                    publisher=origin,
+                    t0_ms=t0_ms,
+                    extra_delay_ms=mix_delay,
+                    # a peer doesn't log its own message when SELFTRIGGER is off, and
+                    # never when unsubscribed (no topic handler to fire): the origin
+                    # on the fanout path, and a mix exit node publishing on the
+                    # origin's behalf while itself unsubscribed
+                    drop_self=[
+                        p for p in {origin, publisher}
+                        if (p == origin and not cfg.self_trigger)
+                        or not self._subscribed_np[p]
+                    ] or None,
+                )
+                self.records.append(rec)
+            # the publish's counters, with the shape of its fixpoint loops
+            # (what a reader of the profile needs to turn iterations into
+            # bytes), on a zero-length annotation
+            counters(
+                "publish/counters", message=len(self.records) - 1,
+                fast_iters=rec.fast_iters, refine_passes=rec.refine_passes,
+                refined=int(rec.refined), fell_back=int(rec.fell_back),
+                converged=int(rec.converged),
+                peers=self.params.n, slots=self.params.capacity,
+                fragments=cfg.topo.num_frags,
+                rounds=self.params.history_gossip if cfg.with_gossip else 0,
+                formulation=fixpoint_formulation(
+                    a["conns"].shape, cfg.topo.num_frags, self.mesh))
         return rec
 
     def publish_batch(
@@ -742,14 +781,16 @@ class Simulator:
         n = cfg.topo.network_size
         done = len(self.records)  # >0 when resumed from a checkpoint
         if done == 0:
-            self.warmup()
+            with span("warmup"):    # dispatch of the warm-up scan
+                self.warmup()
         delay_ms = cfg.topo.delay_seconds * 1000.0
         pub = cfg.publisher_id % n
         if cfg.publisher_rotation:
             pub = (pub + done) % n
         for i in range(done, cfg.topo.messages):
             if i > 0:
-                self.advance(delay_ms)
+                with span("advance"):
+                    self.advance(delay_ms)
             self.publish(pub)
             if cfg.publisher_rotation:
                 pub = (pub + 1) % n  # next message from the next peer (run.sh:16-17)

@@ -145,5 +145,5 @@ def test_sharded_fixpoint_compiles_for_four_chips(peer_mesh, capacity):
     assert "all-gather" in text or "all-reduce" in text
     assert "f32[25000]" in text     # rows really are N/4 per device
     assert _device_bytes(compiled) < V5E_HBM_BYTES
-    t_out, inc_out, _ = compiled.output_shardings
+    t_out, inc_out, _, _ = compiled.output_shardings
     assert t_out.spec == P("peers") and inc_out.spec == P("peers")

@@ -230,8 +230,8 @@ def test_packed_recv_constants_layout_and_tolerance():
         assert c.flags.dtype == jnp.int8
         assert c.u_ms.dtype == jnp.float32
         assert c.rx_c.dtype == jnp.float32
-    t_ref, _, conv_ref = converge_recv(t0, c_ref, 64)
-    t_pk, _, conv_pk = converge_recv(t0, c_pk, 64)
+    t_ref, _, conv_ref, _ = converge_recv(t0, c_ref, 64)
+    t_pk, _, conv_pk, _ = converge_recv(t0, c_pk, 64)
     assert bool(conv_ref) and bool(conv_pk)
     ref = np.asarray(t_ref)
     pk = np.asarray(t_pk)

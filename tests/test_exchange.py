@@ -106,7 +106,7 @@ def test_recv_fixpoint_matches_dense_reference(with_gossip):
     (graph, lat_edge, tx_ms, send_mask, rank, k_p, g_tgt, g_off, hb_phase,
      uplink, rx_const, consts) = _scenario(seed=1, with_gossip=with_gossip)
     t0 = jnp.full((N,), INF).at[0].set(123.0)
-    t_fix, inc, ok = converge_recv(t0, consts, 64)
+    t_fix, inc, ok, _ = converge_recv(t0, consts, 64)
     got = np.asarray(t_fix, dtype=np.float64)
     assert bool(ok)
     t0_np = np.full(N, np.float64(np.asarray(INF)))
@@ -122,12 +122,12 @@ def test_recv_fixpoint_matches_dense_reference(with_gossip):
 def test_sharded_matches_single_shard_exactly():
     consts = _scenario(seed=2, with_gossip=True)[-1]
     t0 = jnp.full((N,), INF).at[3].set(0.0)
-    t_single, inc_single, ok_single = converge_recv(t0, consts, 64)
+    t_single, inc_single, ok_single, it_single = converge_recv(t0, consts, 64)
     single = np.asarray(t_single)
 
     mesh = make_peer_mesh(8)
     t0_s = place_sharded(mesh, t0)
-    t_sh, inc_sh, ok_sh = converge_sharded(t0_s, consts, 64, mesh)
+    t_sh, inc_sh, ok_sh, it_sh = converge_sharded(t0_s, consts, 64, mesh)
     sharded = np.asarray(t_sh)
     np.testing.assert_array_equal(single, sharded)
     # the carried confirmation-pass offer matrices agree too (the
@@ -135,6 +135,8 @@ def test_sharded_matches_single_shard_exactly():
     np.testing.assert_array_equal(np.asarray(inc_single),
                                   np.asarray(inc_sh))
     assert bool(ok_single) and bool(ok_sh)
+    # and the iteration count means the same on a mesh
+    assert int(it_single) == int(it_sh) > 0
 
 
 def test_sharded_under_jit_compiles_collectives():
