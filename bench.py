@@ -262,7 +262,7 @@ def main() -> None:
     # also experiment constants: the lat-sorted answer-queue service tables
     # (two stable argsorts/publish otherwise — the r5 accounting bill) and
     # the neighbor alive&subscribed validity pull (one row-gather/publish)
-    ans_tables = answer_tables(lat_edge, a["conns"])
+    ans_tables = answer_tables(lat_edge, a["conns"], a["rev"])
     valid_edge = (a["conns"] >= 0) & neighbor_pull_bool(
         state.alive & state.subscribed, a["conns"], a["rev"])
 

@@ -103,6 +103,8 @@ from .pull import (
     exceeds_budget,
     neighbor_pull_bool,
     neighbor_pull_min,
+    neighbor_rows_min,
+    permute_rows,
     reciprocal_pull_bool,
     reciprocal_pull_min,
 )
@@ -298,7 +300,7 @@ def edge_tables(stage, lat_ms, conns, rev, loss_stage=None):
 class AnswerTables:
     """Lat-sorted views of the connection slots — the static service order
     of the serialized answer-queue fold (gossip_fold). Like edge_tables,
-    these depend only on (lat_edge, conns): experiment constants rebuilt
+    these depend only on (lat_edge, conns, rev): experiment constants rebuilt
     inside every publish until r6 — two stable (N, C) argsorts plus two
     take_alongs per message at the 100k bench shape, a measured slice of
     the accounting_s regression. Build once with answer_tables() and pass
@@ -309,18 +311,32 @@ class AnswerTables:
     inv_lat: jnp.ndarray      # (N, C) int32 its inverse
     lat_sorted: jnp.ndarray   # (N, C) f32 slot latency in that order, INF pads
     conns_sorted: jnp.ndarray  # (N, C) int32 neighbor ids in that order
+    rev_sorted: jnp.ndarray   # (N, C) int32 the reverse slot's POSITION in
+    #                           the neighbor's lat order, inv_lat[conns, rev]
+    #                           (-1 on pads): a table kept in lat order is
+    #                           pulled to the receiver's slot layout as
+    #                           reciprocal_pull_min(x_sorted, conns,
+    #                           rev_sorted), with no un-permutation first
+    #                           (_converge_prefix's pass)
 
 
-def answer_tables(lat_edge, conns) -> AnswerTables:
-    """Precompute the lat-sort tables of the answer fold (see AnswerTables)."""
+@jax.jit
+def answer_tables(lat_edge, conns, rev) -> AnswerTables:
+    """Precompute the lat-sort tables of the answer fold (see AnswerTables).
+    One dispatch: a Simulator builds them per experiment, and op by op the
+    two permutations alone would be 2 x C dispatches."""
     slot_lat = jnp.where(conns >= 0, lat_edge, INF)
     perm_lat = jnp.argsort(slot_lat, axis=-1, stable=True)
     inv_lat = jnp.argsort(perm_lat, axis=-1, stable=True)
+    # slot positions are < C, exact in f32: the float pull serves, its INF
+    # on pads becomes -1
+    pos = reciprocal_pull_min(inv_lat.astype(jnp.float32), conns, rev)
     return AnswerTables(
         perm_lat=perm_lat,
         inv_lat=inv_lat,
-        lat_sorted=jnp.take_along_axis(slot_lat, perm_lat, axis=-1),
-        conns_sorted=jnp.take_along_axis(conns, perm_lat, axis=-1),
+        lat_sorted=permute_rows(slot_lat, perm_lat),
+        conns_sorted=permute_rows(conns, perm_lat),
+        rev_sorted=jnp.where(pos < INF, pos, -1.0).astype(jnp.int32),
     )
 
 
@@ -719,35 +735,42 @@ def disseminate(
         r = _frag_slice(retx_ms, frag_idx)
         return ld if r is None else ld + r
 
+    formulation = fixpoint_formulation(conns.shape, fragments, mesh)
+
     # ---- serialized gossip-answer machinery --------------------------------
     # Static service order for the per-round queue fold: within a round all
     # of a sender's IWANTs arrive at A_h + 2*lat (A_h shared per sender-
     # round), so arrival order IS lat order — a permutation of each row
     # that never changes across fragments, phases or estimates. Sorting
     # once here turns every fold into elementwise work plus within-row
-    # take_along gathers (the r5 bench catch: per-estimate global argsorts
-    # cost more than the whole r4 publish). The sort itself is an
-    # EXPERIMENT constant (lat_edge + conns only): callers that loop over
+    # permutations (the r5 bench catch: per-estimate global argsorts
+    # cost more than the whole r4 publish). As take_along_axis those
+    # permutations were XLA's general gather, 39.7 ms apiece at 100k x 40
+    # and 6.9 of a publish's 8.3 s with the fold's t[conns_sorted]
+    # (26.8 ms); as ops/pull.permute_rows they are selects, 0.28 ms, and
+    # that lookup a row pull, 8.6 ms (PR 28, TPU v5 lite; PERF.md). The
+    # refinement loop does none of them: it stays in the lat order
+    # (_converge_prefix). The sort itself is an
+    # EXPERIMENT constant (lat_edge, conns and rev only): callers that loop over
     # publishes precompute it via answer_tables() — the in-call fallback
     # keeps one-shot calls self-contained (same contract as edge_tables).
     if with_gossip:
         with jax.named_scope("sample"):
             if ans_tables is None:
-                ans_tables = answer_tables(lat_edge, conns)
+                ans_tables = answer_tables(lat_edge, conns, rev)
             perm_lat = ans_tables.perm_lat                       # (N, C)
             inv_lat = ans_tables.inv_lat
             lat_sorted = ans_tables.lat_sorted
             conns_sorted = ans_tables.conns_sorted
+            rev_sorted = ans_tables.rev_sorted
             gw_sorted = [
-                jnp.take_along_axis(g_tgt_w[h], perm_lat, axis=-1)
-                for h in range(n_rounds)
+                permute_rows(g_tgt_w[h], perm_lat) for h in range(n_rounds)
             ]
 
     def _sorted_frag(x, frag_idx):
         """Per-fragment slice of a (F/None, N, C) array, in lat order."""
         xs = _frag_slice(x, frag_idx)
-        return None if xs is None else jnp.take_along_axis(
-            xs, perm_lat, axis=-1)
+        return None if xs is None else permute_rows(xs, perm_lat)
 
     def _round_req(h, tick, live, q_t, lat, gw_h, sv):
         """THE request/announce semantics of the serialized answer model,
@@ -766,7 +789,28 @@ def disseminate(
             req = req & sv
         return a_h, samp, req
 
+    def _fold_consts(frag_idx):
+        """What the fold reads of a fragment's loss draws, in lat order:
+        (sv_s, lda_s) — the survive mask (None when lossless) and the
+        answers' delivery latency. Constant across estimates, so a loop
+        over estimates (_converge_prefix) builds them once, outside it."""
+        sv_s = _sorted_frag(survive, frag_idx)
+        retx_s = _sorted_frag(retx_ms, frag_idx)
+        lda_s = lat_sorted * ans_scale
+        if retx_s is not None:
+            lda_s = lda_s + retx_s
+        return sv_s, lda_s
+
     def gossip_fold(t_rx, frag_idx):
+        """gossip_fold_sorted brought back to the slot layout: the form
+        every caller outside _converge_prefix's loop reads (see there for
+        the tuple)."""
+        g_sorted, req_any_s, drain, mixed, wait_max = gossip_fold_sorted(
+            t_rx, *_fold_consts(frag_idx))
+        return (permute_rows(g_sorted, inv_lat),
+                permute_rows(req_any_s, inv_lat), drain, mixed, wait_max)
+
+    def gossip_fold_sorted(t_rx, sv_s, lda_s):
         """Exact serialized gossip-answer offers via the per-round fold.
 
         A peer answering several IWANTs serializes the answers on its
@@ -785,21 +829,27 @@ def disseminate(
         answer WOULD arrive if requested — which is self-consistent
         because an offer can only bind for a still-lacking receiver.
 
-        Returns (g_abs, req_any, drain, mixed, wait_max): per-edge
-        absolute offers (INF where no sampled live edge), answered flags,
-        per-peer answer queue drain (0 if none), the scalar interleave
-        flag, and the scalar MAX WAIT any requested answer spent queued
-        behind another (serve - arrival) — the per-hop error bound of the
-        bounded delivery mode (serialize_answers=False)."""
+        `sv_s`, `lda_s`: _fold_consts of the fragment. The whole fold
+        works in the LAT-SORTED layout and returns it: (g_sorted,
+        req_any_s, drain, mixed, wait_max) — per-edge absolute offers (INF
+        where no sampled live edge) and answered flags, both by position
+        in the sender's lat order; per-peer answer queue drain (0 if
+        none), the scalar interleave flag, and the scalar MAX WAIT any
+        requested answer spent queued behind another (serve - arrival) —
+        the per-hop error bound of the bounded delivery mode
+        (serialize_answers=False)."""
         base = t_rx + params.proc_delay_ms
         tick = _next_heartbeat(base, hb_phase, params.heartbeat_ms)  # (N,)
         live = can_send & (t_rx < INF)
-        sv_s = _sorted_frag(survive, frag_idx)
-        retx_s = _sorted_frag(retx_ms, frag_idx)
-        lda_s = lat_sorted * ans_scale
-        if retx_s is not None:
-            lda_s = lda_s + retx_s
-        q_t_s = t_rx[jnp.clip(conns_sorted, 0)]   # receiver times, lat order
+        # receiver times, lat order (pads are never sampled, so what a pad
+        # reads is never used): a per-peer lookup, which a row pull does
+        # for a third of the scalar gather's price (ops/pull.py) where that
+        # pull is in budget and the table on one device
+        if formulation == "row_pull":
+            q_t_s = neighbor_rows_min(
+                t_rx, conns_sorted, batch_factor=fragments)
+        else:
+            q_t_s = t_rx[jnp.clip(conns_sorted, 0)]
         txp = tx_ms[:, None]
         busy = uplink                               # (N,) queue busy carry
         g_sorted = jnp.full((n, c), INF)
@@ -838,11 +888,9 @@ def disseminate(
                 r_last > 0.0,
                 jnp.maximum(busy, M[:, -1]) + r_last * tx_ms, busy)
             had_req = had_req | (r_last > 0.0)
-        g_abs = jnp.take_along_axis(g_sorted, inv_lat, axis=-1)
-        g_abs = jnp.where(g_abs < INF, g_abs, INF)  # overflow -> sentinel
-        req_any = jnp.take_along_axis(req_any_s, inv_lat, axis=-1)
+        g_sorted = jnp.where(g_sorted < INF, g_sorted, INF)  # overflow -> sentinel
         drain = jnp.where(had_req, busy, 0.0)
-        return g_abs, req_any, drain, mixed, wait_max
+        return g_sorted, req_any_s, drain, mixed, wait_max
 
     def _gossip_jobs(t_rx, frag_idx):
         """Shared job builder of the serialized answer model: per sampled
@@ -948,14 +996,19 @@ def disseminate(
             cand = jnp.minimum(cand, ga)
         return cand
 
-    formulation = fixpoint_formulation(conns.shape, fragments, mesh)
-
     def pull(cand):
         """incoming[q, j] = offer made to q by the neighbor in its slot j
         (row-gather + fused slot select; see ops/pull.py for why). Runs
         inside the fragment vmap, so the memory dispatch must see the
         fragment multiplicity."""
         return reciprocal_pull_min(cand, conns, rev, batch_factor=fragments)
+
+    def pull_sorted(cand_s):
+        """pull() of a table whose rows are in the SENDER's lat order: the
+        select takes the reverse slot's sorted position (rev_sorted). The
+        result is in the receiver's slot layout, as pull()'s."""
+        return reciprocal_pull_min(
+            cand_s, conns, rev_sorted, batch_factor=fragments)
 
     def _converge_dyn(rank, k_p, frag_idx, t_pub, send_mask, t_init=None):
         """UNSERIALIZED fixpoint (every gossip answer rides its own uplink
@@ -1198,6 +1251,15 @@ def disseminate(
         queue = (rank + 1.0 + frag_idx * k_p[:, None]) * tx_ms[:, None]
         a_base = jnp.where(
             deliver & can_send[:, None], queue + ld, INF)
+        # The pass stays in the fold's LAT-SORTED layout end to end: the
+        # mesh bases and the fold's constants go there once, here, and the
+        # one consumer of the merged candidates, the pull, selects the
+        # reverse slot's sorted position (rev_sorted) as easily as the
+        # slot itself, so `inc` comes out in the receiver's slot layout
+        # as it always did. Bringing g and req back through inv_lat every
+        # pass was 79 of a pass's 117 ms at 100k peers (PERF.md, PR 27).
+        a_base_s = permute_rows(a_base, perm_lat)
+        sv_s, lda_s = _fold_consts(frag_idx)
         t0 = t_seed.at[publisher].set(t_pub)
         not_pub = jnp.arange(n) != publisher
 
@@ -1207,30 +1269,36 @@ def disseminate(
 
         def body(carry):
             t_g, _, _, _, _, _, it = carry
-            g_abs, req, drain, mixed, _ = gossip_fold(t_g, frag_idx)
+            g_sorted, req_s, drain, mixed, _ = gossip_fold_sorted(
+                t_g, sv_s, lda_s)
             # merged candidates: mesh offers + SV-masked serialized answer
             # offers (every sampled surviving edge offers, matching the
             # serial path — an offer only binds for a still-lacking, hence
             # requesting, receiver)
-            g_d = g_abs if sv is None else jnp.where(sv, g_abs, INF)
+            g_d = g_sorted if sv_s is None else jnp.where(
+                sv_s, g_sorted, INF)
             live = (t_g < INF)[:, None]
             start = jnp.maximum(t_g + params.proc_delay_ms, uplink)
-            cand = jnp.where(live, start[:, None] + a_base, INF)
-            cand = jnp.minimum(cand, jnp.where(live, g_d, INF))
-            inc = pull(cand)
+            cand_s = jnp.where(live, start[:, None] + a_base_s, INF)
+            cand_s = jnp.minimum(cand_s, jnp.where(live, g_d, INF))
+            inc = pull_sorted(cand_s)
             t_new = jnp.where(
                 not_pub,
                 jnp.maximum(inc.min(axis=-1), rx_const), t_pub)
-            return (t_new, g_abs, req, drain, mixed,
+            return (t_new, g_sorted, req_s, drain, mixed,
                     jnp.any(t_new != t_g), it + 1)
 
         with jax.named_scope("fixpoint"):
-            t, g_abs, req, drain, mixed, changed, it = jax.lax.while_loop(
-                cond, body,
-                (t0, jnp.full((n, c), INF), jnp.zeros((n, c), bool),
-                 jnp.zeros((n,), jnp.float32), jnp.bool_(False),
-                 jnp.bool_(True), jnp.int32(0)))
-        return t, g_abs, req, drain, mixed, ~changed, it
+            t, g_sorted, req_s, drain, mixed, changed, it = (
+                jax.lax.while_loop(
+                    cond, body,
+                    (t0, jnp.full((n, c), INF), jnp.zeros((n, c), bool),
+                     jnp.zeros((n,), jnp.float32), jnp.bool_(False),
+                     jnp.bool_(True), jnp.int32(0))))
+        # the caller's tuple is in the slot layout: ONE un-permutation,
+        # after the loop
+        return (t, permute_rows(g_sorted, inv_lat),
+                permute_rows(req_s, inv_lat), drain, mixed, ~changed, it)
 
     def queue_drop(tgt_mask, frag_idx):
         """Priority-queue drop model (main.nim:264-299). The reference's
@@ -1490,12 +1558,21 @@ def disseminate(
         k1 = tgt_f.sum(axis=-1).astype(jnp.float32)
         t1, g1, req1, drain1, mixed1, conv1, it1 = _converge_prefix(
             rank1, k1, frag_idx, t_pub, tgt_f, t_seed)
+
+        def pull_lat(cand):
+            # this pipeline's attribution pulls go through the lat order
+            # like its loops' pulls: XLA hoists a loop's select mask
+            # (iota == rev_sorted) out of the loop and keeps it, 0.5 GB at
+            # (100k, 40), and a pull through `rev` here would keep a second
+            # one beside it. The permutation costs 0.3 ms.
+            return pull_sorted(permute_rows(cand, perm_lat))
+
         # attribution pull: gossip offers masked to ANSWERED edges — an
         # unanswered edge's hypothetical offer must not steal the
         # first-sender argmin (same masking as phases_serial)
-        inc1 = pull(offers(t1, rank1, k1, frag_idx, tgt_f,
-                           deliver_only=True,
-                           g_abs=jnp.where(req1, g1, INF)))
+        inc1 = pull_lat(offers(t1, rank1, k1, frag_idx, tgt_f,
+                               deliver_only=True,
+                               g_abs=jnp.where(req1, g1, INF)))
         if not params.exclude_first_sender:
             return (t1, rank1, k1, tgt_f, g1, req1, drain1, inc1,
                     conv1 & ~mixed1, it1)
@@ -1503,9 +1580,9 @@ def disseminate(
             inc1, t1, rank1, k1, tgt_f)
         t2, g2, req2, drain2, mixed2, conv2, it2 = _converge_prefix(
             rank2, k2, frag_idx, t_pub, send_mask, t1)
-        inc2 = pull(offers(t2, rank2, k2, frag_idx, send_mask,
-                           deliver_only=True,
-                           g_abs=jnp.where(req2, g2, INF)))
+        inc2 = pull_lat(offers(t2, rank2, k2, frag_idx, send_mask,
+                               deliver_only=True,
+                               g_abs=jnp.where(req2, g2, INF)))
         return (t2, rank2, k2, send_mask, g2, req2, drain2, inc2,
                 conv1 & conv2 & ~mixed1 & ~mixed2, it1 + it2)
 

@@ -20,6 +20,16 @@ read amplification for contiguity and win ~4x. The iota mask is built
 inline (never materialized as an (N, C, C) constant) so peak memory stays
 O(N*C*C) only inside the fused loop body.
 
+Measured again, each as a jit of its own, same shape (PR 28, TPU v5 lite):
+  - two-index gather 39.2 ms; the row pull 9.1 ms, gathered rows included
+    (5.3 ms inside the publish's loops: PR 27's trace)
+  - a per-peer lookup `t[idx]` over an (N, C) index: 26.8 ms as XLA's scalar
+    gather, 8.6 ms as a row pull of the broadcast table (neighbor_rows_min)
+  - a WITHIN-ROW permutation (`take_along_axis(x, idx, axis=-1)`, nothing
+    crosses rows): 39.7 ms as XLA lowers it — the same 4M scalar loads — and
+    0.28 ms as selects alone, no gather (permute_rows; f32, bool and int32
+    alike; the (N, C, C) one-hot form 0.21-0.25 ms, see there why not that)
+
 The sharded fixpoint (parallel/exchange.py converge_sharded) deliberately
 does NOT use this: its per-iteration cross-shard traffic is the (N,) time
 vector alone, and the pull there is against receiver-local constants.
@@ -69,6 +79,29 @@ def _row_pull(vals, conns, rev, select, fallback, batch_factor):
     return select(rows, sel)
 
 
+def permute_rows(x: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
+    """out[..., p, j] = x[..., p, idx[p, j]] — a within-row pick (every
+    permutation of the lat-sorted answer fold is one). Nothing crosses rows,
+    so no gather is needed, only selects: column k of x goes wherever idx
+    says k, one (N, J) select per column, all of them one fusion. Exact for
+    every value (a pick, not a masked min); bit for bit
+    `take_along_axis(x, idx, axis=-1)` for in-range `idx`. Deliberately NOT
+    the (N, J, C) one-hot of `_row_pull`: a one-hot shared by several
+    permutations through the same index XLA:TPU materialises (0.5 GB as
+    pred at 100k x 40) instead of fusing. Dispatch is on what is visible at
+    trace time, the static row width: up to one lane tile takes the
+    selects; wider rows keep XLA's gather, whose cost no longer hides
+    behind C selects."""
+    c = x.shape[-1]
+    if c > _LANE:
+        return jnp.take_along_axis(x, idx, axis=-1)
+    shape = jnp.broadcast_shapes(x.shape[:-1], idx.shape[:-1])
+    out = jnp.broadcast_to(x[..., :1], shape + idx.shape[-1:])
+    for k in range(1, c):
+        out = jnp.where(idx == k, x[..., k:k + 1], out)
+    return out
+
+
 def reciprocal_pull_bool(
     edge_mask: jnp.ndarray, conns: jnp.ndarray, rev: jnp.ndarray,
     batch_factor: int = 1,
@@ -101,6 +134,26 @@ def neighbor_pull_min(
     """out[q, j] = per_peer[conns[q,j]] for floats; INF on invalid slots."""
     table = jnp.broadcast_to(per_peer[:, None], conns.shape)
     return reciprocal_pull_min(table, conns, rev, batch_factor)
+
+
+def neighbor_rows_min(
+    per_peer: jnp.ndarray, conns: jnp.ndarray, batch_factor: int = 1,
+) -> jnp.ndarray:
+    """out[q, j] = per_peer[conns[q,j]] for floats; INF on invalid slots —
+    neighbor_pull_min for an index that has no reverse map (the lat-sorted
+    conns_sorted of the answer fold). Every slot of the neighbor's row of
+    the broadcast table holds the value, so the row's min IS the value and
+    no slot is selected: no (N, C, C) iota mask exists, which inside a
+    while_loop XLA would hoist as a loop invariant and keep in HBM (0.5 GB
+    as pred at 100k x 40; ops/disseminate._converge_prefix). Same budget
+    dispatch as `_row_pull`; over it, XLA's scalar gather."""
+    q = jnp.clip(conns, 0)
+    if exceeds_budget(per_peer.dtype, conns.shape, batch_factor):
+        out = per_peer[q]
+    else:
+        table = jnp.broadcast_to(per_peer[:, None], conns.shape)
+        out = table[q, :].min(axis=-1)
+    return jnp.where(conns >= 0, out, INF)
 
 
 def reciprocal_pull_min(

@@ -205,7 +205,7 @@ def packed_state_ab(n: int = 100_000, connect_to: int = 10, reps: int = 3,
     lat = jnp.asarray(topo.latency_ms)
     bw = jnp.asarray(topo.bw_up_mbit)
     lat_edge, _ = edge_tables(stage, lat, a["conns"], a["rev"])
-    ans_tables = answer_tables(lat_edge, a["conns"])
+    ans_tables = answer_tables(lat_edge, a["conns"], a["rev"])
     state = init_state(params, seed=0)
     state = run_heartbeats(state, a["conns"], a["rev"], a["out_mask"],
                            params, warm_hb)           # form the mesh

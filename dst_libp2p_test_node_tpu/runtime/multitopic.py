@@ -151,9 +151,10 @@ class MultiTopicSimulator:
             self._stage, self._lat, self.arrays["conns"], self.arrays["rev"],
             self._loss)
         # lat-sorted answer-queue service tables: also experiment constants
-        # (lat_edge + conns only), hoisted off the per-publish path
+        # (lat_edge, conns and rev only), hoisted off the per-publish path
         self._ans_tables = (
-            answer_tables(self._lat_edge, self.arrays["conns"])
+            answer_tables(self._lat_edge, self.arrays["conns"],
+                          self.arrays["rev"])
             if cfg.with_gossip else None)
 
         rng = np.random.default_rng(np.random.SeedSequence([cfg.seed, 0x709]))

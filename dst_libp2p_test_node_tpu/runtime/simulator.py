@@ -313,7 +313,8 @@ class Simulator:
         # so are the lat-sorted answer-queue service tables (two stable
         # argsorts per publish otherwise — the r5 bench's accounting bill)
         self._ans_tables = (
-            answer_tables(self._lat_edge, self.arrays["conns"])
+            answer_tables(self._lat_edge, self.arrays["conns"],
+                          self.arrays["rev"])
             if cfg.with_gossip else None)
         if mesh is not None:
             import jax
@@ -473,7 +474,8 @@ class Simulator:
             self._stage, self._lat, self.arrays["conns"], self.arrays["rev"],
             self._loss)
         self._ans_tables = (
-            answer_tables(self._lat_edge, self.arrays["conns"])
+            answer_tables(self._lat_edge, self.arrays["conns"],
+                          self.arrays["rev"])
             if self.cfg.with_gossip else None)
         warm = jnp.full((self.params.n,), 3.4e38, dtype=jnp.float32)
         if self.mesh is not None:

@@ -51,10 +51,11 @@ def mesh_setup(*, n=100, connect_to=10, seed=0, hb=10, **over):
 def _publish(state, a, topo, params, **kw):
     stage, lat, bw = topo
     kw.setdefault("publisher", 7)
+    if "t0_ms" not in kw:
+        kw["t0_ms"] = float(state.t_ms)
     return disseminate(
         state, a["conns"], a["rev"], stage, lat, bw,
-        t0_ms=float(state.t_ms), params=params, payload_bytes=15000,
-        with_gossip=True, **kw)
+        params=params, payload_bytes=15000, with_gossip=True, **kw)
 
 
 def _pin_engines_equal(res_p, res_s, *, delay_rtol=1e-6):
@@ -100,19 +101,21 @@ def test_prefix_matches_serial_engine(kw, over):
     _pin_engines_equal(res_p, res_s)
 
 
-def test_prefix_matches_serial_on_answer_star():
-    # the hand-computed exact-serialization corner (test_disseminate
-    # .test_gossip_answer_serialization_exact pins the prefix default
-    # against closed-form delays); here the two engines are pinned against
-    # each other on the same topology: empty mesh, no flood, answers
-    # serialize back-to-back on the publisher's uplink
+def answer_star_setup(stages=1, latency=(100, 100)):
+    """Empty mesh, no flood: the publisher's answers serialize back-to-back
+    on its uplink (the hand-computed corner of test_disseminate
+    .test_gossip_answer_serialization_exact). Peer 0 is the hub; with
+    several `stages` and a `latency` range its eight links differ in
+    latency, so its lat order is a real permutation of its slots."""
     n = 9
     g = build_connection_graph(
         n, 1, seed=0,
         dials=np.vstack([np.full((1, 1), 1),
                          np.zeros((n - 1, 1), dtype=np.int64)]),
         max_degree=n)
-    t = Topology.build(TopoParams(network_size=n, anchor_stages=1))
+    t = Topology.build(TopoParams(
+        network_size=n, anchor_stages=stages,
+        min_latency=latency[0], max_latency=latency[1]))
     topo = (jnp.asarray(t.stage_of_peer), jnp.asarray(t.latency_ms),
             jnp.asarray(t.bw_up_mbit))
     params = SimParams(n=n, capacity=g.capacity, d_lazy=16,
@@ -121,7 +124,16 @@ def test_prefix_matches_serial_on_answer_star():
     state = state.replace(
         mesh_mask=jnp.zeros_like(state.mesh_mask),
         hb_phase=jnp.full((n,), 250.0, jnp.float32))
-    a = graph_arrays(g)
+    return g, params, state, graph_arrays(g), topo
+
+
+def test_prefix_matches_serial_on_answer_star():
+    # the hand-computed exact-serialization corner (test_disseminate
+    # .test_gossip_answer_serialization_exact pins the prefix default
+    # against closed-form delays); here the two engines are pinned against
+    # each other on the same topology: empty mesh, no flood, answers
+    # serialize back-to-back on the publisher's uplink
+    g, params, state, a, topo = answer_star_setup()
     res_p, _ = _publish(state, a, topo, params)
     res_s, _ = _publish(
         state, a, topo,
@@ -129,6 +141,115 @@ def test_prefix_matches_serial_on_answer_star():
     assert bool(np.asarray(res_p.received).all())
     assert int(np.asarray(res_p.refine_passes)) > 0
     _pin_engines_equal(res_p, res_s)
+
+
+def _leaf_bytes(tree):
+    """{path: bytes} of every leaf (PRNG keys by their key data)."""
+    out = {}
+    for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
+        if jnp.issubdtype(leaf.dtype, jax.dtypes.prng_key):
+            leaf = jax.random.key_data(leaf)
+        out[jax.tree_util.keystr(path)] = np.asarray(leaf)
+    return out
+
+
+@pytest.mark.parametrize("fragments", [1, 2])
+@pytest.mark.parametrize("loss", [None, 0.2], ids=["lossless", "lossy"])
+@pytest.mark.parametrize("case", ["answer-star", "gossip-heavy"])
+def test_sorted_layout_refinement_is_the_slot_layout_bits(
+        case, loss, fragments):
+    """ISSUE 28: the prefix refinement keeps the answer fold's lat-sorted
+    layout through the whole Jacobi loop (the pull selects the reverse
+    slot's sorted position; g and req come back through inv_lat once, after
+    it) and every permutation is ops/pull.permute_rows. The slot-layout
+    reference is the serial engine, which sorts globally and never sees
+    perm_lat / inv_lat / rev_sorted: on a publish whose refinement runs,
+    EVERY leaf of the result and of the new state — arrival times, and what
+    the g_abs / req / drain triple and the attribution matrix `inc` feed:
+    sends, copies, IHAVE / IWANT counts, lost copies, first-delivery credit,
+    uplink and downlink occupancy — is the reference's, bit for bit, with
+    and without loss draws (survive, retx_ms), at one and two fragments."""
+    if case == "answer-star":
+        # the hub publishes: eight answers queue on its uplink, in the
+        # order of eight different latencies
+        g, params, state, a, topo = answer_star_setup(
+            stages=3, latency=(40, 130))
+        assert len(set(np.asarray(topo[1])[np.asarray(topo[0])[0]][
+            np.asarray(topo[0])[1:]].tolist())) > 1
+        kw = {"publisher": 0}
+    else:
+        g, params, state, a, topo = mesh_setup(
+            flood_publish=False, d_lazy=12)
+        kw = {"publisher": 3}
+    if loss is not None:
+        kw["loss_stage"] = jnp.full(topo[1].shape, loss, jnp.float32)
+    res_p, st_p = _publish(state, a, topo, params, fragments=fragments, **kw)
+    res_s, st_s = _publish(
+        state, a, topo,
+        dataclasses.replace(params, answer_queue_mode="serial"),
+        fragments=fragments, **kw)
+    fast_iters, passes, refined, fell_back, converged = (
+        int(x) for x in np.asarray(res_p.counters))
+    assert refined == 1 and passes > 0 and fell_back == 0 and converged == 1
+    assert passes <= PASS_BUDGET
+    # the engines count their own passes; the other four are shared
+    ref = np.asarray(res_s.counters)
+    assert [fast_iters, refined, fell_back, converged] == \
+        [int(ref[0]), int(ref[2]), int(ref[3]), int(ref[4])]
+    got, want = _leaf_bytes((res_p, st_p)), _leaf_bytes((res_s, st_s))
+    assert got.keys() == want.keys()
+    for name in got:
+        if name.endswith((".refine_passes", ".counters")):
+            continue
+        if name.endswith(".uplink_free_ms"):
+            # the queue drain: the two engines associate busy + r*tx
+            # differently (1 ulp at 2 fragments under loss, as in the parent)
+            np.testing.assert_allclose(got[name], want[name], rtol=1e-6)
+            continue
+        assert got[name].tobytes() == want[name].tobytes(), name
+
+
+def _gathers(jaxpr, scope=""):
+    """(scope path, slice_sizes, output shape) of every gather traced,
+    sub-jaxprs (while bodies, cond branches, pjit) included."""
+    for eqn in jaxpr.eqns:
+        here = f"{scope}/{eqn.source_info.name_stack}"
+        if eqn.primitive.name == "gather":
+            yield here, eqn.params["slice_sizes"], eqn.outvars[0].aval.shape
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _gathers(sub, here)
+
+
+def test_refinement_pass_and_folds_trace_row_gathers_only():
+    """ISSUE 28's mechanism, read off the trace: on the row_pull formulation
+    no gather of N*C scalars is left in the refinement loop or in a fold —
+    each per-pass lookup is a whole-row pull (slice (1, C), output (N, C, C)
+    before the fused select) and each within-row permutation a select with
+    no gather at all. XLA priced the scalar forms at 26-40 ms apiece at
+    (100k, 40) on a v5e, the row pull at 5-9 ms, a select at 0.25 ms."""
+    g, params, state, a, topo = mesh_setup(hb=2)
+    n, c = a["conns"].shape
+    jaxpr = jax.make_jaxpr(
+        lambda st: _publish(st, a, topo, params, t0_ms=0.0))(state).jaxpr
+    seen = list(_gathers(jaxpr))
+    loop = [(ss, shape) for where, ss, shape in seen
+            if "refine" in where and "fixpoint" in where
+            and "legacy" not in where]
+    # two phases, each one pull of receiver times and one of candidates
+    assert len(loop) == 4
+    fold = [(ss, shape) for where, ss, shape in seen
+            if "fold" in where and np.prod(shape) >= n * c]
+    assert len(fold) == 2               # one receiver-time pull a fold
+    for ss, shape in loop + fold:
+        assert ss[-1] == c and shape[-2:] == (c, c), (ss, shape)
+    # the nine gw_sorted permutations of `sample` are selects: what is
+    # left there is per-peer
+    assert not [1 for where, ss, shape in seen
+                if where.endswith("/sample") and ss[-1] == 1
+                and np.prod(shape) >= n * c]
 
 
 @pytest.mark.parametrize("submesh", [2, 4])

@@ -50,6 +50,28 @@ def test_neighbor_pull_is_per_peer_value(edges):
     np.testing.assert_allclose(got, want)
 
 
+def test_neighbor_rows_min_is_the_per_peer_gather(edges, monkeypatch):
+    """The selector-free per-peer lookup (any index, no reverse map): the
+    row pull, and the scalar gather it falls back to past the budget, both
+    read per_peer[conns] bit for bit — INF included — and INF on pads."""
+    conns, _ = edges
+    n = conns.shape[0]
+    u = jax.random.uniform(jax.random.PRNGKey(8), (n,))
+    per_peer = jnp.where(u < 0.7, u * 1e6, pull.INF)
+    shuffled = jax.random.permutation(
+        jax.random.PRNGKey(9), conns, axis=1, independent=True)
+    cn = np.asarray(shuffled)
+    want = np.where(cn >= 0, np.asarray(per_peer)[np.clip(cn, 0, None)],
+                    np.float32(pull.INF))
+    rows = pull.neighbor_rows_min(per_peer, shuffled)
+    assert np.asarray(rows).tobytes() == want.tobytes()
+    assert "gather" in str(jax.make_jaxpr(pull.neighbor_rows_min)(
+        per_peer, shuffled))
+    monkeypatch.setattr(pull, "_MAX_INTERMEDIATE_BYTES", 1)
+    scalar = pull.neighbor_rows_min(per_peer, shuffled)
+    assert np.asarray(scalar).tobytes() == want.tobytes()
+
+
 def test_fallback_path_identical(edges, monkeypatch):
     """Force the 2-index fallback (as at 1M-peer scale) and require exact
     agreement with the row-gather path."""
@@ -95,3 +117,97 @@ def test_involution_roundtrip(edges):
     once = pull.reciprocal_pull_min(v, conns, rev)
     twice = np.asarray(pull.reciprocal_pull_min(once, conns, rev))
     np.testing.assert_allclose(twice[valid], np.asarray(v)[valid])
+
+
+# ------------------------------------------------- within-row permutations --
+
+def _row_perms(key, n, c):
+    """One random permutation of range(c) per row."""
+    return jnp.argsort(jax.random.uniform(key, (n, c)), axis=-1)
+
+
+def _rows_of(kind, key, n, c):
+    u = jax.random.uniform(key, (n, c))
+    if kind == "f32":       # arrival-time rows: finite values and the sentinel
+        return jnp.where(u < 0.6, u * 1e6, pull.INF).astype(jnp.float32)
+    if kind == "bool":
+        return u < 0.4
+    return jnp.where(u < 0.8, (u * 1e5).astype(jnp.int32), -1)   # ids, -1 pads
+
+
+@pytest.mark.parametrize("c", [8, 40, 128, 200])
+@pytest.mark.parametrize("kind", ["f32", "bool", "int32"])
+def test_permute_rows_is_take_along_axis(kind, c):
+    """The select (up to one lane tile) and the take_along_axis branch (past
+    it) give take_along_axis's bits, dtype and shape."""
+    n = 64
+    x = _rows_of(kind, jax.random.PRNGKey(c), n, c)
+    idx = _row_perms(jax.random.PRNGKey(c + 1), n, c)
+    got = pull.permute_rows(x, idx)
+    want = jnp.take_along_axis(x, idx, axis=-1)
+    assert got.dtype == want.dtype and got.shape == want.shape
+    assert np.asarray(got).tobytes() == np.asarray(want).tobytes()
+    # a permutation and its inverse undo each other
+    inv = jnp.argsort(idx, axis=-1)
+    back = pull.permute_rows(got, inv)
+    assert np.asarray(back).tobytes() == np.asarray(x).tobytes()
+
+
+def test_permute_rows_dispatches_on_row_width():
+    """Up to the lane tile no gather is traced; past it, the one of
+    take_along_axis."""
+    def gathers(c):
+        x = jnp.zeros((16, c), jnp.float32)
+        idx = jnp.zeros((16, c), jnp.int32)
+        jaxpr = jax.make_jaxpr(pull.permute_rows)(x, idx)
+        return str(jaxpr).count("gather")
+    assert gathers(40) == 0 and gathers(128) == 0
+    assert gathers(129) > 0
+
+
+def test_permute_rows_under_a_batch_axis_and_narrow_picks():
+    """x may carry leading axes the index lacks (the fragment vmap), and the
+    index may be narrower than the row (a per-row pick)."""
+    n, c = 32, 40
+    x = _rows_of("f32", jax.random.PRNGKey(0), 3 * n, c).reshape(3, n, c)
+    idx = _row_perms(jax.random.PRNGKey(1), n, c)
+    got = jax.vmap(lambda xf: pull.permute_rows(xf, idx))(x)
+    want = jnp.take_along_axis(x, jnp.broadcast_to(idx, x.shape), axis=-1)
+    assert np.asarray(got).tobytes() == np.asarray(want).tobytes()
+    pick = idx[:, :1]
+    assert np.asarray(pull.permute_rows(x[0], pick)).tobytes() == \
+        np.asarray(jnp.take_along_axis(x[0], pick, axis=-1)).tobytes()
+
+
+def test_rev_sorted_pulls_a_sorted_table_to_the_slot_layout(edges):
+    """AnswerTables.rev_sorted: a table kept in lat order, pulled through
+    (conns, rev_sorted), is the table in slot order pulled through (conns,
+    rev) — on a random involution with pad slots."""
+    from dst_libp2p_test_node_tpu.ops.disseminate import answer_tables
+
+    conns, rev = edges
+    assert bool((conns < 0).any())          # the graph has pad slots
+    lat_edge = jnp.where(
+        conns >= 0,
+        40.0 + 90.0 * jax.random.uniform(jax.random.PRNGKey(5), conns.shape),
+        0.0)
+    tabs = answer_tables(lat_edge, conns, rev)
+    valid = np.asarray(conns >= 0)
+    rs = np.asarray(tabs.rev_sorted)
+    assert (rs[~valid] == -1).all()
+    assert ((rs[valid] >= 0) & (rs[valid] < conns.shape[1])).all()
+    # perm_lat / inv_lat are each other's inverse, lat_sorted ascends
+    c = conns.shape[1]
+    assert (np.asarray(pull.permute_rows(tabs.perm_lat, tabs.inv_lat))
+            == np.arange(c)).all()
+    assert (np.diff(np.asarray(tabs.lat_sorted), axis=-1) >= 0).all()
+    g_sorted = _rows_of("f32", jax.random.PRNGKey(6), *conns.shape)
+    g_slot = jnp.take_along_axis(g_sorted, tabs.inv_lat, axis=-1)
+    got = pull.reciprocal_pull_min(g_sorted, conns, tabs.rev_sorted)
+    want = pull.reciprocal_pull_min(g_slot, conns, rev)
+    assert np.asarray(got).tobytes() == np.asarray(want).tobytes()
+    # and spelt out as the two-index gather of the issue
+    cn, rv = np.clip(np.asarray(conns), 0, None), np.clip(np.asarray(rev), 0, None)
+    np.testing.assert_array_equal(
+        np.asarray(g_sorted)[cn, np.clip(rs, 0, None)][valid],
+        np.asarray(g_slot)[cn, rv][valid])
