@@ -359,6 +359,7 @@ def cmd_run(argv: list[str]) -> int:
                                  "refine_passes": r.refine_passes,
                                  "refined": r.refined,
                                  "fell_back": r.fell_back,
+                                 "refined_serial": r.refined_serial,
                                  "converged": r.converged}
                                 for r in sim.records],
                         }),

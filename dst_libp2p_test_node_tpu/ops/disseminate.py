@@ -80,10 +80,12 @@ IHAVE/IWANT/IDONTWANT counts, IDONTWANT suppression
 (go-test-node/main.go:165), v1.1 score-threshold gating, and
 firstMessageDeliveries score credit.
 
-Fragmentation (FRAGMENTS > 1, main.nim:177-179) vmaps everything over the
-fragment axis; a relay's uplink additionally carries the f earlier fragments
-(f * k_p extra serialization slots) and a message completes at a receiver
-when its LAST fragment lands (main.nim:147-148).
+Fragmentation (FRAGMENTS > 1, main.nim:177-179) runs everything once per
+fragment lane: vmapped where all the lanes' row pulls fit the gather budget
+together, one lane at a time in a rolled loop where only one does
+(fragments_in_sequence); a relay's uplink additionally carries the f earlier
+fragments (f * k_p extra serialization slots) and a message completes at a
+receiver when its LAST fragment lands (main.nim:147-148).
 """
 
 from __future__ import annotations
@@ -233,14 +235,15 @@ class DisseminationResult:
     #                            pass-count budget of the exactness
     #                            certificate pins this on canonical
     #                            topologies (tests/test_exact_prefix.py).
-    counters: jnp.ndarray      # (5,) int32 — [fast_iters, refine_passes,
-    #                            refined, fell_back, converged]: how much
-    #                            work the publish's fixpoints did and which
-    #                            branches ran, packed so that the host takes
-    #                            them in ONE device->host read
+    counters: jnp.ndarray      # (6,) int32 — [fast_iters, refine_passes,
+    #                            refined, fell_back, converged,
+    #                            refined_serial]: how much work the
+    #                            publish's fixpoints did and which branches
+    #                            ran, packed so that the host takes them in
+    #                            ONE device->host read
     #                            (runtime/simulator.record_from_result) and
-    #                            the jit returns one leaf more, not four.
-    #                            The three that are no field of their own
+    #                            the jit returns one leaf more, not five.
+    #                            The four that are no field of their own
     #                            are the properties below.
 
     @property
@@ -262,6 +265,14 @@ class DisseminationResult:
         """() bool — inside the repair, the prefix engine's certificate
         failed and the global-sort pipeline reran all fragments."""
         return self.counters[..., 3] != 0
+
+    @property
+    def refined_serial(self):
+        """() bool — which engine the kept refinement came from: True the
+        global-sort one (phases_serial: chosen off "row_pull" or under
+        answer_queue_mode="serial", or the rerun after fell_back), False
+        with `refined` the parallel-prefix one, False without it none."""
+        return self.counters[..., 5] != 0
 
 
 def _stage_select(stage: jnp.ndarray, n_stages: int, conns: jnp.ndarray,
@@ -369,10 +380,12 @@ def _next_heartbeat(t, phase, hb_ms):
     return (jnp.floor((t - phase) / hb_ms) + 1.0) * hb_ms + phase
 
 
-def fixpoint_formulation(conns_shape, fragments: int = 1, mesh=None) -> str:
+def fixpoint_formulation(conns_shape, mesh=None) -> str:
     """Which formulation of the arrival-time fixpoint `disseminate` traces
     for this shape — decided at trace time from the mesh and the row-gather
-    memory budget (ops/pull.exceeds_budget), nothing else:
+    memory budget of ONE pull (ops/pull.exceeds_budget), nothing else; the
+    fragment count decides only whether the lanes run together
+    (fragments_in_sequence):
 
       "recv_sharded"  parallel/exchange.converge_sharded: receiver-side
                       constants under shard_map, one all-gather of t per
@@ -385,9 +398,23 @@ def fixpoint_formulation(conns_shape, fragments: int = 1, mesh=None) -> str:
     """
     if mesh is not None:
         return "recv_sharded"
-    if exceeds_budget(jnp.float32, conns_shape, fragments):
+    if exceeds_budget(jnp.float32, conns_shape):
         return "recv"
     return "row_pull"
+
+
+def fragments_in_sequence(conns_shape, fragments: int, mesh=None) -> bool:
+    """Whether a one-device publish takes its fragment lanes one at a time
+    (one rolled loop over the fragment axis) instead of vmapping them: where
+    the row pulls of all `fragments` lanes at once would pass the gather
+    budget and one lane's would not. What overflows there is the vmap, not
+    any pull (100,000 x 40: 2.05 GB a lane, 8.19 GB for four against 6 GiB),
+    so each lane keeps the "row_pull" formulation and the engines built on
+    it; a shape whose single pull is past the budget is "recv" whatever its
+    fragments, and a mesh unrolls its lanes as it always did."""
+    return (mesh is None
+            and exceeds_budget(jnp.float32, conns_shape, fragments)
+            and not exceeds_budget(jnp.float32, conns_shape))
 
 
 @partial(
@@ -504,8 +531,8 @@ def disseminate(
         # contraction when needed): experiment constants — callers that loop
         # over publishes precompute them via edge_tables(); the fallback here
         # keeps one-shot calls self-contained. NOTE: the stage pull runs once
-        # at top level, OUTSIDE the fragment vmap — batch_factor stays 1 (the
-        # vmapped pulls below pass fragments).
+        # at top level, OUTSIDE the fragment lanes — batch_factor stays 1 (the
+        # per-lane pulls below pass `lanes`).
         if lat_edge is None or (loss_stage is not None and loss_edge is None):
             lat_edge_c, loss_edge_c = edge_tables(
                 stage, lat_ms, conns, rev, loss_stage)
@@ -560,7 +587,8 @@ def disseminate(
             # Memory note: the draws (and the derived retx/lat_deliver) are
             # (F, N, C) and live through the whole fragment vmap — generating
             # them inside the per-fragment body would not lower the peak,
-            # since vmap batches all lanes anyway. At 1M peers this is
+            # since vmap batches all lanes anyway (and lanes taken in
+            # sequence index them). At 1M peers this is
             # ~0.4 GB per f32 array per fragment; lossy runs at extreme N
             # should keep FRAGMENTS modest (the five BASELINE configs that
             # reach 1M are lossless and never allocate any of this).
@@ -735,7 +763,27 @@ def disseminate(
         r = _frag_slice(retx_ms, frag_idx)
         return ld if r is None else ld + r
 
-    formulation = fixpoint_formulation(conns.shape, fragments, mesh)
+    formulation = fixpoint_formulation(conns.shape, mesh)
+    in_sequence = fragments_in_sequence(conns.shape, fragments, mesh)
+    # how many lanes' pulls are live at once: what every pull's budget
+    # dispatch must see (ops/pull.exceeds_budget's batch_factor)
+    lanes = 1 if in_sequence else fragments
+
+    def _per_fragment(fn, *xs, batched):
+        """fn(*lane) on every fragment lane of the (F, ...) arrays `xs`,
+        its outputs stacked on a leading fragment axis. In sequence
+        (fragments_in_sequence): ONE rolled loop, so that one lane's
+        intermediates are live and one copy of fn is compiled. Else the
+        vmap where `batched`, and F unrolled copies where not (shard_map
+        does not nest under vmap; a refinement engine's loops, vmapped,
+        would run every lane for as long as the slowest)."""
+        with jax.named_scope("per_fragment"):
+            if in_sequence:
+                return jax.lax.map(lambda lane: fn(*lane), xs)
+            if batched:
+                return jax.vmap(fn)(*xs)
+            outs = [fn(*(x[i] for x in xs)) for i in range(fragments)]
+            return tuple(jnp.stack(x) for x in zip(*outs))
 
     # ---- serialized gossip-answer machinery --------------------------------
     # Static service order for the per-round queue fold: within a round all
@@ -847,7 +895,7 @@ def disseminate(
         # pull is in budget and the table on one device
         if formulation == "row_pull":
             q_t_s = neighbor_rows_min(
-                t_rx, conns_sorted, batch_factor=fragments)
+                t_rx, conns_sorted, batch_factor=lanes)
         else:
             q_t_s = t_rx[jnp.clip(conns_sorted, 0)]
         txp = tx_ms[:, None]
@@ -999,16 +1047,16 @@ def disseminate(
     def pull(cand):
         """incoming[q, j] = offer made to q by the neighbor in its slot j
         (row-gather + fused slot select; see ops/pull.py for why). Runs
-        inside the fragment vmap, so the memory dispatch must see the
-        fragment multiplicity."""
-        return reciprocal_pull_min(cand, conns, rev, batch_factor=fragments)
+        inside the fragment vmap, so the memory dispatch must see how many
+        lanes are live at once."""
+        return reciprocal_pull_min(cand, conns, rev, batch_factor=lanes)
 
     def pull_sorted(cand_s):
         """pull() of a table whose rows are in the SENDER's lat order: the
         select takes the reverse slot's sorted position (rev_sorted). The
         result is in the receiver's slot layout, as pull()'s."""
         return reciprocal_pull_min(
-            cand_s, conns, rev_sorted, batch_factor=fragments)
+            cand_s, conns, rev_sorted, batch_factor=lanes)
 
     def _converge_dyn(rank, k_p, frag_idx, t_pub, send_mask, t_init=None):
         """UNSERIALIZED fixpoint (every gossip answer rides its own uplink
@@ -1198,7 +1246,7 @@ def disseminate(
             g_abs, _, _ = gossip_serial_exact(t_g, frag_idx)
             g_d = g_abs if sv is None else jnp.where(sv, g_abs, INF)
             g_in = reciprocal_pull_min(
-                g_d, conns, rev, batch_factor=fragments)
+                g_d, conns, rev, batch_factor=lanes)
             g_floor = g_in.min(axis=-1)
             t_new = _converge_floor(
                 rank, k_p, frag_idx, t_pub, send_mask, g_floor,
@@ -1592,17 +1640,14 @@ def disseminate(
         t_pubs = t0_ms + frag_ids * tx_ms[publisher]
 
     def _run_fast(warm):
-        if mesh is None:
-            return jax.vmap(
-                lambda f, t: phases_fast(f, t, warm))(frag_ids, t_pubs)
         # shard_map doesn't nest under vmap; fragments is static and <= 9
-        # (topogen -f choices), so unroll the fragment axis instead
-        outs = [phases_fast(frag_ids[i], t_pubs[i], warm)
-                for i in range(fragments)]
-        return tuple(jnp.stack(x) for x in zip(*outs))
+        # (topogen -f choices), so a mesh unrolls the fragment axis instead
+        return _per_fragment(lambda f, t: phases_fast(f, t, warm),
+                             frag_ids, t_pubs, batched=mesh is None)
 
     # scope `fast`: the unserialized two-phase pipeline (its loops under
-    # fast/fixpoint, the answer-queue folds under fast/fold)
+    # fast/fixpoint, the answer-queue folds under fast/fold), all of it per
+    # fragment lane (fast/per_fragment) but the warm-seed rerun's predicate
     with jax.named_scope("fast"):
         fast = _run_fast(params.warm_start)
         if params.warm_start:
@@ -1630,7 +1675,7 @@ def disseminate(
     answer_interleaved = jnp.sum(mixed_f.astype(jnp.int32))
     converged = jnp.all(ok_f)
     refine_passes = jnp.int32(0)
-    refined = fell_back = jnp.bool_(False)
+    refined = fell_back = refined_serial = jnp.bool_(False)
     if with_gossip and params.serialize_answers:
         # serialized-answer repair, decided ONCE per message on a SCALAR
         # predicate (_diverged): the fast pipeline is kept whenever no
@@ -1654,9 +1699,8 @@ def disseminate(
                       and formulation == "row_pull")
 
         def _serial_all(seed):
-            outs = [phases_serial(frag_ids[i], t_pubs[i], seed[i])
-                    for i in range(fragments)]
-            return tuple(jnp.stack(x) for x in zip(*outs))
+            return _per_fragment(phases_serial, frag_ids, t_pubs, seed,
+                                 batched=False)
 
         def _slow(fr):
             """The taken branch: fr[:10] refined, then the fell-back bit."""
@@ -1664,9 +1708,8 @@ def disseminate(
             if not use_prefix:
                 # the global-sort engine is the one chosen: no fallback
                 return _serial_all(t_fast) + (jnp.bool_(False),)
-            outs = [phases_prefix(frag_ids[i], t_pubs[i], t_fast[i])
-                    for i in range(fragments)]
-            pref = tuple(jnp.stack(x) for x in zip(*outs))
+            pref = _per_fragment(phases_prefix, frag_ids, t_pubs, t_fast,
+                                 batched=False)
 
             # certificate-gated fallback (nested scalar cond): any
             # fragment the prefix engine could not certify — interleaved
@@ -1696,6 +1739,9 @@ def disseminate(
                 fast_results + (ok_f, jnp.zeros((fragments,), jnp.int32)))
         fast_results, conv_f, passes_f = kept[:8], kept[8], kept[9]
         fell_back = kept[10]
+        # which engine the kept refinement is from: the global-sort one
+        # where it was the one chosen, or after the fallback to it
+        refined_serial = (refined & fell_back) if use_prefix else refined
         converged = jnp.all(conv_f)
         refine_passes = jnp.max(passes_f)
         # exact mode: the repair drives the delivery error to zero
@@ -1734,7 +1780,7 @@ def disseminate(
         # rx side (first-delivery attribution): delivered copies only
         first_slot = jnp.argmin(inc, axis=-1)
         q_t = neighbor_pull_min(  # neighbor arrival times (fragment-vmapped)
-            t_rx_one, conns, rev, batch_factor=fragments)
+            t_rx_one, conns, rev, batch_factor=lanes)
         start_tx = jnp.maximum(t_rx_one + params.proc_delay_ms, uplink)
         # IDONTWANT (v1.2): target announced receipt before our send began
         if payload_bytes >= params.idontwant_threshold_bytes:
@@ -1790,7 +1836,7 @@ def disseminate(
             slot_ok = (conns >= 0) & (rev >= 0)
             pulled = jnp.where(
                 slot_ok,
-                reciprocal_pull_min(pack, conns, rev, batch_factor=fragments),
+                reciprocal_pull_min(pack, conns, rev, batch_factor=lanes),
                 0.0)
             q_ihave = jnp.floor(pulled / 4.0)
             rem = pulled - q_ihave * 4.0
@@ -1811,7 +1857,7 @@ def disseminate(
                        else (sent_any & ~sv_loss).sum(axis=-1)
                        .astype(jnp.float32))
             arrived_rx = reciprocal_pull_bool(
-                arrived, conns, rev, batch_factor=fragments)
+                arrived, conns, rev, batch_factor=lanes)
             copies = arrived_rx.sum(axis=-1).astype(jnp.float32)
         # wire-arrival time of every copy that landed at each receiver slot
         # (for the downlink-occupancy fold below); -INF marks no-copy slots
@@ -1830,7 +1876,7 @@ def disseminate(
             slow_send = send_mask & made_offer & (
                 qdelay > params.slow_threshold_ms)
             slow_inc = reciprocal_pull_bool(
-                slow_send, conns, rev, batch_factor=fragments
+                slow_send, conns, rev, batch_factor=lanes
             ).astype(jnp.float32)
         else:
             slow_inc = jnp.zeros((n, c), jnp.float32)
@@ -1839,10 +1885,9 @@ def disseminate(
 
     with jax.named_scope("accounting"):
         (sends_f, copies_f, ihave_f, iwant_f, ihave_rx_f, iwant_rx_f,
-         first_slot_f, slow_f, arr_f, up_end_f, lost_f) = jax.vmap(
-            frag_accounting
-        )(frag_ids, t_rx_f, rank_f, k_f, smask_f, g_abs_acct, req_acct,
-          drain_acct, inc_acct)
+         first_slot_f, slow_f, arr_f, up_end_f, lost_f) = _per_fragment(
+            frag_accounting, frag_ids, t_rx_f, rank_f, k_f, smask_f,
+            g_abs_acct, req_acct, drain_acct, inc_acct, batched=True)
         sends = sends_f.sum(axis=0).astype(jnp.int32)
         lost_tx = lost_f.sum(axis=0).astype(jnp.int32)
         copies = copies_f.sum(axis=0).astype(jnp.int32)
@@ -1890,7 +1935,8 @@ def disseminate(
             refine_passes=refine_passes,
             counters=jnp.stack([
                 fast_iters, refine_passes, refined.astype(jnp.int32),
-                fell_back.astype(jnp.int32), converged.astype(jnp.int32)]),
+                fell_back.astype(jnp.int32), converged.astype(jnp.int32),
+                refined_serial.astype(jnp.int32)]),
         )
         dup = jnp.maximum(copies - fragments, 0)
         # uplink occupancy write-back: per fragment, frag_accounting computed the

@@ -53,6 +53,14 @@ _MAX_INTERMEDIATE_BYTES = 6 * 1024**3
 _LANE = 128
 
 
+def intermediate_bytes(dtype, conns_shape, batch_factor: int = 1) -> int:
+    """Bytes of the padded (N, C, C) row-gather intermediate of one pull,
+    times `batch_factor` pulls live at once (see exceeds_budget)."""
+    n, c = conns_shape[-2], conns_shape[-1]
+    itemsize = 1 if dtype == jnp.bool_ else jnp.dtype(dtype).itemsize
+    return n * c * max(_LANE, c) * itemsize * max(batch_factor, 1)
+
+
 def exceeds_budget(dtype, conns_shape, batch_factor: int = 1) -> bool:
     """The dispatch decision, exposed for tests: would the padded row-gather
     intermediate for this pull exceed the memory budget?
@@ -61,10 +69,8 @@ def exceeds_budget(dtype, conns_shape, batch_factor: int = 1) -> bool:
     are per-instance — the REAL allocation is batch_factor times the
     per-instance intermediate, so the dispatch must account for it or a
     9-fragment publish would blow an in-budget 2 GiB pull up to 18 GiB."""
-    n, c = conns_shape[-2], conns_shape[-1]
-    itemsize = 1 if dtype == jnp.bool_ else jnp.dtype(dtype).itemsize
-    padded = n * c * max(_LANE, c) * itemsize * max(batch_factor, 1)
-    return padded > _MAX_INTERMEDIATE_BYTES
+    return (intermediate_bytes(dtype, conns_shape, batch_factor)
+            > _MAX_INTERMEDIATE_BYTES)
 
 
 def _row_pull(vals, conns, rev, select, fallback, batch_factor):

@@ -27,7 +27,8 @@ import numpy as np
 
 from ..config.env import GossipSubParams
 from ..config.topology import Topology, TopoParams
-from ..ops.disseminate import disseminate, fixpoint_formulation
+from ..ops.disseminate import disseminate as _disseminate_program
+from ..ops.disseminate import fixpoint_formulation, fragments_in_sequence
 from ..ops.graph import build_connection_graph
 from ..ops.heartbeat import run_heartbeats
 from ..ops.state import SimParams, graph_arrays, init_state
@@ -141,6 +142,22 @@ def graph_capacity(cfg: ExperimentConfig) -> int:
     return min(cfg.max_connections, max(4 * cfg.connect_to, 16))
 
 
+def disseminate(*args, return_plan: bool = False, **kw):
+    """ops/disseminate.disseminate as a Simulator dispatches it: ALWAYS the
+    program that also returns the publish's sampled plan, which is dropped
+    here unless asked for. `return_plan` is a static argument of the jit, so
+    a publish with its plan and one without are two executables: a process
+    that takes one publish's plan (the benchmark's reference check does, and
+    any differential replay would) compiled the whole publish a second
+    time, 53-90 s at 100,000 peers on a v5e, and checked another executable
+    than the one it had timed. The plan is what `sample` drew anyway (send
+    sets, priorities, gossip targets, loss draws) plus five per-peer
+    vectors: returning it keeps about 50 MB of intermediates alive to the
+    end of a publish at (100000, 40) and costs no operation."""
+    res, state, plan = _disseminate_program(*args, return_plan=True, **kw)
+    return (res, state, plan) if return_plan else (res, state)
+
+
 def drain_heartbeat_carry(carry_ms: float, ms: float, hb_ms: float):
     """Advance a fractional-heartbeat accumulator: returns (whole heartbeat
     steps due, new carry). Shared by every runtime that steps simulated time
@@ -168,9 +185,9 @@ def record_from_result(
     # run (multitopic's per-topic projection, a publish_batch column) carry
     # none: their records read converged and no refinement
     packed = getattr(res, "counters", None)
-    fast_iters, refine_passes, refined, fell_back, converged = (
-        (0, 0, 0, 0, 1) if packed is None
-        else (int(v) for v in np.asarray(packed)))
+    (fast_iters, refine_passes, refined, fell_back, converged,
+     refined_serial) = ((0, 0, 0, 0, 1, 0) if packed is None
+                        else (int(v) for v in np.asarray(packed)))
     return MessageRecord(
         msg_id=msg_id,
         publisher=publisher,
@@ -190,6 +207,7 @@ def record_from_result(
         refine_passes=refine_passes,
         refined=bool(refined),
         fell_back=bool(fell_back),
+        refined_serial=bool(refined_serial),
     )
 
 
@@ -230,13 +248,15 @@ class MessageRecord:
     # the fixpoints this record rode (not checkpointed; views that carry no
     # bit read True)
     converged: bool = True
-    # DisseminationResult.fast_iters / refine_passes / refined / fell_back:
-    # how much work the publish's fixpoints did and which branches ran
-    # (`stats<i>.json` "publishes"; not checkpointed, views read 0 / False)
+    # DisseminationResult.fast_iters / refine_passes / refined / fell_back /
+    # refined_serial: how much work the publish's fixpoints did, which
+    # branches ran and which engine refined (`stats<i>.json` "publishes";
+    # not checkpointed, views read 0 / False)
     fast_iters: int = 0
     refine_passes: int = 0
     refined: bool = False
     fell_back: bool = False
+    refined_serial: bool = False
 
     @property
     def receivers(self) -> np.ndarray:
@@ -676,11 +696,13 @@ class Simulator:
                 fast_iters=rec.fast_iters, refine_passes=rec.refine_passes,
                 refined=int(rec.refined), fell_back=int(rec.fell_back),
                 converged=int(rec.converged),
+                refined_serial=int(rec.refined_serial),
                 peers=self.params.n, slots=self.params.capacity,
                 fragments=cfg.topo.num_frags,
                 rounds=self.params.history_gossip if cfg.with_gossip else 0,
-                formulation=fixpoint_formulation(
-                    a["conns"].shape, cfg.topo.num_frags, self.mesh))
+                formulation=fixpoint_formulation(a["conns"].shape, self.mesh),
+                in_sequence=int(fragments_in_sequence(
+                    a["conns"].shape, cfg.topo.num_frags, self.mesh)))
         return rec
 
     def publish_batch(

@@ -580,6 +580,41 @@ def test_fixpoint_matches_des_slow_start(n, ct, seed, stages, frags, payload):
              payload_bytes=payload)
 
 
+FRAG4_CASES = [
+    # (n, connect_to, seed, stages, gossip-heavy): the publish of the
+    # benchmark's runsh-100k-frag4 at a test's size: 15,000 B in FRAGMENTS=4
+    # of 3,750 B sent back to back, loss 0, slow start on (fragment f rides
+    # the stream its f predecessors warmed: 1, 1, 1, 2 flights), a relay
+    # never sends back to its first sender, receipt on the LAST fragment
+    (128, 8, 60, 5, False),
+    (300, 10, 61, 5, False),
+    (300, 10, 62, 5, True),
+]
+
+
+@pytest.mark.parametrize("n,ct,seed,stages,heavy", FRAG4_CASES)
+def test_fixpoint_matches_des_four_fragments(n, ct, seed, stages, heavy):
+    over = {"flood_publish": False, "d_lazy": 12} if heavy else {}
+    g, params, state, a, (stage, lat, bw) = _setup(
+        n, ct, seed, stages, **over)
+    # the cell's link model (benchmark/configs/runsh-100k-frag4.json)
+    assert params.slow_start and params.exclude_first_sender
+    assert params.send_queue_cap >= 4
+    pub = seed % n
+    t0 = float(state.t_ms)
+    res, _, plan = disseminate(
+        state, a["conns"], a["rev"], stage, lat, bw, publisher=pub,
+        t0_ms=t0, params=params, payload_bytes=15000, fragments=4,
+        with_gossip=True, return_plan=True)
+    assert np.asarray(res.received).all()
+    # the last fragment's stream has outgrown the first window: it pays
+    # one more round trip a hop than the first three
+    from dst_libp2p_test_node_tpu.ops.disseminate import tcp_flights
+    assert [tcp_flights((f + 1) * 3750, params) for f in range(4)] \
+        == [1, 1, 1, 2]
+    _compare(res, plan, a["conns"], a["rev"], params, pub, t0, 4)
+
+
 def test_slow_start_flight_counts():
     from dst_libp2p_test_node_tpu.ops.disseminate import tcp_flights
 

@@ -101,6 +101,95 @@ def test_prefix_matches_serial_engine(kw, over):
     _pin_engines_equal(res_p, res_s)
 
 
+@pytest.fixture
+def one_lane_budget(monkeypatch):
+    """Shrink ops/pull's gather budget to exactly one lane's row pull at the
+    shape given: one fragment is in budget, two or more together are not,
+    the (100000, 40) x 4 fragments position of the benchmark's
+    runsh-100k-frag4 at a test's size. The budget is read while
+    `disseminate` is traced, so the jit cache is emptied on both sides."""
+    import dst_libp2p_test_node_tpu.ops.pull as pull_mod
+
+    def shrink(conns_shape):
+        monkeypatch.setattr(
+            pull_mod, "_MAX_INTERMEDIATE_BYTES",
+            pull_mod.intermediate_bytes(jnp.float32, conns_shape))
+        disseminate.clear_cache()
+
+    yield shrink
+    monkeypatch.undo()
+    disseminate.clear_cache()
+
+
+IN_SEQUENCE_CASES = [
+    ({"fragments": 4}, {}),
+    ({"fragments": 3}, {"flood_publish": False, "d_lazy": 12}),
+]
+
+
+@pytest.mark.parametrize("kw,over", IN_SEQUENCE_CASES,
+                         ids=["mesh-frag4", "gossip-heavy-frag3"])
+def test_fragments_in_sequence_stay_on_row_pull_with_the_vmapped_bits(
+        kw, over, one_lane_budget):
+    """ISSUE 30: where the fragments' row pulls pass the gather budget
+    together and one alone does not, the publish takes the lanes one at a
+    time in a rolled loop and keeps the row_pull formulation and the prefix
+    engine (until PR 30 it went to "recv" and the global-sort engine). It
+    is the in-budget vmapped publish, bit for bit, in every leaf of the
+    result and of the new state."""
+    from dst_libp2p_test_node_tpu.ops.disseminate import (
+        fixpoint_formulation, fragments_in_sequence)
+
+    g, params, state, a, topo = mesh_setup(**over)
+    shape = a["conns"].shape
+    assert not fragments_in_sequence(shape, kw["fragments"])
+    res_v, st_v = _publish(state, a, topo, params, **kw)
+    one_lane_budget(shape)
+    assert fragments_in_sequence(shape, kw["fragments"])
+    assert not fragments_in_sequence(shape, 1)
+    assert fixpoint_formulation(shape) == "row_pull"
+    # a mesh unrolls its lanes as it did
+    assert not fragments_in_sequence(shape, kw["fragments"], mesh=object())
+    jaxpr = jax.make_jaxpr(
+        lambda st: _publish(st, a, topo, params, t0_ms=0.0, **kw))(
+            state).jaxpr
+    big = [(where, ss) for where, ss, shp in _gathers(jaxpr)
+           if np.prod(shp) >= np.prod(shape) and "legacy" not in where]
+    # row pulls only (slice (1, C)) outside the global-sort rerun: no lane
+    # fell back to the scalar gather of "recv" or of an over-budget pull,
+    # and each engine is traced once, in the rolled loop, not once a
+    # fragment
+    assert big and all(ss[-1] == shape[1] for _, ss in big)
+    in_loop = [ss for where, ss in big
+               if "refine" in where and "fixpoint" in where
+               and "legacy" not in where]
+    assert len(in_loop) == 4
+    res_q, st_q = _publish(state, a, topo, params, **kw)
+    assert bool(res_q.refined) and not bool(res_q.refined_serial)
+    got, want = _leaf_bytes((res_q, st_q)), _leaf_bytes((res_v, st_v))
+    assert got.keys() == want.keys()
+    for name in got:
+        assert got[name].tobytes() == want[name].tobytes(), name
+
+
+@pytest.mark.parametrize("kw,over", IN_SEQUENCE_CASES,
+                         ids=["mesh-frag4", "gossip-heavy-frag3"])
+def test_prefix_matches_serial_engine_in_sequence(kw, over, one_lane_budget):
+    """test_prefix_matches_serial_engine's fragment cases once more with
+    the lanes in sequence: the rolled loop over phases_prefix against the
+    rolled loop over phases_serial."""
+    g, params, state, a, topo = mesh_setup(**over)
+    one_lane_budget(a["conns"].shape)
+    res_p, _ = _publish(state, a, topo, params, **kw)
+    res_s, _ = _publish(
+        state, a, topo,
+        dataclasses.replace(params, answer_queue_mode="serial"), **kw)
+    assert 0 < int(np.asarray(res_p.refine_passes)) <= PASS_BUDGET
+    assert int(np.asarray(res_s.refine_passes)) > 0
+    assert not bool(res_p.refined_serial) and bool(res_s.refined_serial)
+    _pin_engines_equal(res_p, res_s)
+
+
 def answer_star_setup(stages=1, latency=(100, 100)):
     """Empty mesh, no flood: the publisher's answers serialize back-to-back
     on its uplink (the hand-computed corner of test_disseminate
@@ -188,14 +277,16 @@ def test_sorted_layout_refinement_is_the_slot_layout_bits(
         state, a, topo,
         dataclasses.replace(params, answer_queue_mode="serial"),
         fragments=fragments, **kw)
-    fast_iters, passes, refined, fell_back, converged = (
+    fast_iters, passes, refined, fell_back, converged, by_serial = (
         int(x) for x in np.asarray(res_p.counters))
     assert refined == 1 and passes > 0 and fell_back == 0 and converged == 1
     assert passes <= PASS_BUDGET
-    # the engines count their own passes; the other four are shared
+    # the engines count their own passes and say which of them refined; the
+    # other four are shared
     ref = np.asarray(res_s.counters)
     assert [fast_iters, refined, fell_back, converged] == \
         [int(ref[0]), int(ref[2]), int(ref[3]), int(ref[4])]
+    assert (by_serial, int(ref[5])) == (0, 1)
     got, want = _leaf_bytes((res_p, st_p)), _leaf_bytes((res_s, st_s))
     assert got.keys() == want.keys()
     for name in got:

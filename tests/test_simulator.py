@@ -1,3 +1,4 @@
+import dataclasses
 import os
 import subprocess
 import sys
@@ -251,3 +252,37 @@ def test_packet_loss_becomes_latency_in_tcp_mode():
     p50_tcp = np.percentile(tcp.delays_ms[tcp.received], 50)
     p50_clean = np.percentile(clean.delays_ms[clean.received], 50)
     assert p50_tcp < p50_clean + 250.0
+
+
+def test_taking_a_publishs_plan_compiles_nothing_more(monkeypatch):
+    """A Simulator dispatches ONE publish program, the one that also returns
+    the sampled plan (runtime/simulator.disseminate drops it unless asked):
+    a caller that takes one publish's plan, as the benchmark's reference
+    check does by wrapping the module's `disseminate`, reuses the executable
+    the other publishes ran, and gets their results."""
+    from dst_libp2p_test_node_tpu.runtime import simulator as simmod
+    from dst_libp2p_test_node_tpu.runtime.profiling import count_retraces
+
+    def delays(cfg):
+        sim = Simulator(cfg)
+        sim.run()
+        return [r.delays_ms.copy() for r in sim.records]
+
+    # a shape of its own: no other test's executable is in the jit cache
+    cfg = small_cfg(topo=dataclasses.replace(BASE, network_size=97))
+    plain = delays(cfg)
+    original, plans = simmod.disseminate, []
+
+    def with_plan(*args, **kw):
+        res, state, plan = original(*args, **kw, return_plan=True)
+        plans.append(plan)
+        return res, state
+
+    monkeypatch.setattr(simmod, "disseminate", with_plan)
+    with count_retraces() as retraces:
+        captured = delays(cfg)
+    assert len(plans) == BASE.messages and "rprio" in plans[0]
+    assert not [e for e in retraces.events if "disseminate" in e], \
+        retraces.events
+    for got, want in zip(captured, plain):
+        np.testing.assert_array_equal(got, want)

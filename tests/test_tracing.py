@@ -140,7 +140,7 @@ def test_stats_json_spans_and_publishes_are_strict_json(two_turns):
         assert len(stats["publishes"]) == MESSAGES
         for p in stats["publishes"]:
             assert set(p) == {"fast_iters", "refine_passes", "refined",
-                              "fell_back", "converged"}
+                              "fell_back", "converged", "refined_serial"}
             assert isinstance(p["fast_iters"], int) and p["fast_iters"] > 0
             assert p["converged"] is True and p["fell_back"] is False
 
@@ -191,15 +191,21 @@ def test_lowered_disseminate_carries_the_scopes(fragments):
     outermost = {n.split("/")[0] for n in names}
     assert {"sample", "fast", "refine", "accounting"} <= outermost
     # the loops' bodies and the cond's branches keep the scope they were
-    # traced under, through vmap over the fragment lanes too
-    assert any(re.match(r"fast/(vmap\()?fixpoint\)?/while/body/", n)
+    # traced under, through vmap over the fragment lanes too; what runs once
+    # a lane lies under `per_fragment`
+    assert any(re.match(r"fast/per_fragment/(vmap\()?fixpoint\)?/while/body/",
+                        n) for n in names)
+    assert any(re.match(r"fast/per_fragment/(vmap\()?fold\)?/", n)
                for n in names)
-    assert any(re.match(r"fast/(vmap\()?fold\)?/", n) for n in names)
     assert any(n.startswith("refine/cond/") and "/fixpoint/while/body/" in n
                for n in names)
     assert any(n.startswith("refine/cond/") and "/legacy/" in n
                for n in names)
-    assert any(n.startswith("accounting/vmap(") for n in names)
+    assert any(n.startswith("accounting/per_fragment/vmap(") for n in names)
+    # and the cross-fragment part of both (completion on the last fragment,
+    # the downlink fold, the warm rerun's predicate) does not
+    assert any(n.startswith("accounting/") and "per_fragment" not in n
+               for n in names)
     # what is left outside the four is a handful of scalar reductions
     loose = [n for n in names if n.split("/")[0] not in
              ("sample", "fast", "refine", "accounting")]
@@ -237,7 +243,10 @@ def test_counters_on_the_prefix_cases(kw, over, passes_prefix,
         # the packed vector is the scalars, in the documented order
         assert np.asarray(res.counters).tolist() == [
             int(res.fast_iters), int(res.refine_passes), int(res.refined),
-            int(res.fell_back), int(res.converged)]
+            int(res.fell_back), int(res.converged),
+            int(res.refined_serial)]
+    # which engine refined: the one chosen
+    assert not bool(res_p.refined_serial) and bool(res_s.refined_serial)
     # the fast pipeline is the same program under both engines
     assert int(res_p.fast_iters) == int(res_s.fast_iters)
 
@@ -250,6 +259,7 @@ def test_fell_back_when_the_prefix_engine_is_capped():
     res, _ = _publish(
         state, a, topo, dataclasses.replace(params, max_relax_iters=3), **kw)
     assert bool(res.refined) and bool(res.fell_back)
+    assert bool(res.refined_serial)     # the rerun's result is the one kept
     # the prefix iterations already spent, plus the serial outer passes
     assert int(res.refine_passes) == 2 * 3 + passes_serial
     assert bool(res.converged)
@@ -260,6 +270,7 @@ def test_no_gossip_never_refines(no_gossip):
     for p in no_gossip["publishes"]:
         assert p["fast_iters"] > 0 and p["refine_passes"] == 0
         assert p["refined"] is False and p["fell_back"] is False
+        assert p["refined_serial"] is False
         assert p["converged"] is True
 
 
