@@ -354,6 +354,10 @@ def cmd_run(argv: list[str]) -> int:
                             # turn (`run` and `run/stats_json` are still
                             # open: up to here)
                             "spans": spans.totals(),
+                            # lines of latencies<i> and shadowlog<i>, and how
+                            # many blocks of each the native formatter took
+                            # (0: the Python one wrote them)
+                            "emit": sim.emit_counts,
                             "publishes": [
                                 {"fast_iters": r.fast_iters,
                                  "refine_passes": r.refine_passes,

@@ -21,6 +21,14 @@ Ethernet+IP+TCP header. Control messages (IHAVE/IWANT) are small single
 packets. All simulated traffic is inter-host, so the localhost blocks are
 zero (the awk's Details section prints only the remote blocks,
 summary_shadowlog.awk:133-140).
+
+Which formatter runs: `shadowlog_text` computes every number a line prints
+as an int64 array over the peers (`shadowlog_fields`) and hands the block
+to native_logemit.format_shadowlog, which formats 4,096 peers and more
+(NATIVE_MIN_LINES) in one call of the C++ emitter (native/logemit.cpp) and
+fewer, or all where the library cannot be built, with one f-string a peer
+(`shadowlog_text_python`): the same bytes either way, those of the loop a
+peer that tests/shadowlog_reference.py keeps.
 """
 
 from __future__ import annotations
@@ -69,41 +77,64 @@ def _data_pkts(data_bytes: np.ndarray) -> np.ndarray:
     return np.ceil(data_bytes / MSS_BYTES)
 
 
-def shadowlog_lines(traffic: PeerTraffic, sim_time: str = "00:15:00") -> list[str]:
+def _remote_block(pkt, byt, ctrl) -> list[np.ndarray]:
+    """The seven non-zero flags of one remote block, before truncation."""
+    return [
+        pkt + ctrl,                     # pkt
+        byt + ctrl * CTRL_PKT_BYTES,    # bytes
+        ctrl,                           # ctrl_pkt
+        ctrl * HDR_BYTES,               # ctrl_hdr_bytes
+        pkt,                            # data_pkt
+        pkt * HDR_BYTES,                # data_hdr_bytes
+        byt,                            # data_bytes
+    ]
+
+
+def shadowlog_fields(traffic: PeerTraffic) -> np.ndarray:
+    """(N, 14) int64: what a peer's line prints besides constants, the
+    remote-in block's seven non-zero flags then the remote-out block's (the
+    line's rx and tx totals are each block's bytes flag), each truncated
+    toward zero as the reference's `int()` does."""
+    rx, tx = np.asarray(traffic.rx_bytes), np.asarray(traffic.tx_bytes)
+    crx, ctx = np.asarray(traffic.ctrl_rx), np.asarray(traffic.ctrl_tx)
+    return np.stack(
+        _remote_block(_data_pkts(rx), rx, crx)
+        + _remote_block(_data_pkts(tx), tx, ctx), axis=1).astype(np.int64)
+
+
+def shadowlog_head(sim_time: str) -> str:
+    """A line up to its peer's ordinal."""
+    return f"{sim_time} [shadow] {sim_time} [INFO] pod-"
+
+
+_ZERO_BLOCK = "0," * _FLAG_BLOCK
+
+
+def shadowlog_text_python(head: str, fields: np.ndarray) -> str:
+    """The lines of `shadowlog_text`, formatted here: what runs under
+    native_logemit.NATIVE_MIN_LINES peers and where the library is missing."""
+    # $10 split on ",|;": arr[1]=tag, arr[2]=rx, arr[3]=tx, arr[4..6] pad,
+    # arr[7..54] the four flag blocks: inbound-localhost, outbound-localhost
+    # (all traffic is inter-host), remote in, remote out
+    return "".join(
+        f"{head}{i} n/a shadow heartbeat [node] heartbeat;{ib},{ob},0,0,0;"
+        f"{_ZERO_BLOCK}{_ZERO_BLOCK}"
+        f"{ip},{ib},{ic},{ich},0,0,{idp},{idh},{idb},0,0,0,"
+        f"{op},{ob},{oc},{och},0,0,{odp},{odh},{odb},0,0,0\n"
+        for i, (ip, ib, ic, ich, idp, idh, idb,
+                op, ob, oc, och, odp, odh, odb) in enumerate(fields.tolist())
+    )
+
+
+def shadowlog_text(traffic: PeerTraffic, sim_time: str = "00:15:00") -> str:
     """One cumulative '[node]' heartbeat line per peer, field-compatible with
-    summary_shadowlog.awk ($5 peer, $9 '[node]', $10 counters)."""
-    out = []
-    n = traffic.rx_bytes.shape[0]
-    for i in range(n):
-        rx = traffic.rx_bytes[i]
-        tx = traffic.tx_bytes[i]
-        crx, ctx = traffic.ctrl_rx[i], traffic.ctrl_tx[i]
-        d_in_pkt = _data_pkts(rx)
-        d_out_pkt = _data_pkts(tx)
-        blocks = []
-        blocks.append([0] * _FLAG_BLOCK)  # inbound-localhost
-        blocks.append([0] * _FLAG_BLOCK)  # outbound-localhost
-        for pkt, byt, ctrl in ((d_in_pkt, rx, crx), (d_out_pkt, tx, ctx)):
-            b = [0] * _FLAG_BLOCK
-            b[0] = int(pkt + ctrl)                      # pkt
-            b[1] = int(byt + ctrl * CTRL_PKT_BYTES)     # bytes
-            b[2] = int(ctrl)                            # ctrl_pkt
-            b[3] = int(ctrl * HDR_BYTES)                # ctrl_hdr_bytes
-            b[6] = int(pkt)                             # data_pkt
-            b[7] = int(pkt * HDR_BYTES)                 # data_hdr_bytes
-            b[8] = int(byt)                             # data_bytes
-            blocks.append(b)
-        flags = ",".join(str(v) for b in blocks for v in b)
-        rx_tot = int(rx + crx * CTRL_PKT_BYTES)
-        tx_tot = int(tx + ctx * CTRL_PKT_BYTES)
-        # $10 split on ",|;": arr[1]=tag, arr[2]=rx, arr[3]=tx,
-        # arr[4..6] pad, arr[7..54] the four flag blocks
-        stats = f"heartbeat;{rx_tot},{tx_tot},0,0,0;{flags}"
-        out.append(
-            f"{sim_time} [shadow] {sim_time} [INFO] pod-{i} n/a shadow "
-            f"heartbeat [node] {stats}"
-        )
-    return out
+    summary_shadowlog.awk ($5 peer, $9 '[node]', $10 counters), as one
+    block: the fields computed as arrays, the text from one call of the
+    native emitter or, for small networks, one f-string a peer."""
+    from . import native_logemit
+
+    return native_logemit.format_shadowlog(
+        shadowlog_head(sim_time), shadowlog_fields(traffic))
 
 
 @dataclass

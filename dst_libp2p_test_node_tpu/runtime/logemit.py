@@ -17,9 +17,13 @@ written for `peer<i>` naming (SURVEY.md §7 quirks). We emit `peer<id>` so the
 *reference awk scripts run unchanged* on our latencies files; our own parser
 (runtime/summarize.py) accepts both spellings.
 
-For very large N the Python string path is the bottleneck, so the formatter
-is vectorized through numpy and can optionally hand off to the native C++
-emitter (native/logemit.cpp) when built.
+Which formatter runs: `LatenciesWriter` keeps each message as integer
+arrays (line numbers from a counter array indexed by peer, no Python object
+a receipt) and formats a block at a time through
+native_logemit.format_block, which takes blocks of NATIVE_MIN_LINES lines
+and more to the C++ emitter (native/logemit.cpp, built on first use) and
+smaller ones, or all of them where the library cannot be built, to
+`grep_lines` below: the same bytes either way.
 """
 
 from __future__ import annotations
@@ -44,12 +48,14 @@ def grep_lines(
     linenos: np.ndarray | None = None,
 ) -> list[str]:
     """latencies-file lines for one message: grep-style `path:lineno:content`."""
-    d = delays_ms.astype(np.int64)
     if linenos is None:
         linenos = np.ones(len(peer_ids), dtype=np.int64)
+    head, tail = _STDOUT_TEMPLATE.split("{pid}")
+    # Python ints once, then one f-string a line
     return [
-        f"{_STDOUT_TEMPLATE.format(pid=int(p))}:{int(ln)}:{msg_id} milliseconds: {int(dd)}"
-        for p, ln, dd in zip(peer_ids, linenos, d)
+        f"{head}{p}{tail}:{ln}:{msg_id} milliseconds: {dd}"
+        for p, ln, dd in zip(*(np.asarray(a).astype(np.int64).tolist()
+                               for a in (peer_ids, linenos, delays_ms)))
     ]
 
 
@@ -62,7 +68,8 @@ class LatenciesWriter:
 
     def __init__(self) -> None:
         self._chunks: list[tuple[int, np.ndarray, np.ndarray]] = []
-        self._next_lineno: dict[int, int] = {}
+        # lines printed so far, by peer id
+        self._lines = np.zeros(0, dtype=np.int64)
 
     def add_message(
         self, msg_id: int, peer_ids: np.ndarray, delays_ms: np.ndarray
@@ -71,15 +78,15 @@ class LatenciesWriter:
         order = np.argsort(peer_ids)
         peer_ids = peer_ids[order]
         delays = np.asarray(delays_ms)[order].astype(np.int64)
-        linenos = np.array(
-            [self._bump(int(p)) for p in peer_ids], dtype=np.int64
-        )
+        if peer_ids.size and peer_ids[-1] >= self._lines.size:
+            grown = np.zeros(int(peer_ids[-1]) + 1, dtype=np.int64)
+            grown[:self._lines.size] = self._lines
+            self._lines = grown
+        # ids are sorted: one given twice takes two successive numbers
+        repeat = np.arange(peer_ids.size) - np.searchsorted(peer_ids, peer_ids)
+        linenos = self._lines[peer_ids] + 1 + repeat
+        np.add.at(self._lines, peer_ids, 1)
         self._chunks.append((int(msg_id), peer_ids, np.stack([linenos, delays])))
-
-    def _bump(self, pid: int) -> int:
-        n = self._next_lineno.get(pid, 1)
-        self._next_lineno[pid] = n + 1
-        return n
 
     def write(self, path: str) -> int:
         """Returns the number of lines written."""

@@ -34,7 +34,7 @@ from ..ops.heartbeat import run_heartbeats
 from ..ops.state import SimParams, graph_arrays, init_state
 from .logemit import LatenciesWriter
 from .profiling import counters, span
-from .summarize import LatencySummary, report, summarize
+from .summarize import LatencySummary, report, summarize_records
 
 # Steady-state per-hop processing cost by muxer, DERIVED from the transport
 # stack each choice composes (main.nim:433-441, main.go:361-366,
@@ -371,6 +371,9 @@ class Simulator:
         self._last_msg_id = -1  # go-mode monotonic timestamp tie-break
         self._hb_carry_ms = 0.0
         self.records: list[MessageRecord] = []
+        # what the last write_latencies / write_shadowlog emitted: lines, and
+        # blocks the native formatter took (`stats<i>.json` "emit")
+        self.emit_counts: dict[str, int] = {}
         # flight recorder (ops/telemetry.py): disarmed by default — advance()
         # then runs the exact pre-telemetry heartbeat program. Armed via
         # record_telemetry(); last_telemetry holds the most recent window's
@@ -836,17 +839,23 @@ class Simulator:
         return w
 
     def write_latencies(self, path: str) -> int:
-        return self.latencies_writer().write(path)
+        from . import native_logemit
+
+        before = native_logemit.native_blocks
+        lines = self.latencies_writer().write(path)
+        self.emit_counts["latencies_lines"] = lines
+        self.emit_counts["latencies_native_blocks"] = (
+            native_logemit.native_blocks - before)
+        return lines
 
     def summary(self, large: bool | None = None) -> LatencySummary:
+        """The latency summary, from the records' arrays: what `summarize`
+        gives on the lines of `latencies<i>`, with no line formatted."""
         if large is None:
             large = self.cfg.topo.msg_size_bytes >= 1000  # run.sh:68 switch
-        w = self.latencies_writer()
-        import io
-
-        buf = io.StringIO()
-        w.write_to(buf)
-        return summarize(buf.getvalue().splitlines(), large=large)
+        return summarize_records(
+            ((rec.msg_id, rec.receivers, rec.delays_ms_int)
+             for rec in self.records), large=large)
 
     def summary_report(self) -> str:
         large = self.cfg.topo.msg_size_bytes >= 1000
@@ -861,13 +870,19 @@ class Simulator:
     def write_shadowlog(self, path: str) -> int:
         """Write Shadow-heartbeat-shaped '[node]' lines: the input of
         summary_shadowlog.awk (run.sh:70-74)."""
-        from .bandwidth import shadowlog_lines
+        from . import native_logemit
+        from .bandwidth import shadowlog_text
 
-        lines = shadowlog_lines(self.traffic())
+        traffic = self.traffic()
+        before = native_logemit.native_shadowlog_blocks
+        block = shadowlog_text(traffic)
         with open(path, "w") as f:
-            for ln in lines:
-                f.write(ln + "\n")
-        return len(lines)
+            f.write(block)
+        lines = traffic.rx_bytes.shape[0]
+        self.emit_counts["shadowlog_lines"] = lines
+        self.emit_counts["shadowlog_native_blocks"] = (
+            native_logemit.native_shadowlog_blocks - before)
+        return lines
 
     def bandwidth_report(self) -> str:
         from .bandwidth import report as bw_report

@@ -24,6 +24,8 @@ import math
 import re
 from dataclasses import dataclass, field
 
+import numpy as np
+
 HOP_LAT_MS = 100  # "should be consistent with shadow.yaml" (summary_latency.awk:8)
 
 
@@ -146,6 +148,58 @@ def summarize(lines, large: bool = False) -> LatencySummary:
         avg_latency_ms=sum(delays) / total_lines,  # awk divides by NR
         messages=messages,
         avg_max_latency_ms=avg_max,
+    )
+
+
+def summarize_records(records, large: bool = False) -> LatencySummary:
+    """`summarize` without the text: `records` yields `(msg_id, receivers,
+    delays_ms_int)`, a message's receipts as integer arrays, and the result
+    equals, field for field and bit for bit, what `summarize` gives on the
+    lines a LatenciesWriter formats from them. Every sum is an exact integer
+    sum divided once, as there, so the floats are the same."""
+    by_msg: dict[int, list[np.ndarray]] = {}   # first-seen order, as the lines'
+    network_size = 0
+    for msg_id, receivers, delays in records:
+        receivers = np.asarray(receivers, dtype=np.int64)
+        if receivers.size == 0:
+            continue    # no receipt, no line
+        by_msg.setdefault(int(msg_id), []).append(
+            np.asarray(delays, dtype=np.int64))
+        network_size = max(network_size, int(receivers.max()))
+    if not by_msg:
+        return LatencySummary(0, 0, 0, 0.0, [], 0.0)
+
+    messages = []
+    total = total_lines = 0
+    for mid, chunks in by_msg.items():
+        ds = chunks[0] if len(chunks) == 1 else np.concatenate(chunks)
+        total += int(ds.sum())
+        total_lines += ds.size
+        if large:
+            # the cast truncates toward zero, as int() does
+            # (summary_latency_large.awk:24)
+            src = (ds / HOP_LAT_MS + 0.5).astype(np.int64) * HOP_LAT_MS
+        else:
+            src = ds
+        buckets, counts = np.unique(src // HOP_LAT_MS, return_counts=True)
+        messages.append(
+            MessageSummary(
+                msg_id=mid,
+                avg_latency_ms=int(src.sum()) / ds.size,
+                received=ds.size,
+                max_latency_ms=int(ds.max()),
+                spread=dict(zip(buckets.tolist(), counts.tolist())),
+            )
+        )
+
+    return LatencySummary(
+        network_size=network_size,
+        total_messages=len(messages),
+        max_latency_ms=max(m.max_latency_ms for m in messages),
+        avg_latency_ms=total / total_lines,
+        messages=messages,
+        avg_max_latency_ms=(
+            sum(m.max_latency_ms for m in messages) / len(messages)),
     )
 
 

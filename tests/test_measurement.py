@@ -11,8 +11,10 @@ import pytest
 from dst_libp2p_test_node_tpu.runtime.logemit import LatenciesWriter, stdout_line
 from dst_libp2p_test_node_tpu.runtime.native_logemit import format_block
 from dst_libp2p_test_node_tpu.runtime.summarize import (
+    LatencySummary,
     parse_latencies,
     summarize,
+    summarize_records,
 )
 
 REF_AWK_SMALL = "/root/reference/shadow/summary_latency.awk"
@@ -48,6 +50,28 @@ def test_linenos_increment_per_peer():
     lines = buf.getvalue().splitlines()
     assert ":1:1 milliseconds: 10" in lines[0]
     assert ":2:2 milliseconds: 20" in lines[1]
+
+
+def test_linenos_are_a_count_a_peer_whatever_the_ids():
+    """The counter array indexed by peer id numbers lines as a dict bumped
+    once a receipt did: ids that grow from call to call, receivers in no
+    order, and an id given twice in one call."""
+    rng = np.random.default_rng(5)
+    calls = [(1, np.array([5, 2, 9])), (2, np.arange(6000)),
+             (3, rng.permutation(20_000)[:7000]), (4, np.array([3, 3, 1, 3])),
+             (5, np.array([], dtype=np.int64)), (6, np.array([19_999, 0]))]
+    w = LatenciesWriter()
+    seen: dict[int, int] = {}
+    want = []
+    for msg_id, peers in calls:
+        w.add_message(msg_id, peers, np.full(peers.size, 40 + msg_id))
+        for p in sorted(peers.tolist()):
+            seen[p] = seen.get(p, 0) + 1
+            want.append(f"shadow.data/hosts/peer{p}/main.1000.stdout:"
+                        f"{seen[p]}:{msg_id} milliseconds: {40 + msg_id}\n")
+    buf = io.StringIO()
+    assert w.write_to(buf) == len(want)
+    assert buf.getvalue() == "".join(want)
 
 
 def test_parse_accepts_peer_and_pod_naming():
@@ -90,6 +114,112 @@ def test_summarize_large_rounds_to_hop():
     assert m.spread == {1: 1, 2: 1, 3: 1}
     assert m.max_latency_ms == 250
     assert s.avg_max_latency_ms == 250
+
+
+def _records(case):
+    """(msg_id, receivers, delays_ms_int) a message, as Simulator.summary
+    hands them over."""
+    rng = np.random.default_rng(11)
+    everyone = np.arange(200)
+    if case == "every_peer_receives":
+        return [(7_000_000_000 + m, everyone, rng.integers(0, 2600, 200))
+                for m in range(3)]
+    if case == "a_message_not_every_peer_received":
+        some = np.nonzero(rng.random(200) < 0.6)[0]
+        return [(11, everyone, rng.integers(40, 900, 200)),
+                (12, some, rng.integers(40, 900, some.size)),
+                (13, np.array([199]), np.array([77]))]
+    if case == "receivers_in_no_order":
+        return [(21, rng.permutation(200), rng.integers(0, 5400, 200)),
+                (22, rng.permutation(150), rng.integers(0, 5400, 150))]
+    if case == "one_message":
+        return [(31, everyone[:50], rng.integers(40, 700, 50))]
+    if case == "no_records":
+        return []
+    if case == "a_record_nobody_received":
+        return [(41, everyone[:0], np.zeros(0, dtype=np.int64)),
+                (42, everyone[:9], rng.integers(40, 700, 9))]
+    if case == "nobody_received_anything":
+        return [(51, everyone[:0], np.zeros(0, dtype=np.int64))]
+    if case == "both_sides_of_a_50_ms_rounding_edge":
+        # the large variant rounds to the nearest 100 ms, halves up, and
+        # int() truncates toward zero
+        edges = np.array([0, 1, 49, 50, 51, 99, 100, 101, 149, 150, 151,
+                          249, 250, 251, 1049, 1050, 1051, 5349, 5350, 5351])
+        return [(61, np.arange(edges.size), edges),
+                (62, np.arange(edges.size), edges[::-1] + 50)]
+    if case == "one_msg_id_in_two_records":
+        return [(71, everyone[:40], rng.integers(40, 700, 40)),
+                (72, everyone[:30], rng.integers(40, 700, 30)),
+                (71, everyone[40:90], rng.integers(40, 2700, 50))]
+    raise AssertionError(case)
+
+
+@pytest.mark.parametrize("large", [False, True], ids=["small", "large"])
+@pytest.mark.parametrize("case", [
+    "every_peer_receives", "a_message_not_every_peer_received",
+    "receivers_in_no_order", "one_message", "no_records",
+    "a_record_nobody_received", "nobody_received_anything",
+    "both_sides_of_a_50_ms_rounding_edge", "one_msg_id_in_two_records"])
+def test_summarize_records_is_summarize_on_the_writers_lines(case, large):
+    """Simulator.summary's path (arrays in, no text) against the text path
+    on the very lines `latencies<i>` holds: equal as dataclasses, so every
+    float to the last bit, every spread key and count, the messages' order."""
+    records = _records(case)
+    w = LatenciesWriter()
+    for msg_id, receivers, delays in records:
+        w.add_message(msg_id, receivers, delays)
+    buf = io.StringIO()
+    w.write_to(buf)
+    want = summarize(buf.getvalue().splitlines(), large=large)
+    got = summarize_records(records, large=large)
+    assert got == want
+    assert [m.msg_id for m in got.messages] == [
+        m.msg_id for m in want.messages]
+    for g in [got] + got.messages:
+        for name, value in vars(g).items():
+            if name not in ("messages", "spread"):
+                assert type(value) in (int, float), (name, type(value))
+    if not records or case == "nobody_received_anything":
+        assert got == LatencySummary(0, 0, 0, 0.0, [], 0.0)
+
+
+def test_simulator_summary_reads_the_records_not_the_text(monkeypatch):
+    """Simulator.summary formats no line and parses none: with the writer
+    and the parser set to raise it still gives what the text path gives on
+    `latencies<i>`."""
+    from dst_libp2p_test_node_tpu.config.topology import TopoParams
+    import importlib
+
+    from dst_libp2p_test_node_tpu.runtime import logemit, simulator
+    from dst_libp2p_test_node_tpu.runtime.simulator import (
+        ExperimentConfig, Simulator)
+
+    # (the package exports the function `summarize` under the module's name)
+    summarize_mod = importlib.import_module(
+        "dst_libp2p_test_node_tpu.runtime.summarize")
+    sim = Simulator(ExperimentConfig(
+        topo=TopoParams(network_size=40, msg_size_bytes=1500, messages=3,
+                        delay_seconds=1.0),
+        connect_to=5, warmup_s=5.0, seed=2))
+    sim.run()
+    buf = io.StringIO()
+    sim.latencies_writer().write_to(buf)
+    want = {large: summarize(buf.getvalue().splitlines(), large=large)
+            for large in (False, True)}
+
+    def tripped(*a, **kw):
+        raise AssertionError("Simulator.summary went through the text")
+
+    monkeypatch.setattr(summarize_mod, "parse_latencies", tripped)
+    monkeypatch.setattr(summarize_mod, "summarize", tripped)
+    monkeypatch.setattr(logemit, "LatenciesWriter", tripped)
+    monkeypatch.setattr(simulator, "LatenciesWriter", tripped)
+    monkeypatch.setattr(io, "StringIO", tripped)
+    assert sim.summary(False) == want[False]
+    assert sim.summary(True) == want[True]
+    assert sim.summary() == want[True]      # 1,500 B: run.sh:68's switch
+    assert want[True].total_messages == 3 and want[True].coverage() == 40.0
 
 
 @pytest.mark.skipif(

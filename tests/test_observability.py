@@ -179,9 +179,60 @@ def _block_args(n=5000):
     return 99, peers, np.ones(n, dtype=np.int64), peers % 977
 
 
+def _shadowlog_args(n=5000):
+    from dst_libp2p_test_node_tpu.runtime import native_logemit as nl
+
+    rng = np.random.default_rng(3)
+    return "00:15:00 [shadow] 00:15:00 [INFO] pod-", rng.integers(
+        0, 1 << 40, size=(n, nl.SHADOWLOG_FIELDS))
+
+
+# the library's two formatters: name -> (function, its arguments, the
+# counter of its native calls)
+_FORMATTERS = {
+    "format_block": ("format_block", _block_args, "native_blocks"),
+    "format_shadowlog": ("format_shadowlog", _shadowlog_args,
+                         "native_shadowlog_blocks"),
+}
+
+
+def test_shadowlog_fast_paths_byte_identical():
+    """format_shadowlog's two formatters against a line composed here from
+    the layout summary_shadowlog.awk parses (tests/test_bandwidth.py holds
+    both to the reference loop on real counters)."""
+    from dst_libp2p_test_node_tpu.runtime import native_logemit
+
+    head, fields = _shadowlog_args(10_000)
+    fields[::7] = 0
+    fields[1::7] = np.iinfo(np.int64).max
+    ref = "".join(
+        f"{head}{i} n/a shadow heartbeat [node] heartbeat;{f[1]},{f[8]},0,0,0;"
+        + ",".join(str(v) for v in (
+            [0] * 24 + f[0:4] + [0, 0] + f[4:7] + [0, 0, 0]
+            + f[7:11] + [0, 0] + f[11:14] + [0, 0, 0])) + "\n"
+        for i, f in enumerate(fields.tolist()))
+    assert native_logemit.format_shadowlog(
+        head, fields, force_python=True) == ref
+    if native_logemit.ensure_built():  # toolchain-gated native path
+        before = native_logemit.native_shadowlog_blocks
+        assert native_logemit.format_shadowlog(head, fields) == ref
+        assert native_logemit.native_shadowlog_blocks == before + 1
+    # under the threshold the Python formatter runs, whatever is built
+    before = native_logemit.native_shadowlog_blocks
+    few = native_logemit.NATIVE_MIN_LINES - 1
+    assert native_logemit.format_shadowlog(head, fields[:few]) == "".join(
+        ref.splitlines(keepends=True)[:few])
+    assert native_logemit.native_shadowlog_blocks == before
+    with pytest.raises(ValueError, match="fields must be"):
+        native_logemit.format_shadowlog(head, fields[:, :13])
+
+
+@pytest.mark.parametrize("formatter", list(_FORMATTERS))
 def test_logemit_is_built_from_the_source_never_a_stale_binary(
-        logemit_sandbox, monkeypatch):
+        logemit_sandbox, monkeypatch, formatter):
     nl, d = logemit_sandbox
+    name, args, counter = _FORMATTERS[formatter]
+    fmt = getattr(nl, name)
     # a binary left over from some other version of the source, under the
     # old fixed name and older than logemit.cpp: never what runs
     (d / "liblogemit.so").write_bytes(b"not a library")
@@ -189,10 +240,10 @@ def test_logemit_is_built_from_the_source_never_a_stale_binary(
     assert nl.ensure_built()
     built = nl.lib_path()
     assert os.path.exists(built) and built != str(d / "liblogemit.so")
-    before = nl.native_blocks
-    native = nl.format_block(*_block_args())
-    assert nl.native_blocks == before + 1
-    assert native == nl.format_block(*_block_args(), force_python=True)
+    before = getattr(nl, counter)
+    native = fmt(*args())
+    assert getattr(nl, counter) == before + 1
+    assert native == fmt(*args(), force_python=True)
     # a changed source is a different library name: rebuilt, not reused
     with open(d / "logemit.cpp", "a") as f:
         f.write("\n// edited\n")
@@ -202,13 +253,18 @@ def test_logemit_is_built_from_the_source_never_a_stale_binary(
     assert nl.ensure_built() and os.path.exists(nl.lib_path())
 
 
+@pytest.mark.parametrize("formatter", list(_FORMATTERS))
 def test_logemit_build_failure_is_reported_once_and_bytes_stay_identical(
-        logemit_sandbox, capfd):
+        logemit_sandbox, capfd, formatter):
     nl, d = logemit_sandbox
+    name, args, counter = _FORMATTERS[formatter]
+    fmt = getattr(nl, name)
     (d / "logemit.cpp").write_text("this is not C++\n")
-    py = nl.format_block(*_block_args(), force_python=True)
-    assert nl.format_block(*_block_args()) == py
-    assert nl.format_block(*_block_args()) == py
+    before = getattr(nl, counter)
+    py = fmt(*args(), force_python=True)
+    assert fmt(*args()) == py
+    assert fmt(*args()) == py
+    assert getattr(nl, counter) == before
     assert not nl.ensure_built()
     err = capfd.readouterr().err
     assert err.count("native log emitter unavailable") == 1

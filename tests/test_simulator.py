@@ -132,6 +132,74 @@ def test_cli_run_end_to_end(tmp_path):
     assert (tmp_path / "network_topology.gml").exists()
 
 
+def test_cli_run_emits_what_the_reference_loops_and_the_text_path_give(
+        tmp_path, capsys, monkeypatch):
+    """One `run` in this process: the emit layer formats from arrays and
+    summarises from the records, and every artifact is still what the loops
+    a peer and a receipt, and the summary parsed back from `latencies1`,
+    give on the same run."""
+    import json
+
+    import shadowlog_reference
+    from dst_libp2p_test_node_tpu import cli
+    from dst_libp2p_test_node_tpu.runtime.bandwidth import (
+        report as bandwidth_report, summarize_bandwidth)
+    from dst_libp2p_test_node_tpu.runtime.logemit import grep_lines
+    from dst_libp2p_test_node_tpu.runtime.summarize import (
+        report, summarize_file)
+
+    sims = []
+    real = Simulator.write_shadowlog
+
+    def remember(self, path):
+        sims.append(self)
+        return real(self, path)
+
+    monkeypatch.setattr(Simulator, "write_shadowlog", remember)
+    prefix = str(tmp_path) + os.sep
+    assert cli.main([
+        "run", "1", "60", "15000", "1", "3", "50", "150", "40", "130", "5",
+        "0.0", "4", "0", "1000", "--warmup-s", "20", "--seed", "5",
+        "--stats-json", "--out-prefix", prefix]) == 0
+    printed = capsys.readouterr().out
+    (sim,) = sims
+
+    # latencies1: a line a receipt, numbered by a count a peer
+    seen: dict[int, int] = {}
+    want = []
+    for rec in sim.records:
+        linenos = []
+        for p in rec.receivers.tolist():
+            seen[p] = seen.get(p, 0) + 1
+            linenos.append(seen[p])
+        want += grep_lines(rec.receivers, rec.msg_id, rec.delays_ms_int,
+                           np.array(linenos))
+    assert (tmp_path / "latencies1").read_text() == "".join(
+        ln + "\n" for ln in want)
+    assert (tmp_path / "shadowlog1").read_text() == (
+        shadowlog_reference.shadowlog_text(sim.traffic()))
+
+    s = summarize_file(prefix + "latencies1", large=True)
+    assert s.total_messages == 3 and s.coverage() == 60.0
+    assert sim.summary(True) == s
+    assert ("Summary for turn 1\n" + report(s, large=True)
+            + bandwidth_report(summarize_bandwidth(sim.traffic()))
+            + "[tpu backend] wall=") in printed
+    with open(prefix + "stats1.json") as f:
+        stats = json.load(f)
+    assert {k: stats[k] for k in (
+        "network_size", "coverage", "max_latency_ms", "avg_latency_ms",
+        "avg_max_latency_ms")} == {
+        "network_size": s.network_size, "coverage": s.coverage(),
+        "max_latency_ms": s.max_latency_ms,
+        "avg_latency_ms": s.avg_latency_ms,
+        "avg_max_latency_ms": s.avg_max_latency_ms}
+    # 180 and 60 lines are under NATIVE_MIN_LINES: the Python formatters
+    assert stats["emit"] == {
+        "latencies_lines": 180, "latencies_native_blocks": 0,
+        "shadowlog_lines": 60, "shadowlog_native_blocks": 0}
+
+
 def test_cli_run_lossy_loss_modes(tmp_path):
     # the run driver exposes the two loss models; at topogen -l 0.5 the
     # tcp default must keep full coverage (retransmission, not drops) and
