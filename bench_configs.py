@@ -229,7 +229,7 @@ def config_5():
     # 1M rung stays BOUNDED: at this scale the budgeted receiver-side
     # formulation carries the fixpoint and the bounded accounting is the
     # committed trade (error <= the exported answer_wait_max_ms bar); the
-    # exact default is the 100k-and-below story (config_4, bench.py)
+    # exact default is the 100k-and-below story (config_4)
     return _run_simple(5, 1_000_000, msg_size=15000, uses_mix=True, num_mix=128,
                 messages=2, warmup_s=30.0, serialize_answers=False)
 

@@ -6,7 +6,7 @@ shared across contracts — building them is pure numpy/host work plus a few
 tiny device constants; the audit itself never executes a registered
 entrypoint concretely (checkify mode excepted).
 
-The registered surface mirrors the BENCH hot paths exactly:
+The registered surface:
 
   disseminate/cold        serialized-answer publish (2 surviving conds: the
                           exact-mode repair branch plus the nested
@@ -31,12 +31,18 @@ The registered surface mirrors the BENCH hot paths exactly:
                           intentionally trades these conds for select_n —
                           that form is deliberately NOT registered with a
                           cond contract; see docs/ARCHITECTURE.md §9)
+  adversary/adaptive_window
+                          the adaptive attacker controller in the scan:
+                          the carry widens to (state, ctrl), both feeding
+                          the next window aval-stable; collective-free
   heartbeat_step/evict    the opt-in mesh-repair heartbeat (eviction +
                           PX-capture branches armed: 6 surviving conds)
   repair/recovery_window  the post-attack repair scan (ops/repair.py) with
                           the connection graph in the carry; checkified to
                           preserve the reverse-slot involution over the
                           mutated graph
+  faults/churn_window     the fault window, crash + partition + spike armed
+                          over an attacked mesh (UNBATCHED, collective-free)
   kad/find_node           the DHT lookup scan
   multitopic/disseminate  the T*N block-diagonal publish
   telemetry/recorded_heartbeats
@@ -68,17 +74,19 @@ The registered surface mirrors the BENCH hot paths exactly:
                           the fault-armed nested window: per-trial
                           crash/side/spike cohorts shard over both grid
                           axes like the attacker masks
+  campaign/attack_window_dcn
+                          the nested attack window on the three-level
+                          dcn x trials x peers mesh: GA-S006 proves zero
+                          collective bytes cross the dcn axis
   campaign/dht_attack_window
                           the cross-protocol recovery window
                           (ops/dht_adversary.py): repair armed, per-trial
                           poisoned discovery shortlists sharded over the
                           same nested grid and consumed by the redial path
-  heartbeat/fused_round   the fused mega-round scan (ISSUE 16): one scan
-                          over publish rounds, heartbeat burst + exact
-                          publish in the body — all 6 phase conds survive
-  native/score_update     the fused Pallas scoring-update kernel in
-                          interpret mode (the jaxpr carries the real
-                          pallas_call on every backend)
+  conformance/differential_round
+                          the compiled side of the spec-differential gate
+                          (analysis/conformance.py): heartbeat_step ->
+                          adversary_round, the 4 heartbeat conds surviving
   episub/heartbeat_step   one episub tree round (ISSUE 19, ops/episub.py):
                           eager tree push + lazy IHAVE repair + graylisted
                           re-parenting, thresholds armed — exactly 1
@@ -615,47 +623,6 @@ def _telemetry_attack_spec() -> TraceSpec:
                     telemetry=TelemetryParams(record=True)))
 
 
-def _fused_rounds_spec() -> TraceSpec:
-    import jax.numpy as jnp
-
-    from ..ops.disseminate import run_fused_rounds
-
-    # fused_rounds=True arms the mega-round scan (the disabled path is
-    # intentionally NOT registered here — it IS the phase-split chain's
-    # cache entries, already audited above)
-    g, params, state, a, (stage, lat, bw) = _single_topic(fused_rounds=True)
-    return TraceSpec(
-        fn=run_fused_rounds,
-        args=(state, a["conns"], a["rev"], stage, lat, bw, a["out_mask"],
-              jnp.arange(3, 6, dtype=jnp.int32)),
-        kwargs=dict(params=params, payload_bytes=15000, hb_per_round=2))
-
-
-@functools.lru_cache(maxsize=None)
-def _score_update_fn(params):
-    """One shared jitted wrapper per params: contract builds must return
-    the SAME callable so the second measure_retraces call is a pure cache
-    hit (a per-build closure would retrace by construction)."""
-    import jax
-
-    from ..native.score_update import score_update
-
-    return jax.jit(functools.partial(score_update, params=params,
-                                     interpret=True))
-
-
-def _score_update_spec() -> TraceSpec:
-    import jax.numpy as jnp
-
-    g, params, state, a, _ = _single_topic(slow_weight=-10.0)
-    n, c = params.n, params.capacity
-    fmd = (jnp.arange(n * c, dtype=jnp.float32).reshape(n, c) % 13) * 0.3
-    slow = (jnp.arange(n * c, dtype=jnp.float32).reshape(n, c) % 7) * 0.2
-    return TraceSpec(
-        fn=_score_update_fn(params),
-        args=(fmd, slow, 0.9, 0.8))
-
-
 def _kad_spec() -> TraceSpec:
     import jax.numpy as jnp
 
@@ -1097,35 +1064,6 @@ def default_contracts() -> list[EntrypointContract]:
             notes="attack window with the recorder armed via the static "
                   "telemetry kwarg — same cond census as the bare window; "
                   "the tel_* channels are pure reductions"),
-        EntrypointContract(
-            name="heartbeat/fused_round",
-            build=_fused_rounds_spec,
-            expected_conds=6,
-            feedback=[(_first_out, _state_arg_of)],
-            notes="the fused mega-round scan (ISSUE 16, ARCHITECTURE §18): "
-                  "one lax.scan over publish rounds whose body is the "
-                  "heartbeat burst + the exact publish — run_heartbeats' 4 "
-                  "steady-state skips plus disseminate/cold's 2 conds "
-                  "(repair + serial-certificate fallback) must all survive "
-                  "INSIDE the fused scan body; the returned state feeds the "
-                  "next call aval-stable, and the whole chain must stay one "
-                  "cache entry per shape (the disabled path literally IS "
-                  "the phase-split chain and is audited via its own "
-                  "contracts)"),
-        EntrypointContract(
-            name="native/score_update",
-            build=_score_update_spec,
-            expected_conds=None,
-            feedback=[(lambda out: out[0], lambda spec: spec.args[0]),
-                      (lambda out: out[1], lambda spec: spec.args[1])],
-            notes="the fused Pallas scoring-update kernel "
-                  "(native/score_update.py), traced in interpret mode so "
-                  "the audited jaxpr contains the real pallas_call on any "
-                  "backend; the decayed counters feed back aval-stable "
-                  "(they are the next round's inputs), and the XLA "
-                  "reference score_update_xla is the correctness target: "
-                  "counters bitwise, score to ulp-level FMA tolerance "
-                  "(tests/test_score_kernel.py)"),
         EntrypointContract(
             name="kad/find_node",
             build=_kad_spec,

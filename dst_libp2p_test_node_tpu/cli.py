@@ -49,12 +49,6 @@ Subcommands:
                JSON timeline, a per-round .npz and a CSV of every tel_*
                channel; --profile-dir additionally captures a jax.profiler
                trace around the run.
-  microbench — per-kernel roofline + Pallas block-size autotune harness
-               (runtime/microbench.py): measured walls + XLA cost analyses
-               over the entrypoint-contract registry, an explicit row-block
-               sweep over the native/ kernels (--install writes the winning
-               tuned.json), and the packed_state A/B verdict. Strict-JSON
-               artifact on stdout or --out.
 
 Usage:
   python -m dst_libp2p_test_node_tpu run 1 1000 15000 1 10 50 150 40 130 5 0.0 4 0 4000
@@ -1327,7 +1321,7 @@ def cmd_lint(argv: list[str]) -> int:
         else:
             pkg = os.path.dirname(os.path.abspath(__file__))
             targets = [pkg]
-            for extra in ("bench.py", "bench_configs.py", "scripts"):
+            for extra in ("bench_configs.py", "scripts"):
                 cand = os.path.join(repo_root, extra)
                 if os.path.exists(cand):
                     targets.append(cand)
@@ -1448,16 +1442,6 @@ def cmd_conform(argv: list[str]) -> int:
                     f"{e['sim_bugs']} sim_bug(s))"
         print(line, file=sys.stderr)
     return 0 if cert["clean"] else 1
-
-
-def cmd_microbench(argv: list[str]) -> int:
-    """Microbenchmark + autotune harness (runtime/microbench.py): roofline
-    coordinates per registered entrypoint, the Pallas row-block sweep, and
-    the packed_state A/B. Strict-JSON artifact, exit 0 on success."""
-    from .runtime.microbench import run
-
-    run(argv)
-    return 0
 
 
 def cmd_trace(argv: list[str]) -> int:
@@ -1628,8 +1612,6 @@ def main(argv: list[str] | None = None) -> int:
         return cmd_conform(rest)
     if cmd == "trace":
         return cmd_trace(rest)
-    if cmd == "microbench":
-        return cmd_microbench(rest)
     print(f"unknown command: {cmd}\n{__doc__}", file=sys.stderr)
     return 2
 

@@ -1,4 +1,2 @@
-"""Native acceleration surfaces: the C++ log-emitter source (logemit.cpp,
-built and loaded by runtime/native_logemit.py) and the two Pallas kernels
-(score_update.py, statically routed on TPU backends; vmem_gather.py,
-unrouted — the installed Mosaic does not lower it)."""
+"""The C++ log emitter only: logemit.cpp, built and loaded by
+runtime/native_logemit.py."""

@@ -1093,7 +1093,6 @@ def disseminate(
                 can_send, g_deliver, g_off, hb_phase, uplink, rx_const,
                 params.proc_delay_ms, params.heartbeat_ms, with_gossip,
                 lat_deliver=ld, ld_gossip=_ld_ans(frag_idx),
-                packed=params.packed_state,
             )
             with jax.named_scope("fixpoint"):
                 return converge_sharded(t0, c, params.max_relax_iters, mesh)
@@ -1110,7 +1109,6 @@ def disseminate(
                 can_send, g_deliver, g_off, hb_phase, uplink, rx_const,
                 params.proc_delay_ms, params.heartbeat_ms, with_gossip,
                 lat_deliver=ld, ld_gossip=_ld_ans(frag_idx),
-                packed=params.packed_state,
             )
             with jax.named_scope("fixpoint"):
                 return converge_recv(t0, c, params.max_relax_iters)
@@ -1176,7 +1174,7 @@ def disseminate(
                 conns, rev, lat_edge, tx_ms, rank, k_p, frag_idx, deliver,
                 can_send, g_tgt, g_off, hb_phase, uplink, rx_const,
                 params.proc_delay_ms, params.heartbeat_ms, False,
-                lat_deliver=ld, packed=params.packed_state,
+                lat_deliver=ld,
             )
             if formulation == "recv_sharded":
                 t_rx, _, _, _ = converge_sharded(
@@ -2014,260 +2012,3 @@ def disseminate(
         }
         return result, new_state, plan
     return result, new_state
-
-
-# ---------------------------------------------------------------------------
-# Fused mega-round scan (ISSUE 16, ARCHITECTURE §18): the whole
-# [heartbeat burst -> publish] round chain as ONE lax.scan over rounds.
-# ---------------------------------------------------------------------------
-
-def _fused_rounds_impl(state, ctrl, conns, rev, stage, lat_ms, bw, out_mask,
-                       publishers, loss_stage, lat_edge, loss_edge,
-                       ans_tables, valid_edge, censor_edge, attacker, crash,
-                       side, spike, params, payload_bytes, hb_per_round,
-                       fragments, with_gossip, loss_mode, batch_factor,
-                       adv, faults, telemetry):
-    # lazy imports: adversary/faults/telemetry all import heartbeat, which
-    # must not import disseminate back at module level (publisher.py
-    # precedent for breaking the cycle at the jit boundary)
-    from .heartbeat import _run_heartbeats
-
-    faulted = faults is not None and faults.enabled
-    attacked = attacker is not None and adv is not None
-    adaptive = attacked and adv.adaptive.enabled
-
-    def hb(s, c):
-        # Python-static composition switch: each branch calls the SAME
-        # inner runner the phase-split chain jits, so the per-round trace
-        # (hoists, carried degree, per-call deferred-decay materialization,
-        # PRNG splits) is the phase-split program inlined under the scan.
-        if faulted:
-            from .faults import _run_faulted_heartbeats
-
-            out, obs = _run_faulted_heartbeats(
-                s, conns, rev, out_mask, attacker, crash, side, spike,
-                params, adv, faults, hb_per_round, batch_factor, telemetry,
-                c)
-            return (out if adaptive else (out, c)) + (obs,)
-        if adaptive:
-            from .adversary import _run_adaptive_heartbeats
-
-            (s, c), obs = _run_adaptive_heartbeats(
-                s, c, conns, rev, out_mask, attacker, params, adv,
-                hb_per_round, batch_factor, telemetry)
-            return s, c, obs
-        if attacked:
-            from .adversary import _run_attacked_heartbeats
-
-            s, obs = _run_attacked_heartbeats(
-                s, conns, rev, out_mask, attacker, params, adv,
-                hb_per_round, batch_factor, telemetry)
-            return s, c, obs
-        if telemetry is not None:
-            from .telemetry import _run_recorded_heartbeats
-
-            s, obs = _run_recorded_heartbeats(
-                s, conns, rev, out_mask, params, telemetry, hb_per_round,
-                batch_factor)
-            return s, c, obs
-        return _run_heartbeats(
-            s, conns, rev, out_mask, params, hb_per_round), c, {}
-
-    def body(carry, pub):
-        s, c = carry
-        s, c, obs = hb(s, c)
-        res, s = disseminate(
-            s, conns, rev, stage, lat_ms, bw, publisher=pub, t0_ms=s.t_ms,
-            params=params, payload_bytes=payload_bytes, fragments=fragments,
-            with_gossip=with_gossip, loss_stage=loss_stage,
-            loss_mode=loss_mode, lat_edge=lat_edge, loss_edge=loss_edge,
-            ans_tables=ans_tables, valid_edge=valid_edge,
-            censor_edge=censor_edge)
-        return (s, c), (res, obs)
-
-    (state, ctrl), (results, obs) = jax.lax.scan(body, (state, ctrl),
-                                                 publishers)
-    return state, ctrl, results, obs
-
-
-_fused_rounds_jit = None
-
-
-def run_fused_rounds(state, conns, rev, stage, lat_ms, bw, out_mask,
-                     publishers, params, payload_bytes, hb_per_round,
-                     *, fragments=1, with_gossip=True, loss_stage=None,
-                     loss_mode="tcp", lat_edge=None, loss_edge=None,
-                     ans_tables=None, valid_edge=None, censor_edge=None,
-                     attacker=None, adv=None, ctrl=None, faults=None,
-                     crash=None, side=None, spike=None, telemetry=None,
-                     batch_factor=1):
-    """Run R = len(publishers) simulation rounds, each `hb_per_round`
-    heartbeats followed by one publish from `publishers[r]` at the carried
-    sim clock (t0_ms = state.t_ms, the bench chain's convention).
-
-    `params.fused_rounds=False` (the default) literally delegates: a host
-    loop over the SAME public per-phase entrypoints (run_heartbeats /
-    run_attacked_heartbeats / run_adaptive_heartbeats /
-    run_faulted_heartbeats / run_recorded_heartbeats, then disseminate)
-    with the same statics — same jit cache entries, zero retraces on a
-    warm call, zero extra PRNG splits, bit-identical outputs
-    (tests/test_fused_rounds.py pins all four).
-
-    `params.fused_rounds=True` fuses the whole chain into one lax.scan
-    over rounds — one device dispatch for the entire R-round run instead
-    of R x (phases) dispatches — by inlining the identical inner runners
-    under a single trace. Delivery outcomes (received / lost_tx /
-    answer_interleaved) stay bitwise equal to the phase-split chain; float
-    delay fields carry an rtol because XLA may re-fuse arithmetic inside
-    the scan body. Composition mirrors the delegating runners: a static
-    attacker rides via (attacker, adv), the adaptive controller widens the
-    carry via ctrl (defaulting to a fresh init_adaptive_ctrl), fault
-    cohorts via (faults, crash, side, spike), and armed telemetry joins
-    the per-round observables. Repair-inert params strip the 5 repair
-    leaves around the whole fused program, exactly like every runner.
-
-    Returns (state, results, obs) — results is a DisseminationResult whose
-    leaves are stacked (R, ...), obs maps observable channels to
-    (R, hb_per_round, ...) curves ({} when nothing is armed). With an
-    armed adv.adaptive the first element widens to (state, ctrl)."""
-    from .state import init_adaptive_ctrl, repair_inert, strip_repair
-
-    faulted = faults is not None and faults.enabled
-    attacked = attacker is not None and adv is not None
-    adaptive = attacked and adv.adaptive.enabled
-    if telemetry is not None and not telemetry.enabled:
-        telemetry = None
-    if (adv is None) != (attacker is None):
-        raise ValueError("attacker and adv arm together — pass both or "
-                         "neither")
-    if faulted and not attacked:
-        raise ValueError("faults compose on the attack window — pass "
-                         "attacker and adv (a zero-attacker cohort is fine)")
-    if ctrl is not None and not adaptive:
-        raise ValueError("ctrl given but adv.adaptive is disabled — the "
-                         "base runners carry none")
-    if adaptive and ctrl is None:
-        ctrl = init_adaptive_ctrl(params.n)
-
-    if not params.fused_rounds:
-        return _phase_split_rounds(
-            state, conns, rev, stage, lat_ms, bw, out_mask, publishers,
-            params, payload_bytes, hb_per_round, fragments, with_gossip,
-            loss_stage, loss_mode, lat_edge, loss_edge, ans_tables,
-            valid_edge, censor_edge, attacker, adv, ctrl, faults, crash,
-            side, spike, telemetry, batch_factor, adaptive, faulted,
-            attacked)
-
-    global _fused_rounds_jit
-    if _fused_rounds_jit is None:
-        _fused_rounds_jit = jax.jit(
-            _fused_rounds_impl,
-            static_argnames=("params", "payload_bytes", "hb_per_round",
-                             "fragments", "with_gossip", "loss_mode",
-                             "batch_factor", "adv", "faults", "telemetry"))
-    publishers = jnp.asarray(publishers, jnp.int32)
-    saved = None
-    if repair_inert(params):
-        # disseminate neither reads nor writes the repair leaves, so the
-        # heartbeat runners' host-side excision extends over the whole
-        # fused program
-        state, saved = strip_repair(state)
-    state, ctrl, results, obs = _fused_rounds_jit(
-        state, ctrl, conns, rev, stage, lat_ms, bw, out_mask, publishers,
-        loss_stage, lat_edge, loss_edge, ans_tables, valid_edge,
-        censor_edge, attacker, crash, side, spike, params, payload_bytes,
-        hb_per_round, fragments, with_gossip, loss_mode, batch_factor, adv,
-        faults, telemetry)
-    if saved is not None:
-        from .state import restore_repair
-
-        state = restore_repair(state, saved)
-    head = (state, ctrl) if adaptive else state
-    return head, results, obs
-
-
-def _phase_split_rounds(state, conns, rev, stage, lat_ms, bw, out_mask,
-                        publishers, params, payload_bytes, hb_per_round,
-                        fragments, with_gossip, loss_stage, loss_mode,
-                        lat_edge, loss_edge, ans_tables, valid_edge,
-                        censor_edge, attacker, adv, ctrl, faults, crash,
-                        side, spike, telemetry, batch_factor, adaptive,
-                        faulted, attacked):
-    """The pinned phase-split reference: per round, the public delegating
-    runner then disseminate — the literal pre-fusion program, dispatch for
-    dispatch, cache entry for cache entry."""
-    import numpy as np
-
-    # jit cache keys include the call signature: passing a kwarg explicitly
-    # at its default value is a DIFFERENT entry from omitting it, so only
-    # non-default options ride into the disseminate call — the bench/
-    # simulator chains' exact convention, which is what "same cache entry"
-    # must mean for the disabled path
-    dis_kw = {}
-    if fragments != 1:
-        dis_kw["fragments"] = fragments
-    if not with_gossip:
-        dis_kw["with_gossip"] = with_gossip
-    if loss_stage is not None:
-        dis_kw["loss_stage"] = loss_stage
-    if loss_mode != "tcp":
-        dis_kw["loss_mode"] = loss_mode
-    if lat_edge is not None:
-        dis_kw["lat_edge"] = lat_edge
-    if loss_edge is not None:
-        dis_kw["loss_edge"] = loss_edge
-    if ans_tables is not None:
-        dis_kw["ans_tables"] = ans_tables
-    if valid_edge is not None:
-        dis_kw["valid_edge"] = valid_edge
-    if censor_edge is not None:
-        dis_kw["censor_edge"] = censor_edge
-
-    results = []
-    obs_list = []
-    for pub in np.asarray(publishers, dtype=np.int32).tolist():
-        if faulted:
-            from .faults import run_faulted_heartbeats
-
-            out, obs = run_faulted_heartbeats(
-                state, conns, rev, out_mask, attacker, params, adv, faults,
-                crash, side, spike, hb_per_round, batch_factor, telemetry,
-                ctrl)
-            state, ctrl = out if adaptive else (out, ctrl)
-        elif adaptive:
-            from .adversary import run_adaptive_heartbeats
-
-            (state, ctrl), obs = run_adaptive_heartbeats(
-                state, conns, rev, out_mask, attacker, params, adv,
-                hb_per_round, ctrl=ctrl, batch_factor=batch_factor,
-                telemetry=telemetry)
-        elif attacked:
-            from .adversary import run_attacked_heartbeats
-
-            state, obs = run_attacked_heartbeats(
-                state, conns, rev, out_mask, attacker, params, adv,
-                hb_per_round, batch_factor, telemetry)
-        elif telemetry is not None:
-            from .telemetry import run_recorded_heartbeats
-
-            state, obs = run_recorded_heartbeats(
-                state, conns, rev, out_mask, params, hb_per_round,
-                telemetry, batch_factor)
-        else:
-            from .heartbeat import run_heartbeats
-
-            state = run_heartbeats(state, conns, rev, out_mask, params,
-                                   hb_per_round)
-            obs = {}
-        res, state = disseminate(
-            state, conns, rev, stage, lat_ms, bw, publisher=pub,
-            t0_ms=state.t_ms, params=params, payload_bytes=payload_bytes,
-            **dis_kw)
-        results.append(res)
-        obs_list.append(obs)
-    stacked = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *results)
-    obs = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *obs_list)
-    head = (state, ctrl) if adaptive else state
-    return head, stacked, obs
-
-

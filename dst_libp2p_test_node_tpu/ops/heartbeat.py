@@ -469,7 +469,7 @@ def run_heartbeats(
     mesh-repair leaves from the carry when no repair knob is armed — they
     are provably untouched then, and carrying them cost the r05 bench ~6
     passthrough buffers per segment (ops/state.py strip_repair). NOT
-    donated: callers (bench.py, tests) re-run segments from a kept state.
+    donated: callers (tests) re-run segments from a kept state.
     Jitted with static `steps` so repeated same-length segments (the
     simulator's inter-message gaps) hit the compile cache."""
     if repair_inert(params):

@@ -35,7 +35,6 @@ from dataclasses import dataclass, field
 from typing import Callable
 
 from .adversary import run_adaptive_heartbeats, run_attacked_heartbeats
-from .disseminate import run_fused_rounds
 from .faults import run_faulted_heartbeats
 from .heartbeat import run_heartbeats
 
@@ -62,9 +61,6 @@ class ProtocolSpec:
     run_attacked_heartbeats: Callable
     run_adaptive_heartbeats: Callable
     run_faulted_heartbeats: Callable
-    # round-chained publish driver; None = protocol has no fused-mode
-    # entrypoint (the campaign falls back to the phase-split chain)
-    run_fused_rounds: Callable | None = None
     # fresh per-protocol controller carry for one trial window, or None
     # when the protocol carries everything in SimState (GossipSub)
     init_ctrl: Callable | None = None
@@ -134,7 +130,6 @@ def _ensure_builtin() -> None:
         run_attacked_heartbeats=run_attacked_heartbeats,
         run_adaptive_heartbeats=run_adaptive_heartbeats,
         run_faulted_heartbeats=run_faulted_heartbeats,
-        run_fused_rounds=run_fused_rounds,
         init_ctrl=None,
         protocol_params=None,
         repair_hook="IHAVE/IWANT gossip + mesh repair (ops/repair.py)",
@@ -155,7 +150,6 @@ def _ensure_builtin() -> None:
         run_attacked_heartbeats=run_episub_attacked_heartbeats,
         run_adaptive_heartbeats=run_episub_adaptive_heartbeats,
         run_faulted_heartbeats=run_episub_faulted_heartbeats,
-        run_fused_rounds=None,
         init_ctrl=init_episub_ctrl,
         protocol_params=EpisubParams,
         repair_hook="lazy IHAVE along non-tree edges + re-parenting "

@@ -112,26 +112,6 @@ class SimParams:
     # everywhere — the reference implementation the prefix path is
     # bit/rtol-pinned against (tests/test_exact_prefix.py).
     answer_queue_mode: str = "parallel_prefix"
-    # Packed dissemination constants (ARCHITECTURE §6): store the per-edge
-    # RELATIVE cost tables of the receiver-side fixpoint formulation
-    # (parallel/exchange.py RecvConstants) as bf16 and fold the validity
-    # masks into the bf16 +inf sentinel, halving the memory-bound carry's
-    # HBM traffic on the budget/sharded dispatch paths. Absolute-time
-    # fields and the accounting fold stay f32 (bf16's 8-bit mantissa
-    # resolves only ~4 s at a 1e6 ms sim clock). OFF by default: the ~2 ms
-    # per-edge quantization is inside the bounded mode's error bar but
-    # breaks the exact mode's model-of-record bit guarantees.
-    packed_state: bool = False
-    # Fused mega-round scan (ARCHITECTURE §18): run the whole
-    # heartbeat-burst + publish round chain as ONE lax.scan over rounds —
-    # one device dispatch per round instead of one per phase. OFF by
-    # default: run_fused_rounds (ops/disseminate.py) literally delegates to
-    # the phase-split run_heartbeats + disseminate chain (same jit cache
-    # entries, zero retraces, zero extra PRNG splits, bit-identical). ON,
-    # the fused body calls the SAME per-phase programs under one trace, so
-    # delivery outcomes stay bitwise equal; float delays carry an rtol
-    # because XLA may re-fuse arithmetic inside the scan body.
-    fused_rounds: bool = False
     exclude_first_sender: bool = True   # don't forward back to the delivering peer
     idontwant_threshold_bytes: int = 1000  # go-test-node/main.go:165 (v1.2)
     churn_down_per_hb: float = 0.0  # P(alive peer dies) per heartbeat

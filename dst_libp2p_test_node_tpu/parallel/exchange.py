@@ -48,27 +48,19 @@ class RecvConstants:
 
     The fixpoint carry is memory-bound (ARCHITECTURE §6): every iteration
     streams these tables from HBM, so their byte width IS the iteration
-    cost at the 1M-peer shapes this formulation exists for. Two layout
-    decisions follow. (1) The two validity masks are packed into one int8
-    `flags` word per slot (bit 0 mesh, bit 1 gossip) — half the bool
-    traffic, bit-identical results. (2) With `packed=True` at build time
-    (SimParams.packed_state), the per-edge RELATIVE cost tables
-    (a_ms/g_ms/g_off/phase — values span a few thousand ms) are stored
-    bf16 and upcast in _inc_from, halving their traffic at a worst-case
-    quantization of ~2 ms per edge (bf16's 8-bit mantissa at the ~200 ms
-    edge scale), inside the bounded mode's exported error bar. The
-    ABSOLUTE-time fields (u_ms, rx_c, and the t vector itself) and the
-    accounting fold stay f32 unconditionally: the sim clock runs to ~1e6
-    ms, where a bf16 ulp is ~4 s."""
+    cost at the 1M-peer shapes this formulation exists for. So the two
+    validity masks are packed into one int8 `flags` word per slot (bit 0
+    mesh, bit 1 gossip) — half the bool traffic, bit-identical results.
+    Every time and cost table is float32: the sim clock runs to ~1e6 ms."""
 
     src: jnp.ndarray        # (N, C) int32 sender peer id (conns), -1 pad
-    a_ms: jnp.ndarray       # (N, C) f32/bf16 mesh-edge additive constant
+    a_ms: jnp.ndarray       # (N, C) float32 mesh-edge additive constant
     #                         (queue slot + latency; proc applies to the start)
-    g_ms: jnp.ndarray       # (N, C) f32/bf16 gossip additive constant
-    g_off: jnp.ndarray      # (N, C) f32/bf16 gossip-round heartbeat offset:
+    g_ms: jnp.ndarray       # (N, C) float32 gossip additive constant
+    g_off: jnp.ndarray      # (N, C) float32 gossip-round heartbeat offset:
     #                         the mcache window re-samples IHAVE targets each
     #                         heartbeat; this is (first round sampled) * hb_ms
-    phase: jnp.ndarray      # (N, C) f32/bf16 sender heartbeat phase
+    phase: jnp.ndarray      # (N, C) float32 sender heartbeat phase
     u_ms: jnp.ndarray       # (N, C) float32 sender uplink-free time: sends
     #                         start no earlier than this (cross-message
     #                         bandwidth contention, ops/state.py uplink_free_ms)
@@ -108,7 +100,6 @@ def build_recv_constants(
     with_gossip: bool,
     lat_deliver=None,
     ld_gossip=None,
-    packed: bool = False,
 ) -> RecvConstants:
     """Gather every sender-side term of ops/disseminate.offers through the
     reverse-slot map once, leaving a fixpoint that touches only t_rx.
@@ -118,11 +109,7 @@ def build_recv_constants(
     latency scaled by the TCP slow-start flight count plus the sampled
     retransmission stall (ops/disseminate loss_mode="tcp"). Additive edge
     constants, so they fold into a_ms/g_ms here and cost the fixpoint
-    nothing per iteration. Default to the bare lat_edge.
-
-    `packed`: store the relative cost tables bf16 (see RecvConstants) —
-    the unpacked build is the reference path the packed one is
-    tolerance-pinned against (tests/test_exchange.py)."""
+    nothing per iteration. Default to the bare lat_edge."""
     valid = (conns >= 0) & (rev >= 0)
     queue = (rank + 1.0 + frag_idx * k_p[:, None]) * tx_ms[:, None]
     if lat_deliver is None:
@@ -147,17 +134,12 @@ def build_recv_constants(
         jnp.broadcast_to(hb_phase[:, None], conns.shape), conns, rev)
     u_ms = _edge_gather(
         jnp.broadcast_to(uplink_free[:, None], conns.shape), conns, rev)
-    # relative cost tables only: bf16's exponent range carries the INF
-    # sentinel through as inf, and _inc_from's flag masks make the pad
-    # values dead anyway
-    store = ((lambda x: x.astype(jnp.bfloat16)) if packed
-             else (lambda x: x))
     return RecvConstants(
         src=jnp.where(valid, conns, -1),
-        a_ms=store(a_ms),
-        g_ms=store(g_ms),
-        g_off=store(g_off),
-        phase=store(phase),
+        a_ms=a_ms,
+        g_ms=g_ms,
+        g_off=g_off,
+        phase=phase,
         u_ms=u_ms,
         flags=(mesh_ok.astype(jnp.int8)
                | (g_ok.astype(jnp.int8) << 1)),
@@ -168,9 +150,7 @@ def build_recv_constants(
 
 
 # What `_src_gather` lowers to, stated once (chip_smoke.py prints it): the
-# plain XLA gather, on every backend. The Pallas VMEM kernel that used to
-# be probed for here does not compile on the installed toolchain
-# (native/vmem_gather.py has the compiler's message).
+# plain XLA gather, on every backend.
 SRC_GATHER = "xla"
 
 
@@ -182,27 +162,20 @@ def _src_gather(t_all: jnp.ndarray, src: jnp.ndarray) -> jnp.ndarray:
 
 
 def _inc_from(t_all: jnp.ndarray, c: RecvConstants) -> jnp.ndarray:
-    """Incoming offers of every receiver slot given the global t_rx.
-    Upcasts the (possibly bf16-packed) relative cost tables to f32 at the
-    registers — the arithmetic and the returned matrix are f32 either way;
-    packing only changes what streams from HBM."""
+    """Incoming offers of every receiver slot given the global t_rx."""
     t_src = _src_gather(t_all, c.src)
     live = (c.src >= 0) & (t_src < INF)
     mesh_ok = (c.flags & 1) > 0
     g_ok = (c.flags & 2) > 0
-    a_ms = c.a_ms.astype(jnp.float32)
-    g_ms = c.g_ms.astype(jnp.float32)
-    g_off = c.g_off.astype(jnp.float32)
-    phase = c.phase.astype(jnp.float32)
     base = t_src + c.proc_ms
     # a sender's queue can't start before its uplink drains earlier traffic
     start = jnp.maximum(base, c.u_ms)
-    inc = jnp.where(mesh_ok & live, start + a_ms, INF)
-    hb = (jnp.floor((base - phase) / c.hb_ms) + 1.0) * c.hb_ms + phase
+    inc = jnp.where(mesh_ok & live, start + c.a_ms, INF)
+    hb = (jnp.floor((base - c.phase) / c.hb_ms) + 1.0) * c.hb_ms + c.phase
     inc_g = jnp.where(
-        g_ok & live, jnp.maximum(hb + g_off, c.u_ms) + g_ms, INF)
-    # min with the sentinel: packed builds round INF up to bf16 inf, and
-    # inf-tainted arithmetic must not leak past the f32 sentinel the
+        g_ok & live, jnp.maximum(hb + c.g_off, c.u_ms) + c.g_ms, INF)
+    # min with the sentinel: a live offer whose sum overflowed (a sender
+    # time near INF plus its costs) must not leak past the f32 sentinel the
     # fixpoint (and strict-JSON export) reasons in
     return jnp.minimum(jnp.minimum(inc, inc_g), INF)
 

@@ -2,7 +2,7 @@
 
 A chip call starts on a fresh machine and a checkout is run many times, so
 whatever a program compiles should be found again by the next run in the
-same place. cli.main, chip_smoke.py, bench.py and bench_configs.py call
+same place. cli.main, chip_smoke.py and bench_configs.py call
 `enable_compile_cache()` before their first compile:
 
   - JAX_COMPILATION_CACHE_DIR set: JAX reads it itself; nothing is set here.

@@ -20,16 +20,15 @@ analyses:
                     retrace count; EntrypointContract.retrace_budget
                     (default 0) turns any excess into a tier-1 failure
                     (tests/test_profiling.py)
-  roofline          the strict-JSON per-entrypoint block bench.py merges
-                    into BENCH_r*.json detail: {flops, hbm_bytes,
-                    peak_memory_bytes, retraces, retrace_budget}
+  roofline          the strict-JSON per-entrypoint block: {flops,
+                    hbm_bytes, peak_memory_bytes, retraces,
+                    retrace_budget}
   chrome_trace      flight-recorder curves (ops/telemetry.py) rendered as
                     Chrome-trace/perfetto JSON — one "X" slice per
                     heartbeat with the channel values in args, plus "C"
                     counter tracks for the scalar channels
   profiler_trace    optional jax.profiler capture around a block (the
-                    `trace` CLI's --profile-dir and bench's
-                    BENCH_PROFILE_DIR use the same mechanism)
+                    `trace` CLI's --profile-dir)
   span / turn       the program's own host spans: `turn(...)` opens the
                     recorder of one `run` turn, `span(name)` notes a span
                     in it and opens a "sim:" TraceAnnotation, so a running
@@ -199,7 +198,7 @@ def roofline(contracts=None, with_retraces: bool = True,
     """The per-entrypoint roofline block: contract name -> {flops,
     hbm_bytes, peak_memory_bytes, retraces, retrace_budget} (strict-JSON
     safe; a contract that cannot lower on this backend reports an `error`
-    string instead of crashing the caller — bench must keep emitting).
+    string instead of crashing the caller).
 
     `name_prefix` restricts the sweep to contracts whose name starts with
     it (e.g. "disseminate/" for the publish-entrypoint CI artifact — the

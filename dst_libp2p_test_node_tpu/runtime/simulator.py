@@ -115,12 +115,6 @@ class ExperimentConfig:
     # "serial" = force the legacy global-sort outer iteration (the
     # reference engine the prefix path is bit/rtol-pinned against).
     answer_queue_mode: str = "parallel_prefix"
-    # Packed dissemination constants (SimParams.packed_state): bf16 per-edge
-    # cost tables + sentinel-folded validity masks on the receiver-side
-    # fixpoint paths (ARCHITECTURE §6). Off by default — the quantization
-    # is inside the bounded mode's error bar but breaks exact-mode bit
-    # guarantees.
-    packed_state: bool = False
     # Cross-publish warm-started fixpoints (SimParams.warm_start): seed
     # each publish's relaxation from the previous message's arrival
     # offsets, certified + cold-rerun-guarded so results stay bit-identical
@@ -309,7 +303,6 @@ class Simulator:
             churn_up_per_hb=cfg.churn_up_per_hb,
             serialize_answers=cfg.serialize_answers,
             answer_queue_mode=cfg.answer_queue_mode,
-            packed_state=cfg.packed_state,
             warm_start=cfg.warm_start,
         )
         self.state = init_state(self.params, seed=cfg.seed)

@@ -9,13 +9,12 @@ against the single-process nested campaign on the SAME total work
   - merged observables must be IDENTICAL field-for-field (wall-clock
     excluded): the DCN boundary moves placement, never numerics;
   - scaling efficiency = dcn_trials_per_s / single_trials_per_s is
-    reported for the bench probe's pre-emit gate (same device count on
+    reported (same device count on
     both sides, so 1.0 is the ideal and the process split + rank merge is
     the only overhead being measured).
 
 The launcher writes one strict-JSON result file (--out) consumed by
-bench.py's dcn_trials_per_s probe, tests/test_dcn_smoke.py and the CI
-smoke job.
+tests/test_dcn_smoke.py and the CI smoke job.
 
 Run:  python scripts/dcn_campaign.py --out /tmp/dcn.json
       python scripts/dcn_campaign.py --worker I ... (internal: one rank)

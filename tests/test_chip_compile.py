@@ -6,8 +6,6 @@ would refuse on the machine with the chip, it refuses in this file, at no
 chip time. Shapes are the 100,000-peer headline config's
 (`chip_smoke.py` phase 2). Cases:
 
-  - native/score_update.py — the Pallas kernel `score_update_best` routes to
-    on a TPU backend — at (N, capacity);
   - the gather `parallel/exchange._src_gather` lowers to (the plain XLA
     gather: `exchange.SRC_GATHER`), also in the vmapped fragment form;
   - the sharded fixpoint `converge_sharded` on a 4-chip peer mesh: the
@@ -81,21 +79,6 @@ def _device_bytes(compiled) -> int:
     ma = compiled.memory_analysis()
     return (ma.argument_size_in_bytes + ma.output_size_in_bytes
             + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
-
-
-def test_score_update_kernel_compiles_for_v5e(one_chip, capacity):
-    import functools
-
-    from dst_libp2p_test_node_tpu.native.score_update import score_update
-    from dst_libp2p_test_node_tpu.ops.state import SimParams
-
-    params = SimParams(n=N, capacity=capacity, slow_weight=-10.0)
-    compiled = jax.jit(functools.partial(score_update, params=params)).lower(
-        one_chip((N, capacity), jnp.float32),
-        one_chip((N, capacity), jnp.float32),
-        one_chip((), jnp.float32), one_chip((), jnp.float32)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-    assert _device_bytes(compiled) < V5E_HBM_BYTES
 
 
 @pytest.mark.parametrize("fragments", [None, 4])

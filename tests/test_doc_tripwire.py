@@ -7,6 +7,8 @@ quoted numbers and fails on mismatch, so a model/benchmark change cannot
 ship without its doc lines.
 """
 
+import ast
+import functools
 import glob
 import json
 import os
@@ -99,20 +101,44 @@ def test_validity_doc_matches_anchor_artifact():
         "VALIDITY.md max must quote the artifact", anchor["max_ms"])
 
 
+_RATE = re.compile(
+    r"\d[\d.,]*\s*[kM]?\s*(?:simulated\s+)?peers?\s*[×x*·-]\s*"
+    r"(?:heartbeat-)?rounds", re.I)
+# `runsh-100k.headline`, `attack-2k.sybil`: <configuration>.<traffic mix>
+_CELL = re.compile(r"`([a-z]+-\d+[km]?(?:-[a-z0-9]+)*\.[a-z]+)`")
+_FILE_SUFFIXES = ("json", "jsonl", "py", "md", "yaml", "npz", "csv", "gml")
+
+
+def _outside_ladder(readme: str) -> str:
+    """README less "The five scaling configs": that section's table is a
+    copy of BENCH_CONFIGS.json, rate column included, held to it by
+    test_readme_config_table_matches_artifact (pre-chip rows, says its note)."""
+    head, _, rest = readme.partition("## The five scaling configs")
+    return head + rest[rest.index("\n## "):]
+
+
 def test_metric_of_record_quote_matches_artifact():
-    # README/PARITY quote the single-chip peers*rounds/s headline; it must
-    # be the committed bench output (docs/BENCH_LOCAL_r5.json), same drift
-    # class as the ladder table
-    with open(os.path.join(ROOT, "docs", "BENCH_LOCAL_r5.json")) as f:
-        bench = json.load(f)
-    want = f"{bench['value'] / 1e6:.1f}M"
+    # The speed of record is the driver's ledger, in seconds an experiment
+    # a cell. README and PARITY therefore quote NO peer-rounds/s figure (the
+    # pre-chip "13.8M" survived in both for thirty PRs because this test
+    # used to require it), and every cell they name is one BENCHMARK.json
+    # declares.
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        cells = {w["name"] for w in json.load(f)["workloads"]}
     for name in ("README.md", "PARITY.md"):
         doc = _read(name)
-        m = re.search(r"(\d+\.\d)M\s*\n?\s*peer", doc)
-        assert m, f"{name} must quote the metric-of-record as '<n.n>M peer…'"
-        assert f"{m[1]}M" == want, (
-            f"{name} quotes {m[1]}M peers*rounds/s; committed bench artifact "
-            f"says {want} — update the doc")
+        scanned = _outside_ladder(doc) if name == "README.md" else doc
+        m = _RATE.search(scanned)
+        assert not m, (
+            f"{name} quotes a peer-rounds/s figure ({m[0]!r}): speeds are "
+            "ledger lines with their origin, or 'not measured'")
+        named = {c for c in _CELL.findall(doc)
+                 if c.rsplit(".", 1)[1] not in _FILE_SUFFIXES}
+        assert named <= cells, (
+            f"{name} names cells BENCHMARK.json does not declare: "
+            f"{sorted(named - cells)}")
+    assert _CELL.findall(_read("README.md")), \
+        "README must state its speed by cell (a ledger line)"
 
 
 def test_validity_doc_matches_second_anchor_artifact():
@@ -146,18 +172,17 @@ def test_validity_muxer_sensitivity_quotes_match_artifact():
 
 
 def test_readme_delivery_mode_quotes_match_bench_artifact():
-    # README's delivery-modes section quotes the exact/bounded publish
-    # costs and the bounded-mode error bar; pin them to the bench artifact
-    with open(os.path.join(ROOT, "docs", "BENCH_LOCAL_r5.json")) as f:
-        det = json.load(f)["detail"]
+    # README's delivery-modes section used to quote the exact/bounded
+    # publish walls of a CPU bench artifact. No cell runs the bounded mode,
+    # so until one does the section must say so, not quote a cost.
     readme = _read("README.md")
-    m = re.search(r"([\d.]+) s/publish vs ([\d.]+) s bounded", readme)
-    assert m, "README must quote '<exact> s/publish vs <bounded> s bounded'"
-    assert float(m[1]) == pytest.approx(det["publish_exact_s"], abs=0.0051)
-    assert float(m[2]) == pytest.approx(det["publish_full_s"], abs=0.0051)
-    m = re.search(r"([\d.]+) ms at the bench shape", readme)
-    assert m, "README must quote the bounded-mode error bar"
-    assert float(m[1]) == pytest.approx(det["answer_wait_max_ms"], abs=0.051)
+    section = readme.partition("## Delivery-fidelity modes")[2]
+    section = section[:section.index("\n## ")]
+    assert "**bounded**" in section
+    assert "not measured on a chip" in section, (
+        "README's delivery-modes section must state the bounded mode's "
+        "cost as 'not measured on a chip'")
+    assert not re.search(r"[\d.]+ s/publish", section)
 
 
 def test_readme_loss_tail_matches_artifact():
@@ -208,3 +233,110 @@ def test_readme_delivery_mode_labels_match_bench_configs():
         assert labeled[c] == mode, (
             f"README labels config {c} as {labeled[c]}; committed "
             f"BENCH_CONFIGS.json row says {mode} — update the doc")
+
+
+# ------------------------------------------------- names that must exist --
+
+PKG = os.path.join(ROOT, "dst_libp2p_test_node_tpu")
+_PKG_DIRS = ("ops", "runtime", "native", "parallel", "config", "analysis")
+_ROOT_DIRS = ("dst_libp2p_test_node_tpu", "tests", "scripts", "docs",
+              "benchmark", "deploy")
+# the reference's own files, which the documents cite by line
+_REFERENCE_FILES = {"topogen.py", "traffic_sync.py"}
+_TOKEN = re.compile(r"`([^`\n]+)`")
+_PATHLIKE = re.compile(r"^(?:[\w.-]+/)*[\w.-]+$")
+_RECORD = re.compile(r"^[A-Z][A-Z0-9_]*(?:_r\d+)?\.jsonl?$")
+
+
+@functools.lru_cache(maxsize=None)
+def _every_py() -> frozenset[str]:
+    """Names of the Python files of the checkout's own directories (not of
+    whatever else lies beside them: scratch copies, build outputs)."""
+    names = {os.path.basename(f) for f in glob.glob(os.path.join(ROOT, "*.py"))}
+    for top in _ROOT_DIRS:
+        for _, _, files in os.walk(os.path.join(ROOT, top)):
+            names.update(f for f in files if f.endswith(".py"))
+    return frozenset(names)
+
+
+def _exists(base: str, path: str) -> bool:
+    """`path` under `base`: a file, a directory, or `<module>.<attribute>`
+    of a module that exists and spells the attribute."""
+    full = os.path.join(base, path)
+    if os.path.exists(full):
+        return True
+    module, dot, attr = full.rpartition(".")
+    if dot and os.path.isfile(module + ".py"):
+        with open(module + ".py") as f:
+            return re.search(rf"\b{re.escape(attr)}\b", f.read()) is not None
+    return False
+
+
+def _missing_paths(doc: str) -> list[str]:
+    every_py = _every_py()
+    missing = []
+    for tok in _TOKEN.findall(_read(doc)):
+        if any(c in tok for c in "<*{…$"):
+            continue        # a pattern or a placeholder, not one path
+        path = re.split(r"\s|::|:", tok.strip())[0].rstrip(".,;)")
+        if not _PATHLIKE.match(path):
+            continue
+        head = path.split("/")[0]
+        if "/" not in path:
+            if path.endswith(".py"):
+                ok = path in every_py or path in _REFERENCE_FILES
+            elif _RECORD.match(path):
+                ok = (os.path.exists(os.path.join(ROOT, path))
+                      or os.path.exists(os.path.join(ROOT, "docs", path)))
+            else:
+                continue    # an artifact a run writes, a word
+        elif head in _PKG_DIRS:
+            ok = _exists(PKG, path)
+        elif head in _ROOT_DIRS:
+            ok = _exists(ROOT, path)
+        else:
+            continue
+        if not ok:
+            missing.append(tok)
+    return sorted(set(missing))
+
+
+@pytest.mark.parametrize("doc", [
+    "README.md", "PARITY.md", os.path.join("docs", "ARCHITECTURE.md"),
+    "PERF.md", os.path.join("docs", "VALIDITY.md")])
+def test_every_repository_path_a_document_names_exists(doc):
+    # a deleted module, script or record that a document still points at
+    # (`bench.py` and its BENCH_r*.json outlived their use by thirty PRs)
+    assert _missing_paths(doc) == []
+
+
+def _dispatched_subcommands() -> set[str]:
+    with open(os.path.join(PKG, "cli.py")) as f:
+        tree = ast.parse(f.read())
+    main = next(n for n in tree.body
+                if isinstance(n, ast.FunctionDef) and n.name == "main")
+    return {
+        n.comparators[0].value for n in ast.walk(main)
+        if isinstance(n, ast.Compare) and isinstance(n.left, ast.Name)
+        and n.left.id == "cmd" and isinstance(n.ops[0], ast.Eq)
+        and isinstance(n.comparators[0], ast.Constant)}
+
+
+def test_subcommands_in_readme_and_cli_docstring_are_dispatched(capsys):
+    from dst_libp2p_test_node_tpu import cli
+
+    dispatched = _dispatched_subcommands()
+    assert {"run", "topogen", "lint"} <= dispatched
+    listed = set(re.findall(r"^  (\w+)\s+— ", cli.__doc__, re.M))
+    assert listed == dispatched, (
+        "cli.py's docstring lists", sorted(listed ^ dispatched),
+        "differently from what main dispatches")
+    invoked = set()
+    for block in re.findall(r"```(?:sh|bash)?\n(.*?)```", _read("README.md"),
+                            re.S):
+        invoked.update(re.findall(
+            r"python3? -m dst_libp2p_test_node_tpu(?:\.cli)? ([a-z]+)", block))
+    assert invoked and invoked <= dispatched, sorted(invoked - dispatched)
+    # a deleted subcommand is the unknown-command exit, not a stub
+    assert cli.main(["microbench"]) == 2
+    assert "unknown command: microbench" in capsys.readouterr().err

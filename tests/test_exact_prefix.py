@@ -5,9 +5,8 @@ The parallel-prefix answer-queue refinement (SimParams.answer_queue_mode
 ("serial", the pre-prefix model of record) on every result surface: bitwise
 on the integer counters and delivery masks, to float tolerance on arrival
 times, with the exactness certificate (converged=True) and a bounded pass
-count. The packed dissemination state (SimParams.packed_state) and the
-Pallas VMEM-gather kernel body (native/vmem_gather.py) are the two
-satellite fronts pinned here too.
+count. The receiver-side constants' layout and the fixpoint's hot gather
+(parallel/exchange.py) are pinned here too.
 """
 
 import dataclasses
@@ -384,7 +383,7 @@ def test_refine_passes_zero_when_untriggered():
     assert bool(np.asarray(res.converged))
 
 
-# ---------------------------------------------------------------- packed --
+# ---------------------------------------------------------- recv constants --
 
 
 def _recv_scenario(seed=0):
@@ -415,116 +414,41 @@ def _recv_scenario(seed=0):
     uplink = jnp.zeros((n,), jnp.float32)
     rx_const = jnp.zeros((n,), jnp.float32)
 
-    def build(packed):
-        return build_recv_constants(
-            conns, rev, lat_edge, tx_ms, rank, k_p, 0.0, send_mask,
-            jnp.ones((n,), bool), g_tgt, g_off, hb_phase, uplink, rx_const,
-            2.0, 1000.0, True, packed=packed)
-
+    c = build_recv_constants(
+        conns, rev, lat_edge, tx_ms, rank, k_p, 0.0, send_mask,
+        jnp.ones((n,), bool), g_tgt, g_off, hb_phase, uplink, rx_const,
+        2.0, 1000.0, True)
     t0 = jnp.full((n,), 3.4e38, jnp.float32).at[0].set(0.0)
-    return build, t0
+    return c, t0
 
 
-def test_packed_recv_constants_layout_and_tolerance():
+def test_recv_constants_layout():
     from dst_libp2p_test_node_tpu.parallel.exchange import converge_recv
 
-    build, t0 = _recv_scenario()
-    c_ref = build(False)
-    c_pk = build(True)
-    # layout contract (ARCHITECTURE §6): relative cost tables drop to
-    # bf16, the two validity booleans pack into one int8 flags word in
-    # BOTH layouts, and every absolute-time field stays f32 (bf16's ulp
-    # at a 1e6 ms clock is ~4 s — packing those would corrupt times)
-    for f in ("a_ms", "g_ms", "g_off", "phase"):
-        assert getattr(c_pk, f).dtype == jnp.bfloat16
-        assert getattr(c_ref, f).dtype == jnp.float32
-    for c in (c_ref, c_pk):
-        assert c.flags.dtype == jnp.int8
-        assert c.u_ms.dtype == jnp.float32
-        assert c.rx_c.dtype == jnp.float32
-    t_ref, _, conv_ref, _ = converge_recv(t0, c_ref, 64)
-    t_pk, _, conv_pk, _ = converge_recv(t0, c_pk, 64)
-    assert bool(conv_ref) and bool(conv_pk)
-    ref = np.asarray(t_ref)
-    pk = np.asarray(t_pk)
-    ok = ref < 1e30
-    np.testing.assert_array_equal(ok, pk < 1e30)
-    # bf16 relative tables quantize each edge cost by <= ~0.4% (8 mantissa
-    # bits); a handful of hops compounds to small-ms drift, never seconds
-    np.testing.assert_allclose(pk[ok], ref[ok], rtol=1e-2, atol=25.0)
+    c, t0 = _recv_scenario()
+    # layout contract (ARCHITECTURE §6): the two validity booleans pack
+    # into one int8 flags word and every time and cost table is f32 (the
+    # exact mode's bit guarantees, and the sharded/single-shard bitwise
+    # pins in test_exchange, are stated over this layout)
+    for f in ("a_ms", "g_ms", "g_off", "phase", "u_ms", "rx_c"):
+        assert getattr(c, f).dtype == jnp.float32
+    assert c.flags.dtype == jnp.int8
+    assert set(np.unique(np.asarray(c.flags))) <= {0, 1, 2}
+    t_rx, _, converged, _ = converge_recv(t0, c, 64)
+    assert bool(converged)
+    assert (np.asarray(t_rx) < 1e30).all()
 
 
-def test_packed_state_rides_receiver_side_path(monkeypatch):
-    # end-to-end wiring: SimParams.packed_state reaches the receiver-side
-    # constant formulation (the budget path the 1M rung runs). Shrink the
-    # budget so the small shape compiles through that branch, then compare
-    # packed vs unpacked delays within the quantization tolerance.
-    import dst_libp2p_test_node_tpu.ops.pull as pull_mod
-
-    n = 103
-    g, params, state, a, topo = mesh_setup(
-        n=n, serialize_answers=False)
-    stage, lat, bw = topo
-    kw = dict(publisher=7, t0_ms=float(state.t_ms),
-              payload_bytes=15000, with_gossip=True)
-    monkeypatch.setattr(pull_mod, "_MAX_INTERMEDIATE_BYTES", 1)
-    disseminate.clear_cache()
-    try:
-        res_ref, _ = disseminate(
-            state, a["conns"], a["rev"], stage, lat, bw,
-            params=params, **kw)
-        res_pk, _ = disseminate(
-            state, a["conns"], a["rev"], stage, lat, bw,
-            params=dataclasses.replace(params, packed_state=True), **kw)
-    finally:
-        monkeypatch.undo()
-        disseminate.clear_cache()
-    np.testing.assert_array_equal(
-        np.asarray(res_ref.received), np.asarray(res_pk.received))
-    ok = np.asarray(res_ref.received)
-    np.testing.assert_allclose(
-        np.asarray(res_pk.delay_ms)[ok],
-        np.asarray(res_ref.delay_ms)[ok], rtol=1e-2, atol=25.0)
-
-
-def test_packed_state_default_off_preserves_bit_exactness():
-    # packed=False must be the default: the exact mode's bit-equality
-    # guarantees (and the sharded/single-shard bitwise pins in
-    # test_exchange) are stated over the f32 layout
-    assert SimParams(n=8, capacity=4).packed_state is False
-    g, params, state, a, topo = mesh_setup(n=64, connect_to=6)
-    res_a, _ = _publish(state, a, topo, params)
-    res_b, _ = _publish(state, a, topo, params)
-    np.testing.assert_array_equal(
-        np.asarray(res_a.delay_ms), np.asarray(res_b.delay_ms))
-
-
-# ---------------------------------------------------------------- pallas --
-
-
-def test_vmem_gather_interpret_matches_reference():
-    # the kernel body itself, run under Pallas interpret mode (no Mosaic):
-    # out[q, j] = t[max(src[q, j], 0)], pad slots clipped to row 0
-    from dst_libp2p_test_node_tpu.native.vmem_gather import vmem_gather
-
-    rng = np.random.default_rng(0)
-    for n, cap in ((64, 5), (30, 7)):
-        t = jnp.asarray(rng.uniform(0.0, 1e6, size=n).astype(np.float32))
-        src = rng.integers(-1, n, size=(n, cap)).astype(np.int32)
-        got = vmem_gather(t, jnp.asarray(src), interpret=True)
-        want = np.asarray(t)[np.clip(src, 0, None)]
-        np.testing.assert_array_equal(np.asarray(got), want)
+# ---------------------------------------------------------------- gather --
 
 
 def test_src_gather_is_statically_the_xla_gather(monkeypatch):
     # the gather is decided by what exchange.py states, not by a probe or
     # an environment switch: no pallas_call in its trace, whatever the
     # retired DST_PALLAS_GATHER says, and no capability function left
-    from dst_libp2p_test_node_tpu.native import vmem_gather as vg
     from dst_libp2p_test_node_tpu.parallel import exchange
 
     assert exchange.SRC_GATHER == "xla"
-    assert not hasattr(vg, "gather_kernel_available")
     t = jnp.zeros((128,), jnp.float32)
     src = jnp.zeros((128, 6), jnp.int32)
     for env in ("0", "1"):

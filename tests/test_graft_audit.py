@@ -53,7 +53,7 @@ def _rules_of(violations):
 
 def test_repo_ast_surface_is_clean():
     targets = [str(REPO / "dst_libp2p_test_node_tpu"),
-               str(REPO / "bench.py"), str(REPO / "bench_configs.py"),
+               str(REPO / "bench_configs.py"),
                str(REPO / "scripts")]
     violations, checked = lint_paths(targets, str(REPO))
     assert checked > 30, "lint walked suspiciously few files"

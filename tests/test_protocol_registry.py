@@ -24,7 +24,6 @@ from dst_libp2p_test_node_tpu.ops.adversary import (
     AdversaryParams,
     attacker_cohort,
 )
-from dst_libp2p_test_node_tpu.ops.disseminate import run_fused_rounds
 from dst_libp2p_test_node_tpu.ops.faults import FaultParams, fault_masks
 from dst_libp2p_test_node_tpu.ops.graph import build_connection_graph
 from dst_libp2p_test_node_tpu.ops.protocol import (
@@ -70,7 +69,6 @@ def test_gossipsub_spec_fields_are_the_module_runner_objects():
     assert spec.run_attacked_heartbeats is adv_mod.run_attacked_heartbeats
     assert spec.run_adaptive_heartbeats is adv_mod.run_adaptive_heartbeats
     assert spec.run_faulted_heartbeats is faults_mod.run_faulted_heartbeats
-    assert spec.run_fused_rounds is run_fused_rounds
     assert spec.init_ctrl is None and spec.protocol_params is None
 
 

@@ -45,9 +45,6 @@ ALLOWLIST = {
     "ops/dht_adversary": (
         "DHT adversary masks are compiled only inside the campaign window "
         "— campaign/dht_attack_window traces them transitively"),
-    "runtime/microbench": (
-        "the autotune harness jits ad-hoc probe kernels to MEASURE "
-        "candidates; they are never production entrypoints"),
     "runtime/profiling": (
         "lower_spec's jit wrapper is the audit machinery itself — it "
         "compiles OTHER contracts, it is not an entrypoint"),
