@@ -252,6 +252,8 @@ def cmd_run(argv: list[str]) -> int:
     t = None
     for i in range(1, int(a.runs) + 1):
         with turn(seed=a.seed + i - 1, turn=i) as spans:
+            # what this turn's `shadow.yaml` took (no file written: zeros)
+            artifacts = {"yaml_hosts_dumped": 0, "yaml_alias_lines": 0}
             if i == 1 and a.gml:
                 # run an existing experiment dir: link properties come from
                 # the GML (stage latencies/bandwidths), peers/messages from
@@ -269,7 +271,7 @@ def cmd_run(argv: list[str]) -> int:
                 with span("run/write_gml"):
                     t.write_gml(a.out_prefix + "network_topology.gml")
                 with span("run/write_yaml"):
-                    t.write_shadow_yaml(a.out_prefix + "shadow.yaml")
+                    artifacts = t.write_shadow_yaml(a.out_prefix + "shadow.yaml")
             large = topo.msg_size_bytes >= 1000
             print(f"Running for turn {i}")
             cfg = ExperimentConfig(
@@ -352,6 +354,9 @@ def cmd_run(argv: list[str]) -> int:
                             # many blocks of each the native formatter took
                             # (0: the Python one wrote them)
                             "emit": sim.emit_counts,
+                            # hosts PyYAML wrote into `shadow.yaml` and
+                            # alias lines joined beside them
+                            "artifacts": artifacts,
                             "publishes": [
                                 {"fast_iters": r.fast_iters,
                                  "refine_passes": r.refine_passes,

@@ -17,6 +17,10 @@ stage[q]]`, a 2-gather, no N x N materialization at any scale.
 We both *emit* network_topology.gml + shadow.yaml (same schema, so existing
 Shadow tooling can consume our configs) and *ingest* a GML produced by the
 reference topogen (so `SIMBACKEND=tpu` can run an existing experiment dir).
+`shadow.yaml` is written without a YAML node a peer: PyYAML spells a document
+of constant size and the other hosts are alias lines joined as text
+(`write_shadow_yaml`); its bytes are those of PyYAML's dump of the whole
+`shadow_config()`, kept as `tests/shadow_yaml_reference.py`, the contract.
 """
 
 from __future__ import annotations
@@ -156,6 +160,12 @@ class Topology:
 
     def shadow_config(self) -> dict:
         """shadow.yaml dict in the reference schema (topogen.py:74-136)."""
+        return self._shadow_document(self.params.network_size)
+
+    def _shadow_document(self, listed_peers: int) -> dict:
+        """The schema, with hosts pod-0 .. pod-(listed_peers - 1) and the
+        injector pod-N: all N peers is the file (`shadow_config`), the first
+        min(N, 2S) is what `write_shadow_yaml` lets PyYAML spell."""
         p = self.params
         node_env = {
             "PEERS": str(p.network_size),
@@ -174,7 +184,7 @@ class Topology:
                     {"path": "./main", "start_time": "5s", "environment": dict(node_env)}
                 ],
             }
-        for i in range(p.network_size):
+        for i in range(listed_peers):
             hosts[f"pod-{i}"] = stage_host[i % self.n_stages]
         controller_args = (
             f"../../../traffic_sync.py -s {p.msg_size_bytes} -m {p.messages} "
@@ -203,11 +213,38 @@ class Topology:
             "hosts": hosts,
         }
 
-    def write_shadow_yaml(self, path: str = YAML_FILE) -> None:
+    def write_shadow_yaml(self, path: str = YAML_FILE) -> dict:
+        """Write the bytes of PyYAML's dump of `shadow_config()` (block
+        style, keys as inserted) without a YAML node a peer; returns how
+        many hosts PyYAML wrote and how many alias lines were joined
+        (`stats<i>.json` "artifacts").
+
+        PyYAML spells the head, the hosts pod-0 .. pod-(min(N, 2S) - 1) and
+        the injector: with two occurrences of every stage host it has
+        assigned every anchor it ever would. Hosts pod-2S .. pod-(N-1) are
+        alias lines, each with the anchor read off the line PyYAML wrote for
+        pod-(S + i % S), placed before the injector's entry."""
         import yaml
 
+        n, s = self.params.network_size, self.n_stages
+        dumped = min(n, 2 * s)
+        text = yaml.dump(self._shadow_document(dumped),
+                         default_flow_style=False, sort_keys=False)
+        aliases = injector = ""
+        if n > dumped:
+            cut = text.rindex(f"\n  pod-{n}:\n") + 1
+            text, injector = text[:cut], text[cut:]
+            refs = []
+            for i, line in enumerate(text.splitlines()[-s:], start=s):
+                key, _, ref = line.partition(": ")
+                if key != f"  pod-{i}" or not ref.startswith("*"):
+                    raise AssertionError(f"not an alias line of pod-{i}: {line!r}")
+                refs.append(ref)
+            aliases = "".join(
+                f"  pod-{i}: {refs[i % s]}\n" for i in range(dumped, n))
         with open(path, "w") as f:
-            yaml.dump(self.shadow_config(), f, default_flow_style=False, sort_keys=False)
+            f.writelines((text, aliases, injector))
+        return {"yaml_hosts_dumped": dumped + 1, "yaml_alias_lines": n - dumped}
 
     # ----------------------------------------------------------------- ingest
 

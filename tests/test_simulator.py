@@ -140,6 +140,7 @@ def test_cli_run_emits_what_the_reference_loops_and_the_text_path_give(
     give on the same run."""
     import json
 
+    import shadow_yaml_reference
     import shadowlog_reference
     from dst_libp2p_test_node_tpu import cli
     from dst_libp2p_test_node_tpu.runtime.bandwidth import (
@@ -194,6 +195,13 @@ def test_cli_run_emits_what_the_reference_loops_and_the_text_path_give(
         "max_latency_ms": s.max_latency_ms,
         "avg_latency_ms": s.avg_latency_ms,
         "avg_max_latency_ms": s.avg_max_latency_ms}
+    # shadow.yaml: the whole-document dump's bytes, 50 of its hosts joined
+    # as alias lines
+    shadow_yaml_reference.write_shadow_yaml(sim.topology, prefix + "ref.yaml")
+    assert (tmp_path / "shadow.yaml").read_bytes() == (
+        tmp_path / "ref.yaml").read_bytes()
+    assert stats["artifacts"] == {
+        "yaml_hosts_dumped": 11, "yaml_alias_lines": 50}
     # 180 and 60 lines are under NATIVE_MIN_LINES: the Python formatters
     assert stats["emit"] == {
         "latencies_lines": 180, "latencies_native_blocks": 0,

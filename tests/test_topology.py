@@ -1,6 +1,10 @@
+import dataclasses
+import hashlib
+
 import numpy as np
 import pytest
 
+import shadow_yaml_reference
 from dst_libp2p_test_node_tpu.config.topology import Topology, TopoParams
 
 
@@ -86,6 +90,88 @@ def test_shadow_yaml_schema(tmp_path):
     assert "traffic_sync.py" in ctrl["args"]
     # round-robin network node assignment
     assert hosts["pod-7"]["network_node_id"] == 2
+
+
+# (N, S): fewer peers than stages (no anchor at all), N = S, S < N < 2S (only
+# the first N - S stages get one), N = 2S and one past it, one stage
+SIZES = [(1, 1), (3, 5), (5, 5), (7, 5), (10, 5), (11, 5), (12, 5),
+         (1000, 5), (2000, 3), (2048, 1)]
+# what else reaches the file: the stage hosts' environment and the
+# injector's `args` line (the last two fold it past 80 columns at any N)
+FIELDS = {
+    "defaults": {},
+    "frag4-mplex": dict(num_frags=4, muxer="mplex", messages=3,
+                        msg_size_bytes=15000, delay_seconds=4.0),
+    "frag9-quic": dict(num_frags=9, muxer="quic", messages=1,
+                       msg_size_bytes=100, delay_seconds=0.5),
+    "yamux-long-args": dict(num_frags=2, muxer="yamux", messages=1000,
+                            msg_size_bytes=1500000, delay_seconds=0.125),
+    "quic-longer-args": dict(num_frags=9, muxer="quic", messages=123456,
+                             msg_size_bytes=2 ** 31, delay_seconds=1e-05,
+                             packet_loss=0.25),
+}
+
+
+def _both_files(tmp_path, params):
+    t = Topology.build(params)
+    ours, ref = tmp_path / "shadow.yaml", tmp_path / "reference.yaml"
+    counts = t.write_shadow_yaml(str(ours))
+    shadow_yaml_reference.write_shadow_yaml(t, str(ref))
+    return ours.read_bytes(), ref.read_bytes(), counts
+
+
+@pytest.mark.parametrize("fields_id", FIELDS)
+@pytest.mark.parametrize("n,s", SIZES)
+def test_shadow_yaml_bytes_are_the_whole_document_dumps(
+        tmp_path, n, s, fields_id):
+    params = dataclasses.replace(BASELINE, network_size=n, anchor_stages=s,
+                                 **FIELDS[fields_id])
+    ours, ref, counts = _both_files(tmp_path, params)
+    assert ours == ref
+    dumped = min(n, 2 * s)
+    assert counts == {"yaml_hosts_dumped": dumped + 1,
+                      "yaml_alias_lines": n - dumped}
+    if "args" in fields_id:
+        # past 80 columns the injector's `args` is PyYAML's to fold
+        lines = ref.decode().splitlines()
+        assert lines[lines.index("      start_time: 500s") - 1].startswith(
+            "        ")
+
+
+def test_shadow_yaml_bytes_at_100k_peers(tmp_path):
+    ours, ref, counts = _both_files(
+        tmp_path, dataclasses.replace(BASELINE, network_size=100_000,
+                                      messages=3))
+    assert ours == ref
+    assert hashlib.sha256(ours).hexdigest() \
+        == hashlib.sha256(ref).hexdigest()
+    assert counts == {"yaml_hosts_dumped": 11, "yaml_alias_lines": 99_990}
+
+
+@pytest.mark.parametrize("n,s", [(100_000, 5), (2048, 1), (10, 5), (3, 5),
+                                 (40, 7)])
+def test_shadow_yaml_hands_pyyaml_a_document_of_constant_size(
+        tmp_path, monkeypatch, n, s):
+    """The mechanism engaged, and there is no other path to fall back to:
+    whatever the size, `yaml.dump` sees the first min(N, 2S) hosts and the
+    injector, once."""
+    import yaml
+
+    handed = []
+    real = yaml.dump
+
+    def counting(data, *args, **kwargs):
+        handed.append(len(data["hosts"]))
+        return real(data, *args, **kwargs)
+
+    monkeypatch.setattr(yaml, "dump", counting)
+    t = Topology.build(dataclasses.replace(
+        BASELINE, network_size=n, anchor_stages=s))
+    counts = t.write_shadow_yaml(str(tmp_path / "shadow.yaml"))
+    # 11 of 100,001 hosts at (100,000, 5); never more than 2S + 1
+    assert handed == [min(n, 2 * s) + 1]
+    assert counts == {"yaml_hosts_dumped": handed[0],
+                      "yaml_alias_lines": n + 1 - handed[0]}
 
 
 def test_validation():

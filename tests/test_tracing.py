@@ -145,6 +145,21 @@ def test_stats_json_spans_and_publishes_are_strict_json(two_turns):
             assert p["converged"] is True and p["fell_back"] is False
 
 
+def test_stats_json_artifacts_count_what_wrote_shadow_yaml(
+        two_turns, tmp_path):
+    """PyYAML wrote the ten hosts that fix the anchors and the injector, the
+    other hosts are alias lines; a turn that wrote no file says zeros."""
+    _run(tmp_path, "--warmup-s", "20", nodes=1000)
+    assert _strict(tmp_path / "stats1.json")["artifacts"] == {
+        "yaml_hosts_dumped": 11, "yaml_alias_lines": 990}
+    assert (tmp_path / "shadow.yaml").read_text().count("\n  pod-") == 1001
+    tmp, _ = two_turns
+    assert _strict(tmp / "stats1.json")["artifacts"] == {
+        "yaml_hosts_dumped": 11, "yaml_alias_lines": 190}
+    assert _strict(tmp / "stats2.json")["artifacts"] == {
+        "yaml_hosts_dumped": 0, "yaml_alias_lines": 0}
+
+
 def test_wall_s_is_the_build_and_simulate_spans(two_turns):
     tmp, turns = two_turns
     stats = _strict(tmp / "stats1.json")
