@@ -363,6 +363,9 @@ def cmd_run(argv: list[str]) -> int:
                                  "refined": r.refined,
                                  "fell_back": r.fell_back,
                                  "refined_serial": r.refined_serial,
+                                 "refine_lane_passes": r.refine_lane_passes,
+                                 "lanes_hinted": r.lanes_hinted,
+                                 "lanes_uncertified": r.lanes_uncertified,
                                  "converged": r.converged}
                                 for r in sim.records],
                         }),

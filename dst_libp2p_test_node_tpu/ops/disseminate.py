@@ -235,15 +235,16 @@ class DisseminationResult:
     #                            pass-count budget of the exactness
     #                            certificate pins this on canonical
     #                            topologies (tests/test_exact_prefix.py).
-    counters: jnp.ndarray      # (6,) int32 — [fast_iters, refine_passes,
+    counters: jnp.ndarray      # (9,) int32 — [fast_iters, refine_passes,
     #                            refined, fell_back, converged,
-    #                            refined_serial]: how much work the
-    #                            publish's fixpoints did and which branches
-    #                            ran, packed so that the host takes them in
-    #                            ONE device->host read
+    #                            refined_serial, refine_lane_passes,
+    #                            lanes_hinted, lanes_uncertified]: how much
+    #                            work the publish's fixpoints did and which
+    #                            branches ran, packed so that the host
+    #                            takes them in ONE device->host read
     #                            (runtime/simulator.record_from_result) and
-    #                            the jit returns one leaf more, not five.
-    #                            The four that are no field of their own
+    #                            the jit returns one leaf more, not eight.
+    #                            The seven that are no field of their own
     #                            are the properties below.
 
     @property
@@ -273,6 +274,27 @@ class DisseminationResult:
         answer_queue_mode="serial", or the rerun after fell_back), False
         with `refined` the parallel-prefix one, False without it none."""
         return self.counters[..., 5] != 0
+
+    @property
+    def refine_lane_passes(self):
+        """() int32 — the kept refinement's passes SUMMED over the fragment
+        lanes (`refine_passes` is the deepest lane's): what the scope
+        refine/per_fragment ran. Equal to `refine_passes` at one
+        fragment."""
+        return self.counters[..., 6]
+
+    @property
+    def lanes_hinted(self):
+        """() int32 — fragment lanes whose own fast pipeline asked for the
+        repair; every lane refines when one does, so `fragments` less this
+        is the lanes refined for another's sake. 1..F whenever `refined`."""
+        return self.counters[..., 7]
+
+    @property
+    def lanes_uncertified(self):
+        """() int32 — lanes the parallel-prefix engine refined and could
+        not certify; 0 unless `fell_back`."""
+        return self.counters[..., 8]
 
 
 def _stage_select(stage: jnp.ndarray, n_stages: int, conns: jnp.ndarray,
@@ -1672,7 +1694,8 @@ def disseminate(
     answer_wait = jnp.max(wait_f)
     answer_interleaved = jnp.sum(mixed_f.astype(jnp.int32))
     converged = jnp.all(ok_f)
-    refine_passes = jnp.int32(0)
+    refine_passes = refine_lane_passes = jnp.int32(0)
+    lanes_hinted = lanes_uncertified = jnp.int32(0)
     refined = fell_back = refined_serial = jnp.bool_(False)
     if with_gossip and params.serialize_answers:
         # serialized-answer repair, decided ONCE per message on a SCALAR
@@ -1700,12 +1723,17 @@ def disseminate(
             return _per_fragment(phases_serial, frag_ids, t_pubs, seed,
                                  batched=False)
 
+        # what a branch that did not fall back appends to its 10-tuple:
+        # the fell-back bit and the count of uncertified lanes
+        no_fallback = (jnp.bool_(False), jnp.int32(0))
+
         def _slow(fr):
-            """The taken branch: fr[:10] refined, then the fell-back bit."""
+            """The taken branch: fr[:10] refined, then the fell-back bit
+            and how many lanes the prefix engine left uncertified."""
             t_fast = fr[0]
             if not use_prefix:
                 # the global-sort engine is the one chosen: no fallback
-                return _serial_all(t_fast) + (jnp.bool_(False),)
+                return _serial_all(t_fast) + no_fallback
             pref = _per_fragment(phases_prefix, frag_ids, t_pubs, t_fast,
                                  batched=False)
 
@@ -1719,11 +1747,11 @@ def disseminate(
             def _legacy(p):
                 with jax.named_scope("legacy"):
                     leg = _serial_all(p[0])
-                return leg[:9] + (p[9] + leg[9], jnp.bool_(True))
+                return leg[:9] + (p[9] + leg[9], jnp.bool_(True),
+                                  jnp.sum(~p[8], dtype=jnp.int32))
 
             return jax.lax.cond(
-                jnp.all(pref[8]), lambda p: p + (jnp.bool_(False),),
-                _legacy, pref)
+                jnp.all(pref[8]), lambda p: p + no_fallback, _legacy, pref)
 
         # the convergence bit rides the cond operand so the kept branch's
         # verdict (fast ok / serialized refinement certificate) wins; the
@@ -1733,15 +1761,19 @@ def disseminate(
         refined = jnp.any(hint_f)
         with jax.named_scope("refine"):
             kept = jax.lax.cond(
-                refined, _slow, lambda fr: fr + (jnp.bool_(False),),
+                refined, _slow, lambda fr: fr + no_fallback,
                 fast_results + (ok_f, jnp.zeros((fragments,), jnp.int32)))
         fast_results, conv_f, passes_f = kept[:8], kept[8], kept[9]
-        fell_back = kept[10]
+        fell_back, lanes_uncertified = kept[10], kept[11]
         # which engine the kept refinement is from: the global-sort one
         # where it was the one chosen, or after the fallback to it
         refined_serial = (refined & fell_back) if use_prefix else refined
         converged = jnp.all(conv_f)
         refine_passes = jnp.max(passes_f)
+        # the scope refine/per_fragment runs every lane's passes, and every
+        # lane refines when one hints
+        refine_lane_passes = jnp.sum(passes_f)
+        lanes_hinted = jnp.sum(hint_f, dtype=jnp.int32)
         # exact mode: the repair drives the delivery error to zero
         answer_wait = jnp.float32(0.0)
         answer_interleaved = jnp.int32(0)
@@ -1752,7 +1784,13 @@ def disseminate(
     with jax.named_scope("accounting"):
         received = jnp.all(t_rx_f < INF, axis=0)
         # last fragment completes
-        t_rx = jnp.where(received, t_rx_f.max(axis=0), INF)
+        t_last = jnp.where(received, t_rx_f.max(axis=0), INF)
+        # but the publisher's own receipt is the publish call: it stamps
+        # `now` once before the fragment loop (main.nim:169) and its own
+        # handler counts every fragment inside `publish` (SELFTRIGGER,
+        # main.nim:245), so it logs t0_ms; its lanes' t_pubs[f] are send
+        # origins, and t_last there is the START of its last send
+        t_rx = jnp.where(is_pub & received, t0_ms, t_last)
         delay = jnp.where(received, t_rx - t0_ms, INF)
 
     # ---- post-fixpoint accounting (bytes, duplicates, gossip, score) -------
@@ -1934,7 +1972,8 @@ def disseminate(
             counters=jnp.stack([
                 fast_iters, refine_passes, refined.astype(jnp.int32),
                 fell_back.astype(jnp.int32), converged.astype(jnp.int32),
-                refined_serial.astype(jnp.int32)]),
+                refined_serial.astype(jnp.int32), refine_lane_passes,
+                lanes_hinted, lanes_uncertified]),
         )
         dup = jnp.maximum(copies - fragments, 0)
         # uplink occupancy write-back: per fragment, frag_accounting computed the
@@ -1963,7 +2002,7 @@ def disseminate(
         # the next publish's relaxation (phases_fast re-bases them to the new
         # publish time). INF where the message never fully arrived; churn and
         # subscription changes invalidate the carry (heartbeat/simulator).
-        warm_new = jnp.where(received, t_rx - t0_ms, INF)
+        warm_new = jnp.where(received, t_last - t0_ms, INF)
         new_state = state.replace(
             key=key,
             warm_offset_ms=warm_new,

@@ -180,8 +180,9 @@ def record_from_result(
     # none: their records read converged and no refinement
     packed = getattr(res, "counters", None)
     (fast_iters, refine_passes, refined, fell_back, converged,
-     refined_serial) = ((0, 0, 0, 0, 1, 0) if packed is None
-                        else (int(v) for v in np.asarray(packed)))
+     refined_serial, refine_lane_passes, lanes_hinted, lanes_uncertified) = (
+        (0, 0, 0, 0, 1, 0, 0, 0, 0) if packed is None
+        else (int(v) for v in np.asarray(packed)))
     return MessageRecord(
         msg_id=msg_id,
         publisher=publisher,
@@ -202,6 +203,9 @@ def record_from_result(
         refined=bool(refined),
         fell_back=bool(fell_back),
         refined_serial=bool(refined_serial),
+        refine_lane_passes=refine_lane_passes,
+        lanes_hinted=lanes_hinted,
+        lanes_uncertified=lanes_uncertified,
     )
 
 
@@ -243,14 +247,18 @@ class MessageRecord:
     # bit read True)
     converged: bool = True
     # DisseminationResult.fast_iters / refine_passes / refined / fell_back /
-    # refined_serial: how much work the publish's fixpoints did, which
-    # branches ran and which engine refined (`stats<i>.json` "publishes";
-    # not checkpointed, views read 0 / False)
+    # refined_serial / refine_lane_passes / lanes_hinted / lanes_uncertified:
+    # how much work the publish's fixpoints did, which branches ran, which
+    # engine refined and what the fragment lanes added (`stats<i>.json`
+    # "publishes"; not checkpointed, views read 0 / False)
     fast_iters: int = 0
     refine_passes: int = 0
     refined: bool = False
     fell_back: bool = False
     refined_serial: bool = False
+    refine_lane_passes: int = 0
+    lanes_hinted: int = 0
+    lanes_uncertified: int = 0
 
     @property
     def receivers(self) -> np.ndarray:
@@ -693,6 +701,9 @@ class Simulator:
                 refined=int(rec.refined), fell_back=int(rec.fell_back),
                 converged=int(rec.converged),
                 refined_serial=int(rec.refined_serial),
+                refine_lane_passes=rec.refine_lane_passes,
+                lanes_hinted=rec.lanes_hinted,
+                lanes_uncertified=rec.lanes_uncertified,
                 peers=self.params.n, slots=self.params.capacity,
                 fragments=cfg.topo.num_frags,
                 rounds=self.params.history_gossip if cfg.with_gossip else 0,

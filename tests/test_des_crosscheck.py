@@ -357,9 +357,16 @@ def _compare(res, plan, conns, rev, params, publisher, t0, frags,
         np.asarray(conns), np.asarray(rev), plan, params, publisher, t0,
         frags, payload_bytes=payload_bytes)
     np.testing.assert_array_equal(got_r, want_r)
+    # the publisher's own receipt is the publish call, delay 0 whatever its
+    # fragments' send origins are; the DES keeps it at the last origin,
+    # t_pubs[F-1] (as its frozen copy does, benchmark/reference/des.py), so
+    # its entry is taken out of the comparison
+    if want_r[publisher]:
+        assert got_d[publisher] == 0.0
+    others = want_r & (np.arange(len(want_r)) != publisher)
     # engine runs float32 at absolute times up to ~1e4 ms: ~1e-3 ms wobble
     np.testing.assert_allclose(
-        got_d[want_r], want_d[want_r], rtol=1e-4, atol=0.5)
+        got_d[others], want_d[others], rtol=1e-4, atol=0.5)
 
 
 CASES = [

@@ -140,7 +140,9 @@ def test_stats_json_spans_and_publishes_are_strict_json(two_turns):
         assert len(stats["publishes"]) == MESSAGES
         for p in stats["publishes"]:
             assert set(p) == {"fast_iters", "refine_passes", "refined",
-                              "fell_back", "converged", "refined_serial"}
+                              "fell_back", "converged", "refined_serial",
+                              "refine_lane_passes", "lanes_hinted",
+                              "lanes_uncertified"}
             assert isinstance(p["fast_iters"], int) and p["fast_iters"] > 0
             assert p["converged"] is True and p["fell_back"] is False
 
@@ -259,7 +261,8 @@ def test_counters_on_the_prefix_cases(kw, over, passes_prefix,
         assert np.asarray(res.counters).tolist() == [
             int(res.fast_iters), int(res.refine_passes), int(res.refined),
             int(res.fell_back), int(res.converged),
-            int(res.refined_serial)]
+            int(res.refined_serial), int(res.refine_lane_passes),
+            int(res.lanes_hinted), int(res.lanes_uncertified)]
     # which engine refined: the one chosen
     assert not bool(res_p.refined_serial) and bool(res_s.refined_serial)
     # the fast pipeline is the same program under both engines
