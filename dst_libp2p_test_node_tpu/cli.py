@@ -357,6 +357,10 @@ def cmd_run(argv: list[str]) -> int:
                             # hosts PyYAML wrote into `shadow.yaml` and
                             # alias lines joined beside them
                             "artifacts": artifacts,
+                            # which path of the graph build engaged how
+                            # often (ops/graph.ConnGraph.build): a run that
+                            # fell back to a slow one says so here
+                            "build": sim.graph.build,
                             "publishes": [
                                 {"fast_iters": r.fast_iters,
                                  "refine_passes": r.refine_passes,

@@ -295,69 +295,75 @@ class Simulator:
         self.mesh = mesh
         self.topology = topology or Topology.build(cfg.topo)
         n = cfg.topo.network_size
-        self.graph = build_connection_graph(
-            n,
-            cfg.connect_to,
-            seed=cfg.seed,
-            max_degree=graph_capacity(cfg),
-        )
-        proc_ms = MUXER_PROC_MS.get(cfg.topo.muxer.lower(), 2.0)
-        self.params = SimParams.from_gossipsub(
-            n,
-            self.graph.capacity,
-            cfg.gossipsub,
-            proc_delay_ms=proc_ms,
-            churn_down_per_hb=cfg.churn_down_per_hb,
-            churn_up_per_hb=cfg.churn_up_per_hb,
-            serialize_answers=cfg.serialize_answers,
-            answer_queue_mode=cfg.answer_queue_mode,
-            warm_start=cfg.warm_start,
-        )
-        self.state = init_state(self.params, seed=cfg.seed)
-        self.arrays = graph_arrays(self.graph)
-        self._stage = jnp.asarray(self.topology.stage_of_peer)
-        self._lat = jnp.asarray(self.topology.latency_ms)
-        self._bw = jnp.asarray(self.topology.bw_up_mbit)
-        # per-stage-pair packet loss (topogen -l); None keeps the lossless
-        # fast path out of the compiled step entirely
-        self._loss = (
-            jnp.asarray(self.topology.packet_loss)
-            if float(np.max(self.topology.packet_loss)) > 0.0 else None
-        )
-        # stage-pair edge tables are experiment constants: build them once
-        # here instead of 70 ms/publish inside disseminate (ops edge_tables)
-        from ..ops.disseminate import answer_tables, edge_tables
+        # the two halves of the build, as the program's own spans (noted
+        # only inside a `turn`): the graph is host numpy and nothing on the
+        # device can start before it, the rest is state, device copies and
+        # the tables made from the graph
+        with span("build/graph"):
+            self.graph = build_connection_graph(
+                n,
+                cfg.connect_to,
+                seed=cfg.seed,
+                max_degree=graph_capacity(cfg),
+            )
+        with span("build/tables"):
+            proc_ms = MUXER_PROC_MS.get(cfg.topo.muxer.lower(), 2.0)
+            self.params = SimParams.from_gossipsub(
+                n,
+                self.graph.capacity,
+                cfg.gossipsub,
+                proc_delay_ms=proc_ms,
+                churn_down_per_hb=cfg.churn_down_per_hb,
+                churn_up_per_hb=cfg.churn_up_per_hb,
+                serialize_answers=cfg.serialize_answers,
+                answer_queue_mode=cfg.answer_queue_mode,
+                warm_start=cfg.warm_start,
+            )
+            self.state = init_state(self.params, seed=cfg.seed)
+            self.arrays = graph_arrays(self.graph)
+            self._stage = jnp.asarray(self.topology.stage_of_peer)
+            self._lat = jnp.asarray(self.topology.latency_ms)
+            self._bw = jnp.asarray(self.topology.bw_up_mbit)
+            # per-stage-pair packet loss (topogen -l); None keeps the lossless
+            # fast path out of the compiled step entirely
+            self._loss = (
+                jnp.asarray(self.topology.packet_loss)
+                if float(np.max(self.topology.packet_loss)) > 0.0 else None
+            )
+            # stage-pair edge tables are experiment constants: build them once
+            # here instead of 70 ms/publish inside disseminate (ops edge_tables)
+            from ..ops.disseminate import answer_tables, edge_tables
 
-        self._lat_edge, self._loss_edge = edge_tables(
-            self._stage, self._lat, self.arrays["conns"], self.arrays["rev"],
-            self._loss)
-        # so are the lat-sorted answer-queue service tables (two stable
-        # argsorts per publish otherwise — the r5 bench's accounting bill)
-        self._ans_tables = (
-            answer_tables(self._lat_edge, self.arrays["conns"],
-                          self.arrays["rev"])
-            if cfg.with_gossip else None)
-        if mesh is not None:
-            import jax
+            self._lat_edge, self._loss_edge = edge_tables(
+                self._stage, self._lat, self.arrays["conns"], self.arrays["rev"],
+                self._loss)
+            # so are the lat-sorted answer-queue service tables (two stable
+            # argsorts per publish otherwise — the r5 bench's accounting bill)
+            self._ans_tables = (
+                answer_tables(self._lat_edge, self.arrays["conns"],
+                              self.arrays["rev"])
+                if cfg.with_gossip else None)
+            if mesh is not None:
+                import jax
 
-            from ..parallel.sharding import place_simulation, reshard_rows
+                from ..parallel.sharding import place_simulation, reshard_rows
 
-            (self.state, self.arrays, self._stage, self._lat, self._bw,
-             self._loss) = place_simulation(
-                self.state, self.arrays, self._stage, self._lat, self._bw,
-                self._loss, mesh)
-            self._lat_edge = reshard_rows(self._lat_edge, mesh)
-            if self._loss_edge is not None:
-                self._loss_edge = reshard_rows(self._loss_edge, mesh)
-            if self._ans_tables is not None:
-                self._ans_tables = jax.tree_util.tree_map(
-                    lambda x: reshard_rows(x, mesh), self._ans_tables)
-        # neighbor alive&subscribed validity is publish-invariant between
-        # membership changes: maintained here (set_subscribed recomputes,
-        # churn disables the hoist — heartbeats mutate alive on device)
-        self._churny = (cfg.churn_down_per_hb > 0.0
-                        or cfg.churn_up_per_hb > 0.0)
-        self._valid_edge = None if self._churny else self._compute_valid_edge()
+                (self.state, self.arrays, self._stage, self._lat, self._bw,
+                 self._loss) = place_simulation(
+                    self.state, self.arrays, self._stage, self._lat, self._bw,
+                    self._loss, mesh)
+                self._lat_edge = reshard_rows(self._lat_edge, mesh)
+                if self._loss_edge is not None:
+                    self._loss_edge = reshard_rows(self._loss_edge, mesh)
+                if self._ans_tables is not None:
+                    self._ans_tables = jax.tree_util.tree_map(
+                        lambda x: reshard_rows(x, mesh), self._ans_tables)
+            # neighbor alive&subscribed validity is publish-invariant between
+            # membership changes: maintained here (set_subscribed recomputes,
+            # churn disables the hoist — heartbeats mutate alive on device)
+            self._churny = (cfg.churn_down_per_hb > 0.0
+                            or cfg.churn_up_per_hb > 0.0)
+            self._valid_edge = None if self._churny else self._compute_valid_edge()
         # host mirror of state.subscribed: publish() picks the fanout code
         # path (static arg) without a device sync; keep in sync via
         # set_subscribed()

@@ -202,6 +202,15 @@ def test_cli_run_emits_what_the_reference_loops_and_the_text_path_give(
         tmp_path / "ref.yaml").read_bytes()
     assert stats["artifacts"] == {
         "yaml_hosts_dumped": 11, "yaml_alias_lines": 50}
+    # the graph is the one the commit before the build lost its sorts made
+    # (`graph_sha256` read there), and the build says which paths it took
+    from dst_libp2p_test_node_tpu.runtime.checkpoint import _graph_hash
+
+    assert _graph_hash(sim.graph) == (
+        "2eb570a1b800336dec4b2a46cb59c38b4649c61fe4cb67cf9626df7e7ece01b2")
+    assert stats["build"] == {
+        "dial_rows_resampled": 0, "mutual_dials_dropped": 55,
+        "dedupe": "mutual", "cap_filtered_edges": 0}
     # 180 and 60 lines are under NATIVE_MIN_LINES: the Python formatters
     assert stats["emit"] == {
         "latencies_lines": 180, "latencies_native_blocks": 0,

@@ -27,6 +27,7 @@ TREE = {
             "run/simulator_init", "run/simulate", "run/write_latencies",
             "run/write_shadowlog", "run/summary", "run/report",
             "run/stats_json"},
+    "run/simulator_init": {"build/graph", "build/tables"},
     "run/simulate": {"warmup", "advance", "publish"},
     "publish": {"publish/prepare", "publish/dispatch", "publish/read"},
 }
@@ -170,6 +171,23 @@ def test_wall_s_is_the_build_and_simulate_spans(two_turns):
     assert stats["wall_s"] == pytest.approx(want, rel=1e-9)
     # and the spans the experiment is made of fit inside the turn
     assert want <= turns[0].seconds("run")
+
+
+def test_the_build_is_two_spans_that_make_up_simulator_init(two_turns):
+    """`Simulator.__init__` is the graph (host numpy) and then everything
+    made from it: once each a turn, and nothing of the build outside them."""
+    tmp, turns = two_turns
+    for i, turn in enumerate(turns, start=1):
+        (init,) = [j for j, s in enumerate(turn.spans)
+                   if s.name == "run/simulator_init"]
+        kids = [s for s in turn.spans if s.parent == init]
+        assert [s.name for s in kids] == ["build/graph", "build/tables"]
+        whole = turn.seconds("run/simulator_init")
+        halves = turn.seconds("build/graph") + turn.seconds("build/tables")
+        assert 0.0 <= whole - halves <= max(1e-3, 0.05 * whole)
+        assert _strict(tmp / f"stats{i}.json")["build"] == {
+            "dial_rows_resampled": 0, "dedupe": "mutual",
+            "mutual_dials_dropped": 47, "cap_filtered_edges": 0}
 
 
 def test_span_outside_a_turn_records_nothing_and_does_not_raise():
