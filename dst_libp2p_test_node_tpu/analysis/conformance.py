@@ -408,11 +408,14 @@ def run_faults_differential(n=48, connect_to=8, seed=0, steps=8,
 
 
 def run_churn_differential(n=48, connect_to=8, seed=0, steps=8,
-                           warm_steps=4):
+                           warm_steps=4, spared=None):
     """Benign churn differential: a zero-attacker walk with churn armed, so
     the k_churn_d/k_churn_u PRNG draws and the liveness-driven validity
     algebra are covered (an all-False cohort makes adversary_round the
-    identity on state)."""
+    identity on state). `spared`: peer ids the churn draw does not kill
+    (heartbeat_step's `spared`, as a Simulator spares the peers it
+    publishes through): the walk is then the plain heartbeat's, step for
+    step against the spec with the same mask."""
     from ..ops.state import SimParams
 
     params = None
@@ -424,9 +427,42 @@ def run_churn_differential(n=48, connect_to=8, seed=0, steps=8,
     from ..ops.graph import build_connection_graph
     g = build_connection_graph(n, connect_to, seed=seed)
     params = build_params(g)
-    return run_scenario_differential(
-        "sybil_graft_flood", n=n, connect_to=connect_to, seed=seed,
-        steps=steps, warm_steps=warm_steps, params=params, fraction=0.0)
+    if spared is None:
+        return run_scenario_differential(
+            "sybil_graft_flood", n=n, connect_to=connect_to, seed=seed,
+            steps=steps, warm_steps=warm_steps, params=params, fraction=0.0)
+    return _spared_churn_walk(g, params, seed, steps, warm_steps, spared)
+
+
+def _spared_churn_walk(g, params, seed, steps, warm_steps, spared):
+    import numpy as np
+
+    _, jnp = _jax()
+    from ..ops.heartbeat import heartbeat_step, run_heartbeats
+    from ..ops.spec import host_state, spec_heartbeat
+    from ..ops.state import graph_arrays, init_state
+
+    a = graph_arrays(g)
+    mask = np.zeros(params.n, bool)
+    mask[list(spared)] = True
+    on_device = jnp.asarray(mask)
+    state = run_heartbeats(init_state(params, seed=seed), a["conns"],
+                           a["rev"], a["out_mask"], params, warm_steps,
+                           spared=on_device)
+    st = host_state(state)
+    hosts = {k: np.asarray(v) for k, v in a.items()}
+    divs = []
+    for i in range(steps):
+        state = heartbeat_step(state, a["conns"], a["rev"], a["out_mask"],
+                               params, spared=on_device)
+        st = spec_heartbeat(st, hosts["conns"], hosts["rev"],
+                            hosts["out_mask"], params, spared=mask)
+        divs.extend(_diff_states(state, st, "churn_spared", seed, i))
+        if len(divs) >= _MAX_DIV_STEPS:
+            break
+    if not bool(np.asarray(state.alive)[mask].all()):
+        raise AssertionError("a spared peer died")
+    return divs
 
 
 def run_og_differential(n=48, connect_to=8, seed=0, steps=8, warm_steps=4,

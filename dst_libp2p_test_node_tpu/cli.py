@@ -166,6 +166,22 @@ def cmd_topogen(argv: list[str]) -> int:
     return 0
 
 
+def _churn_rates(text: str) -> tuple[float, float]:
+    """`--churn DOWN[:UP]`: the probabilities a heartbeat; UP is DOWN / 2
+    where it is left out."""
+    down, colon, up = text.partition(":")
+    try:
+        down = float(down)
+        up = float(up) if colon else down / 2
+    except ValueError:
+        raise argparse.ArgumentTypeError(
+            f"wants DOWN or DOWN:UP, two probabilities, got {text!r}")
+    if not (0.0 <= down <= 1.0 and 0.0 <= up <= 1.0):
+        raise argparse.ArgumentTypeError(
+            f"a probability lies in [0, 1], got {text!r}")
+    return down, up
+
+
 def cmd_run(argv: list[str]) -> int:
     # flags appended after the 14 positionals tune the TPU backend
     p = argparse.ArgumentParser(
@@ -182,8 +198,12 @@ def cmd_run(argv: list[str]) -> int:
     p.add_argument("--connect-to", type=int, default=10)  # run.sh:38
     p.add_argument("--muxer", choices=["mplex", "yamux", "quic"], default="yamux")
     p.add_argument("--no-gossip", action="store_true")
-    p.add_argument("--churn", type=float, default=0.0,
-                   help="per-heartbeat down-probability (failure injection)")
+    p.add_argument("--churn", type=_churn_rates, default=(0.0, 0.0),
+                   metavar="DOWN[:UP]",
+                   help="failure injection: each heartbeat a living peer "
+                   "goes down with probability DOWN and a dead one comes "
+                   "back with UP (DOWN / 2 where it is left out); the peers "
+                   "the run publishes through are spared")
     p.add_argument("--use-mix", action="store_true",
                    help="route publishes through the mix network (USESMIX)")
     p.add_argument("--num-mix", type=int, default=0, help="NUMMIX")
@@ -286,8 +306,8 @@ def cmd_run(argv: list[str]) -> int:
                 warmup_s=a.warmup_s,
                 seed=a.seed + i - 1,
                 with_gossip=not a.no_gossip,
-                churn_down_per_hb=a.churn,
-                churn_up_per_hb=a.churn / 2 if a.churn else 0.0,
+                churn_down_per_hb=a.churn[0],
+                churn_up_per_hb=a.churn[1],
                 uses_mix=a.use_mix,
                 num_mix=a.num_mix,
                 mix_d=a.mix_d,
@@ -372,6 +392,19 @@ def cmd_run(argv: list[str]) -> int:
                                  "lanes_uncertified": r.lanes_uncertified,
                                  "converged": r.converged}
                                 for r in sim.records],
+                            # under --churn only: the rates a heartbeat,
+                            # the peers the draw spares (those the run
+                            # publishes through), and a message how many
+                            # peers could send and how many of them sat
+                            # under D_low valid mesh members
+                            **({"churn": {
+                                "down_per_hb": cfg.churn_down_per_hb,
+                                "up_per_hb": cfg.churn_up_per_hb,
+                                "spared_peers": sim.spared_peers,
+                                "alive": [r.alive for r in sim.records],
+                                "under_dlow": [r.under_dlow
+                                               for r in sim.records],
+                            }} if sim.spared_peers else {}),
                         }),
                         f,
                         indent=2,

@@ -238,7 +238,9 @@ class DisseminationResult:
     counters: jnp.ndarray      # (9,) int32 — [fast_iters, refine_passes,
     #                            refined, fell_back, converged,
     #                            refined_serial, refine_lane_passes,
-    #                            lanes_hinted, lanes_uncertified]: how much
+    #                            lanes_hinted, lanes_uncertified], and
+    #                            under churn (11,): [..., alive,
+    #                            under_dlow]: how much
     #                            work the publish's fixpoints did and which
     #                            branches ran, packed so that the host
     #                            takes them in ONE device->host read
@@ -295,6 +297,22 @@ class DisseminationResult:
         """() int32 — lanes the parallel-prefix engine refined and could
         not certify; 0 unless `fell_back`."""
         return self.counters[..., 8]
+
+    @property
+    def alive(self):
+        """() int32 — peers that could send at this publish (alive and
+        subscribed, and the fanout publisher). Under churn only
+        (`SimParams.churn_*_per_hb`); None without."""
+        return (self.counters[..., 9] if self.counters.shape[-1] > 9
+                else None)
+
+    @property
+    def under_dlow(self):
+        """() int32 — of those, the peers whose valid mesh degree was under
+        D_low at this publish: what the heartbeats' repair had not yet
+        mended. Under churn only; None without."""
+        return (self.counters[..., 10] if self.counters.shape[-1] > 9
+                else None)
 
 
 def _stage_select(stage: jnp.ndarray, n_stages: int, conns: jnp.ndarray,
@@ -371,6 +389,21 @@ def answer_tables(lat_edge, conns, rev) -> AnswerTables:
         conns_sorted=permute_rows(conns, perm_lat),
         rev_sorted=jnp.where(pos < INF, pos, -1.0).astype(jnp.int32),
     )
+
+
+def valid_edge_of(alive, subscribed, conns, rev):
+    """`disseminate`'s `valid_edge`: per edge, connected AND the neighbor
+    alive & subscribed (one row-gather pass). A caller whose liveness and
+    membership stand still makes it once (runtime/simulator.py)."""
+    return (conns >= 0) & neighbor_pull_bool(alive & subscribed, conns, rev)
+
+
+@jax.jit
+def valid_edge_at_publish(alive, subscribed, conns, rev, publisher):
+    """`valid_edge_of` for one publish of a churned network, fused into one
+    dispatch, with the publisher's liveness beside it (`publisher` is
+    traced: one executable whoever publishes)."""
+    return valid_edge_of(alive, subscribed, conns, rev), alive[publisher]
 
 
 def _ranks_f32(priority: jnp.ndarray) -> jnp.ndarray:
@@ -1956,6 +1989,21 @@ def disseminate(
             idw_tx_pp = jnp.zeros((n,), jnp.int32)
             idw_rx_pp = jnp.zeros((n,), jnp.int32)
 
+        packed = [
+            fast_iters, refine_passes, refined.astype(jnp.int32),
+            fell_back.astype(jnp.int32), converged.astype(jnp.int32),
+            refined_serial.astype(jnp.int32), refine_lane_passes,
+            lanes_hinted, lanes_uncertified]
+        if params.churn_down_per_hb > 0.0 or params.churn_up_per_hb > 0.0:
+            # under churn only (a churn-free publish stays the program it
+            # was): who could send at this publish, and how many of them
+            # the mesh repair has not yet brought back to D_low valid
+            # mesh members
+            mesh_deg = (state.mesh_mask & valid
+                        & can_send[:, None]).sum(axis=-1)
+            packed += [
+                can_send.sum(dtype=jnp.int32),
+                (can_send & (mesh_deg < params.d_low)).sum(dtype=jnp.int32)]
         result = DisseminationResult(
             t_rx_ms=t_rx,
             delay_ms=delay,
@@ -1969,11 +2017,7 @@ def disseminate(
             answer_interleaved=answer_interleaved,
             converged=converged,
             refine_passes=refine_passes,
-            counters=jnp.stack([
-                fast_iters, refine_passes, refined.astype(jnp.int32),
-                fell_back.astype(jnp.int32), converged.astype(jnp.int32),
-                refined_serial.astype(jnp.int32), refine_lane_passes,
-                lanes_hinted, lanes_uncertified]),
+            counters=jnp.stack(packed),
         )
         dup = jnp.maximum(copies - fragments, 0)
         # uplink occupancy write-back: per fragment, frag_accounting computed the

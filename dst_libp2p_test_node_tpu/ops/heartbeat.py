@@ -73,6 +73,7 @@ def heartbeat_step(
     decay_scales=None,
     deg_in: jnp.ndarray | None = None,
     edge_ok: jnp.ndarray | None = None,
+    spared: jnp.ndarray | None = None,
 ):
     """`batch_factor`: width of any enclosing vmap (e.g. the topic axis of
     runtime/multitopic.py) so the pull memory dispatch sees the true
@@ -106,7 +107,17 @@ def heartbeat_step(
     validity conjunction — the fault-injection hook (ops/faults.py): a
     partitioned edge is connected but unusable, so it falls out of `valid`
     exactly like an edge to a dead peer. None keeps the default trace
-    untouched (the same optional-arg contract as nbr_ok/valid_pre)."""
+    untouched (the same optional-arg contract as nbr_ok/valid_pre).
+
+    `spared`: optional (N,) mask of peers the churn draw does not kill (the
+    nodes an injector publishes through, runtime/simulator.py): applied
+    AFTER the draw, so every other peer's liveness is what the same key
+    gives without it. Read only under churn; None (always, with churn off)
+    keeps the trace untouched.
+
+    Device scopes (jax.named_scope: metadata only, no operation is added)
+    name the step's stages for a profile: `churn`, `validity`, `graft`,
+    `prune`, `evict`, `px`, `opportunistic`, `decay`, `fanout`, `state`."""
     if deg_in is not None and (
         valid_pre is None
         or edge_ok is not None
@@ -120,52 +131,64 @@ def heartbeat_step(
         raise ValueError("deg_in requires valid_pre, no edge_ok, and churn "
                          "off (run_heartbeats' churn-free scan protocol)")
     n, c = conns.shape
-    key, k_graft, k_keep, k_churn_d, k_churn_u = jax.random.split(state.key, 5)
+    with jax.named_scope("state"):
+        key, k_graft, k_keep, k_churn_d, k_churn_u = jax.random.split(
+            state.key, 5)
     t = state.t_ms
 
     # -- churn (failure injection; BASELINE config 4) ------------------------
     alive = state.alive
     if params.churn_down_per_hb > 0.0 or params.churn_up_per_hb > 0.0:
-        dies = jax.random.uniform(k_churn_d, (n,)) < params.churn_down_per_hb
-        revives = jax.random.uniform(k_churn_u, (n,)) < params.churn_up_per_hb
-        alive = jnp.where(alive, ~dies, revives)
-        nbr_ok = None   # alive just changed; precomputed masks are stale
-        valid_pre = None
-        # the warm-start carry measured arrival offsets on the OLD liveness
-        # set — a revived peer's stale offset (or a died relay's reachability)
-        # makes the re-based seed meaningless, so invalidate the whole carry
-        # (disseminate's certificate would catch a bad seed anyway; this
-        # keeps the next publish on the cheap no-rerun path)
-        warm = jnp.full_like(state.warm_offset_ms, 3.4e38)
+        with jax.named_scope("churn"):
+            dies = (jax.random.uniform(k_churn_d, (n,))
+                    < params.churn_down_per_hb)
+            revives = (jax.random.uniform(k_churn_u, (n,))
+                       < params.churn_up_per_hb)
+            if spared is not None:
+                # after the draw: nobody else's liveness moves with the mask
+                dies = dies & ~spared
+            alive = jnp.where(alive, ~dies, revives)
+            nbr_ok = None   # alive just changed; precomputed masks are stale
+            valid_pre = None
+            # the warm-start carry measured arrival offsets on the OLD
+            # liveness set — a revived peer's stale offset (or a died relay's
+            # reachability) makes the re-based seed meaningless, so
+            # invalidate the whole carry (disseminate's certificate would
+            # catch a bad seed anyway; this keeps the next publish on the
+            # cheap no-rerun path)
+            warm = jnp.full_like(state.warm_offset_ms, 3.4e38)
     else:
         warm = state.warm_offset_ms
 
-    if valid_pre is not None:
-        valid = valid_pre
-    else:
-        has_conn = conns >= 0
-        if nbr_ok is None:
-            # one pull for the conjunction (alive AND subscribed) — each pull
-            # is a full row-gather pass, so fusing the two masks halves the
-            # cost
-            nbr_ok = neighbor_pull_bool(
-                alive & state.subscribed, conns, rev, batch_factor)
-        valid = has_conn & alive[:, None] & nbr_ok & state.subscribed[:, None]
-    if edge_ok is not None:
-        # fault injection: a partitioned edge is invalid for the round even
-        # though both endpoints are alive; applied after valid_pre too, so
-        # the fault scan can hoist the liveness conjunction and still mask
-        valid = valid & edge_ok
+    with jax.named_scope("validity"):
+        if valid_pre is not None:
+            valid = valid_pre
+        else:
+            has_conn = conns >= 0
+            if nbr_ok is None:
+                # one pull for the conjunction (alive AND subscribed) — each
+                # pull is a full row-gather pass, so fusing the two masks
+                # halves the cost
+                nbr_ok = neighbor_pull_bool(
+                    alive & state.subscribed, conns, rev, batch_factor)
+            valid = (has_conn & alive[:, None] & nbr_ok
+                     & state.subscribed[:, None])
+        if edge_ok is not None:
+            # fault injection: a partitioned edge is invalid for the round
+            # even though both endpoints are alive; applied after valid_pre
+            # too, so the fault scan can hoist the liveness conjunction and
+            # still mask
+            valid = valid & edge_ok
 
-    if deg_in is not None:
-        # carried-degree protocol: mesh_mask ⊆ valid already (caller's
-        # pre-scan AND + every branch write re-ANDing), so the per-step
-        # mesh-AND and degree reduce are skipped outright
-        mesh = state.mesh_mask
-        deg = deg_in
-    else:
-        mesh = state.mesh_mask & valid  # drop edges to dead/unsubscribed
-        deg = mesh.sum(axis=-1)
+        if deg_in is not None:
+            # carried-degree protocol: mesh_mask ⊆ valid already (caller's
+            # pre-scan AND + every branch write re-ANDing), so the per-step
+            # mesh-AND and degree reduce are skipped outright
+            mesh = state.mesh_mask
+            deg = deg_in
+        else:
+            mesh = state.mesh_mask & valid  # drop edges to dead/unsubscribed
+            deg = mesh.sum(axis=-1)
 
     def _score_now():
         if decay_scales is None:
@@ -193,11 +216,11 @@ def heartbeat_step(
     # runs under a cond: at steady state every row sits in [D_low, D_high]
     # and the step skips straight through. Key consumption stays identical
     # either way (k_graft was split above).
-    need = jnp.where(deg < params.d_low, params.d - deg, 0)
-
-    # built from deg so it varies over whatever manual axes deg does: a
-    # cond under shard_map needs both branches to agree on them
-    zeros_n = jnp.zeros_like(deg, dtype=jnp.int32)
+    with jax.named_scope("graft"):
+        need = jnp.where(deg < params.d_low, params.d - deg, 0)
+        # built from deg so it varies over whatever manual axes deg does: a
+        # cond under shard_map needs both branches to agree on them
+        zeros_n = jnp.zeros_like(deg, dtype=jnp.int32)
 
     def do_graft(mesh):
         eligible = (valid & ~mesh & (state.backoff_until <= t)
@@ -216,17 +239,19 @@ def heartbeat_step(
                 grafted.sum(axis=-1, dtype=jnp.int32),
                 graft_rx.sum(axis=-1, dtype=jnp.int32))
 
-    mesh, deg2, graft_tx_inc, graft_rx_inc = jax.lax.cond(
-        (need > 0).any(),
-        do_graft,
-        lambda m: (m, deg, zeros_n, zeros_n),
-        mesh,
-    )
+    with jax.named_scope("graft"):
+        mesh, deg2, graft_tx_inc, graft_rx_inc = jax.lax.cond(
+            (need > 0).any(),
+            do_graft,
+            lambda m: (m, deg, zeros_n, zeros_n),
+            mesh,
+        )
 
     # -- PRUNE: |mesh| > D_high -> keep D (D_score best, >= D_out outbound) --
     # The whole selection (4 rank passes) plus the reciprocal pull runs under
     # a cond: at steady state no row exceeds D_high and the step skips it.
-    over = deg2 > params.d_high
+    with jax.named_scope("prune"):
+        over = deg2 > params.d_high
 
     def _prune_sel(mesh):
         rand_keep = jax.random.uniform(k_keep, (n, c))
@@ -257,23 +282,25 @@ def heartbeat_step(
                 pruned_by_peer)
 
     pruned_rx = None
-    if params.px:
-        # PX needs the received-PRUNE edge set out of the branch; the extra
-        # output exists only on the opt-in trace (ops/repair.py)
-        mesh, backoff, prune_tx_inc, prune_rx_inc, pruned_rx = jax.lax.cond(
-            over.any(),
-            _prune_sel,
-            lambda m: (m, state.backoff_until, zeros_n, zeros_n,
-                       jnp.zeros((n, c), dtype=bool)),
-            mesh,
-        )
-    else:
-        mesh, backoff, prune_tx_inc, prune_rx_inc = jax.lax.cond(
-            over.any(),
-            lambda m: _prune_sel(m)[:4],
-            lambda m: (m, state.backoff_until, zeros_n, zeros_n),
-            mesh,
-        )
+    with jax.named_scope("prune"):
+        if params.px:
+            # PX needs the received-PRUNE edge set out of the branch; the
+            # extra output exists only on the opt-in trace (ops/repair.py)
+            (mesh, backoff, prune_tx_inc, prune_rx_inc,
+             pruned_rx) = jax.lax.cond(
+                over.any(),
+                _prune_sel,
+                lambda m: (m, state.backoff_until, zeros_n, zeros_n,
+                           jnp.zeros((n, c), dtype=bool)),
+                mesh,
+            )
+        else:
+            mesh, backoff, prune_tx_inc, prune_rx_inc = jax.lax.cond(
+                over.any(),
+                lambda m: _prune_sel(m)[:4],
+                lambda m: (m, state.backoff_until, zeros_n, zeros_n),
+                mesh,
+            )
 
     # -- score eviction (mesh repair; opt-in via params.evict) ---------------
     # v1.1 mesh maintenance also drops members whose score sank below a
@@ -287,26 +314,27 @@ def heartbeat_step(
     ev_tx_inc = ev_rx_inc = None
     evict_fired = None
     ev_rx_edges = None
-    if params.evict:
-        ev_cand = mesh & (get_scores() < params.eviction_threshold)
-        evict_fired = ev_cand.any()
+    with jax.named_scope("evict"):
+        if params.evict:
+            ev_cand = mesh & (get_scores() < params.eviction_threshold)
+            evict_fired = ev_cand.any()
 
-        def do_evict(mesh, backoff):
-            ev_rx = _reciprocal_view(ev_cand, conns, rev, batch_factor)
-            new_backoff = jnp.where(
-                ev_cand | ev_rx, t + params.prune_backoff_ms, backoff)
-            return (mesh & ~ev_cand & ~ev_rx, new_backoff,
-                    ev_cand.sum(axis=-1, dtype=jnp.int32),
-                    ev_rx.sum(axis=-1, dtype=jnp.int32),
-                    ev_rx)
+            def do_evict(mesh, backoff):
+                ev_rx = _reciprocal_view(ev_cand, conns, rev, batch_factor)
+                new_backoff = jnp.where(
+                    ev_cand | ev_rx, t + params.prune_backoff_ms, backoff)
+                return (mesh & ~ev_cand & ~ev_rx, new_backoff,
+                        ev_cand.sum(axis=-1, dtype=jnp.int32),
+                        ev_rx.sum(axis=-1, dtype=jnp.int32),
+                        ev_rx)
 
-        mesh, backoff, ev_tx_inc, ev_rx_inc, ev_rx_edges = jax.lax.cond(
-            evict_fired,
-            do_evict,
-            lambda m, b: (m, b, zeros_n, zeros_n,
-                          jnp.zeros((n, c), dtype=bool)),
-            mesh, backoff,
-        )
+            mesh, backoff, ev_tx_inc, ev_rx_inc, ev_rx_edges = jax.lax.cond(
+                evict_fired,
+                do_evict,
+                lambda m, b: (m, b, zeros_n, zeros_n,
+                              jnp.zeros((n, c), dtype=bool)),
+                mesh, backoff,
+            )
 
     # -- PX on PRUNE (mesh repair; opt-in via params.px) ---------------------
     # Every PRUNE (degree rebalance or eviction) carries up to px_count
@@ -317,38 +345,39 @@ def heartbeat_step(
     # (ops/repair.py repair_round). Deterministic slot-index tiebreak: no
     # PRNG is consumed, keeping the default key schedule untouched.
     px_pool = None
-    if params.px:
-        got_pruned = pruned_rx
-        if ev_rx_edges is not None:
-            got_pruned = got_pruned | ev_rx_edges
+    with jax.named_scope("px"):
+        if params.px:
+            got_pruned = pruned_rx
+            if ev_rx_edges is not None:
+                got_pruned = got_pruned | ev_rx_edges
 
-        def do_px(pool):
-            scores = get_scores()
-            elig = valid & (scores >= 0.0)
-            prio = (jnp.where(elig, -scores, BIG)
-                    + 1e-4 * jnp.arange(c, dtype=jnp.float32))
-            w = min(PX_POOL_WIDTH, c)
-            order = jnp.argsort(prio, axis=-1)[:, :w]
-            take_ok = (jnp.take_along_axis(elig, order, axis=-1)
-                       & (jnp.arange(w) < params.px_count))
-            cand = jnp.where(
-                take_ok, jnp.take_along_axis(conns, order, axis=-1), -1)
-            if w < PX_POOL_WIDTH:
-                cand = jnp.pad(cand, ((0, 0), (0, PX_POOL_WIDTH - w)),
-                               constant_values=-1)
-            # the prunee reads the advert off ONE pruning edge (the lowest
-            # pruning slot) — one row-gather through the involution, same
-            # shape economics as _reciprocal_view
-            got = got_pruned.any(axis=-1)
-            i0 = jnp.argmax(got_pruned, axis=-1)
-            pruner = jnp.take_along_axis(conns, i0[:, None], axis=1)[:, 0]
-            advert = cand[jnp.clip(pruner, 0)]
-            advert = jnp.where(
-                advert == jnp.arange(n, dtype=jnp.int32)[:, None], -1, advert)
-            return jnp.where(got[:, None], advert, pool)
+            def do_px(pool):
+                scores = get_scores()
+                elig = valid & (scores >= 0.0)
+                prio = (jnp.where(elig, -scores, BIG)
+                        + 1e-4 * jnp.arange(c, dtype=jnp.float32))
+                w = min(PX_POOL_WIDTH, c)
+                order = jnp.argsort(prio, axis=-1)[:, :w]
+                take_ok = (jnp.take_along_axis(elig, order, axis=-1)
+                           & (jnp.arange(w) < params.px_count))
+                cand = jnp.where(
+                    take_ok, jnp.take_along_axis(conns, order, axis=-1), -1)
+                if w < PX_POOL_WIDTH:
+                    cand = jnp.pad(cand, ((0, 0), (0, PX_POOL_WIDTH - w)),
+                                   constant_values=-1)
+                # the prunee reads the advert off ONE pruning edge (the lowest
+                # pruning slot) — one row-gather through the involution, same
+                # shape economics as _reciprocal_view
+                got = got_pruned.any(axis=-1)
+                i0 = jnp.argmax(got_pruned, axis=-1)
+                pruner = jnp.take_along_axis(conns, i0[:, None], axis=1)[:, 0]
+                advert = cand[jnp.clip(pruner, 0)]
+                advert = jnp.where(
+                    advert == jnp.arange(n, dtype=jnp.int32)[:, None], -1, advert)
+                return jnp.where(got[:, None], advert, pool)
 
-        px_pool = jax.lax.cond(
-            got_pruned.any(), do_px, lambda p: p, state.px_pool)
+            px_pool = jax.lax.cond(
+                got_pruned.any(), do_px, lambda p: p, state.px_pool)
 
     # -- opportunistic grafting (v1.1, main.nim:292): when the MEDIAN mesh
     # score sinks below the threshold, graft up to 2 peers scoring above the
@@ -356,101 +385,106 @@ def heartbeat_step(
     # disabled default (-10000) the sort never enters the compiled step.
     og_tx_inc = zeros_n
     og_rx_inc = zeros_n
-    if params.opportunistic_graft_threshold > -9999.0:
-        scores = get_scores()
-        deg3 = mesh.sum(axis=-1)
-        msort = jnp.sort(jnp.where(mesh, scores, BIG), axis=-1)
-        # upper median (sorted[len/2]) — matches the libp2p implementations
-        k_med = jnp.clip(deg3 // 2, 0, c - 1)
-        median = jnp.take_along_axis(msort, k_med[:, None], axis=-1)[:, 0]
-        low = (median < params.opportunistic_graft_threshold) & (deg3 > 0)
-        og_elig = (valid & ~mesh & (backoff <= t)
-                   & (scores > median[:, None]) & low[:, None])
-        og_prio = jnp.where(og_elig, -scores, BIG)  # best scores first
-        og = (_ranks(og_prio) < 2) & og_elig
-        # same steady-state economics as graft/prune: the reciprocal pull
-        # and the counter reduces only run when something actually grafted
-        def do_og(m):
-            rx = _reciprocal_view(og, conns, rev, batch_factor)
-            return ((m | og | rx) & valid,
-                    og.sum(axis=-1, dtype=jnp.int32),
-                    rx.sum(axis=-1, dtype=jnp.int32))
+    with jax.named_scope("opportunistic"):
+        if params.opportunistic_graft_threshold > -9999.0:
+            scores = get_scores()
+            deg3 = mesh.sum(axis=-1)
+            msort = jnp.sort(jnp.where(mesh, scores, BIG), axis=-1)
+            # upper median (sorted[len/2]) — matches the libp2p implementations
+            k_med = jnp.clip(deg3 // 2, 0, c - 1)
+            median = jnp.take_along_axis(msort, k_med[:, None], axis=-1)[:, 0]
+            low = (median < params.opportunistic_graft_threshold) & (deg3 > 0)
+            og_elig = (valid & ~mesh & (backoff <= t)
+                       & (scores > median[:, None]) & low[:, None])
+            og_prio = jnp.where(og_elig, -scores, BIG)  # best scores first
+            og = (_ranks(og_prio) < 2) & og_elig
+            # same steady-state economics as graft/prune: the reciprocal pull
+            # and the counter reduces only run when something actually grafted
+            def do_og(m):
+                rx = _reciprocal_view(og, conns, rev, batch_factor)
+                return ((m | og | rx) & valid,
+                        og.sum(axis=-1, dtype=jnp.int32),
+                        rx.sum(axis=-1, dtype=jnp.int32))
 
-        mesh, og_tx_inc, og_rx_inc = jax.lax.cond(
-            og.any(),
-            do_og,
-            lambda m: (m, zeros_n, zeros_n),
-            mesh,
-        )
+            mesh, og_tx_inc, og_rx_inc = jax.lax.cond(
+                og.any(),
+                do_og,
+                lambda m: (m, zeros_n, zeros_n),
+                mesh,
+            )
 
     # -- score decay (decayInterval == heartbeat here; main.nim:272-273) -----
-    if decay_scales is not None:
-        # deferred: the scan carries the scalar scales; arrays untouched
-        fmd, slow = state.fmd, state.slow_penalty
-    else:
-        # gated: once everything decayed to zero (no recent messages) the
-        # two (N, C) rewrite passes per step are skipped
-        def do_decay(fmd, slow):
-            return (_apply_decay(fmd, params.fmd_decay, params),
-                    _apply_decay(slow, params.slow_decay, params))
+    with jax.named_scope("decay"):
+        if decay_scales is not None:
+            # deferred: the scan carries the scalar scales; arrays untouched
+            fmd, slow = state.fmd, state.slow_penalty
+        else:
+            # gated: once everything decayed to zero (no recent messages) the
+            # two (N, C) rewrite passes per step are skipped
+            def do_decay(fmd, slow):
+                return (_apply_decay(fmd, params.fmd_decay, params),
+                        _apply_decay(slow, params.slow_decay, params))
 
-        fmd, slow = jax.lax.cond(
-            # one fused (N, C) reduce for the predicate, not one per array
-            ((state.fmd > 0) | (state.slow_penalty > 0)).any(),
-            do_decay,
-            lambda f, s: (f, s),
-            state.fmd, state.slow_penalty,
-        )
+            fmd, slow = jax.lax.cond(
+                # one fused (N, C) reduce for the predicate, not one per array
+                ((state.fmd > 0) | (state.slow_penalty > 0)).any(),
+                do_decay,
+                lambda f, s: (f, s),
+                state.fmd, state.slow_penalty,
+            )
 
     # -- fanout expiry (v1.1 fanoutTTL): a fanout set whose owner hasn't
     # fanout-published within the TTL is dropped wholesale (nim-libp2p
     # dropFanoutPeers). Gated on the (N,) expiry stamps — nonzero only for
     # peers that ever fanout-published — so runs with no fanout publishers
     # pay an (N,) reduce, not an (N, C) one.
-    fanout = jax.lax.cond(
-        (state.fanout_expire > 0.0).any(),
-        lambda fm: fm & (t < state.fanout_expire)[:, None],
-        lambda fm: fm,
-        state.fanout_mask,
-    )
+    with jax.named_scope("fanout"):
+        fanout = jax.lax.cond(
+            (state.fanout_expire > 0.0).any(),
+            lambda fm: fm & (t < state.fanout_expire)[:, None],
+            lambda fm: fm,
+            state.fanout_mask,
+        )
 
-    prunes_new = state.prunes + prune_tx_inc
-    prunes_rx_new = state.prunes_rx + prune_rx_inc
-    repair_extra = {}
-    if params.evict:
-        # an eviction IS a PRUNE control message; count it in both ledgers
-        prunes_new = prunes_new + ev_tx_inc
-        prunes_rx_new = prunes_rx_new + ev_rx_inc
-        repair_extra["evictions"] = state.evictions + ev_tx_inc
-    if params.px:
-        repair_extra["px_pool"] = px_pool
-    new_state = state.replace(
-        mesh_mask=mesh,
-        fanout_mask=fanout,
-        backoff_until=backoff,
-        fmd=fmd,
-        slow_penalty=slow,
-        alive=alive,
-        warm_offset_ms=warm,
-        t_ms=t + params.heartbeat_ms,
-        key=key,
-        grafts=state.grafts + graft_tx_inc + og_tx_inc,
-        grafts_rx=state.grafts_rx + graft_rx_inc + og_rx_inc,
-        prunes=prunes_new,
-        prunes_rx=prunes_rx_new,
-        **repair_extra,
-    )
+    with jax.named_scope("state"):
+        prunes_new = state.prunes + prune_tx_inc
+        prunes_rx_new = state.prunes_rx + prune_rx_inc
+        repair_extra = {}
+        if params.evict:
+            # an eviction IS a PRUNE control message; count it in both ledgers
+            prunes_new = prunes_new + ev_tx_inc
+            prunes_rx_new = prunes_rx_new + ev_rx_inc
+            repair_extra["evictions"] = state.evictions + ev_tx_inc
+        if params.px:
+            repair_extra["px_pool"] = px_pool
+        new_state = state.replace(
+            mesh_mask=mesh,
+            fanout_mask=fanout,
+            backoff_until=backoff,
+            fmd=fmd,
+            slow_penalty=slow,
+            alive=alive,
+            warm_offset_ms=warm,
+            t_ms=t + params.heartbeat_ms,
+            key=key,
+            grafts=state.grafts + graft_tx_inc + og_tx_inc,
+            grafts_rx=state.grafts_rx + graft_rx_inc + og_rx_inc,
+            prunes=prunes_new,
+            prunes_rx=prunes_rx_new,
+            **repair_extra,
+        )
     if deg_in is None:
         return new_state
     # carried degree: re-reduce only if some branch actually touched the
     # mesh this step — the steady-state round stays free of (N, C) reduces
-    fired = (need > 0).any() | over.any()
-    if params.opportunistic_graft_threshold > -9999.0:
-        fired = fired | og.any()
-    if params.evict:
-        fired = fired | evict_fired
-    deg_out = jax.lax.cond(
-        fired, lambda m: m.sum(axis=-1), lambda m: deg_in, mesh)
+    with jax.named_scope("validity"):
+        fired = (need > 0).any() | over.any()
+        if params.opportunistic_graft_threshold > -9999.0:
+            fired = fired | og.any()
+        if params.evict:
+            fired = fired | evict_fired
+        deg_out = jax.lax.cond(
+            fired, lambda m: m.sum(axis=-1), lambda m: deg_in, mesh)
     return new_state, deg_out
 
 
@@ -461,6 +495,7 @@ def run_heartbeats(
     out_mask: jnp.ndarray,
     params: SimParams,
     steps: int,
+    spared: jnp.ndarray | None = None,
 ) -> SimState:
     """lax.scan over heartbeat rounds — simulated time scales in rounds with
     no host sync (the reference's 'long simulated time' axis, SURVEY.md §5).
@@ -471,12 +506,16 @@ def run_heartbeats(
     passthrough buffers per segment (ops/state.py strip_repair). NOT
     donated: callers (tests) re-run segments from a kept state.
     Jitted with static `steps` so repeated same-length segments (the
-    simulator's inter-message gaps) hit the compile cache."""
+    simulator's inter-message gaps) hit the compile cache.
+
+    `spared`: heartbeat_step's — (N,) peers the churn draw does not kill;
+    None with churn off, where nothing would read it."""
     if repair_inert(params):
         state, saved = strip_repair(state)
-        out = _run_heartbeats(state, conns, rev, out_mask, params, steps)
+        out = _run_heartbeats(
+            state, conns, rev, out_mask, params, steps, spared)
         return restore_repair(out, saved)
-    return _run_heartbeats(state, conns, rev, out_mask, params, steps)
+    return _run_heartbeats(state, conns, rev, out_mask, params, steps, spared)
 
 
 @partial(jax.jit, static_argnames=("params", "steps"))
@@ -487,6 +526,7 @@ def _run_heartbeats(
     out_mask: jnp.ndarray,
     params: SimParams,
     steps: int,
+    spared: jnp.ndarray | None = None,
 ) -> SimState:
 
     nbr_ok = None
@@ -495,16 +535,20 @@ def _run_heartbeats(
         # alive/subscribed are invariant across the scan without churn, so
         # the neighbor pull — a full row-gather pass — hoists out of the
         # loop, and so does the whole edge-validity conjunction
-        nbr_ok = neighbor_pull_bool(state.alive & state.subscribed, conns, rev)
-        valid_pre = ((conns >= 0) & state.alive[:, None] & nbr_ok
-                     & state.subscribed[:, None])
+        with jax.named_scope("validity"):
+            nbr_ok = neighbor_pull_bool(
+                state.alive & state.subscribed, conns, rev)
+            valid_pre = ((conns >= 0) & state.alive[:, None] & nbr_ok
+                         & state.subscribed[:, None])
 
     one = jnp.float32(1.0)
     if valid_pre is not None:
         # carried-degree protocol: establish mesh_mask ⊆ valid ONCE (the
         # AND every step used to apply), then the steady-state round pays
         # no (N, C) mesh-AND or degree reduce at all
-        mesh0 = state.mesh_mask & valid_pre
+        with jax.named_scope("validity"):
+            mesh0 = state.mesh_mask & valid_pre
+            deg0 = mesh0.sum(axis=-1)
         state = state.replace(mesh_mask=mesh0)
 
         def body(carry, _):
@@ -512,27 +556,31 @@ def _run_heartbeats(
             s, deg = heartbeat_step(
                 s, conns, rev, out_mask, params, nbr_ok=nbr_ok,
                 valid_pre=valid_pre, decay_scales=(f_sc, s_sc), deg_in=deg)
-            return (s, deg, f_sc * params.fmd_decay,
-                    s_sc * params.slow_decay), None
+            with jax.named_scope("decay"):
+                f_sc, s_sc = f_sc * params.fmd_decay, s_sc * params.slow_decay
+            return (s, deg, f_sc, s_sc), None
 
         (state, _, f_sc, s_sc), _ = jax.lax.scan(
-            body, (state, mesh0.sum(axis=-1), one, one), None, length=steps)
+            body, (state, deg0, one, one), None, length=steps)
     else:
         def body(carry, _):
             s, f_sc, s_sc = carry
             s = heartbeat_step(
                 s, conns, rev, out_mask, params, nbr_ok=nbr_ok,
-                valid_pre=valid_pre, decay_scales=(f_sc, s_sc))
+                valid_pre=valid_pre, decay_scales=(f_sc, s_sc),
+                spared=spared)
             # end-of-round decay, factored to two scalar multiplies
-            return (s, f_sc * params.fmd_decay,
-                    s_sc * params.slow_decay), None
+            with jax.named_scope("decay"):
+                f_sc, s_sc = f_sc * params.fmd_decay, s_sc * params.slow_decay
+            return (s, f_sc, s_sc), None
 
         (state, f_sc, s_sc), _ = jax.lax.scan(
             body, (state, one, one), None, length=steps)
     # materialize the deferred decay ONCE per scan (vs two (N, C) passes
     # plus a predicate reduce per round): exact, because geometric decay
     # with a monotone zero-cutoff commutes with deferral
-    return state.replace(
-        fmd=_apply_decay(state.fmd, f_sc, params),
-        slow_penalty=_apply_decay(state.slow_penalty, s_sc, params),
-    )
+    with jax.named_scope("decay"):
+        return state.replace(
+            fmd=_apply_decay(state.fmd, f_sc, params),
+            slow_penalty=_apply_decay(state.slow_penalty, s_sc, params),
+        )

@@ -159,12 +159,15 @@ def _validity(st, conns, rev, alive, edge_ok):
 
 
 def spec_heartbeat(st: dict, conns, rev, out_mask, params: SimParams,
-                   edge_ok=None, og_tie_highest: bool = False) -> dict:
+                   edge_ok=None, og_tie_highest: bool = False,
+                   spared=None) -> dict:
     """One heartbeat of the reference transition relation — the spec twin of
     ops/heartbeat.heartbeat_step on its per-step (non-deferred-decay) path.
     Branch guards mirror the engine's lax.cond predicates exactly: a guard
     that does not fire leaves its fields untouched AND consumes no extra
-    randomness (both k_graft and k_keep are split unconditionally)."""
+    randomness (both k_graft and k_keep are split unconditionally).
+    `spared`: (N,) bool, the peers churn does not kill (the nodes an
+    injector publishes through); everyone else dies as the draw says."""
     import jax
 
     st = dict(st)
@@ -179,6 +182,8 @@ def spec_heartbeat(st: dict, conns, rev, out_mask, params: SimParams,
                 < np.float32(params.churn_down_per_hb))
         revives = (np.asarray(jax.random.uniform(k_churn_u, (n,)))
                    < np.float32(params.churn_up_per_hb))
+        if spared is not None:
+            dies = dies & ~np.asarray(spared)
         alive = np.where(alive, ~dies, revives)
         warm = np.full_like(st["warm_offset_ms"], INF)
     else:

@@ -212,9 +212,11 @@ def run_recorded_heartbeats(
     steps: int,
     telemetry: TelemetryParams | None = None,
     batch_factor: int = 1,
+    spared: jnp.ndarray | None = None,
 ):
     """run_heartbeats with the flight recorder: returns (state, trace) where
     trace maps each tel_* channel to a (steps,) or (steps, k) curve.
+    `spared` is run_heartbeats' (peers the churn draw does not kill).
 
     Disabled (`telemetry` None or record=False) this IS run_heartbeats —
     the same call, the same jit cache entry, the same output buffers — and
@@ -223,16 +225,18 @@ def run_recorded_heartbeats(
     scores apply the running scales on the fly), so the final state is
     bit-identical to the untraced runner; only the outputs grow."""
     if telemetry is None or not telemetry.enabled:
-        return run_heartbeats(state, conns, rev, out_mask, params, steps), {}
+        return run_heartbeats(state, conns, rev, out_mask, params, steps,
+                              spared=spared), {}
     telemetry.validate()
     if repair_inert(params):
         state, saved = strip_repair(state)
         out, trace = _run_recorded_heartbeats(
             state, conns, rev, out_mask, params, telemetry, steps,
-            batch_factor)
+            batch_factor, spared)
         return restore_repair(out, saved), trace
     return _run_recorded_heartbeats(
-        state, conns, rev, out_mask, params, telemetry, steps, batch_factor)
+        state, conns, rev, out_mask, params, telemetry, steps, batch_factor,
+        spared)
 
 
 @partial(jax.jit,
@@ -246,6 +250,7 @@ def _run_recorded_heartbeats(
     telemetry: TelemetryParams,
     steps: int,
     batch_factor: int = 1,
+    spared: jnp.ndarray | None = None,
 ):
     # mirror of ops/heartbeat._run_heartbeats with a per-round telemetry
     # emission — the hoist/carry/deferral decisions must stay in lockstep
@@ -285,7 +290,7 @@ def _run_recorded_heartbeats(
             s = heartbeat_step(
                 s, conns, rev, out_mask, params, batch_factor=batch_factor,
                 nbr_ok=nbr_ok, valid_pre=valid_pre,
-                decay_scales=(f_sc, s_sc))
+                decay_scales=(f_sc, s_sc), spared=spared)
             f2, s2 = f_sc * params.fmd_decay, s_sc * params.slow_decay
             obs = telemetry_observables(
                 s, conns, rev, params, telemetry, batch_factor=batch_factor,

@@ -23,12 +23,14 @@ from __future__ import annotations
 
 from dataclasses import dataclass, field
 
+import jax
 import numpy as np
 
 from ..config.env import GossipSubParams
 from ..config.topology import Topology, TopoParams
 from ..ops.disseminate import disseminate as _disseminate_program
-from ..ops.disseminate import fixpoint_formulation, fragments_in_sequence
+from ..ops.disseminate import (fixpoint_formulation, fragments_in_sequence,
+                               valid_edge_at_publish, valid_edge_of)
 from ..ops.graph import build_connection_graph
 from ..ops.heartbeat import run_heartbeats
 from ..ops.state import SimParams, graph_arrays, init_state
@@ -69,6 +71,14 @@ _MUXER_CROSSINGS = {"yamux": 4.0, "mplex": 4.4, "quic": 3.0}
 MUXER_PROC_MS = {m: EVENT_LOOP_MS * x for m, x in _MUXER_CROSSINGS.items()}
 
 _INF_CUTOFF = 1e30
+
+
+class PublisherDownError(RuntimeError):
+    """A publish was asked of a peer that is dead at that moment. Under
+    churn the peers `Simulator.run` publishes through are spared by the
+    draw, so this is a caller's own choice of publisher (or state); a dead
+    node sends nothing, and a record of it would read as a message nobody
+    received."""
 
 
 class MixDegradedError(RuntimeError):
@@ -129,6 +139,18 @@ class ExperimentConfig:
     msgid_mode: str = "nim"
 
 
+def scheduled_publishers(cfg: ExperimentConfig) -> list[int]:
+    """The peers `Simulator.run` publishes through, in order of first use:
+    publisher_id, or with rotation one peer on for every message
+    (run.sh:16-17, 34-35)."""
+    n = cfg.topo.network_size
+    first = cfg.publisher_id % n
+    if not cfg.publisher_rotation:
+        return [first]
+    return list(dict.fromkeys(
+        (first + i) % n for i in range(cfg.topo.messages)))
+
+
 def graph_capacity(cfg: ExperimentConfig) -> int:
     """Neighbor-table width C of the (N, C) arrays a Simulator builds for
     `cfg`: MAXCONNECTIONS, or the dial-count slack that keeps rejections
@@ -179,10 +201,13 @@ def record_from_result(
     # run (multitopic's per-topic projection, a publish_batch column) carry
     # none: their records read converged and no refinement
     packed = getattr(res, "counters", None)
+    values = ([0, 0, 0, 0, 1, 0, 0, 0, 0] if packed is None
+              else [int(v) for v in np.asarray(packed)])
     (fast_iters, refine_passes, refined, fell_back, converged,
-     refined_serial, refine_lane_passes, lanes_hinted, lanes_uncertified) = (
-        (0, 0, 0, 0, 1, 0, 0, 0, 0) if packed is None
-        else (int(v) for v in np.asarray(packed)))
+     refined_serial, refine_lane_passes, lanes_hinted,
+     lanes_uncertified) = values[:9]
+    # under churn two more: who could send, and who of them sat under D_low
+    alive, under_dlow = values[9:] or (None, None)
     return MessageRecord(
         msg_id=msg_id,
         publisher=publisher,
@@ -206,6 +231,8 @@ def record_from_result(
         refine_lane_passes=refine_lane_passes,
         lanes_hinted=lanes_hinted,
         lanes_uncertified=lanes_uncertified,
+        alive=alive,
+        under_dlow=under_dlow,
     )
 
 
@@ -259,6 +286,11 @@ class MessageRecord:
     refine_lane_passes: int = 0
     lanes_hinted: int = 0
     lanes_uncertified: int = 0
+    # DisseminationResult.alive / under_dlow: under churn, the peers that
+    # could send at this publish and those of them under D_low valid mesh
+    # members (`stats<i>.json` "churn"); None without churn
+    alive: int | None = None
+    under_dlow: int | None = None
 
     @property
     def receivers(self) -> np.ndarray:
@@ -344,8 +376,6 @@ class Simulator:
                               self.arrays["rev"])
                 if cfg.with_gossip else None)
             if mesh is not None:
-                import jax
-
                 from ..parallel.sharding import place_simulation, reshard_rows
 
                 (self.state, self.arrays, self._stage, self._lat, self._bw,
@@ -364,6 +394,22 @@ class Simulator:
             self._churny = (cfg.churn_down_per_hb > 0.0
                             or cfg.churn_up_per_hb > 0.0)
             self._valid_edge = None if self._churny else self._compute_valid_edge()
+            # the node the injector publishes through does not churn (the
+            # reference's injector POSTs to a named pod; a message from a
+            # dead node measures nothing): the churn draw spares the peers
+            # run() publishes through. Absent, not all-false, with churn
+            # off: no churn-free program sees an argument more
+            self.spared_peers: list[int] = []
+            self._spared = None
+            if self._churny:
+                self.spared_peers = scheduled_publishers(cfg)
+                spared = np.zeros(n, dtype=bool)
+                spared[self.spared_peers] = True
+                self._spared = jnp.asarray(spared)
+                if mesh is not None:
+                    from ..parallel.sharding import reshard_rows
+
+                    self._spared = reshard_rows(self._spared, mesh)
         # host mirror of state.subscribed: publish() picks the fanout code
         # path (static arg) without a device sync; keep in sync via
         # set_subscribed()
@@ -399,15 +445,10 @@ class Simulator:
         """Hoisted per-edge delivery validity (connected AND the neighbor
         alive & subscribed): one row-gather pass here instead of one per
         publish. Only valid while liveness/membership is static — churny
-        runs keep it None and disseminate falls back in-call."""
-        import jax.numpy as jnp
-
-        from ..ops.pull import neighbor_pull_bool
-
-        conns = self.arrays["conns"]
-        return (conns >= 0) & neighbor_pull_bool(
-            self.state.alive & self.state.subscribed, conns,
-            self.arrays["rev"])
+        runs keep it None and every publish makes its own
+        (`valid_edge_at_publish`)."""
+        return valid_edge_of(self.state.alive, self.state.subscribed,
+                             self.arrays["conns"], self.arrays["rev"])
 
     # ---------------------------------------------------------------- phases
 
@@ -509,8 +550,6 @@ class Simulator:
             if self.cfg.with_gossip else None)
         warm = jnp.full((self.params.n,), 3.4e38, dtype=jnp.float32)
         if self.mesh is not None:
-            import jax
-
             from ..parallel.sharding import reshard_rows
 
             self.arrays = {k: reshard_rows(v, self.mesh)
@@ -549,13 +588,14 @@ class Simulator:
 
                 self.state, trace = run_recorded_heartbeats(
                     self.state, a["conns"], a["rev"], a["out_mask"],
-                    self.params, steps, telemetry=self._telemetry)
+                    self.params, steps, telemetry=self._telemetry,
+                    spared=self._spared)
                 self.last_telemetry = {
                     k: np.asarray(v) for k, v in trace.items()}
             else:
                 self.state = run_heartbeats(
                     self.state, a["conns"], a["rev"], a["out_mask"],
-                    self.params, steps)
+                    self.params, steps, spared=self._spared)
 
     def warmup(self) -> None:
         self.advance(self.cfg.warmup_s * 1000.0)
@@ -578,13 +618,31 @@ class Simulator:
                 cfg = self.cfg
                 size = msg_size if msg_size is not None else cfg.topo.msg_size_bytes
                 a = self.arrays
-                t0_ms = float(self.state.t_ms) + self._hb_carry_ms
+                valid_edge = self._valid_edge
+                if self._churny:
+                    # liveness moved since the last publish: the per-edge
+                    # validity is this publish's own (one fused row pull,
+                    # one dispatch), and the publisher's liveness comes
+                    # with the read of t_ms
+                    with span("publish/valid_edge"):
+                        valid_edge, up = valid_edge_at_publish(
+                            self.state.alive, self.state.subscribed,
+                            a["conns"], a["rev"], publisher)
+                    t_ms, up = jax.device_get((self.state.t_ms, up))
+                    if not up:
+                        raise PublisherDownError(
+                            f"peer {publisher} is dead at t={float(t_ms)} ms "
+                            "and cannot publish (the churn draw spares "
+                            f"{self.spared_peers}: the peers run() "
+                            "publishes through)")
+                else:
+                    t_ms = self.state.t_ms
+                t0_ms = float(t_ms) + self._hb_carry_ms
                 origin = publisher
                 mix_delay = 0.0
                 if self.mix_params is not None:
                     # relay through the mix network first; the exit node publishes
                     # on the origin's behalf (ops/mix.py, README.md:42-46)
-                    import jax
                     import jax.numpy as jnp
 
                     from ..ops.mix import eligible_mix_count, mix_route, mix_wire_bytes
@@ -662,7 +720,7 @@ class Simulator:
                     lat_edge=self._lat_edge,
                     loss_edge=self._loss_edge,
                     ans_tables=self._ans_tables,
-                    valid_edge=self._valid_edge,
+                    valid_edge=valid_edge,
                     censor_edge=censor_edge,
                     # unsubscribed publisher -> gossipsub v1.1 fanout publish
                     with_fanout=not bool(self._subscribed_np[publisher]),
@@ -715,7 +773,9 @@ class Simulator:
                 rounds=self.params.history_gossip if cfg.with_gossip else 0,
                 formulation=fixpoint_formulation(a["conns"].shape, self.mesh),
                 in_sequence=int(fragments_in_sequence(
-                    a["conns"].shape, cfg.topo.num_frags, self.mesh)))
+                    a["conns"].shape, cfg.topo.num_frags, self.mesh)),
+                **({} if rec.alive is None else
+                   {"alive": rec.alive, "under_dlow": rec.under_dlow}))
         return rec
 
     def publish_batch(

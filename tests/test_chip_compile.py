@@ -130,3 +130,26 @@ def test_sharded_fixpoint_compiles_for_four_chips(peer_mesh, capacity):
     assert _device_bytes(compiled) < V5E_HBM_BYTES
     t_out, inc_out, _, _ = compiled.output_shardings
     assert t_out.spec == P("peers") and inc_out.spec == P("peers")
+
+
+def test_churned_scan_compiles_for_v5e(one_chip, capacity):
+    """The half of `_run_heartbeats` a churned network takes, at the shape of
+    the benchmark's runsh-100k-churn: the draws, the neighbour pull and the
+    validity conjunction every step, the spared mask as an argument."""
+    from dst_libp2p_test_node_tpu.ops.heartbeat import _run_heartbeats
+    from dst_libp2p_test_node_tpu.ops.state import (
+        SimParams, init_state, strip_repair)
+
+    params = SimParams(n=N, capacity=capacity, churn_down_per_hb=1e-4,
+                       churn_up_per_hb=5e-5)
+    state = jax.tree_util.tree_map(
+        lambda s: one_chip(s.shape, s.dtype),
+        jax.eval_shape(lambda: strip_repair(init_state(params, seed=0))[0]))
+    compiled = _run_heartbeats.lower(
+        state, one_chip((N, capacity), jnp.int32),
+        one_chip((N, capacity), jnp.int32), one_chip((N, capacity), jnp.bool_),
+        params, 500, one_chip((N,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    # the pull is in the loop's body, not in front of it
+    assert "while" in text and "gather" in text
+    assert _device_bytes(compiled) < 0.1 * V5E_HBM_BYTES

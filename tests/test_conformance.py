@@ -62,6 +62,47 @@ def test_churn_differential_is_clean():
     assert divs == [], divs[:3]
 
 
+@pytest.mark.parametrize("spared", [[5], [0, 17, 31]])
+def test_churn_differential_with_spared_peers_is_clean(spared):
+    """The plain heartbeat under churn with peers the draw does not kill
+    (a Simulator spares those it publishes through), step for step against
+    the spec with the same mask: exact; the walk itself checks that the
+    spared peers lived."""
+    divs = run_churn_differential(n=48, steps=12, warm_steps=6,
+                                  spared=spared)
+    assert divs == [], divs[:3]
+
+
+def test_sparing_moves_the_spared_peer_alone():
+    """The spec's own statement of the mask: with and without it the walk's
+    liveness differs at the spared peers and nowhere else."""
+    import numpy as np
+
+    from dst_libp2p_test_node_tpu.ops.graph import build_connection_graph
+    from dst_libp2p_test_node_tpu.ops.spec import host_state, spec_heartbeat
+    from dst_libp2p_test_node_tpu.ops.state import (
+        SimParams, graph_arrays, init_state)
+
+    g = build_connection_graph(48, 8, seed=0)
+    params = SimParams(n=48, capacity=g.capacity, churn_down_per_hb=0.05,
+                       churn_up_per_hb=0.02)
+    hosts = {k: np.asarray(v) for k, v in graph_arrays(g).items()}
+    def walk(spared=None):
+        st, seen = host_state(init_state(params, seed=0)), []
+        for _ in range(40):
+            st = spec_heartbeat(st, hosts["conns"], hosts["rev"],
+                                hosts["out_mask"], params, spared=spared)
+            seen.append(st["alive"])
+        return np.stack(seen)
+
+    plain = walk()
+    mask = np.zeros(48, bool)
+    mask[np.nonzero(~plain.all(axis=0))[0][:2]] = True   # two that die
+    masked = walk(mask)
+    assert masked[:, mask].all() and not plain[:, mask].all(axis=0).any()
+    np.testing.assert_array_equal(masked[:, ~mask], plain[:, ~mask])
+
+
 @pytest.mark.parametrize("mutant", sorted(MUTANTS))
 def test_mutant_is_caught_as_sim_bug(mutant):
     """The differential discriminates: a step that violates the spec (drops

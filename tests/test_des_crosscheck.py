@@ -622,6 +622,46 @@ def test_fixpoint_matches_des_four_fragments(n, ct, seed, stages, heavy):
     _compare(res, plan, a["conns"], a["rev"], params, pub, t0, 4)
 
 
+CHURN_CASES = [
+    # (n, connect_to, seed, stages, fragments, gossip): the publish of the
+    # benchmark's runsh-100k-churn at a test's size: 40 churned heartbeats
+    # (2 % down, 1 % up a heartbeat) leave a third of the peers dead, their
+    # edges out of every mesh and some of the living short of D_low
+    (128, 8, 70, 5, 1, True),
+    (128, 8, 71, 5, 1, False),
+    (300, 10, 72, 5, 4, True),
+    (300, 10, 73, 5, 4, False),
+]
+
+
+@pytest.mark.parametrize("n,ct,seed,stages,frags,gossip", CHURN_CASES)
+def test_fixpoint_matches_des_under_churn(n, ct, seed, stages, frags, gossip):
+    g, params, state, a, (stage, lat, bw) = _setup(
+        n, ct, seed, stages, hb_steps=40, churn_down_per_hb=0.02,
+        churn_up_per_hb=0.01)
+    alive = np.asarray(state.alive)
+    assert 0.4 * n < alive.sum() < 0.9 * n
+    pub = int(np.nonzero(alive)[0][seed % 7])
+    t0 = float(state.t_ms)
+    res, _, plan = disseminate(
+        state, a["conns"], a["rev"], stage, lat, bw, publisher=pub,
+        t0_ms=t0, params=params, payload_bytes=15000, fragments=frags,
+        with_gossip=gossip, return_plan=True)
+    plan = dict(plan)
+    np.testing.assert_array_equal(np.asarray(plan["can_send"]), alive)
+    if not gossip:
+        # the engine exports gossip targets even with with_gossip=False; a
+        # mesh-only publish announces nothing
+        plan["g_tgt_w"] = np.zeros_like(np.asarray(plan["g_tgt_w"]))
+    got_r = np.asarray(res.received)
+    # no dead peer is reached, and the living are, but for those the dead
+    # cut off (reached sets equal: _compare)
+    assert not (got_r & ~alive).any()
+    assert got_r.sum() > 0.9 * alive.sum()
+    assert int(res.alive) == alive.sum()
+    _compare(res, plan, a["conns"], a["rev"], params, pub, t0, frags)
+
+
 def test_slow_start_flight_counts():
     from dst_libp2p_test_node_tpu.ops.disseminate import tcp_flights
 

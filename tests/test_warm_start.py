@@ -91,8 +91,11 @@ def test_churn_invalidates_carry_and_results_stay_equal():
     # publish runs cold through the seed gate — no certificate gymnastics
     kw = dict(churn_down_per_hb=0.05, churn_up_per_hb=0.025,
               serialize_answers=False)
-    simw, warm = _run(_cfg(True, **kw))
-    simc, cold = _run(_cfg(False, **kw))
+    # both messages through peer 4, the configuration's publisher_id: the
+    # draw spares it; peer 5 is dead by the second publish on this seed,
+    # and a publish through a dead peer raises
+    simw, warm = _run(_cfg(True, **kw), publishers=(4, 4))
+    simc, cold = _run(_cfg(False, **kw), publishers=(4, 4))
     _assert_same(warm, cold)
     # the last publish wrote a fresh carry; one churny heartbeat batch
     # later it must be back at the INF sentinel
