@@ -381,6 +381,10 @@ def cmd_run(argv: list[str]) -> int:
                             # often (ops/graph.ConnGraph.build): a run that
                             # fell back to a slow one says so here
                             "build": sim.graph.build,
+                            # the heartbeat scans' reciprocity, a stage:
+                            # steps delivered from the rows that sent, steps
+                            # pulled dense, the most sending rows of a step
+                            "heartbeat": sim.heartbeat_counts,
                             "publishes": [
                                 {"fast_iters": r.fast_iters,
                                  "refine_passes": r.refine_passes,

@@ -8,12 +8,13 @@ highest-scored members and at least D_out outbound members), PRUNE backoff
 bookkeeping, and peer-score decay.
 
 Everything is a masked fixed-shape op over the (N, C) neighbor-slot arrays;
-reciprocity (GRAFT/PRUNE control messages) is a single row-gather pull
-through the precomputed reverse-slot involution (ops/graph.py, ops/pull.py),
-and the rebalance work runs under lax.cond so a stable mesh skips it
-entirely. Dead neighbors (churn) simply fall out of the validity mask and
-are replaced on the next rebalance — the elastic-recovery analog of the
-reference's dial-retry loops (SURVEY.md §5).
+reciprocity (GRAFT/PRUNE control messages) is one delivery through the
+precomputed reverse-slot involution (ops/graph.py, ops/pull.py): a scatter
+from the few rows that send or, where most rows send (step 0 from an empty
+mesh), a single row-gather pull. The rebalance work runs under lax.cond so
+a stable mesh skips it entirely. Dead neighbors (churn) simply fall out of
+the validity mask and are replaced on the next rebalance — the
+elastic-recovery analog of the reference's dial-retry loops (SURVEY.md §5).
 """
 
 from __future__ import annotations
@@ -23,7 +24,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from .pull import neighbor_pull_bool, reciprocal_pull_bool
+from .pull import (neighbor_pull_bool, neighbor_update_bool,
+                   reciprocal_send_bool, sparse_route)
 from .state import (PX_POOL_WIDTH, SimParams, SimState, repair_inert,
                     restore_repair, strip_repair)
 
@@ -43,21 +45,38 @@ def _apply_decay(arr: jnp.ndarray, scale, params: SimParams) -> jnp.ndarray:
     return jnp.where(eff < params.decay_to_zero, 0.0, eff)
 
 
+# The scan's delivery counters (`pulls`, int32 (3, 3)): a row a stage that
+# crosses the involution every churned step, a column a count.
+PULL_STAGES = ("validity", "graft", "prune")
+PULL_COUNTS = ("sparse", "dense", "max_rows")
+
+
 def _reciprocal_view(
     edge_mask: jnp.ndarray, conns: jnp.ndarray, rev: jnp.ndarray,
     batch_factor: int = 1,
-) -> jnp.ndarray:
+):
     """view[q, j] = edge_mask[conns[q,j], rev[q,j]] — the counterpart edge's
     flag seen from my slot space. Because the reverse-slot map is an
     involution ((p,i) <-> (q,j)), a reciprocal *scatter* ("for every selected
-    (p,i), mark (conns[p,i], rev[p,i])") is exactly this *gather*. One gather
-    replaces the reference's GRAFT/PRUNE RPC round trips.
+    (p,i), mark (conns[p,i], rev[p,i])") is exactly this *gather*. One
+    delivery replaces the reference's GRAFT/PRUNE RPC round trips.
 
-    Shape note (TPU): the naive 2-index-vector gather `m[conns, rev]` lowers
-    to 4M random scalar loads (~45 ms at N=100k). Gathering whole neighbor
-    ROWS (contiguous, embedding-style) and selecting the slot with a fused
-    iota-compare is ~4x faster — see ops/pull.py for the measured numbers."""
-    return reciprocal_pull_bool(edge_mask, conns, rev, batch_factor)
+    Which of the two runs is the call's own choice (ops/pull.py
+    reciprocal_send_bool): on step 0 from an empty mesh every row sends and
+    the gather of whole neighbor ROWS with a fused iota-compare select is
+    the fastest form (~4x the naive 2-index gather, ~45 ms at N=100k); on
+    every later step a handful of rows send, and the scatter from those
+    rows costs a tenth of it. Returns (view, tally): tally int32 (3,) =
+    (delivered sparse, pulled dense, sending rows)."""
+    return reciprocal_send_bool(edge_mask, conns, rev, batch_factor)
+
+
+def _tallied(pulls: jnp.ndarray, tallies) -> jnp.ndarray:
+    """`pulls` after one step: the counts added, the row maxima kept."""
+    t = jnp.stack(tallies)
+    return jnp.concatenate(
+        [pulls[:, :2] + t[:, :2], jnp.maximum(pulls[:, 2:], t[:, 2:])],
+        axis=1)
 
 
 @partial(jax.jit, static_argnames=("params", "batch_factor"))
@@ -74,6 +93,7 @@ def heartbeat_step(
     deg_in: jnp.ndarray | None = None,
     edge_ok: jnp.ndarray | None = None,
     spared: jnp.ndarray | None = None,
+    pulls: jnp.ndarray | None = None,
 ):
     """`batch_factor`: width of any enclosing vmap (e.g. the topic axis of
     runtime/multitopic.py) so the pull memory dispatch sees the true
@@ -115,6 +135,18 @@ def heartbeat_step(
     gives without it. Read only under churn; None (always, with churn off)
     keeps the trace untouched.
 
+    `pulls`: optional int32 (3, 3) delivery counters — the third scan-level
+    protocol (run_heartbeats'): a row each for `PULL_STAGES`, the steps the
+    stage delivered sparse, the steps it pulled dense, and the largest
+    number of sending rows it saw (`PULL_COUNTS`; a stage whose cond did not
+    fire delivered nothing and counts nowhere). When given, the step
+    returns the updated counters last. Under churn it also makes `nbr_ok`
+    a CARRIED view instead of a stale one: the caller passes the neighbour
+    pull of the incoming `alive & subscribed`, the step flips it at the
+    slots that point at the peers this step's draw changed
+    (ops/pull.neighbor_update_bool: a handful of rows, not a pull) and
+    returns the new view before the counters: (state, nbr_ok, pulls).
+
     Device scopes (jax.named_scope: metadata only, no operation is added)
     name the step's stages for a profile: `churn`, `validity`, `graft`,
     `prune`, `evict`, `px`, `opportunistic`, `decay`, `fanout`, `state`."""
@@ -148,7 +180,10 @@ def heartbeat_step(
                 # after the draw: nobody else's liveness moves with the mask
                 dies = dies & ~spared
             alive = jnp.where(alive, ~dies, revives)
-            nbr_ok = None   # alive just changed; precomputed masks are stale
+            # alive just changed: precomputed masks are stale, but for the
+            # scan's carried view, which the validity stage brings up to date
+            nbr_carried = nbr_ok if pulls is not None else None
+            nbr_ok = None
             valid_pre = None
             # the warm-start carry measured arrival offsets on the OLD
             # liveness set — a revived peer's stale offset (or a died relay's
@@ -159,13 +194,22 @@ def heartbeat_step(
             warm = jnp.full_like(state.warm_offset_ms, 3.4e38)
     else:
         warm = state.warm_offset_ms
+        nbr_carried = None
 
+    validity_tally = None   # what this stage delivered this step (`pulls`)
     with jax.named_scope("validity"):
         if valid_pre is not None:
             valid = valid_pre
         else:
             has_conn = conns >= 0
-            if nbr_ok is None:
+            if nbr_carried is not None:
+                # about ten peers of 100,000 changed: flip the slots that
+                # point at them instead of pulling every neighbour anew
+                nbr_ok, validity_tally = neighbor_update_bool(
+                    nbr_carried, alive & state.subscribed,
+                    (alive ^ state.alive) & state.subscribed,
+                    conns, rev, batch_factor)
+            elif nbr_ok is None:
                 # one pull for the conjunction (alive AND subscribed) — each
                 # pull is a full row-gather pass, so fusing the two masks
                 # halves the cost
@@ -221,6 +265,7 @@ def heartbeat_step(
         # built from deg so it varies over whatever manual axes deg does: a
         # cond under shard_map needs both branches to agree on them
         zeros_n = jnp.zeros_like(deg, dtype=jnp.int32)
+        no_pull = zeros_n[:3]
 
     def do_graft(mesh):
         eligible = (valid & ~mesh & (state.backoff_until <= t)
@@ -233,17 +278,17 @@ def heartbeat_step(
         # directions are counted per peer. The counter increments and the
         # refreshed degree are reduced INSIDE the branch: at steady state
         # the round pays no (N, C) reduce for them at all.
-        graft_rx = _reciprocal_view(grafted, conns, rev, batch_factor)
+        graft_rx, tally = _reciprocal_view(grafted, conns, rev, batch_factor)
         mesh = (mesh | grafted | graft_rx) & valid
         return (mesh, mesh.sum(axis=-1),
                 grafted.sum(axis=-1, dtype=jnp.int32),
-                graft_rx.sum(axis=-1, dtype=jnp.int32))
+                graft_rx.sum(axis=-1, dtype=jnp.int32), tally)
 
     with jax.named_scope("graft"):
-        mesh, deg2, graft_tx_inc, graft_rx_inc = jax.lax.cond(
+        mesh, deg2, graft_tx_inc, graft_rx_inc, graft_tally = jax.lax.cond(
             (need > 0).any(),
             do_graft,
-            lambda m: (m, deg, zeros_n, zeros_n),
+            lambda m: (m, deg, zeros_n, zeros_n, no_pull),
             mesh,
         )
 
@@ -272,13 +317,14 @@ def heartbeat_step(
         pruned = mesh & ~keep & over[:, None]
         mesh = mesh & ~pruned
         # PRUNE control msg: counterpart drops us; backoff on both sides
-        pruned_by_peer = _reciprocal_view(pruned, conns, rev, batch_factor)
+        pruned_by_peer, tally = _reciprocal_view(
+            pruned, conns, rev, batch_factor)
         backoff = jnp.where(
             pruned | pruned_by_peer,
             t + params.prune_backoff_ms, state.backoff_until)
         return (mesh & ~pruned_by_peer, backoff,
                 pruned.sum(axis=-1, dtype=jnp.int32),
-                pruned_by_peer.sum(axis=-1, dtype=jnp.int32),
+                pruned_by_peer.sum(axis=-1, dtype=jnp.int32), tally,
                 pruned_by_peer)
 
     pruned_rx = None
@@ -286,19 +332,20 @@ def heartbeat_step(
         if params.px:
             # PX needs the received-PRUNE edge set out of the branch; the
             # extra output exists only on the opt-in trace (ops/repair.py)
-            (mesh, backoff, prune_tx_inc, prune_rx_inc,
+            (mesh, backoff, prune_tx_inc, prune_rx_inc, prune_tally,
              pruned_rx) = jax.lax.cond(
                 over.any(),
                 _prune_sel,
-                lambda m: (m, state.backoff_until, zeros_n, zeros_n,
+                lambda m: (m, state.backoff_until, zeros_n, zeros_n, no_pull,
                            jnp.zeros((n, c), dtype=bool)),
                 mesh,
             )
         else:
-            mesh, backoff, prune_tx_inc, prune_rx_inc = jax.lax.cond(
+            (mesh, backoff, prune_tx_inc, prune_rx_inc,
+             prune_tally) = jax.lax.cond(
                 over.any(),
-                lambda m: _prune_sel(m)[:4],
-                lambda m: (m, state.backoff_until, zeros_n, zeros_n),
+                lambda m: _prune_sel(m)[:5],
+                lambda m: (m, state.backoff_until, zeros_n, zeros_n, no_pull),
                 mesh,
             )
 
@@ -320,7 +367,7 @@ def heartbeat_step(
             evict_fired = ev_cand.any()
 
             def do_evict(mesh, backoff):
-                ev_rx = _reciprocal_view(ev_cand, conns, rev, batch_factor)
+                ev_rx, _ = _reciprocal_view(ev_cand, conns, rev, batch_factor)
                 new_backoff = jnp.where(
                     ev_cand | ev_rx, t + params.prune_backoff_ms, backoff)
                 return (mesh & ~ev_cand & ~ev_rx, new_backoff,
@@ -401,7 +448,7 @@ def heartbeat_step(
             # same steady-state economics as graft/prune: the reciprocal pull
             # and the counter reduces only run when something actually grafted
             def do_og(m):
-                rx = _reciprocal_view(og, conns, rev, batch_factor)
+                rx, _ = _reciprocal_view(og, conns, rev, batch_factor)
                 return ((m | og | rx) & valid,
                         og.sum(axis=-1, dtype=jnp.int32),
                         rx.sum(axis=-1, dtype=jnp.int32))
@@ -473,19 +520,27 @@ def heartbeat_step(
             prunes_rx=prunes_rx_new,
             **repair_extra,
         )
-    if deg_in is None:
-        return new_state
-    # carried degree: re-reduce only if some branch actually touched the
-    # mesh this step — the steady-state round stays free of (N, C) reduces
-    with jax.named_scope("validity"):
-        fired = (need > 0).any() | over.any()
-        if params.opportunistic_graft_threshold > -9999.0:
-            fired = fired | og.any()
-        if params.evict:
-            fired = fired | evict_fired
-        deg_out = jax.lax.cond(
-            fired, lambda m: m.sum(axis=-1), lambda m: deg_in, mesh)
-    return new_state, deg_out
+    out = [new_state]
+    if deg_in is not None:
+        # carried degree: re-reduce only if some branch actually touched the
+        # mesh this step — the steady-state round stays free of (N, C)
+        # reduces
+        with jax.named_scope("validity"):
+            fired = (need > 0).any() | over.any()
+            if params.opportunistic_graft_threshold > -9999.0:
+                fired = fired | og.any()
+            if params.evict:
+                fired = fired | evict_fired
+            out.append(jax.lax.cond(
+                fired, lambda m: m.sum(axis=-1), lambda m: deg_in, mesh))
+    if nbr_carried is not None:
+        out.append(nbr_ok)
+    if pulls is not None:
+        with jax.named_scope("state"):
+            out.append(_tallied(pulls, [
+                no_pull if validity_tally is None else validity_tally,
+                graft_tally, prune_tally]))
+    return out[0] if len(out) == 1 else tuple(out)
 
 
 def run_heartbeats(
@@ -496,7 +551,8 @@ def run_heartbeats(
     params: SimParams,
     steps: int,
     spared: jnp.ndarray | None = None,
-) -> SimState:
+    with_pulls: bool = False,
+):
     """lax.scan over heartbeat rounds — simulated time scales in rounds with
     no host sync (the reference's 'long simulated time' axis, SURVEY.md §5).
 
@@ -509,13 +565,20 @@ def run_heartbeats(
     simulator's inter-message gaps) hit the compile cache.
 
     `spared`: heartbeat_step's — (N,) peers the churn draw does not kill;
-    None with churn off, where nothing would read it."""
+    None with churn off, where nothing would read it.
+
+    `with_pulls`: also return the scan's delivery counters (heartbeat_step's
+    `pulls`, the pull in front of the scan counted as a dense `validity`),
+    a device array nobody has waited for: (state, pulls). The program is
+    the same either way."""
+    saved = None
     if repair_inert(params):
         state, saved = strip_repair(state)
-        out = _run_heartbeats(
-            state, conns, rev, out_mask, params, steps, spared)
-        return restore_repair(out, saved)
-    return _run_heartbeats(state, conns, rev, out_mask, params, steps, spared)
+    out, pulls = _run_heartbeats(
+        state, conns, rev, out_mask, params, steps, spared)
+    if saved is not None:
+        out = restore_repair(out, saved)
+    return (out, pulls) if with_pulls else out
 
 
 @partial(jax.jit, static_argnames=("params", "steps"))
@@ -527,17 +590,29 @@ def _run_heartbeats(
     params: SimParams,
     steps: int,
     spared: jnp.ndarray | None = None,
-) -> SimState:
-
-    nbr_ok = None
+):
+    """(state, pulls): the state after `steps` rounds and the scan's delivery
+    counters (heartbeat_step's `pulls`)."""
+    # the one dense pull in front of either scan: without churn it is the
+    # only one (alive/subscribed are invariant, so the pull — a full
+    # row-gather pass — hoists out of the loop, and so does the whole
+    # edge-validity conjunction); under churn it seeds the view the steps
+    # keep up to date from the handful of peers each draw changes. (A
+    # churned scan of a shape the sparse route refuses pulls anew every
+    # step and never reads the view: the pull in front is dead code there,
+    # and not counted.)
+    churn_free = (params.churn_down_per_hb == 0.0
+                  and params.churn_up_per_hb == 0.0)
+    with jax.named_scope("validity"), jax.named_scope("dense"):
+        nbr_ok = neighbor_pull_bool(
+            state.alive & state.subscribed, conns, rev)
+        # PULL_STAGES x PULL_COUNTS: it counts as one dense `validity`
+        in_front = int(churn_free or sparse_route(conns.shape))
+        pulls = jnp.asarray(
+            [[0, in_front, 0], [0, 0, 0], [0, 0, 0]], jnp.int32)
     valid_pre = None
-    if params.churn_down_per_hb == 0.0 and params.churn_up_per_hb == 0.0:
-        # alive/subscribed are invariant across the scan without churn, so
-        # the neighbor pull — a full row-gather pass — hoists out of the
-        # loop, and so does the whole edge-validity conjunction
+    if churn_free:
         with jax.named_scope("validity"):
-            nbr_ok = neighbor_pull_bool(
-                state.alive & state.subscribed, conns, rev)
             valid_pre = ((conns >= 0) & state.alive[:, None] & nbr_ok
                          & state.subscribed[:, None])
 
@@ -552,35 +627,38 @@ def _run_heartbeats(
         state = state.replace(mesh_mask=mesh0)
 
         def body(carry, _):
-            s, deg, f_sc, s_sc = carry
-            s, deg = heartbeat_step(
+            s, deg, pulls, f_sc, s_sc = carry
+            s, deg, pulls = heartbeat_step(
                 s, conns, rev, out_mask, params, nbr_ok=nbr_ok,
-                valid_pre=valid_pre, decay_scales=(f_sc, s_sc), deg_in=deg)
+                valid_pre=valid_pre, decay_scales=(f_sc, s_sc), deg_in=deg,
+                pulls=pulls)
             with jax.named_scope("decay"):
                 f_sc, s_sc = f_sc * params.fmd_decay, s_sc * params.slow_decay
-            return (s, deg, f_sc, s_sc), None
+            return (s, deg, pulls, f_sc, s_sc), None
 
-        (state, _, f_sc, s_sc), _ = jax.lax.scan(
-            body, (state, deg0, one, one), None, length=steps)
+        (state, _, pulls, f_sc, s_sc), _ = jax.lax.scan(
+            body, (state, deg0, pulls, one, one), None, length=steps)
     else:
+        # the neighbour view rides in the carry (4 MB at 100,000 x 40) and
+        # nowhere else: no SimState leaf, nothing for a checkpoint to hold
         def body(carry, _):
-            s, f_sc, s_sc = carry
-            s = heartbeat_step(
-                s, conns, rev, out_mask, params, nbr_ok=nbr_ok,
-                valid_pre=valid_pre, decay_scales=(f_sc, s_sc),
-                spared=spared)
+            s, nbr, pulls, f_sc, s_sc = carry
+            s, nbr, pulls = heartbeat_step(
+                s, conns, rev, out_mask, params, nbr_ok=nbr,
+                decay_scales=(f_sc, s_sc), spared=spared, pulls=pulls)
             # end-of-round decay, factored to two scalar multiplies
             with jax.named_scope("decay"):
                 f_sc, s_sc = f_sc * params.fmd_decay, s_sc * params.slow_decay
-            return (s, f_sc, s_sc), None
+            return (s, nbr, pulls, f_sc, s_sc), None
 
-        (state, f_sc, s_sc), _ = jax.lax.scan(
-            body, (state, one, one), None, length=steps)
+        (state, _, pulls, f_sc, s_sc), _ = jax.lax.scan(
+            body, (state, nbr_ok, pulls, one, one), None, length=steps)
     # materialize the deferred decay ONCE per scan (vs two (N, C) passes
     # plus a predicate reduce per round): exact, because geometric decay
     # with a monotone zero-cutoff commutes with deferral
     with jax.named_scope("decay"):
-        return state.replace(
+        state = state.replace(
             fmd=_apply_decay(state.fmd, f_sc, params),
             slow_penalty=_apply_decay(state.slow_penalty, s_sc, params),
         )
+    return state, pulls

@@ -30,6 +30,30 @@ Measured again, each as a jit of its own, same shape (PR 28, TPU v5 lite):
     0.28 ms as selects alone, no gather (permute_rows; f32, bool and int32
     alike; the (N, C, C) one-hot form 0.21-0.25 ms, see there why not that)
 
+Sparse reciprocity (reciprocal_send_bool; PR 37, TPU v5 lite, same shape,
+each candidate a jit of its own, median of the timed calls; such a call costs
+0.5-0.6 ms whatever it does: an (N, C) `any` + `sum` alone 0.62 ms, an (N,)
+cumsum 0.64 ms, so every line under a millisecond stands on that floor):
+  - the dense bool pull: reciprocal_pull_bool 11.2 ms, neighbor_pull_bool
+    11.3 ms; 10.3 ms a step inside a 50-step scan
+  - compacting the sending rows into K = 128 (256) indices: jnp.nonzero(
+    size=K) 1.55 (1.53) ms; cumsum + searchsorted 0.62 (0.64), `scan` and
+    `compare_all` alike; lax.top_k over a masked iota 0.67 (0.69); a
+    two-level reduce 0.61 (0.63) — all but nonzero are at the floor
+  - delivering K = 128 rows, 0 / 3 / 30 / 127 of them sending: the 2-D
+    scatter with unique_indices, mode="drop" 0.67 / 0.68 / 0.70 / 0.67 ms
+    (0.82 at K = 256); without unique_indices 0.67-0.72; into int32 0.64-
+    0.70; a flattened 1-D scatter 0.77-0.81; a fori_loop of
+    dynamic_update_slice 0.65 / 0.64 / 0.84 / 1.46; the scatter-free
+    membership compare against the edge ids conns*C + rev 0.65 for 16 marked
+    edges, 0.83 for 64. XLA:TPU's scatter is not slow at this size
+  - the whole of reciprocal_send_bool (count, switch over none / sparse /
+    dense): 0.67 ms with no sending row, 0.80-0.82 with 3, 30 and 127,
+    11.1 with 200 (the dense side); neighbor_update_bool with 10 changed
+    peers 0.84 ms
+  - where it counts, inside a 50-step scan with no dispatch between steps:
+    0.25 ms a step with 3 or with 30 sending rows, against 10.3 ms dense
+
 The sharded fixpoint (parallel/exchange.py converge_sharded) deliberately
 does NOT use this: its per-iteration cross-shard traffic is the (N,) time
 vector alone, and the pull there is against receiver-local constants.
@@ -37,6 +61,7 @@ vector alone, and the pull there is against receiver-local constants.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 INF = jnp.float32(3.4e38)
@@ -51,6 +76,17 @@ INF = jnp.float32(3.4e38)
 # more than the random scalar loads), so large pulls simply fall back.
 _MAX_INTERMEDIATE_BYTES = 6 * 1024**3
 _LANE = 128
+
+# Sparse reciprocity (see reciprocal_send_bool). `_SPARSE_ROWS` is K, the
+# most sending rows a step delivers from the rows themselves: the churned
+# scan at 100,000 peers sends from a few tens of rows a stage after its
+# first steps, and compaction plus delivery cost the same for every count
+# up to K (the numbers are in the module docstring). `_SPARSE_MIN_DENSE_BYTES`
+# is the static half of the dispatch: below it the dense pull is cheaper
+# than the sparse path's fixed cost (at (1000, 40) the pull is microseconds),
+# so small shapes keep the one program they had.
+_SPARSE_ROWS = 128
+_SPARSE_MIN_DENSE_BYTES = 128 * 1024**2
 
 
 def intermediate_bytes(dtype, conns_shape, batch_factor: int = 1) -> int:
@@ -118,6 +154,129 @@ def reciprocal_pull_bool(
         lambda rows, sel: (rows & sel).any(axis=-1),
         lambda q, r: edge_mask[q, r], batch_factor)
     return out & (conns >= 0) & (rev >= 0)
+
+
+def sparse_route(conns_shape, batch_factor: int = 1) -> bool:
+    """The trace-time half of the sparse dispatch: one instance (under an
+    enclosing vmap a `cond` lowers to a select and both branches would run)
+    of a static shape whose dense bool pull costs more than the sparse
+    path's fixed cost."""
+    return (batch_factor == 1 and len(conns_shape) == 2
+            and intermediate_bytes(jnp.bool_, conns_shape)
+            >= _SPARSE_MIN_DENSE_BYTES)
+
+
+def sending_rows(row_mask: jnp.ndarray, k: int) -> jnp.ndarray:
+    """The indices of the first k True entries of an (N,) mask, ascending,
+    N (one past the end) beyond their count: the k-th sender is the first
+    row whose running count reaches k."""
+    running = jnp.cumsum(row_mask.astype(jnp.int32))
+    return jnp.searchsorted(
+        running, jnp.arange(1, k + 1, dtype=jnp.int32), side="left",
+        method="compare_all").astype(jnp.int32)
+
+
+def _deliver(into, senders, marks, values, conns, rev):
+    """into[conns[p,i], rev[p,i]] = values[k,i] for every marked slot i of
+    sender p = senders[k]. The involution makes the targets of distinct
+    (p, i) distinct; slots that are unmarked, invalid or a sentinel row's
+    are sent past the end, each to an index of its own, and dropped."""
+    n, c = conns.shape
+    k = senders.shape[0]
+    cn = conns.at[senders].get(mode="fill", fill_value=-1)
+    rv = rev.at[senders].get(mode="fill", fill_value=-1)
+    ok = marks & (cn >= 0) & (rv >= 0)
+    q = jnp.where(ok, cn, n + jnp.arange(k, dtype=jnp.int32)[:, None])
+    r = jnp.where(ok, rv, jnp.arange(c, dtype=jnp.int32)[None, :])
+    return into.at[q, r].set(values, mode="drop", unique_indices=True)
+
+
+def _tally(count, routed: bool) -> jnp.ndarray:
+    """int32 (3,) = (1 if delivered sparse, 1 if pulled dense, `count`, the
+    sending rows): sparse where the shape is `routed` there and the rows fit
+    `_SPARSE_ROWS`."""
+    sparse = ((count <= _SPARSE_ROWS).astype(jnp.int32) if routed
+              else jnp.int32(0))
+    return jnp.stack([sparse, 1 - sparse, count])
+
+
+def reciprocal_send_bool(
+    edge_mask: jnp.ndarray, conns: jnp.ndarray, rev: jnp.ndarray,
+    batch_factor: int = 1,
+):
+    """`reciprocal_pull_bool`, bit for bit, delivered from the rows that
+    send where they are few. The reverse-slot map is an involution, so
+    "for every marked (p, i), mark (conns[p,i], rev[p,i])" IS the gather
+    out[q, j] = edge_mask[conns[q,j], rev[q,j]]: with at most `_SPARSE_ROWS`
+    non-empty rows it is a compaction of their indices, three K-row gathers
+    and one K*C-update scatter into an all-False (N, C); with more (step 0
+    of a scan from an empty mesh: every row sends) it is the dense pull;
+    with none it is the all-False array. Chosen by `lax.switch` on the count
+    the call observes, where `sparse_route` allows it at trace time.
+
+    Returns (out, tally): tally as `_tally` packs it."""
+    rows = edge_mask.any(axis=-1)
+    count = rows.sum(dtype=jnp.int32)
+    routed = sparse_route(conns.shape, batch_factor)
+
+    def dense(m):
+        with jax.named_scope("dense"):
+            return reciprocal_pull_bool(m, conns, rev, batch_factor)
+
+    def sparse(m):
+        with jax.named_scope("sparse"):
+            senders = sending_rows(rows, _SPARSE_ROWS)
+            marks = m.at[senders].get(mode="fill", fill_value=False)
+            return _deliver(jnp.zeros_like(m), senders, marks, True,
+                            conns, rev)
+
+    def none(m):
+        with jax.named_scope("sparse"):
+            return jnp.zeros_like(m)
+
+    if routed:
+        out = jax.lax.switch(
+            (count > 0).astype(jnp.int32) + (count > _SPARSE_ROWS),
+            [none, sparse, dense], edge_mask)
+    else:
+        out = dense(edge_mask)
+    return out, _tally(count, routed)
+
+
+def neighbor_update_bool(
+    nbr: jnp.ndarray, per_peer: jnp.ndarray, changed: jnp.ndarray,
+    conns: jnp.ndarray, rev: jnp.ndarray, batch_factor: int = 1,
+):
+    """`neighbor_pull_bool(per_peer, ...)`, bit for bit, given `nbr`, the
+    pull of the vector as it was before the peers in `changed` (N,) took
+    their new values: `nbr` changes exactly at the slots that point at a
+    changed peer, (conns[p,i], rev[p,i]) for every valid slot i of a changed
+    p, which is `reciprocal_send_bool`'s delivery of "every slot of a
+    changed row" with the peer's new value instead of True. More than
+    `_SPARSE_ROWS` changed peers, or a shape `sparse_route` refuses: the
+    dense pull. Returns (out, tally) as `reciprocal_send_bool` does."""
+    count = changed.sum(dtype=jnp.int32)
+    routed = sparse_route(conns.shape, batch_factor)
+
+    def dense(nbr):
+        with jax.named_scope("dense"):
+            return neighbor_pull_bool(per_peer, conns, rev, batch_factor)
+
+    def sparse(nbr):
+        with jax.named_scope("sparse"):
+            senders = sending_rows(changed, _SPARSE_ROWS)
+            values = per_peer.at[senders].get(mode="fill", fill_value=False)
+            return _deliver(
+                nbr, senders, jnp.ones((_SPARSE_ROWS, 1), dtype=bool),
+                jnp.broadcast_to(values[:, None],
+                                 (_SPARSE_ROWS, conns.shape[-1])),
+                conns, rev)
+
+    if routed:
+        out = jax.lax.cond(count <= _SPARSE_ROWS, sparse, dense, nbr)
+    else:
+        out = dense(nbr)
+    return out, _tally(count, routed)
 
 
 def neighbor_pull_bool(

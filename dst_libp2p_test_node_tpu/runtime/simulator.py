@@ -32,7 +32,7 @@ from ..ops.disseminate import disseminate as _disseminate_program
 from ..ops.disseminate import (fixpoint_formulation, fragments_in_sequence,
                                valid_edge_at_publish, valid_edge_of)
 from ..ops.graph import build_connection_graph
-from ..ops.heartbeat import run_heartbeats
+from ..ops.heartbeat import PULL_COUNTS, PULL_STAGES, run_heartbeats
 from ..ops.state import SimParams, graph_arrays, init_state
 from .logemit import LatenciesWriter
 from .profiling import counters, span
@@ -427,6 +427,7 @@ class Simulator:
         # what the last write_latencies / write_shadowlog emitted: lines, and
         # blocks the native formatter took (`stats<i>.json` "emit")
         self.emit_counts: dict[str, int] = {}
+        self._reset_heartbeat_pulls()
         # flight recorder (ops/telemetry.py): disarmed by default — advance()
         # then runs the exact pre-telemetry heartbeat program. Armed via
         # record_telemetry(); last_telemetry holds the most recent window's
@@ -449,6 +450,63 @@ class Simulator:
         (`valid_edge_at_publish`)."""
         return valid_edge_of(self.state.alive, self.state.subscribed,
                              self.arrays["conns"], self.arrays["rev"])
+
+    # ------------------------------------------- the scans' delivery counters
+
+    # unread scans a Simulator holds at most before it reads them itself (a
+    # caller that advances and never publishes must not hoard device arrays)
+    _HB_UNREAD_MAX = 256
+
+    def _reset_heartbeat_pulls(self) -> None:
+        # per scan its packed counters (ops/heartbeat `pulls`), on the device
+        # and not waited for, until a publish reads them with `t_ms`
+        self._hb_unread: list = []
+        self._hb_scans = 0
+        self._hb_pulls = np.zeros((len(PULL_STAGES), len(PULL_COUNTS)),
+                                  dtype=np.int64)
+
+    @staticmethod
+    def _folded(total: np.ndarray, scans: np.ndarray) -> np.ndarray:
+        # ops/heartbeat._tallied over whole scans: counts add, maxima stay
+        return np.concatenate(
+            [total[:, :2] + scans[:, :, :2].sum(axis=0),
+             np.maximum(total[:, 2:], scans[:, :, 2:].max(axis=0))], axis=1)
+
+    def _note_heartbeat_pulls(self, read: list) -> None:
+        """Fold the counters of the scans since the last read into the
+        experiment's totals and put them on a zero-length annotation."""
+        self._hb_unread = []
+        if not read:
+            return
+        scans = np.stack(read).astype(np.int64)
+        since = self._folded(np.zeros_like(self._hb_pulls), scans)
+        self._hb_pulls = self._folded(self._hb_pulls, scans)
+        self._hb_scans += len(scans)
+        counters(
+            "heartbeat/counters", scans=len(scans),
+            pulls_sparse=int(since[:, PULL_COUNTS.index("sparse")].sum()),
+            pulls_dense=int(since[:, PULL_COUNTS.index("dense")].sum()),
+            **{f"{stage}_{count}": int(since[i, j])
+               for i, stage in enumerate(PULL_STAGES)
+               for j, count in enumerate(PULL_COUNTS)})
+
+    @property
+    def heartbeat_counts(self) -> dict:
+        """`stats<i>.json` "heartbeat": over the experiment's scans, a stage
+        how many steps delivered its reciprocity from the rows that sent,
+        how many pulled it dense (the pull in front of a scan is a dense
+        `validity`), and the most sending rows a step saw."""
+        if self._hb_unread:
+            self._note_heartbeat_pulls(jax.device_get(self._hb_unread))
+        by_stage = {
+            stage: {count: int(self._hb_pulls[i, j])
+                    for j, count in enumerate(PULL_COUNTS)}
+            for i, stage in enumerate(PULL_STAGES)}
+        return {
+            "scans": self._hb_scans,
+            "pulls_sparse": sum(v["sparse"] for v in by_stage.values()),
+            "pulls_dense": sum(v["dense"] for v in by_stage.values()),
+            **by_stage}
 
     # ---------------------------------------------------------------- phases
 
@@ -476,6 +534,7 @@ class Simulator:
         self._last_msg_id = -1
         self._hb_carry_ms = 0.0
         self.records = []
+        self._reset_heartbeat_pulls()
         self.last_telemetry = {}  # the recorder stays armed across resets
         if not self._churny:
             self._valid_edge = self._compute_valid_edge()
@@ -593,9 +652,13 @@ class Simulator:
                 self.last_telemetry = {
                     k: np.asarray(v) for k, v in trace.items()}
             else:
-                self.state = run_heartbeats(
+                self.state, pulls = run_heartbeats(
                     self.state, a["conns"], a["rev"], a["out_mask"],
-                    self.params, steps, spared=self._spared)
+                    self.params, steps, spared=self._spared, with_pulls=True)
+                if len(self._hb_unread) >= self._HB_UNREAD_MAX:
+                    self._note_heartbeat_pulls(
+                        jax.device_get(self._hb_unread))
+                self._hb_unread.append(pulls)
 
     def warmup(self) -> None:
         self.advance(self.cfg.warmup_s * 1000.0)
@@ -628,7 +691,9 @@ class Simulator:
                         valid_edge, up = valid_edge_at_publish(
                             self.state.alive, self.state.subscribed,
                             a["conns"], a["rev"], publisher)
-                    t_ms, up = jax.device_get((self.state.t_ms, up))
+                    t_ms, up, pulls = jax.device_get(
+                        (self.state.t_ms, up, self._hb_unread))
+                    self._note_heartbeat_pulls(pulls)
                     if not up:
                         raise PublisherDownError(
                             f"peer {publisher} is dead at t={float(t_ms)} ms "
@@ -636,7 +701,11 @@ class Simulator:
                             f"{self.spared_peers}: the peers run() "
                             "publishes through)")
                 else:
-                    t_ms = self.state.t_ms
+                    # the scans' counters come with the read that waits for
+                    # them anyway
+                    t_ms, pulls = jax.device_get(
+                        (self.state.t_ms, self._hb_unread))
+                    self._note_heartbeat_pulls(pulls)
                 t0_ms = float(t_ms) + self._hb_carry_ms
                 origin = publisher
                 mix_delay = 0.0

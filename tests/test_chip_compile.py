@@ -132,24 +132,29 @@ def test_sharded_fixpoint_compiles_for_four_chips(peer_mesh, capacity):
     assert t_out.spec == P("peers") and inc_out.spec == P("peers")
 
 
-def test_churned_scan_compiles_for_v5e(one_chip, capacity):
-    """The half of `_run_heartbeats` a churned network takes, at the shape of
-    the benchmark's runsh-100k-churn: the draws, the neighbour pull and the
-    validity conjunction every step, the spared mask as an argument."""
+@pytest.mark.parametrize("churn", [1e-4, 0.0])
+def test_heartbeat_scan_compiles_for_v5e(one_chip, capacity, churn):
+    """Both halves of `_run_heartbeats` at the shape of the benchmark's
+    100,000-peer cells: under churn (runsh-100k-churn) the draws, the carried
+    neighbour view and the validity conjunction every step, the spared mask
+    as an argument; without, the hoisted validity. At this shape the step's
+    reciprocity takes the sparse route: the scatter from the rows that send
+    and the dense pull it falls back to are both in the loop's body."""
+    from dst_libp2p_test_node_tpu.ops import pull
     from dst_libp2p_test_node_tpu.ops.heartbeat import _run_heartbeats
     from dst_libp2p_test_node_tpu.ops.state import (
         SimParams, init_state, strip_repair)
 
-    params = SimParams(n=N, capacity=capacity, churn_down_per_hb=1e-4,
-                       churn_up_per_hb=5e-5)
+    assert pull.sparse_route((N, capacity))
+    params = SimParams(n=N, capacity=capacity, churn_down_per_hb=churn,
+                       churn_up_per_hb=churn / 2)
     state = jax.tree_util.tree_map(
         lambda s: one_chip(s.shape, s.dtype),
         jax.eval_shape(lambda: strip_repair(init_state(params, seed=0))[0]))
     compiled = _run_heartbeats.lower(
         state, one_chip((N, capacity), jnp.int32),
         one_chip((N, capacity), jnp.int32), one_chip((N, capacity), jnp.bool_),
-        params, 500, one_chip((N,), jnp.bool_)).compile()
+        params, 500, one_chip((N,), jnp.bool_) if churn else None).compile()
     text = compiled.as_text()
-    # the pull is in the loop's body, not in front of it
-    assert "while" in text and "gather" in text
+    assert "while" in text and "gather" in text and "scatter" in text
     assert _device_bytes(compiled) < 0.1 * V5E_HBM_BYTES

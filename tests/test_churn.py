@@ -5,6 +5,7 @@ publish counts who could send and who sat under D_low; a publish through a
 dead peer raises; `run ... --churn` end to end; and the churn-free programs
 are the ones they were, but for scope names."""
 
+import contextlib
 import hashlib
 import json
 import math
@@ -17,6 +18,7 @@ import numpy as np
 import pytest
 
 import bench_configs
+import pull_route
 from dst_libp2p_test_node_tpu import cli
 from dst_libp2p_test_node_tpu.config.topology import TopoParams
 from dst_libp2p_test_node_tpu.ops.disseminate import disseminate
@@ -281,9 +283,11 @@ def _lowered(churn, debug_info=False):
                                      "disseminate.f4"])
 def test_churn_free_programs_are_the_parents_but_for_scope_names(program):
     """The StableHLO text without debug info (where the scope names live)
-    of the churn-free scan and publish, against what PR 36's parent lowered
-    to at the same shapes (tests/fixtures/lowered_churn_free.json, taken on
-    the parent commit with the jax named there)."""
+    of the churn-free scan and publish, against what was pinned at the same
+    shapes (tests/fixtures/lowered_churn_free.json, with the jax named
+    there): the two publishes as PR 36's parent lowered them, the scan as
+    PR 37 left it (its delivery counters ride in the carry; at this shape
+    the step keeps the dense pull)."""
     with open(os.path.join(HERE, "fixtures", "lowered_churn_free.json")) as f:
         pinned = json.load(f)
     if pinned["jax"] != jax.__version__:
@@ -292,9 +296,12 @@ def test_churn_free_programs_are_the_parents_but_for_scope_names(program):
     assert hashlib.sha256(text.encode()).hexdigest() == pinned[program]
 
 
+@pytest.mark.parametrize("sparse", [False, True])
 @pytest.mark.parametrize("churn", [0.0, 0.01])
-def test_lowered_scan_carries_the_scopes(churn):
-    text = _lowered(churn, debug_info=True)["_run_heartbeats"]
+def test_lowered_scan_carries_the_scopes(churn, sparse):
+    # sparse: the route a 100,000-peer scan takes, at this test's 300 peers
+    with pull_route.forced(0) if sparse else contextlib.nullcontext():
+        text = _lowered(churn, debug_info=True)["_run_heartbeats"]
     # the scan's own ops carry their names from `jit(_run_heartbeats)` on;
     # the step is a jit of its own inside the body, and its ops' names
     # start at its stages (the profile of a chip run joins the two)
@@ -308,14 +315,29 @@ def test_lowered_scan_carries_the_scopes(churn):
     assert {n.split("/")[0] for n in step} == stages
     assert {"graft/cond", "prune/cond", "fanout/cond"} <= {
         "/".join(n.split("/")[:2]) for n in step}
-    # what the scan does around the steps: the deferred decay, and without
-    # churn the hoisted validity; the rest is the loop itself
+    # what the scan does around the steps: the deferred decay, and the one
+    # dense neighbour pull in front (without churn with the whole validity
+    # conjunction; under churn the seed of the carried view, which a scan
+    # too small for the sparse route never reads: dead code, not lowered);
+    # the rest is the loop itself
     around = {n.split("/")[0] for n in scan}
     assert around == {"decay", "scan", "while"} | (
-        set() if churn else {"validity"})
+        {"validity"} if sparse or not churn else set())
     assert {n for n in scan if n.startswith("while/")} <= {
         "while/body/add", "while/cond/lt", "while/body/closed_call",
         "while/body/decay/mul"}
+    assert any(n.startswith("validity/dense/gather") for n in scan) == (
+        sparse or not churn)
     # under churn the draws and the validity conjunction run every step
     assert any(n.startswith("validity/and") for n in step) == bool(churn)
     assert any(n.startswith("validity/and") for n in scan) != bool(churn)
+    # which delivery ran is a sub-scope of the stage: the dense pull alone
+    # at a small shape; past the static bound a switch over none / sparse /
+    # dense in graft and prune, and under churn a cond in validity
+    def ways(stage):
+        return {part for n in step if n.startswith(stage + "/")
+                for part in n.split("/")[1:] if part in ("sparse", "dense")}
+    both = {"sparse", "dense"} if sparse else {"dense"}
+    assert ways("graft") == both and ways("prune") == both
+    assert ways("validity") == (both if churn else set())
+    assert any("sparse/scatter" in n for n in step) == sparse

@@ -1,8 +1,15 @@
-import numpy as np
-import jax.numpy as jnp
+import functools
 
+import flax.serialization as ser
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import pull_route
 from dst_libp2p_test_node_tpu.ops.graph import build_connection_graph
-from dst_libp2p_test_node_tpu.ops.heartbeat import heartbeat_step, run_heartbeats
+from dst_libp2p_test_node_tpu.ops.heartbeat import (
+    PULL_COUNTS, PULL_STAGES, heartbeat_step, run_heartbeats)
 from dst_libp2p_test_node_tpu.ops.state import SimParams, init_state, graph_arrays
 
 
@@ -43,9 +50,6 @@ def test_mesh_subset_of_connections():
     state = run_heartbeats(state, a["conns"], a["rev"], a["out_mask"], params, 8)
     mesh = np.asarray(state.mesh_mask)
     assert not (mesh & (g.conns < 0)).any()
-
-
-import pytest
 
 
 @pytest.mark.parametrize("og", [False, True])
@@ -168,3 +172,88 @@ def test_prune_keeps_high_score_members():
     # edges must outscore pruned ones on average
     score = np.where(hi_edge, 25.0, 0.0)
     assert score[kept].mean() > score[pruned].mean() + 1.0
+
+
+# ------------------------------------------ sparse reciprocity in the scan --
+
+STEPS = 12
+
+
+@functools.lru_cache(maxsize=None)
+def _both_routes(churn, repair):
+    """Two scans of STEPS heartbeats, from an empty mesh (step 0: every row
+    sends) and from the warmed one, with the sparse route forced on at 8
+    rows and forced off: {route: {start: (state, pulls)}} on the host."""
+    over = dict(churn_down_per_hb=churn, churn_up_per_hb=churn / 2)
+    if repair:
+        over.update(slow_weight=-10.0, slow_decay=0.9, evict=True, px=True,
+                    eviction_threshold=-50.0)
+    out = {}
+    for route, min_dense_bytes in (("sparse", 0), ("dense", 1 << 62)):
+        with pull_route.forced(min_dense_bytes, rows=8):
+            g, params, state, a = make(n=300, connect_to=10, seed=5, **over)
+            spared = jnp.zeros(300, bool).at[4].set(True) if churn else None
+            graph = a["conns"], a["rev"], a["out_mask"]
+            cold = run_heartbeats(state, *graph, params, STEPS,
+                                  spared=spared, with_pulls=True)
+            warm = cold[0]
+            if repair:
+                # six mesh members sink under the eviction floor
+                p, i = np.nonzero(np.asarray(warm.mesh_mask))
+                bad = np.zeros(warm.fmd.shape, np.float32)
+                bad[p[::400][:6], i[::400][:6]] = 100.0
+                warm = warm.replace(slow_penalty=jnp.asarray(bad))
+            warm = run_heartbeats(warm, *graph, params, STEPS,
+                                  spared=spared, with_pulls=True)
+            out[route] = jax.device_get({"empty": cold, "warmed": warm})
+    return out
+
+
+SCANS = [(0.0, False), (0.01, False), (0.0001, False), (0.01, True)]
+
+
+@pytest.mark.parametrize("start", ["empty", "warmed"])
+@pytest.mark.parametrize("churn,repair", SCANS)
+def test_sparse_scan_is_the_dense_scan_leaf_for_leaf(churn, repair, start):
+    runs = _both_routes(churn, repair)
+    (sparse, _), (dense, _) = runs["sparse"][start], runs["dense"][start]
+    d1, d2 = ser.to_state_dict(sparse), ser.to_state_dict(dense)
+    assert d1.keys() == d2.keys()
+    for k in d1:
+        np.testing.assert_array_equal(
+            np.asarray(d1[k]), np.asarray(d2[k]), err_msg=k)
+    assert np.asarray(sparse.mesh_mask).any()
+    if churn == 0.01:
+        assert not np.asarray(sparse.alive).all() and sparse.alive[4]
+    if repair and start == "warmed":
+        assert int(np.asarray(sparse.evictions).sum()) > 0
+
+
+@pytest.mark.parametrize("start", ["empty", "warmed"])
+@pytest.mark.parametrize("churn,repair", SCANS)
+def test_pull_counters_sum_to_the_steps(churn, repair, start):
+    runs = _both_routes(churn, repair)
+    sparse = dict(zip(PULL_STAGES, runs["sparse"][start][1].tolist()))
+    dense = dict(zip(PULL_STAGES, runs["dense"][start][1].tolist()))
+    n_sparse, n_dense, rows = (PULL_COUNTS.index(c) for c in PULL_COUNTS)
+    # the validity view: one pull in front of the scan, then under churn a
+    # delivery a step; a churned scan that pulls every step has none in front
+    v = sparse["validity"]
+    assert v[n_sparse] + v[n_dense] == 1 + (STEPS if churn else 0)
+    # at 300 peers a step never changes more than 8 of them
+    assert v[n_dense] == 1
+    assert dense["validity"][:2] == [0, STEPS if churn else 1]
+    assert dense["validity"][rows] == v[rows]
+    for stage in ("graft", "prune"):
+        s, d = sparse[stage], dense[stage]
+        assert d[n_sparse] == 0 and d[n_dense] <= STEPS
+        # the same steps fire, whichever way they deliver; the same rows send
+        assert s[n_sparse] + s[n_dense] == d[n_dense]
+        assert s[rows] == d[rows]
+    if start == "empty":
+        # step 0 grafts from every row (dense), later steps from a few
+        assert sparse["graft"][rows] >= 290    # but for the dead
+        assert sparse["graft"][n_dense] >= 1
+        assert sparse["graft"][n_sparse] + sparse["prune"][n_sparse] >= 1
+    elif churn == 0.01:
+        assert sparse["graft"][n_sparse] >= 1 and sparse["graft"][rows] <= 300

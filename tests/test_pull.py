@@ -211,3 +211,102 @@ def test_rev_sorted_pulls_a_sorted_table_to_the_slot_layout(edges):
     np.testing.assert_array_equal(
         np.asarray(g_sorted)[cn, np.clip(rs, 0, None)][valid],
         np.asarray(g_slot)[cn, rv][valid])
+
+
+# ------------------------------------------------------ sparse reciprocity --
+
+K = 8      # `_SPARSE_ROWS` for these tests: both sides of the cond at n=300
+
+
+@pytest.fixture
+def sparse_at_k(monkeypatch):
+    """The sparse route at this file's small shapes, K sending rows wide
+    (nothing here is jitted, so the constants are read at every call)."""
+    monkeypatch.setattr(pull, "_SPARSE_MIN_DENSE_BYTES", 0)
+    monkeypatch.setattr(pull, "_SPARSE_ROWS", K)
+
+
+def _sending(conns, rows, seed, on_pads=False):
+    """An edge mask with `rows` non-empty rows (None: every row): one to
+    three marked slots each, on pad slots too if asked."""
+    n, c = conns.shape
+    rng = np.random.default_rng(seed)
+    m = np.zeros((n, c), bool)
+    senders = np.arange(n) if rows is None else rng.choice(n, rows, False)
+    cn = np.asarray(conns)
+    for p in senders:
+        slots = np.arange(c) if on_pads else np.flatnonzero(cn[p] >= 0)
+        m[p, rng.choice(slots, rng.integers(1, 4), replace=False)] = True
+    return jnp.asarray(m)
+
+
+@pytest.mark.parametrize("on_pads", [False, True])
+@pytest.mark.parametrize("rows", [0, 1, K - 1, K, K + 1, None])
+def test_sparse_send_is_the_dense_pull(edges, sparse_at_k, rows, on_pads):
+    """reciprocal_send_bool against reciprocal_pull_bool and the loop
+    reference, on both sides of the cond, marked pad slots included; the
+    tally says which side ran and how many rows sent."""
+    conns, rev = edges
+    m = _sending(conns, rows, seed=11 + (rows or 0), on_pads=on_pads)
+    sent = int(np.asarray(m).any(axis=-1).sum())
+    got, tally = pull.reciprocal_send_bool(m, conns, rev)
+    want = np.asarray(pull.reciprocal_pull_bool(m, conns, rev))
+    np.testing.assert_array_equal(np.asarray(got), want)
+    np.testing.assert_array_equal(want, _ref_pull(m, conns, rev, False))
+    assert np.asarray(tally).tolist() == [int(sent <= K), int(sent > K), sent]
+    if rows and not on_pads:    # marks on pads alone deliver nothing
+        assert want.any()
+    jitted, _ = jax.jit(pull.reciprocal_send_bool)(m, conns, rev)
+    np.testing.assert_array_equal(np.asarray(jitted), want)
+
+
+def test_sparse_route_is_a_trace_time_choice(edges, monkeypatch):
+    """Small shapes and batched callers keep the one dense program: no cond
+    is traced; past the static bound there is one, and a scatter in it."""
+    conns, rev = edges
+    m = _sending(conns, 3, seed=1)
+
+    def text(**kw):
+        return str(jax.make_jaxpr(
+            lambda m: pull.reciprocal_send_bool(m, conns, rev, **kw))(m))
+    assert "cond" not in text() and "scatter" not in text()
+    assert not pull.sparse_route((100_000, 40), batch_factor=4)
+    assert pull.sparse_route((100_000, 40)) and not pull.sparse_route((1000, 40))
+    monkeypatch.setattr(pull, "_SPARSE_MIN_DENSE_BYTES", 0)
+    assert "cond" in text() and "scatter" in text()
+    assert "cond" not in text(batch_factor=2)
+    _, tally = pull.reciprocal_send_bool(m, conns, rev, batch_factor=2)
+    assert np.asarray(tally).tolist() == [0, 1, 3]
+
+
+@pytest.mark.parametrize("k", [1, 5, 64])
+def test_sending_rows_are_the_first_k_senders(k):
+    mask = np.zeros(200, bool)
+    mask[[3, 17, 18, 150, 199]] = True
+    got = np.asarray(pull.sending_rows(jnp.asarray(mask), k))
+    want = np.full(k, 200)
+    want[:min(k, 5)] = np.flatnonzero(mask)[:k]
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("changed", [0, 1, K, K + 1, 120])
+def test_neighbor_update_is_a_fresh_neighbor_pull(edges, sparse_at_k, changed):
+    """The carried neighbour view after `changed` peers flipped, against
+    neighbor_pull_bool of the new vector: on both sides of the cond, the
+    unchanged peers' slots untouched."""
+    conns, rev = edges
+    n = conns.shape[0]
+    rng = np.random.default_rng(changed)
+    old = rng.random(n) < 0.8
+    flips = np.zeros(n, bool)
+    flips[rng.choice(n, changed, replace=False)] = True
+    new = jnp.asarray(old ^ flips)
+    carried = pull.neighbor_pull_bool(jnp.asarray(old), conns, rev)
+    got, tally = pull.neighbor_update_bool(
+        carried, new, jnp.asarray(flips), conns, rev)
+    want = np.asarray(pull.neighbor_pull_bool(new, conns, rev))
+    np.testing.assert_array_equal(np.asarray(got), want)
+    assert np.asarray(tally).tolist() == [
+        int(changed <= K), int(changed > K), changed]
+    if changed:
+        assert (want != np.asarray(carried)).any()

@@ -38,6 +38,41 @@ def test_sharded_simulator_matches_single_device():
     np.testing.assert_array_equal(ra.sends, rb.sends)
 
 
+@pytest.mark.parametrize("churn", [0.0, 0.02])
+@pytest.mark.skipif(len(jax.devices()) < 8, reason="needs 8 (virtual) devices")
+def test_sharded_scan_on_the_sparse_route_matches_single_device(
+        churn):
+    """The heartbeat scan's sparse reciprocity (the scatter from the rows
+    that send, the carried neighbour view under churn) through the SPMD
+    partitioner: the route a large mesh-sharded Simulator takes, forced at
+    this size, against one device on the dense pull."""
+    import dataclasses
+
+    import pull_route
+
+    cfg = dataclasses.replace(_cfg(), churn_down_per_hb=churn,
+                              churn_up_per_hb=churn / 2)
+    a = Simulator(cfg)
+    a.warmup()
+    ra = a.publish(4)
+    assert a.heartbeat_counts["pulls_sparse"] == 0
+
+    with pull_route.forced(0, rows=8):
+        b = Simulator(cfg, mesh=make_peer_mesh(8))
+        b.warmup()
+        rb = b.publish(4)
+    counts = b.heartbeat_counts
+    assert counts["pulls_sparse"] > 0 and counts["graft"]["dense"] >= 1
+    if churn:
+        assert counts["validity"]["sparse"] > 0
+    np.testing.assert_array_equal(
+        np.asarray(a.state.mesh_mask), np.asarray(b.state.mesh_mask))
+    np.testing.assert_array_equal(
+        np.asarray(a.state.alive), np.asarray(b.state.alive))
+    np.testing.assert_array_equal(ra.received, rb.received)
+    np.testing.assert_allclose(ra.delays_ms, rb.delays_ms, rtol=1e-5)
+
+
 @pytest.mark.skipif(len(jax.devices()) < 8, reason="needs 8 (virtual) devices")
 def test_sharded_fragments_unrolled():
     a = Simulator(_cfg(num_frags=2))
