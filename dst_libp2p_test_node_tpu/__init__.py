@@ -22,4 +22,10 @@ Prometheus metric names, and the `"<msgId> milliseconds: <ms>"` stdout line
 format consumed by the reference's awk summaries.
 """
 
+import time
+
+# the first mark of runtime/profiling.process_record(); nothing heavier is
+# imported here, so it stands before jax's import
+IMPORTED_AT = time.perf_counter()
+
 __version__ = "0.1.0"

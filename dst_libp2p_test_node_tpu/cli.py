@@ -210,7 +210,21 @@ def cmd_run(argv: list[str]) -> int:
     p.add_argument("--mix-d", type=int, default=4, help="MIXD")
     p.add_argument("--out-prefix", default="")
     p.add_argument("--stats-json", action="store_true",
-                   help="also write stats<i>.json next to latencies<i>")
+                   help="also write stats<i>.json next to latencies<i>: the "
+                   "summary's numbers, the turn's spans and counters, and "
+                   "under \"compile\" what jax traced, lowered, compiled or "
+                   "loaded from the persistent cache in that turn. Reading "
+                   "it: compiled > 0 with loaded == 0 and stored > 0 is a "
+                   "cold cache (the next process loads instead); programs "
+                   "> 0 in a later turn of the same arguments is a steady "
+                   "state that recompiles (slowest names the program, "
+                   "by_span where); compiled_under_threshold close to "
+                   "compiled with stored == 0, process after process, is a "
+                   "store threshold (stored_threshold_s) that keeps "
+                   "nothing: compile_s of it is paid by every process. "
+                   "Turn 1 of a process adds \"process\": seconds from the "
+                   "package's import to cli.main and to the turn, and the "
+                   "device backend's start (span setup/backend)")
     p.add_argument("--checkpoint", default=None,
                    help="snapshot the experiment to this .npz during the run "
                    "(crash-resumable; see --resume; requires runs == 1)")
@@ -264,7 +278,7 @@ def cmd_run(argv: list[str]) -> int:
                     f"(mix-d={a.mix_d}, publisher inside mix range or "
                     f"rotation on), got {a.num_mix}")
 
-    from .runtime.profiling import span, turn
+    from .runtime.profiling import process_summary, span, turn
     from .runtime.simulator import ExperimentConfig, Simulator
     from .runtime.summarize import report
 
@@ -370,6 +384,16 @@ def cmd_run(argv: list[str]) -> int:
                             # turn (`run` and `run/stats_json` are still
                             # open: up to here)
                             "spans": spans.totals(),
+                            # what jax traced, lowered, compiled or loaded
+                            # from the persistent cache in this turn
+                            # (profiling.CompileTotals); a steady-state turn
+                            # says zeros
+                            "compile": spans.compile.as_dict(),
+                            # the process's first turn only: seconds from
+                            # the package's import to cli.main and to this
+                            # turn, and the device backend's start
+                            **({"process": process_summary()}
+                               if spans.number == 1 else {}),
                             # lines of latencies<i> and shadowlog<i>, and how
                             # many blocks of each the native formatter took
                             # (0: the Python one wrote them)
@@ -1603,6 +1627,7 @@ def cmd_summarize(argv: list[str]) -> int:
 
 
 def main(argv: list[str] | None = None) -> int:
+    entered = time.perf_counter()
     argv = list(sys.argv[1:] if argv is None else argv)
     if not argv:
         print(__doc__)
@@ -1621,9 +1646,19 @@ def main(argv: list[str] | None = None) -> int:
 
         jax.config.update("jax_platforms", platform)
     if cmd not in ("topogen", "summarize"):
+        from .runtime import profiling
         from .runtime.compile_cache import enable_compile_cache
 
+        profiling.mark("main", at=entered)
         enable_compile_cache()
+        if not profiling.marked("backend_ready"):
+            # the device runtime's start as a span of its own: otherwise
+            # the import of `ops/*` (module constants) would make it
+            import jax
+
+            with profiling.span("setup/backend"):
+                jax.devices()
+            profiling.mark("backend_ready")
     if cmd == "topogen":
         return cmd_topogen(rest)
     if cmd == "run":

@@ -12,6 +12,10 @@ same place. cli.main, chip_smoke.py and bench_configs.py call
 
 The directory is part of the cache key, so it is never built from a temp
 name, a pid or a time.
+
+It also starts the compile ledger (runtime/profiling.py): from here on every
+program jax traces, lowers, compiles or loads from this cache is counted in
+`profiling.process_record()` and in the turn's `stats<i>.json` "compile".
 """
 
 from __future__ import annotations
@@ -24,6 +28,9 @@ _CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
 
 def enable_compile_cache() -> str:
     """Apply the rule above; returns the cache directory in effect."""
+    from .profiling import register_compile_listeners
+
+    register_compile_listeners()
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env

@@ -11,18 +11,16 @@ analyses:
                     .memory_analysis() — version-gated (the analysis
                     surfaces moved across jax releases; absent fields
                     come back None, never a crash)
-  count_retraces    a context manager counting jit cache misses (the
-                    "Finished tracing + compiling" log events that
-                    jax_log_compiles exposes) — the PR 1/PR 3 carry bugs
-                    were exactly silent per-iteration retraces
+  count_retraces    a context manager counting jit cache misses (one
+                    backend_compile_duration event of jax.monitoring a
+                    miss, from the compile ledger's listener) — the PR 1/
+                    PR 3 carry bugs were exactly silent per-iteration
+                    retraces
   measure_retraces  calls a contract's representative spec twice with
                     same-aval inputs and returns the SECOND call's
                     retrace count; EntrypointContract.retrace_budget
                     (default 0) turns any excess into a tier-1 failure
                     (tests/test_profiling.py)
-  roofline          the strict-JSON per-entrypoint block: {flops,
-                    hbm_bytes, peak_memory_bytes, retraces,
-                    retrace_budget}
   chrome_trace      flight-recorder curves (ops/telemetry.py) rendered as
                     Chrome-trace/perfetto JSON — one "X" slice per
                     heartbeat with the channel values in args, plus "C"
@@ -35,77 +33,55 @@ analyses:
                     profiler session puts the span on the device trace's
                     clock; `counters` is the zero-length annotation that
                     carries a publish's device-side counters
+  process_record    what the process did up to the end of its first turn:
+                    marks (package imported, cli.main, backend ready, the
+                    first turn's start and end), the spans opened outside
+                    a turn (`setup/backend`) and the compile ledger: every
+                    program jax traced, lowered, compiled or loaded from
+                    the persistent cache, from jax.monitoring's events
+                    (`register_compile_listeners`, which
+                    `enable_compile_cache()` calls), aggregated by
+                    fun_name; a turn's own share, of every turn, goes out
+                    as `stats<i>.json` "compile"
 """
 
 from __future__ import annotations
 
 import contextvars
-import logging
-import os
+import threading
 import time
 from contextlib import contextmanager
 from dataclasses import dataclass, field
 
 import numpy as np
 
-# the pjit cache-miss log lines. jax 0.4.3x logs "Compiling <fn> with
-# global shapes and types" (jax._src.interpreters.pxla) once per in-memory
-# cache miss; earlier releases logged "Finished tracing + compiling"
-# (jax._src.dispatch). A version emits exactly one of the two per miss, so
-# matching either counts each miss once. Counting log events instead of
-# private cache sizes keeps the counter working through jit-internals
-# refactors. (NOT "Finished tracing + transforming": that fires once per
-# sub-transform and would overcount a single compile.)
-_COMPILE_MARKERS = ("Finished tracing + compiling",
-                    "with global shapes and types")
-
 
 class RetraceCounter:
-    """Mutable counter handed out by count_retraces()."""
+    """Mutable counter handed out by count_retraces(); `events` holds the
+    `fun_name` of each miss ("jit(disseminate)")."""
 
     def __init__(self):
         self.count = 0
         self.events: list[str] = []
 
 
-class _CountingHandler(logging.Handler):
-    def __init__(self, counter: RetraceCounter):
-        super().__init__(level=logging.DEBUG)
-        self._counter = counter
-
-    def emit(self, record: logging.LogRecord) -> None:
-        try:
-            msg = record.getMessage()
-        except Exception:
-            return
-        if any(m in msg for m in _COMPILE_MARKERS):
-            self._counter.count += 1
-            self._counter.events.append(msg[:200])
+_RETRACE_COUNTERS: list[RetraceCounter] = []
 
 
 @contextmanager
 def count_retraces():
-    """Count jit cache misses (trace+compile events) inside the block.
-
-    Flips jax_log_compiles on for the duration so the events are emitted at
-    WARNING, attaches a counting handler to the "jax" logger (every
-    jax._src.* module logger propagates into it), and restores both on
-    exit. Persistent-compile-cache hits still count — they are in-memory
-    cache MISSES (a full retrace happened; only the XLA backend compile was
+    """Count jit cache misses (trace+compile events) inside the block: one
+    backend_compile_duration event of the compile ledger's listener a miss.
+    Persistent-compile-cache hits still count — they are in-memory cache
+    MISSES (a full retrace happened; only the XLA backend compile was
     skipped), which is exactly what a retrace budget is about."""
-    import jax
-
+    register_compile_listeners()
     counter = RetraceCounter()
-    handler = _CountingHandler(counter)
-    jlog = logging.getLogger("jax")
-    prev = bool(getattr(jax.config, "jax_log_compiles", False))
-    jax.config.update("jax_log_compiles", True)
-    jlog.addHandler(handler)
+    _RETRACE_COUNTERS.append(counter)
     try:
         yield counter
     finally:
-        jlog.removeHandler(handler)
-        jax.config.update("jax_log_compiles", prev)
+        _RETRACE_COUNTERS.remove(counter)
 
 
 def _dynamic(x) -> bool:
@@ -193,60 +169,6 @@ def measure_retraces(contract) -> int:
     return counter.count
 
 
-def roofline(contracts=None, with_retraces: bool = True,
-             name_prefix: str | None = None) -> dict:
-    """The per-entrypoint roofline block: contract name -> {flops,
-    hbm_bytes, peak_memory_bytes, retraces, retrace_budget} (strict-JSON
-    safe; a contract that cannot lower on this backend reports an `error`
-    string instead of crashing the caller).
-
-    `name_prefix` restricts the sweep to contracts whose name starts with
-    it (e.g. "disseminate/" for the publish-entrypoint CI artifact — the
-    full registry costs minutes of compiles, the publish family seconds).
-    Also honored via the BENCH_ROOFLINE_ONLY env var when the caller does
-    not pass one."""
-    if name_prefix is None:
-        name_prefix = os.environ.get("BENCH_ROOFLINE_ONLY") or None
-    if contracts is None:
-        from ..analysis.registry import default_contracts
-
-        contracts = default_contracts()
-    if name_prefix:
-        contracts = [c for c in contracts if c.name.startswith(name_prefix)]
-    block: dict = {}
-    for c in contracts:
-        entry: dict = {}
-        try:
-            entry.update(entrypoint_cost(c))
-        except Exception as e:  # noqa: BLE001 — per-entry degradation
-            entry["error"] = repr(e)[:200]
-        if with_retraces and "error" not in entry:
-            try:
-                entry["retraces"] = measure_retraces(c)
-                entry["retrace_budget"] = int(c.retrace_budget)
-            except Exception as e:  # noqa: BLE001
-                entry["error"] = repr(e)[:200]
-        block[c.name] = entry
-    return block
-
-
-def check_retrace_budgets(contracts=None) -> list[dict]:
-    """[{name, retraces, budget}] for every contract whose second call
-    retraces above its declared budget (empty = all clean). The tier-1
-    gate (tests/test_profiling.py) asserts this is empty."""
-    if contracts is None:
-        from ..analysis.registry import default_contracts
-
-        contracts = default_contracts()
-    bad = []
-    for c in contracts:
-        got = measure_retraces(c)
-        if got > c.retrace_budget:
-            bad.append({"name": c.name, "retraces": got,
-                        "budget": int(c.retrace_budget)})
-    return bad
-
-
 @contextmanager
 def profiler_trace(log_dir: str | None):
     """jax.profiler capture around the block when `log_dir` is set; a
@@ -282,14 +204,16 @@ class Span:
 
 
 class TurnSpans:
-    """The spans of one `run` turn, in order of opening. One turn owns one
-    of these and drops it when the turn ends, so a process that loops over
-    experiments keeps nothing."""
+    """The spans of one `run` turn, in order of opening, and what jax
+    compiled in it. One turn owns one of these and drops it when the turn
+    ends, so a process that loops over experiments keeps nothing."""
 
     def __init__(self, **attrs):
         self.attrs = attrs    # the turn's identifier, shared by its spans
+        self.number = 0       # which turn of the process: 1, 2, ...
         self.spans: list[Span] = []
         self._open: list[int] = []
+        self.compile = CompileTotals()
 
     def seconds(self, name: str) -> float:
         """Summed duration of the closed spans of that name."""
@@ -310,6 +234,9 @@ class TurnSpans:
 
 _TURN: contextvars.ContextVar[TurnSpans | None] = contextvars.ContextVar(
     "dst_sim_turn_spans", default=None)
+# the innermost span open outside any turn (`setup/backend`)
+_OUTSIDE: contextvars.ContextVar[str | None] = contextvars.ContextVar(
+    "dst_sim_span_outside_a_turn", default=None)
 
 
 @contextmanager
@@ -319,13 +246,22 @@ def span(name: str, **attrs):
     profiler session runs; on the device trace's clock while one does: the
     session is the switch), carrying the turn's identifier and `attrs`.
     Inside a `turn()` it is also noted in the turn's recorder; outside one
-    nothing is recorded."""
+    only its count and seconds are added to the process record's "spans"."""
     from jax.profiler import TraceAnnotation
 
     turn_spans = _TURN.get()
     if turn_spans is None:
-        with TraceAnnotation(SPAN_PREFIX + name, **attrs):
-            yield
+        token = _OUTSIDE.set(name)
+        start = time.perf_counter()
+        try:
+            with TraceAnnotation(SPAN_PREFIX + name, **attrs):
+                yield
+        finally:
+            entry = _PROCESS.spans.setdefault(
+                name, {"count": 0, "total_s": 0.0})
+            entry["count"] += 1
+            entry["total_s"] += time.perf_counter() - start
+            _OUTSIDE.reset(token)
         return
     index = len(turn_spans.spans)
     opened = turn_spans._open
@@ -344,13 +280,22 @@ def span(name: str, **attrs):
 @contextmanager
 def turn(**attrs):
     """One `run` turn: a fresh recorder, current for the block, under one
-    root span "run". Yields the recorder."""
+    root span "run". Yields the recorder. Turns are numbered a process; the
+    first leaves its start and end in the process record's marks, and its
+    end closes the compile ledger."""
     turn_spans = TurnSpans(**attrs)
+    _PROCESS.turns += 1
+    turn_spans.number = _PROCESS.turns
+    first = turn_spans.number == 1
     token = _TURN.set(turn_spans)
     try:
+        if first:
+            mark("turn1_start")
         with span("run"):
             yield turn_spans
     finally:
+        if first:
+            mark("turn1_end")
         _TURN.reset(token)
 
 
@@ -363,6 +308,249 @@ def counters(name: str, **values) -> None:
     ident = turn_spans.attrs if turn_spans is not None else {}
     with TraceAnnotation(SPAN_PREFIX + name, **ident, **values):
         pass
+
+
+# ----------------------------------- process record and compile ledger
+
+# jax.monitoring's names (jax._src.dispatch, jax._src.compiler): the three
+# stages of one program, each delivered as a time span on time.time() with
+# `fun_name`, and what the persistent cache did inside the third
+_TRACE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
+                 "/jax/core/compile/jaxpr_to_mlir_module_duration")
+_BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+# recorded where an entry is written: the program took the store threshold
+_CACHE_STORE_EVENT = "/jax/compilation_cache/cache_misses"
+
+SLOWEST_KEPT = 5
+# the disjoint trace intervals still open to a merge
+_INTERVALS_KEPT = 256
+
+
+class CompileTotals:
+    """What jax traced, lowered, compiled and loaded in one stretch of the
+    process: a turn, or with `by_fun` the ledger (up to the end of the
+    first turn). Sums, a few bounded lists and there one entry a function's
+    name, never a list of events."""
+
+    def __init__(self, by_fun: bool = False):
+        self.compiled = self.loaded = 0
+        self.stored = self.compiled_under_threshold = 0
+        self.compile_s = self.load_s = 0.0
+        self.by_span: dict[str, float] = {}
+        self.slowest: list[tuple] = []      # (seconds, fun_name, kind, ...)
+        self.by_fun: dict[str, dict] | None = {} if by_fun else None
+        # trace and lowering: the union of their intervals, so that a jit
+        # traced inside another counts once. Disjoint, in order of end;
+        # the oldest are folded into a sum
+        self._intervals: list[tuple[float, float]] = []
+        self._folded_s = 0.0
+
+    def _fun(self, fun_name: str) -> dict | None:
+        if self.by_fun is None:
+            return None
+        return self.by_fun.setdefault(fun_name, {
+            "programs": 0, "compiled": 0, "loaded": 0, "stored": 0,
+            "trace_lower_s": 0.0, "compile_s": 0.0, "load_s": 0.0,
+            "span": None})
+
+    def add_trace(self, fun_name: str, start: float, end: float) -> None:
+        entry = self._fun(fun_name)
+        if entry is not None:
+            entry["trace_lower_s"] += end - start
+        kept = self._intervals
+        while kept and kept[-1][1] > start:     # inside or overlapping
+            a, b = kept.pop()
+            start, end = min(start, a), max(end, b)
+        kept.append((start, end))
+        if len(kept) > _INTERVALS_KEPT:
+            half = _INTERVALS_KEPT // 2
+            self._folded_s += sum(b - a for a, b in kept[:half])
+            del kept[:half]
+
+    def add_program(self, fun_name: str, kind: str, seconds: float,
+                    stored: bool, threshold_s: float,
+                    turn_number: int, span_name: str | None) -> None:
+        if kind == "loaded":
+            self.loaded += 1
+            self.load_s += seconds
+        else:
+            self.compiled += 1
+            self.compile_s += seconds
+            self.stored += stored
+            self.compiled_under_threshold += seconds < threshold_s
+        where = span_name or "(no span)"
+        self.by_span[where] = self.by_span.get(where, 0.0) + seconds
+        self.slowest.append((seconds, fun_name, kind, turn_number, span_name))
+        if len(self.slowest) > SLOWEST_KEPT:
+            self.slowest.remove(min(self.slowest, key=lambda e: e[0]))
+        entry = self._fun(fun_name)
+        if entry is not None:
+            entry["programs"] += 1
+            entry[kind] += 1
+            entry["stored"] += stored
+            entry["load_s" if kind == "loaded" else "compile_s"] += seconds
+            if entry["span"] is None:
+                entry["span"] = span_name
+
+    @property
+    def trace_lower_s(self) -> float:
+        return self._folded_s + sum(b - a for a, b in self._intervals)
+
+    def as_dict(self) -> dict:
+        """`stats<i>.json` "compile" (and, with "by_fun" and the longer
+        "slowest" rows, the process record's "compile")."""
+        ledger = self.by_fun is not None
+        out = {
+            "programs": self.compiled + self.loaded,
+            "compiled": self.compiled,
+            "loaded": self.loaded,
+            "stored": self.stored,
+            "trace_lower_s": self.trace_lower_s,
+            "compile_s": self.compile_s,
+            "load_s": self.load_s,
+            "stored_threshold_s": store_threshold_s(),
+            "compiled_under_threshold": self.compiled_under_threshold,
+            "by_span": dict(self.by_span),
+            "slowest": [
+                [fun, kind, sec] + ([turn_number, where] if ledger else [])
+                for sec, fun, kind, turn_number, where
+                in sorted(self.slowest, key=lambda e: -e[0])],
+        }
+        if ledger:
+            out["by_fun"] = {k: dict(v) for k, v in self.by_fun.items()}
+        return out
+
+
+class ProcessRecord:
+    """What `process_record()` is made from. One a process (`_PROCESS`)."""
+
+    def __init__(self):
+        from .. import IMPORTED_AT
+
+        # time.perf_counter(), the clock of Span; the first call of a name
+        # stays: "imported", "main", "backend_ready", "turn1_start",
+        # "turn1_end"
+        self.marks: dict[str, float] = {"imported": IMPORTED_AT}
+        self.turns = 0
+        self.spans: dict[str, dict] = {}    # those opened outside a turn
+        # the compile ledger: open up to the end of the first turn
+        self.setup = CompileTotals(by_fun=True)
+
+
+_PROCESS = ProcessRecord()
+# what the cache did since the thread's last backend event
+_PENDING = threading.local()
+_LISTENING = False
+
+
+def mark(name: str, at: float | None = None) -> None:
+    """Note a moment of the process, once: a later call of the same name
+    changes nothing."""
+    _PROCESS.marks.setdefault(name, time.perf_counter() if at is None else at)
+
+
+def marked(name: str) -> bool:
+    return name in _PROCESS.marks
+
+
+def store_threshold_s() -> float:
+    import jax
+
+    return float(jax.config.jax_persistent_cache_min_compile_time_secs)
+
+
+def _between(marks: dict, a: str, b: str) -> float | None:
+    return marks[b] - marks[a] if a in marks and b in marks else None
+
+
+def process_summary() -> dict:
+    """`stats1.json` "process": seconds from the package's import to
+    cli.main and to the first turn, and the device backend's start between
+    them (span `setup/backend`); None where the process has no such mark."""
+    marks = _PROCESS.marks
+    backend = _PROCESS.spans.get("setup/backend")
+    return {
+        "import_to_main_s": _between(marks, "imported", "main"),
+        "backend_s": backend["total_s"] if backend else None,
+        "import_to_first_turn_s": _between(marks, "imported", "turn1_start"),
+    }
+
+
+def process_record() -> dict:
+    """The process so far, strict-JSON safe: marks on time.perf_counter(),
+    turns started, the spans opened outside a turn, `process_summary()`, and
+    the compile ledger ("setup": up to the end of the first turn)."""
+    return {
+        "marks": dict(_PROCESS.marks),
+        "turns": _PROCESS.turns,
+        "spans": {k: dict(v) for k, v in _PROCESS.spans.items()},
+        "process": process_summary(),
+        "compile": {"setup": _PROCESS.setup.as_dict()},
+    }
+
+
+def _stretches() -> list[CompileTotals]:
+    """Where an event of now is added: the ledger while it is open, and the
+    turn."""
+    turn_spans = _TURN.get()
+    return (([] if "turn1_end" in _PROCESS.marks else [_PROCESS.setup])
+            + ([] if turn_spans is None else [turn_spans.compile]))
+
+
+def _on_event(event: str, **_) -> None:
+    if event == _CACHE_HIT_EVENT:
+        _PENDING.hit = True
+    elif event == _CACHE_STORE_EVENT:
+        _PENDING.stored = True
+
+
+def _on_time_span(event: str, start: float, end: float, fun_name: str = "",
+                  **_) -> None:
+    if event in _TRACE_EVENTS:
+        # the trace is of "f", its lowering and compile of "jit(f)"
+        key = fun_name if "(" in fun_name else f"jit({fun_name})"
+        for totals in _stretches():
+            totals.add_trace(key, start, end)
+        return
+    if event != _BACKEND_EVENT:
+        return
+    # one program: the persistent cache answered, or XLA compiled
+    kind = "loaded" if getattr(_PENDING, "hit", False) else "compiled"
+    stored = getattr(_PENDING, "stored", False)
+    _PENDING.hit = _PENDING.stored = False
+    seconds = end - start
+    turn_spans = _TURN.get()
+    if turn_spans is None:
+        number, where = 0, _OUTSIDE.get()
+    else:
+        number = turn_spans.number
+        opened = turn_spans._open
+        where = turn_spans.spans[opened[-1]].name if opened else None
+    threshold_s = store_threshold_s()
+    for totals in _stretches():
+        totals.add_program(fun_name, kind, seconds, stored, threshold_s,
+                           number, where)
+    for counter in _RETRACE_COUNTERS:
+        counter.count += 1
+        counter.events.append(fun_name)
+    # on the device trace's clock while a profiler session runs: a
+    # recompilation stands beside the idle gap it made
+    counters("compile", fun_name=fun_name, kind=kind, seconds=seconds)
+
+
+def register_compile_listeners() -> None:
+    """Listen to jax.monitoring for the compile ledger, once a process:
+    `enable_compile_cache()` calls this, and every entry point calls that
+    before its first compile."""
+    global _LISTENING
+    if _LISTENING:
+        return
+    from jax import monitoring
+
+    monitoring.register_event_listener(_on_event)
+    monitoring.register_event_time_span_listener(_on_time_span)
+    _LISTENING = True
 
 
 # ------------------------------------------------------- trace export

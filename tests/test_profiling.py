@@ -10,7 +10,7 @@
   - entrypoint_cost returns the {flops, hbm_bytes, peak_memory_bytes}
     block with each field either None (surface absent on this backend) or
     a positive number — never a crash.
-  - roofline() and chrome_trace() emit strict-JSON-safe structures.
+  - chrome_trace() emits a strict-JSON-safe structure.
 """
 
 import json
@@ -21,9 +21,7 @@ import pytest
 from dst_libp2p_test_node_tpu.analysis.registry import default_contracts
 from dst_libp2p_test_node_tpu.runtime.profiling import (
     chrome_trace, count_retraces, entrypoint_cost, measure_retraces,
-    roofline,
 )
-from dst_libp2p_test_node_tpu.runtime.summarize import sanitize_nonfinite
 
 _CONTRACTS = {c.name: c for c in default_contracts()}
 
@@ -59,16 +57,6 @@ def test_entrypoint_cost_fields():
     assert set(cost) == {"flops", "hbm_bytes", "peak_memory_bytes"}
     for k, v in cost.items():
         assert v is None or (isinstance(v, (int, float)) and v > 0), (k, v)
-
-
-def test_roofline_is_strict_json_safe():
-    c = _CONTRACTS["run_heartbeats"]
-    block = roofline(contracts=[c])
-    assert set(block) == {c.name}
-    entry = block[c.name]
-    assert "error" not in entry, entry
-    assert entry["retraces"] <= entry["retrace_budget"]
-    json.dumps(sanitize_nonfinite(block), allow_nan=False)
 
 
 def test_chrome_trace_structure_and_strict_json():

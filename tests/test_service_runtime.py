@@ -535,7 +535,12 @@ class TestBatchedDispatch:
     def test_ewma_times_device_work_not_backoff_sleep(self, sim):
         # satellite pin: a retried dispatch sleeps 200ms of backoff, but
         # the admission estimator must only see the device wall — the old
-        # estimator folded the sleep in and over-shed healthy tenants
+        # estimator folded the sleep in and over-shed healthy tenants.
+        # The device wall of a first dispatch is its compile: warm this
+        # message size here, whatever ran before on this worker
+        warm = _service(sim)
+        warm.submit(PublishRequest("test", 100))
+        assert warm.pump() == 1
         svc = _service(sim, inject_failures=1, max_retries=1,
                        retry_backoff_s=0.2)
         svc.submit(PublishRequest("test", 100))

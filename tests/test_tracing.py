@@ -1,7 +1,10 @@
 """The program's own tracing (ISSUE 27): host spans of one `run` turn
 (runtime/profiling.span / turn), device scopes inside `disseminate`
 (jax.named_scope) and the publish's device-side counters, all through
-`cli.main(["run", ...])` or `disseminate` itself on the CPU backend."""
+`cli.main(["run", ...])` or `disseminate` itself on the CPU backend; and
+(ISSUE 40) the process record and the compile ledger that
+`jax.monitoring`'s events feed, by real compiles and by events fed through
+`jax.monitoring.record_*`."""
 
 import contextlib
 import dataclasses
@@ -10,6 +13,8 @@ import json
 import os
 import re
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -64,6 +69,30 @@ def two_turns(tmp_path_factory):
     _run(tmp, runs=2, capture=turns)
     assert len(turns) == 2
     return tmp, turns
+
+
+@pytest.fixture(scope="module")
+def first_turns(tmp_path_factory):
+    """Two turns of one `run` as the first two of a process: the process
+    record starts anew before them. At a peer count no other test runs, so
+    that whatever this worker ran before, the turn's programs compile."""
+    tmp = tmp_path_factory.mktemp("first_turns")
+    was, profiling._PROCESS = profiling._PROCESS, profiling.ProcessRecord()
+    try:
+        _run(tmp, runs=2, nodes=193)
+        record = profiling.process_record()
+    finally:
+        profiling._PROCESS = was
+    return tmp, record
+
+
+@pytest.fixture
+def fresh_process(monkeypatch):
+    """A process record of the test's own, fed by the listeners."""
+    profiling.register_compile_listeners()
+    process = profiling.ProcessRecord()
+    monkeypatch.setattr(profiling, "_PROCESS", process)
+    return process
 
 
 @pytest.fixture(scope="module")
@@ -341,3 +370,256 @@ def test_latencies_of_the_quick_start_are_the_parents_bytes(tmp_path):
     _run(tmp_path, nodes=1000, seed=0)
     with open(tmp_path / "latencies1", "rb") as f:
         assert hashlib.sha256(f.read()).hexdigest() == want
+
+
+# ------------------------------------ process record and compile ledger
+
+TRACE, LOWER, BACKEND = profiling._TRACE_EVENTS + (profiling._BACKEND_EVENT,)
+COMPILE_KEYS = {
+    "programs", "compiled", "loaded", "stored", "trace_lower_s", "compile_s",
+    "load_s", "stored_threshold_s",
+    "compiled_under_threshold", "by_span", "slowest"}
+
+
+def _feed_program(name, seconds, at=100.0, hit=False, stored=False):
+    """One program's events as jax sends them: what the cache did, then the
+    backend span."""
+    if hit:
+        jax.monitoring.record_event(profiling._CACHE_HIT_EVENT)
+    if stored:
+        jax.monitoring.record_event(profiling._CACHE_STORE_EVENT)
+    jax.monitoring.record_event_time_span(
+        BACKEND, at, at + seconds, fun_name=name)
+
+
+def test_first_turn_of_a_process_carries_process_and_ordered_marks(
+        first_turns):
+    tmp, record = first_turns
+    first, second = _strict(tmp / "stats1.json"), _strict(tmp / "stats2.json")
+    assert set(first["process"]) == {
+        "import_to_main_s", "backend_s", "import_to_first_turn_s"}
+    assert "process" not in second
+    p = first["process"]
+    assert 0.0 <= p["import_to_main_s"] <= p["import_to_first_turn_s"]
+    assert p["backend_s"] >= 0.0
+    json.dumps(record, allow_nan=False)
+    marks = record["marks"]
+    order = ["imported", "main", "backend_ready", "turn1_start", "turn1_end"]
+    assert list(marks) == order
+    assert [marks[k] for k in order] == sorted(marks.values())
+    assert record["turns"] == 2
+    assert record["spans"]["setup/backend"]["count"] == 1
+    assert record["process"] == p
+    # the ledger holds the first turn's programs and closes with it
+    assert set(first["compile"]) == COMPILE_KEYS == set(second["compile"])
+    assert set(record["compile"]) == {"setup"}
+    setup = record["compile"]["setup"]
+    assert setup["programs"] == first["compile"]["programs"] > 0
+    assert second["compile"]["programs"] == 0
+    assert setup["compile_s"] + setup["load_s"] == pytest.approx(
+        sum(first["compile"]["by_span"].values()))
+    # each program under the span it was first called in
+    by_fun = setup["by_fun"]
+    assert by_fun["jit(disseminate)"]["span"] == "publish/dispatch"
+    assert by_fun["jit(_run_heartbeats)"]["span"] == "warmup"
+    assert "build/tables" in first["compile"]["by_span"]
+
+
+def test_profiling_is_importable_before_jax_and_the_backend():
+    """cli.main marks, enables the cache and times the backend's start
+    before anything imports `ops/*`, whose module constants start it."""
+    import subprocess
+    import sys
+
+    code = (
+        "import sys\n"
+        "from dst_libp2p_test_node_tpu.runtime import profiling\n"
+        "from dst_libp2p_test_node_tpu.runtime import compile_cache\n"
+        "assert 'jax' not in sys.modules, 'jax imported'\n"
+        "p = profiling._PROCESS\n"
+        "assert list(p.marks) == ['imported'] and p.turns == 0\n")
+    subprocess.run([sys.executable, "-c", code], check=True,
+                   cwd=os.path.dirname(os.path.dirname(__file__)))
+
+
+def test_a_later_turn_keeps_no_marks(fresh_process):
+    for i in range(1, 4):
+        with profiling.turn(seed=0, turn=1) as spans:
+            assert spans.number == i
+    assert fresh_process.turns == 3
+    assert [k for k in fresh_process.marks if k.startswith("turn")] == [
+        "turn1_start", "turn1_end"]
+
+
+def test_a_jit_first_called_in_a_span_shows_under_name_and_span(
+        fresh_process):
+    @jax.jit
+    def fresh_program_of_test_tracing(x):
+        return x * 5 - 2
+
+    name = "jit(fresh_program_of_test_tracing)"
+    x = jnp.arange(11.0)
+    with profiling.turn(seed=0, turn=1) as spans:
+        with profiling.span("run/simulator_init"), \
+                profiling.span("build/tables"):
+            jax.block_until_ready(fresh_program_of_test_tracing(x))
+    got = spans.compile.as_dict()
+    assert set(got) == COMPILE_KEYS
+    # only the innermost open span is charged
+    assert set(got["by_span"]) == {"build/tables"}
+    rows = [r for r in got["slowest"] if r[0] == name]
+    assert len(rows) == 1 and rows[0][1] in ("compiled", "loaded")
+    assert rows[0][2] == pytest.approx(got["by_span"]["build/tables"])
+    entry = profiling.process_record()["compile"]["setup"]["by_fun"][name]
+    assert entry["programs"] == 1 and entry["span"] == "build/tables"
+    assert entry["trace_lower_s"] > 0.0
+    # the same call again: in memory, no event
+    with profiling.turn(seed=0, turn=1) as again:
+        jax.block_until_ready(fresh_program_of_test_tracing(x))
+    assert again.compile.as_dict()["programs"] == 0
+
+
+def test_second_run_of_the_same_arguments_compiles_nothing(
+        two_turns, tmp_path, capsys):
+    """The steady state the benchmark's window relies on; and what the turn
+    wrote is what the parent wrote, byte for byte."""
+    with open(os.path.join(FIXTURES, "tracing_run_200_seed3.json")) as f:
+        want = json.load(f)
+    capsys.readouterr()
+    _run(tmp_path, runs=1, seed=3)
+    out = capsys.readouterr().out
+    stats = _strict(tmp_path / "stats1.json")
+    assert stats["compile"] == {
+        "programs": 0, "compiled": 0, "loaded": 0, "stored": 0,
+        "trace_lower_s": 0.0, "compile_s": 0.0, "load_s": 0.0,
+        "compiled_under_threshold": 0, "by_span": {}, "slowest": [],
+        "stored_threshold_s": stats["compile"]["stored_threshold_s"]}
+    for name in ("latencies1", "shadowlog1"):
+        with open(tmp_path / name, "rb") as f:
+            assert hashlib.sha256(f.read()).hexdigest() == want[name], name
+    out = re.sub(r"(?m)^\[tpu backend\].*$", "[tpu backend]", out)
+    assert hashlib.sha256(out.encode()).hexdigest() == want["stdout"]
+
+
+def test_a_jit_traced_inside_another_adds_its_interval_once(fresh_process):
+    @jax.jit
+    def inner_of_test_tracing(x):
+        return jnp.sin(x) * 2
+
+    @jax.jit
+    def outer_of_test_tracing(x):
+        return inner_of_test_tracing(x) + inner_of_test_tracing(x * 3)
+
+    with profiling.turn(seed=0, turn=1) as spans:
+        jax.block_until_ready(outer_of_test_tracing(jnp.arange(13.0)))
+    got = spans.compile.as_dict()
+    assert 0.0 < got["trace_lower_s"] <= spans.seconds("run")
+    by_fun = fresh_process.setup.by_fun
+    assert by_fun["jit(inner_of_test_tracing)"]["programs"] == 0
+    assert by_fun["jit(outer_of_test_tracing)"]["programs"] == 1
+    # and exactly, on fed events: the inner trace ends first, inside the
+    # outer; the lowering follows
+    totals = profiling.CompileTotals(by_fun=True)
+    totals.add_trace("jit(inner)", 10.2, 10.4)
+    totals.add_trace("jit(inner)", 10.5, 10.6)
+    totals.add_trace("jit(outer)", 10.0, 11.0)
+    totals.add_trace("jit(outer)", 11.0, 11.5)
+    assert totals.trace_lower_s == pytest.approx(1.5)
+    assert totals.by_fun["jit(inner)"]["trace_lower_s"] == pytest.approx(0.3)
+    assert totals.by_fun["jit(outer)"]["trace_lower_s"] == pytest.approx(1.5)
+
+
+def test_compiled_loaded_stored_and_under_the_threshold(fresh_process):
+    threshold = profiling.store_threshold_s()
+    assert threshold == 1.0             # tests/conftest.py
+    with profiling.turn(seed=0, turn=1) as spans:
+        with profiling.span("warmup"):
+            _feed_program("jit(small)", 0.25)
+            _feed_program("jit(big)", 2.5, stored=True)
+        with profiling.span("publish"), profiling.span("publish/dispatch"):
+            _feed_program("jit(cached)", 0.5, hit=True)
+    got = spans.compile.as_dict()
+    assert (got["programs"], got["compiled"], got["loaded"]) == (3, 2, 1)
+    assert got["stored"] == 1 and got["compiled_under_threshold"] == 1
+    assert got["compile_s"] == pytest.approx(2.75)
+    assert got["load_s"] == pytest.approx(0.5)
+    assert got["by_span"] == pytest.approx(
+        {"warmup": 2.75, "publish/dispatch": 0.5})
+    assert [r[:2] for r in got["slowest"]] == [
+        ["jit(big)", "compiled"], ["jit(cached)", "loaded"],
+        ["jit(small)", "compiled"]]
+    # a hit is spent by the program it answered; and the first turn's end
+    # closed the ledger: a later program counts in its own turn alone
+    with profiling.turn(seed=0, turn=2) as later:
+        _feed_program("jit(in_a_later_turn)", 0.125)
+    got = later.compile.as_dict()
+    assert (got["compiled"], got["loaded"]) == (1, 0)
+    assert fresh_process.setup.compiled + fresh_process.setup.loaded == 3
+
+
+def test_a_thousand_events_of_one_name_leave_one_entry(fresh_process):
+    for i in range(1000):
+        jax.monitoring.record_event_time_span(
+            TRACE, 2.0 * i, 2.0 * i + 0.5, fun_name="again")
+        jax.monitoring.record_event_time_span(
+            LOWER, 2.0 * i + 0.5, 2.0 * i + 1.0, fun_name="jit(again)")
+        _feed_program("jit(again)", 0.001 * (i + 1), at=2.0 * i + 1.0)
+    setup = fresh_process.setup
+    assert list(setup.by_fun) == ["jit(again)"]
+    assert setup.by_fun["jit(again)"]["programs"] == 1000
+    assert len(setup.slowest) == profiling.SLOWEST_KEPT
+    assert len(setup._intervals) <= profiling._INTERVALS_KEPT
+    got = profiling.process_record()["compile"]["setup"]
+    assert got["trace_lower_s"] == pytest.approx(1000.0)
+    assert got["compile_s"] == pytest.approx(0.001 * 1000 * 1001 / 2)
+    assert [r[2] for r in got["slowest"]] == pytest.approx(
+        [1.0, 0.999, 0.998, 0.997, 0.996])
+    json.dumps(got, allow_nan=False)
+
+
+def test_registering_twice_leaves_one_listener(fresh_process):
+    from dst_libp2p_test_node_tpu.runtime.compile_cache import (
+        enable_compile_cache,
+    )
+
+    enable_compile_cache()
+    profiling.register_compile_listeners()
+    enable_compile_cache()
+    with profiling.count_retraces() as counter:
+        _feed_program("jit(once)", 0.5)
+    assert counter.count == 1 and counter.events == ["jit(once)"]
+    assert fresh_process.setup.compiled == 1
+
+
+def test_count_retraces_reads_a_fresh_jit_then_nothing():
+    @jax.jit
+    def fresh_for_count_retraces(x):
+        return x * 7 + 3
+
+    x = jnp.arange(5.0)
+    with profiling.count_retraces() as first:
+        jax.block_until_ready(fresh_for_count_retraces(x))
+    assert first.count >= 1
+    assert "jit(fresh_for_count_retraces)" in first.events
+    with profiling.count_retraces() as second:
+        jax.block_until_ready(fresh_for_count_retraces(x))
+    assert (second.count, second.events) == (0, [])
+    # a counter that was closed hears nothing more
+    _feed_program("jit(later)", 0.5)
+    assert first.count == len(first.events) and second.count == 0
+
+
+def test_a_program_outside_a_turn_names_the_span_it_fell_in(fresh_process):
+    with profiling.span("setup/backend"):
+        _feed_program("jit(early)", 0.5)
+    _feed_program("jit(nowhere)", 0.25)
+    got = profiling.process_record()
+    assert got["spans"]["setup/backend"]["count"] == 1
+    assert got["compile"]["setup"]["by_span"] == pytest.approx(
+        {"setup/backend": 0.5, "(no span)": 0.25})
+    assert got["compile"]["setup"]["slowest"][0] == [
+        "jit(early)", "compiled", pytest.approx(0.5), 0, "setup/backend"]
+    assert got["process"] == {
+        "import_to_main_s": None,
+        "backend_s": got["spans"]["setup/backend"]["total_s"],
+        "import_to_first_turn_s": None}
