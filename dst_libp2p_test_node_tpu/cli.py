@@ -418,6 +418,7 @@ def cmd_run(argv: list[str]) -> int:
                                  "refine_lane_passes": r.refine_lane_passes,
                                  "lanes_hinted": r.lanes_hinted,
                                  "lanes_uncertified": r.lanes_uncertified,
+                                 "lanes_in_pull": r.lanes_in_pull,
                                  "converged": r.converged}
                                 for r in sim.records],
                             # under --churn only: the rates a heartbeat,

@@ -81,9 +81,10 @@ IHAVE/IWANT/IDONTWANT counts, IDONTWANT suppression
 firstMessageDeliveries score credit.
 
 Fragmentation (FRAGMENTS > 1, main.nim:177-179) runs everything once per
-fragment lane: vmapped where all the lanes' row pulls fit the gather budget
-together, one lane at a time in a rolled loop where only one does
-(fragments_in_sequence); a relay's uplink additionally carries the f earlier
+fragment lane: vmapped where one row gather with every lane's table in the
+gathered row fits the gather budget (ops/pull.py: the lanes iterate
+together, a joint pull about one lane's price), one lane at a time in a
+rolled loop where only a single lane's does (fragments_in_sequence); a relay's uplink additionally carries the f earlier
 fragments (f * k_p extra serialization slots) and a message completes at a
 receiver when its LAST fragment lands (main.nim:147-148).
 """
@@ -461,15 +462,30 @@ def fixpoint_formulation(conns_shape, mesh=None) -> str:
 def fragments_in_sequence(conns_shape, fragments: int, mesh=None) -> bool:
     """Whether a one-device publish takes its fragment lanes one at a time
     (one rolled loop over the fragment axis) instead of vmapping them: where
-    the row pulls of all `fragments` lanes at once would pass the gather
-    budget and one lane's would not. What overflows there is the vmap, not
-    any pull (100,000 x 40: 2.05 GB a lane, 8.19 GB for four against 6 GiB),
-    so each lane keeps the "row_pull" formulation and the engines built on
-    it; a shape whose single pull is past the budget is "recv" whatever its
-    fragments, and a mesh unrolls its lanes as it always did."""
+    the row pull of all `fragments` lanes at once would pass the gather
+    budget and one lane's would not. Vmapped lanes share ONE row gather
+    with every lane's table in the gathered row (ops/pull._lanes_in_the_row),
+    so what is weighed is that packed row, N * C * roundup(F * C, 128) * 4
+    bytes: at 100,000 x 40, 2.05 GB a lane, 4.1 GB for four or five lanes,
+    6.1 GB for nine, all inside 6 GiB, so every `topogen -f` choice vmaps
+    there (until PR 41 the lanes gathered one by one, 8.19 GB for four, and
+    ran in sequence). Past the budget each lane keeps the "row_pull"
+    formulation and the engines built on it, one after another; a shape
+    whose single pull is past the budget is "recv" whatever its fragments,
+    and a mesh unrolls its lanes as it always did."""
     return (mesh is None
             and exceeds_budget(jnp.float32, conns_shape, fragments)
             and not exceeds_budget(jnp.float32, conns_shape))
+
+
+def lanes_in_pull(conns_shape, fragments: int, mesh=None) -> int:
+    """How many fragment lanes one row gather of the publish's fixpoints
+    carries: `fragments` where the lanes are vmapped on "row_pull" (their
+    tables side by side in the gathered row), 1 at one fragment, in
+    sequence, on a mesh, and where no row is gathered ("recv")."""
+    packed = (fixpoint_formulation(conns_shape, mesh) == "row_pull"
+              and not fragments_in_sequence(conns_shape, fragments, mesh))
+    return fragments if packed else 1
 
 
 @partial(
@@ -829,9 +845,11 @@ def disseminate(
         its outputs stacked on a leading fragment axis. In sequence
         (fragments_in_sequence): ONE rolled loop, so that one lane's
         intermediates are live and one copy of fn is compiled. Else the
-        vmap where `batched`, and F unrolled copies where not (shard_map
-        does not nest under vmap; a refinement engine's loops, vmapped,
-        would run every lane for as long as the slowest)."""
+        vmap where `batched`: the lanes' loops run jointly, as long as the
+        slowest lane's, a finished lane's carry held, and every joint pull
+        is one row gather with all the lanes in the row (ops/pull.py), about
+        one lane's price. Else F unrolled copies (shard_map does not nest
+        under vmap)."""
         with jax.named_scope("per_fragment"):
             if in_sequence:
                 return jax.lax.map(lambda lane: fn(*lane), xs)
@@ -1753,6 +1771,16 @@ def disseminate(
                       and formulation == "row_pull")
 
         def _serial_all(seed):
+            # the global-sort engine: a rerun that no benchmark cell has
+            # ever taken (refine/legacy) or the engine off "row_pull". Its
+            # from-INF outer loops gain nothing from joint lanes, and F
+            # copies of it would multiply the publish's compile time: one
+            # rolled copy, its pulls one lane wide (a mesh unrolls, as
+            # shard_map under a loop never ran; one lane has no axis)
+            if mesh is None and fragments > 1:
+                with jax.named_scope("per_fragment"):
+                    return jax.lax.map(lambda lane: phases_serial(*lane),
+                                       (frag_ids, t_pubs, seed))
             return _per_fragment(phases_serial, frag_ids, t_pubs, seed,
                                  batched=False)
 
@@ -1767,8 +1795,12 @@ def disseminate(
             if not use_prefix:
                 # the global-sort engine is the one chosen: no fallback
                 return _serial_all(t_fast) + no_fallback
+            # phases_prefix holds no lax.cond, so its lanes vmap like the
+            # fast pipeline's: F(t) == t is a fixed point of further passes
+            # and the batched while_loop holds a finished lane's carry, so
+            # every leaf is the lane's own loop's, its pass count included
             pref = _per_fragment(phases_prefix, frag_ids, t_pubs, t_fast,
-                                 batched=False)
+                                 batched=fragments > 1)
 
             # certificate-gated fallback (nested scalar cond): any
             # fragment the prefix engine could not certify — interleaved

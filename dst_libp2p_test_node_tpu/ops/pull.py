@@ -22,7 +22,10 @@ O(N*C*C) only inside the fused loop body.
 
 Measured again, each as a jit of its own, same shape (PR 28, TPU v5 lite):
   - two-index gather 39.2 ms; the row pull 9.1 ms, gathered rows included
-    (5.3 ms inside the publish's loops: PR 27's trace)
+    (10.6-10.9 ms a pull inside the publish's loops: `publish.fast.device_s`
+    over the pulls it counts, ledger, PR 40, which is 2 x 2.05 GB of padded
+    rows in 10.8 ms, 46 % of 819 GB/s; the 5.3 ms once read from PR 27's
+    trace is borne out by no ledger line)
   - a per-peer lookup `t[idx]` over an (N, C) index: 26.8 ms as XLA's scalar
     gather, 8.6 ms as a row pull of the broadcast table (neighbor_rows_min)
   - a WITHIN-ROW permutation (`take_along_axis(x, idx, axis=-1)`, nothing
@@ -54,6 +57,38 @@ cumsum 0.64 ms, so every line under a millisecond stands on that floor):
   - where it counts, inside a 50-step scan with no dispatch between steps:
     0.25 ms a step with 3 or with 30 sending rows, against 10.3 ms dense
 
+Row width against time (PR 41, TPU v5 lite, same shape and index, each
+candidate a jit of its own, median of 8 timed calls): one gather of the
+rows of an (N, W) table through the (N, C) index and one fused select of
+column `rev` over the whole row.
+      W        40      128     160     256     360
+      f32     8.96    9.02   16.59   16.77   47.90 ms
+      bool   11.13   11.22   17.04   16.94   17.48 ms
+A pull is bound by the ROWS it fetches and the 128-lane tiles they fill, not
+by their bytes: flat across the first tile, 1.85 x for the second (four
+times the columns of W = 40), bool no cheaper than f32 until f32 falls off
+at a third tile. So F tables that share `conns` and `rev` lie side by side in
+one gathered row (_lanes_in_the_row). Four lanes (F, N, C), f32 / bool:
+  - one after another (lax.map of the pull above): 33.7 / 42.1 ms
+  - ONE gather of the (N, 160) table, then a select a lane on its own 40
+    columns: as F reduces 25.7 / 31.5 ms (24.8 a step inside a 20-step
+    loop, against 33.7 one after another and 8.1 for one lane);
+    the same with every lane in ONE variadic reduce, one fusion and one
+    pass over the rows, 27.4 / 28.6, and over F masks 25.4 / 25.8 (24.7 a
+    step): no faster, the selects are elementwise work, not reads;
+    F masks over the flat row 33.2 / 29.3; one masked row and a
+    reduce_window of C columns 24.6 / 17.9 (6.2 GiB of temporaries as the
+    TPU compiler counts them, against 3.8)
+  - a reshape of the gathered rows to (N, C, F, C) 105.8, a slot-major table
+    (column i*F + f) 106.1: relayouts of 4 GB
+  - XLA's own batching of the one-lane pull, what a vmap over `vals` alone
+    got until PR 41: 388.3 ms (and 11.7 GiB of temporaries)
+  - nine lanes (three tiles: f32's fall-off): 74.9 one after another, 72.3
+    packed; no loss, and the per-peer lookup below still gains
+neighbor_rows_min for F lanes, an (N, F) table gathered once, lane f its
+column f: 8.9 ms for four lanes against 33.8 one after another (9.6 against
+74.5 for nine); the (N, F*C) table of broadcast lanes 25.1.
+
 The sharded fixpoint (parallel/exchange.py converge_sharded) deliberately
 does NOT use this: its per-iteration cross-shard traffic is the (N,) time
 vector alone, and the pull there is against receiver-local constants.
@@ -61,19 +96,24 @@ vector alone, and the pull there is against receiver-local constants.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+from jax.custom_batching import custom_vmap
 
 INF = jnp.float32(3.4e38)
 
-# Peak-memory budget for the (N, C, C) row-gather intermediate. The last
-# axis pads to the 128-lane TPU tile, so the real footprint is
-# N*C*max(128,C)*itemsize bytes. Within budget the row gather is the fastest
-# formulation (11 ms f32 / at 100k,C=40 vs 45 ms for the naive 2-index
-# gather). Beyond it — e.g. f32 at 1M peers would be a 20 GiB intermediate —
-# the memory-light 2-index gather WINS outright (732 ms/pull at 1M vs
-# ~2.7 s for a sequentially-chunked row gather: chunk serialization costs
-# more than the random scalar loads), so large pulls simply fall back.
+# Peak-memory budget for the row-gather intermediate: (N, C, C) for one
+# table, (N, C, F*C) where F tables that share `conns` lie side by side in
+# the gathered row (the lanes of a vmap: _lanes_in_the_row). The last axis
+# pads to the 128-lane TPU tile, so the real footprint is
+# N*C*roundup(F*C, 128)*itemsize bytes. Within budget the row gather is the
+# fastest formulation (11 ms f32 / at 100k,C=40 vs 45 ms for the naive
+# 2-index gather). Beyond it — e.g. f32 at 1M peers would be a 20 GiB
+# intermediate — the memory-light 2-index gather WINS outright (732 ms/pull
+# at 1M vs ~2.7 s for a sequentially-chunked row gather: chunk serialization
+# costs more than the random scalar loads), so large pulls simply fall back.
 _MAX_INTERMEDIATE_BYTES = 6 * 1024**3
 _LANE = 128
 
@@ -90,35 +130,121 @@ _SPARSE_MIN_DENSE_BYTES = 128 * 1024**2
 
 
 def intermediate_bytes(dtype, conns_shape, batch_factor: int = 1) -> int:
-    """Bytes of the padded (N, C, C) row-gather intermediate of one pull,
-    times `batch_factor` pulls live at once (see exceeds_budget)."""
+    """Bytes of the padded row-gather intermediate of one pull whose gathered
+    row carries `batch_factor` tables side by side (see exceeds_budget):
+    N * C * roundup(batch_factor * C, 128) * itemsize."""
     n, c = conns_shape[-2], conns_shape[-1]
     itemsize = 1 if dtype == jnp.bool_ else jnp.dtype(dtype).itemsize
-    return n * c * max(_LANE, c) * itemsize * max(batch_factor, 1)
+    width = -(-max(batch_factor, 1) * c // _LANE) * _LANE
+    return n * c * width * itemsize
 
 
 def exceeds_budget(dtype, conns_shape, batch_factor: int = 1) -> bool:
     """The dispatch decision, exposed for tests: would the padded row-gather
     intermediate for this pull exceed the memory budget?
 
-    `batch_factor`: outer vmap width (fragments, topics). Trace-time shapes
-    are per-instance — the REAL allocation is batch_factor times the
-    per-instance intermediate, so the dispatch must account for it or a
-    9-fragment publish would blow an in-budget 2 GiB pull up to 18 GiB."""
+    `batch_factor`: the width of the enclosing vmap over `vals` alone that
+    the caller declares (fragments, topics, trials of one graph).
+    Trace-time shapes are per-instance, and the lanes of such a vmap share
+    ONE gather with all of them in the row (_lanes_in_the_row), so the REAL
+    allocation is the packed row's: at (100000, 40) f32, 2.05 GB for one
+    lane, 4.1 GB for four or five, 6.1 GB for nine, where one gather a lane
+    would have been 2.05 GB times the lanes. The dispatch must account for
+    it or a wide vmap would blow an in-budget pull past the device."""
     return (intermediate_bytes(dtype, conns_shape, batch_factor)
             > _MAX_INTERMEDIATE_BYTES)
 
 
-def _row_pull(vals, conns, rev, select, fallback, batch_factor):
-    """Size-dispatched core. `select(rows, sel)` reduces the gathered rows;
-    `fallback(q, r)` is the direct 2-index gather used when the row-gather
-    intermediate would not fit the budget (see exceeds_budget)."""
-    c = conns.shape[-1]
+def _select_min(rows, sel):
+    return jnp.where(sel, rows, INF).min(axis=-1)
+
+
+def _select_any(rows, sel):
+    return (rows & sel).any(axis=-1)
+
+
+def _gather_select(select):
+    """out[..., q, j] = vals[..., conns[q,j], rev[q,j]] as the whole-row
+    gather and `select(rows, sel)`, the fused pick of column `rev` (clipped:
+    callers mask the invalid slots)."""
+    def pull(vals, conns, rev):
+        c = conns.shape[-1]
+        rows = vals[..., jnp.clip(conns, 0), :]   # (..., N, C, C) contiguous
+        sel = jnp.arange(c) == jnp.clip(rev, 0)[..., None]
+        return select(rows, sel)
+    return pull
+
+
+def _gather_select_packed(select):
+    """`_gather_select` for `vals` (F, N, C) through ONE (N, C) `conns` and
+    `rev`: the F tables lie side by side in one (N, F*C) table, lane f in
+    columns [f*C, (f+1)*C), so ONE gather of N*C rows serves every lane
+    (a pull is bound by the rows it fetches, not by their width: the
+    module docstring's table), and each lane picks column `rev` of its own
+    C columns with the one (N, C, C) mask a single lane builds."""
+    def pull(vals, conns, rev):
+        f, n, c = vals.shape
+        table = jnp.moveaxis(vals, 0, 1).reshape(n, f * c)
+        rows = table[jnp.clip(conns, 0), :]       # (N, C, F*C)
+        sel = jnp.arange(c) == jnp.clip(rev, 0)[..., None]
+        return jnp.stack([select(rows[..., k * c:(k + 1) * c], sel)
+                          for k in range(f)])
+    return pull
+
+
+def _two_index(vals, conns, rev):
+    return vals[jnp.clip(conns, 0), jnp.clip(rev, 0)]
+
+
+@functools.lru_cache(maxsize=None)
+def _lanes_in_the_row(one, packed, scalar, lanes: int, rank: int = 3):
+    """`one(vals, *index)` for a caller that declares an enclosing vmap
+    `lanes` wide, with a batching rule of its own: where that vmap batches
+    `vals` alone (fragment lanes, trials of one graph: every lane goes
+    through the SAME index) the lanes take `packed`, one gather with all of
+    them in the gathered row (`rank`: of the batched `vals` it takes). What
+    the rule sees decides, nothing else:
+    a vmap that batches the index too (trials with a graph each) or is not
+    `lanes` wide (the nested device grids declare what one device holds of
+    a vmap spread over several: a shared row would gather the lanes onto
+    each) keeps what it had, the batched row gather or, where the declared
+    lanes' rows together pass the budget, `scalar`, the gather of single
+    elements.
+    Unbatched (a rolled loop over the lanes) it is `one`."""
+    core = custom_vmap(one)
+
+    @core.def_vmap
+    def rule(axis_size, in_batched, vals, *index):
+        if (axis_size == lanes and vals.ndim == rank and in_batched[0]
+                and not any(in_batched[1:])):
+            return packed(vals, *index), True
+        lane = scalar if (lanes * intermediate_bytes(
+            vals.dtype, index[0].shape) > _MAX_INTERMEDIATE_BYTES) else one
+        axes = tuple(0 if b else None for b in in_batched)
+        return jax.vmap(lane, in_axes=axes)(vals, *index), True
+
+    return core
+
+
+# (one lane, declared lanes in one row, past the budget) of a select
+_PULL_MIN = (_gather_select(_select_min), _gather_select_packed(_select_min),
+             _two_index)
+_PULL_ANY = (_gather_select(_select_any), _gather_select_packed(_select_any),
+             _two_index)
+
+
+def _row_pull(vals, conns, rev, forms, batch_factor: int):
+    """Size-dispatched core over a select's `forms` (_PULL_MIN, _PULL_ANY):
+    the whole-row gather and the select, or past the budget (see
+    exceeds_budget) the direct 2-index gather. One lane is the plain
+    program; declared lanes may share a row (_lanes_in_the_row)."""
+    one, packed, scalar = forms
     if exceeds_budget(vals.dtype, conns.shape, batch_factor):
-        return fallback(jnp.clip(conns, 0), jnp.clip(rev, 0))
-    rows = vals[..., jnp.clip(conns, 0), :]   # (..., N, C, C) contiguous
-    sel = jnp.arange(c) == jnp.clip(rev, 0)[..., None]
-    return select(rows, sel)
+        return scalar(vals, conns, rev)
+    if batch_factor <= 1:
+        return one(vals, conns, rev)
+    return _lanes_in_the_row(one, packed, scalar, batch_factor)(
+        vals, conns, rev)
 
 
 def permute_rows(x: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
@@ -149,10 +275,7 @@ def reciprocal_pull_bool(
     batch_factor: int = 1,
 ) -> jnp.ndarray:
     """out[q, j] = edge_mask[conns[q,j], rev[q,j]]; False on invalid slots."""
-    out = _row_pull(
-        edge_mask, conns, rev,
-        lambda rows, sel: (rows & sel).any(axis=-1),
-        lambda q, r: edge_mask[q, r], batch_factor)
+    out = _row_pull(edge_mask, conns, rev, _PULL_ANY, batch_factor)
     return out & (conns >= 0) & (rev >= 0)
 
 
@@ -301,6 +424,23 @@ def neighbor_pull_min(
     return reciprocal_pull_min(table, conns, rev, batch_factor)
 
 
+def _rows_min_one(per_peer, conns):
+    q = jnp.clip(conns, 0)
+    table = jnp.broadcast_to(per_peer[:, None], conns.shape)
+    return table[q, :].min(axis=-1)
+
+
+def _rows_min_packed(per_peer, conns):
+    """`_rows_min_one` for F lanes (F, N) through one index: the table is
+    constant along a lane's row, so a lane needs one COLUMN of the gathered
+    row, not C of them: (N, F), one gather, lane f reads column f."""
+    return jnp.moveaxis(per_peer.T[jnp.clip(conns, 0), :], -1, 0)
+
+
+def _rows_scalar(per_peer, conns):
+    return per_peer[jnp.clip(conns, 0)]
+
+
 def neighbor_rows_min(
     per_peer: jnp.ndarray, conns: jnp.ndarray, batch_factor: int = 1,
 ) -> jnp.ndarray:
@@ -312,12 +452,14 @@ def neighbor_rows_min(
     while_loop XLA would hoist as a loop invariant and keep in HBM (0.5 GB
     as pred at 100k x 40; ops/disseminate._converge_prefix). Same budget
     dispatch as `_row_pull`; over it, XLA's scalar gather."""
-    q = jnp.clip(conns, 0)
     if exceeds_budget(per_peer.dtype, conns.shape, batch_factor):
-        out = per_peer[q]
+        out = _rows_scalar(per_peer, conns)
+    elif batch_factor <= 1:
+        out = _rows_min_one(per_peer, conns)
     else:
-        table = jnp.broadcast_to(per_peer[:, None], conns.shape)
-        out = table[q, :].min(axis=-1)
+        out = _lanes_in_the_row(
+            _rows_min_one, _rows_min_packed, _rows_scalar, batch_factor,
+            rank=2)(per_peer, conns)
     return jnp.where(conns >= 0, out, INF)
 
 
@@ -328,8 +470,5 @@ def reciprocal_pull_min(
     """out[q, j] = vals[conns[q,j], rev[q,j]] for float vals; INF on invalid
     slots. Exactly-one-hot select via masked min (INF-safe: the fill value
     is the identity of min and also the 'absent' sentinel)."""
-    out = _row_pull(
-        vals, conns, rev,
-        lambda rows, sel: jnp.where(sel, rows, INF).min(axis=-1),
-        lambda q, r: vals[q, r], batch_factor)
+    out = _row_pull(vals, conns, rev, _PULL_MIN, batch_factor)
     return jnp.where((conns >= 0) & (rev >= 0), out, INF)

@@ -30,7 +30,8 @@ from ..config.env import GossipSubParams
 from ..config.topology import Topology, TopoParams
 from ..ops.disseminate import disseminate as _disseminate_program
 from ..ops.disseminate import (fixpoint_formulation, fragments_in_sequence,
-                               valid_edge_at_publish, valid_edge_of)
+                               lanes_in_pull, valid_edge_at_publish,
+                               valid_edge_of)
 from ..ops.graph import build_connection_graph
 from ..ops.heartbeat import PULL_COUNTS, PULL_STAGES, run_heartbeats
 from ..ops.state import SimParams, graph_arrays, init_state
@@ -185,7 +186,7 @@ def drain_heartbeat_carry(carry_ms: float, ms: float, hb_ms: float):
 
 def record_from_result(
     res, *, msg_id: int, publisher: int, t0_ms: float,
-    extra_delay_ms: float = 0.0, drop_self=None,
+    extra_delay_ms: float = 0.0, drop_self=None, lanes_in_pull: int = 1,
 ) -> "MessageRecord":
     """Build a MessageRecord from a DisseminationResult (shared by the
     single-topic and multi-topic publish paths). `drop_self`: peer id (or
@@ -231,6 +232,7 @@ def record_from_result(
         refine_lane_passes=refine_lane_passes,
         lanes_hinted=lanes_hinted,
         lanes_uncertified=lanes_uncertified,
+        lanes_in_pull=lanes_in_pull,
         alive=alive,
         under_dlow=under_dlow,
     )
@@ -286,6 +288,10 @@ class MessageRecord:
     refine_lane_passes: int = 0
     lanes_hinted: int = 0
     lanes_uncertified: int = 0
+    # ops/disseminate.lanes_in_pull: the fragment lanes one row gather of
+    # this publish's fixpoints carried (a trace-time constant of its shape,
+    # no device read)
+    lanes_in_pull: int = 1
     # DisseminationResult.alive / under_dlow: under churn, the peers that
     # could send at this publish and those of them under D_low valid mesh
     # members (`stats<i>.json` "churn"); None without churn
@@ -823,6 +829,8 @@ class Simulator:
                         if (p == origin and not cfg.self_trigger)
                         or not self._subscribed_np[p]
                     ] or None,
+                    lanes_in_pull=lanes_in_pull(
+                        a["conns"].shape, cfg.topo.num_frags, self.mesh),
                 )
                 self.records.append(rec)
             # the publish's counters, with the shape of its fixpoint loops
@@ -843,6 +851,7 @@ class Simulator:
                 formulation=fixpoint_formulation(a["conns"].shape, self.mesh),
                 in_sequence=int(fragments_in_sequence(
                     a["conns"].shape, cfg.topo.num_frags, self.mesh)),
+                lanes_in_pull=rec.lanes_in_pull,
                 **({} if rec.alive is None else
                    {"alive": rec.alive, "under_dlow": rec.under_dlow}))
         return rec

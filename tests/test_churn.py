@@ -285,9 +285,11 @@ def test_churn_free_programs_are_the_parents_but_for_scope_names(program):
     """The StableHLO text without debug info (where the scope names live)
     of the churn-free scan and publish, against what was pinned at the same
     shapes (tests/fixtures/lowered_churn_free.json, with the jax named
-    there): the two publishes as PR 36's parent lowered them, the scan as
-    PR 37 left it (its delivery counters ride in the carry; at this shape
-    the step keeps the dense pull)."""
+    there): the one-fragment publish as PR 36's parent lowered it, the scan
+    as PR 37 left it (its delivery counters ride in the carry; at this shape
+    the step keeps the dense pull): PR 41's lanes in the gathered row leave
+    both texts as they were. The four-fragment publish is PR 41's, whose
+    lanes share their gathers."""
     with open(os.path.join(HERE, "fixtures", "lowered_churn_free.json")) as f:
         pinned = json.load(f)
     if pinned["jax"] != jax.__version__:

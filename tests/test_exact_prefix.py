@@ -103,9 +103,10 @@ def test_prefix_matches_serial_engine(kw, over):
 @pytest.fixture
 def one_lane_budget(monkeypatch):
     """Shrink ops/pull's gather budget to exactly one lane's row pull at the
-    shape given: one fragment is in budget, two or more together are not,
-    the (100000, 40) x 4 fragments position of the benchmark's
-    runsh-100k-frag4 at a test's size. The budget is read while
+    shape given: the lanes whose columns fill one 128-lane tile with one
+    fragment's are in budget (three at C = 40), more together are not: the
+    position the benchmark's runsh-100k-frag4 had until PR 41 put four lanes
+    in one gathered row, kept alive at a test's size. The budget is read while
     `disseminate` is traced, so the jit cache is emptied on both sides."""
     import dst_libp2p_test_node_tpu.ops.pull as pull_mod
 
@@ -120,30 +121,52 @@ def one_lane_budget(monkeypatch):
     disseminate.clear_cache()
 
 
+# four or five lanes of 40 columns are two 128-lane tiles of gathered row:
+# past a budget of one lane's pull (three lanes are one tile, and fit it)
 IN_SEQUENCE_CASES = [
     ({"fragments": 4}, {}),
-    ({"fragments": 3}, {"flood_publish": False, "d_lazy": 12}),
+    ({"fragments": 5}, {"flood_publish": False, "d_lazy": 12}),
 ]
 
 
+def _loop_pulls(jaxpr, shape):
+    """(slice_sizes, output shape) of the row gathers inside the prefix
+    refinement's loops."""
+    return [(ss, shp) for where, ss, shp in _gathers(jaxpr)
+            if "refine" in where and "fixpoint" in where
+            and "legacy" not in where and np.prod(shp) >= np.prod(shape)]
+
+
 @pytest.mark.parametrize("kw,over", IN_SEQUENCE_CASES,
-                         ids=["mesh-frag4", "gossip-heavy-frag3"])
+                         ids=["mesh-frag4", "gossip-heavy-frag5"])
 def test_fragments_in_sequence_stay_on_row_pull_with_the_vmapped_bits(
         kw, over, one_lane_budget):
-    """ISSUE 30: where the fragments' row pulls pass the gather budget
-    together and one alone does not, the publish takes the lanes one at a
+    """ISSUE 30: where the fragments' row pull passes the gather budget
+    and one lane's alone does not, the publish takes the lanes one at a
     time in a rolled loop and keeps the row_pull formulation and the prefix
     engine (until PR 30 it went to "recv" and the global-sort engine). It
     is the in-budget vmapped publish, bit for bit, in every leaf of the
-    result and of the new state."""
+    result and of the new state, the counters' leaf (fast_iters,
+    refine_passes, refine_lane_passes) among them. ISSUE 41: vmapped, the
+    lanes share ONE gather with every lane's table in the gathered row."""
     from dst_libp2p_test_node_tpu.ops.disseminate import (
-        fixpoint_formulation, fragments_in_sequence)
+        fixpoint_formulation, fragments_in_sequence, lanes_in_pull)
 
     g, params, state, a, topo = mesh_setup(**over)
     shape = a["conns"].shape
-    assert not fragments_in_sequence(shape, kw["fragments"])
+    f, (n, c) = kw["fragments"], shape
+    assert not fragments_in_sequence(shape, f)
+    assert lanes_in_pull(shape, f) == f
     res_v, st_v = _publish(state, a, topo, params, **kw)
+    packed = _loop_pulls(jax.make_jaxpr(
+        lambda st: _publish(st, a, topo, params, t0_ms=0.0, **kw))(
+            state).jaxpr, shape)
+    # two phases, each one pull of receiver times (a column a lane) and one
+    # of candidates (C columns a lane): four gathers for all the lanes
+    assert sorted(packed) == sorted(
+        2 * [((1, f), (n, c, f)), ((1, f * c), (n, c, f * c))])
     one_lane_budget(shape)
+    assert lanes_in_pull(shape, f) == 1
     assert fragments_in_sequence(shape, kw["fragments"])
     assert not fragments_in_sequence(shape, 1)
     assert fixpoint_formulation(shape) == "row_pull"
@@ -171,8 +194,27 @@ def test_fragments_in_sequence_stay_on_row_pull_with_the_vmapped_bits(
         assert got[name].tobytes() == want[name].tobytes(), name
 
 
+def test_three_lanes_share_the_tile_one_lane_fills(one_lane_budget):
+    """Three lanes of 40 columns are 120 of a tile's 128: the packed row is
+    the size of one lane's, so a budget that holds one lane holds them and
+    they stay vmapped, one gather for the three."""
+    from dst_libp2p_test_node_tpu.ops.disseminate import (
+        fragments_in_sequence, lanes_in_pull)
+
+    g, params, state, a, topo = mesh_setup(flood_publish=False, d_lazy=12)
+    shape = a["conns"].shape
+    one_lane_budget(shape)
+    assert not fragments_in_sequence(shape, 3)
+    assert lanes_in_pull(shape, 3) == 3
+    assert fragments_in_sequence(shape, 4)
+    pulls = _loop_pulls(jax.make_jaxpr(
+        lambda st: _publish(st, a, topo, params, t0_ms=0.0, fragments=3))(
+            state).jaxpr, shape)
+    assert {ss[-1] for ss, _ in pulls} == {3, 3 * shape[1]}
+
+
 @pytest.mark.parametrize("kw,over", IN_SEQUENCE_CASES,
-                         ids=["mesh-frag4", "gossip-heavy-frag3"])
+                         ids=["mesh-frag4", "gossip-heavy-frag5"])
 def test_prefix_matches_serial_engine_in_sequence(kw, over, one_lane_budget):
     """test_prefix_matches_serial_engine's fragment cases once more with
     the lanes in sequence: the rolled loop over phases_prefix against the

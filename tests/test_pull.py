@@ -310,3 +310,201 @@ def test_neighbor_update_is_a_fresh_neighbor_pull(edges, sparse_at_k, changed):
         int(changed <= K), int(changed > K), changed]
     if changed:
         assert (want != np.asarray(carried)).any()
+
+
+# --------------------------------------------- lanes in the gathered row --
+
+LANES = [1, 2, 4, 9]
+
+
+def _lane_tables(kind, f, conns, seed):
+    """(F, N, C) tables with INF (f32) / False-heavy (bool) entries."""
+    n, c = conns.shape
+    return jnp.stack([_rows_of(kind, jax.random.PRNGKey(seed + k), n, c)
+                      for k in range(f)])
+
+
+def _jaxpr_gathers(fn, *args):
+    """(slice_sizes, output shape) of every gather `fn` traces."""
+    from test_exact_prefix import _gathers
+
+    return [(ss, shape)
+            for _, ss, shape in _gathers(jax.make_jaxpr(fn)(*args).jaxpr)]
+
+
+@pytest.mark.parametrize("f", LANES)
+@pytest.mark.parametrize("name,kind", [
+    ("reciprocal_pull_min", "f32"), ("reciprocal_pull_bool", "bool")])
+def test_vmapped_lanes_are_the_per_lane_pulls(edges, name, kind, f):
+    """ISSUE 41: under the vmap a caller declares (`batch_factor` lanes
+    wide), batching the table alone, the lanes take ONE gather with every
+    lane's C columns in the gathered row. Bit for bit the pull of each lane
+    alone, -1 slots and INF values included."""
+    conns, rev = edges
+    assert bool((conns < 0).any())
+    fn = getattr(pull, name)
+    vals = _lane_tables(kind, f, conns, seed=20)
+    got = jax.vmap(lambda v: fn(v, conns, rev, batch_factor=f))(vals)
+    want = jnp.stack([fn(vals[k], conns, rev) for k in range(f)])
+    assert got.dtype == want.dtype and got.shape == want.shape
+    assert np.asarray(got).tobytes() == np.asarray(want).tobytes()
+    jitted = jax.jit(jax.vmap(
+        lambda v: fn(v, conns, rev, batch_factor=f)))(vals)
+    assert np.asarray(jitted).tobytes() == np.asarray(want).tobytes()
+    # and in a loop's body, which is batched as a jaxpr, not as it is traced
+    looped = jax.vmap(lambda v: jax.lax.fori_loop(
+        0, 2, lambda _, x: fn(x, conns, rev, batch_factor=f), v))(vals)
+    twice = jnp.stack([fn(want[k], conns, rev) for k in range(f)])
+    assert np.asarray(looped).tobytes() == np.asarray(twice).tobytes()
+
+
+@pytest.mark.parametrize("f", LANES)
+def test_vmapped_neighbor_rows_min_is_the_per_lane_lookup(edges, f):
+    """The per-peer lookup of F lanes: one gather of an (N, F) table, lane f
+    its column f; the lookups one by one, bit for bit, INF included."""
+    conns, _ = edges
+    n = conns.shape[0]
+    u = jax.random.uniform(jax.random.PRNGKey(30), (f, n))
+    per_peer = jnp.where(u < 0.7, u * 1e6, pull.INF)
+    shuffled = jax.random.permutation(
+        jax.random.PRNGKey(31), conns, axis=1, independent=True)
+    got = jax.vmap(lambda t: pull.neighbor_rows_min(
+        t, shuffled, batch_factor=f))(per_peer)
+    want = jnp.stack([pull.neighbor_rows_min(per_peer[k], shuffled)
+                      for k in range(f)])
+    assert np.asarray(got).tobytes() == np.asarray(want).tobytes()
+
+
+@pytest.mark.parametrize("name", ["neighbor_pull_min", "neighbor_pull_bool"])
+def test_vmapped_neighbor_pulls_are_the_per_lane_pulls(edges, name):
+    conns, rev = edges
+    n = conns.shape[0]
+    u = jax.random.uniform(jax.random.PRNGKey(32), (4, n))
+    per_peer = u < 0.5 if name.endswith("bool") else u * 1e3
+    fn = getattr(pull, name)
+    got = jax.vmap(lambda t: fn(t, conns, rev, batch_factor=4))(per_peer)
+    want = jnp.stack([fn(per_peer[k], conns, rev) for k in range(4)])
+    assert np.asarray(got).tobytes() == np.asarray(want).tobytes()
+
+
+def test_the_gathers_a_pull_traces(edges):
+    """Unbatched, a pull holds the one gather it always had: rows of C
+    values, (N, C, C) gathered. Under the declared vmap over the table alone
+    it holds ONE gather of rows F*C wide; a lookup's gather is F wide. A vmap
+    nobody declared, or of another width than declared (the nested device
+    grids: lanes spread over devices), keeps the batched gather of rows of
+    C."""
+    conns, rev = edges
+    n, c = conns.shape
+    v = _rows_of("f32", jax.random.PRNGKey(40), n, c)
+    assert _jaxpr_gathers(
+        lambda v: pull.reciprocal_pull_min(v, conns, rev), v) \
+        == [((1, c), (n, c, c))]
+    assert _jaxpr_gathers(
+        lambda t: pull.neighbor_rows_min(t, conns), v[:, 0]) \
+        == [((1, c), (n, c, c))]
+    for f in (2, 4, 9):
+        vals = _lane_tables("f32", f, conns, seed=41)
+        assert _jaxpr_gathers(
+            jax.vmap(lambda v: pull.reciprocal_pull_min(
+                v, conns, rev, batch_factor=f)),
+            vals) == [((1, f * c), (n, c, f * c))]
+        assert _jaxpr_gathers(
+            jax.vmap(lambda m: pull.reciprocal_pull_bool(
+                m, conns, rev, batch_factor=f)),
+            vals < 5e5) == [((1, f * c), (n, c, f * c))]
+        assert _jaxpr_gathers(
+            jax.vmap(lambda t: pull.neighbor_rows_min(
+                t, conns, batch_factor=f)),
+            vals[:, :, 0]) == [((1, f), (n, c, f))]
+        for declared in (1, f + 1):
+            assert _jaxpr_gathers(
+                jax.vmap(lambda v: pull.reciprocal_pull_min(
+                    v, conns, rev, batch_factor=declared)),
+                vals) == [((f, 1, c), (f, n, c, c))]
+
+
+def test_a_batched_index_keeps_the_batched_gather(edges):
+    """Trials with a graph each (the index carries the batch axis too) and a
+    packed row past the budget take the gather they always took: F rows of C
+    values, never a row F*C wide; same bits either way."""
+    conns, rev = edges
+    n, c = conns.shape
+    vals = _lane_tables("f32", 3, conns, seed=50)
+    perms = jnp.stack([jax.random.permutation(jax.random.PRNGKey(k), n)
+                       for k in range(3)])
+    # three relabelled copies of the graph: conns'[p] = perm[conns[inv[p]]]
+    inv = jnp.argsort(perms, axis=-1)
+    cn = jnp.stack([jnp.where(conns[inv[k]] >= 0,
+                              perms[k][jnp.clip(conns[inv[k]], 0)], -1)
+                    for k in range(3)])
+    rv = jnp.stack([rev[inv[k]] for k in range(3)])
+    batched = jax.vmap(
+        lambda v, q, r: pull.reciprocal_pull_min(v, q, r, batch_factor=3))
+    widths = {ss[-1] for ss, _ in _jaxpr_gathers(batched, vals, cn, rv)}
+    assert widths == {c}
+    got = batched(vals, cn, rv)
+    want = jnp.stack([pull.reciprocal_pull_min(vals[k], cn[k], rv[k])
+                      for k in range(3)])
+    assert np.asarray(got).tobytes() == np.asarray(want).tobytes()
+
+
+def test_a_packed_row_past_the_budget_gathers_single_elements(
+        edges, monkeypatch):
+    """Where the declared lanes' packed row is past the budget they take
+    the 2-index gather, as declared lanes past it always did; so do lanes
+    whose rows, gathered one by one (the index batched too), pass it
+    together. Same bits."""
+    conns, rev = edges
+    n, c = conns.shape
+    vals = _lane_tables("f32", 9, conns, seed=60)
+
+    def shared(bf):
+        return jax.vmap(lambda v: pull.reciprocal_pull_min(
+            v, conns, rev, batch_factor=bf))
+
+    want = np.asarray(shared(9)(vals))
+    assert {ss[-1] for ss, _ in _jaxpr_gathers(shared(9), vals)} == {9 * c}
+    # room for one lane's row, one tile wide; nine lanes' columns fill two
+    monkeypatch.setattr(pull, "_MAX_INTERMEDIATE_BYTES",
+                        pull.intermediate_bytes(jnp.float32, (n, c)))
+    assert not pull.exceeds_budget(jnp.float32, (n, c), 128 // c)
+    assert pull.exceeds_budget(jnp.float32, (n, c), 9)
+    assert {ss[-1] for ss, _ in _jaxpr_gathers(shared(9), vals)} == {1}
+    assert np.asarray(shared(9)(vals)).tobytes() == want.tobytes()
+    # five lanes' columns fit the one tile; with an index a lane their five
+    # rows do not
+    each = jax.vmap(lambda v, q, r: pull.reciprocal_pull_min(
+        v, q, r, batch_factor=5))
+    five = (vals[:5], jnp.stack([conns] * 5), jnp.stack([rev] * 5))
+    assert {ss[-1] for ss, _ in _jaxpr_gathers(each, *five)} == {1}
+    assert np.asarray(each(*five)).tobytes() == want[:5].tobytes()
+    assert {ss[-1] for ss, _ in _jaxpr_gathers(shared(5), vals[:5])} \
+        == {5 * c}
+
+
+def test_vmapped_trials_of_one_graph_keep_their_bits(edges):
+    """The campaign's position: heartbeats vmapped over trials that share
+    one graph. Their reciprocity and neighbour pulls share one gathered row
+    from PR 41 on; every leaf is the trial's own run's."""
+    from dst_libp2p_test_node_tpu.ops.heartbeat import heartbeat_step
+    from dst_libp2p_test_node_tpu.ops.state import SimParams, init_state
+
+    g = build_connection_graph(120, 6, seed=3)
+    a = graph_arrays(g)
+    params = SimParams(n=120, capacity=g.capacity)
+    args = (a["conns"], a["rev"], a["out_mask"], params)
+    alone = [init_state(params, seed=s) for s in (1, 2, 3)]
+    together = jax.tree.map(lambda *xs: jnp.stack(xs), *alone)
+    step = jax.vmap(lambda s: heartbeat_step(s, *args, batch_factor=3))
+    assert {ss[-1] for ss, _ in _jaxpr_gathers(step, together)
+            if len(ss) == 2} == {3 * g.capacity}
+    for _ in range(4):
+        together = step(together)
+        alone = [heartbeat_step(s, *args) for s in alone]
+    assert bool(together.mesh_mask.any())
+    for k, s in enumerate(alone):
+        for x, y in zip(jax.tree.leaves(together), jax.tree.leaves(s)):
+            if jnp.issubdtype(y.dtype, jax.dtypes.prng_key):
+                x, y = jax.random.key_data(x), jax.random.key_data(y)
+            assert np.asarray(x[k]).tobytes() == np.asarray(y).tobytes()

@@ -172,9 +172,36 @@ def test_stats_json_spans_and_publishes_are_strict_json(two_turns):
             assert set(p) == {"fast_iters", "refine_passes", "refined",
                               "fell_back", "converged", "refined_serial",
                               "refine_lane_passes", "lanes_hinted",
-                              "lanes_uncertified"}
+                              "lanes_uncertified", "lanes_in_pull"}
+            assert p["lanes_in_pull"] == 1      # one fragment: no lane axis
             assert isinstance(p["fast_iters"], int) and p["fast_iters"] > 0
             assert p["converged"] is True and p["fell_back"] is False
+
+
+@pytest.mark.parametrize("fragments", [1, 4])
+def test_lanes_in_pull_on_the_counters_and_in_stats_json(
+        tmp_path, monkeypatch, fragments):
+    """ISSUE 41: how many fragment lanes one row gather of the publish's
+    fixpoints carries, a trace-time constant of the shape, stated on the
+    `sim:publish/counters` annotation and in `stats<i>.json` "publishes"."""
+    from dst_libp2p_test_node_tpu.runtime import simulator as simmod
+
+    seen = []
+    monkeypatch.setattr(
+        simmod, "counters", lambda name, **values: seen.append((name, values)))
+    rc = cli.main(["run", "1", "200", "15000", str(fragments), "2", "50",
+                   "150", "40", "130", "5", "0.0", "4", "0", "4000", "--seed",
+                   "3", "--stats-json", "--out-prefix", str(tmp_path) + os.sep])
+    assert rc == 0
+    on_publish = [v for name, v in seen if name == "publish/counters"]
+    assert len(on_publish) == 2
+    for values in on_publish:
+        assert values["fragments"] == fragments
+        assert values["lanes_in_pull"] == fragments
+        assert values["in_sequence"] == 0
+        assert values["formulation"] == "row_pull"
+    publishes = _strict(tmp_path / "stats1.json")["publishes"]
+    assert [p["lanes_in_pull"] for p in publishes] == [fragments] * 2
 
 
 def test_stats_json_artifacts_count_what_wrote_shadow_yaml(
@@ -261,7 +288,9 @@ def test_lowered_disseminate_carries_the_scopes(fragments):
                         n) for n in names)
     assert any(re.match(r"fast/per_fragment/(vmap\()?fold\)?/", n)
                for n in names)
-    assert any(n.startswith("refine/cond/") and "/fixpoint/while/body/" in n
+    # (the prefix refinement's lanes are vmapped too where there are several)
+    assert any(n.startswith("refine/cond/")
+               and re.search(r"/per_fragment/(vmap\()?fixpoint\)?/while/body/", n)
                for n in names)
     assert any(n.startswith("refine/cond/") and "/legacy/" in n
                for n in names)
