@@ -3,17 +3,18 @@
 
     python3 benchmark/control.py --workload <cell> --seeds 1,2,3
 
-For each seed it runs the cell's own experiment with its publishes captured
-as benchmark/run.py's part 3 does (same functions, same messages), and
-prints two readings of the numbers that part 3 limits (receivers in one
-reached set only, the share of receivers beyond the tolerance, the share
-beyond one hop):
+For each seed it runs the cell's own experiment with what part 3 checks of
+it captured, as benchmark/run.py's part 3 does (the same functions of the
+cell's entry, benchmark/entries/<entry>.py, the same messages), and prints
+two readings of the numbers that part 3 limits (for the entry `run`:
+receivers in one reached set only, the share of receivers beyond the
+tolerance, the share beyond one hop):
 
-  sound    the program's delays against the float64 reference;
+  sound    what the program produced against the entry's plain reference;
   control  the reference put in the program's place and computed one
-           precision lower (every table and event time rounded to bfloat16,
-           the step below the engine's float32 clock), against the float64
-           reference.
+           precision lower (`run`: every table and event time rounded to
+           bfloat16, the step below the engine's float32 clock), against the
+           same reference.
 
 Every sound reading has to pass the limits of the configuration's file and
 every control reading has to fail one; the last line says whether they do.
@@ -33,27 +34,17 @@ if CHECKOUT not in sys.path:
 
 
 def readings(cell, seed: int, work: str) -> list[dict]:
-    from benchmark.harness import reference_check as rc
-    from benchmark.reference import des
-
-    ref = cell.config["reference"]
-    out = []
-    outcome, taken = rc.captured_experiment(
-        cell, seed, rc.messages_checked(cell, seed), work)
+    outcome, taken = cell.entry.captured(cell, seed, work)
     if not outcome.ok:
         raise RuntimeError(f"seed {seed}: {outcome.faults}")
-    for pub in taken:
-        want_d, want_r = rc.reference_delays(pub, cell)
-        low_d, low_r = rc.reference_delays(pub, cell,
-                                           quantize=des.bfloat16_round)
-        sound = rc.compare(pub["delay_ms"], pub["received"], want_d, want_r,
-                           ref, pub["message"], pub["t0_ms"])
-        control = rc.compare(low_d, low_r, want_d, want_r, ref,
-                             pub["message"], pub["t0_ms"])
-        out.append({"seed": seed, "message": pub["message"],
-                    "sound": sound.line(), "control": control.line(),
-                    "sound_passes": rc.passes(sound, ref),
-                    "control_passes": rc.passes(control, ref)})
+    out = []
+    for item in taken:
+        sound = cell.entry.against_reference(cell, item)
+        control = cell.entry.against_reference(cell, item, control=True)
+        out.append({"seed": seed, "message": sound["message"],
+                    "sound": sound, "control": control,
+                    "sound_passes": sound["passed"],
+                    "control_passes": control["passed"]})
     return out
 
 
@@ -86,11 +77,8 @@ def main(argv=None) -> int:
         "workload": cell.name, "platform": devices[0].platform,
         "device_kind": devices[0].device_kind, "messages": len(rows),
         "limits": cell.config["reference"],
-        **{f"sound_{k}_max": max(r["sound"][k] for r in rows)
-           for k in ("reached_differing", "share_beyond",
-                     "share_beyond_hop", "max_abs_diff_ms")},
-        **{f"control_{k}_min": min(r["control"][k] for r in rows)
-           for k in ("share_beyond", "share_beyond_hop")},
+        **cell.entry.summarised([r["sound"] for r in rows]),
+        **cell.entry.summarised([r["control"] for r in rows], control=True),
         "sound_all_pass_and_control_all_fail": ok}), flush=True)
     return 0 if ok else 1
 

@@ -2,6 +2,10 @@
 
     cell     -> BENCHMARK.json workloads[name]
     config   -> the `file` of BENCHMARK.json configs[cell.config]
+    entry    -> benchmark/entries/<the configuration's `entry`>.py: the
+                argv and environment of one experiment, its invariants, its
+                reference check (benchmark/entries/__init__.py names the
+                entry of a file that says none)
     traffic  -> benchmark/traffic/<cell.traffic>.json
     metric   -> benchmark/layer_metrics/<metric name>.json, whose `reader`
                 names benchmark/readers/<reader>.py (per-layer), or
@@ -17,6 +21,9 @@ import importlib
 import json
 import os
 from dataclasses import dataclass
+from types import ModuleType
+
+from benchmark import entries
 
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CHECKOUT = os.path.dirname(BENCH_DIR)
@@ -35,6 +42,40 @@ def _named(entries: list[dict], name: str, what: str) -> dict:
     return found[0]
 
 
+# what an entry module has to have (benchmark/README.md, "An entry point")
+ENTRY_PARTS = ("invocation", "invariants", "digest_line", "captured",
+               "against_reference", "summarised")
+
+
+def load_entry(name: str, config_path: str) -> ModuleType:
+    """The entry module `<name>.py`: of `entries/` beside the directory the
+    configuration's file is in, where this checkout has one (a
+    configuration of benchmark/tests brings the entry it rehearses), else
+    of benchmark/entries/. Missing, or lacking a part, is a sentence here
+    and not a traceback in the first experiment."""
+    beside = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(config_path))), "entries")
+    places = [d for d in dict.fromkeys((beside, entries.__path__[0]))
+              if os.path.commonpath((d, CHECKOUT)) == CHECKOUT
+              and os.path.isfile(os.path.join(d, name + ".py"))]
+    if not name.isidentifier() or not places:
+        has = sorted(f[:-3] for f in os.listdir(entries.__path__[0])
+                     if f.endswith(".py") and f != "__init__.py")
+        raise SystemExit(
+            f"benchmark: the configuration {config_path} names the entry "
+            f"{name!r}, and there is no benchmark/entries/{name}.py "
+            f"(has: {has})")
+    package = os.path.relpath(places[0], CHECKOUT).replace(os.sep, ".")
+    module = importlib.import_module(f"{package}.{name}")
+    missing = [part for part in ENTRY_PARTS if not hasattr(module, part)]
+    if missing:
+        raise SystemExit(
+            f"benchmark: the entry {name!r} ({module.__file__}) lacks "
+            f"{missing}; benchmark/README.md, 'An entry point', says what "
+            "each is")
+    return module
+
+
 @dataclass
 class Cell:
     name: str
@@ -43,18 +84,10 @@ class Cell:
     config: dict            # the configuration's file
     traffic_name: str
     traffic: dict           # the traffic mix's file
+    entry_name: str         # the configuration's `entry`
+    entry: ModuleType       # benchmark/entries/<entry_name>.py
     end_to_end: list[dict]  # manifest entries, each with its metric file
     per_layer: list[dict]   # manifest entries, each with its metric file
-
-    @property
-    def argv(self) -> dict:
-        """The `run` positionals and flags: the configuration's, with what
-        the traffic mix overrides."""
-        run = self.config["run"]
-        positionals = dict(run["positionals"])
-        positionals.update(self.traffic.get("positionals", {}))
-        flags = list(run.get("flags", [])) + list(self.traffic.get("flags", []))
-        return {"positionals": positionals, "flags": flags}
 
     @property
     def spans(self) -> list[str]:
@@ -80,11 +113,14 @@ def load_cell(workload: str, manifest_path: str | None = None) -> Cell:
             spec = _load(os.path.join(BENCH_DIR, "layer_metrics",
                                       m["name"] + ".json"))
             per_layer.append({**m, "spec": spec})
+    config_path = os.path.join(CHECKOUT, cfg_entry["file"])
+    config = _load(config_path)
+    entry_name = config.get("entry", entries.DEFAULT)
     return Cell(
         name=workload, chips=int(entry["chips"]),
-        config_name=entry["config"],
-        config=_load(os.path.join(CHECKOUT, cfg_entry["file"])),
+        config_name=entry["config"], config=config,
         traffic_name=entry["traffic"], traffic=_load(traffic_path),
+        entry_name=entry_name, entry=load_entry(entry_name, config_path),
         end_to_end=[
             {**m, "spec": _load(os.path.join(BENCH_DIR, "end_to_end",
                                              m["name"] + ".json"))}

@@ -47,48 +47,42 @@ def rehearse_seed(job: tuple[str, int, int, bool]) -> dict:
     os.environ["JAX_PLATFORMS"] = "cpu"
     if CHECKOUT not in sys.path:
         sys.path.insert(0, CHECKOUT)
-    from benchmark.harness import manifest, reference_check as rc
+    from benchmark.harness import manifest
     from benchmark.harness.experiment import run_experiment
-    from benchmark.reference import des
 
     cell = manifest.load_cell(workload)
-    ref = cell.config["reference"]
-    every = list(range(int(cell.argv["positionals"]["num_publishers"])))
+    entry = cell.entry
     work = os.path.join(CHECKOUT, ".bench_work", "rehearse", str(os.getpid()))
     t0 = time.time()
-    with rc.capture_publishes(every) as taken:
-        again = run_experiment(cell, seed, os.path.join(work, "b"))
+    again, taken = entry.captured(cell, seed, os.path.join(work, "b"),
+                                  every=True)
     first = again if part3_only else run_experiment(
         cell, seed, os.path.join(work, "a"))
     t_exp = (time.time() - t0) / (1 if part3_only else 2)
     t0 = time.time()
-    messages, control = [], []
-    for pub in taken:
-        want_d, want_r = rc.reference_delays(pub, cell)
-        messages.append(rc.compare(pub["delay_ms"], pub["received"], want_d,
-                                   want_r, ref, pub["message"], pub["t0_ms"]))
-        if pub["message"] < control_messages:
-            low_d, low_r = rc.reference_delays(pub, cell,
-                                               quantize=des.bfloat16_round)
-            control.append(rc.compare(low_d, low_r, want_d, want_r, ref,
-                                      pub["message"], pub["t0_ms"]))
+    messages = [entry.against_reference(cell, item) for item in taken]
+    control = [entry.against_reference(cell, item, control=True)
+               for item in taken if item["message"] < control_messages]
     t_des = time.time() - t0
-    checked = rc.messages_checked(cell, seed)
+    # the digest line's fields: the digest goes to part 2, cut to 16
+    # characters, and what else the entry says of an experiment to part 1
+    said = entry.digest_line(first)
     row = {
         "seed": seed,
         "part1": {"pass": first.ok and again.ok,
                   "faults": first.faults + again.faults,
-                  "avg_latency_ms": first.stats.get("avg_latency_ms"),
-                  "max_latency_ms": first.stats.get("max_latency_ms")},
-        "part2": {"pass": first.latencies_sha256 == again.latencies_sha256
-                  and first.latencies_sha256 != "", "run": not part3_only,
-                  "latencies_sha256": first.latencies_sha256[:16]},
-        "part3": {"pass": len(messages) == len(every)
-                  and all(rc.passes(c, ref) for c in messages),
-                  "run_py_checks": checked,
-                  "messages": [c.line() for c in messages]},
-        "control": {"fails": all(not rc.passes(c, ref) for c in control),
-                    "messages": [c.line() for c in control]},
+                  **{k: v for k, v in said.items() if v != first.digest}},
+        "part2": {"pass": first.digest == again.digest
+                  and first.digest != "", "run": not part3_only,
+                  **{k: v[:16] for k, v in said.items()
+                     if v == first.digest}},
+        "part3": {"pass": bool(messages)
+                  and all(m["passed"] for m in messages),
+                  "run_py_checks": [item["message"] for item in taken
+                                    if item["drawn"]],
+                  "messages": messages},
+        "control": {"fails": all(not c["passed"] for c in control),
+                    "messages": control},
         "seconds": {"experiment": round(t_exp, 2),
                     "des_and_control": round(t_des, 2)},
     }
@@ -127,16 +121,9 @@ def main(argv=None) -> int:
     sys.path.insert(0, CHECKOUT)
     from benchmark.harness import manifest   # no JAX in the parent
 
-    from_config = manifest.load_cell(a.workload).config["reference"]
+    cell = manifest.load_cell(a.workload)
     sound = [m for r in rows for m in r["part3"]["messages"]]
     low = [m for r in rows for m in r["control"]["messages"]]
-
-    def largest(ms, key):
-        return max((m[key] for m in ms), default=None)
-
-    def smallest(ms, key):
-        return min((m[key] for m in ms), default=None)
-
     summary = {
         "workload": a.workload, "backend": "XLA:CPU (no device number here)",
         "seeds": len(rows), "passed": sum(r["pass"] for r in rows),
@@ -144,15 +131,11 @@ def main(argv=None) -> int:
         "failed_seeds": [r["seed"] for r in rows if not r["pass"]],
         "control_passed_seeds": [r["seed"] for r in rows
                                  if not r["control"]["fails"]],
-        "reference": from_config,
+        "reference": cell.config["reference"],
         "messages_compared": len(sound),
-        "sound_reached_differing_max": largest(sound, "reached_differing"),
-        "sound_share_beyond_max": largest(sound, "share_beyond"),
-        "sound_share_beyond_hop_max": largest(sound, "share_beyond_hop"),
-        "sound_max_abs_diff_ms_max": largest(sound, "max_abs_diff_ms"),
+        **cell.entry.summarised(sound),
         "control_messages_compared": len(low),
-        "control_share_beyond_min": smallest(low, "share_beyond"),
-        "control_share_beyond_hop_min": smallest(low, "share_beyond_hop"),
+        **cell.entry.summarised(low, control=True),
     }
     with open(a.out, "w") as f:
         f.write('{"summary": ' + json.dumps(summary) + ',\n "rows": [\n')

@@ -5,14 +5,17 @@
         --trace <0|1>
 
 A cell is a closed loop of whole experiments through
-`dst_libp2p_test_node_tpu.cli.main(["run", ...])` in this process (see
-benchmark/README.md). Set-up is imports, the device, and one warm-up
-experiment on `--seed` (it compiles on the first run in a checkout and reads
-<checkout>/.jax_cache after). The window then runs experiment after
-experiment, iteration i on `--seed + i`, until `--seconds` have passed and
-the experiment in flight has returned. `correct` is decided after the window
-(parts 1 to 3 below). Earlier lines are JSON objects with a "line" key; the
-last line of stdout is the result.
+`dst_libp2p_test_node_tpu.cli.main([<entry>, ...])` in this process, by the
+configuration's `entry` (see benchmark/README.md; the argv and environment
+of an experiment, its invariants and its reference check are
+benchmark/entries/<entry>.py's, and nothing here knows which entry it is).
+Set-up is imports, the device, and one warm-up experiment on `--seed` (it
+compiles on the first run in a checkout and reads <checkout>/.jax_cache
+after). The window then runs experiment after experiment, iteration i on
+`--seed + i`, until `--seconds` have passed and the experiment in flight has
+returned. `correct` is decided after the window (parts 1 to 3 below).
+Earlier lines are JSON objects with a "line" key; the last line of stdout is
+the result.
 
 It measures on a TPU only. `--rehearse` drives the same path on whatever
 backend JAX has, to rehearse `correct` and the control flow; it prints no
@@ -168,46 +171,44 @@ def main(argv=None) -> int:
         compilations_in_window=retraces.count, setup_s=setup_s,
         warmup_experiment_s=warm.seconds,
         first_s=times[0], last_s=times[-1], min_s=min(times), max_s=max(times))
-    say("statistics_digest", seed=a.seed,
-        latencies_sha256=warm.latencies_sha256,
-        avg_latency_ms=warm.stats.get("avg_latency_ms"),
-        max_latency_ms=warm.stats.get("max_latency_ms"))
+    say("statistics_digest", seed=a.seed, **cell.entry.digest_line(warm))
 
     # part 1: the exact invariants, in the warm-up and in every experiment
     failed = [o for o in outcomes if not o.ok]
     for o in ([] if warm.ok else [warm]) + failed:
         say("correct_part1_fault", seed=o.seed, rc=o.rc, faults=o.faults)
     part1 = warm.ok and not failed
+    missed = len(failed) + (not warm.ok)
     say("correct_part1", what="invariants of every experiment, exact",
-        experiments=len(outcomes) + 1, missed=len(failed) + (not warm.ok),
-        limit=0, passed=part1)
+        experiments=len(outcomes) + 1, missed=missed, limit=0, passed=part1)
     # part 2: iteration 0 repeats the warm-up experiment, byte for byte
-    part2 = (warm.latencies_sha256 != ""
-             and outcomes[0].latencies_sha256 == warm.latencies_sha256)
-    say("correct_part2", what="same seed, same latencies1", seed=a.seed,
-        warmup=warm.latencies_sha256, iteration0=outcomes[0].latencies_sha256,
+    part2 = warm.digest != "" and outcomes[0].digest == warm.digest
+    say("correct_part2", what=f"same seed, same {warm.digest_of}",
+        seed=a.seed, warmup=warm.digest, iteration0=outcomes[0].digest,
         differing_files=int(not part2), limit=0, passed=part2)
-    # part 3: the same experiment once more, its publishes' plans captured,
-    # against the plain reference
-    ref = cell.config["reference"]
+    # part 3: the same experiment once more, what the entry checks of it
+    # captured, against the entry's plain reference
     t0 = time.perf_counter()
     captured, compared, ref_seconds = reference_check.check(
         cell, a.seed, os.path.join(work, "reference"))
-    tied = captured.ok and captured.latencies_sha256 == warm.latencies_sha256
+    tied = captured.ok and captured.digest == warm.digest
     say("correct_part3_tie", what="the captured experiment writes the timed "
-        "experiments' latencies1", seed=a.seed, rc=captured.rc,
-        faults=captured.faults, captured=captured.latencies_sha256,
-        timed=warm.latencies_sha256, differing_files=int(not tied), limit=0,
+        f"experiments' {warm.digest_of}", seed=a.seed, rc=captured.rc,
+        faults=captured.faults, captured=captured.digest,
+        timed=warm.digest, differing_files=int(not tied), limit=0,
         passed=tied)
-    passed = [reference_check.passes(c, ref) for c in compared]
-    for c, ok in zip(compared, passed):
-        say("correct_part3", what="publish against the float64 reference",
-            seed=a.seed, **c.line(),
-            tolerance=f"{ref['atol_ms']} ms + {ref['rtol']} * delay",
-            hop_ms=ref["hop_ms"], **reference_check.limits(ref), passed=ok)
-    part3 = tied and bool(compared) and all(passed)
+    for record in compared:
+        say("correct_part3", **record)
+    part3 = tied and bool(compared) and all(r["passed"] for r in compared)
     say("reference_seconds", **ref_seconds,
         after_window_s=time.perf_counter() - t0)
+    numbers = {
+        "part1.missed": (missed, 0),
+        "part2.differing_files": (int(not part2), 0),
+        "part3.tie.differing_files": (int(not tied), 0),
+        "part3.compared_none": (int(not compared), 0),
+        **{f"part3.m{r['message']}.{k}": pair for r in compared
+           for k, pair in reference_check.limited(r).items()}}
 
     result = {"correct": bool(part1 and part2 and part3),
               "attempted": len(outcomes), "failed": len(failed)}
@@ -236,7 +237,13 @@ def main(argv=None) -> int:
         for key in ("busy_s", "window_s"):
             device.pop(key, None)
     result["device"] = device
+    # every number compared beside its limit: last in the result, and the
+    # last lines of stderr
+    result["compared"] = {name: {"value": value, "limit": limit}
+                          for name, (value, limit) in numbers.items()}
     shutil.rmtree(work, ignore_errors=True)
+    for name, (value, limit) in numbers.items():
+        print(f"compared {name} {value} limit {limit}", file=sys.stderr)
     print(json.dumps(result), flush=True)
     return 0
 

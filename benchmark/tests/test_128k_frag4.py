@@ -14,9 +14,9 @@ from types import SimpleNamespace
 import pytest
 
 from benchmark import run
+from benchmark.entries.run import POSITIONALS, arguments, run_argv
 from benchmark.harness import manifest, program_profile, trace
-from benchmark.harness.experiment import (
-    POSITIONALS, run_argv, run_experiment)
+from benchmark.harness.experiment import run_experiment
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 MANIFEST = os.path.join(HERE, "BENCHMARK.128k-frag4.test.json")
@@ -41,15 +41,15 @@ def test_the_cell_loads_and_runs_a_blob_in_four_fragments():
     cell = manifest.load_cell(CELL)
     assert (cell.chips, cell.config_name, cell.traffic_name) == (
         1, "runsh-100k-128k-frag4", "headline")
-    argv = run_argv(cell.argv, 2147483777, "out")
+    argv = run_argv(arguments(cell), 2147483777, "out")
     assert argv[:15] == ["run", "1", "100000", "131072", "4", "3", "50",
                          "150", "40", "130", "5", "0.0", "4", "0", "12000"]
     # runsh-100k-frag4's experiment but for the size and the slot
     other = manifest.load_cell("runsh-100k-frag4.headline")
-    differing = {k for k in POSITIONALS if cell.argv["positionals"][k]
-                 != other.argv["positionals"][k]}
+    differing = {k for k in POSITIONALS if arguments(cell)["positionals"][k]
+                 != arguments(other)["positionals"][k]}
     assert differing == {"msg_size", "inter_message_delay_ms"}
-    assert cell.argv["flags"] == other.argv["flags"] == []
+    assert arguments(cell)["flags"] == arguments(other)["flags"] == []
     assert cell.config["link_model"] == other.config["link_model"]
     assert cell.config["guarantees"] == other.config["guarantees"]
     # the publisher's own 0 is held in this cell, not waived
@@ -70,7 +70,6 @@ def test_the_new_metrics_are_the_new_cells_alone():
     with open(os.path.join(manifest.CHECKOUT, "BENCHMARK.json")) as f:
         man = json.load(f)
     by_name = {m["name"]: m for m in man["per_layer"]}
-    assert [m["name"] for m in man["per_layer"]][-4:] == list(NEW)
     for name in NEW:
         assert by_name[name]["workloads"] == [CELL]
         assert by_name[name]["moves"] == "experiment_s"

@@ -13,8 +13,8 @@ import os
 import pytest
 
 from benchmark import run
+from benchmark.entries.run import POSITIONALS, arguments, run_argv
 from benchmark.harness import manifest, program_profile
-from benchmark.harness.experiment import POSITIONALS, run_argv
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 MANIFEST = os.path.join(HERE, "BENCHMARK.churn.test.json")
@@ -31,11 +31,11 @@ def test_the_cell_loads_and_runs_under_churn():
     cell = manifest.load_cell(CELL)
     assert (cell.chips, cell.config_name, cell.traffic_name) == (
         1, "runsh-100k-churn", "headline")
-    argv = run_argv(cell.argv, 2147483777, "out")
+    argv = run_argv(arguments(cell), 2147483777, "out")
     assert argv[15:17] == ["--churn", "0.0001:0.00005"]
     # runsh-100k-frag4 with the churn PR 30 left out, and nothing else
     other = manifest.load_cell("runsh-100k-frag4.headline")
-    assert cell.argv["positionals"] == other.argv["positionals"]
+    assert arguments(cell)["positionals"] == arguments(other)["positionals"]
     assert dict(zip(POSITIONALS, argv[1:]))["num_frag"] == "4"
     assert cell.config["link_model"] == other.config["link_model"]
     assert cell.config["reduced"] == ["num_publishers"]

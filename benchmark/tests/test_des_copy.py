@@ -11,7 +11,7 @@ import sys
 import numpy as np
 import pytest
 
-from benchmark.harness import reference_check
+from benchmark.entries import run as run_entry
 from benchmark.reference import des
 
 CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
@@ -63,7 +63,7 @@ def test_copy_equals_the_tests_des(crosscheck, case_index):
     # one precision lower, the same reference is far outside the tolerance
     low_d, low_r = des.des_delays(conns, rev, plan, link, pub, t0, frags,
                                   15000, quantize=des.bfloat16_round)
-    c = reference_check.compare(
+    c = run_entry.compare(
         low_d, low_r, want_d, want_r,
         {"atol_ms": 0.5, "rtol": 1e-4, "hop_ms": 40}, 0, t0)
     assert c.share_beyond > 0.5
@@ -89,14 +89,15 @@ def test_reference_link_tables_are_the_programs():
     cell = manifest.load_cell("tiny.headline", os.path.join(
         os.path.dirname(os.path.abspath(__file__)), "BENCHMARK.test.json"))
     work = os.path.join(manifest.CHECKOUT, ".bench_work", "test.tables")
-    with reference_check.capture_publishes([0, 1]) as taken:
+    with run_entry.capture_publishes([0, 1]) as taken:
         assert run_experiment(cell, 21, work).ok
     pub, later = taken
     # `idle_links_at_publish`: what the last message left has drained
     assert cell.config["reference"]["idle_links_at_publish"]
     assert 0 < later["plan"]["uplink"].max() < later["t0_ms"]
     assert 0 < later["plan"]["rx_free"].max() < later["t0_ms"]
-    own = link_tables.edge_tables(pub["conns"], cell.argv["positionals"],
+    own = link_tables.edge_tables(
+        pub["conns"], run_entry.arguments(cell)["positionals"],
                                   pub["payload_bytes"], pub["fragments"])
     for key, table in own.items():
         np.testing.assert_allclose(table, pub["plan"][key], rtol=1e-6,

@@ -13,8 +13,8 @@ from types import SimpleNamespace
 import pytest
 
 from benchmark import run
+from benchmark.entries.run import POSITIONALS, arguments, run_argv
 from benchmark.harness import manifest, program_profile, trace
-from benchmark.harness.experiment import POSITIONALS, run_argv
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 MANIFEST = os.path.join(HERE, "BENCHMARK.frag4.test.json")
@@ -39,17 +39,17 @@ def test_the_cell_loads_and_runs_four_fragments():
     cell = manifest.load_cell(CELL)
     assert (cell.chips, cell.config_name, cell.traffic_name) == (
         1, "runsh-100k-frag4", "headline")
-    argv = run_argv(cell.argv, 2147483777, "out")
+    argv = run_argv(arguments(cell), 2147483777, "out")
     positional = dict(zip(POSITIONALS, argv[1:]))
     assert positional["num_frag"] == "4" and positional["nodes"] == "100000"
     assert positional["msg_size"] == "15000"
     assert positional["num_publishers"] == "3"
     # the same experiment as runsh-100k but for the fragments
     other = manifest.load_cell("runsh-100k.headline")
-    differing = {k for k in POSITIONALS if cell.argv["positionals"][k]
-                 != other.argv["positionals"][k]}
+    differing = {k for k in POSITIONALS if arguments(cell)["positionals"][k]
+                 != arguments(other)["positionals"][k]}
     assert differing == {"num_frag"}
-    assert cell.argv["flags"] == other.argv["flags"] == []
+    assert arguments(cell)["flags"] == arguments(other)["flags"] == []
     assert cell.config["link_model"] == other.config["link_model"]
     assert set(cell.config["reduced"]) == {"num_publishers", "churn"}
     assert any("LAST fragment" in s
@@ -98,8 +98,8 @@ def one_lane_budget(monkeypatch):
         ExperimentConfig, graph_capacity)
 
     def shrink():
-        peers = int(manifest.load_cell("tiny-frag4.headline", MANIFEST).argv[
-            "positionals"]["nodes"])
+        peers = int(arguments(manifest.load_cell(
+            "tiny-frag4.headline", MANIFEST))["positionals"]["nodes"])
         shape = (peers, graph_capacity(ExperimentConfig()))
         monkeypatch.setattr(
             pull_mod, "_MAX_INTERMEDIATE_BYTES",

@@ -91,9 +91,10 @@ def test_every_cell_resolves_and_reports(man):
         assert set(cell.config["reduced"]) == set(
             next(c for c in man["configs"]
                  if c["name"] == cell.config_name)["reduced"])
-        # every positional the run needs is there
-        from benchmark.harness.experiment import run_argv
-        assert run_argv(cell.argv, 1, "x")[0] == "run"
+        # the entry is found and makes an experiment's argv (for `run`,
+        # every positional it needs is there)
+        argv, env = cell.entry.invocation(cell, 1, "x")
+        assert argv[0] == cell.entry_name and isinstance(env, dict)
     assert used == {c["name"] for c in man["configs"]}
     assert all(m["moves"] in e2e for m in man["per_layer"])
 

@@ -164,23 +164,25 @@ def test_control_fails_part3_on_three_seeds(capsys):
 
 
 def test_messages_checked_are_drawn_from_the_seed():
-    from benchmark.harness import reference_check
+    from benchmark.entries import run as run_entry
 
     cell = manifest.load_cell("tiny.headline", MANIFEST)
-    assert reference_check.messages_checked(cell, 5) == [0, 1, 2]
+
+    def checked(cell, seed):
+        return run_entry.drawn(cell, seed, 3)
+
+    assert checked(cell, 5) == [0, 1, 2]
     cell.config["reference"]["messages"] = 1
-    drawn = {tuple(reference_check.messages_checked(cell, s))
-             for s in range(2147483648, 2147483688)}
+    drawn = {tuple(checked(cell, s)) for s in range(2147483648, 2147483688)}
     assert drawn == {(0,), (1,), (2,)}
-    assert (reference_check.messages_checked(cell, 77)
-            == reference_check.messages_checked(cell, 77))
+    assert checked(cell, 77) == checked(cell, 77)
 
 
 def test_invariants_are_the_configurations_guarantees(tmp_path):
     """check_artifacts holds what the configuration's file guarantees: a
     lossy deployment states a coverage floor and no early-delay rule, and
     the same files then pass."""
-    from benchmark.harness.experiment import check_artifacts
+    from benchmark.entries.run import check_artifacts
 
     argv = {"positionals": {"nodes": 4, "num_publishers": 1,
                             "publisher_id": 0, "publisher_rotation": 0}}

@@ -93,7 +93,6 @@ def test_a_program_without_the_record_gives_none(monkeypatch):
 def test_the_metric_file_and_the_manifest_entry_agree(name):
     with open(os.path.join(manifest.CHECKOUT, "BENCHMARK.json")) as f:
         man = json.load(f)
-    assert [m["name"] for m in man["per_layer"]][-7:] == NAMES
     (entry,) = [m for m in man["per_layer"] if m["name"] == name]
     s = spec(name)
     assert s["name"] == name and s["reader"] == "program_setup"
