@@ -182,6 +182,22 @@ def _churn_rates(text: str) -> tuple[float, float]:
     return down, up
 
 
+def _publish_counts(records) -> list[dict]:
+    """`stats<i>.json` "publishes": a message how much work its fixpoints
+    did and which branches ran (MessageRecord)."""
+    return [{"fast_iters": r.fast_iters,
+             "refine_passes": r.refine_passes,
+             "refined": r.refined,
+             "fell_back": r.fell_back,
+             "refined_serial": r.refined_serial,
+             "refine_lane_passes": r.refine_lane_passes,
+             "lanes_hinted": r.lanes_hinted,
+             "lanes_uncertified": r.lanes_uncertified,
+             "lanes_in_pull": r.lanes_in_pull,
+             "converged": r.converged}
+            for r in records]
+
+
 def cmd_run(argv: list[str]) -> int:
     # flags appended after the 14 positionals tune the TPU backend
     p = argparse.ArgumentParser(
@@ -409,18 +425,7 @@ def cmd_run(argv: list[str]) -> int:
                             # steps delivered from the rows that sent, steps
                             # pulled dense, the most sending rows of a step
                             "heartbeat": sim.heartbeat_counts,
-                            "publishes": [
-                                {"fast_iters": r.fast_iters,
-                                 "refine_passes": r.refine_passes,
-                                 "refined": r.refined,
-                                 "fell_back": r.fell_back,
-                                 "refined_serial": r.refined_serial,
-                                 "refine_lane_passes": r.refine_lane_passes,
-                                 "lanes_hinted": r.lanes_hinted,
-                                 "lanes_uncertified": r.lanes_uncertified,
-                                 "lanes_in_pull": r.lanes_in_pull,
-                                 "converged": r.converged}
-                                for r in sim.records],
+                            "publishes": _publish_counts(sim.records),
                             # under --churn only: the rates a heartbeat,
                             # the peers the draw spares (those the run
                             # publishes through), and a message how many
@@ -1219,16 +1224,45 @@ def cmd_connmanager(argv: list[str]) -> int:
 def cmd_regression(argv: list[str]) -> int:
     """Regression workload (regression/main.nim): GossipSub mesh formed via
     kad-dht bootstrap + mesh ping probes + standard latency output."""
-    p = argparse.ArgumentParser(prog="regression")
-    p.add_argument("-n", "--nodes", type=int, default=None)
+    p = argparse.ArgumentParser(
+        prog="regression",
+        description="The reference's regression node: every peer seeds its "
+        "kad-dht table with the bootstrap, runs a FIND_NODE(self) wave and "
+        "warm-up waves on random targets, dials CONNECTTO peers of its "
+        "routing table, GossipSub (D 6, D_low 4, D_high 8) warms up for "
+        "STARTSLEEP / 4 seconds, the first normal peer publishes, and "
+        "every mesh peer is pinged each 45 s.",
+        epilog="Environment, as the node reads it (regression/env.nim): "
+        "PEERS (100), CONNECTTO (10), STARTSLEEP (180, seconds), FRAGMENTS "
+        "(1), MUXER (yamux), SEED (0), and REGRESSION_BOOTSTRAPS (1: peers "
+        "0.. are the anchors; the first peer after them publishes).")
+    p.add_argument("-n", "--nodes", type=int, default=None,
+                   help="overrides PEERS")
     p.add_argument("--messages", type=int, default=None)
     p.add_argument("--msg-size", type=int, default=None)
     p.add_argument("--log", default=None)
     p.add_argument("--latencies", default=None,
                    help="write awk-compatible latencies file here")
+    p.add_argument("--stats-json", default=None, metavar="PATH",
+                   help="write the experiment's numbers here, as `run "
+                   "--stats-json` names them where they apply (coverage: "
+                   "mean receivers a message, with the count of every "
+                   "message under coverage_by_message; spans, compile, "
+                   "process, emit, build, heartbeat, publishes), and two "
+                   "keys of this entry. \"kad\": the lookups of all waves "
+                   "(lookups, hops_mean: rounds in which a shortlist still "
+                   "improved; queries_per_lookup: FIND_NODE requests sent, "
+                   "at most 18; rtable_census_mean: entries a routing "
+                   "table holds after the last wave), queries_tx / "
+                   "queries_rx summed over the peers (equal: every request "
+                   "is served), lookup_latency_ms: a wave its p50 and p99, "
+                   "a round costing its slowest query; a p99 near 6 rounds "
+                   "or a census under CONNECTTO says the tables are too "
+                   "thin for the mesh. \"pings\": count (one a mesh edge "
+                   "and round), p50_ms, p99_ms, timeouts (over 4000 ms)")
     a = p.parse_args(argv)
 
-    from .runtime.logemit import LatenciesWriter
+    from .runtime.profiling import process_summary, span, turn
     from .runtime.regression_runtime import (
         RegressionSimulator,
         config_from_env as regression_config,
@@ -1242,20 +1276,55 @@ def cmd_regression(argv: list[str]) -> int:
     if a.msg_size is not None:
         cfg.msg_size = a.msg_size
     cfg.validate()
-    t0 = time.time()
-    sim = RegressionSimulator(cfg)
-    summary = sim.run()
-    wall = time.time() - t0
-    if a.log:
-        with open(a.log, "w") as f:
-            f.write("\n".join(sim.lines) + "\n")
-    if a.latencies:
-        w = LatenciesWriter()
-        for rec in sim.records():
-            w.add_message(rec.msg_id, rec.receivers, rec.delays_ms_int)
-        w.write(a.latencies)
-    print(summary.report())
-    print(f"[tpu backend] wall={wall:.2f}s")
+    with turn(seed=cfg.seed) as spans:
+        reg = RegressionSimulator(cfg)
+        summary = reg.run()
+        sim = reg.sim
+        # the program's one clock, from the spans: discovery, build and run
+        wall = sum(spans.seconds(name) for name in (
+            "run/topology", "run/discover", "run/discovery_graph",
+            "run/simulator_init", "run/simulate", "run/pings"))
+        if a.log:
+            with open(a.log, "w") as f:
+                f.write("\n".join(reg.lines) + "\n")
+        if a.latencies:
+            with span("run/write_latencies"):
+                sim.write_latencies(a.latencies)
+        with span("run/summary"):
+            s = sim.summary()
+        with span("run/report"):
+            print(summary.report())
+            print(f"[tpu backend] wall={wall:.2f}s")
+        if a.stats_json:
+            from .runtime.summarize import sanitize_nonfinite
+
+            with span("run/stats_json"), open(a.stats_json, "w") as f:
+                json.dump(
+                    sanitize_nonfinite({
+                        "network_size": s.network_size,
+                        # mean receivers a message, as `run` has it, and
+                        # the count of each: a percentage to one decimal
+                        # cannot say whether 9,999 or 10,000 peers logged
+                        "coverage": s.coverage(),
+                        "coverage_by_message": [
+                            int(r.received.sum()) for r in sim.records],
+                        "max_latency_ms": s.max_latency_ms,
+                        "avg_latency_ms": s.avg_latency_ms,
+                        "avg_max_latency_ms": s.avg_max_latency_ms,
+                        "mesh_degree_mean": summary.mesh_degree_mean,
+                        "wall_s": wall,
+                        "spans": spans.totals(),
+                        "compile": spans.compile.as_dict(),
+                        **({"process": process_summary()}
+                           if spans.number == 1 else {}),
+                        "emit": sim.emit_counts,
+                        "build": sim.graph.build,
+                        "heartbeat": sim.heartbeat_counts,
+                        "publishes": _publish_counts(sim.records),
+                        "kad": reg.kad_stats,
+                        "pings": reg.ping_stats(),
+                    }),
+                    f, indent=2, allow_nan=False)
     return 0
 
 

@@ -41,6 +41,7 @@ KEY_BITS = 32 * KEY_WORDS
 ALPHA = 3                        # parallel queries per lookup round
 K_RESP = 16                      # closest entries returned per FIND_NODE
 PROC_MS = 2.0                    # per-query handler latency
+LEARN_CAP = 8                    # origins a queried peer learns of one wave
 
 
 def make_keys(n: int, seed: int = 0) -> np.ndarray:
@@ -202,20 +203,50 @@ def _closest_from_table(table: jnp.ndarray, keys: jnp.ndarray,
 
 def _teach_learners(state: KadState, flat_peers: jnp.ndarray,
                     flat_origin: jnp.ndarray, extra_ok=None,
-                    e_cap: int = 8) -> KadState:
-    """Group flat (learner <- candidate) events by learner with
-    capacity-bounded segment ranks and batch-insert into every learner's
-    table — the shared scatter behind find_node's query-learning pass and
-    connect_found's dial-backs."""
-    n = state.rtable.shape[0]
+                    e_cap: int | None = LEARN_CAP) -> KadState:
+    """Every learner `flat_peers[e]` learns the candidate `flat_origin[e]`,
+    in the order of the events and under `_insert_one`'s bucket policy (not
+    itself, each candidate once, not one its bucket holds, appended while
+    the bucket has room) — the shared scatter behind find_node's
+    query-learning pass and connect_found's dial-backs. `e_cap`: only a
+    learner's first e_cap events count (None: all of them).
+
+    Flat over the M events, with no per-learner table of candidates: a
+    vmapped `_insert_one` compares every pair of a row's candidates, and at
+    a row of 384 the program of one 10,000-peer wave took twenty minutes to
+    compile for a v5e where this one takes under one; what it costs here
+    is three sorts of M keys."""
+    n, b, k = state.rtable.shape
+    m = flat_peers.shape[0]
     rank, _ = _segment_rank(jnp.where(flat_peers >= 0, flat_peers, n))
-    ok = (flat_peers >= 0) & (rank < e_cap)
+    ok = flat_peers >= 0
+    if e_cap is not None:
+        ok = ok & (rank < e_cap)
     if extra_ok is not None:
         ok = ok & extra_ok
-    learn = jnp.full((n, e_cap), -1, jnp.int32).at[
-        jnp.where(ok, flat_peers, n), jnp.where(ok, rank, 0)
-    ].set(jnp.where(ok, flat_origin, -1), mode="drop")
-    return rtable_insert(state, jnp.arange(n, dtype=jnp.int32), learn)
+    valid = ok & (flat_origin >= 0) & (flat_origin != flat_peers)
+    learner = jnp.where(valid, flat_peers, 0)
+    cand = jnp.where(valid, flat_origin, 0)
+    slot = bucket_slot(
+        jnp.bitwise_xor(state.keys[cand], state.keys[learner]), b)
+    held = (state.rtable[learner, slot] == cand[:, None]).any(axis=-1)
+    # a (learner, candidate) pair that came before: its first event stands
+    group = jnp.where(valid, learner, n)
+    first = jnp.lexsort((jnp.arange(m), cand, group))
+    sl, sc = group[first], cand[first]
+    again = jnp.zeros((m,), bool).at[first].set(jnp.concatenate(
+        [jnp.zeros((1,), bool), (sl[1:] == sl[:-1]) & (sc[1:] == sc[:-1])]))
+    keep = valid & ~held & ~again
+    # position in the bucket: what it holds, then the kept events in order
+    in_bucket, _ = _segment_rank(
+        jnp.where(keep, learner * b + slot, n * b).astype(jnp.int32))
+    occupancy = (state.rtable >= 0).sum(axis=-1)
+    pos = occupancy[learner, slot] + in_bucket
+    put = keep & (pos < k)
+    return state.replace(rtable=state.rtable.at[
+        jnp.where(put, learner, n), jnp.where(put, slot, 0),
+        jnp.where(put, pos, 0)
+    ].set(cand.astype(state.rtable.dtype), mode="drop"))
 
 
 def _pick_alpha(sl: jnp.ndarray, rank: jnp.ndarray, cand: jnp.ndarray,
@@ -278,6 +309,7 @@ def _find_node_impl(
     shortlist: int,
     attacker: jnp.ndarray | None = None,
     poison0: jnp.ndarray | None = None,
+    learn_cap: int | None = LEARN_CAP,
 ) -> tuple[LookupResult, KadState]:
     """Shared lookup body behind find_node and the DHT adversary's attacked
     lookup (ops/dht_adversary.find_node_attacked). The poison hook is
@@ -365,7 +397,7 @@ def _find_node_impl(
     # so parallel lookups hitting the same responder all land
     flat_peers = picked_seq.reshape(-1)
     flat_origin = jnp.broadcast_to(origins[:, None], picked_seq.shape).reshape(-1)
-    state = _teach_learners(state, flat_peers, flat_origin)
+    state = _teach_learners(state, flat_peers, flat_origin, e_cap=learn_cap)
 
     served = jnp.zeros((n,), jnp.int32).at[
         jnp.where(flat_peers >= 0, flat_peers, n)
@@ -382,7 +414,7 @@ def _find_node_impl(
     return result, state
 
 
-@partial(jax.jit, static_argnames=("rounds", "shortlist"))
+@partial(jax.jit, static_argnames=("rounds", "shortlist", "learn_cap"))
 def find_node(
     state: KadState,
     origins: jnp.ndarray,     # (Q,) int32 distinct querying peers
@@ -391,6 +423,7 @@ def find_node(
     lat_ms: jnp.ndarray,      # (S+1, S+1) float32 stage-pair latency
     rounds: int = 6,
     shortlist: int = 32,
+    learn_cap: int | None = LEARN_CAP,
 ) -> tuple[LookupResult, KadState]:
     """Batched iterative FIND_NODE (kad-dht/core.nim warmup/probe primitive).
 
@@ -402,11 +435,13 @@ def find_node(
     still improves — matching the iterative lookup's termination ("no peer
     closer than the best seen" => stop counting).
 
-    Returns per-origin results plus state with updated tables (origin learns
-    every response entry; queried peers learn the origin) and counters.
+    Returns per-origin results plus state with updated tables (the origin
+    learns its final shortlist; a queried peer learns the origins that asked
+    it, the first `learn_cap` of the wave in the order of origin, round and
+    pick, or with None all of them) and counters.
     """
     return _find_node_impl(state, origins, targets, stage, lat_ms,
-                           rounds, shortlist)
+                           rounds, shortlist, learn_cap=learn_cap)
 
 
 @partial(jax.jit, static_argnames=("max_fails", "backoff_base_ms"))

@@ -32,7 +32,7 @@ from ..ops.disseminate import disseminate as _disseminate_program
 from ..ops.disseminate import (fixpoint_formulation, fragments_in_sequence,
                                lanes_in_pull, valid_edge_at_publish,
                                valid_edge_of)
-from ..ops.graph import build_connection_graph
+from ..ops.graph import ConnGraph, build_connection_graph
 from ..ops.heartbeat import PULL_COUNTS, PULL_STAGES, run_heartbeats
 from ..ops.state import SimParams, graph_arrays, init_state
 from .logemit import LatenciesWriter
@@ -132,6 +132,10 @@ class ExperimentConfig:
     # to cold starts. Off by default — the guard's untaken branch doubles
     # the publish compile, which only long publish loops amortize.
     warm_start: bool = False
+    # Per-hop processing delay (SimParams.proc_delay_ms). None (default) =
+    # the muxer's, MUXER_PROC_MS[topo.muxer]; an entry point whose node
+    # runs with another states it here (the regression node: 2.0)
+    proc_delay_ms: float | None = None
     # Message-id layout compat (SURVEY §7 quirks). "nim": a random 64-bit id
     # embedded at payload bytes 8-16 (gossipsub-queues/main.nim:169); "go":
     # the publish timestamp is the dedup key — Go/Rust embed no random id
@@ -315,12 +319,18 @@ class Simulator:
         cfg: ExperimentConfig,
         topology: Topology | None = None,
         mesh=None,
+        graph: ConnGraph | None = None,
     ):
         """`mesh`: optional 1-D jax.sharding.Mesh over the peer axis. When
         given, state/graph arrays are placed row-sharded across its devices
         and the dissemination fixpoint runs the explicit shard_map + ICI
         collective path (parallel/exchange.py). network_size must divide
-        evenly by the device count."""
+        evenly by the device count.
+
+        `graph`: the connection graph of a caller that formed its own (the
+        regression node's, from kad-dht discovery) in place of the shuffle
+        dials of `build_connection_graph`. Params, state, device arrays and
+        every hoisted per-edge table are made from it here, in one place."""
         import jax.numpy as jnp
 
         cfg.topo.validate()
@@ -337,15 +347,21 @@ class Simulator:
         # only inside a `turn`): the graph is host numpy and nothing on the
         # device can start before it, the rest is state, device copies and
         # the tables made from the graph
+        if graph is not None and graph.n != n:
+            raise ValueError(
+                f"graph of {graph.n} peers for a network of {n}")
         with span("build/graph"):
-            self.graph = build_connection_graph(
-                n,
-                cfg.connect_to,
-                seed=cfg.seed,
-                max_degree=graph_capacity(cfg),
-            )
+            if graph is None:
+                graph = build_connection_graph(
+                    n,
+                    cfg.connect_to,
+                    seed=cfg.seed,
+                    max_degree=graph_capacity(cfg),
+                )
+            self.graph = graph
         with span("build/tables"):
-            proc_ms = MUXER_PROC_MS.get(cfg.topo.muxer.lower(), 2.0)
+            proc_ms = (cfg.proc_delay_ms if cfg.proc_delay_ms is not None
+                       else MUXER_PROC_MS.get(cfg.topo.muxer.lower(), 2.0))
             self.params = SimParams.from_gossipsub(
                 n,
                 self.graph.capacity,
