@@ -18,13 +18,18 @@ TPU-native design (not a port):
                     each round queries ALPHA closest unqueried shortlist
                     entries in parallel (round time = max RTT, per the
                     iterative-lookup wait-for-all semantics), merges their
-                    K_RESP closest entries via stable multi-word argsort.
+                    K_RESP closest entries by one keyed sort (`lex_sort`).
 
 Everything is a masked fixed-shape op: shortlists are padded to S entries,
-bucket inserts route dropped entries out of bounds (`mode="drop"`), and
-big-integer XOR comparisons are radix argsorts over the W key words — no
-Python bigints, no dynamic shapes, so the whole lookup batch jits and shards
-over the peer axis like the GossipSub engine.
+bucket inserts route dropped entries out of bounds (`mode="drop"`), and a
+big-integer XOR comparison is one stable `lax.sort` whose keys are the W
+distance words and whose payload is what the caller wants in that order
+(ids, queried flags, or an iota for the permutation) — no Python bigints, no
+dynamic shapes, so the whole lookup batch jits and shards over the peer axis
+like the GossipSub engine. A wave reads `rtable` and `keys` only (what it
+teaches is written after its last round), so `find_node` gathers the key
+words of every table slot once (`_table_keys`) and a response is the row
+pulls of a peer's ids and key words, an XOR and that sort.
 """
 
 from __future__ import annotations
@@ -78,15 +83,23 @@ def bucket_slot(d: jnp.ndarray, n_buckets: int) -> jnp.ndarray:
     return jnp.clip(KEY_BITS - xor_bitlen(d), 0, n_buckets - 1)
 
 
+def lex_sort(words: jnp.ndarray, *payload: jnp.ndarray
+             ) -> tuple[jnp.ndarray, ...]:
+    """The (..., M) `payload` arrays in ascending big-int order of `words`,
+    (W, ..., M) with the most significant word first; entries that tie keep
+    their order. The package's one implementation of the XOR-metric order:
+    a single stable `lax.sort` along the last axis, the W words its keys, the
+    payload carried through the same exchanges (no gather afterwards)."""
+    out = jax.lax.sort(tuple(words) + payload, dimension=-1, is_stable=True,
+                       num_keys=words.shape[0])
+    return out[words.shape[0]:]
+
+
 def lex_argsort(d: jnp.ndarray) -> jnp.ndarray:
-    """Ascending big-int argsort over the trailing word axis of (..., M, W):
-    repeated stable argsorts from least to most significant word (radix)."""
-    idx = jnp.argsort(d[..., -1], axis=-1, stable=True)
-    for w in range(KEY_WORDS - 2, -1, -1):
-        key = jnp.take_along_axis(d[..., w], idx, axis=-1)
-        refine = jnp.argsort(key, axis=-1, stable=True)
-        idx = jnp.take_along_axis(idx, refine, axis=-1)
-    return idx
+    """Ascending stable big-int argsort over the trailing word axis of
+    (..., M, W): `lex_sort` with an iota as its payload."""
+    iota = jax.lax.broadcasted_iota(jnp.int32, d.shape[:-1], d.ndim - 2)
+    return lex_sort(jnp.moveaxis(d, -1, 0), iota)[0]
 
 
 def _dist(keys: jnp.ndarray, entries: jnp.ndarray, target_key: jnp.ndarray):
@@ -190,15 +203,31 @@ def rtable_insert(state: KadState, owners: jnp.ndarray, cands: jnp.ndarray
     return state.replace(rtable=state.rtable.at[owners].set(new_rows))
 
 
+def _table_keys(state: KadState) -> jnp.ndarray:
+    """The key words of every routing-table slot, (W, N, B*K) with the word
+    axis first (a peer's words are W contiguous rows); what an empty slot
+    reads is masked where it is used."""
+    n = state.rtable.shape[0]
+    return jnp.moveaxis(
+        state.keys[jnp.clip(state.rtable.reshape(n, -1), 0)], -1, 0)
+
+
 def _closest_from_table(table: jnp.ndarray, keys: jnp.ndarray,
-                        target_key: jnp.ndarray, k_out: int) -> jnp.ndarray:
-    """The K_RESP closest entries of one (B, K) table to target (-1 padded) —
-    a FIND_NODE response (the reference returns the k nearest from the
-    routing table)."""
+                        target_key: jnp.ndarray, k_out: int,
+                        table_keys: jnp.ndarray | None = None) -> jnp.ndarray:
+    """The k_out closest entries of one (B, K) table (or any id list: it is
+    flattened) to target, closest first, -1 padded — a FIND_NODE response
+    (the reference returns the k nearest from the routing table). One
+    `lex_sort` over the slots' XOR distances that carries the ids; an empty
+    slot's distance is all ones, so it sorts last. `table_keys`: the (W, B*K)
+    key words of the slots where the caller has gathered them already
+    (`_table_keys`), else they are gathered from `keys` here."""
     flat = table.reshape(-1)
-    order = lex_argsort(_dist(keys, flat, target_key))
-    best = flat[order[:k_out]]
-    return best
+    if table_keys is None:
+        table_keys = keys[jnp.clip(flat, 0)].T
+    d = jnp.where(flat >= 0, jnp.bitwise_xor(table_keys, target_key[:, None]),
+                  jnp.uint32(0xFFFFFFFF))
+    return lex_sort(d, flat)[0][:k_out]
 
 
 def _teach_learners(state: KadState, flat_peers: jnp.ndarray,
@@ -267,27 +296,25 @@ def _merge_shortlist(keys: jnp.ndarray, sl: jnp.ndarray, queried: jnp.ndarray,
                      targets: jnp.ndarray, s: int
                      ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Merge FIND_NODE responses into the shortlist: concat, dedup keeping
-    the queried copy of an id (sort key = id*2 + freshness; ids < 2^30 so
-    int32 is safe), lex-sort by XOR distance, keep the closest S with their
-    queried flags. Shared by find_node and servicedisco.lookup."""
+    the queried copy of an id (one sort of the key id*2 + freshness, from
+    which id and flag are read back; ids < 2^30 so int32 is safe), then one
+    `lex_sort` by XOR distance that carries the ids and the queried flags;
+    keep the closest S. Shared by find_node and servicedisco.lookup."""
     q = sl.shape[0]
     merged = jnp.concatenate([sl, resp.reshape(q, -1)], axis=-1)
     mq = jnp.concatenate(
         [queried | pick, jnp.zeros((q, merged.shape[1] - s), bool)], axis=-1
     )
-    mkey = merged * 2 + jnp.where(mq, 0, 1)
-    dorder = jnp.argsort(mkey, axis=-1, stable=True)
-    msort = jnp.take_along_axis(merged, dorder, axis=-1)
-    qsort = jnp.take_along_axis(mq, dorder, axis=-1)
+    # the key holds both what it sorts: id = key >> 1, queried = even
+    mkey = jnp.sort(merged * 2 + jnp.where(mq, 0, 1), axis=-1, stable=False)
+    msort, qsort = mkey >> 1, (mkey & 1) == 0
     dup = jnp.concatenate(
         [jnp.zeros((q, 1), bool), msort[:, 1:] == msort[:, :-1]], axis=-1
     )
     msort = jnp.where(dup | (msort < 0), -1, msort)
     md = _dist(keys, msort, targets)
-    morder = lex_argsort(md)[:, :s]
-    sl_new = jnp.take_along_axis(msort, morder, axis=-1)
-    q_new = jnp.take_along_axis(qsort & ~dup, morder, axis=-1)
-    return sl_new, q_new
+    sl_new, q_new = lex_sort(jnp.moveaxis(md, -1, 0), msort, qsort & ~dup)
+    return sl_new[:, :s], q_new[:, :s]
 
 
 @struct.dataclass
@@ -324,54 +351,72 @@ def _find_node_impl(
     s = shortlist
 
     o_stage = stage[origins]
+    flat_tables = state.rtable.reshape(n, -1)
+
+    with jax.named_scope("seed"):
+        # the tables and the keys are read-only until the learning pass, so
+        # every slot's key words are gathered once a wave, not a response
+        table_keys = _table_keys(state)
+
+    def table_closest(peer, target_key, k_out):
+        """The k_out closest of `peer`'s table: its ids and each of its W
+        key-word rows are one contiguous row pull."""
+        return _closest_from_table(
+            flat_tables[peer], state.keys, target_key, k_out,
+            table_keys=jnp.stack([words[peer] for words in table_keys]))
+
+    with jax.named_scope("seed"):
+        # seed shortlist from the origin's own table
+        sl0 = jax.vmap(lambda o, t: table_closest(o, t, s))(origins, targets)
+    queried0 = jnp.zeros((q, s), bool)
 
     def response(peer, target_key):
         """FIND_NODE response of `peer` (masked if dead)."""
-        resp = _closest_from_table(state.rtable[peer], state.keys, target_key,
-                                   K_RESP)
-        return jnp.where(state.alive[peer], resp, -1)
-
-    # seed shortlist from the origin's own table
-    sl0 = jax.vmap(
-        lambda o, t: _closest_from_table(state.rtable[o], state.keys, t, s)
-    )(origins, targets)
-    queried0 = jnp.zeros((q, s), bool)
+        return jnp.where(state.alive[peer],
+                         table_closest(peer, target_key, K_RESP), -1)
 
     def round_body(carry, _):
         sl, queried, t_acc, hops, nq = carry
-        d = _dist(state.keys, sl, targets)
-        order = lex_argsort(d)                            # (Q, S)
-        rank = jnp.argsort(order, axis=-1)                # distance rank
-        # a node never FIND_NODEs itself over the network, so the origin's
-        # own id (distance 0 on self-lookups) is not a query candidate
-        cand = ((sl >= 0) & ~queried & state.alive[jnp.clip(sl, 0)]
-                & (sl != origins[:, None]))
-        # classic termination: the lookup is done once every entry in the
-        # top-K_RESP head of the shortlist has been queried
-        head_unqueried = (cand & (rank < K_RESP)).any(axis=-1)
-        cand = cand & head_unqueried[:, None]
-        # pick the ALPHA closest unqueried, by distance rank
-        pick, p_ids = _pick_alpha(sl, rank, cand, s)
-        any_pick = pick.any(axis=-1)
+        with jax.named_scope("order"):
+            # a node never FIND_NODEs itself over the network, so the
+            # origin's own id (distance 0 on self-lookups) is not a query
+            # candidate
+            cand = ((sl >= 0) & ~queried & state.alive[jnp.clip(sl, 0)]
+                    & (sl != origins[:, None]))
+            # classic termination: the lookup is done once every entry in
+            # the top-K_RESP head of the shortlist has been queried. The
+            # shortlist is in distance order as _closest_from_table and
+            # _merge_shortlist return it (only empty slots tie, and they
+            # come last), so an entry's distance rank is its position
+            head_unqueried = cand[:, :K_RESP].any(axis=-1)
+            cand = cand & head_unqueried[:, None]
+            # pick the ALPHA closest unqueried
+            pick, p_ids = _pick_alpha(sl, jnp.arange(s), cand, s)
+            any_pick = pick.any(axis=-1)
 
-        resp = jax.vmap(jax.vmap(response, in_axes=(0, None)))(
-            jnp.clip(p_ids, 0), targets
-        )                                                 # (Q, ALPHA, K_RESP)
-        resp = jnp.where((p_ids >= 0)[..., None], resp, -1)
-        if attacker is not None:
-            # lookup eclipse: a live attacker responder answers with the
-            # sybil directory's closest entries instead of its table
-            is_att = ((p_ids >= 0) & attacker[jnp.clip(p_ids, 0)]
-                      & state.alive[jnp.clip(p_ids, 0)])
-            resp = jnp.where(is_att[..., None], poison0[:, None, :], resp)
+        with jax.named_scope("response"):
+            # one flat batch of Q*ALPHA tables: the sort runs over rows of
+            # a (Q*ALPHA, B*K) array, whose tiles are full
+            resp = jax.vmap(response)(
+                jnp.clip(p_ids, 0).reshape(-1),
+                jnp.repeat(targets, ALPHA, axis=0),
+            ).reshape(q, ALPHA, K_RESP)
+            resp = jnp.where((p_ids >= 0)[..., None], resp, -1)
+            if attacker is not None:
+                # lookup eclipse: a live attacker responder answers with the
+                # sybil directory's closest entries instead of its table
+                is_att = ((p_ids >= 0) & attacker[jnp.clip(p_ids, 0)]
+                          & state.alive[jnp.clip(p_ids, 0)])
+                resp = jnp.where(is_att[..., None], poison0[:, None, :], resp)
 
         # round RTT = max over the parallel queries (iterative lookup waits)
         rtt = 2.0 * lat_ms[o_stage[:, None], stage[jnp.clip(p_ids, 0)]] + PROC_MS
         rtt = jnp.where(p_ids >= 0, rtt, 0.0)
         round_ms = rtt.max(axis=-1)
 
-        sl_new, q_new = _merge_shortlist(
-            state.keys, sl, queried, pick, resp, targets, s)
+        with jax.named_scope("merge"):
+            sl_new, q_new = _merge_shortlist(
+                state.keys, sl, queried, pick, resp, targets, s)
 
         improved = jnp.any(sl_new != sl, axis=-1) & any_pick
         t_acc = t_acc + jnp.where(any_pick, round_ms, 0.0)
@@ -390,22 +435,26 @@ def _find_node_impl(
     picked_seq = jnp.moveaxis(picked_seq, 0, 1).reshape(q, -1)  # (Q, R*ALPHA)
 
     # ---- learning + accounting -------------------------------------------
-    # origin learns its final shortlist (every response it accepted)
-    state = rtable_insert(state, origins, sl)
-    # each queried peer learns the origins that queried it: group the
-    # (learner, origin) events by learner (segment ranks, capacity-bounded)
-    # so parallel lookups hitting the same responder all land
-    flat_peers = picked_seq.reshape(-1)
-    flat_origin = jnp.broadcast_to(origins[:, None], picked_seq.shape).reshape(-1)
-    state = _teach_learners(state, flat_peers, flat_origin, e_cap=learn_cap)
+    with jax.named_scope("learn"):
+        # origin learns its final shortlist (every response it accepted)
+        state = rtable_insert(state, origins, sl)
+        # each queried peer learns the origins that queried it: group the
+        # (learner, origin) events by learner (segment ranks,
+        # capacity-bounded) so parallel lookups hitting the same responder
+        # all land
+        flat_peers = picked_seq.reshape(-1)
+        flat_origin = jnp.broadcast_to(
+            origins[:, None], picked_seq.shape).reshape(-1)
+        state = _teach_learners(state, flat_peers, flat_origin,
+                                e_cap=learn_cap)
 
-    served = jnp.zeros((n,), jnp.int32).at[
-        jnp.where(flat_peers >= 0, flat_peers, n)
-    ].add(1, mode="drop")
-    state = state.replace(
-        queries_tx=state.queries_tx.at[origins].add(nq),
-        queries_rx=state.queries_rx + served,
-    )
+        served = jnp.zeros((n,), jnp.int32).at[
+            jnp.where(flat_peers >= 0, flat_peers, n)
+        ].add(1, mode="drop")
+        state = state.replace(
+            queries_tx=state.queries_tx.at[origins].add(nq),
+            queries_rx=state.queries_rx + served,
+        )
 
     result = LookupResult(
         closest=sl[:, :K_RESP], hops=hops, latency_ms=t_acc,

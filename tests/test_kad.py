@@ -2,6 +2,12 @@
 lookup correctness, and the role-program runtime (reference behavior:
 nim-test-node/kad-dht/{core,main,helpers}.nim)."""
 
+import json
+import os
+import re
+import sys
+from types import SimpleNamespace
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -9,6 +15,10 @@ import pytest
 
 from dst_libp2p_test_node_tpu.ops import kad
 from dst_libp2p_test_node_tpu.runtime.kad_runtime import KadConfig, KadSimulator
+
+CHECKOUT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if CHECKOUT not in sys.path:        # the benchmark's reference and readers
+    sys.path.insert(0, CHECKOUT)
 
 
 def _key_ints(keys: np.ndarray) -> list[int]:
@@ -43,6 +53,304 @@ def test_lex_argsort_matches_bigint_sort():
     ints = _key_ints(d)
     sorted_ints = [ints[i] for i in order]
     assert sorted_ints == sorted(ints)
+
+
+# ---- the oracle: the order as stable radix argsorts, least to most
+# significant word, and the closest-K selection and merge built on it (what
+# ops/kad.py ran before lex_sort; kept here only, as the reference the one
+# keyed sort is held to)
+
+
+def _radix_lex_argsort(d):
+    idx = jnp.argsort(d[..., -1], axis=-1, stable=True)
+    for w in range(kad.KEY_WORDS - 2, -1, -1):
+        key = jnp.take_along_axis(d[..., w], idx, axis=-1)
+        refine = jnp.argsort(key, axis=-1, stable=True)
+        idx = jnp.take_along_axis(idx, refine, axis=-1)
+    return idx
+
+
+def _radix_closest_from_table(table, keys, target_key, k_out,
+                              table_keys=None):
+    flat = table.reshape(-1)
+    order = _radix_lex_argsort(kad._dist(keys, flat, target_key))
+    return flat[order[:k_out]]
+
+
+def _radix_merge_shortlist(keys, sl, queried, pick, resp, targets, s):
+    q = sl.shape[0]
+    merged = jnp.concatenate([sl, resp.reshape(q, -1)], axis=-1)
+    mq = jnp.concatenate(
+        [queried | pick, jnp.zeros((q, merged.shape[1] - s), bool)], axis=-1)
+    mkey = merged * 2 + jnp.where(mq, 0, 1)
+    dorder = jnp.argsort(mkey, axis=-1, stable=True)
+    msort = jnp.take_along_axis(merged, dorder, axis=-1)
+    qsort = jnp.take_along_axis(mq, dorder, axis=-1)
+    dup = jnp.concatenate(
+        [jnp.zeros((q, 1), bool), msort[:, 1:] == msort[:, :-1]], axis=-1)
+    msort = jnp.where(dup | (msort < 0), -1, msort)
+    morder = _radix_lex_argsort(kad._dist(keys, msort, targets))[:, :s]
+    return (jnp.take_along_axis(msort, morder, axis=-1),
+            jnp.take_along_axis(qsort & ~dup, morder, axis=-1))
+
+
+def _tied_case(tied_words: int):
+    """40 peers whose distances to the target agree in their first
+    `tied_words` words (so a later word decides), two of them with one key
+    (a full tie: the earlier slot wins), in a table with empty slots."""
+    rng = np.random.default_rng(10 + tied_words)
+    target = rng.integers(0, 1 << 32, kad.KEY_WORDS, dtype=np.uint32)
+    d = rng.integers(0, 1 << 32, (40, kad.KEY_WORDS), dtype=np.uint32)
+    d[:, :tied_words] = d[0, :tied_words]
+    d[20:30, tied_words:tied_words + 1] = d[5, tied_words]   # and one deeper
+    keys = d ^ target
+    keys[17] = keys[3]
+    table = np.full((6, 16), -1, np.int32)
+    slots = rng.choice(96, 40, replace=False)
+    table.reshape(-1)[slots] = rng.permutation(40)
+    return keys, table, target
+
+
+def _plain_case(n_valid: int, shape=(6, 16), repeat: bool = False):
+    rng = np.random.default_rng(100 + n_valid)
+    keys = rng.integers(0, 1 << 32, (64, kad.KEY_WORDS), dtype=np.uint32)
+    target = rng.integers(0, 1 << 32, kad.KEY_WORDS, dtype=np.uint32)
+    table = np.full(shape, -1, np.int32)
+    ids = rng.choice(64, n_valid, replace=False)
+    if repeat:          # one id in several slots, as a directory may hold it
+        ids[1::3] = ids[0]
+    table.reshape(-1)[rng.choice(table.size, n_valid, replace=False)] = ids
+    return keys, table, target
+
+
+ORDER_CASES = {
+    "tie_in_first_word": lambda: _tied_case(1),
+    "tie_in_two_words": lambda: _tied_case(2),
+    "tie_in_three_words": lambda: _tied_case(3),
+    "duplicate_ids": lambda: _plain_case(30, repeat=True),
+    "all_empty": lambda: _plain_case(0),
+    "one_entry": lambda: _plain_case(1),
+    "fewer_than_k": lambda: _plain_case(9),
+    # ops/dht_adversary passes its (D,) sybil directory as a (1, D) view
+    "flat_directory": lambda: _plain_case(50, shape=(1, 50)),
+}
+
+
+@pytest.mark.parametrize("k_out", [kad.K_RESP, 32])
+@pytest.mark.parametrize("case", sorted(ORDER_CASES))
+def test_keyed_sort_is_the_radix_oracle_and_bigint_order(case, k_out):
+    """`lex_argsort` and the closest-K selection against the radix oracle
+    and against Python integers (`true_closest`'s arithmetic), entry for
+    entry; an empty slot is the farthest, ties keep slot order."""
+    keys, table, target = ORDER_CASES[case]()
+    flat = table.reshape(-1)
+    ints = _key_ints(keys)
+    far = (1 << kad.KEY_BITS) - 1
+    t_int = _key_ints(target[None, :])[0]
+    dist = [ints[e] ^ t_int if e >= 0 else far for e in flat]
+    by_int = sorted(range(flat.size), key=dist.__getitem__)   # stable
+
+    jkeys, jtable, jtarget = map(jnp.asarray, (keys, table, target))
+    d = kad._dist(jkeys, jnp.asarray(flat), jtarget)
+    assert _key_ints(np.asarray(d)) == dist
+    order = np.asarray(kad.lex_argsort(d))
+    assert order.tolist() == by_int
+    assert order.tolist() == np.asarray(_radix_lex_argsort(d)).tolist()
+    # batched, as the round and servicedisco.lookup call it
+    both = jnp.stack([d, d[::-1]])
+    assert (np.asarray(kad.lex_argsort(both))
+            == np.asarray(_radix_lex_argsort(both))).all()
+
+    got = np.asarray(kad._closest_from_table(jtable, jkeys, jtarget, k_out))
+    assert got.tolist() == flat[by_int][:k_out].tolist()
+    assert got.tolist() == np.asarray(_radix_closest_from_table(
+        jtable, jkeys, jtarget, k_out)).tolist()
+    # the slots' key words gathered beforehand, as find_node passes them
+    pulled = jkeys[jnp.clip(jnp.asarray(flat), 0)].T
+    assert got.tolist() == np.asarray(kad._closest_from_table(
+        jtable, jkeys, jtarget, k_out, table_keys=pulled)).tolist()
+    # what comes back is in distance order already: sorting it again is
+    # the identity (what find_node's round takes a shortlist's rank from)
+    again = kad.lex_argsort(kad._dist(jkeys, jnp.asarray(got), jtarget))
+    assert np.asarray(again).tolist() == list(range(got.size))
+
+
+def test_merged_shortlist_is_the_oracles_and_in_distance_order():
+    """`_merge_shortlist` against the oracle's, ids and queried flags, on
+    responses that repeat the shortlist's ids, each other's, and hold empty
+    slots; and what it returns is in distance order, which is why
+    find_node's round reads an entry's rank off its position."""
+    rng = np.random.default_rng(4)
+    n, q, s = 120, 50, 32
+    keys = jnp.asarray(rng.integers(0, 1 << 32, (n, kad.KEY_WORDS),
+                                    dtype=np.uint32))
+    targets = jnp.asarray(rng.integers(0, 1 << 32, (q, kad.KEY_WORDS),
+                                       dtype=np.uint32))
+    tables = np.full((q, 60), -1, np.int32)
+    for row in tables:
+        m = rng.integers(0, 40)
+        row[rng.choice(60, m, replace=False)] = rng.choice(n, m, replace=False)
+    sl = jax.vmap(lambda tb, t: kad._closest_from_table(tb, keys, t, s))(
+        jnp.asarray(tables), targets)
+    queried = jnp.asarray(rng.random((q, s)) < 0.4) & (sl >= 0)
+    pick = jnp.asarray(rng.random((q, s)) < 0.2) & (sl >= 0) & ~queried
+    resp = rng.integers(-1, n, (q, kad.ALPHA, kad.K_RESP)).astype(np.int32)
+    resp[:, 0, :4] = np.asarray(sl)[:, :4]          # ids the shortlist holds
+    resp[:, 1, :6] = resp[:, 2, :6]                 # and each other's
+    resp = jnp.asarray(resp)
+    got = kad._merge_shortlist(keys, sl, queried, pick, resp, targets, s)
+    want = _radix_merge_shortlist(keys, sl, queried, pick, resp, targets, s)
+    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
+    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
+    assert np.asarray(got[1]).any() and (np.asarray(got[0]) < 0).any()
+    for shortlist in (sl, got[0]):
+        again = kad.lex_argsort(kad._dist(keys, shortlist, targets))
+        assert (np.asarray(again) == np.arange(s)).all()
+
+
+@pytest.mark.parametrize("learn_cap", [kad.LEARN_CAP, None])
+def test_find_node_wave_is_the_oracle_programs_and_kad_plains(
+        learn_cap, monkeypatch):
+    """One wave at 200 peers on tables a first wave filled: the program
+    (one keyed sort, the wave's table keys gathered once) against the same
+    wave built on the radix oracle and un-hoisted, bit for bit, and against
+    benchmark/reference/kad_plain.py."""
+    from benchmark.reference import kad_plain
+
+    n, seed = 200, 11
+    state = kad.seed_bootstraps(kad.init_kad_state(n, seed=seed),
+                                jnp.asarray([0], jnp.int32))
+    stage = jnp.arange(n, dtype=jnp.int32) % 2
+    lat = jnp.asarray([[100.0, 130.0], [130.0, 40.0]], jnp.float32)
+    origins = jnp.arange(1, n, dtype=jnp.int32)
+    _, state = kad.find_node(state, origins, state.keys[origins], stage, lat,
+                             learn_cap=learn_cap)
+    targets = kad.random_targets(jax.random.PRNGKey(seed), n - 1)
+    res, after = kad.find_node(state, origins, targets, stage, lat,
+                               learn_cap=learn_cap)
+    assert int(np.asarray(res.hops).max()) > 0
+
+    monkeypatch.setattr(kad, "_closest_from_table", _radix_closest_from_table)
+    monkeypatch.setattr(kad, "_merge_shortlist", _radix_merge_shortlist)
+    o_res, o_after = jax.jit(
+        lambda st, o, t: kad._find_node_impl(
+            st, o, t, stage, lat, 6, 32, learn_cap=learn_cap)
+    )(state, origins, targets)
+    monkeypatch.undo()
+    for name in ("closest", "hops", "n_queries", "latency_ms", "queried"):
+        np.testing.assert_array_equal(
+            np.asarray(getattr(res, name)), np.asarray(getattr(o_res, name)),
+            err_msg=name)
+    np.testing.assert_array_equal(np.asarray(after.rtable),
+                                  np.asarray(o_after.rtable))
+
+    keys = [kad_plain.key_of(row) for row in np.asarray(state.keys)]
+    lookups, tables = kad_plain.wave(
+        kad_plain.tables_from_array(np.asarray(state.rtable)), keys,
+        np.asarray(origins), np.asarray(targets), np.asarray(stage),
+        np.asarray(lat, np.float64), learn_cap=learn_cap)
+    closest = np.full((n - 1, kad_plain.K_RESP), -1)
+    for i, found in enumerate(lookups):
+        closest[i, :len(found["closest"])] = found["closest"]
+    assert (closest == np.asarray(res.closest)).all()
+    assert [f["hops"] for f in lookups] == np.asarray(res.hops).tolist()
+    assert ([f["n_queries"] for f in lookups]
+            == np.asarray(res.n_queries).tolist())
+    np.testing.assert_allclose([f["latency_ms"] for f in lookups],
+                               np.asarray(res.latency_ms), atol=1e-3, rtol=0)
+    assert (kad_plain.tables_to_array(tables)
+            == np.asarray(after.rtable)).all()
+
+
+# ---- the scopes of jit_find_node and the four metrics that read them
+
+FIND_NODE_SCOPES = ["seed", "order", "response", "merge", "learn"]
+
+
+@pytest.fixture(scope="module")
+def find_node_scope_paths():
+    """The scope path (`op_name`, what a profile's op event carries) of
+    every instruction of `find_node` compiled as the tiny-regression cell
+    runs it (64 peers, one bootstrap, no learn cap)."""
+    n = 64
+    state = kad.seed_bootstraps(kad.init_kad_state(n, seed=1),
+                                jnp.asarray([0], jnp.int32))
+    origins = jnp.arange(1, n, dtype=jnp.int32)
+    text = kad.find_node.lower(
+        state, origins, state.keys[origins], jnp.zeros((n,), jnp.int32),
+        jnp.full((2, 2), 100.0, jnp.float32), learn_cap=None,
+    ).compile().as_text()
+    return re.findall(r'op_name="(jit\(find_node\)/[^"]*)"', text)
+
+
+def test_compiled_find_node_carries_the_scopes(find_node_scope_paths):
+    from benchmark.harness import program_profile
+
+    paths = find_node_scope_paths
+    under = {name: [p for p in paths if program_profile.follows(
+        p, [name], FIND_NODE_SCOPES)] for name in FIND_NODE_SCOPES}
+    assert all(under.values()), {k: len(v) for k, v in under.items()}
+    # the rounds' three keep their scope inside the scan's body, the
+    # responses through the vmap over the queried peers too
+    for name in ("order", "response", "merge"):
+        assert all("while/body" in p for p in under[name]), name
+    assert any(p.endswith("/sort") for p in under["response"])
+    assert not any("while/body" in p for p in under["seed"] + under["learn"])
+    # the hoisted key gather is the seed's
+    assert any(p.endswith("/gather") for p in under["seed"])
+    # outside the five: the round's RTT and counters, a few scalars
+    loose = len(paths) - sum(map(len, under.values()))
+    assert loose <= 0.1 * len(paths), loose
+
+
+@pytest.mark.parametrize("scope", ["seed", "response", "merge", "learn"])
+def test_kad_scope_metric_reads_a_traced_find_node(
+        scope, find_node_scope_paths, monkeypatch):
+    """benchmark/layer_metrics/kad.<scope>.device_s.json through the reader
+    it names, on a profile with one microsecond of device time for every
+    instruction of the compiled tiny-regression `find_node` (XLA:CPU's own
+    profile has no device plane to read): not None, and with `order` and the
+    unscoped rest the scopes add up to the module."""
+    from benchmark.harness import manifest, program_profile, trace
+
+    plane = trace.DEVICE_PLANE_PREFIX + "0"
+    ops = [{"plane": plane, "name": f"op.{i}", "start_ns": 1e3 * i,
+            "dur_ns": 1e3, "scope": p}
+           for i, p in enumerate(find_node_scope_paths)]
+    whole = 1e3 * len(ops)
+    profile = {"ops": ops, "host": [], "modules": [
+        {"plane": plane, "name": "jit_find_node(7)", "start_ns": 0.0,
+         "dur_ns": whole}]}
+    monkeypatch.setattr(program_profile, "load", lambda: profile)
+    ctx = SimpleNamespace(
+        trace_windows=[(0.0, whole)], recorder=None, experiments=[],
+        memory_stats=[], trace_rows=(
+            [{**r, "line": trace.MODULE_LINE} for r in profile["modules"]]
+            + [{**r, "line": trace.OP_LINE} for r in ops]))
+
+    def read(name):
+        with open(os.path.join(CHECKOUT, "benchmark", "layer_metrics",
+                               name + ".json")) as f:
+            spec = json.load(f)
+        assert spec["name"] == name
+        return spec, manifest.reader(spec["reader"])(ctx, **spec["params"])
+
+    spec, seconds = read(f"kad.{scope}.device_s")
+    assert seconds is not None and seconds > 0.0
+    assert spec["params"]["scopes"] == FIND_NODE_SCOPES
+    with open(os.path.join(CHECKOUT, "BENCHMARK.json")) as f:
+        entries = {m["name"]: m for m in json.load(f)["per_layer"]}
+    entry, whole_entry = entries[spec["name"]], entries["kad.find_node.device_s"]
+    assert (entry["layer"], entry["moves"], entry["workloads"]) == (
+        spec["layer"], "experiment_s", ["regression-10k.headline"])
+    assert entry["layer"] == whole_entry["layer"]
+    parts = program_profile.scope_seconds(
+        profile, ctx.trace_windows, "jit_find_node",
+        [[n] for n in FIND_NODE_SCOPES], FIND_NODE_SCOPES)
+    assert seconds == parts[FIND_NODE_SCOPES.index(scope)]
+    module = read("kad.find_node.device_s")[1]
+    assert module == pytest.approx(whole / 1e9)
+    assert sum(parts) <= module and sum(parts) >= 0.9 * module
 
 
 def test_bucket_slot_ranges():
