@@ -1156,18 +1156,55 @@ def cmd_serve(argv: list[str]) -> int:
 def cmd_kad(argv: list[str]) -> int:
     """Role-based kad-dht workload (kad-dht/main.nim:15-72): bootstrap
     anchors + RoleNormal warmup + RoleProbe lookup loop, batched."""
-    p = argparse.ArgumentParser(prog="kad")
+    p = argparse.ArgumentParser(
+        prog="kad",
+        description="The reference's kad-dht node, every role at once: the "
+        "bootstraps are seeded into every table, every RoleNormal peer runs "
+        "5 FIND_NODE(self) waves a second apart and 15 on random targets two "
+        "seconds apart, every RoleProbe peer then looks up a random target "
+        "every 5 s under a 30 s time-out.",
+        epilog="Environment, as the node reads it (kad-dht/env.nim): PEERS "
+        "(100), KAD_BOOTSTRAPS (3: peers 0.. are the anchors), KAD_PROBES "
+        "(10: the highest ids), DISCOVERY (kad-dht | extended), MUXER "
+        "(yamux), SEED (0), and KAD_LEARN_CAP (8: the origins a queried "
+        "peer learns of one wave; `all` for every requester, as KadDHT "
+        "adds them).")
     p.add_argument("-n", "--nodes", type=int, default=None,
                    help="defaults to PEERS env")
     p.add_argument("--bootstraps", type=int, default=None)
     p.add_argument("--probes", type=int, default=None)
     p.add_argument("--discovery", choices=["kad-dht", "extended"], default=None)
-    p.add_argument("--duration-s", type=float, default=60.0)
+    p.add_argument("--duration-s", type=float, default=60.0,
+                   help="length of the probe loop (a tick every 5 s)")
     p.add_argument("--seed", type=int, default=None)
     p.add_argument("--log", default=None, help="write node log lines here")
+    p.add_argument("--stats-json", default=None, metavar="PATH",
+                   help="write the experiment's numbers here: network_size, "
+                   "wall_s (boot, warm-up, probe loop and their reads, from "
+                   "the spans), spans, compile and process as `run "
+                   "--stats-json` names them, and \"kad\": lookups, "
+                   "warmup_waves, probe_ticks; hops_mean (rounds in which a "
+                   "shortlist still improved) and queries_per_lookup "
+                   "(FIND_NODE requests sent, at most 18) over all lookups; "
+                   "census_mean, census_min (entries a routing table holds "
+                   "at the end: a minimum near the bootstraps' count says "
+                   "some peer learned nothing); bucket_full_share (of the "
+                   "peers a wave offered to a table as new, the share whose "
+                   "bucket was full and which were dropped: it rises as the "
+                   "tables fill); probe_lookups, probe_success, "
+                   "probe_success_share (under the 30 s time-out); "
+                   "closest1_share (of the last random warm-up wave's and "
+                   "the probes' lookups, the share that returned first the "
+                   "peer a brute force over every key finds closest: "
+                   "lookups that converge read near 1); queries_tx / "
+                   "queries_rx summed over the peers (equal: every request "
+                   "is served), queries_per_bootstrap; lookup_latency_ms: a "
+                   "wave or tick its kind, p50 and p99, in order, a round "
+                   "costing its slowest query")
     a = p.parse_args(argv)
 
     from .runtime.kad_runtime import KadSimulator, config_from_env
+    from .runtime.profiling import process_summary, span, turn
 
     cfg = config_from_env()
     if a.nodes is not None:
@@ -1182,15 +1219,36 @@ def cmd_kad(argv: list[str]) -> int:
         cfg.seed = a.seed
     cfg.probe_duration_s = a.duration_s
     cfg.validate()
-    t0 = time.time()
-    sim = KadSimulator(cfg)
-    summary = sim.run()
-    wall = time.time() - t0
-    if a.log:
-        with open(a.log, "w") as f:
-            f.write("\n".join(sim.lines) + "\n")
-    print(summary.report())
-    print(f"[tpu backend] wall={wall:.2f}s lookups={len(sim.lookups)}")
+    with turn(seed=cfg.seed) as spans:
+        sim = KadSimulator(cfg)
+        summary = sim.run()
+        # the program's one clock, from the spans: build, phases, reads
+        wall = sum(spans.seconds(name) for name in (
+            "run/topology", "run/boot", "run/warmup", "run/probe",
+            "run/record"))
+        if a.log:
+            with span("run/write_log"), open(a.log, "w") as f:
+                f.write("\n".join(sim.lines) + "\n")
+        kad_stats = sim.stats(summary)
+        with span("run/report"):
+            print(summary.report())
+            print(f"[tpu backend] wall={wall:.2f}s "
+                  f"lookups={kad_stats['lookups']}")
+        if a.stats_json:
+            from .runtime.summarize import sanitize_nonfinite
+
+            with span("run/stats_json"), open(a.stats_json, "w") as f:
+                json.dump(
+                    sanitize_nonfinite({
+                        "network_size": cfg.network_size,
+                        "wall_s": wall,
+                        "spans": spans.totals(),
+                        "compile": spans.compile.as_dict(),
+                        **({"process": process_summary()}
+                           if spans.number == 1 else {}),
+                        "kad": kad_stats,
+                    }),
+                    f, indent=2, allow_nan=False)
     return 0
 
 

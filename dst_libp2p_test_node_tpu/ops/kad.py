@@ -163,8 +163,11 @@ def _segment_rank(sort_key: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
 
 
 def _insert_one(table: jnp.ndarray, keys: jnp.ndarray, owner: jnp.ndarray,
-                cands: jnp.ndarray) -> jnp.ndarray:
-    """Insert candidate peer ids into one owner's (B, K) table.
+                cands: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Insert candidate peer ids into one owner's (B, K) table; returns the
+    table and (offered, full): how many candidates were new to the table
+    (valid, each once, not held) and how many of those found their bucket
+    full.
 
     Kademlia bucket policy: keep existing entries (the reference's LRU
     preference without the ping-eviction probe), append new distinct entries
@@ -187,9 +190,20 @@ def _insert_one(table: jnp.ndarray, keys: jnp.ndarray, owner: jnp.ndarray,
     rank, _ = _segment_rank(jnp.where(keep, slot, b).astype(jnp.int32))
     pos = occupancy[slot] + rank
     ok = keep & (pos < k)
-    return table.at[
+    new = table.at[
         jnp.where(ok, slot, b), jnp.where(ok, pos, 0)
     ].set(jnp.where(ok, cands, -1).astype(table.dtype), mode="drop")
+    return new, jnp.stack([keep.sum(), (keep & ~ok).sum()])
+
+
+def _insert_rows(state: KadState, owners: jnp.ndarray, cands: jnp.ndarray
+                 ) -> tuple[KadState, jnp.ndarray]:
+    """`rtable_insert` and its (offered, full) summed over the owners."""
+    new_rows, counts = jax.vmap(_insert_one, in_axes=(0, None, 0, 0))(
+        state.rtable[owners], state.keys, owners, cands
+    )
+    return (state.replace(rtable=state.rtable.at[owners].set(new_rows)),
+            counts.sum(axis=0))
 
 
 @jax.jit
@@ -197,10 +211,7 @@ def rtable_insert(state: KadState, owners: jnp.ndarray, cands: jnp.ndarray
                   ) -> KadState:
     """Batch insert: owners (M,) each learn cands (M, E). Owner rows must be
     distinct within a batch (callers vmap over distinct lookup origins)."""
-    new_rows = jax.vmap(_insert_one, in_axes=(0, None, 0, 0))(
-        state.rtable[owners], state.keys, owners, cands
-    )
-    return state.replace(rtable=state.rtable.at[owners].set(new_rows))
+    return _insert_rows(state, owners, cands)[0]
 
 
 def _table_keys(state: KadState) -> jnp.ndarray:
@@ -230,9 +241,10 @@ def _closest_from_table(table: jnp.ndarray, keys: jnp.ndarray,
     return lex_sort(d, flat)[0][:k_out]
 
 
-def _teach_learners(state: KadState, flat_peers: jnp.ndarray,
-                    flat_origin: jnp.ndarray, extra_ok=None,
-                    e_cap: int | None = LEARN_CAP) -> KadState:
+def _teach_events(state: KadState, flat_peers: jnp.ndarray,
+                  flat_origin: jnp.ndarray, extra_ok=None,
+                  e_cap: int | None = LEARN_CAP
+                  ) -> tuple[KadState, jnp.ndarray]:
     """Every learner `flat_peers[e]` learns the candidate `flat_origin[e]`,
     in the order of the events and under `_insert_one`'s bucket policy (not
     itself, each candidate once, not one its bucket holds, appended while
@@ -275,7 +287,15 @@ def _teach_learners(state: KadState, flat_peers: jnp.ndarray,
     return state.replace(rtable=state.rtable.at[
         jnp.where(put, learner, n), jnp.where(put, slot, 0),
         jnp.where(put, pos, 0)
-    ].set(cand.astype(state.rtable.dtype), mode="drop"))
+    ].set(cand.astype(state.rtable.dtype), mode="drop")), jnp.stack(
+        [keep.sum(), (keep & ~put).sum()])
+
+
+def _teach_learners(state: KadState, flat_peers: jnp.ndarray,
+                    flat_origin: jnp.ndarray, extra_ok=None,
+                    e_cap: int | None = LEARN_CAP) -> KadState:
+    """`_teach_events` without its counts."""
+    return _teach_events(state, flat_peers, flat_origin, extra_ok, e_cap)[0]
 
 
 def _pick_alpha(sl: jnp.ndarray, rank: jnp.ndarray, cand: jnp.ndarray,
@@ -324,6 +344,11 @@ class LookupResult:
     latency_ms: jnp.ndarray  # (Q,) float32 wall time of the lookup
     queried: jnp.ndarray     # (Q, rounds*ALPHA) int32 query log (-1 padded)
     n_queries: jnp.ndarray   # (Q,) int32 total FIND_NODE requests
+    # (2,) int32: the candidates the wave offered to the tables (the final
+    # shortlists to the origins, the origins to the peers they queried:
+    # valid, each once, not held already) and how many found their bucket
+    # full
+    learn_counts: jnp.ndarray
 
 
 def _find_node_impl(
@@ -437,7 +462,7 @@ def _find_node_impl(
     # ---- learning + accounting -------------------------------------------
     with jax.named_scope("learn"):
         # origin learns its final shortlist (every response it accepted)
-        state = rtable_insert(state, origins, sl)
+        state, own_counts = _insert_rows(state, origins, sl)
         # each queried peer learns the origins that queried it: group the
         # (learner, origin) events by learner (segment ranks,
         # capacity-bounded) so parallel lookups hitting the same responder
@@ -445,8 +470,9 @@ def _find_node_impl(
         flat_peers = picked_seq.reshape(-1)
         flat_origin = jnp.broadcast_to(
             origins[:, None], picked_seq.shape).reshape(-1)
-        state = _teach_learners(state, flat_peers, flat_origin,
-                                e_cap=learn_cap)
+        state, taught_counts = _teach_events(state, flat_peers, flat_origin,
+                                             e_cap=learn_cap)
+        learn_counts = own_counts + taught_counts
 
         served = jnp.zeros((n,), jnp.int32).at[
             jnp.where(flat_peers >= 0, flat_peers, n)
@@ -459,6 +485,7 @@ def _find_node_impl(
     result = LookupResult(
         closest=sl[:, :K_RESP], hops=hops, latency_ms=t_acc,
         queried=picked_seq, n_queries=nq,
+        learn_counts=learn_counts,
     )
     return result, state
 
@@ -607,6 +634,21 @@ def random_targets(key: jnp.ndarray, q: int) -> jnp.ndarray:
     """Random lookup targets — getRandomPeerId (kad-dht/helpers.nim:10-12):
     uniform keys that (almost surely) match no live node."""
     return jax.random.bits(key, (q, KEY_WORDS), dtype=jnp.uint32)
+
+
+@jax.jit
+def closest_peer(keys: jnp.ndarray, targets: jnp.ndarray) -> jnp.ndarray:
+    """(Q,) int32: for each target the peer whose key is closest to it under
+    the XOR metric among ALL N keys, by brute force and no routing table:
+    the lexicographic minimum taken a word at a time, each a masked
+    min-reduction over (Q, N). Peers with one key tie; the lowest id wins.
+    What a lookup should return first (the kad-dht node's `closest1_share`)."""
+    live = jnp.ones((targets.shape[0], keys.shape[0]), bool)
+    for w in range(KEY_WORDS):
+        d = jnp.where(live, keys[None, :, w] ^ targets[:, None, w],
+                      jnp.uint32(0xFFFFFFFF))
+        live = live & (d == d.min(axis=1, keepdims=True))
+    return jnp.argmax(live, axis=1).astype(jnp.int32)
 
 
 def true_closest(keys: np.ndarray, target: np.ndarray, k: int = 1) -> np.ndarray:

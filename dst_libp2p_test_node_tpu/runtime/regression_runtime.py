@@ -42,6 +42,7 @@ from ..config.env import GossipSubParams, env_int, env_str
 from ..config.topology import Topology, TopoParams
 from ..ops import kad
 from ..ops.graph import ConnGraph, build_connection_graph
+from .kad_runtime import dispatch_waves, latency_percentiles, wave_numbers
 from .profiling import counters, span
 from .simulator import (ExperimentConfig, MessageRecord, Simulator,
                         graph_capacity)
@@ -239,28 +240,22 @@ class RegressionSimulator:
             self.kstate = kad.seed_bootstraps(self.kstate, self.bootstraps)
             self._log(f"kad-dht discovery active bootstraps={cfg.n_bootstrap}")
             origins = jnp.arange(cfg.n_bootstrap, n, dtype=jnp.int32)
-            # a queried peer learns EVERYONE who asked it (learn_cap None),
-            # as KadDHT adds every requester. ops/kad's default of 8 a wave
-            # leaves, at 10,000 peers, 240 peers that anybody's table
-            # holds: the capacity then turns 95 % of the dials away and
-            # 70 % of the network has no connection
-            key = jax.random.PRNGKey(cfg.seed ^ 0x4E62)
-            waves = []
-            for i in range(cfg.discovery_rounds):
-                if i == 0:  # forceRefresh bootstrap round: FIND_NODE(self)
-                    kind, targets = "bootstrap", self.kstate.keys[origins]
-                else:
-                    kind = "random"
-                    key, k = jax.random.split(key)
-                    targets = kad.random_targets(k, origins.shape[0])
-                with span("discover/wave", kind=kind):
-                    res, self.kstate = kad.find_node(
-                        self.kstate, origins, targets, self._stage,
-                        self._lat, learn_cap=None)
-                waves.append((res.hops, res.n_queries, res.latency_ms))
+            # the forceRefresh bootstrap round is FIND_NODE(self), the
+            # warm-up waves look up random targets. A queried peer learns
+            # EVERYONE who asked it (learn_cap None), as KadDHT adds every
+            # requester. ops/kad's default of 8 a wave leaves, at 10,000
+            # peers, 240 peers that anybody's table holds: the capacity
+            # then turns 95 % of the dials away and 70 % of the network has
+            # no connection
+            self.kstate, _, waves = dispatch_waves(
+                self.kstate, origins,
+                (["bootstrap"] + ["random"] * cfg.discovery_rounds)[
+                    :cfg.discovery_rounds],
+                jax.random.PRNGKey(cfg.seed ^ 0x4E62), self._stage,
+                self._lat, learn_cap=None, wave_span="discover/wave")
             # one device->host read for every counter of the discovery
             waves, census, tx, rx = jax.device_get(
-                (waves, kad.rtable_census(self.kstate),
+                (wave_numbers(waves), kad.rtable_census(self.kstate),
                  self.kstate.queries_tx.sum(), self.kstate.queries_rx.sum()))
         self.kad_stats = _kad_stats(waves, float(census.mean()), int(tx),
                                     int(rx))
@@ -368,7 +363,7 @@ class RegressionSimulator:
 
 
 def _kad_stats(waves: list, census_mean: float, tx: int, rx: int) -> dict:
-    """`--stats-json` "kad" from the waves' (hops, n_queries, latency_ms)."""
+    """`--stats-json` "kad" from the read of `wave_numbers`."""
     hops = np.concatenate([w[0] for w in waves])
     queries = np.concatenate([w[1] for w in waves])
     return {
@@ -379,9 +374,7 @@ def _kad_stats(waves: list, census_mean: float, tx: int, rx: int) -> dict:
         "rtable_census_mean": census_mean,
         "queries_tx": tx,
         "queries_rx": rx,
-        "lookup_latency_ms": [
-            {"p50": float(np.percentile(w[2], 50)),
-             "p99": float(np.percentile(w[2], 99))} for w in waves],
+        "lookup_latency_ms": [latency_percentiles(w[2]) for w in waves],
     }
 
 
