@@ -15,6 +15,18 @@ mesh), a single row-gather pull. The rebalance work runs under lax.cond so
 a stable mesh skips it entirely. Dead neighbors (churn) simply fall out of
 the validity mask and are replaced on the next rebalance — the
 elastic-recovery analog of the reference's dial-retry loops (SURVEY.md §5).
+
+Which slots a row grafts or prunes is a rank of its slots under a random
+priority. The selection is by rows (`_select_rows`): the rows that can
+select are the rows that then send, a few tens of 100,000 after a scan's
+first step, so where the delivery goes from the few rows that send the rank
+is taken in those rows alone, by counting, and sorts nothing; a step in
+which every row selects, and every shape under ops/pull's static bound,
+keeps the double argsort of every row (`_ranks`). The draws behind the
+priorities are NOT by rows: `jax.random.uniform(key, (N, C))` gives a slot
+its value by its position in the whole array, and the key schedule and every
+bit of the draw are part of the result, so both draws stay whole whichever
+rows read them.
 """
 
 from __future__ import annotations
@@ -25,7 +37,8 @@ import jax
 import jax.numpy as jnp
 
 from .pull import (neighbor_pull_bool, neighbor_update_bool,
-                   reciprocal_send_bool, sparse_route)
+                   reciprocal_send_bool, rows_route, sending_rows,
+                   sparse_route)
 from .state import (PX_POOL_WIDTH, SimParams, SimState, repair_inert,
                     restore_repair, strip_repair)
 
@@ -33,8 +46,71 @@ BIG = jnp.float32(1e30)
 
 
 def _ranks(priority: jnp.ndarray) -> jnp.ndarray:
-    """Per-row rank of each slot under ascending priority (double argsort)."""
+    """Per-row rank of each slot under ascending priority (double argsort):
+    two sorts of every row, which is what a selection over ALL the rows
+    costs (`_select_rows` says where the rows are few instead)."""
     return jnp.argsort(jnp.argsort(priority, axis=-1), axis=-1)
+
+
+def _ranks_counted(priority: jnp.ndarray) -> jnp.ndarray:
+    """`_ranks`, bit for bit, without a sort: jax's argsort is stable, so a
+    slot's rank is the number of slots of its row that sort before it,
+    rank[k, i] = #{j : p[k, j] < p[k, i] or (p[k, j] == p[k, i] and j < i)}.
+    C compares a slot, so for a few rows only (priorities are never NaN)."""
+    mine, other = priority[..., :, None], priority[..., None, :]
+    earlier = jnp.tri(priority.shape[-1], k=-1, dtype=bool)   # [i, j]: j < i
+    before = (other < mine) | ((other == mine) & earlier)
+    return before.sum(axis=-1, dtype=jnp.int32)
+
+
+def _select_rows(select, rows, operands):
+    """`select(_ranks, *operands)`: an (N, C) bool selection of slots, of a
+    `select(rank, *operands)` that works row by row on operands of N rows
+    ((N, C) or (N,)) and is all False outside the rows marked in `rows`
+    (N,). GRAFT and PRUNE both are: after its first step a scan selects in a
+    few tens of rows of 100,000, and every other row would be sorted only to
+    compare its rank with 0. So where the shape takes the sparse route
+    (ops/pull.sparse_route: the caller passes `rows`; None keeps the one
+    program a small, vmapped or sharded step had) a `lax.switch` on the
+    marked rows' count, the count against the K that the delivery of the
+    same selection switches on (ops/pull.reciprocal_send_bool), chooses:
+    `none`: the all-False array, no rank at all (dead peers need D and have
+    no eligible slot); `few` (at most K): the K rows of every operand
+    gathered, the same `select` on (K, C) with the rank counted
+    (`_ranks_counted`: no sort), its rows scattered into an all-False
+    (N, C); `all` (step 0 from an empty mesh): `_ranks` over every row.
+    What `select` reads must be built whole before the call: the draws are
+    (N, C) under one key whichever rows read them, and float arithmetic
+    stays in the program every route shares."""
+    def all_rows(ops):
+        with jax.named_scope("all"):
+            return select(_ranks, *ops)
+
+    def none(ops):
+        with jax.named_scope("none"):
+            return jnp.zeros_like(ops[0], dtype=bool)
+
+    def few(ops):
+        with jax.named_scope("few"):
+            n = rows.shape[0]
+            senders = sending_rows(rows)
+            chosen = select(_ranks_counted, *(
+                x.at[senders].get(mode="clip") for x in ops))
+            # rows past the count go past the end, each to an index of its
+            # own, and are dropped (as ops/pull._deliver sends them)
+            k = senders.shape[0]
+            at = jnp.where(senders < n, senders,
+                           n + jnp.arange(k, dtype=jnp.int32))
+            return jnp.zeros_like(ops[0], dtype=bool).at[at].set(
+                chosen, mode="drop", unique_indices=True,
+                indices_are_sorted=True)
+
+    with jax.named_scope("rank"):
+        if rows is None:
+            return all_rows(operands)
+        return jax.lax.switch(
+            rows_route(rows.sum(dtype=jnp.int32)), [none, few, all_rows],
+            operands)
 
 
 def _apply_decay(arr: jnp.ndarray, scale, params: SimParams) -> jnp.ndarray:
@@ -163,6 +239,9 @@ def heartbeat_step(
         raise ValueError("deg_in requires valid_pre, no edge_ok, and churn "
                          "off (run_heartbeats' churn-free scan protocol)")
     n, c = conns.shape
+    # whether GRAFT and PRUNE select by rows (_select_rows), as their
+    # deliveries do: the trace-time half of ops/pull's sparse dispatch
+    routed = sparse_route(conns.shape, batch_factor)
     with jax.named_scope("state"):
         key, k_graft, k_keep, k_churn_d, k_churn_u = jax.random.split(
             state.key, 5)
@@ -256,9 +335,9 @@ def heartbeat_step(
         return _scores if _scores is not None else _score_now()
 
     # -- GRAFT: |mesh| < D_low -> add random eligible peers up to D ----------
-    # The whole selection (uniform draw + double argsort + reciprocal pull)
-    # runs under a cond: at steady state every row sits in [D_low, D_high]
-    # and the step skips straight through. Key consumption stays identical
+    # The whole selection (uniform draw + rank + reciprocal delivery) runs
+    # under a cond: at steady state every row sits in [D_low, D_high] and
+    # the step skips straight through. Key consumption stays identical
     # either way (k_graft was split above).
     with jax.named_scope("graft"):
         need = jnp.where(deg < params.d_low, params.d - deg, 0)
@@ -271,7 +350,14 @@ def heartbeat_step(
         eligible = (valid & ~mesh & (state.backoff_until <= t)
                     & (get_scores() >= 0.0))
         g_prio = jnp.where(eligible, jax.random.uniform(k_graft, (n, c)), BIG)
-        grafted = (_ranks(g_prio) < need[:, None]) & eligible
+
+        def select(rank, g_prio, need, eligible):
+            return (rank(g_prio) < need[:, None]) & eligible
+
+        # a row grafts iff it needs a member and has an eligible slot
+        grafted = _select_rows(
+            select, (need > 0) & eligible.any(axis=-1) if routed else None,
+            (g_prio, need, eligible))
         # GRAFT control msg: counterpart adds us to its mesh (handleGraft
         # accepts unless backed off; overflow is corrected at its own next
         # heartbeat). The reciprocal view IS the receive side — both
@@ -293,8 +379,9 @@ def heartbeat_step(
         )
 
     # -- PRUNE: |mesh| > D_high -> keep D (D_score best, >= D_out outbound) --
-    # The whole selection (4 rank passes) plus the reciprocal pull runs under
-    # a cond: at steady state no row exceeds D_high and the step skips it.
+    # The whole selection (three ranks) plus the reciprocal delivery runs
+    # under a cond: at steady state no row exceeds D_high and the step skips
+    # it.
     with jax.named_scope("prune"):
         over = deg2 > params.d_high
 
@@ -303,18 +390,25 @@ def heartbeat_step(
         scores = get_scores()
         # rank by descending score (random tiebreak) among mesh members
         s_prio = jnp.where(mesh, -scores + 1e-3 * rand_keep, BIG)
-        top_score = (_ranks(s_prio) < params.d_score) & mesh
-        # at least D_out outbound among the kept set
-        out_in_top = (top_score & out_mask).sum(axis=-1)
-        need_out = jnp.clip(params.d_out - out_in_top, 0, params.d)
-        o_prio = jnp.where(mesh & out_mask & ~top_score, rand_keep, BIG)
-        keep_out = (_ranks(o_prio) < need_out[:, None]) & mesh & out_mask & ~top_score
-        # random fill to exactly D
-        base = top_score | keep_out
-        need_fill = jnp.clip(params.d - base.sum(axis=-1), 0, params.d)
-        f_prio = jnp.where(mesh & ~base, rand_keep, BIG)
-        keep = base | ((_ranks(f_prio) < need_fill[:, None]) & mesh & ~base)
-        pruned = mesh & ~keep & over[:, None]
+
+        def select(rank, s_prio, mesh, rand_keep, out_mask, over):
+            top_score = (rank(s_prio) < params.d_score) & mesh
+            # at least D_out outbound among the kept set
+            out_in_top = (top_score & out_mask).sum(axis=-1)
+            need_out = jnp.clip(params.d_out - out_in_top, 0, params.d)
+            o_prio = jnp.where(mesh & out_mask & ~top_score, rand_keep, BIG)
+            keep_out = ((rank(o_prio) < need_out[:, None])
+                        & mesh & out_mask & ~top_score)
+            # random fill to exactly D
+            base = top_score | keep_out
+            need_fill = jnp.clip(params.d - base.sum(axis=-1), 0, params.d)
+            f_prio = jnp.where(mesh & ~base, rand_keep, BIG)
+            keep = base | ((rank(f_prio) < need_fill[:, None]) & mesh & ~base)
+            return mesh & ~keep & over[:, None]
+
+        pruned = _select_rows(
+            select, over if routed else None,
+            (s_prio, mesh, rand_keep, out_mask, over))
         mesh = mesh & ~pruned
         # PRUNE control msg: counterpart drops us; backoff on both sides
         pruned_by_peer, tally = _reciprocal_view(

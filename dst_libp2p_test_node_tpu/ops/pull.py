@@ -289,10 +289,18 @@ def sparse_route(conns_shape, batch_factor: int = 1) -> bool:
             >= _SPARSE_MIN_DENSE_BYTES)
 
 
-def sending_rows(row_mask: jnp.ndarray, k: int) -> jnp.ndarray:
-    """The indices of the first k True entries of an (N,) mask, ascending,
-    N (one past the end) beyond their count: the k-th sender is the first
-    row whose running count reaches k."""
+def rows_route(count) -> jnp.ndarray:
+    """The run-time half of the sparse dispatch, as a `lax.switch` index
+    over (none, few, all) of the rows a step marks: 0 where `count` is 0, 1
+    up to `_SPARSE_ROWS`, 2 beyond."""
+    return (count > 0).astype(jnp.int32) + (count > _SPARSE_ROWS)
+
+
+def sending_rows(row_mask: jnp.ndarray, k: int | None = None) -> jnp.ndarray:
+    """The indices of the first k (`_SPARSE_ROWS` where not given) True
+    entries of an (N,) mask, ascending, N (one past the end) beyond their
+    count: the k-th sender is the first row whose running count reaches k."""
+    k = _SPARSE_ROWS if k is None else k
     running = jnp.cumsum(row_mask.astype(jnp.int32))
     return jnp.searchsorted(
         running, jnp.arange(1, k + 1, dtype=jnp.int32), side="left",
@@ -348,7 +356,7 @@ def reciprocal_send_bool(
 
     def sparse(m):
         with jax.named_scope("sparse"):
-            senders = sending_rows(rows, _SPARSE_ROWS)
+            senders = sending_rows(rows)
             marks = m.at[senders].get(mode="fill", fill_value=False)
             return _deliver(jnp.zeros_like(m), senders, marks, True,
                             conns, rev)
@@ -359,8 +367,7 @@ def reciprocal_send_bool(
 
     if routed:
         out = jax.lax.switch(
-            (count > 0).astype(jnp.int32) + (count > _SPARSE_ROWS),
-            [none, sparse, dense], edge_mask)
+            rows_route(count), [none, sparse, dense], edge_mask)
     else:
         out = dense(edge_mask)
     return out, _tally(count, routed)
@@ -387,7 +394,7 @@ def neighbor_update_bool(
 
     def sparse(nbr):
         with jax.named_scope("sparse"):
-            senders = sending_rows(changed, _SPARSE_ROWS)
+            senders = sending_rows(changed)
             values = per_peer.at[senders].get(mode="fill", fill_value=False)
             return _deliver(
                 nbr, senders, jnp.ones((_SPARSE_ROWS, 1), dtype=bool),

@@ -343,3 +343,32 @@ def test_lowered_scan_carries_the_scopes(churn, sparse):
     assert ways("graft") == both and ways("prune") == both
     assert ways("validity") == (both if churn else set())
     assert any("sparse/scatter" in n for n in step) == sparse
+    # how the selection ranked is a sub-scope too (`rank`, inside the
+    # stage's cond): past the static bound a switch over none / few / all
+    # of the rows that select; at a small shape `all` alone and no switch,
+    # the parent's program
+    for stage in ("graft", "prune"):
+        rank = [n.split("/rank/", 1)[1] for n in step
+                if n.startswith(stage + "/cond/") and "/rank/" in n]
+        assert {r.split("/")[0] for r in rank if "/" in r} == (
+            {"cond"} if sparse else {"all"})
+        assert {r.split("/")[2] for r in rank if r.startswith("cond/")} == (
+            {"none", "few", "all"} if sparse else set())
+        assert any(r.startswith("cond/branch_1_fun/few/scatter")
+                   for r in rank) == sparse
+        # every sort is `all`'s: `few` counts its ranks
+        assert all("/all/" in "/" + r for r in rank if "argsort" in r)
+        assert any("argsort" in r for r in rank)
+
+
+@pytest.mark.parametrize("sparse", [False, True])
+@pytest.mark.parametrize("churn", [0.0, 0.01])
+def test_scan_holds_the_parents_sorts(churn, sparse):
+    """`_ranks`' two argsorts lower to one function each (float32 priorities,
+    int32 orders), called eight times a step, before PR 46 and since: the
+    selection by rows ranks its few rows by counting, so the routed scan
+    gives XLA:TPU, which is slow to compile a sort, no sort it had not."""
+    with pull_route.forced(0) if sparse else contextlib.nullcontext():
+        text = _lowered(churn)["_run_heartbeats"]
+    assert text.count("stablehlo.sort") == 2
+    assert len(re.findall(r"call @argsort", text)) == 8

@@ -1,3 +1,4 @@
+import contextlib
 import functools
 
 import flax.serialization as ser
@@ -7,9 +8,11 @@ import numpy as np
 import pytest
 
 import pull_route
+import dst_libp2p_test_node_tpu.ops.heartbeat as heartbeat
 from dst_libp2p_test_node_tpu.ops.graph import build_connection_graph
 from dst_libp2p_test_node_tpu.ops.heartbeat import (
-    PULL_COUNTS, PULL_STAGES, heartbeat_step, run_heartbeats)
+    BIG, PULL_COUNTS, PULL_STAGES, _ranks, _ranks_counted, heartbeat_step,
+    run_heartbeats)
 from dst_libp2p_test_node_tpu.ops.state import SimParams, init_state, graph_arrays
 
 
@@ -177,23 +180,69 @@ def test_prune_keeps_high_score_members():
 # ------------------------------------------ sparse reciprocity in the scan --
 
 STEPS = 12
+K = 8       # `_SPARSE_ROWS` for these scans: 300 peers cross it both ways
 
 
-@functools.lru_cache(maxsize=None)
-def _both_routes(churn, repair):
-    """Two scans of STEPS heartbeats, from an empty mesh (step 0: every row
-    sends) and from the warmed one, with the sparse route forced on at 8
-    rows and forced off: {route: {start: (state, pulls)}} on the host."""
+def _staged(start, warm, g, params):
+    """A start made from the warmed state, the same bits on either route.
+    `dead`: three peers are down and nothing else is wrong, so that every
+    step some row needs D members (the dead ones: the graft cond fires) and
+    after the first step none can graft. `crossing`: 25 rows lose their whole
+    mesh, both ways, and wait behind backoffs that end at staged steps: 2 may
+    graft at once, 20 from step 4, 3 from step 8, and until then they need
+    members and have no eligible slot. The rows are picked so that none of
+    their members falls under D_low, so nobody else grafts with them."""
+    if start == "dead":
+        alive = np.asarray(warm.alive).copy()
+        alive[[7, 99, 200]] = False
+        return warm.replace(alive=jnp.asarray(alive))
+    mesh = np.asarray(warm.mesh_mask).copy()
+    backoff = np.asarray(warm.backoff_until).copy()
+    deg, picked = mesh.sum(axis=-1), []
+    for p in range(params.n):
+        members = g.conns[p, mesh[p]]
+        if len(members) and (deg[members] > params.d_low).all():
+            picked.append(p)
+            deg[members] -= 1
+            mesh[members, g.rev[p, mesh[p]]] = False
+            mesh[p] = False
+            deg[p] = 0
+        if len(picked) == 25:
+            break
+    assert len(picked) == 25
+    hb_ms = params.heartbeat_ms
+    for rows, steps in ((picked[:2], 0.0), (picked[2:22], 3.5),
+                        (picked[22:], 7.5)):
+        backoff[rows] = float(warm.t_ms) + steps * hb_ms if steps else 0.0
+    return warm.replace(mesh_mask=jnp.asarray(mesh),
+                        backoff_until=jnp.asarray(backoff))
+
+
+STARTS = ["empty", "warmed", "crossing", "dead"]
+
+
+def _network(churn, repair=False):
+    """(graph, params, state, the graph's arrays, the spared mask) of the
+    300 peers these scans run on."""
     over = dict(churn_down_per_hb=churn, churn_up_per_hb=churn / 2)
     if repair:
         over.update(slow_weight=-10.0, slow_decay=0.9, evict=True, px=True,
                     eviction_threshold=-50.0)
+    g, params, state, a = make(n=300, connect_to=10, seed=5, **over)
+    spared = jnp.zeros(300, bool).at[4].set(True) if churn else None
+    return g, params, state, (a["conns"], a["rev"], a["out_mask"]), spared
+
+
+@functools.lru_cache(maxsize=None)
+def _both_routes(churn, repair):
+    """Four scans of STEPS heartbeats, from an empty mesh (step 0: every row
+    sends), from the warmed one and from the two starts `_staged` makes of
+    it, with the sparse route forced on at K rows and forced off:
+    {route: {start: (state, pulls)}} on the host."""
     out = {}
     for route, min_dense_bytes in (("sparse", 0), ("dense", 1 << 62)):
-        with pull_route.forced(min_dense_bytes, rows=8):
-            g, params, state, a = make(n=300, connect_to=10, seed=5, **over)
-            spared = jnp.zeros(300, bool).at[4].set(True) if churn else None
-            graph = a["conns"], a["rev"], a["out_mask"]
+        with pull_route.forced(min_dense_bytes, rows=K):
+            g, params, state, graph, spared = _network(churn, repair)
             cold = run_heartbeats(state, *graph, params, STEPS,
                                   spared=spared, with_pulls=True)
             warm = cold[0]
@@ -205,14 +254,20 @@ def _both_routes(churn, repair):
                 warm = warm.replace(slow_penalty=jnp.asarray(bad))
             warm = run_heartbeats(warm, *graph, params, STEPS,
                                   spared=spared, with_pulls=True)
-            out[route] = jax.device_get({"empty": cold, "warmed": warm})
+            staged = {
+                start: run_heartbeats(
+                    _staged(start, warm[0], g, params), *graph, params,
+                    STEPS, spared=spared, with_pulls=True)
+                for start in ("crossing", "dead")}
+            out[route] = jax.device_get(
+                {"empty": cold, "warmed": warm, **staged})
     return out
 
 
 SCANS = [(0.0, False), (0.01, False), (0.0001, False), (0.01, True)]
 
 
-@pytest.mark.parametrize("start", ["empty", "warmed"])
+@pytest.mark.parametrize("start", STARTS)
 @pytest.mark.parametrize("churn,repair", SCANS)
 def test_sparse_scan_is_the_dense_scan_leaf_for_leaf(churn, repair, start):
     runs = _both_routes(churn, repair)
@@ -257,3 +312,136 @@ def test_pull_counters_sum_to_the_steps(churn, repair, start):
         assert sparse["graft"][n_sparse] + sparse["prune"][n_sparse] >= 1
     elif churn == 0.01:
         assert sparse["graft"][n_sparse] >= 1 and sparse["graft"][rows] <= 300
+
+
+# ------------------------------------- GRAFT and PRUNE selection by rows --
+
+
+def _priorities(case, c=40, rows=64):
+    """(rows, c) float32 priorities as the step builds them: draws in
+    [0, 1) where a slot takes part, BIG elsewhere."""
+    rng = np.random.default_rng(3)
+    p = rng.random((rows, c), dtype=np.float32)
+    if case == "ties":
+        # equal draws, and the same draw on many slots of a row
+        p = np.round(p * 4) / 4
+    elif case == "all_big":
+        p[:] = BIG
+    elif case == "masked":
+        p[rng.random((rows, c)) < 0.6] = BIG
+        p[::7] = BIG                    # whole rows without a slot, too
+    elif case == "scores":
+        # PRUNE's first pass: negative scores with a small random tiebreak
+        p = (-np.round(p * 3) + 1e-3 * rng.random((rows, c))).astype(
+            np.float32)
+        p[rng.random((rows, c)) < 0.3] = BIG
+    return jnp.asarray(p)
+
+
+@pytest.mark.parametrize("case", ["draws", "ties", "all_big", "masked",
+                                  "scores"])
+def test_counted_rank_is_the_double_argsort(case):
+    prio = _priorities(case)
+    want, got = np.asarray(_ranks(prio)), np.asarray(_ranks_counted(prio))
+    assert got.dtype == want.dtype
+    np.testing.assert_array_equal(got, want)
+    # a permutation of the slots in every row, whatever ties
+    assert (np.sort(got, axis=-1) == np.arange(prio.shape[-1])).all()
+    # and so the same selection for every need a row can have, 0..C
+    for need in range(prio.shape[-1] + 1):
+        np.testing.assert_array_equal(got < need, want < need)
+
+
+@contextlib.contextmanager
+def _selections_logged():
+    """Every firing of `_select_rows` on the sparse route, in order of the
+    steps: (operands it read, its `rows`, the rows its selection marked).
+    GRAFT's reads 3 operands, PRUNE's 5."""
+    log, inner = [], heartbeat._select_rows
+
+    def logged(select, rows, operands):
+        selection = inner(select, rows, operands)
+        if rows is not None:
+            jax.debug.callback(
+                lambda r, s: log.append(
+                    (len(operands), np.asarray(r), np.asarray(s))),
+                rows, selection.any(axis=-1), ordered=True)
+        return selection
+
+    heartbeat._select_rows = logged
+    try:
+        yield log
+    finally:
+        heartbeat._select_rows = inner
+
+
+@functools.lru_cache(maxsize=None)
+def _logged_route(churn):
+    """`_both_routes`' four scans on the sparse route, with what every
+    selection saw: {start: (firings, pulls)}."""
+    out = {}
+    with _selections_logged() as log, pull_route.forced(0, rows=K):
+        g, params, state, graph, spared = _network(churn)
+
+        def scan(start, state):
+            del log[:]
+            state, pulls = run_heartbeats(state, *graph, params, STEPS,
+                                          spared=spared, with_pulls=True)
+            out[start] = list(log), np.asarray(pulls)   # waits for the scan
+            return state
+
+        warm = scan("warmed", scan("empty", state))
+        for start in ("crossing", "dead"):
+            scan(start, _staged(start, warm, g, params))
+    return out
+
+
+def _counts(firings, operands):
+    """The rows that could select at each firing of one stage, in order."""
+    return [int(rows.sum()) for n, rows, _ in firings if n == operands]
+
+
+@pytest.mark.parametrize("start", STARTS)
+@pytest.mark.parametrize("churn", [0.0, 0.01])
+def test_pull_counters_count_the_ranks_routes_too(churn, start):
+    """A row selects iff it sends: at every firing the rows the rank
+    switches on are the rows of its selection, which the delivery counts.
+    So the `graft` and `prune` rows of `pulls` say how the rank went."""
+    firings, pulls = _logged_route(churn)[start]
+    # (a warmed mesh nobody leaves is stable: neither cond fires)
+    assert firings or (start == "warmed" and not churn)
+    for _, rows, selected in firings:
+        np.testing.assert_array_equal(rows, selected)
+    for stage, operands in (("graft", 3), ("prune", 5)):
+        counts = _counts(firings, operands)
+        assert pulls[PULL_STAGES.index(stage)].tolist() == [
+            sum(c <= K for c in counts), sum(c > K for c in counts),
+            max(counts, default=0)]
+
+
+def _routes(firings, operands):
+    return ["none" if c == 0 else "few" if c <= K else "all"
+            for c in _counts(firings, operands)]
+
+
+def test_selecting_rows_cross_k_within_a_scan():
+    routes = _routes(_logged_route(0.0)["crossing"][0], 3)
+    # 2 rows, then nobody (20 rows wait behind a backoff), then those 20,
+    # and at last the 3; with nobody left in need the cond stops firing
+    assert routes[:5] == ["few", "none", "none", "none", "all"]
+    assert "few" in routes[5:] and len(routes) < STEPS
+    # the 20 graft 120 members at once: some of those now prune
+    assert "few" in _routes(_logged_route(0.0)["crossing"][0], 5)
+
+
+@pytest.mark.parametrize("churn", [0.0, 0.01])
+def test_dead_rows_need_members_and_rank_nothing(churn):
+    firings, _ = _logged_route(churn)["dead"]
+    routes = _routes(firings, 3)
+    # a dead peer needs D members every step, so the cond fires every step
+    assert len(routes) == STEPS
+    if not churn:
+        # once the dead peers' members have mended, no row can graft
+        assert routes[0] == "few" and set(routes[1:]) == {"none"}
+    else:
+        assert "none" in routes
