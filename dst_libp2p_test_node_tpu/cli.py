@@ -1191,7 +1191,11 @@ def cmd_kad(argv: list[str]) -> int:
                    "some peer learned nothing); bucket_full_share (of the "
                    "peers a wave offered to a table as new, the share whose "
                    "bucket was full and which were dropped: it rises as the "
-                   "tables fill); probe_lookups, probe_success, "
+                   "tables fill); packed_share (of the FIND_NODE calls, the "
+                   "share in which every routing table fitted the width the "
+                   "responses sort, ops/kad.packed_width: under 1 the tables "
+                   "hold more than uniform keys allow and a response sorts "
+                   "twice); probe_lookups, probe_success, "
                    "probe_success_share (under the 30 s time-out); "
                    "closest1_share (of the last random warm-up wave's and "
                    "the probes' lookups, the share that returned first the "
@@ -1311,7 +1315,10 @@ def cmd_regression(argv: list[str]) -> int:
                    "(lookups, hops_mean: rounds in which a shortlist still "
                    "improved; queries_per_lookup: FIND_NODE requests sent, "
                    "at most 18; rtable_census_mean: entries a routing "
-                   "table holds after the last wave), queries_tx / "
+                   "table holds after the last wave; packed_share: of the "
+                   "waves, the share in which every routing table fitted "
+                   "the width the responses sort, ops/kad.packed_width), "
+                   "queries_tx / "
                    "queries_rx summed over the peers (equal: every request "
                    "is served), lookup_latency_ms: a wave its p50 and p99, "
                    "a round costing its slowest query; a p99 near 6 rounds "

@@ -22,18 +22,21 @@ TPU-native design (not a port):
 
 Everything is a masked fixed-shape op: shortlists are padded to S entries,
 bucket inserts route dropped entries out of bounds (`mode="drop"`), and a
-big-integer XOR comparison is one stable `lax.sort` whose keys are the W
-distance words and whose payload is what the caller wants in that order
-(ids, queried flags, or an iota for the permutation) — no Python bigints, no
-dynamic shapes, so the whole lookup batch jits and shards over the peer axis
-like the GossipSub engine. A wave reads `rtable` and `keys` only (what it
-teaches is written after its last round), so `find_node` gathers the key
-words of every table slot once (`_table_keys`) and a response is the row
-pulls of a peer's ids and key words, an XOR and that sort.
+big-integer XOR comparison is one `lax.sort` whose keys are the W distance
+words and whose payload is what the caller wants in that order (ids, queried
+flags, or an iota for the permutation) — no Python bigints, no dynamic
+shapes, so the whole lookup batch jits and shards over the peer axis like
+the GossipSub engine. A wave reads `rtable` and `keys` only (what it teaches
+is written after its last round), so `find_node` packs every table once
+(occupied slots in front, `packed_width` of them kept as the head), gathers
+the head's key words once (`_slot_keys`), and a response is the row pulls of
+a peer's head ids and key words, an XOR and that sort; what a table holds
+past the head is sorted only where some table reaches that far.
 """
 
 from __future__ import annotations
 
+import math
 from functools import partial
 
 import jax
@@ -53,7 +56,16 @@ def make_keys(n: int, seed: int = 0) -> np.ndarray:
     """Uniform 128-bit node keys, host-generated once per experiment (the
     reference derives keys from peer identities; only uniformity matters)."""
     rng = np.random.default_rng(np.random.SeedSequence([seed, 0x6AD]))
-    return rng.integers(0, 1 << 32, size=(n, KEY_WORDS), dtype=np.uint32)
+    keys = rng.integers(0, 1 << 32, size=(n, KEY_WORDS), dtype=np.uint32)
+    # two peers with one key would be at one distance from every target:
+    # `_closest_from_table`'s sort leaves the order of such a pair open.
+    # Keys that differ in their high 64 bits differ (one sort of n words)
+    high = (keys[:, 0].astype(np.uint64) << np.uint64(32)) | keys[:, 1]
+    if (np.unique(high).size != n
+            and np.unique(keys, axis=0).shape[0] != n):
+        raise ValueError(f"make_keys({n}, seed={seed}): two peers drew the "
+                         "same key; take another seed")
+    return keys
 
 
 def _bitlen32(x: jnp.ndarray) -> jnp.ndarray:
@@ -83,15 +95,20 @@ def bucket_slot(d: jnp.ndarray, n_buckets: int) -> jnp.ndarray:
     return jnp.clip(KEY_BITS - xor_bitlen(d), 0, n_buckets - 1)
 
 
-def lex_sort(words: jnp.ndarray, *payload: jnp.ndarray
-             ) -> tuple[jnp.ndarray, ...]:
+def lex_sort(words: jnp.ndarray, *payload: jnp.ndarray,
+             is_stable: bool = True) -> tuple[jnp.ndarray, ...]:
     """The (..., M) `payload` arrays in ascending big-int order of `words`,
     (W, ..., M) with the most significant word first; entries that tie keep
     their order. The package's one implementation of the XOR-metric order:
-    a single stable `lax.sort` along the last axis, the W words its keys, the
-    payload carried through the same exchanges (no gather afterwards)."""
-    out = jax.lax.sort(tuple(words) + payload, dimension=-1, is_stable=True,
-                       num_keys=words.shape[0])
+    a single `lax.sort` along the last axis, the W words its keys, the
+    payload carried through the same exchanges (no gather afterwards).
+
+    `is_stable=False` leaves the order of entries that tie open and saves
+    the operand XLA adds to keep it (an iota as wide as the rest, the last
+    key): exact only where entries that tie in every word carry one payload,
+    so that either order is the same bits (`_closest_from_table`)."""
+    out = jax.lax.sort(tuple(words) + payload, dimension=-1,
+                       is_stable=is_stable, num_keys=words.shape[0])
     return out[words.shape[0]:]
 
 
@@ -214,13 +231,34 @@ def rtable_insert(state: KadState, owners: jnp.ndarray, cands: jnp.ndarray
     return _insert_rows(state, owners, cands)[0]
 
 
-def _table_keys(state: KadState) -> jnp.ndarray:
-    """The key words of every routing-table slot, (W, N, B*K) with the word
-    axis first (a peer's words are W contiguous rows); what an empty slot
-    reads is masked where it is used."""
-    n = state.rtable.shape[0]
-    return jnp.moveaxis(
-        state.keys[jnp.clip(state.rtable.reshape(n, -1), 0)], -1, 0)
+def packed_width(n: int, n_buckets: int, k_bucket: int) -> int:
+    """How many slots of a packed table (occupied slots in front) a FIND_NODE
+    response sorts unconditionally: K * (ceil(log2(n / K)) + 3) rounded up to
+    a multiple of 128 (a sort's tile), or B*K where that is no narrower (no
+    packing then). From the shapes alone.
+
+    Why it holds what a table can hold: bucket b of a peer takes only peers
+    that share exactly b leading key bits with it, n / 2^(b+1) of n uniform
+    keys, and at most K of them. The log2(n / K) buckets in which that
+    expectation exceeds K fill to K; the deeper ones expect K/2, K/4, ...,
+    under K together. So a table that knows everybody holds about
+    K * (log2(n / K) + 1) peers (10,000 uniform keys, everybody known: mean
+    163, max 175 of 384), the rule keeps two buckets of K beyond that and the
+    rounding more: 256 at 10,000 and at 100,000 peers (expected 164 and 217),
+    128 from 64 to 512. It is a width, not a promise: `find_node` reads on
+    the tables it is handed whether every one fits (`LookupResult.packed`)
+    and sorts the rest where one does not."""
+    full = n_buckets * k_bucket
+    levels = math.ceil(math.log2(max(n, k_bucket) / k_bucket)) + 3
+    width = -(-k_bucket * levels // 128) * 128
+    return width if width < full else full
+
+
+def _slot_keys(keys: jnp.ndarray, slots: jnp.ndarray) -> jnp.ndarray:
+    """The key words of the (N, M) id slots, (W, N, M) with the word axis
+    first (a peer's words are W contiguous rows); what an empty slot reads
+    is masked where it is used."""
+    return jnp.moveaxis(keys[jnp.clip(slots, 0)], -1, 0)
 
 
 def _closest_from_table(table: jnp.ndarray, keys: jnp.ndarray,
@@ -230,15 +268,21 @@ def _closest_from_table(table: jnp.ndarray, keys: jnp.ndarray,
     flattened) to target, closest first, -1 padded — a FIND_NODE response
     (the reference returns the k nearest from the routing table). One
     `lex_sort` over the slots' XOR distances that carries the ids; an empty
-    slot's distance is all ones, so it sorts last. `table_keys`: the (W, B*K)
+    slot's distance is all ones, so it sorts last. `table_keys`: the (W, M)
     key words of the slots where the caller has gathered them already
-    (`_table_keys`), else they are gathered from `keys` here."""
+    (`_slot_keys`), else they are gathered from `keys` here.
+
+    The sort is not stable, and exact all the same: entries at one distance
+    are empty slots (all -1) or one id held twice (a sybil directory may),
+    the same bits in either order, because two peers never have one key
+    (`make_keys` raises) and a peer at distance 2^128 - 1, where an empty
+    slot sorts, is one draw in 2^128."""
     flat = table.reshape(-1)
     if table_keys is None:
         table_keys = keys[jnp.clip(flat, 0)].T
     d = jnp.where(flat >= 0, jnp.bitwise_xor(table_keys, target_key[:, None]),
                   jnp.uint32(0xFFFFFFFF))
-    return lex_sort(d, flat)[0][:k_out]
+    return lex_sort(d, flat, is_stable=False)[0][:k_out]
 
 
 def _teach_events(state: KadState, flat_peers: jnp.ndarray,
@@ -349,6 +393,9 @@ class LookupResult:
     # valid, each once, not held already) and how many found their bucket
     # full
     learn_counts: jnp.ndarray
+    # () bool: every table held at most `packed_width` peers, so every
+    # response of this call was sorted from the packed head alone
+    packed: jnp.ndarray
 
 
 def _find_node_impl(
@@ -371,24 +418,53 @@ def _find_node_impl(
     peer is replaced wholesale by `poison0` (the (Q, K_RESP) sybil-directory
     response per target): a lookup eclipse denies honest entries entirely
     instead of merely biasing the merge."""
-    n = state.rtable.shape[0]
+    n, n_buckets, k_bucket = state.rtable.shape
     q = origins.shape[0]
     s = shortlist
 
     o_stage = stage[origins]
     flat_tables = state.rtable.reshape(n, -1)
+    width = packed_width(n, n_buckets, k_bucket)
 
     with jax.named_scope("seed"):
         # the tables and the keys are read-only until the learning pass, so
-        # every slot's key words are gathered once a wave, not a response
-        table_keys = _table_keys(state)
+        # once a wave, not a response: every table packed (occupied slots in
+        # front, in any order: their distances differ), the key words of the
+        # head's slots gathered, and whether every table fits its head read
+        # off the first column past it
+        if width < flat_tables.shape[1]:
+            packed = -jnp.sort(-flat_tables, axis=-1, stable=False)
+            head, tail = packed[:, :width], packed[:, width:]
+            fits = (tail[:, 0] < 0).all()
+        else:
+            head, tail, fits = flat_tables, None, jnp.ones((), bool)
+        head_keys = _slot_keys(state.keys, head)
 
     def table_closest(peer, target_key, k_out):
-        """The k_out closest of `peer`'s table: its ids and each of its W
-        key-word rows are one contiguous row pull."""
-        return _closest_from_table(
-            flat_tables[peer], state.keys, target_key, k_out,
-            table_keys=jnp.stack([words[peer] for words in table_keys]))
+        """The k_out closest of `peer`'s table: its head's ids and each of
+        their W key-word rows are one contiguous row pull. Where some table
+        of the wave reaches past its head, the head's closest and the tail's
+        slots are sorted once more together: the same entries, whatever a
+        table holds. That branch gathers its key words a response (a tail
+        hoisted beside the head's would be an operand of the conditional,
+        and the branch that is taken then paid 1.9 ms a call for zeros in
+        its place, on the chip)."""
+        near = _closest_from_table(
+            head[peer], state.keys, target_key, k_out,
+            table_keys=jnp.stack([words[peer] for words in head_keys]))
+        if tail is None:
+            return near
+
+        def with_tail():
+            both = jnp.concatenate([near, tail[peer]])
+            # a word at a time: rows of W words gathered here would pad
+            # 32-fold, 2.2 GB of the program's temporaries at 10,000 peers
+            return _closest_from_table(
+                both, state.keys, target_key, k_out,
+                table_keys=jnp.stack(
+                    [word[jnp.clip(both, 0)] for word in state.keys.T]))
+
+        return jax.lax.cond(fits, lambda: near, with_tail)
 
     with jax.named_scope("seed"):
         # seed shortlist from the origin's own table
@@ -485,7 +561,7 @@ def _find_node_impl(
     result = LookupResult(
         closest=sl[:, :K_RESP], hops=hops, latency_ms=t_acc,
         queried=picked_seq, n_queries=nq,
-        learn_counts=learn_counts,
+        learn_counts=learn_counts, packed=fits,
     )
     return result, state
 

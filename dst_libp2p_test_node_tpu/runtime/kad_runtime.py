@@ -113,9 +113,9 @@ def dispatch_waves(state, origins, kinds, key, stage, lat_ms, *, learn_cap,
 
 def wave_numbers(waves) -> list:
     """What the one read after a phase takes of its waves, still on the
-    device: a wave's (hops, n_queries, latency_ms, learn_counts)."""
-    return [(res.hops, res.n_queries, res.latency_ms, res.learn_counts)
-            for _, _, res in waves]
+    device: a wave's (hops, n_queries, latency_ms, learn_counts, packed)."""
+    return [(res.hops, res.n_queries, res.latency_ms, res.learn_counts,
+             res.packed) for _, _, res in waves]
 
 
 def latency_percentiles(latency_ms: np.ndarray) -> dict:
@@ -134,15 +134,17 @@ class Lookups:
     latency_ms: np.ndarray    # (W, Q) float32
     timed_out: np.ndarray     # (W, Q) bool: over the probe time-out
     learn_counts: np.ndarray  # (W, 2) int32: offered, found the bucket full
+    packed: np.ndarray        # (W,) bool: answered from the packed heads alone
 
     @classmethod
     def of(cls, origins, kinds, numbers, timeout_ms: float) -> "Lookups":
         """From the read of `wave_numbers`."""
-        hops, queries, latency, learned = (
+        hops, queries, latency, learned, packed = (
             np.stack(column) for column in zip(*numbers))
         return cls(origins=np.asarray(origins), kinds=list(kinds), hops=hops,
                    n_queries=queries, latency_ms=latency,
-                   timed_out=latency > timeout_ms, learn_counts=learned)
+                   timed_out=latency > timeout_ms, learn_counts=learned,
+                   packed=packed)
 
     @property
     def count(self) -> int:
@@ -454,6 +456,9 @@ class KadSimulator:
             "probe_success_share": s.probe_success_share,
             "closest1_share": s.closest1_share,
             "bucket_full_share": float(full) / max(float(offered), 1.0),
+            "packed_share": float(
+                sum(int(p.packed.sum()) for p in phases)
+                / max(sum(len(p.kinds) for p in phases), 1)),
         }
 
     def stats(self, s: KadSummary) -> dict:

@@ -324,6 +324,7 @@ class RegressionSimulator:
             "lookups": k["lookups"], "hops_mean": k["hops_mean"],
             "queries_per_lookup": k["queries_per_lookup"],
             "rtable_census_mean": k["rtable_census_mean"],
+            "packed_share": k["packed_share"],
             "cap_filtered_edges": self.sim.graph.build["cap_filtered_edges"],
             "mesh_pings": sum(len(p.ping_ms) for p in self.pings),
         }
@@ -372,6 +373,7 @@ def _kad_stats(waves: list, census_mean: float, tx: int, rx: int) -> dict:
         "hops_mean": float(hops.mean()),
         "queries_per_lookup": float(queries.mean()),
         "rtable_census_mean": census_mean,
+        "packed_share": float(np.mean([w[4] for w in waves])),
         "queries_tx": tx,
         "queries_rx": rx,
         "lookup_latency_ms": [latency_percentiles(w[2]) for w in waves],

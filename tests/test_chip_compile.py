@@ -10,7 +10,9 @@ chip time. Shapes are the 100,000-peer headline config's
     gather: `exchange.SRC_GATHER`), also in the vmapped fragment form;
   - the sharded fixpoint `converge_sharded` on a 4-chip peer mesh: the
     collective the design rests on is in the HLO and the per-device memory
-    fits a v5e.
+    fits a v5e;
+  - both halves of the heartbeat scan, and `ops/kad.find_node` at the
+    kad-10k cell's probe tick (10,000 peers): what its response sorts.
 
 Everything that touches the topology lives in fixtures of THIS file (one
 xdist worker loads the TPU library, only after a test here has started);
@@ -162,3 +164,32 @@ def test_heartbeat_scan_compiles_for_v5e(one_chip, capacity, churn):
     # the sorts XLA:TPU is slow to compile: as many as before GRAFT and
     # PRUNE selected by rows (PR 46: the few rows' ranks are counted)
     assert len(re.findall(r" sort\(", text)) <= (11 if churn else 10)
+
+
+def test_find_node_probe_tick_compiles_for_v5e(one_chip):
+    """`find_node` at the kad-10k cell's probe tick (10,000 peers, ten
+    origins): a response sorts the packed head, 256 of a table's 384
+    columns, in five operands (four distance words and the ids, no
+    stability iota), and what a table holds past the head sits behind a
+    conditional."""
+    from dst_libp2p_test_node_tpu.ops import kad
+
+    n, q = 10_000, 10
+    assert kad.packed_width(n, 24, 16) == 256
+    state = jax.tree_util.tree_map(
+        lambda s: one_chip(s.shape, s.dtype),
+        jax.eval_shape(lambda: kad.init_kad_state(n, seed=1)))
+    compiled = kad.find_node.lower(
+        state, one_chip((q,), jnp.int32),
+        one_chip((q, kad.KEY_WORDS), jnp.uint32), one_chip((n,), jnp.int32),
+        one_chip((2, 2), jnp.float32), learn_cap=None).compile()
+    sorts = [line for line in compiled.as_text().splitlines()
+             if re.search(r" sort\(", line) and "/response/" in line
+             and "cond/branch" not in line]
+    assert len(sorts) == 1, sorts
+    result = sorts[0].split(" sort(")[0]
+    operands = re.findall(r"(?:pred|[a-z]+\d+)\[[\d,]*\]", result)
+    assert operands == ["u32[30,256]"] * 4 + ["s32[30,256]"], operands
+    assert "is_stable=true" not in sorts[0]
+    assert re.search(r" conditional\(.*/response/", compiled.as_text())
+    assert _device_bytes(compiled) < V5E_HBM_BYTES

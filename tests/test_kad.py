@@ -53,6 +53,8 @@ def test_lex_argsort_matches_bigint_sort():
     ints = _key_ints(d)
     sorted_ints = [ints[i] for i in order]
     assert sorted_ints == sorted(ints)
+    # entries that tie keep their order: servicedisco's ranks read it
+    assert order.tolist().index(5) + 1 == order.tolist().index(9)
 
 
 # ---- the oracle: the order as stable radix argsorts, least to most
@@ -94,17 +96,22 @@ def _radix_merge_shortlist(keys, sl, queried, pick, resp, targets, s):
             jnp.take_along_axis(qsort & ~dup, morder, axis=-1))
 
 
-def _tied_case(tied_words: int):
+def _tied_case(tied_words: int, one_key: bool = True):
     """40 peers whose distances to the target agree in their first
-    `tied_words` words (so a later word decides), two of them with one key
-    (a full tie: the earlier slot wins), in a table with empty slots."""
+    `tied_words` words (so a later word decides), in a table with empty
+    slots. `one_key`: two of them have one key (a full tie: `lex_argsort`
+    keeps the earlier slot first; the closest-K selection, which holds only
+    peers of distinct keys in the program, promises their distances and not
+    which of the two comes first)."""
     rng = np.random.default_rng(10 + tied_words)
     target = rng.integers(0, 1 << 32, kad.KEY_WORDS, dtype=np.uint32)
     d = rng.integers(0, 1 << 32, (40, kad.KEY_WORDS), dtype=np.uint32)
     d[:, :tied_words] = d[0, :tied_words]
-    d[20:30, tied_words:tied_words + 1] = d[5, tied_words]   # and one deeper
+    if one_key or tied_words + 1 < kad.KEY_WORDS:
+        d[20:30, tied_words:tied_words + 1] = d[5, tied_words]  # one deeper
     keys = d ^ target
-    keys[17] = keys[3]
+    if one_key:
+        keys[17] = keys[3]
     table = np.full((6, 16), -1, np.int32)
     slots = rng.choice(96, 40, replace=False)
     table.reshape(-1)[slots] = rng.permutation(40)
@@ -123,16 +130,39 @@ def _plain_case(n_valid: int, shape=(6, 16), repeat: bool = False):
     return keys, table, target
 
 
+def _holes_case():
+    """A (24, 16) table whose buckets hold their entries with holes between
+    them (an eviction that did not compact, a hand-written table): where
+    an entry stands in its bucket decides nothing."""
+    rng = np.random.default_rng(7)
+    keys = rng.integers(0, 1 << 32, (64, kad.KEY_WORDS), dtype=np.uint32)
+    target = rng.integers(0, 1 << 32, kad.KEY_WORDS, dtype=np.uint32)
+    table = np.full((24, 16), -1, np.int32)
+    ids = iter(rng.permutation(64))
+    for bucket in table[:9]:
+        at = rng.choice(16, 5, replace=False)
+        bucket[at] = [next(ids) for _ in at]
+    assert (table[:9, 0] < 0).any() and (table[:9, 15] >= 0).any()
+    return keys, table, target
+
+
 ORDER_CASES = {
     "tie_in_first_word": lambda: _tied_case(1),
     "tie_in_two_words": lambda: _tied_case(2),
     "tie_in_three_words": lambda: _tied_case(3),
+    "tie_in_first_word_distinct_keys": lambda: _tied_case(1, one_key=False),
+    "tie_in_two_words_distinct_keys": lambda: _tied_case(2, one_key=False),
+    "tie_in_three_words_distinct_keys": lambda: _tied_case(3, one_key=False),
     "duplicate_ids": lambda: _plain_case(30, repeat=True),
     "all_empty": lambda: _plain_case(0),
+    "all_empty_table": lambda: _plain_case(0, shape=(24, 16)),
     "one_entry": lambda: _plain_case(1),
     "fewer_than_k": lambda: _plain_case(9),
+    "holes_in_buckets": _holes_case,
     # ops/dht_adversary passes its (D,) sybil directory as a (1, D) view
     "flat_directory": lambda: _plain_case(50, shape=(1, 50)),
+    "flat_directory_repeated_id": lambda: _plain_case(
+        50, shape=(1, 50), repeat=True),
 }
 
 
@@ -141,10 +171,17 @@ ORDER_CASES = {
 def test_keyed_sort_is_the_radix_oracle_and_bigint_order(case, k_out):
     """`lex_argsort` and the closest-K selection against the radix oracle
     and against Python integers (`true_closest`'s arithmetic), entry for
-    entry; an empty slot is the farthest, ties keep slot order."""
+    entry; an empty slot is the farthest. `lex_argsort` is stable (ties keep
+    slot order); the selection's sort is not, and gives the stable one's
+    bits wherever entries at one distance are one id or empty slots, which
+    is every case but the three that give two peers one key."""
     keys, table, target = ORDER_CASES[case]()
     flat = table.reshape(-1)
     ints = _key_ints(keys)
+    held = set(flat[flat >= 0].tolist())
+    one_key = len({ints[e] for e in held}) < len(held)
+    assert one_key == (case in ("tie_in_first_word", "tie_in_two_words",
+                                "tie_in_three_words"))
     far = (1 << kad.KEY_BITS) - 1
     t_int = _key_ints(target[None, :])[0]
     dist = [ints[e] ^ t_int if e >= 0 else far for e in flat]
@@ -162,7 +199,12 @@ def test_keyed_sort_is_the_radix_oracle_and_bigint_order(case, k_out):
             == np.asarray(_radix_lex_argsort(both))).all()
 
     got = np.asarray(kad._closest_from_table(jtable, jkeys, jtarget, k_out))
-    assert got.tolist() == flat[by_int][:k_out].tolist()
+    want = flat[by_int][:k_out]
+    assert ([ints[e] ^ t_int if e >= 0 else far for e in got]
+            == [dist[i] for i in by_int[:k_out]])
+    if one_key:         # which of two peers with one key: not promised
+        return
+    assert got.tolist() == want.tolist()
     assert got.tolist() == np.asarray(_radix_closest_from_table(
         jtable, jkeys, jtarget, k_out)).tolist()
     # the slots' key words gathered beforehand, as find_node passes them
@@ -208,6 +250,42 @@ def test_merged_shortlist_is_the_oracles_and_in_distance_order():
         assert (np.asarray(again) == np.arange(s)).all()
 
 
+def _assert_same_wave(res, after, o_res, o_after):
+    """Two programs' results of one wave, leaf for leaf (but `packed`, which
+    says how the program answered, not what)."""
+    for name in ("closest", "hops", "n_queries", "latency_ms", "queried",
+                 "learn_counts"):
+        np.testing.assert_array_equal(
+            np.asarray(getattr(res, name)), np.asarray(getattr(o_res, name)),
+            err_msg=name)
+    np.testing.assert_array_equal(np.asarray(after.rtable),
+                                  np.asarray(o_after.rtable))
+
+
+def _assert_wave_is_kad_plains(state, origins, targets, stage, lat,
+                               learn_cap, res, after):
+    """The wave `res, after = find_node(state, origins, targets, ...)`
+    against benchmark/reference/kad_plain.py from the same start tables."""
+    from benchmark.reference import kad_plain
+
+    lookups, tables = kad_plain.wave(
+        kad_plain.tables_from_array(np.asarray(state.rtable)),
+        [kad_plain.key_of(row) for row in np.asarray(state.keys)],
+        np.asarray(origins), np.asarray(targets), np.asarray(stage),
+        np.asarray(lat, np.float64), learn_cap=learn_cap)
+    closest = np.full((len(lookups), kad_plain.K_RESP), -1)
+    for i, found in enumerate(lookups):
+        closest[i, :len(found["closest"])] = found["closest"]
+    assert (closest == np.asarray(res.closest)).all()
+    assert [f["hops"] for f in lookups] == np.asarray(res.hops).tolist()
+    assert ([f["n_queries"] for f in lookups]
+            == np.asarray(res.n_queries).tolist())
+    np.testing.assert_allclose([f["latency_ms"] for f in lookups],
+                               np.asarray(res.latency_ms), atol=1e-3, rtol=0)
+    assert (kad_plain.tables_to_array(tables)
+            == np.asarray(after.rtable)).all()
+
+
 @pytest.mark.parametrize("learn_cap", [kad.LEARN_CAP, None])
 def test_find_node_wave_is_the_oracle_programs_and_kad_plains(
         learn_cap, monkeypatch):
@@ -215,8 +293,6 @@ def test_find_node_wave_is_the_oracle_programs_and_kad_plains(
     (one keyed sort, the wave's table keys gathered once) against the same
     wave built on the radix oracle and un-hoisted, bit for bit, and against
     benchmark/reference/kad_plain.py."""
-    from benchmark.reference import kad_plain
-
     n, seed = 200, 11
     state = kad.seed_bootstraps(kad.init_kad_state(n, seed=seed),
                                 jnp.asarray([0], jnp.int32))
@@ -237,29 +313,149 @@ def test_find_node_wave_is_the_oracle_programs_and_kad_plains(
             st, o, t, stage, lat, 6, 32, learn_cap=learn_cap)
     )(state, origins, targets)
     monkeypatch.undo()
-    for name in ("closest", "hops", "n_queries", "latency_ms", "queried"):
-        np.testing.assert_array_equal(
-            np.asarray(getattr(res, name)), np.asarray(getattr(o_res, name)),
-            err_msg=name)
-    np.testing.assert_array_equal(np.asarray(after.rtable),
-                                  np.asarray(o_after.rtable))
+    _assert_same_wave(res, after, o_res, o_after)
+    _assert_wave_is_kad_plains(state, origins, targets, stage, lat,
+                               learn_cap, res, after)
 
-    keys = [kad_plain.key_of(row) for row in np.asarray(state.keys)]
-    lookups, tables = kad_plain.wave(
-        kad_plain.tables_from_array(np.asarray(state.rtable)), keys,
-        np.asarray(origins), np.asarray(targets), np.asarray(stage),
-        np.asarray(lat, np.float64), learn_cap=learn_cap)
-    closest = np.full((n - 1, kad_plain.K_RESP), -1)
-    for i, found in enumerate(lookups):
-        closest[i, :len(found["closest"])] = found["closest"]
-    assert (closest == np.asarray(res.closest)).all()
-    assert [f["hops"] for f in lookups] == np.asarray(res.hops).tolist()
-    assert ([f["n_queries"] for f in lookups]
-            == np.asarray(res.n_queries).tolist())
-    np.testing.assert_allclose([f["latency_ms"] for f in lookups],
-                               np.asarray(res.latency_ms), atol=1e-3, rtol=0)
-    assert (kad_plain.tables_to_array(tables)
-            == np.asarray(after.rtable)).all()
+
+# ---- the packed head: its width from the shapes, and a wave on tables that
+# fit it or do not
+
+def test_packed_width_rule():
+    """K * (ceil(log2(n / K)) + 3) rounded up to 128 columns, B*K where
+    that is no narrower: a multiple of 128 or the whole table, never wider,
+    never narrower at a larger n."""
+    assert kad.packed_width(10_000, 24, 16) == 256
+    assert kad.packed_width(100_000, 24, 16) == 256
+    assert [kad.packed_width(n, 24, 16) for n in (64, 200, 512)] == [128] * 3
+    # where it does not pay: a table no wider than the rule's width
+    assert kad.packed_width(64, 6, 16) == 96
+    assert kad.packed_width(64, 8, 16) == 128
+    assert kad.packed_width(10_000_000, 24, 16) == 384
+    assert kad.packed_width(4, 24, 16) == 128          # fewer peers than K
+    widths = [kad.packed_width(n, 24, 16) for n in (1, 16, 10**3, 10**4,
+                                                    10**5, 10**6, 10**7)]
+    assert widths == sorted(widths)
+    assert all(w % 128 == 0 or w == 384 for w in widths)
+    # two buckets of K over what a table that knows everybody expects
+    for n in (512, 10_000, 100_000):
+        assert kad.packed_width(n, 24, 16) >= 16 * (np.log2(n / 16) + 3)
+
+
+def test_unpacked_table_traces_no_pack_and_no_cond():
+    """Where the width is the whole table (B*K under the rule's), find_node
+    is the program without a pack and without a conditional, and says
+    `packed`."""
+    n = 64
+    state = kad.seed_bootstraps(kad.init_kad_state(n, n_buckets=6, seed=1),
+                                jnp.asarray([0], jnp.int32))
+    origins = jnp.arange(1, n, dtype=jnp.int32)
+    args = (state, origins, state.keys[origins], jnp.zeros((n,), jnp.int32),
+            jnp.full((2, 2), 100.0, jnp.float32))
+    text = str(jax.make_jaxpr(lambda *a: kad.find_node(*a))(*args))
+    assert "cond[" not in text
+    wide = kad.seed_bootstraps(kad.init_kad_state(n, seed=1),
+                               jnp.asarray([0], jnp.int32))
+    text = str(jax.make_jaxpr(lambda *a: kad.find_node(*a))(
+        wide, *args[1:]))
+    assert text.count("cond[") == 2       # the seed's and the response's
+    res, _ = kad.find_node(*args)
+    assert bool(res.packed)
+
+
+def test_init_kad_state_refuses_two_peers_with_one_key(monkeypatch):
+    """What makes the unstable sort exact is checked where the keys are
+    made: two equal rows raise."""
+    class OneKey:
+        def integers(self, low, high, size, dtype):
+            keys = np.arange(size[0] * size[1], dtype=dtype).reshape(size)
+            keys[-1] = keys[2]
+            return keys
+
+    assert kad.make_keys(300, seed=5).shape == (300, kad.KEY_WORDS)
+    monkeypatch.setattr(kad.np.random, "default_rng", lambda *_: OneKey())
+    with pytest.raises(ValueError, match="same key"):
+        kad.make_keys(300, seed=5)
+    with pytest.raises(ValueError, match="same key"):
+        kad.init_kad_state(300, seed=5)
+
+
+def _written_tables(keys: np.ndarray, entries) -> np.ndarray:
+    """Hand-written (N, 24, 16) tables: peer p holds `entries[p]` peers,
+    drawn in a random order from all the others and put where the bucket
+    policy puts them (the bucket of their XOR distance, appended while it
+    has room), in Python integers."""
+    n = keys.shape[0]
+    ints = _key_ints(keys)
+    rng = np.random.default_rng(3)
+    rtable = np.full((n, 24, 16), -1, np.int32)
+    for p in range(n):
+        held = [0] * 24
+        for c in rng.permutation(n):
+            if c == p or sum(held) >= entries[p]:
+                continue
+            b = min(kad.KEY_BITS - (ints[c] ^ ints[p]).bit_length(), 23)
+            if held[b] < 16:
+                rtable[p, b, held[b]] = c
+                held[b] += 1
+    return rtable
+
+
+@pytest.mark.parametrize("fits", [True, False])
+def test_find_node_on_written_tables_packed_or_not(fits, monkeypatch):
+    """One wave at 512 peers (128 of 384 columns packed) on hand-written
+    tables: every table under 128 entries (`packed` true), or forty of them
+    at 200 (`packed` false: the tail is sorted too and merged). Either way
+    the lookups and the tables the wave leaves are those of the program
+    built on the radix oracle over all 384 slots, and of kad_plain, leaf for
+    leaf. Sixteen groups of 32 peers, group g sharing exactly g leading key
+    bits with one base key, so that a peer of a late group has a full
+    bucket for every earlier group: uniform keys give 512 peers no table
+    past 96."""
+    n, seed = 512, 21
+    assert kad.packed_width(n, 24, 16) == 128
+    rng = np.random.default_rng(seed)
+    keys = rng.integers(0, 1 << 32, (n, kad.KEY_WORDS), dtype=np.uint32)
+    base = int(rng.integers(0, 1 << 16))
+    group = np.arange(n) // 32
+    # the first 16 bits: the base's first g, the next one flipped, then own
+    own = keys[:, 0] >> 16
+    mask = (0xFFFF << (16 - group)) & 0xFFFF
+    head = (base & mask) | ((~base & 0xFFFF) & (0x8000 >> group)) | (
+        own & ~mask & ~(0x8000 >> group) & 0xFFFF)
+    keys[:, 0] = (head.astype(np.uint32) << 16) | (keys[:, 0] & 0xFFFF)
+    assert np.unique(keys, axis=0).shape[0] == n
+    entries = np.full(n, 100)
+    if not fits:
+        entries[-40:] = 200
+    rtable = _written_tables(keys, entries)
+    census = (rtable >= 0).sum(axis=(1, 2))
+    assert census.max() == (100 if fits else 200), census.max()
+
+    state = kad.init_kad_state(n, seed=seed).replace(
+        keys=jnp.asarray(keys), rtable=jnp.asarray(rtable))
+    stage = jnp.arange(n, dtype=jnp.int32) % 2
+    lat = jnp.asarray([[100.0, 130.0], [130.0, 40.0]], jnp.float32)
+    origins = jnp.arange(1, n, dtype=jnp.int32)
+    targets = kad.random_targets(jax.random.PRNGKey(seed), n - 1)
+    res, after = kad.find_node(state, origins, targets, stage, lat,
+                               learn_cap=None)
+    assert bool(res.packed) == fits
+    assert int(np.asarray(res.hops).max()) > 0
+
+    # the oracle program: radix argsorts over all 384 slots, nothing packed
+    monkeypatch.setattr(kad, "_closest_from_table", _radix_closest_from_table)
+    monkeypatch.setattr(kad, "_merge_shortlist", _radix_merge_shortlist)
+    monkeypatch.setattr(kad, "packed_width", lambda n, b, k: b * k)
+    o_res, o_after = jax.jit(
+        lambda st, o, t: kad._find_node_impl(
+            st, o, t, stage, lat, 6, 32, learn_cap=None)
+    )(state, origins, targets)
+    monkeypatch.undo()
+    assert bool(o_res.packed)
+    _assert_same_wave(res, after, o_res, o_after)
+    _assert_wave_is_kad_plains(state, origins, targets, stage, lat, None,
+                               res, after)
 
 
 # ---- the scopes of jit_find_node and the four metrics that read them
@@ -298,19 +494,31 @@ def test_compiled_find_node_carries_the_scopes(find_node_scope_paths):
     assert not any("while/body" in p for p in under["seed"] + under["learn"])
     # the hoisted key gather is the seed's
     assert any(p.endswith("/gather") for p in under["seed"])
+    # no sixth: the pack is the seed's, the tail's sort under its
+    # conditional the seed's or the response's
+    for word, homes in (("jit(sort)", ("seed", "merge")),
+                        ("cond/branch_", ("seed", "response"))):
+        inside = [p for p in paths if word in p]
+        assert inside and all(
+            any(p in under[home] for home in homes) for p in inside), word
+    assert any("cond/branch_" in p for p in under["response"])
     # outside the five: the round's RTT and counters, a few scalars
     loose = len(paths) - sum(map(len, under.values()))
     assert loose <= 0.1 * len(paths), loose
 
 
-@pytest.mark.parametrize("scope", ["seed", "response", "merge", "learn"])
+@pytest.mark.parametrize("cell, scope", [
+    ("regression-10k", "seed"), ("regression-10k", "response"),
+    ("regression-10k", "merge"), ("regression-10k", "learn"),
+    ("kad-10k", "seed"), ("kad-10k", "response"), ("kad-10k", "learn")])
 def test_kad_scope_metric_reads_a_traced_find_node(
-        scope, find_node_scope_paths, monkeypatch):
-    """benchmark/layer_metrics/kad.<scope>.device_s.json through the reader
-    it names, on a profile with one microsecond of device time for every
-    instruction of the compiled tiny-regression `find_node` (XLA:CPU's own
-    profile has no device plane to read): not None, and with `order` and the
-    unscoped rest the scopes add up to the module."""
+        cell, scope, find_node_scope_paths, monkeypatch):
+    """benchmark/layer_metrics/kad.<scope>.device_s.json (the regression
+    cell's) and kadnode.<scope>.device_s.json (the kad cell's) through the
+    reader they name, on a profile with one microsecond of device time for
+    every instruction of the compiled tiny-regression `find_node` (XLA:CPU's
+    own profile has no device plane to read): not None, and with `order` and
+    the unscoped rest the scopes add up to the module."""
     from benchmark.harness import manifest, program_profile, trace
 
     plane = trace.DEVICE_PLANE_PREFIX + "0"
@@ -335,20 +543,22 @@ def test_kad_scope_metric_reads_a_traced_find_node(
         assert spec["name"] == name
         return spec, manifest.reader(spec["reader"])(ctx, **spec["params"])
 
-    spec, seconds = read(f"kad.{scope}.device_s")
+    prefix = {"regression-10k": "kad", "kad-10k": "kadnode"}[cell]
+    spec, seconds = read(f"{prefix}.{scope}.device_s")
     assert seconds is not None and seconds > 0.0
     assert spec["params"]["scopes"] == FIND_NODE_SCOPES
     with open(os.path.join(CHECKOUT, "BENCHMARK.json")) as f:
         entries = {m["name"]: m for m in json.load(f)["per_layer"]}
-    entry, whole_entry = entries[spec["name"]], entries["kad.find_node.device_s"]
+    entry = entries[spec["name"]]
+    whole_entry = entries[f"{prefix}.find_node.device_s"]
     assert (entry["layer"], entry["moves"], entry["workloads"]) == (
-        spec["layer"], "experiment_s", ["regression-10k.headline"])
+        spec["layer"], "experiment_s", [f"{cell}.headline"])
     assert entry["layer"] == whole_entry["layer"]
     parts = program_profile.scope_seconds(
         profile, ctx.trace_windows, "jit_find_node",
         [[n] for n in FIND_NODE_SCOPES], FIND_NODE_SCOPES)
     assert seconds == parts[FIND_NODE_SCOPES.index(scope)]
-    module = read("kad.find_node.device_s")[1]
+    module = read(f"{prefix}.find_node.device_s")[1]
     assert module == pytest.approx(whole / 1e9)
     assert sum(parts) <= module and sum(parts) >= 0.9 * module
 

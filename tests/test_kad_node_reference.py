@@ -214,9 +214,10 @@ def test_cli_kad_turn_spans_counters_stats_keys_and_same_seed_same_log(
     rc, stats, log, _ = _run_cli(cell, 7, str(tmp_path / "a"))
     assert rc == 0
     # two reads an experiment, each of everything its phase left: the
-    # twenty waves' four arrays, five censuses and the hits; the twelve
-    # ticks' four arrays and targets, the hits and the final state's four
-    assert reads == [20 * 4 + 5 + 1, 12 * 5 + 1 + 4]
+    # twenty waves' five leaves (hops, queries, latency, learn counts,
+    # packed), five censuses and the hits; the twelve ticks' five leaves
+    # and targets, the hits and the final state's four
+    assert reads == [20 * 5 + 5 + 1, 12 * 6 + 1 + 4]
     assert {"network_size", "wall_s", "spans", "compile", "kad"} <= set(stats)
     assert stats["network_size"] == 64
     spans = stats["spans"]
@@ -236,7 +237,14 @@ def test_cli_kad_turn_spans_counters_stats_keys_and_same_seed_same_log(
     assert set(counted) == {
         "lookups", "warmup_waves", "probe_ticks", "hops_mean",
         "queries_per_lookup", "census_mean", "census_min",
-        "probe_success_share", "closest1_share", "bucket_full_share"}
+        "probe_success_share", "closest1_share", "bucket_full_share",
+        "packed_share"}
+    # 64 peers hold at most 63 of the 128 columns a response sorts
+    assert counted["packed_share"] == 1.0
+    with open(os.path.join(CHECKOUT, "benchmark", "layer_metrics",
+                           "kadnode.packed_share.json")) as f:
+        assert json.load(f)["params"] == {
+            "annotation": "kadnode/counters", "counter": "packed_share"}
     assert {k: stats["kad"][k] for k in counted} == counted
     assert set(stats["kad"]) == set(counted) | {
         "queries_tx", "queries_rx", "queries_per_bootstrap",
@@ -257,8 +265,9 @@ def test_kad_help_says_how_to_read_stats_json(capsys):
         cli.main(["kad", "--help"])
     said = " ".join(capsys.readouterr().out.split())
     for word in ("--stats-json", "closest1_share", "bucket_full_share",
-                 "probe_success_share", "census_min", "queries_per_bootstrap",
-                 "lookup_latency_ms", "KAD_LEARN_CAP"):
+                 "packed_share", "probe_success_share", "census_min",
+                 "queries_per_bootstrap", "lookup_latency_ms",
+                 "KAD_LEARN_CAP"):
         assert word in said, word
 
 

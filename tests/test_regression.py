@@ -5,6 +5,7 @@ One shared simulation run (module fixture) keeps the jit compile chain to a
 single network size; the assertions slice it from different angles."""
 
 import json
+import os
 
 import jax
 import numpy as np
@@ -194,9 +195,14 @@ def test_simulator_graph_argument_and_proc_delay_default():
 
 def test_regression_cli_stats_json(tmp_path, monkeypatch, capsys):
     """`regression --stats-json PATH`: what `run`'s stats file has that
-    applies, "kad" and "pings"; the summary's stdout lines stay."""
+    applies, "kad" and "pings"; the summary's stdout lines stay; the
+    `kad/counters` annotation, one an experiment, is "kad"'s own numbers."""
     from dst_libp2p_test_node_tpu import cli
+    from dst_libp2p_test_node_tpu.runtime import regression_runtime
 
+    noted = []
+    monkeypatch.setattr(regression_runtime, "counters",
+                        lambda name, **values: noted.append((name, values)))
     for name, value in (("PEERS", N), ("CONNECTTO", 6), ("SEED", 5),
                         ("STARTSLEEP", 180)):
         monkeypatch.setenv(name, str(value))
@@ -229,6 +235,19 @@ def test_regression_cli_stats_json(tmp_path, monkeypatch, capsys):
             "rtable_census_mean", "queries_tx", "queries_rx",
             "lookup_latency_ms"} <= set(kad_stats)
     assert kad_stats["waves"] == 3 and kad_stats["lookups"] == 3 * (N - 1)
+    # every table fitted the columns a response sorts, in all three waves
+    assert kad_stats["packed_share"] == 1.0
+    assert [name for name, _ in noted] == ["kad/counters"]
+    assert set(noted[0][1]) == {
+        "lookups", "hops_mean", "queries_per_lookup", "rtable_census_mean",
+        "packed_share", "cap_filtered_edges", "mesh_pings"}
+    assert all(kad_stats[k] == v for k, v in noted[0][1].items()
+               if k in kad_stats)
+    with open(os.path.join(os.path.dirname(os.path.dirname(__file__)),
+                           "benchmark", "layer_metrics",
+                           "kad.packed_share.json")) as f:
+        assert json.load(f)["params"] == {
+            "annotation": "kad/counters", "counter": "packed_share"}
     assert kad_stats["queries_tx"] == kad_stats["queries_rx"] > 0
     assert [set(w) for w in kad_stats["lookup_latency_ms"]] == [
         {"p50", "p99"}] * 3
