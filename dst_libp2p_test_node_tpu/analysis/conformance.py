@@ -292,7 +292,8 @@ def run_adaptive_differential(scenario="sybil_graft_flood", n=48,
     """The AdaptivePolicy differential: heartbeat -> adaptive_round with the
     controller carry compared alongside the state (ctrl.* fields). Repair
     leaves are LIVE (evict+px armed) so the PX poisoner writes real px_pool
-    rows on both sides — the stripped path would compile the poison out."""
+    rows on both sides — over a state without the pool the poison compiles
+    out."""
     jax, jnp = _jax()
     from ..ops.adversary import (AdaptivePolicy, AdversaryParams,
                                  run_adaptive_heartbeats)

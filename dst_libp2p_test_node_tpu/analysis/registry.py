@@ -210,7 +210,7 @@ def _adaptive_attack_spec() -> TraceSpec:
     from ..ops.state import init_adaptive_ctrl
 
     # repair leaves live: the PX-poison behavior writes px_pool rows and the
-    # audit should see that program, not the stripped fallback
+    # audit should see that program, not the one without a pool
     g, params, state, a, _ = _single_topic(**_REPAIR)
     att = jnp.asarray(attacker_cohort(params.n, 0.25, seed=1))
     adv = AdversaryParams(adaptive=AdaptivePolicy(enabled=True))
@@ -268,14 +268,10 @@ def _sharded_attack_spec() -> TraceSpec:
     import jax.numpy as jnp
 
     from ..ops.adversary import AdversaryParams, attacker_cohort
-    from ..ops.state import strip_repair
     from ..parallel.sharding import audit_trial_groups, make_trial_mesh
     from ..runtime.campaign import sharded_attack_window
 
     g, params, state, a, _ = _single_topic()
-    # production path: params are repair-inert, so the campaign strips the
-    # repair leaves host-side before stacking — trace the same program
-    state, _saved = strip_repair(state)
     groups = audit_trial_groups()
     mesh = make_trial_mesh(groups, n_devices=groups)
     local = 2
@@ -298,12 +294,10 @@ def _nested_attack_spec() -> TraceSpec:
     import jax.numpy as jnp
 
     from ..ops.adversary import AdversaryParams, attacker_cohort
-    from ..ops.state import strip_repair
     from ..parallel.sharding import audit_trial_groups, make_trial_mesh
     from ..runtime.campaign import sharded_attack_window
 
     g, params, state, a, _ = _single_topic()
-    state, _saved = strip_repair(state)
     # the FULL grid: trial groups x every remaining device as each group's
     # peer submesh (2x2 under the CI lint gate's 4 virtual devices),
     # degenerating gracefully to 1x1 on a single device — the contract
@@ -337,9 +331,9 @@ def _dht_attack_window_spec() -> TraceSpec:
     from ..parallel.sharding import audit_trial_groups, make_trial_mesh
     from ..runtime.campaign import sharded_dht_recovery_window
 
-    # repair ARMED (no strip_repair): the DHT window exists to feed the
-    # redial path a poisoned shortlist, so the audited program is the one
-    # with the repair leaves live in the carry
+    # repair ARMED: the DHT window exists to feed the redial path a
+    # poisoned shortlist, so the audited program is the one with the repair
+    # leaves live in the carry
     g, params, state, a, (stage, lat, bw) = _single_topic(**_REPAIR)
     groups = audit_trial_groups()
     mesh = make_trial_mesh(groups)
@@ -374,15 +368,10 @@ def _faulted_nested_spec() -> TraceSpec:
 
     from ..ops.adversary import AdversaryParams, attacker_cohort
     from ..ops.faults import FaultParams, fault_masks
-    from ..ops.state import strip_repair
     from ..parallel.sharding import audit_trial_groups, make_trial_mesh
     from ..runtime.campaign import sharded_faulted_window
 
     g, params, state, a, _ = _single_topic(**_ARMED)
-    # production path: _ARMED leaves repair inert, so the campaign strips
-    # the repair leaves host-side before stacking (runtime/campaign.py's
-    # faulted dispatch) — trace that same program
-    state, _saved = strip_repair(state)
     groups = audit_trial_groups()
     mesh = make_trial_mesh(groups)
     local = 2
@@ -431,13 +420,10 @@ def _arena_window_spec() -> TraceSpec:
     from ..ops.adversary import (AdaptivePolicy, AdversaryParams,
                                  attacker_cohort)
     from ..ops.episub import EpisubParams, init_episub_ctrl
-    from ..ops.state import strip_repair
     from ..parallel.sharding import audit_trial_groups, make_trial_mesh
     from ..runtime.campaign import sharded_episub_window
 
-    # _ARMED is repair-inert: strip host-side exactly like _episub_windows
     g, params, state, a, _ = _single_topic(**_ARMED)
-    state, _saved = strip_repair(state)
     groups = audit_trial_groups()
     mesh = make_trial_mesh(groups)
     local = 2
@@ -470,12 +456,10 @@ def attack_rung_spec(n: int, *, steps: int = 20, connect_to: int = 10,
     import jax.numpy as jnp
 
     from ..ops.adversary import AdversaryParams, attacker_cohort
-    from ..ops.state import strip_repair
     from ..parallel.sharding import make_trial_mesh
     from ..runtime.campaign import sharded_attack_window
 
     g, params, state, a, _ = _single_topic(n=n, connect_to=connect_to)
-    state, _saved = strip_repair(state)
     groups = 2 if trial_groups is None else trial_groups
     mesh = make_trial_mesh(groups)
     trials = groups * local_trials
@@ -524,7 +508,6 @@ def _dcn_attack_window_spec() -> TraceSpec:
     import jax.numpy as jnp
 
     from ..ops.adversary import AdversaryParams, attacker_cohort
-    from ..ops.state import strip_repair
     from ..parallel.sharding import make_dcn_mesh
     from ..runtime.campaign import sharded_attack_window
 
@@ -533,7 +516,6 @@ def _dcn_attack_window_spec() -> TraceSpec:
     # dcn x trials x peers mesh so GA-S006 can statically prove no
     # peer-axis collective ever crosses a dcn block boundary
     g, params, state, a, _ = _single_topic()
-    state, _saved = strip_repair(state)
     dcn, groups = _dcn_audit_shape()
     mesh = make_dcn_mesh(dcn=dcn, trial_groups=groups)
     local = 2
@@ -567,13 +549,11 @@ def arena_rung_spec(n: int, *, steps: int = 20, connect_to: int = 10,
     from ..ops.adversary import (AdaptivePolicy, AdversaryParams,
                                  attacker_cohort)
     from ..ops.episub import EpisubParams, init_episub_ctrl
-    from ..ops.state import strip_repair
     from ..parallel.sharding import make_trial_mesh
     from ..runtime.campaign import sharded_episub_window
 
     g, params, state, a, _ = _single_topic(n=n, connect_to=connect_to,
                                            **_ARMED)
-    state, _saved = strip_repair(state)
     groups = 2 if trial_groups is None else trial_groups
     mesh = make_trial_mesh(groups)
     trials = groups * local_trials
@@ -934,9 +914,9 @@ def default_contracts() -> list[EntrypointContract]:
                   "per-trial crash/side/spike cohorts shard over both grid "
                   "axes exactly like the attacker masks, so fault sweeps "
                   "ride the trials x peers grid instead of falling back to "
-                  "the vmapped single-device stack; repair leaves stripped "
-                  "(the _ARMED params are repair-inert, matching the "
-                  "campaign's host-side strip), and the sharding auditor "
+                  "the vmapped single-device stack; no repair leaf in the "
+                  "state (the _ARMED params are repair-inert, as the "
+                  "campaign's are), and the sharding auditor "
                   "pins the same collective-kind set as the attack window"),
         EntrypointContract(
             name="campaign/attack_window_sharded",
@@ -960,8 +940,8 @@ def default_contracts() -> list[EntrypointContract]:
                       "design — it exists as the replicated-peer-submesh "
                       "equality baseline for the nested program "
                       "(docs/ARCHITECTURE.md §13)"),),
-            notes="legacy trial-only shard_map (nested=False), repair "
-                  "leaves stripped — the replicated-peer-submesh baseline "
+            notes="legacy trial-only shard_map (nested=False), no repair "
+                  "leaf in the state — the replicated-peer-submesh baseline "
                   "the nested program is pinned against; the stacked state "
                   "must feed back aval-stable across windows, and "
                   "loop/carry rules catch dead weight the r05 way"),

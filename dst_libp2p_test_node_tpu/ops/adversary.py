@@ -69,8 +69,7 @@ import numpy as np
 from .heartbeat import heartbeat_step
 from .pull import neighbor_pull_bool, reciprocal_pull_bool
 from .state import (PX_POOL_WIDTH, AdaptiveCtrl, SimParams, SimState,
-                    init_adaptive_ctrl, repair_inert, restore_repair,
-                    strip_repair)
+                    init_adaptive_ctrl)
 
 SCENARIOS = (
     "sybil_graft_flood",
@@ -645,7 +644,7 @@ def adaptive_round(
          ids into the px_pool row of every honest peer adjacent to the
          cohort, filling empty (-1) slots only — the same write discipline
          as heartbeat's PX capture, consumed by repair_round's candidate
-         lattice. With repair fully inert the leaves are stripped and this
+         lattice. A state made for inert repair holds no pool and this
          block compiles out (pool is None)."""
     pol = adv.adaptive
     if not pol.enabled:
@@ -762,10 +761,9 @@ def run_attacked_heartbeats(
     neighbor pull still hoists when churn is off (the attack mutates
     neither). Returns (state, obs) with obs leaves shaped (steps,).
 
-    Like run_heartbeats, the jit boundary is the inner function: no attack
-    behavior touches the mesh-repair leaves, so attack windows with repair
-    off (the common campaign case — repair arms only the RECOVERY window)
-    run with the 5 repair leaves stripped from the scan carry.
+    No attack behavior touches the mesh-repair leaves, and an attack window
+    with repair off (the common campaign case — repair arms only the
+    RECOVERY window) scans a state that holds none (ops/state.py).
 
     `telemetry`: optional armed ops/telemetry.TelemetryParams — the flight
     recorder's per-round tel_* channels join the obs dict. None or a
@@ -775,12 +773,6 @@ def run_attacked_heartbeats(
     so the protocol trajectory is bit-identical either way."""
     if telemetry is not None and not telemetry.enabled:
         telemetry = None
-    if repair_inert(params):
-        state, saved = strip_repair(state)
-        out, obs = _run_attacked_heartbeats(
-            state, conns, rev, out_mask, attacker, params, adv, steps,
-            batch_factor, telemetry)
-        return restore_repair(out, saved), obs
     return _run_attacked_heartbeats(
         state, conns, rev, out_mask, attacker, params, adv, steps,
         batch_factor, telemetry)
@@ -847,9 +839,9 @@ def run_adaptive_heartbeats(
     the return is the base runner's (state, obs). Armed, `ctrl` defaults to
     a fresh init_adaptive_ctrl(params.n) and the return widens to
     ((state, ctrl), obs) — the run_dht_recovery_heartbeats carry
-    convention. Armed obs adds the adv_* controller channels; with repair
-    fully inert the 5 repair leaves are still stripped around the jit (the
-    PX poisoner compiles out: nothing could read the pool)."""
+    convention. Armed obs adds the adv_* controller channels; over a state
+    without repair leaves the PX poisoner compiles out (nothing could read
+    the pool)."""
     if not adv.adaptive.enabled:
         if ctrl is not None:
             raise ValueError("ctrl given but adv.adaptive is disabled — the "
@@ -862,12 +854,6 @@ def run_adaptive_heartbeats(
         telemetry = None
     if ctrl is None:
         ctrl = init_adaptive_ctrl(params.n, like=attacker)
-    if repair_inert(params):
-        state, saved = strip_repair(state)
-        (out, ctrl), obs = _run_adaptive_heartbeats(
-            state, ctrl, conns, rev, out_mask, attacker, params, adv, steps,
-            batch_factor, telemetry)
-        return (restore_repair(out, saved), ctrl), obs
     return _run_adaptive_heartbeats(
         state, ctrl, conns, rev, out_mask, attacker, params, adv, steps,
         batch_factor, telemetry)

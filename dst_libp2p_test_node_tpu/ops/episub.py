@@ -57,8 +57,7 @@ from .adversary import AdversaryParams, adaptive_round, adversary_round
 from .faults import FaultParams, partition_edge_mask
 from .heartbeat import _apply_decay
 from .pull import neighbor_pull_bool, neighbor_pull_min, reciprocal_pull_bool
-from .state import (SimParams, SimState, init_adaptive_ctrl, repair_inert,
-                    restore_repair, strip_repair)
+from .state import SimParams, SimState, init_adaptive_ctrl
 
 # numpy, NOT jnp: the protocol registry imports this module lazily, and
 # the first import can happen INSIDE an active jit trace (a campaign
@@ -267,6 +266,7 @@ def episub_heartbeat_step(
     return new_state, new_ctrl
 
 
+@partial(jax.jit, static_argnames=("params", "ep", "steps", "batch_factor"))
 def run_episub_heartbeats(
     state: SimState,
     ctrl: EpisubCtrl,
@@ -279,32 +279,10 @@ def run_episub_heartbeats(
     batch_factor: int = 1,
 ):
     """lax.scan of episub_heartbeat_step x steps -> (state, ctrl). The
-    runner contract mirrors run_heartbeats (strip_repair around the jit
-    when repair is inert, static steps for segment cache hits) with the
-    ctrl carry prepended per the ProtocolSpec convention."""
+    runner contract mirrors run_heartbeats (static steps for segment cache
+    hits) with the ctrl carry prepended per the ProtocolSpec convention.
+    `ep` is static: it is validated once, where it is traced."""
     ep.validate(params.n)
-    if repair_inert(params):
-        state, saved = strip_repair(state)
-        out, ctrl = _run_episub_heartbeats(
-            state, ctrl, conns, rev, out_mask, params, ep, steps,
-            batch_factor)
-        return restore_repair(out, saved), ctrl
-    return _run_episub_heartbeats(
-        state, ctrl, conns, rev, out_mask, params, ep, steps, batch_factor)
-
-
-@partial(jax.jit, static_argnames=("params", "ep", "steps", "batch_factor"))
-def _run_episub_heartbeats(
-    state: SimState,
-    ctrl: EpisubCtrl,
-    conns: jnp.ndarray,
-    rev: jnp.ndarray,
-    out_mask: jnp.ndarray,
-    params: SimParams,
-    ep: EpisubParams,
-    steps: int,
-    batch_factor: int = 1,
-):
     nbr_ok = None
     if params.churn_down_per_hb == 0.0 and params.churn_up_per_hb == 0.0:
         nbr_ok = neighbor_pull_bool(
@@ -346,12 +324,6 @@ def run_episub_attacked_heartbeats(
     ep.validate(params.n)
     if telemetry is not None and not telemetry.enabled:
         telemetry = None
-    if repair_inert(params):
-        state, saved = strip_repair(state)
-        (out, ctrl), obs = _run_episub_attacked_heartbeats(
-            state, ctrl, conns, rev, out_mask, attacker, params, ep, adv,
-            steps, batch_factor, telemetry)
-        return (restore_repair(out, saved), ctrl), obs
     return _run_episub_attacked_heartbeats(
         state, ctrl, conns, rev, out_mask, attacker, params, ep, adv, steps,
         batch_factor, telemetry)
@@ -434,12 +406,6 @@ def run_episub_adaptive_heartbeats(
         telemetry = None
     if actrl is None:
         actrl = init_adaptive_ctrl(params.n)
-    if repair_inert(params):
-        state, saved = strip_repair(state)
-        (out, ctrl, actrl), obs = _run_episub_adaptive_heartbeats(
-            state, ctrl, actrl, conns, rev, out_mask, attacker, params, ep,
-            adv, steps, batch_factor, telemetry)
-        return (restore_repair(out, saved), ctrl, actrl), obs
     return _run_episub_adaptive_heartbeats(
         state, ctrl, actrl, conns, rev, out_mask, attacker, params, ep, adv,
         steps, batch_factor, telemetry)
@@ -544,16 +510,6 @@ def run_episub_faulted_heartbeats(
         actrl = init_adaptive_ctrl(params.n)
     if not adv.adaptive.enabled and actrl is not None:
         raise ValueError("actrl given but the adaptive policy is disabled")
-    if repair_inert(params):
-        state, saved = strip_repair(state)
-        out, obs = _run_episub_faulted_heartbeats(
-            state, ctrl, actrl, conns, rev, out_mask, attacker, crash, side,
-            spike, params, ep, adv, faults, steps, batch_factor, telemetry)
-        if adv.adaptive.enabled:
-            out2, ctrl, actrl = out
-            return (restore_repair(out2, saved), ctrl, actrl), obs
-        out2, ctrl = out
-        return (restore_repair(out2, saved), ctrl), obs
     return _run_episub_faulted_heartbeats(
         state, ctrl, actrl, conns, rev, out_mask, attacker, crash, side,
         spike, params, ep, adv, faults, steps, batch_factor, telemetry)

@@ -41,7 +41,7 @@ relative to the fault-armed scan:
                   window round: the Shadow latency-injection analog, felt
                   as delivery delay by everything downstream.
 
-Determinism contract (the strip_repair discipline from PR 5, applied at the
+Determinism contract (what is off is not in the program, applied at the
 config level): `FaultParams()` is all-off, `run_faulted_heartbeats` then
 literally delegates to run_attacked_heartbeats — same function object, same
 jit cache entry, bit-identical outputs, zero PRNG consumed by any fault
@@ -64,7 +64,7 @@ from .adversary import (AdversaryParams, adaptive_round, adversary_round,
 from .heartbeat import heartbeat_step
 from .pull import neighbor_pull_bool
 from .state import (SimParams, SimState, init_adaptive_ctrl, repair_inert,
-                    restore_repair, strip_repair)
+                    require_repair)
 
 INF = jnp.float32(3.4e38)
 
@@ -234,19 +234,9 @@ def run_faulted_heartbeats(
         ctrl = init_adaptive_ctrl(params.n)
     if not adv.adaptive.enabled and ctrl is not None:
         raise ValueError("ctrl given but the adaptive policy is disabled")
-    if repair_inert(params):
-        state, saved = strip_repair(state)
-        out, obs = _run_faulted_heartbeats(
-            state, conns, rev, out_mask, attacker, crash, side, spike,
-            params, adv, faults, steps, batch_factor, telemetry, ctrl)
-        if adv.adaptive.enabled:
-            out, ctrl = out
-            return (restore_repair(out, saved), ctrl), obs
-        return restore_repair(out, saved), obs
-    out, obs = _run_faulted_heartbeats(
+    return _run_faulted_heartbeats(
         state, conns, rev, out_mask, attacker, crash, side, spike,
         params, adv, faults, steps, batch_factor, telemetry, ctrl)
-    return out, obs
 
 
 @partial(jax.jit,
@@ -308,8 +298,9 @@ def _run_faulted_heartbeats(
             warm_offset_ms=jnp.full_like(s.warm_offset_ms, INF),
         )
         if not repair_inert(params):
-            # repair leaves ride the carry only when a knob is armed; a
-            # restarted peer's PX pool and starvation clock reset with it
+            # the state holds the repair leaves only when a knob is armed;
+            # a restarted peer's PX pool and starvation clock reset with it
+            require_repair(s)
             repl["px_pool"] = jnp.where(crash[:, None], -1, s.px_pool)
             repl["starve_hb"] = jnp.where(crash, 0, s.starve_hb)
         return s.replace(**repl)

@@ -40,7 +40,7 @@ from .pull import (neighbor_pull_bool, neighbor_update_bool,
                    reciprocal_send_bool, rows_route, sending_rows,
                    sparse_route)
 from .state import (PX_POOL_WIDTH, SimParams, SimState, repair_inert,
-                    restore_repair, strip_repair)
+                    require_repair)
 
 BIG = jnp.float32(1e30)
 
@@ -238,6 +238,8 @@ def heartbeat_step(
         # or the return arity would silently change under churn)
         raise ValueError("deg_in requires valid_pre, no edge_ok, and churn "
                          "off (run_heartbeats' churn-free scan protocol)")
+    if not repair_inert(params):
+        require_repair(state)
     n, c = conns.shape
     # whether GRAFT and PRUNE select by rows (_select_rows), as their
     # deliveries do: the trace-time half of ops/pull's sparse dispatch
@@ -650,13 +652,12 @@ def run_heartbeats(
     """lax.scan over heartbeat rounds — simulated time scales in rounds with
     no host sync (the reference's 'long simulated time' axis, SURVEY.md §5).
 
-    The jitted scan is `_run_heartbeats`; this boundary strips the 5
-    mesh-repair leaves from the carry when no repair knob is armed — they
-    are provably untouched then, and carrying them cost the r05 bench ~6
-    passthrough buffers per segment (ops/state.py strip_repair). NOT
-    donated: callers (tests) re-run segments from a kept state.
-    Jitted with static `steps` so repeated same-length segments (the
-    simulator's inter-message gaps) hit the compile cache.
+    The jitted scan is `_run_heartbeats`, which carries the state as it
+    is: one made for params that arm no repair holds no repair leaf
+    (ops/state.py), so the scan has none to pass through. NOT donated:
+    callers (tests) re-run segments from a kept state. Jitted with static
+    `steps` so repeated same-length segments (the simulator's inter-message
+    gaps) hit the compile cache.
 
     `spared`: heartbeat_step's — (N,) peers the churn draw does not kill;
     None with churn off, where nothing would read it.
@@ -665,13 +666,8 @@ def run_heartbeats(
     `pulls`, the pull in front of the scan counted as a dense `validity`),
     a device array nobody has waited for: (state, pulls). The program is
     the same either way."""
-    saved = None
-    if repair_inert(params):
-        state, saved = strip_repair(state)
     out, pulls = _run_heartbeats(
         state, conns, rev, out_mask, params, steps, spared)
-    if saved is not None:
-        out = restore_repair(out, saved)
     return (out, pulls) if with_pulls else out
 
 

@@ -59,7 +59,8 @@ import jax.numpy as jnp
 
 from .adversary import (AdversaryParams, adaptive_round, attack_observables)
 from .heartbeat import heartbeat_step
-from .state import (AdaptiveCtrl, SimParams, SimState, init_adaptive_ctrl)
+from .state import (AdaptiveCtrl, SimParams, SimState, init_adaptive_ctrl,
+                    require_repair)
 
 INF = jnp.float32(3.4e38)
 
@@ -148,6 +149,7 @@ def repair_round(
 
     The whole action machinery runs under one lax.cond: a healthy network
     (nobody starved, no PX pending) pays only the trigger probes."""
+    require_repair(state)
     n, c = conns.shape
     me = jnp.arange(n, dtype=jnp.int32)
     iota_c = jnp.arange(c, dtype=jnp.int32)
@@ -349,6 +351,7 @@ def run_recovery_heartbeats(
     here directly is treated as None so the trace stays identical)."""
     if telemetry is not None and not telemetry.enabled:
         telemetry = None
+    require_repair(state)
 
     def body(carry, _):
         s, cn, rv, om = carry
@@ -386,6 +389,8 @@ def run_recovery_heartbeats(
 def _run_dht_recovery_heartbeats(state, conns, rev, out_mask, attacker,
                                  dht_pool, params, steps, publisher,
                                  batch_factor, telemetry):
+    require_repair(state)
+
     def body(carry, _):
         s, cn, rv, om, pool = carry
         ev0 = s.evictions.sum()
@@ -463,6 +468,7 @@ def _run_adaptive_recovery_heartbeats(state, ctrl, conns, rev, out_mask,
                                       attacker, dht_pool, params, adv,
                                       steps, publisher, batch_factor,
                                       telemetry):
+    require_repair(state)
     pol = adv.adaptive
     # slot_race: the cohort runs the dial controller too, and its sybil
     # identities COMPLETE inbound handshakes (it wants the slot) — the

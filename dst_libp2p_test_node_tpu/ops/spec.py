@@ -54,9 +54,11 @@ SPEC_FIELDS = (
 
 
 def host_state(state: SimState) -> dict:
-    """SimState -> the oracle's state dict: one numpy array per leaf, plus
-    the carried jax PRNG key (left as a jax array for splitting)."""
-    st = {f: np.asarray(getattr(state, f)) for f in SPEC_FIELDS}
+    """SimState -> the oracle's state dict: one numpy array per leaf (None
+    for a repair leaf the state does not hold), plus the carried jax PRNG
+    key (left as a jax array for splitting)."""
+    st = {f: None if getattr(state, f) is None
+          else np.asarray(getattr(state, f)) for f in SPEC_FIELDS}
     st["key"] = state.key
     return st
 

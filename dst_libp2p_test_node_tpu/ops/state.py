@@ -272,19 +272,22 @@ class SimState:
     idontwant_tx: jnp.ndarray  # (N,) int32 IDONTWANTs sent (v1.2: on first
     #                            receipt of a large message, to mesh peers)
     idontwant_rx: jnp.ndarray  # (N,) int32 IDONTWANTs received
-    # mesh-repair bookkeeping (ops/repair.py; inert at the repair-off
-    # default — the default compiled step neither reads nor writes them)
-    px_pool: jnp.ndarray       # (N, PX_POOL_WIDTH) int32 — PX candidate ids
-    #                            carried by the most recent PRUNE received;
-    #                            -1 = empty slot
-    starve_hb: jnp.ndarray     # (N,) int32 — consecutive heartbeats the peer
-    #                            spent below d_low (re-dial trigger)
-    evictions: jnp.ndarray     # (N,) int32 — score-evictions sent (a subset
-    #                            of `prunes`, counted separately)
-    px_grafts: jnp.ndarray     # (N,) int32 — mesh edges gained through a PX
-    #                            candidate (grafted or dialed+grafted)
-    redials: jnp.ndarray       # (N,) int32 — new connections dialed by the
-    #                            re-dial controller
+    # mesh-repair bookkeeping (ops/repair.py): held exactly where the params
+    # the state was made for arm repair (init_state), or after arm_repair;
+    # None, an empty subtree, otherwise: no jit of an inert run carries them
+    px_pool: jnp.ndarray | None = None    # (N, PX_POOL_WIDTH) int32 — PX
+    #                            candidate ids carried by the most recent
+    #                            PRUNE received; -1 = empty slot
+    starve_hb: jnp.ndarray | None = None  # (N,) int32 — consecutive
+    #                            heartbeats the peer spent below d_low
+    #                            (re-dial trigger)
+    evictions: jnp.ndarray | None = None  # (N,) int32 — score-evictions sent
+    #                            (a subset of `prunes`, counted separately)
+    px_grafts: jnp.ndarray | None = None  # (N,) int32 — mesh edges gained
+    #                            through a PX candidate (grafted or
+    #                            dialed+grafted)
+    redials: jnp.ndarray | None = None    # (N,) int32 — new connections
+    #                            dialed by the re-dial controller
 
     def score(self, params: SimParams) -> jnp.ndarray:
         """Peer score as seen across each directed edge (v1.1 subset:
@@ -302,7 +305,7 @@ def init_state(params: SimParams, seed: int = 0) -> SimState:
     n, c = params.n, params.capacity
     key = jax.random.PRNGKey(seed)
     key, k_phase = jax.random.split(key)
-    return SimState(
+    state = SimState(
         mesh_mask=jnp.zeros((n, c), dtype=bool),
         fanout_mask=jnp.zeros((n, c), dtype=bool),
         fanout_expire=jnp.zeros((n,), dtype=jnp.float32),
@@ -330,22 +333,16 @@ def init_state(params: SimParams, seed: int = 0) -> SimState:
         iwant_rx=jnp.zeros((n,), dtype=jnp.int32),
         idontwant_tx=jnp.zeros((n,), dtype=jnp.int32),
         idontwant_rx=jnp.zeros((n,), dtype=jnp.int32),
-        px_pool=jnp.full((n, PX_POOL_WIDTH), -1, dtype=jnp.int32),
-        starve_hb=jnp.zeros((n,), dtype=jnp.int32),
-        evictions=jnp.zeros((n,), dtype=jnp.int32),
-        px_grafts=jnp.zeros((n,), dtype=jnp.int32),
-        redials=jnp.zeros((n,), dtype=jnp.int32),
     )
+    return state if repair_inert(params) else arm_repair(state)
 
 
-# The mesh-repair leaves ride SimState so repair-armed traces can carry
-# them, but the default (repair-off) compiled step neither reads nor
-# writes any of them — they are pure passthrough at every jit boundary
-# and dead weight in every scan carry. strip_repair/restore_repair excise
-# them HOST-SIDE around the public entrypoints when repair_inert(params):
-# a None field is an empty pytree subtree, so the stripped state traces
-# through the same code with 5 fewer carry/output buffers (the r05 BENCH
-# regression was exactly these buffers riding the publish/heartbeat jits).
+# The five mesh-repair leaves. A state holds them only where repair is armed
+# (a None field is an empty pytree subtree): an inert run's programs neither
+# read nor write them, and a jit that carried them would pay five
+# pass-through buffers (the r05 BENCH regression was exactly these riding
+# the publish and heartbeat jits). They are made in arm_repair and nowhere
+# else.
 REPAIR_LEAVES = ("px_pool", "starve_hb", "evictions", "px_grafts", "redials")
 
 
@@ -356,22 +353,50 @@ def repair_inert(params: SimParams) -> bool:
     return not (params.evict or params.px or params.redial)
 
 
-def strip_repair(state: SimState):
-    """(state without repair leaves, saved dict to restore them later)."""
-    saved = {k: getattr(state, k) for k in REPAIR_LEAVES}
-    return state.replace(**{k: None for k in REPAIR_LEAVES}), saved
+def arm_repair(state: SimState) -> SimState:
+    """`state` with the repair leaves: its own where it holds them, fresh
+    ones (empty PX pool, zero counters) where it was made inert. init_state
+    arms through here; the other caller is the one real transition, a state
+    an inert window carried into a window that arms repair (a campaign
+    trial's recovery). Works on a stacked (trials, N) state too."""
+    if state.px_pool is not None:
+        return state
+    like = state.grafts  # (..., N) int32
+    return state.replace(
+        px_pool=jnp.full(like.shape + (PX_POOL_WIDTH,), -1, jnp.int32),
+        # a buffer each, not one shared: a donating jit may take them
+        **{k: jnp.zeros_like(like) for k in REPAIR_LEAVES if k != "px_pool"})
 
 
-def restore_repair(state: SimState, saved: dict) -> SimState:
-    """Reattach the leaves strip_repair removed (they were untouched by
-    construction — no inert trace references them)."""
-    return state.replace(**saved)
+def disarm_repair(state: SimState) -> SimState:
+    """The way back: `state` without the repair leaves, for one that leaves
+    the window that armed repair and goes on under inert params (read its
+    counters first: repair_totals)."""
+    return state.replace(**dict.fromkeys(REPAIR_LEAVES))
+
+
+def require_repair(state: SimState) -> None:
+    """Trace-time guard of every stage that reads a repair leaf."""
+    if state.px_pool is None:
+        raise ValueError(
+            "this program reads the mesh-repair leaves (evict / px / redial "
+            "or a recovery window) and the state holds none: it was made "
+            "for params that arm no repair. Pass it through "
+            "ops.state.arm_repair(state) first.")
+
+
+def repair_totals(state: SimState) -> dict[str, int]:
+    """Host-side totals of the three repair activity counters; zeros for a
+    state that holds no repair leaves (nothing could have counted)."""
+    return {k: 0 if getattr(state, k) is None
+            else int(np.asarray(getattr(state, k)).sum())
+            for k in ("evictions", "px_grafts", "redials")}
 
 
 # Per-attacker controller leaves for the ADAPTIVE adversary (ops/adversary.py
-# AdaptivePolicy). These are the strip_repair discipline taken to its limit:
-# instead of riding SimState and being excised host-side when inert, the
-# controller is a SEPARATE pytree threaded through the armed scan carry
+# AdaptivePolicy). The same rule as the repair leaves, as a struct of its
+# own: instead of riding SimState where the policy is off, the controller
+# is a SEPARATE pytree threaded through the armed scan carry
 # (run_adaptive_heartbeats / run_adaptive_recovery_heartbeats) and never
 # materialized at all on the disabled path — the delegating wrappers call the
 # base runners with the exact argument list, so the default trace cannot grow

@@ -57,8 +57,7 @@ import jax.numpy as jnp
 
 from .heartbeat import _apply_decay, heartbeat_step, run_heartbeats
 from .pull import neighbor_pull_bool
-from .state import (SimParams, SimState, repair_inert, restore_repair,
-                    strip_repair)
+from .state import SimParams, SimState
 
 
 @dataclass(frozen=True)
@@ -179,9 +178,9 @@ def adaptive_observables(
       adv_regraft_attempts  cumulative backoff-expiry re-grafts sent
       adv_px_sybil_frac     fraction of OCCUPIED honest px_pool entries
                             holding attacker ids — how poisoned the repair
-                            candidate lattice currently is (0.0 when the
-                            repair leaves are stripped: nothing reads the
-                            pool either)
+                            candidate lattice currently is (0.0 for a state
+                            without repair leaves: nothing reads the pool
+                            either)
 
     `ctrl` is the ops/state.AdaptiveCtrl carry; `acting` the (N,) bool
     flood mask the duty cycle chose; `violations` the round's scalar
@@ -228,12 +227,6 @@ def run_recorded_heartbeats(
         return run_heartbeats(state, conns, rev, out_mask, params, steps,
                               spared=spared), {}
     telemetry.validate()
-    if repair_inert(params):
-        state, saved = strip_repair(state)
-        out, trace = _run_recorded_heartbeats(
-            state, conns, rev, out_mask, params, telemetry, steps,
-            batch_factor, spared)
-        return restore_repair(out, saved), trace
     return _run_recorded_heartbeats(
         state, conns, rev, out_mask, params, telemetry, steps, batch_factor,
         spared)

@@ -126,6 +126,8 @@ from ..ops.repair import (
     run_dht_recovery_heartbeats,
     run_recovery_heartbeats,
 )
+from ..ops.state import (arm_repair, disarm_repair, repair_inert,
+                         repair_totals)
 from ..ops.telemetry import TelemetryParams
 from .simulator import ExperimentConfig, MessageRecord, Simulator
 from .summarize import sanitize_nonfinite
@@ -912,17 +914,12 @@ def _attack_windows(sim: Simulator, attackers, states, adv, steps: int,
     adaptive = adv.adaptive.enabled
     faulted = faults is not None and faults.enabled
     if faulted and trial_mesh is not None and len(states) > 1:
-        from ..ops.state import repair_inert, restore_repair, strip_repair
         from ..parallel.sharding import place_trial_batch
 
         n_rows = sim.params.n
         s_count = len(states)
         states, attackers, fmasks, local = _pad_to_groups(
             states, attackers, trial_mesh, extras=fmasks)
-        saved = None
-        if repair_inert(sim.params):
-            pairs = [strip_repair(s) for s in states]
-            states, saved = [p[0] for p in pairs], [p[1] for p in pairs]
         stacked = tree(lambda *xs: jnp.stack(xs), *states)
         att = jnp.stack(attackers)
         crs = jnp.stack([m["crash"] for m in fmasks])
@@ -941,8 +938,6 @@ def _attack_windows(sim: Simulator, attackers, states, adv, steps: int,
             if adaptive:
                 st, c = st
                 ctrls.append(c)
-            if saved is not None:
-                st = restore_repair(st, saved[j])
             outs.append(st)
         return outs, [{k: v[j] for k, v in obs_np.items()}
                       for j in range(s_count)], ctrls
@@ -983,19 +978,11 @@ def _attack_windows(sim: Simulator, attackers, states, adv, steps: int,
              if adaptive else None),
         )
     if trial_mesh is not None and len(states) > 1:
-        from ..ops.state import repair_inert, restore_repair, strip_repair
         from ..parallel.sharding import place_trial_batch
 
         s_count = len(states)
         states, attackers, local = _pad_to_groups(states, attackers,
                                                   trial_mesh)
-        # strip the repair leaves host-side, ONCE for the whole batch (the
-        # wrapper inside the mapped body would strip per-trace but still
-        # ship the leaves through the shard_map boundary)
-        saved = None
-        if repair_inert(sim.params):
-            pairs = [strip_repair(s) for s in states]
-            states, saved = [p[0] for p in pairs], [p[1] for p in pairs]
         stacked = tree(lambda *xs: jnp.stack(xs), *states)
         att = jnp.stack(attackers)
         (stacked, att), shared = place_trial_batch(
@@ -1010,8 +997,6 @@ def _attack_windows(sim: Simulator, attackers, states, adv, steps: int,
             if adaptive:
                 st, c = st
                 ctrls.append(c)
-            if saved is not None:
-                st = restore_repair(st, saved[j])
             outs.append(st)
         return outs, [{k: v[j] for k, v in obs_np.items()}
                       for j in range(s_count)], ctrls
@@ -1062,9 +1047,7 @@ def _try_resume(sim: Simulator, cfg: CampaignConfig, fraction: float,
     trusted."""
     import json
 
-    from flax import serialization
-
-    from .checkpoint import FORMAT_VERSION, _graph_hash
+    from .checkpoint import FORMAT_VERSION, _graph_hash, restore_state
 
     ck, sc = _trial_ckpt(cfg, fraction, seed)
     if not (os.path.exists(ck) and os.path.exists(sc)):
@@ -1076,9 +1059,7 @@ def _try_resume(sim: Simulator, cfg: CampaignConfig, fraction: float,
             return None
         if meta.get("graph_sha256") != _graph_hash(sim.graph):
             return None
-        sd = {k.split("/", 1)[1]: z[k]
-              for k in z.files if k.startswith("state/")}
-        state = serialization.from_state_dict(sim.state, sd)
+        state = restore_state(sim.state, z, meta["version"])
         zo = np.load(sc)
         obs = {k: np.asarray(zo[k]) for k in zo.files}
     except Exception:
@@ -1099,7 +1080,7 @@ def _recovery_windows_sharded(sim: Simulator, cfg: CampaignConfig,
     tree = jax.tree_util.tree_map
     t_count = len(states)
     states, attackers, local = _pad_to_groups(states, attackers, trial_mesh)
-    stacked = tree(lambda *xs: jnp.stack(xs), *states)
+    stacked = arm_repair(tree(lambda *xs: jnp.stack(xs), *states))
     att = jnp.stack(attackers)
     rparams = cfg.repair.apply(sim.params)
     outs, obs = sharded_recovery_window(
@@ -1140,7 +1121,7 @@ def _dht_recovery_windows_sharded(sim: Simulator, cfg: CampaignConfig,
     pairs = list(zip(pools_a, pools_b))
     states, attackers, pairs, local = _pad_to_groups(
         states, attackers, trial_mesh, extras=pairs)
-    stacked = tree(lambda *xs: jnp.stack(xs), *states)
+    stacked = arm_repair(tree(lambda *xs: jnp.stack(xs), *states))
     att = jnp.stack(attackers)
     (stacked, att), shared = place_trial_batch(
         (stacked, att), sim.arrays, trial_mesh, n_rows=sim.params.n)
@@ -1337,6 +1318,7 @@ def _attacked_trials(
             os.replace(tmp, sc)
         obs_j = obs_by_seed[s]
         recovery_time_ms = -1.0
+        repaired = repair_totals(sim.state)
         if cfg.recovery_heartbeats > 0:
             # post-attack repair window. The checkpoint above snapshots the
             # post-window/pre-repair state against the EPOCH graph (whose
@@ -1345,55 +1327,59 @@ def _attacked_trials(
 
             if recov is not None:
                 (st2, cn2, rv2, om2), robs = recov[j]
-            elif dht_on:
-                # two-leg window: attacked pool, then (optionally) healed
-                # pool resuming the same trial's dialed graph
-                rparams = cfg.repair.apply(sim.params)
-                a = sim.arrays
-                _, pool_a, pool_b, _ = kad_ctx[s]
-                st2, cn2, rv2, om2 = (sim.state, a["conns"], a["rev"],
-                                      a["out_mask"])
-                leg_obs = []
-                ctrl2 = ctrl_by_seed.get(s)
-                for leg_steps, pool in ((steps1, pool_a),
-                                        (steps2, pool_b)):
-                    if leg_steps <= 0:
-                        continue
-                    if adaptive:
-                        # the controller carry crosses the heal edge: the
-                        # attacker keeps its violation estimate while the
-                        # DHT under it heals
-                        carry, lobs = run_adaptive_recovery_heartbeats(
-                            st2, cn2, rv2, om2, att_j, rparams, leg_steps,
-                            adv=adv, ctrl=ctrl2, dht_pool=pool,
-                            publisher=pub, telemetry=tel)
-                        st2, ctrl2, cn2, rv2, om2 = carry[:5]
-                    else:
-                        carry, lobs = run_dht_recovery_heartbeats(
-                            st2, cn2, rv2, om2, att_j, rparams, leg_steps,
-                            dht_pool=pool, publisher=pub, telemetry=tel)
-                        st2, cn2, rv2, om2 = carry[:4]
-                    leg_obs.append(lobs)
-                robs = jax.tree_util.tree_map(
-                    lambda *xs: np.concatenate(
-                        [np.asarray(x) for x in xs], axis=0), *leg_obs)
-            elif adaptive:
-                rparams = cfg.repair.apply(sim.params)
-                a = sim.arrays
-                carry, robs = run_adaptive_recovery_heartbeats(
-                    sim.state, a["conns"], a["rev"], a["out_mask"], att_j,
-                    rparams, cfg.recovery_heartbeats, adv=adv,
-                    ctrl=ctrl_by_seed.get(s), publisher=pub, telemetry=tel)
-                st2, _, cn2, rv2, om2 = carry
             else:
+                # the one transition of a state's layout: the attack window
+                # ran inert params over a state without repair leaves, the
+                # recovery window arms repair over it
                 rparams = cfg.repair.apply(sim.params)
                 a = sim.arrays
-                (st2, cn2, rv2, om2), robs = run_recovery_heartbeats(
-                    sim.state, a["conns"], a["rev"], a["out_mask"], att_j,
-                    rparams, cfg.recovery_heartbeats, publisher=pub,
-                    telemetry=tel)
+                st2, cn2, rv2, om2 = (arm_repair(sim.state), a["conns"],
+                                      a["rev"], a["out_mask"])
+                if dht_on:
+                    # two-leg window: attacked pool, then (optionally) healed
+                    # pool resuming the same trial's dialed graph
+                    _, pool_a, pool_b, _ = kad_ctx[s]
+                    leg_obs = []
+                    ctrl2 = ctrl_by_seed.get(s)
+                    for leg_steps, pool in ((steps1, pool_a),
+                                            (steps2, pool_b)):
+                        if leg_steps <= 0:
+                            continue
+                        if adaptive:
+                            # the controller carry crosses the heal edge: the
+                            # attacker keeps its violation estimate while the
+                            # DHT under it heals
+                            carry, lobs = run_adaptive_recovery_heartbeats(
+                                st2, cn2, rv2, om2, att_j, rparams, leg_steps,
+                                adv=adv, ctrl=ctrl2, dht_pool=pool,
+                                publisher=pub, telemetry=tel)
+                            st2, ctrl2, cn2, rv2, om2 = carry[:5]
+                        else:
+                            carry, lobs = run_dht_recovery_heartbeats(
+                                st2, cn2, rv2, om2, att_j, rparams, leg_steps,
+                                dht_pool=pool, publisher=pub, telemetry=tel)
+                            st2, cn2, rv2, om2 = carry[:4]
+                        leg_obs.append(lobs)
+                    robs = jax.tree_util.tree_map(
+                        lambda *xs: np.concatenate(
+                            [np.asarray(x) for x in xs], axis=0), *leg_obs)
+                elif adaptive:
+                    carry, robs = run_adaptive_recovery_heartbeats(
+                        st2, cn2, rv2, om2, att_j, rparams,
+                        cfg.recovery_heartbeats, adv=adv,
+                        ctrl=ctrl_by_seed.get(s), publisher=pub, telemetry=tel)
+                    st2, _, cn2, rv2, om2 = carry
+                else:
+                    (st2, cn2, rv2, om2), robs = run_recovery_heartbeats(
+                        st2, cn2, rv2, om2, att_j, rparams,
+                        cfg.recovery_heartbeats, publisher=pub, telemetry=tel)
             robs = jax.tree_util.tree_map(np.asarray, robs)
-            sim.state = st2
+            # and back: the publish schedule below runs sim.params, and on
+            # a state of their layout it runs the program the baseline
+            # trial compiled
+            repaired = repair_totals(st2)
+            sim.state = (disarm_repair(st2) if repair_inert(sim.params)
+                         else st2)
             if not graph_static:
                 sim.rebind_graph(cn2, rv2, om2)
             # concatenate the shared observables: engagement/recovery
@@ -1483,9 +1469,9 @@ def _attacked_trials(
             attacker_mesh_share_final=share_final,
             attacker_score_final=score_final,
             wall_s=(time.time() - t0) / len(seeds),
-            mesh_evictions_total=int(np.asarray(sim.state.evictions).sum()),
-            px_grafts_total=int(np.asarray(sim.state.px_grafts).sum()),
-            redials_total=int(np.asarray(sim.state.redials).sum()),
+            mesh_evictions_total=repaired["evictions"],
+            px_grafts_total=repaired["px_grafts"],
+            redials_total=repaired["redials"],
             recovery_time_ms=recovery_time_ms,
             bytes_tx_total=float(np.asarray(sim.state.bytes_tx).sum()),
             heal_time_ms=heal_time_ms,
@@ -2068,7 +2054,6 @@ def _episub_windows(sim: Simulator, ep, attackers, states, ctrls, adv,
 
     from ..ops.episub import (run_episub_adaptive_heartbeats,
                               run_episub_faulted_heartbeats)
-    from ..ops.state import repair_inert, restore_repair, strip_repair
 
     tree = jax.tree_util.tree_map
     a = sim.arrays
@@ -2107,11 +2092,6 @@ def _episub_windows(sim: Simulator, ep, attackers, states, ctrls, adv,
 
         states, attackers, ctrls, local = _pad_to_groups(
             states, attackers, trial_mesh, extras=ctrls)
-        # strip host-side ONCE for the batch, same as _attack_windows
-        saved = None
-        if repair_inert(sim.params):
-            pairs = [strip_repair(s) for s in states]
-            states, saved = [p[0] for p in pairs], [p[1] for p in pairs]
         stacked = tree(lambda *xs: jnp.stack(xs), *states)
         ctk = tree(lambda *xs: jnp.stack(xs), *ctrls)
         att = jnp.stack(attackers)
@@ -2122,15 +2102,10 @@ def _episub_windows(sim: Simulator, ep, attackers, states, ctrls, adv,
             trial_mesh, local, telemetry=telemetry)
         o_states, o_ctrls = _unpack(out)
         obs_np = tree(np.asarray, obs)
-        sts, cts = [], []
-        for j in range(s_count):
-            st = _unstack_trial(tree, o_states, j)
-            if saved is not None:
-                st = restore_repair(st, saved[j])
-            sts.append(st)
-            cts.append(_unstack_trial(tree, o_ctrls, j))
-        return sts, cts, [{k: v[j] for k, v in obs_np.items()}
-                          for j in range(s_count)]
+        return ([_unstack_trial(tree, o_states, j) for j in range(s_count)],
+                [_unstack_trial(tree, o_ctrls, j) for j in range(s_count)],
+                [{k: v[j] for k, v in obs_np.items()}
+                 for j in range(s_count)])
     if s_count == 1:
         out, obs = run_episub_adaptive_heartbeats(
             states[0], ctrls[0], a["conns"], a["rev"], a["out_mask"],

@@ -36,7 +36,12 @@ from ..config.env import GossipSubParams
 from ..config.topology import Topology, TopoParams
 from .simulator import ExperimentConfig, MessageRecord, Simulator
 
-FORMAT_VERSION = 10  # bump on any SimState layout change (v10: resident
+FORMAT_VERSION = 11  # bump on any SimState layout change (v11: the
+#                     mesh-repair leaves are in a snapshot exactly where the
+#                     state held them, a run with repair armed; a v8..v10
+#                     snapshot always holds them, as init_state made them
+#                     wherever repair was inert, and the loader leaves them
+#                     out (restore_state); v10: resident
 #                     service mode — snapshots may carry a `service_json`
 #                     sidecar (pending publish queue + counters, read only
 #                     by NodeService.restore) and a meta "kind" that extends
@@ -50,9 +55,7 @@ FORMAT_VERSION = 10  # bump on any SimState layout change (v10: resident
 #                     loader IGNORES them (campaign resume re-derives the
 #                     DHT deterministically from (seed, dht config)), so
 #                     v8 snapshots load unchanged; v8: mesh-repair
-#                     leaves px_pool/starve_hb/evictions/px_grafts/redials —
-#                     older snapshots load with an empty PX pool and zeroed
-#                     repair counters, exactly a fresh run's repair state;
+#                     leaves px_pool/starve_hb/evictions/px_grafts/redials;
 #                     v7: warm_offset_ms cross-publish warm-start carry,
 #                     defaulted to INF = "no usable carry"; v6 added
 #                     per-record answer_wait_max_ms, read tolerantly)
@@ -163,7 +166,8 @@ def save_checkpoint(sim, path: str, kad_state=None,
     arrays["meta_json"] = np.frombuffer(
         json.dumps(meta, allow_nan=False).encode(), dtype=np.uint8)
     for k, v in serialization.to_state_dict(sim.state).items():
-        arrays[f"state/{k}"] = np.asarray(v)
+        if v is not None:  # a repair leaf of a state that holds none
+            arrays[f"state/{k}"] = np.asarray(v)
     topo = sim.topology
     for k in _TOPO_KEYS:
         arrays[f"topo/{k}"] = np.asarray(getattr(topo, k))
@@ -182,6 +186,31 @@ def save_checkpoint(sim, path: str, kad_state=None,
     os.replace(tmp, path)
 
 
+def restore_state(state, z, version: int):
+    """The `state/` leaves of the open snapshot `z` restored into `state`,
+    the fresh one of the rebuilt simulator (made for inert repair: no
+    config arms it). A v11 snapshot holds the repair leaves exactly where
+    the run had armed repair: they are restored, over arm_repair's. Up to
+    v10 every snapshot held them and almost every one as init_state made
+    them: left out."""
+    from flax import serialization
+
+    from ..ops.state import REPAIR_LEAVES, arm_repair
+
+    sd = {k.split("/", 1)[1]: z[k] for k in z.files if k.startswith("state/")}
+    if version >= 11 and "px_pool" in sd:
+        state = arm_repair(state)
+    else:
+        sd.update(dict.fromkeys(REPAIR_LEAVES))
+    if "warm_offset_ms" not in sd:
+        # pre-v7 snapshot: no warm-start carry was recorded. INF = "no
+        # usable carry" — the next publish simply runs cold, identical to
+        # a fresh run's first message.
+        sd["warm_offset_ms"] = np.full(
+            state.warm_offset_ms.shape, 3.4e38, dtype=np.float32)
+    return serialization.from_state_dict(state, sd)
+
+
 def load_service_meta(path: str) -> dict:
     """Read the resident-service sidecar out of a checkpoint; {} when the
     snapshot was written without one (plain sim checkpoints)."""
@@ -198,16 +227,13 @@ def load_checkpoint(path: str, mesh=None) -> Simulator:
     `mesh`: re-shard the restored state over this device mesh (a sharded
     run does NOT remember its mesh — device topology is a property of the
     resuming host, not of the experiment)."""
-    from flax import serialization
-
     z = np.load(path)
     meta = json.loads(bytes(z["meta_json"]).decode())
-    if meta["version"] not in (5, 6, 7, 8, 9, FORMAT_VERSION):
-        # v5..v9 differ only by absent leaves with safe fresh-run defaults:
-        # per-record answer_wait (record reader), the warm-start carry
-        # (INF below), the mesh-repair leaves (empty pool / zero
-        # counters below), v9's write-only kad/* extras, and v10's
-        # service sidecar / multitopic kind — accept all
+    if meta["version"] not in (5, 6, 7, 8, 9, 10, FORMAT_VERSION):
+        # v5..v10 differ only by leaves with safe fresh-run defaults:
+        # per-record answer_wait (record reader), the warm-start carry and
+        # the mesh-repair leaves (restore_state), v9's write-only kad/*
+        # extras, and v10's service sidecar / multitopic kind — accept all
         raise ValueError(
             f"checkpoint format {meta['version']} != supported {FORMAT_VERSION}"
         )
@@ -231,26 +257,7 @@ def load_checkpoint(path: str, mesh=None) -> Simulator:
             "changed between save and load; the restored edge-slot state "
             "would silently refer to different edges."
         )
-    state_dict = {
-        k.split("/", 1)[1]: z[k] for k in z.files if k.startswith("state/")
-    }
-    if "warm_offset_ms" not in state_dict:
-        # pre-v7 snapshot: no warm-start carry was recorded. INF = "no
-        # usable carry" — the next publish simply runs cold, identical to
-        # a fresh run's first message.
-        state_dict["warm_offset_ms"] = np.full(
-            (cfg.topo.network_size,), 3.4e38, dtype=np.float32)
-    n = cfg.topo.network_size
-    if "px_pool" not in state_dict:
-        # pre-v8 snapshot: no mesh-repair subsystem. Empty PX pool + zero
-        # starvation/activity counters = a fresh run's repair state.
-        from ..ops.state import PX_POOL_WIDTH
-
-        state_dict["px_pool"] = np.full((n, PX_POOL_WIDTH), -1,
-                                        dtype=np.int32)
-        for k in ("starve_hb", "evictions", "px_grafts", "redials"):
-            state_dict[k] = np.zeros((n,), dtype=np.int32)
-    sim.state = serialization.from_state_dict(sim.state, state_dict)
+    sim.state = restore_state(sim.state, z, meta["version"])
     # the publish-path fanout decision reads a host mirror of subscription
     sim._subscribed_np = np.asarray(sim.state.subscribed).copy()
     sim._sub_events_np = np.asarray(z["host/sub_events"]).copy()
@@ -278,8 +285,6 @@ def _load_multitopic(z, meta: dict, mesh):
     """kind="multitopic" restore path: same contract as the single-topic
     branch — rebuild from config, verify the physical graph hash, replace
     the stacked state leaves, restore the host extras."""
-    from flax import serialization
-
     from .multitopic import MultiTopicConfig, MultiTopicSimulator
 
     cfg_d = dict(meta["cfg"])
@@ -297,10 +302,7 @@ def _load_multitopic(z, meta: dict, mesh):
             f"(sha256 {got[:12]}…) differs from the one the checkpoint was "
             f"written against ({want[:12]}…)."
         )
-    state_dict = {
-        k.split("/", 1)[1]: z[k] for k in z.files if k.startswith("state/")
-    }
-    sim.state = serialization.from_state_dict(sim.state, state_dict)
+    sim.state = restore_state(sim.state, z, meta["version"])
     sim.subscribed_np = np.asarray(z["host/subscribed_np"]).copy()
     if mesh is not None:
         from ..parallel.sharding import shard_simulation

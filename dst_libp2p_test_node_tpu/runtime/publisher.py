@@ -125,8 +125,7 @@ def publish_batch_scan(state, conns, rev, stage, lat_ms, bw, rows, active,
     bit-identical to publishing the active columns sequentially: each column
     sees the previous column's key split, warm-offset advance, and uplink/rx
     occupancy exactly as publish() would. Returns (ys, new_state) where each
-    ys leaf is stacked along the batch axis. Callers strip repair-inert
-    fields first (runtime/simulator.py does).
+    ys leaf is stacked along the batch axis.
     """
     global _batch_scan_jit
     if _batch_scan_jit is None:

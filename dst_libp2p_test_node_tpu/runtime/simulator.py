@@ -781,15 +781,6 @@ class Simulator:
                         uplink_free_ms=uplink_new, rx_free_ms=rx_new,
                     )
                     publisher = int(exit_node)
-                # strip the mesh-repair leaves around the publish jit when no knob
-                # is armed: disseminate never touches them, and carrying them as
-                # passthrough outputs cost the r05 bench a copy of all 5 buffers
-                # per publish (ops/state.py strip_repair)
-                from ..ops.state import repair_inert, restore_repair, strip_repair
-
-                saved = None
-                if repair_inert(self.params):
-                    self.state, saved = strip_repair(self.state)
             # enqueue only (trace + compile on a first call)
             with span("publish/dispatch"):
                 res, self.state = disseminate(
@@ -816,8 +807,6 @@ class Simulator:
                     # unsubscribed publisher -> gossipsub v1.1 fanout publish
                     with_fanout=not bool(self._subscribed_np[publisher]),
                 )
-                if saved is not None:
-                    self.state = restore_repair(self.state, saved)
             # every device->host read: the host waits for the publish here
             with span("publish/read"):
                 if cfg.msgid_mode == "go":
@@ -920,20 +909,14 @@ class Simulator:
         active = np.zeros(width, dtype=bool)
         active[:b] = True
 
-        from ..ops.state import repair_inert, restore_repair, strip_repair
         from .publisher import publish_batch_scan
 
-        saved = None
-        if repair_inert(self.params):
-            self.state, saved = strip_repair(self.state)
         ys, self.state = publish_batch_scan(
             self.state, a["conns"], a["rev"], self._stage, self._lat,
             self._bw, rows, active, t0_ms, self.params, size,
             cfg.topo.num_frags, cfg.with_gossip, self._loss, cfg.loss_mode,
             self._lat_edge, self._loss_edge, self._ans_tables,
             self._valid_edge, with_fanout)
-        if saved is not None:
-            self.state = restore_repair(self.state, saved)
 
         ys_np = {k: np.asarray(v) for k, v in ys.items()}
         recs = []

@@ -45,7 +45,6 @@ from dst_libp2p_test_node_tpu.ops.state import (
     graph_arrays,
     init_adaptive_ctrl,
     init_state,
-    strip_repair,
 )
 from dst_libp2p_test_node_tpu.parallel.sharding import make_trial_mesh
 from dst_libp2p_test_node_tpu.runtime.campaign import (
@@ -189,8 +188,7 @@ def test_armed_controller_counters_engage_and_stay_on_the_cohort():
 
 def _stacked_fixture(trials=4, fraction=0.2):
     params, _, a = _op_fixture()
-    states = [strip_repair(init_state(params, seed=s))[0]
-              for s in range(trials)]
+    states = [init_state(params, seed=s) for s in range(trials)]
     stacked = jax.tree_util.tree_map(
         lambda *xs: jnp.stack([jnp.asarray(x) for x in xs]), *states)
     att = jnp.stack([

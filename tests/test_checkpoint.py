@@ -231,6 +231,59 @@ def test_pre_v7_checkpoint_loads_with_inf_carry(tmp_path):
     np.testing.assert_array_equal(a.delays_ms, b.delays_ms)
 
 
+@pytest.mark.parametrize("case", ["v10_inert", "v11_armed"])
+def test_repair_leaves_across_a_checkpoint(midpoint, tmp_path, case):
+    # a state holds the mesh-repair leaves only where repair is armed
+    # (ops/state.py), and a snapshot what the state held. v10_inert: until
+    # v10 every snapshot held the five, as init_state made them wherever
+    # repair was off; such a file still loads, into a state without them,
+    # and resumes bit-exactly. v11_armed: a state that counted repairs keeps
+    # its pool and counters through save and load
+    import json
+
+    from dst_libp2p_test_node_tpu.ops.state import (
+        REPAIR_LEAVES, arm_repair, repair_totals)
+
+    _sim, path, _ = midpoint
+    z = np.load(path)
+    assert not any(f"state/{k}" in z.files for k in REPAIR_LEAVES)
+    restored = load_checkpoint(path)
+    assert all(getattr(restored.state, k) is None for k in REPAIR_LEAVES)
+    armed = arm_repair(restored.state)
+    out = str(tmp_path / f"{case}.npz")
+    if case == "v10_inert":
+        meta = json.loads(bytes(z["meta_json"]).decode())
+        meta["version"] = 10
+        arrays = {k: z[k] for k in z.files if k != "meta_json"}
+        arrays.update({f"state/{k}": np.asarray(getattr(armed, k))
+                       for k in REPAIR_LEAVES})
+        arrays["meta_json"] = np.frombuffer(
+            json.dumps(meta).encode(), dtype=np.uint8)
+        np.savez_compressed(out, **arrays)
+        again = load_checkpoint(out)
+        assert all(getattr(again.state, k) is None for k in REPAIR_LEAVES)
+        assert repair_totals(again.state) == {
+            "evictions": 0, "px_grafts": 0, "redials": 0}
+        a, b = _finish(restored), _finish(again)
+        np.testing.assert_array_equal(a.received, b.received)
+        np.testing.assert_array_equal(a.delays_ms, b.delays_ms)
+    else:
+        restored.state = armed.replace(
+            px_pool=armed.px_pool.at[3, 0].set(7),
+            starve_hb=armed.starve_hb.at[5].set(2),
+            evictions=armed.evictions.at[3].set(4),
+            px_grafts=armed.px_grafts.at[9].set(1),
+            redials=armed.redials.at[11].set(6))
+        save_checkpoint(restored, out)
+        again = load_checkpoint(out)
+        for k in REPAIR_LEAVES:
+            np.testing.assert_array_equal(
+                np.asarray(getattr(again.state, k)),
+                np.asarray(getattr(restored.state, k)), err_msg=k)
+        assert repair_totals(again.state) == {
+            "evictions": 4, "px_grafts": 1, "redials": 6}
+
+
 def test_restored_valid_edge_tracks_restored_subscriptions(tmp_path):
     # the publish path hoists a validity mask from alive&subscribed at
     # construction; load_checkpoint replaces the state AFTER construction,

@@ -145,15 +145,14 @@ def test_heartbeat_scan_compiles_for_v5e(one_chip, capacity, churn):
     and the dense pull it falls back to are both in the loop's body."""
     from dst_libp2p_test_node_tpu.ops import pull
     from dst_libp2p_test_node_tpu.ops.heartbeat import _run_heartbeats
-    from dst_libp2p_test_node_tpu.ops.state import (
-        SimParams, init_state, strip_repair)
+    from dst_libp2p_test_node_tpu.ops.state import SimParams, init_state
 
     assert pull.sparse_route((N, capacity))
     params = SimParams(n=N, capacity=capacity, churn_down_per_hb=churn,
                        churn_up_per_hb=churn / 2)
     state = jax.tree_util.tree_map(
         lambda s: one_chip(s.shape, s.dtype),
-        jax.eval_shape(lambda: strip_repair(init_state(params, seed=0))[0]))
+        jax.eval_shape(lambda: init_state(params, seed=0)))
     compiled = _run_heartbeats.lower(
         state, one_chip((N, capacity), jnp.int32),
         one_chip((N, capacity), jnp.int32), one_chip((N, capacity), jnp.bool_),

@@ -26,7 +26,7 @@ from dst_libp2p_test_node_tpu.ops.graph import build_connection_graph
 from dst_libp2p_test_node_tpu.ops.heartbeat import (
     _run_heartbeats, run_heartbeats)
 from dst_libp2p_test_node_tpu.ops.state import (
-    SimParams, graph_arrays, init_state, strip_repair)
+    SimParams, graph_arrays, init_state)
 from dst_libp2p_test_node_tpu.runtime.simulator import (
     ExperimentConfig, PublisherDownError, Simulator, scheduled_publishers)
 
@@ -263,7 +263,6 @@ def test_run_without_churn_has_no_churn_block(tmp_path, capsys):
 def _lowered(churn, debug_info=False):
     params, state, a = _network(n=300, seed=1, churn_down_per_hb=churn,
                                 churn_up_per_hb=churn / 2)
-    state, _ = strip_repair(state)
     scan = _run_heartbeats.lower(
         state, a["conns"], a["rev"], a["out_mask"], params, 7)
     stage = jnp.zeros((params.n,), jnp.int32)

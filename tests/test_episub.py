@@ -36,7 +36,6 @@ from dst_libp2p_test_node_tpu.ops.state import (
     SimParams,
     graph_arrays,
     init_state,
-    strip_repair,
 )
 from dst_libp2p_test_node_tpu.parallel.sharding import (
     make_trial_mesh,
@@ -149,9 +148,8 @@ def test_sharded_window_equals_per_trial_runs(groups):
         steps) for st, ct, at in zip(states, ctrls, atts)]
 
     mesh = make_trial_mesh(groups)
-    stripped = [strip_repair(s)[0] for s in states]
     tree = jax.tree_util.tree_map
-    stacked = tree(lambda *xs: jnp.stack(xs), *stripped)
+    stacked = tree(lambda *xs: jnp.stack(xs), *states)
     ctk = tree(lambda *xs: jnp.stack(xs), *ctrls)
     att = jnp.stack(atts)
     (stacked, ctk, att), shared = place_trial_batch(
@@ -161,10 +159,9 @@ def test_sharded_window_equals_per_trial_runs(groups):
 
     for j in range(trials):
         (rs, rc, _ra), ro = ref[j]
-        rs_stripped = strip_repair(rs)[0]
         sj = tree(lambda x, j=j: np.asarray(x[j]), o_states)
         cj = tree(lambda x, j=j: np.asarray(x[j]), o_ctrls)
-        for (la, lb) in zip(jax.tree_util.tree_leaves(rs_stripped),
+        for (la, lb) in zip(jax.tree_util.tree_leaves(rs),
                             jax.tree_util.tree_leaves(sj)):
             np.testing.assert_allclose(
                 np.asarray(la), np.asarray(lb), rtol=1e-5, atol=1e-6,

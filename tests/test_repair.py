@@ -41,7 +41,9 @@ from dst_libp2p_test_node_tpu.ops.repair import (
 )
 from dst_libp2p_test_node_tpu.ops.state import (
     PX_POOL_WIDTH,
+    REPAIR_LEAVES,
     SimParams,
+    arm_repair,
     graph_arrays,
     init_state,
 )
@@ -95,15 +97,22 @@ def test_armed_but_unfired_eviction_is_bit_identical():
     p_ev = dataclasses.replace(p_base, evict=True, eviction_threshold=-50.0)
     s_base = run_heartbeats(state, a["conns"], a["rev"], a["out_mask"],
                             p_base, 10)
-    s_ev = run_heartbeats(state, a["conns"], a["rev"], a["out_mask"],
-                          p_ev, 10)
-    _leaves_equal(s_base, s_ev)
+    s_ev = run_heartbeats(arm_repair(state), a["conns"], a["rev"],
+                          a["out_mask"], p_ev, 10)
+    _leaves_equal(s_base, s_ev, skip=REPAIR_LEAVES)
+    # and the leaves the armed scan threaded are as arm_repair made them
+    _leaves_equal(arm_repair(s_base), s_ev)
 
 
 def test_default_run_leaves_repair_state_untouched():
     p, state, a = _net(**ARMED)
     s = run_heartbeats(state, a["conns"], a["rev"], a["out_mask"], p, 10)
-    assert np.asarray(s.px_pool).max() == -1       # pool never written
+    # a default run has no repair state to touch: armed afterwards, it is
+    # a fresh run's (pool never written, nothing counted)
+    assert all(getattr(s, leaf) is None for leaf in REPAIR_LEAVES)
+    s = arm_repair(s)
+    assert np.asarray(s.px_pool).shape == (p.n, PX_POOL_WIDTH)
+    assert np.asarray(s.px_pool).max() == -1
     for leaf in ("starve_hb", "evictions", "px_grafts", "redials"):
         assert np.asarray(getattr(s, leaf)).sum() == 0, leaf
 
@@ -124,7 +133,7 @@ def test_graylist_curve_bit_equal_eviction_on_and_off():
     p_off, state, a = _net(**ARMED)
     p_on = dataclasses.replace(p_off, evict=True, eviction_threshold=-50.0)
     att, s_off, obs_off = _attacked(p_off, state, a)
-    _att, s_on, obs_on = _attacked(p_on, state, a)
+    _att, s_on, obs_on = _attacked(p_on, arm_repair(state), a)
     # the accrual cadence is identical (backoff replaces mesh in the
     # violation predicate) -> same penalties, same scores, bit-equal curves
     np.testing.assert_array_equal(
@@ -145,8 +154,8 @@ def test_simulated_engagement_matches_budget_both_modes():
     budget = heartbeats_to_graylist(AdversaryParams(), p_off)
     assert budget == heartbeats_to_graylist(AdversaryParams(), p_on)
     assert math.isfinite(budget)
-    for p in (p_off, p_on):
-        _att, _s, obs = _attacked(p, state, a)
+    for p, st in ((p_off, state), (p_on, arm_repair(state))):
+        _att, _s, obs = _attacked(p, st, a)
         gf = obs["graylisted_frac"]
         hits = np.nonzero(gf >= 1.0)[0]
         assert hits.size, "defense never fully engaged"
@@ -357,24 +366,25 @@ def test_checkpoint_v7_loads_with_fresh_repair_state(tmp_path):
     path = tmp_path / "ck.npz"
     save_checkpoint(sim, str(path))
 
-    # doctor the snapshot into a pre-repair v7 one: drop the new leaves
+    # doctor the snapshot into a pre-repair v7 one: a run with repair off
+    # holds no repair leaf, as a v7 snapshot held none
     z = dict(np.load(str(path), allow_pickle=False))
     meta = json.loads(bytes(z["meta_json"]).decode())
     meta["version"] = 7
     z["meta_json"] = np.frombuffer(
         json.dumps(meta, allow_nan=False).encode(), dtype=np.uint8)
-    for k in ("state/px_pool", "state/starve_hb", "state/evictions",
-              "state/px_grafts", "state/redials"):
-        z.pop(k)
+    assert not any(f"state/{k}" in z for k in REPAIR_LEAVES)
     v7 = tmp_path / "ck_v7.npz"
     with open(v7, "wb") as f:
         np.savez_compressed(f, **z)
 
     sim2 = load_checkpoint(str(v7))
-    assert np.asarray(sim2.state.px_pool).shape == (32, PX_POOL_WIDTH)
-    assert np.asarray(sim2.state.px_pool).max() == -1
+    assert all(getattr(sim2.state, k) is None for k in REPAIR_LEAVES)
+    armed = arm_repair(sim2.state)
+    assert np.asarray(armed.px_pool).shape == (32, PX_POOL_WIDTH)
+    assert np.asarray(armed.px_pool).max() == -1
     for leaf in ("starve_hb", "evictions", "px_grafts", "redials"):
-        assert np.asarray(getattr(sim2.state, leaf)).sum() == 0, leaf
+        assert np.asarray(getattr(armed, leaf)).sum() == 0, leaf
     # the restored run still continues bit-exactly
     np.testing.assert_array_equal(
         np.asarray(sim.state.mesh_mask), np.asarray(sim2.state.mesh_mask))

@@ -32,7 +32,7 @@ from dst_libp2p_test_node_tpu.ops.adversary import (
 from dst_libp2p_test_node_tpu.ops.graph import build_connection_graph
 from dst_libp2p_test_node_tpu.ops.heartbeat import run_heartbeats
 from dst_libp2p_test_node_tpu.ops.state import (
-    SimParams, graph_arrays, init_state, strip_repair,
+    SimParams, graph_arrays, init_state,
 )
 from dst_libp2p_test_node_tpu.ops.telemetry import (
     TelemetryParams, run_recorded_heartbeats,
@@ -192,8 +192,7 @@ def test_attack_window_telemetry_only_grows_the_obs_dict():
 
 def _stacked_fixture(trials=4, fraction=0.2):
     params, _, a = _fixture(gossip_threshold=-10.0, publish_threshold=-20.0)
-    states = [strip_repair(init_state(params, seed=s))[0]
-              for s in range(trials)]
+    states = [init_state(params, seed=s) for s in range(trials)]
     stacked = jax.tree_util.tree_map(
         lambda *xs: jnp.stack([jnp.asarray(x) for x in xs]), *states)
     att = jnp.stack([

@@ -14,11 +14,11 @@ whole column onto one vmapped device program. The contracts pinned here:
     boundaries: a sweep checkpointed under one trial grid resumes under a
     different one (the checkpoint identity is the epoch-graph hash, which
     is grid-independent).
-  - the r05 dead-weight fix: with the repair subsystem off (the default),
-    the public heartbeat/adversary entrypoints carry the five repair
-    leaves AROUND the scan (strip_repair/restore_repair, ops/state.py),
-    not through it — the leaves come back as the SAME buffers, which is
-    impossible if they rode the scan carry.
+  - the r05 dead-weight fix: with the repair subsystem off (the default)
+    a state holds none of the five repair leaves (ops/state.py), so the
+    heartbeat and adversary scans have none to carry; a state made for
+    armed params threads them, and an armed trace over a state without
+    them fails by name.
 
 conftest.py forces 8 virtual CPU devices, so the 2- and 4-group meshes are
 real multi-device placements here.
@@ -30,19 +30,23 @@ import os
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
+from dst_libp2p_test_node_tpu.analysis.jaxpr_audit import iter_eqns
 from dst_libp2p_test_node_tpu.config.topology import TopoParams
 from dst_libp2p_test_node_tpu.ops.adversary import (
     AdversaryParams, attacker_cohort, run_attacked_heartbeats,
 )
 from dst_libp2p_test_node_tpu.ops.graph import build_connection_graph
-from dst_libp2p_test_node_tpu.ops.heartbeat import run_heartbeats
+from dst_libp2p_test_node_tpu.ops.heartbeat import (
+    _run_heartbeats, run_heartbeats,
+)
 from dst_libp2p_test_node_tpu.ops.repair import RepairParams
 from dst_libp2p_test_node_tpu.ops.faults import FaultParams
 from dst_libp2p_test_node_tpu.ops.state import (
-    REPAIR_LEAVES, SimParams, graph_arrays, init_state, repair_inert,
-    strip_repair,
+    PX_POOL_WIDTH, REPAIR_LEAVES, SimParams, arm_repair, graph_arrays,
+    init_state, repair_inert,
 )
 from dst_libp2p_test_node_tpu.parallel.sharding import (
     TRIAL_AXIS, make_trial_mesh, peers_per_group,
@@ -214,23 +218,48 @@ def _make_op_fixture(n=64, connect_to=8, seed=0, **over):
 def test_inert_repair_leaves_ride_around_the_scan():
     # the r05 regression: the five repair leaves ((N,8) px_pool and four
     # (N,) counters) rode every default scan carry as dead weight. With
-    # repair off the public wrapper must strip them before the jit and
-    # restore the ORIGINAL buffers after — object identity proves the scan
-    # never carried them
+    # repair off the state has none of them, so no scan can carry one
     params, state, a = _make_op_fixture()
     assert repair_inert(params)
-    out = run_heartbeats(state, a["conns"], a["rev"], a["out_mask"],
-                         params, 3)
-    for k in REPAIR_LEAVES:
-        assert getattr(out, k) is getattr(state, k), (
-            f"{k} was carried through the inert scan")
-    # an ARMED config must thread them through the scan (fresh buffers)
+    assert all(getattr(state, k) is None for k in REPAIR_LEAVES)
     armed = RepairParams(evict=True).apply(params)
     assert not repair_inert(armed)
-    out2 = run_heartbeats(state, a["conns"], a["rev"], a["out_mask"],
-                          armed, 3)
-    for k in REPAIR_LEAVES:
-        assert getattr(out2, k) is not getattr(state, k)
+    armed_state = init_state(armed, seed=0)
+    assert (len(jax.tree.leaves(armed_state))
+            == len(jax.tree.leaves(state)) + len(REPAIR_LEAVES))
+
+    def carried(st, p):
+        """(count of (N, 8) int32, count of (N,) int32) in the carry of the
+        scan `_run_heartbeats` traces to."""
+        jaxpr = jax.make_jaxpr(
+            lambda s: _run_heartbeats(s, a["conns"], a["rev"],
+                                      a["out_mask"], p, 3))(st)
+        scans = [e for e, _ in iter_eqns(jaxpr.jaxpr)
+                 if e.primitive.name == "scan"]
+        assert len(scans) == 1
+        e = scans[0]
+        nc = e.params["num_consts"]
+        carry = e.invars[nc:nc + e.params["num_carry"]]
+        avals = [(v.aval.shape, str(v.aval.dtype)) for v in carry]
+        return (avals.count(((params.n, PX_POOL_WIDTH), "int32")),
+                avals.count(((params.n,), "int32")))
+
+    pools, counters = carried(state, params)
+    assert pools == 0
+    # an ARMED state threads them through the scan: the pool and the four
+    # counters more
+    assert carried(armed_state, armed) == (1, counters + 4)
+    out = run_heartbeats(armed_state, a["conns"], a["rev"], a["out_mask"],
+                         armed, 3)
+    assert all(getattr(out, k) is not None for k in REPAIR_LEAVES)
+    # an armed trace over a state made inert fails where it is traced, and
+    # names the way out, which gives init_state's own leaves
+    with pytest.raises(ValueError, match="arm_repair"):
+        run_heartbeats(state, a["conns"], a["rev"], a["out_mask"], armed, 3)
+    for x, y in zip(jax.tree.leaves(arm_repair(state)),
+                    jax.tree.leaves(armed_state)):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+    assert arm_repair(armed_state) is armed_state
 
 
 def test_trial_mesh_full_grid_and_edge_cases():
@@ -258,10 +287,7 @@ def _stacked_attack_fixture(trials=4, fraction=0.2):
     params, _, a = _make_op_fixture(
         slow_weight=-10.0, slow_decay=0.9, graylist_threshold=-50.0,
         gossip_threshold=-10.0, publish_threshold=-20.0)
-    import jax
-
-    states = [strip_repair(init_state(params, seed=s))[0]
-              for s in range(trials)]
+    states = [init_state(params, seed=s) for s in range(trials)]
     stacked = jax.tree_util.tree_map(
         lambda *xs: jnp.stack([jnp.asarray(x) for x in xs]), *states)
     att = jnp.stack([
@@ -390,6 +416,7 @@ def test_inert_repair_leaves_stripped_from_attack_window():
     adv = AdversaryParams(scenario="sybil_graft_flood")
     out, _obs = run_attacked_heartbeats(
         state, a["conns"], a["rev"], a["out_mask"], att, params, adv, 3)
+    assert len(jax.tree.leaves(out)) == len(jax.tree.leaves(state))
     for k in REPAIR_LEAVES:
-        assert getattr(out, k) is getattr(state, k), (
+        assert getattr(out, k) is None, (
             f"{k} was carried through the attack-window scan")
