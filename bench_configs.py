@@ -21,6 +21,12 @@
      8192 x peers_per_group  [--only 8; never written to
      BENCH_CONFIGS.json]
 
+Rows 6-8 are this file's own sweeps on whatever devices it finds, with
+compiles inside the wall: not a chip's speed. The chip's numbers for the
+sybil sweep at 2,048 peers are the cell `attack-2k.sybil`'s (BENCHMARK.json;
+PERF.md sections 4 and 5, and the driver's lines in PERF_LEDGER.jsonl), not
+these rows'.
+
 Each config prints ONE JSON line: config id, peers, wall seconds,
 peers*rounds/sec, coverage, p50/p99 dissemination latency (ms). Run:
 

@@ -505,7 +505,14 @@ def validate_attack_flags(
 def cmd_attack(argv: list[str]) -> int:
     """Adversarial campaign driver: one scenario, a fraction x seed grid,
     resilience report + optional JSON/Prometheus artifacts."""
-    p = argparse.ArgumentParser(prog="attack")
+    p = argparse.ArgumentParser(
+        prog="attack",
+        description="One adversarial campaign: ONE network, a sweep of "
+        "attacker fractions x trial seeds. A trial at fraction 0 is the "
+        "benign experiment (and its seed's baseline); an attacked trial is "
+        "a cohort draw, the warm-up, --attack-heartbeats rounds of "
+        "[heartbeat, adversary] (the trials of one fraction as ONE vmapped "
+        "window), then the publish schedule with the attackers censoring.")
     from .ops.adversary import SCENARIOS
 
     p.add_argument("--scenario", choices=SCENARIOS,
@@ -642,6 +649,36 @@ def cmd_attack(argv: list[str]) -> int:
     p.add_argument("--metrics-out", default=None,
                    help="write Prometheus text exposition of the "
                    "dst_testnode_attack_* series here")
+    p.add_argument("--stats-json", default=None, metavar="PATH",
+                   help="write the campaign's numbers here: network_size, "
+                   "wall_s (the network's build and the campaign, from the "
+                   "spans), spans, compile and process as `run --stats-json` "
+                   "names them, and \"attack\": the campaign's counters and "
+                   "\"rows\", a row a trial. Counters: trials, attacked_trials; "
+                   "vmapped_windows (attack windows that ran a stack of "
+                   "trials as one program) and window_heartbeats (the "
+                   "heartbeats of every window dispatched, a stack's "
+                   "counted once); publishes (each read back); device_reads "
+                   "(device->host reads of the campaign: one a window for "
+                   "its observable curves, nine a publish (the clock, then "
+                   "eight leaves of the result one by one), three an "
+                   "attacked trial and one a benign one for the metrics; a "
+                   "supervised retry's are in it; the repair, "
+                   "DHT and checkpoint options read without counting); over "
+                   "the attacked trials honest_coverage_min, "
+                   "latency_inflation_max (honest p50 over the same seed's "
+                   "benign p50), hb_to_graylist_max (the window round by "
+                   "which 95 %% of honest->attacker edges were graylisted, "
+                   "-1 if a trial never got there: compare hb_budget, the "
+                   "closed form), graylisted_frac_final_min, "
+                   "attacker_mesh_share_peak (the largest attacker share "
+                   "of honest mesh edges any round of any window saw), "
+                   "attacker_score_final_mean. A row: fraction, seed, "
+                   "attackers, honest_coverage, latency_p50_ms, "
+                   "latency_p99_ms, benign_p50_ms, latency_inflation, "
+                   "hb_to_graylist, mesh_recovery_hb (first round after "
+                   "its peak at which the attacker mesh share is back "
+                   "under 5 %%)")
     a = p.parse_args(argv)
 
     def _window(spec: str, flag: str) -> tuple[int, int]:
@@ -657,8 +694,9 @@ def cmd_attack(argv: list[str]) -> int:
     from .ops.repair import RepairParams
     from .runtime.campaign import (
         CampaignConfig, SupervisorConfig, attack_gossipsub, run_campaign)
+    from .runtime.profiling import process_summary, span, turn
     from .runtime.simulator import ExperimentConfig
-    from .runtime.summarize import report_campaign
+    from .runtime.summarize import report_campaign, sanitize_nonfinite
 
     try:
         validate_attack_flags(
@@ -761,24 +799,52 @@ def cmd_attack(argv: list[str]) -> int:
             trial_mesh = make_trial_mesh(a.trial_groups or None)
         except ValueError as e:
             p.error(str(e))
-    t0 = time.time()
-    res = run_campaign(cfg, mesh=mesh, trial_mesh=trial_mesh)
-    wall = time.time() - t0
-    d = res.to_dict()
-    print(report_campaign(d), end="")
-    if a.json:
-        with open(a.json, "w") as f:
-            # strict JSON: non-finite metrics are already nulled by to_dict
-            json.dump(d, f, indent=2, allow_nan=False)
-    if a.metrics_out:
-        from .runtime.metrics import CampaignMetrics
+    # the turn's identifier is the campaign's seed; `seed` on a span is the
+    # trial's
+    with turn(campaign_seed=a.seed) as spans:
+        res = run_campaign(cfg, mesh=mesh, trial_mesh=trial_mesh)
+        # the program's one clock, from the spans: the build and the sweep
+        wall = sum(spans.seconds(name) for name in (
+            "run/topology", "run/simulator_init", "run/campaign"))
+        with span("run/summary"):
+            d = res.to_dict()
+        with span("run/report"):
+            print(report_campaign(d), end="")
+        if a.json:
+            with span("run/write_json"), open(a.json, "w") as f:
+                # strict JSON: non-finite metrics are already nulled by
+                # to_dict
+                json.dump(d, f, indent=2, allow_nan=False)
+        if a.metrics_out:
+            from .runtime.metrics import CampaignMetrics
 
-        m = CampaignMetrics()
-        m.fill_from_campaign(d)
-        with open(a.metrics_out, "w") as f:
-            f.write(m.render())
-    print(f"[tpu backend] wall={wall:.2f}s trials={len(res.trials)} "
-          f"trials/s={res.trials_per_s:.3f}")
+            m = CampaignMetrics()
+            m.fill_from_campaign(d)
+            with open(a.metrics_out, "w") as f:
+                f.write(m.render())
+        if a.stats_json:
+            rows = ("fraction", "seed", "attackers", "honest_coverage",
+                    "latency_p50_ms", "latency_p99_ms", "benign_p50_ms",
+                    "latency_inflation", "hb_to_graylist",
+                    "mesh_recovery_hb")
+            with span("run/stats_json"), open(a.stats_json, "w") as f:
+                json.dump(
+                    sanitize_nonfinite({
+                        "network_size": res.network_size,
+                        "wall_s": wall,
+                        "spans": spans.totals(),
+                        "compile": spans.compile.as_dict(),
+                        **({"process": process_summary()}
+                           if spans.number == 1 else {}),
+                        "attack": {
+                            **res.counters,
+                            "rows": [
+                                {k: t[k] for k in rows}
+                                for t in d["trials"]]},
+                    }),
+                    f, indent=2, allow_nan=False)
+        print(f"[tpu backend] wall={wall:.2f}s trials={len(res.trials)} "
+              f"trials/s={len(res.trials) / max(wall, 1e-9):.3f}")
     return 0
 
 

@@ -759,7 +759,10 @@ def run_attacked_heartbeats(
     counter and the mesh mid-scan, so per-round decay interleaving and the
     per-step mesh&valid AND are both load-bearing. The alive/subscribed
     neighbor pull still hoists when churn is off (the attack mutates
-    neither). Returns (state, obs) with obs leaves shaped (steps,).
+    neither). Returns (state, obs) with obs leaves shaped (steps,). Device
+    scopes of a step: `attack/heartbeat` (heartbeat_step, whose own scopes
+    nest under it) and `attack/adversary` (adversary_round with the
+    observables).
 
     No attack behavior touches the mesh-repair leaves, and an attack window
     with repair off (the common campaign case — repair arms only the
@@ -801,12 +804,17 @@ def _run_attacked_heartbeats(
     # scrub cadence); every other scenario scans over nothing, as before
     xs = jnp.arange(steps) if adv.identity_rotation else None
 
+    # device scopes (jax.named_scope: metadata only, no operation is added):
+    # a step's two halves as `attack/heartbeat`, under which heartbeat_step's
+    # own scopes nest, and `attack/adversary`
     def body(s, hb):
-        s = heartbeat_step(s, conns, rev, out_mask, params,
-                           batch_factor=batch_factor, nbr_ok=nbr_ok)
-        s, obs = adversary_round(s, conns, rev, attacker, params, adv,
-                                 batch_factor=batch_factor, nbr_ok=nbr_ok,
-                                 hb_idx=hb)
+        with jax.named_scope("attack"), jax.named_scope("heartbeat"):
+            s = heartbeat_step(s, conns, rev, out_mask, params,
+                               batch_factor=batch_factor, nbr_ok=nbr_ok)
+        with jax.named_scope("attack"), jax.named_scope("adversary"):
+            s, obs = adversary_round(
+                s, conns, rev, attacker, params, adv,
+                batch_factor=batch_factor, nbr_ok=nbr_ok, hb_idx=hb)
         if telemetry is not None:
             from .telemetry import telemetry_observables
 

@@ -76,6 +76,22 @@ sweep resumes per-trial (`_try_resume`, keyed on the epoch-graph hash)
 instead of restarting the campaign, including across trial-group
 boundaries of a sharded run.
 
+Spans and counters (runtime/profiling.py; noted inside a `turn`, which
+`cli.cmd_attack` opens): `run/topology` and `run/simulator_init` (the ONE
+network every trial shares), `run/campaign`, and under it a span a trial and
+phase with attributes `fraction`, `seed` and `vmapped`: `campaign/baseline`
+(a benign run of `_ensure_baseline`, whose phases nest in it),
+`trial/setup` (cohort draw, `_reset_trial`), `trial/warmup`, `trial/window`
+(ONE span a stack of trials, attribute `trials`), `trial/publish` (the
+schedule; `Simulator.publish`'s own spans nest in it), `trial/metrics`. A
+campaign ends with one zero-length annotation `sim:attack/counters`
+(`CampaignResult.counters`), which also says how many device->host reads the
+campaign made (`device_reads`: every read of the normal path is counted
+where it is made, the campaign's own and `Simulator.publish`'s through
+`profiling.device_read`, the eight leaves `record_from_result` takes of a
+publish's result one by one; the repair, DHT and checkpoint paths read
+without counting).
+
 Two-level device parallelism: `run_campaign(trial_mesh=...)` takes a 2-D
 (trials x peers) grid from parallel/sharding.make_trial_mesh and runs the
 STACKED TRIAL BATCH as one nested-sharded program — the trial axis splits
@@ -129,6 +145,7 @@ from ..ops.repair import (
 from ..ops.state import (arm_repair, disarm_repair, repair_inert,
                          repair_totals)
 from ..ops.telemetry import TelemetryParams
+from .profiling import counters, device_read, device_reads, span
 from .simulator import ExperimentConfig, MessageRecord, Simulator
 from .summarize import sanitize_nonfinite
 
@@ -419,6 +436,10 @@ class CampaignResult:
     # conformance certificate for this scenario (CampaignConfig.conformance;
     # analysis/conformance.py) — None when the gate wasn't requested
     conformance: dict | None = None
+    # what the campaign counted of itself (`_campaign_counters`; the
+    # `sim:attack/counters` annotation and `attack --stats-json`); not part
+    # of `to_dict`, so the campaign's JSON is what it was
+    counters: dict = field(default_factory=dict)
 
     @property
     def trials_per_s(self) -> float:
@@ -440,6 +461,51 @@ class CampaignResult:
 
 
 # --------------------------------------------------------------------- trials
+
+
+@dataclass
+class _Tally:
+    """What a campaign counts of itself while it runs, for
+    `_campaign_counters`: window dispatches and their heartbeats, publishes,
+    and each attacked trial's peak of the `attacker_mesh_share` curve. It
+    counts what was run, as `device_reads` does: an attempt the supervisor
+    retried is in it beside the retry."""
+    vmapped_windows: int = 0
+    window_heartbeats: int = 0
+    publishes: int = 0
+    mesh_share_peaks: list = field(default_factory=list)
+
+
+def _campaign_counters(trials: list, tally: _Tally, budget: float,
+                       reads: int) -> dict:
+    """The campaign's counters, from `TrialResult`'s fields and the window's
+    observable curves: nothing here reads the device. The resilience numbers
+    are over the attacked trials (over all where none is attacked);
+    `hb_to_graylist_max` is -1 where one of them never engaged."""
+    attacked = [t for t in trials if t.fraction > 0.0]
+    over = attacked or trials
+    engaged = [t.hb_to_graylist for t in attacked]
+
+    def of(fn, key):
+        return fn((getattr(t, key) for t in over), default=0.0)
+
+    return {
+        "trials": len(trials),
+        "attacked_trials": len(attacked),
+        "vmapped_windows": tally.vmapped_windows,
+        "window_heartbeats": tally.window_heartbeats,
+        "publishes": tally.publishes,
+        "device_reads": reads,
+        "honest_coverage_min": of(min, "honest_coverage"),
+        "latency_inflation_max": of(max, "latency_inflation"),
+        "hb_to_graylist_max": (-1 if not engaged or min(engaged) < 0
+                               else max(engaged)),
+        "hb_budget": budget,
+        "graylisted_frac_final_min": of(min, "graylisted_frac_final"),
+        "attacker_mesh_share_peak": max(tally.mesh_share_peaks, default=0.0),
+        "attacker_score_final_mean": float(np.mean(
+            [t.attacker_score_final for t in over] or [0.0])),
+    }
 
 
 def _reset_trial(sim: Simulator, seed: int) -> None:
@@ -480,7 +546,7 @@ def _publish_schedule(
             sim.advance(delay_ms)
         eff = censor
         if cross is not None and partition_ms is not None:
-            t_now = float(np.asarray(sim.state.t_ms))
+            t_now = float(device_read(sim.state.t_ms))
             if partition_ms[0] <= t_now < partition_ms[1]:
                 eff = cross if censor is None else (censor | cross)
         rec = sim.publish(pub, censor_edge=eff)
@@ -509,24 +575,36 @@ def _delivery_metrics(records: list[MessageRecord], honest: np.ndarray):
     return (cov, float(np.percentile(pool, 50)), float(np.percentile(pool, 99)))
 
 
-def _ensure_baseline(sim: Simulator, cache: dict, seed: int) -> dict:
+def _ensure_baseline(sim: Simulator, cache: dict, seed: int,
+                     tally: _Tally) -> dict:
     """Benign metrics for `seed` (the fraction-0.0 path), computed at most
     once per seed per campaign."""
     if seed not in cache:
-        _reset_trial(sim, seed)
-        sim.warmup()
-        records = _publish_schedule(sim)
-        honest = np.ones(sim.params.n, dtype=bool)
-        cov, p50, p99 = _delivery_metrics(records, honest)
+        at = dict(fraction=0.0, seed=seed, vmapped=False)
+        with span("campaign/baseline", **at):
+            with span("trial/setup", **at):
+                _reset_trial(sim, seed)
+            with span("trial/warmup", **at):
+                sim.warmup()
+            with span("trial/publish", **at):
+                records = _publish_schedule(sim)
+            with span("trial/metrics", **at):
+                honest = np.ones(sim.params.n, dtype=bool)
+                cov, p50, p99 = _delivery_metrics(records, honest)
+        tally.publishes += len(records)
         cache[seed] = {"coverage": cov, "p50": p50, "p99": p99}
     return cache[seed]
 
 
 def _benign_trial(sim: Simulator, cfg: CampaignConfig, seed: int,
-                  cache: dict, budget: float) -> TrialResult:
+                  cache: dict, budget: float, tally: _Tally) -> TrialResult:
     t0 = time.time()
     cache.pop(seed, None)  # force the run (the trial IS the baseline)
-    base = _ensure_baseline(sim, cache, seed)
+    base = _ensure_baseline(sim, cache, seed, tally)
+    with span("trial/metrics", fraction=0.0, seed=seed, vmapped=False):
+        # the forced _ensure_baseline run above leaves the benign trial's
+        # post-publish state bound — its byte counters ARE this trial's
+        bytes_tx = float(device_read(sim.state.bytes_tx).sum())
     return TrialResult(
         scenario=cfg.scenario, fraction=0.0, seed=seed, attackers=0,
         honest_coverage=base["coverage"], benign_coverage=base["coverage"],
@@ -536,9 +614,7 @@ def _benign_trial(sim: Simulator, cfg: CampaignConfig, seed: int,
         graylisted_frac_final=0.0, mesh_recovery_hb=-1,
         attacker_mesh_share_final=0.0, attacker_score_final=0.0,
         wall_s=time.time() - t0,
-        # the forced _ensure_baseline run above leaves the benign trial's
-        # post-publish state bound — its byte counters ARE this trial's
-        bytes_tx_total=float(np.asarray(sim.state.bytes_tx).sum()),
+        bytes_tx_total=bytes_tx,
     )
 
 
@@ -931,7 +1007,7 @@ def _attack_windows(sim: Simulator, attackers, states, adv, steps: int,
             stacked, shared, att, crs, sds, sps, sim.params, adv, faults,
             steps, trial_mesh, local, telemetry=telemetry,
             protocol=protocol)
-        obs_np = tree(np.asarray, obs)
+        obs_np = device_read(obs)
         outs, ctrls = [], ([] if adaptive else None)
         for j in range(s_count):
             st = _unstack_trial(tree, out_states, j)
@@ -951,7 +1027,7 @@ def _attack_windows(sim: Simulator, attackers, states, adv, steps: int,
         if adaptive:
             st, c = st
             ctrls = [c]
-        return [st], [tree(np.asarray, obs)], ctrls
+        return [st], [device_read(obs)], ctrls
     if faulted:
         s_count = len(states)
         stacked = tree(lambda *xs: jnp.stack(xs), *states)
@@ -970,7 +1046,7 @@ def _attack_windows(sim: Simulator, attackers, states, adv, steps: int,
         ctrl_stack = None
         if adaptive:
             out_states, ctrl_stack = out_states
-        obs_np = tree(np.asarray, obs)
+        obs_np = device_read(obs)
         return (
             [tree(lambda x, j=j: x[j], out_states) for j in range(s_count)],
             [{k: v[j] for k, v in obs_np.items()} for j in range(s_count)],
@@ -990,7 +1066,7 @@ def _attack_windows(sim: Simulator, attackers, states, adv, steps: int,
         out_states, obs = sharded_attack_window(
             stacked, shared, att, sim.params, adv, steps, trial_mesh, local,
             telemetry=telemetry, protocol=protocol)
-        obs_np = tree(np.asarray, obs)
+        obs_np = device_read(obs)
         outs, ctrls = [], ([] if adaptive else None)
         for j in range(s_count):
             st = _unstack_trial(tree, out_states, j)
@@ -1008,7 +1084,7 @@ def _attack_windows(sim: Simulator, attackers, states, adv, steps: int,
         if adaptive:
             st, c = st
             ctrls = [c]
-        return [st], [tree(np.asarray, obs)], ctrls
+        return [st], [device_read(obs)], ctrls
     s_count = len(states)
     stacked = tree(lambda *xs: jnp.stack(xs), *states)
     att = jnp.stack(attackers)
@@ -1022,7 +1098,7 @@ def _attack_windows(sim: Simulator, attackers, states, adv, steps: int,
     ctrl_stack = None
     if adaptive:
         out_states, ctrl_stack = out_states
-    obs_np = tree(np.asarray, obs)
+    obs_np = device_read(obs)
     return (
         [tree(lambda x, j=j: x[j], out_states) for j in range(s_count)],
         [{k: v[j] for k, v in obs_np.items()} for j in range(s_count)],
@@ -1161,6 +1237,7 @@ def _attacked_trials(
     seeds: list[int],
     cache: dict,
     budget: float,
+    tally: _Tally,
     trial_mesh=None,
 ) -> list[TrialResult]:
     import jax.numpy as jnp
@@ -1197,10 +1274,14 @@ def _attacked_trials(
     # (the attacker re-learns its violation estimate from zero)
     ctrl_by_seed: dict[int, object] = {}
     resumed: set[int] = set()
+    # the spans' attributes: a stack of several trials runs ONE window
+    vmapped = len(seeds) > 1
+    at = {s: dict(fraction=fraction, seed=s, vmapped=vmapped) for s in seeds}
     for s in seeds:
-        att = attacker_cohort(n, fraction, seed=s, conns=conns_np,
-                              publisher=pub, eclipse=adv.eclipse)
-        cohorts[s] = (att, jnp.asarray(att))
+        with span("trial/setup", **at[s]):
+            att = attacker_cohort(n, fraction, seed=s, conns=conns_np,
+                                  publisher=pub, eclipse=adv.eclipse)
+            cohorts[s] = (att, jnp.asarray(att))
     faulted = cfg.faults.enabled
     fmasks_np: dict[int, dict] = {}
     fmasks_dev: dict[int, dict] = {}
@@ -1218,21 +1299,30 @@ def _attacked_trials(
     run_seeds = [s for s in seeds if s not in resumed]
     run_states = []
     for s in run_seeds:
-        _reset_trial(sim, s)
+        with span("trial/setup", **at[s]):
+            _reset_trial(sim, s)
         if not adv.cold_boot:
-            sim.warmup()
+            with span("trial/warmup", **at[s]):
+                sim.warmup()
         if adv.eclipse:
             sim.state = eclipse_setup(sim.state, sim.arrays["conns"],
                                       cohorts[s][1], pub)
         run_states.append(sim.state)
 
     if run_seeds:
-        w_states, w_obs, w_ctrls = _attack_windows(
-            sim, [cohorts[s][1] for s in run_seeds], run_states, adv, steps,
-            trial_mesh=trial_mesh,
-            faults=cfg.faults if faulted else None,
-            fmasks=[fmasks_dev[s] for s in run_seeds] if faulted else None,
-            telemetry=tel)
+        # ONE span a stack: the dispatch of the window and the read of its
+        # observable curves, in which the host waits for the warm-ups too
+        with span("trial/window", fraction=fraction, seed=run_seeds[0],
+                  vmapped=len(run_seeds) > 1, trials=len(run_seeds)):
+            w_states, w_obs, w_ctrls = _attack_windows(
+                sim, [cohorts[s][1] for s in run_seeds], run_states, adv,
+                steps, trial_mesh=trial_mesh,
+                faults=cfg.faults if faulted else None,
+                fmasks=([fmasks_dev[s] for s in run_seeds]
+                        if faulted else None),
+                telemetry=tel)
+        tally.vmapped_windows += len(run_seeds) > 1
+        tally.window_heartbeats += steps
         for j, s in enumerate(run_seeds):
             state_by_seed[s] = w_states[j]
             obs_by_seed[s] = w_obs[j]
@@ -1289,16 +1379,17 @@ def _attacked_trials(
     out = []
     for j, s in enumerate(seeds):
         att, att_j = cohorts[s]
-        base = _ensure_baseline(sim, cache, s)
-        _reset_trial(sim, s)
-        sim.state = state_by_seed[s]
+        base = _ensure_baseline(sim, cache, s, tally)
+        with span("trial/setup", **at[s]):
+            _reset_trial(sim, s)
+            sim.state = state_by_seed[s]
         part_ms = None
         if cfg.faults.partition:
             # sim-ms bounds of the partition window, anchored on the
             # post-window clock (works for resumed trials too): a window
             # extending past the attack window stays open for the publish
             # schedule below
-            t_win0 = float(np.asarray(sim.state.t_ms)) - steps * hb_ms
+            t_win0 = float(device_read(sim.state.t_ms)) - steps * hb_ms
             pws, pwe = cfg.faults.partition_window
             part_ms = (t_win0 + pws * hb_ms, t_win0 + pwe * hb_ms)
         if cfg.checkpoint_dir and s not in resumed:
@@ -1373,7 +1464,7 @@ def _attacked_trials(
                     (st2, cn2, rv2, om2), robs = run_recovery_heartbeats(
                         st2, cn2, rv2, om2, att_j, rparams,
                         cfg.recovery_heartbeats, publisher=pub, telemetry=tel)
-            robs = jax.tree_util.tree_map(np.asarray, robs)
+            robs = device_read(robs)
             # and back: the publish schedule below runs sim.params, and on
             # a state of their layout it runs the program the baseline
             # trial compiled
@@ -1400,87 +1491,94 @@ def _attacked_trials(
             hit = np.nonzero(rec_ok)[0]
             if hit.size:
                 recovery_time_ms = float((hit[0] + 1) * hb_ms)
-        censor = censor_mask(att_j, sim.arrays["conns"])
-        part_cross = None
-        if part_ms is not None:
-            # cross-cut mask over the CURRENT conns (the repair window may
-            # have extended the graph)
-            part_cross = partition_edge_mask(
-                fmasks_dev[s]["side"], sim.arrays["conns"])
-        records = _publish_schedule(sim, censor=censor, attacker=att_j,
-                                    adv=adv, cross=part_cross,
-                                    partition_ms=part_ms)
-        honest = ~att
-        cov, p50, p99 = _delivery_metrics(records, honest)
-        heal_time_ms = -1.0
-        reconv_hb = -1
-        cov_part = -1.0
-        if cfg.faults.partition:
-            pws, pwe = cfg.faults.partition_window
-            curve = np.asarray(obs_j.get("cross_mesh_edges", ()))
-            if curve.size > pwe:
-                hit = np.nonzero(curve[pwe:] > 0)[0]
-                if hit.size:
-                    heal_time_ms = float((hit[0] + 1) * hb_ms)
-            side_np = fmasks_np[s]["side"]
-            same_side = side_np == side_np[pub]
-            cov_part = float((same_side & honest).sum()
-                             / max(int(honest.sum()), 1))
-        if cfg.faults.crash:
-            cwe = cfg.faults.crash_window[1]
-            curve = np.asarray(obs_j.get("restarted_mean_degree", ()))
-            if curve.size > cwe:
-                hit = np.nonzero(curve[cwe:] >= sim.params.d_low)[0]
-                if hit.size:
-                    reconv_hb = int(hit[0] + 1)
-        engaged, gf_final, recovery, share_final = _obs_metrics(
-            obs_j, cfg.mesh_recovery_share)
-        # flight-recorder curve milestones over the concatenated
-        # attack+recovery timeline (the tel_* channels ride both windows)
-        cov90_hb = -1
-        score_cross_hb = -1
-        tel_cov = np.asarray(obs_j.get("tel_mesh_coverage", ()))
-        if tel_cov.size:
-            cov90_hb = _first_round(tel_cov, lambda c: c >= 0.9)
-        tel_q = np.asarray(obs_j.get("tel_score_q", ()))
-        if tel_q.size:
-            med = tel_q[:, tel_q.shape[1] // 2]
-            thr = float(sim.params.graylist_threshold)
-            score_cross_hb = _first_round(med, lambda c: c < thr)
-        # final honest-side view of attacker edges (post-publish: includes
-        # the censorship penalties the window could not see). Read the
-        # CURRENT conns — the repair window may have extended the graph.
-        cn_now = np.asarray(sim.arrays["conns"])
-        sc = np.asarray(sim.state.score(sim.params), dtype=np.float64)
-        att_edge = (cn_now >= 0) & att[np.clip(cn_now, 0, None)]
-        h_att = att_edge & honest[:, None]
-        score_final = float(sc[h_att].mean()) if h_att.any() else 0.0
-        out.append(TrialResult(
-            scenario=cfg.scenario, fraction=fraction, seed=s,
-            attackers=int(att.sum()),
-            honest_coverage=cov, benign_coverage=base["coverage"],
-            latency_p50_ms=p50, latency_p99_ms=p99,
-            benign_p50_ms=base["p50"],
-            latency_inflation=(p50 / base["p50"]
-                               if base["p50"] > 0 and math.isfinite(p50)
-                               else math.inf),
-            hb_to_graylist=engaged, hb_budget=budget,
-            graylisted_frac_final=gf_final, mesh_recovery_hb=recovery,
-            attacker_mesh_share_final=share_final,
-            attacker_score_final=score_final,
-            wall_s=(time.time() - t0) / len(seeds),
-            mesh_evictions_total=repaired["evictions"],
-            px_grafts_total=repaired["px_grafts"],
-            redials_total=repaired["redials"],
-            recovery_time_ms=recovery_time_ms,
-            bytes_tx_total=float(np.asarray(sim.state.bytes_tx).sum()),
-            heal_time_ms=heal_time_ms,
-            post_churn_reconvergence_hb=reconv_hb,
-            coverage_under_partition=cov_part,
-            coverage90_hb=cov90_hb,
-            score_cross_hb=score_cross_hb,
-            rtable_poison_frac=(kad_ctx[s][3] if dht_on else -1.0),
-        ))
+        with span("trial/publish", **at[s]):
+            censor = censor_mask(att_j, sim.arrays["conns"])
+            part_cross = None
+            if part_ms is not None:
+                # cross-cut mask over the CURRENT conns (the repair window
+                # may have extended the graph)
+                part_cross = partition_edge_mask(
+                    fmasks_dev[s]["side"], sim.arrays["conns"])
+            records = _publish_schedule(sim, censor=censor, attacker=att_j,
+                                        adv=adv, cross=part_cross,
+                                        partition_ms=part_ms)
+        with span("trial/metrics", **at[s]):
+            honest = ~att
+            cov, p50, p99 = _delivery_metrics(records, honest)
+            heal_time_ms = -1.0
+            reconv_hb = -1
+            cov_part = -1.0
+            if cfg.faults.partition:
+                pws, pwe = cfg.faults.partition_window
+                curve = np.asarray(obs_j.get("cross_mesh_edges", ()))
+                if curve.size > pwe:
+                    hit = np.nonzero(curve[pwe:] > 0)[0]
+                    if hit.size:
+                        heal_time_ms = float((hit[0] + 1) * hb_ms)
+                side_np = fmasks_np[s]["side"]
+                same_side = side_np == side_np[pub]
+                cov_part = float((same_side & honest).sum()
+                                 / max(int(honest.sum()), 1))
+            if cfg.faults.crash:
+                cwe = cfg.faults.crash_window[1]
+                curve = np.asarray(obs_j.get("restarted_mean_degree", ()))
+                if curve.size > cwe:
+                    hit = np.nonzero(curve[cwe:] >= sim.params.d_low)[0]
+                    if hit.size:
+                        reconv_hb = int(hit[0] + 1)
+            engaged, gf_final, recovery, share_final = _obs_metrics(
+                obs_j, cfg.mesh_recovery_share)
+            tally.publishes += len(records)
+            tally.mesh_share_peaks.append(
+                float(np.max(obs_j["attacker_mesh_share"])))
+            # flight-recorder curve milestones over the concatenated
+            # attack+recovery timeline (the tel_* channels ride both windows)
+            cov90_hb = -1
+            score_cross_hb = -1
+            tel_cov = np.asarray(obs_j.get("tel_mesh_coverage", ()))
+            if tel_cov.size:
+                cov90_hb = _first_round(tel_cov, lambda c: c >= 0.9)
+            tel_q = np.asarray(obs_j.get("tel_score_q", ()))
+            if tel_q.size:
+                med = tel_q[:, tel_q.shape[1] // 2]
+                thr = float(sim.params.graylist_threshold)
+                score_cross_hb = _first_round(med, lambda c: c < thr)
+            # final honest-side view of attacker edges (post-publish: includes
+            # the censorship penalties the window could not see). Read the
+            # CURRENT conns — the repair window may have extended the graph.
+            cn_now = device_read(sim.arrays["conns"])
+            sc = np.asarray(device_read(sim.state.score(sim.params)),
+                            dtype=np.float64)
+            att_edge = (cn_now >= 0) & att[np.clip(cn_now, 0, None)]
+            h_att = att_edge & honest[:, None]
+            score_final = float(sc[h_att].mean()) if h_att.any() else 0.0
+            out.append(TrialResult(
+                scenario=cfg.scenario, fraction=fraction, seed=s,
+                attackers=int(att.sum()),
+                honest_coverage=cov, benign_coverage=base["coverage"],
+                latency_p50_ms=p50, latency_p99_ms=p99,
+                benign_p50_ms=base["p50"],
+                latency_inflation=(p50 / base["p50"]
+                                   if base["p50"] > 0 and math.isfinite(p50)
+                                   else math.inf),
+                hb_to_graylist=engaged, hb_budget=budget,
+                graylisted_frac_final=gf_final, mesh_recovery_hb=recovery,
+                attacker_mesh_share_final=share_final,
+                attacker_score_final=score_final,
+                wall_s=(time.time() - t0) / len(seeds),
+                mesh_evictions_total=repaired["evictions"],
+                px_grafts_total=repaired["px_grafts"],
+                redials_total=repaired["redials"],
+                recovery_time_ms=recovery_time_ms,
+                bytes_tx_total=float(
+                    device_read(sim.state.bytes_tx).sum()),
+                heal_time_ms=heal_time_ms,
+                post_churn_reconvergence_hb=reconv_hb,
+                coverage_under_partition=cov_part,
+                coverage90_hb=cov90_hb,
+                score_cross_hb=score_cross_hb,
+                rtable_poison_frac=(kad_ctx[s][3] if dht_on else -1.0),
+            ))
         if cfg.recovery_heartbeats > 0 and not graph_static:
             # restore the epoch graph: the next trial (and _reset_trial's
             # valid_edge refresh) must start from the built topology
@@ -1524,7 +1622,15 @@ def run_campaign(cfg: CampaignConfig, mesh=None,
     cfg.validate()
     adv = cfg.adversary_params()
     t0 = time.time()
-    sim = Simulator(cfg.experiment, mesh=mesh)
+    reads0 = device_reads()
+    # the ONE network every trial shares, under the names `cmd_run` gives
+    # the two halves of its build
+    with span("run/topology"):
+        from ..config.topology import Topology
+
+        topology = Topology.build(cfg.experiment.topo)
+    with span("run/simulator_init"):
+        sim = Simulator(cfg.experiment, topology=topology, mesh=mesh)
     budget = heartbeats_to_graylist(adv, sim.params)
     if ((adv.graft_flood or adv.ihave_spam or adv.iwant_spam)
             and not adv.identity_rotation
@@ -1543,6 +1649,7 @@ def run_campaign(cfg: CampaignConfig, mesh=None,
             "attack_gossipsub() is the armed default")
     cache: dict[int, dict] = {}
     trials: list[TrialResult] = []
+    tally = _Tally()
     sup = cfg.supervisor
     injector = _FailureInjector(sup.inject_failures)
     quarantined: list[dict] = []
@@ -1559,15 +1666,17 @@ def run_campaign(cfg: CampaignConfig, mesh=None,
 
     def _cell(f: float, ss: list[int]) -> list[TrialResult]:
         if f == 0.0:
-            return [_benign_trial(sim, cfg, s, cache, budget) for s in ss]
+            return [_benign_trial(sim, cfg, s, cache, budget, tally)
+                    for s in ss]
         if trial_mesh is not None and cfg.vmap_trials and len(ss) > 1:
-            return _attacked_trials(sim, cfg, f, ss, cache, budget,
+            return _attacked_trials(sim, cfg, f, ss, cache, budget, tally,
                                     trial_mesh=trial_mesh)
         if cfg.vmap_trials and len(ss) > 1 and mesh is None:
-            return _attacked_trials(sim, cfg, f, ss, cache, budget)
+            return _attacked_trials(sim, cfg, f, ss, cache, budget, tally)
         out: list[TrialResult] = []
         for s in ss:
-            out.extend(_attacked_trials(sim, cfg, f, [s], cache, budget))
+            out.extend(_attacked_trials(sim, cfg, f, [s], cache, budget,
+                                        tally))
         return out
 
     def _quarantine(f: float, ss: list[int], err) -> None:
@@ -1577,28 +1686,37 @@ def run_campaign(cfg: CampaignConfig, mesh=None,
             "error": repr(err)[:500] if err is not None else "unknown",
         })
 
-    for f in cfg.fractions:
-        seeds = list(cfg.seeds)
-        res, used, err = _supervise(
-            sup, injector, lambda f=f, ss=seeds: _cell(f, ss), _on_fail)
-        retries_total += used
-        if res is not None:
-            trials.extend(res)
-            continue
-        if len(seeds) == 1:
-            _quarantine(f, seeds, err)
-            continue
-        # the batch is poisoned — isolate per seed so siblings survive
-        # (checkpointed seeds resume instead of recomputing their windows)
-        for s in seeds:
-            res1, used1, err1 = _supervise(
-                sup, injector, lambda f=f, s=s: _cell(f, [s]), _on_fail)
-            retries_total += used1
-            if res1 is not None:
-                trials.extend(res1)
-            else:
-                _quarantine(f, [s], err1)
+    with span("run/campaign"):
+        for f in cfg.fractions:
+            seeds = list(cfg.seeds)
+            res, used, err = _supervise(
+                sup, injector, lambda f=f, ss=seeds: _cell(f, ss), _on_fail)
+            retries_total += used
+            if res is not None:
+                trials.extend(res)
+                continue
+            if len(seeds) == 1:
+                _quarantine(f, seeds, err)
+                continue
+            # the batch is poisoned — isolate per seed so siblings survive
+            # (checkpointed seeds resume instead of recomputing their
+            # windows)
+            for s in seeds:
+                res1, used1, err1 = _supervise(
+                    sup, injector, lambda f=f, s=s: _cell(f, [s]), _on_fail)
+                retries_total += used1
+                if res1 is not None:
+                    trials.extend(res1)
+                else:
+                    _quarantine(f, [s], err1)
     conformance = _campaign_conformance(cfg, adv) if cfg.conformance else None
+    counted = _campaign_counters(trials, tally, budget,
+                                 device_reads() - reads0)
+    # one zero-length annotation a campaign, for a reader of the profile
+    # (a number that is not finite, an infinite budget, reads -1 there)
+    counters("attack/counters", **{
+        k: -1.0 if v is None else v
+        for k, v in sanitize_nonfinite(counted).items()})
     return CampaignResult(
         scenario=cfg.scenario,
         network_size=sim.params.n,
@@ -1609,6 +1727,7 @@ def run_campaign(cfg: CampaignConfig, mesh=None,
         quarantined_trials=quarantined,
         retries_total=retries_total,
         conformance=conformance,
+        counters=counted,
     )
 
 
@@ -2159,7 +2278,7 @@ def _episub_publish(sim: Simulator, ctrl, ep, censor=None, attacker=None,
                     sim.params, ep, hb_steps)
         eff = censor
         if cross is not None and partition_ms is not None:
-            t_now = float(np.asarray(sim.state.t_ms))
+            t_now = float(device_read(sim.state.t_ms))
             if partition_ms[0] <= t_now < partition_ms[1]:
                 eff = cross if censor is None else (censor | cross)
         rec = sim.publish(pub, censor_edge=eff)

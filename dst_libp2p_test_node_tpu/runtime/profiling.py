@@ -33,6 +33,12 @@ analyses:
                     profiler session puts the span on the device trace's
                     clock; `counters` is the zero-length annotation that
                     carries a publish's device-side counters
+  device_read       one device->host read (`jax.device_get`, a pytree in one
+                    call is one read), counted a process
+                    (`count_device_read` notes a read made by `np.asarray`):
+                    a phase that wants to know how many reads it made takes
+                    `device_reads()` before and after (runtime/campaign.py
+                    does, for `sim:attack/counters`)
   process_record    what the process did up to the end of its first turn:
                     marks (package imported, cli.main, backend ready, the
                     first turn's start and end), the spans opened outside
@@ -310,6 +316,28 @@ def counters(name: str, **values) -> None:
         pass
 
 
+def count_device_read() -> None:
+    """Note one device->host read that is made some other way than through
+    `device_read` (an `np.asarray` of a device array, as
+    `simulator.record_from_result` reads a publish's leaves)."""
+    _PROCESS.device_reads += 1
+
+
+def device_read(tree):
+    """One device->host read, counted: `jax.device_get(tree)`, the arrays
+    of a pytree fetched in the one call. What the host waits for, it waits
+    for here; `device_reads()` says how many the process has made."""
+    import jax
+
+    count_device_read()
+    return jax.device_get(tree)
+
+
+def device_reads() -> int:
+    """The device->host reads the process has counted so far."""
+    return _PROCESS.device_reads
+
+
 # ----------------------------------- process record and compile ledger
 
 # jax.monitoring's names (jax._src.dispatch, jax._src.compiler): the three
@@ -433,6 +461,7 @@ class ProcessRecord:
         # "turn1_end"
         self.marks: dict[str, float] = {"imported": IMPORTED_AT}
         self.turns = 0
+        self.device_reads = 0               # `device_read` calls
         self.spans: dict[str, dict] = {}    # those opened outside a turn
         # the compile ledger: open up to the end of the first turn
         self.setup = CompileTotals(by_fun=True)

@@ -36,7 +36,7 @@ from ..ops.graph import ConnGraph, build_connection_graph
 from ..ops.heartbeat import PULL_COUNTS, PULL_STAGES, run_heartbeats
 from ..ops.state import SimParams, graph_arrays, init_state
 from .logemit import LatenciesWriter
-from .profiling import counters, span
+from .profiling import count_device_read, counters, device_read, span
 from .summarize import LatencySummary, report, summarize_records
 
 # Steady-state per-hop processing cost by muxer, DERIVED from the transport
@@ -188,6 +188,15 @@ def drain_heartbeat_carry(carry_ms: float, ms: float, hb_ms: float):
     return steps, carry - steps * hb_ms
 
 
+def _host(x, dtype=None):
+    """`np.asarray(x)`, counted as the device->host read it is where `x`
+    lives on the device (`profiling.device_reads`); a leaf a result view
+    holds as numpy already, or a Python scalar, is no read."""
+    if isinstance(x, jax.Array):
+        count_device_read()
+    return np.asarray(x, dtype=dtype)
+
+
 def record_from_result(
     res, *, msg_id: int, publisher: int, t0_ms: float,
     extra_delay_ms: float = 0.0, drop_self=None, lanes_in_pull: int = 1,
@@ -196,8 +205,8 @@ def record_from_result(
     single-topic and multi-topic publish paths). `drop_self`: peer id (or
     list of ids) whose own delivery is suppressed (SELFTRIGGER off,
     main.nim:245; unsubscribed originators/exit nodes with no handler)."""
-    delays = np.asarray(res.delay_ms, dtype=np.float64) + extra_delay_ms
-    received = np.asarray(res.received).copy()
+    delays = _host(res.delay_ms, dtype=np.float64) + extra_delay_ms
+    received = _host(res.received).copy()
     if drop_self is not None:
         received[np.asarray(drop_self)] = False
     delays = np.where(received, delays, np.inf)
@@ -207,7 +216,7 @@ def record_from_result(
     # none: their records read converged and no refinement
     packed = getattr(res, "counters", None)
     values = ([0, 0, 0, 0, 1, 0, 0, 0, 0] if packed is None
-              else [int(v) for v in np.asarray(packed)])
+              else [int(v) for v in _host(packed)])
     (fast_iters, refine_passes, refined, fell_back, converged,
      refined_serial, refine_lane_passes, lanes_hinted,
      lanes_uncertified) = values[:9]
@@ -219,13 +228,13 @@ def record_from_result(
         t0_ms=t0_ms,
         delays_ms=delays,
         received=received,
-        sends=np.asarray(res.sends),
-        copies_rx=np.asarray(res.copies_rx),
-        ihave=int(np.asarray(res.ihave_sent).sum()),
-        iwant=int(np.asarray(res.iwant_sent).sum()),
+        sends=_host(res.sends),
+        copies_rx=_host(res.copies_rx),
+        ihave=int(_host(res.ihave_sent).sum()),
+        iwant=int(_host(res.iwant_sent).sum()),
         # the views above may not carry the scalar; exact mode's bar is 0.0
         # anyway
-        answer_wait_max_ms=float(np.asarray(
+        answer_wait_max_ms=float(_host(
             getattr(res, "answer_wait_max_ms", 0.0))),
         converged=bool(converged),
         fast_iters=fast_iters,
@@ -519,7 +528,7 @@ class Simulator:
         how many pulled it dense (the pull in front of a scan is a dense
         `validity`), and the most sending rows a step saw."""
         if self._hb_unread:
-            self._note_heartbeat_pulls(jax.device_get(self._hb_unread))
+            self._note_heartbeat_pulls(device_read(self._hb_unread))
         by_stage = {
             stage: {count: int(self._hb_pulls[i, j])
                     for j, count in enumerate(PULL_COUNTS)}
@@ -679,7 +688,7 @@ class Simulator:
                     self.params, steps, spared=self._spared, with_pulls=True)
                 if len(self._hb_unread) >= self._HB_UNREAD_MAX:
                     self._note_heartbeat_pulls(
-                        jax.device_get(self._hb_unread))
+                        device_read(self._hb_unread))
                 self._hb_unread.append(pulls)
 
     def warmup(self) -> None:
@@ -713,7 +722,7 @@ class Simulator:
                         valid_edge, up = valid_edge_at_publish(
                             self.state.alive, self.state.subscribed,
                             a["conns"], a["rev"], publisher)
-                    t_ms, up, pulls = jax.device_get(
+                    t_ms, up, pulls = device_read(
                         (self.state.t_ms, up, self._hb_unread))
                     self._note_heartbeat_pulls(pulls)
                     if not up:
@@ -725,7 +734,7 @@ class Simulator:
                 else:
                     # the scans' counters come with the read that waits for
                     # them anyway
-                    t_ms, pulls = jax.device_get(
+                    t_ms, pulls = device_read(
                         (self.state.t_ms, self._hb_unread))
                     self._note_heartbeat_pulls(pulls)
                 t0_ms = float(t_ms) + self._hb_carry_ms
