@@ -194,6 +194,7 @@ def _publish_counts(records) -> list[dict]:
              "lanes_hinted": r.lanes_hinted,
              "lanes_uncertified": r.lanes_uncertified,
              "lanes_in_pull": r.lanes_in_pull,
+             "pull_rows_share": r.pull_rows_share,
              "converged": r.converged}
             for r in records]
 

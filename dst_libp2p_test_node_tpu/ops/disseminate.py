@@ -517,6 +517,7 @@ def disseminate(
     ans_tables=None,
     valid_edge=None,
     censor_edge=None,
+    pull_bands=None,
 ):
     """Propagate one application message (all fragments) through the mesh.
 
@@ -570,6 +571,14 @@ def disseminate(
     regardless of floodPublish. The caller decides with_fanout from the
     publisher's subscription (host-side; subscription is publish-path
     static), keeping the subscribed-publisher compile unchanged.
+
+    `pull_bands`: the hoisted `ops/pull.PullBands` of this graph
+    (make_pull_bands; a table like `ans_tables`, under the same contract,
+    and with gossip made with that table's `conns_sorted` and `rev_sorted`).
+    Given, every row pull of the fixpoints, the folds, the attribution and
+    the accounting fetches slots [0, C1) of every row and the rest of the
+    heavy rows only, and returns what it returns without, bit for bit; None
+    (the default) is the whole-width program.
     """
     # device scopes (jax.named_scope: metadata only, no operation is added):
     # `sample` is everything drawn or hoisted before the fixpoints run
@@ -618,13 +627,13 @@ def disseminate(
         # loop over publishes precompute it (Simulator/bench maintain it and
         # invalidate on churn or subscription flips), saving one full
         # row-gather pass per publish. DYNAMIC-GRAPH CONTRACT: a hoisted
-        # valid_edge (and lat_edge/loss_edge/ans_tables) is a pure function of
-        # conns/rev — if the repair controller's dial path extended the graph
-        # (ops/repair.py), the caller must re-derive all of them against the
-        # mutated arrays (Simulator.rebind_graph) and the warm-start carry in
-        # state.warm_offset_ms must already be INF (repair_round writes it on
-        # any committed dial); passing stale tables here silently publishes
-        # over the pre-repair edge set.
+        # valid_edge (and lat_edge/loss_edge/ans_tables/pull_bands) is a pure
+        # function of conns/rev — if the repair controller's dial path
+        # extended the graph (ops/repair.py), the caller must re-derive all
+        # of them against the mutated arrays (Simulator.rebind_graph) and the
+        # warm-start carry in state.warm_offset_ms must already be INF
+        # (repair_round writes it on any committed dial); passing stale
+        # tables here silently publishes over the pre-repair edge set.
         has = conns >= 0
         if valid_edge is not None:
             valid = valid_edge
@@ -836,6 +845,16 @@ def disseminate(
 
     formulation = fixpoint_formulation(conns.shape, mesh)
     in_sequence = fragments_in_sequence(conns.shape, fragments, mesh)
+    if pull_bands is not None and formulation != "row_pull":
+        raise ValueError(
+            f"pull_bands serve the row_pull formulation, not {formulation}")
+
+    def _banded(name, index):
+        """The index array a lane's row pull goes through: `index`, or its
+        two bands where the caller hoisted them (ops/pull.Banded)."""
+        return index if pull_bands is None else pull_bands.of(name)
+
+    p_conns, p_rev = _banded("conns", conns), _banded("rev", rev)
     # how many lanes' pulls are live at once: what every pull's budget
     # dispatch must see (ops/pull.exceeds_budget's batch_factor)
     lanes = 1 if in_sequence else fragments
@@ -882,8 +901,8 @@ def disseminate(
             perm_lat = ans_tables.perm_lat                       # (N, C)
             inv_lat = ans_tables.inv_lat
             lat_sorted = ans_tables.lat_sorted
-            conns_sorted = ans_tables.conns_sorted
-            rev_sorted = ans_tables.rev_sorted
+            p_conns_sorted = _banded("conns_sorted", ans_tables.conns_sorted)
+            p_rev_sorted = _banded("rev_sorted", ans_tables.rev_sorted)
             gw_sorted = [
                 permute_rows(g_tgt_w[h], perm_lat) for h in range(n_rounds)
             ]
@@ -968,9 +987,9 @@ def disseminate(
         # pull is in budget and the table on one device
         if formulation == "row_pull":
             q_t_s = neighbor_rows_min(
-                t_rx, conns_sorted, batch_factor=lanes)
+                t_rx, p_conns_sorted, batch_factor=lanes)
         else:
-            q_t_s = t_rx[jnp.clip(conns_sorted, 0)]
+            q_t_s = t_rx[jnp.clip(p_conns_sorted, 0)]
         txp = tx_ms[:, None]
         busy = uplink                               # (N,) queue busy carry
         g_sorted = jnp.full((n, c), INF)
@@ -1122,14 +1141,14 @@ def disseminate(
         (row-gather + fused slot select; see ops/pull.py for why). Runs
         inside the fragment vmap, so the memory dispatch must see how many
         lanes are live at once."""
-        return reciprocal_pull_min(cand, conns, rev, batch_factor=lanes)
+        return reciprocal_pull_min(cand, p_conns, p_rev, batch_factor=lanes)
 
     def pull_sorted(cand_s):
         """pull() of a table whose rows are in the SENDER's lat order: the
         select takes the reverse slot's sorted position (rev_sorted). The
         result is in the receiver's slot layout, as pull()'s."""
         return reciprocal_pull_min(
-            cand_s, conns, rev_sorted, batch_factor=lanes)
+            cand_s, p_conns, p_rev_sorted, batch_factor=lanes)
 
     def _converge_dyn(rank, k_p, frag_idx, t_pub, send_mask, t_init=None):
         """UNSERIALIZED fixpoint (every gossip answer rides its own uplink
@@ -1316,8 +1335,7 @@ def disseminate(
             t_g, _, _, it = carry
             g_abs, _, _ = gossip_serial_exact(t_g, frag_idx)
             g_d = g_abs if sv is None else jnp.where(sv, g_abs, INF)
-            g_in = reciprocal_pull_min(
-                g_d, conns, rev, batch_factor=lanes)
+            g_in = pull(g_d)
             g_floor = g_in.min(axis=-1)
             t_new = _converge_floor(
                 rank, k_p, frag_idx, t_pub, send_mask, g_floor,
@@ -1881,7 +1899,7 @@ def disseminate(
         # rx side (first-delivery attribution): delivered copies only
         first_slot = jnp.argmin(inc, axis=-1)
         q_t = neighbor_pull_min(  # neighbor arrival times (fragment-vmapped)
-            t_rx_one, conns, rev, batch_factor=lanes)
+            t_rx_one, p_conns, p_rev, batch_factor=lanes)
         start_tx = jnp.maximum(t_rx_one + params.proc_delay_ms, uplink)
         # IDONTWANT (v1.2): target announced receipt before our send began
         if payload_bytes >= params.idontwant_threshold_bytes:
@@ -1937,7 +1955,7 @@ def disseminate(
             slot_ok = (conns >= 0) & (rev >= 0)
             pulled = jnp.where(
                 slot_ok,
-                reciprocal_pull_min(pack, conns, rev, batch_factor=lanes),
+                pull(pack),
                 0.0)
             q_ihave = jnp.floor(pulled / 4.0)
             rem = pulled - q_ihave * 4.0
@@ -1958,7 +1976,7 @@ def disseminate(
                        else (sent_any & ~sv_loss).sum(axis=-1)
                        .astype(jnp.float32))
             arrived_rx = reciprocal_pull_bool(
-                arrived, conns, rev, batch_factor=lanes)
+                arrived, p_conns, p_rev, batch_factor=lanes)
             copies = arrived_rx.sum(axis=-1).astype(jnp.float32)
         # wire-arrival time of every copy that landed at each receiver slot
         # (for the downlink-occupancy fold below); -INF marks no-copy slots
@@ -1977,7 +1995,7 @@ def disseminate(
             slow_send = send_mask & made_offer & (
                 qdelay > params.slow_threshold_ms)
             slow_inc = reciprocal_pull_bool(
-                slow_send, conns, rev, batch_factor=lanes
+                slow_send, p_conns, p_rev, batch_factor=lanes
             ).astype(jnp.float32)
         else:
             slow_inc = jnp.zeros((n, c), jnp.float32)
@@ -2016,7 +2034,7 @@ def disseminate(
                         & (got & can_send)[:, None])
             idw_tx_pp = idw_edge.sum(axis=-1).astype(jnp.int32)
             idw_rx_pp = reciprocal_pull_bool(
-                idw_edge, conns, rev).sum(axis=-1).astype(jnp.int32)
+                idw_edge, p_conns, p_rev).sum(axis=-1).astype(jnp.int32)
         else:
             idw_tx_pp = jnp.zeros((n,), jnp.int32)
             idw_rx_pp = jnp.zeros((n,), jnp.int32)

@@ -89,6 +89,45 @@ neighbor_rows_min for F lanes, an (N, F) table gathered once, lane f its
 column f: 8.9 ms for four lanes against 33.8 one after another (9.6 against
 74.5 for nine); the (N, F*C) table of broadcast lanes 25.1.
 
+Rows against time (PR 50, TPU v5 lite, chiprun call 125: `python
+scripts/pull_bands_bench.py`; same shape, f32, the index of
+`build_connection_graph(100000, 10, 1, max_degree=40)`, each candidate a jit
+of its own, median of 8 timed calls; this machine read the whole pull at
+10.0 ms where PR 41's read 9.0). Half of an (N, C) index at 4 x connect_to
+is pads: mean degree 20.0 of 40 slots, filled share 0.49998, max degree
+36-37, every row filled from the front; rows with more than 16 / 20 / 24 /
+28 / 32 connections: 87,059 / 41,525 / 8,384 / 669 / 32. A pad's -1 clips to
+row 0 and is gathered like any other, so a pull that fetches slots [0, C1)
+of every row (band A) and slots [C1, C) of the M rows that hold a
+connection there (band B) returns the same array from fewer rows
+(make_pull_bands; C1 = 24, M = 12,504: 2,600,064 of 4,000,000 rows, 65 %):
+      rows gathered             one lane   four packed lanes
+      whole (N, 40)              10.01       26.08 ms
+      band A alone, C1 = 16       5.28
+      band A alone, C1 = 24       6.53
+      band A alone, C1 = 32       9.59
+      A + B, C1 = 24, M = 12,504, band B back through an (N,) inverse row
+        gather of the (M + 1, C - C1) block (_spread: what runs)
+                                  7.42       17.47
+      the same, band B scattered into a filled (N, C - C1)
+                                  6.70       18.94 (one scatter of (F, T)
+                                             windows; a scatter of (M, F*T)
+                                             rows: not measured)
+      A + B, C1 = 24, M = 50,000  8.94
+      A + B, C1 = 32, M = 12,504 10.40
+      A + B, C1 = 20, M = 50,000 13.75
+      inside a 20-step loop, a step: whole 8.27 / 24.88; A + B 5.89 / 16.75
+      bool, one lane: whole 11.28; A + B 9.37
+      neighbor_rows_min: whole 9.20 / 9.05; A + B 6.81 / 6.47
+Cost follows the rows, less than in proportion: 65 % of the rows cost 74 %
+standalone and 67-71 % inside a loop, and a second band is not free (band
+B's 200,000 rows and their way back cost 0.9 ms as an inverse gather of
+100,000 16-wide rows, 0.2 ms as a scatter of one lane). In the publish's
+loops (ledger and traced runs, PR 50): a fast iteration 10.98 -> 7.89 ms at
+F = 1 and 30.5 -> 21.5 ms for four joint lanes, a refinement pass (two
+pulls) 21.5 -> 16.0 and 46.1 -> 35.4 ms. Band A is still 17.4 % pads and the
+heavy rows' tails 86 %: what fetches filled slots alone would gather 50 %.
+
 The sharded fixpoint (parallel/exchange.py converge_sharded) deliberately
 does NOT use this: its per-iteration cross-shard traffic is the (N,) time
 vector alone, and the pull there is against receiver-local constants.
@@ -97,9 +136,11 @@ vector alone, and the pull there is against receiver-local constants.
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.custom_batching import custom_vmap
 
 INF = jnp.float32(3.4e38)
@@ -168,7 +209,7 @@ def _gather_select(select):
     gather and `select(rows, sel)`, the fused pick of column `rev` (clipped:
     callers mask the invalid slots)."""
     def pull(vals, conns, rev):
-        c = conns.shape[-1]
+        c = vals.shape[-1]
         rows = vals[..., jnp.clip(conns, 0), :]   # (..., N, C, C) contiguous
         sel = jnp.arange(c) == jnp.clip(rev, 0)[..., None]
         return select(rows, sel)
@@ -233,18 +274,173 @@ _PULL_ANY = (_gather_select(_select_any), _gather_select_packed(_select_any),
              _two_index)
 
 
-def _row_pull(vals, conns, rev, forms, batch_factor: int):
-    """Size-dispatched core over a select's `forms` (_PULL_MIN, _PULL_ANY):
-    the whole-row gather and the select, or past the budget (see
-    exceeds_budget) the direct 2-index gather. One lane is the plain
-    program; declared lanes may share a row (_lanes_in_the_row)."""
+def _row_pull(vals, index, forms, shape, batch_factor: int, rank: int = 3):
+    """Size-dispatched core over a select's `forms` (_PULL_MIN, _PULL_ANY,
+    _ROWS_MIN, or one of them `_in_bands`) and the `index` arrays they take
+    after `vals`: the whole-row gather and the select, or past the budget
+    (see exceeds_budget; `shape`, the whole (N, C) index whatever its bands)
+    the gather of single elements. One lane is the plain program; declared
+    lanes may share a row (_lanes_in_the_row, `rank` as there)."""
     one, packed, scalar = forms
-    if exceeds_budget(vals.dtype, conns.shape, batch_factor):
-        return scalar(vals, conns, rev)
+    if exceeds_budget(vals.dtype, shape, batch_factor):
+        return scalar(vals, *index)
     if batch_factor <= 1:
-        return one(vals, conns, rev)
-    return _lanes_in_the_row(one, packed, scalar, batch_factor)(
-        vals, conns, rev)
+        return one(vals, *index)
+    return _lanes_in_the_row(one, packed, scalar, batch_factor, rank)(
+        vals, *index)
+
+
+class Banded(NamedTuple):
+    """An (N, C) index array as the two bands a publish's pulls fetch
+    (make_pull_bands): `head` (N, C1), slots [0, C1) of every row; `tail`
+    (M, C - C1), slots [C1, C) of the heavy rows, the rows that hold a
+    connection there (all -1 past their count); `back` (N,), a row's place
+    in `tail`, M for a row that is not heavy. The pulls take it in place of
+    the array and return the (N, C) array they return for the array."""
+
+    head: jnp.ndarray
+    tail: jnp.ndarray
+    back: jnp.ndarray
+
+    @property
+    def shape(self):
+        return (self.head.shape[0], self.head.shape[1] + self.tail.shape[1])
+
+
+def _spread(tail, back, fill):
+    """Band B back into the rows of the index: row p of the result is row
+    `back[p]` of `tail` (M, T), `fill` where `back` says M. One gather of N
+    rows through the (N,) inverse index; F lanes (F, M, T) lie side by side
+    in its rows, as the pulled tables do (_gather_select_packed)."""
+    if tail.ndim == 2:
+        row = jnp.full((1, tail.shape[-1]), fill, tail.dtype)
+        return jnp.concatenate([tail, row])[back]
+    f, m, t = tail.shape
+    table = jnp.moveaxis(tail, 0, 1).reshape(m, f * t)
+    rows = jnp.concatenate(
+        [table, jnp.full((1, f * t), fill, tail.dtype)])[back]
+    return jnp.moveaxis(rows.reshape(-1, f, t), 1, 0)
+
+
+def _in_bands(form, mask, fill):
+    """`form(vals, *index)`, one of a select's three, through a `Banded`
+    index: band A, slots [0, C1) of every row, and band B, slots [C1, C) of
+    the heavy rows, each `mask`ed by its own slots as the whole pull's
+    result is, and B spread back over the rows. The index arrives flat, the
+    heads, the tails, then `back`, so that the batching rule of
+    `_lanes_in_the_row` sees arrays."""
+    def banded(vals, *index):
+        *index, back = index
+        heads, tails = index[:len(index) // 2], index[len(index) // 2:]
+        head = mask(form(vals, *heads), *heads)
+        tail = mask(form(vals, *tails), *tails)
+        return jnp.concatenate([head, _spread(tail, back, fill)], axis=-1)
+    return banded
+
+
+def _flat(*index):
+    """What `_in_bands` takes of `Banded` arrays that share a `back`."""
+    return (*(x.head for x in index), *(x.tail for x in index),
+            index[0].back)
+
+
+def _mask_min(out, conns, rev):
+    return jnp.where((conns >= 0) & (rev >= 0), out, INF)
+
+
+def _mask_rows(out, conns):
+    return jnp.where(conns >= 0, out, INF)
+
+
+def _mask_any(out, conns, rev):
+    return out & (conns >= 0) & (rev >= 0)
+
+
+class PullBands(NamedTuple):
+    """The hoisted index tables of a publish's banded pulls: a pure function
+    of the index arrays (make_pull_bands), like `AnswerTables` of theirs.
+    `heads[name]` (N, C1) and `tails[name]` (M, C - C1) for every index
+    array given by `name` ("conns", "rev", "conns_sorted", "rev_sorted");
+    `back` (N,) as `Banded` has it, one for all of them."""
+
+    back: jnp.ndarray
+    heads: dict
+    tails: dict
+
+    def of(self, name: str) -> Banded:
+        return Banded(self.heads[name], self.tails[name], self.back)
+
+
+def band_shape(conns_shape) -> tuple[int, int]:
+    """(C1, M) of the two bands of an (N, C) index, from the static shape
+    alone, so that every graph of a shape compiles one program: the cut at
+    three fifths of the slots and room for an eighth of the rows past it,
+    both rounded up to 8 (24 and 12,504 at (100000, 40): at C = 4 x
+    connect_to the degrees centre on C / 2, and 8.4 % of the rows hold more
+    than 24 connections; the module docstring has the census)."""
+    n, c = conns_shape
+    return -(-3 * c // 5 // 8) * 8, -(-n // 8 // 8) * 8
+
+
+def pull_rows_share(bands: PullBands | None) -> float:
+    """100 x the rows one pull of the publish gathers / (peers x slots):
+    100 without bands."""
+    if bands is None:
+        return 100.0
+    conns = bands.of("conns")
+    n, c = conns.shape
+    return 100.0 * (conns.head.size + conns.tail.size) / (n * c)
+
+
+def make_pull_bands(conns, rev, conns_sorted=None, rev_sorted=None, *,
+                    mesh=None, c1=None, rows=None,
+                    min_bytes=_SPARSE_MIN_DENSE_BYTES) -> PullBands | None:
+    """The bands of a publish's pulls over this graph, or None where the
+    whole-width pull stays: half of an (N, C) index at C = 4 x connect_to is
+    pads, a graph fills a row's slots from the front, and a pull is bound by
+    the rows it fetches, so the pulls fetch slots [0, C1) of every row and
+    slots [C1, C) of the heavy rows only. A row is heavy when any slot at or
+    past C1 holds a connection, read from `conns` itself (holes in a row
+    keep it exact); a row of `conns_sorted` that holds one there has more
+    than C1 connections and is heavy already, so one set serves both
+    layouts (`conns_sorted` and `rev_sorted` of `AnswerTables`; without
+    them, the slot layout alone).
+
+    (C1, M) is `band_shape`'s, static; what the graph in hand decides is
+    only bands or none. None: on a `mesh`; where the dense pull is under
+    `min_bytes` of gathered rows (microseconds: the small shapes keep the
+    one program they had) or past the gather budget (no row is pulled);
+    where more than M rows are heavy (a skewed or capped graph). `c1`,
+    `rows`, `min_bytes`: for tests."""
+    n, c = conns.shape
+    cut, most = band_shape((n, c))
+    c1 = cut if c1 is None else c1
+    rows = most if rows is None else rows
+    if (mesh is not None or not 0 < c1 < c
+            or intermediate_bytes(jnp.float32, (n, c)) < min_bytes
+            or exceeds_budget(jnp.float32, (n, c))):
+        return None
+    # the census, reduced where the array lives: N flags come to the host
+    heavy = np.flatnonzero(np.asarray((conns[:, c1:] >= 0).any(axis=-1)))
+    if heavy.size > rows:
+        return None
+    ids = np.full(rows, n, np.int32)
+    ids[:heavy.size] = heavy
+    back = np.full(n, rows, np.int32)
+    back[heavy] = np.arange(heavy.size, dtype=np.int32)
+    index = {"conns": conns, "rev": rev, "conns_sorted": conns_sorted,
+             "rev_sorted": rev_sorted}
+    index = {k: jnp.asarray(x) for k, x in index.items() if x is not None}
+    ids = jnp.asarray(ids)
+    return PullBands(
+        back=jnp.asarray(back),
+        heads={k: x[:, :c1] for k, x in index.items()},
+        tails={k: x.at[ids].get(mode="fill", fill_value=-1)[:, c1:]
+               for k, x in index.items()})
+
+
+_PULL_MIN_BANDED = tuple(_in_bands(f, _mask_min, INF) for f in _PULL_MIN)
+_PULL_ANY_BANDED = tuple(_in_bands(f, _mask_any, False) for f in _PULL_ANY)
 
 
 def permute_rows(x: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
@@ -274,9 +470,14 @@ def reciprocal_pull_bool(
     edge_mask: jnp.ndarray, conns: jnp.ndarray, rev: jnp.ndarray,
     batch_factor: int = 1,
 ) -> jnp.ndarray:
-    """out[q, j] = edge_mask[conns[q,j], rev[q,j]]; False on invalid slots."""
-    out = _row_pull(edge_mask, conns, rev, _PULL_ANY, batch_factor)
-    return out & (conns >= 0) & (rev >= 0)
+    """out[q, j] = edge_mask[conns[q,j], rev[q,j]]; False on invalid slots.
+    `conns` and `rev` may be `Banded`."""
+    if isinstance(conns, Banded):
+        return _row_pull(edge_mask, _flat(conns, rev), _PULL_ANY_BANDED,
+                         conns.shape, batch_factor)
+    out = _row_pull(edge_mask, (conns, rev), _PULL_ANY, conns.shape,
+                    batch_factor)
+    return _mask_any(out, conns, rev)
 
 
 def sparse_route(conns_shape, batch_factor: int = 1) -> bool:
@@ -433,7 +634,8 @@ def neighbor_pull_min(
 
 def _rows_min_one(per_peer, conns):
     q = jnp.clip(conns, 0)
-    table = jnp.broadcast_to(per_peer[:, None], conns.shape)
+    table = jnp.broadcast_to(
+        per_peer[:, None], per_peer.shape + conns.shape[-1:])
     return table[q, :].min(axis=-1)
 
 
@@ -448,6 +650,10 @@ def _rows_scalar(per_peer, conns):
     return per_peer[jnp.clip(conns, 0)]
 
 
+_ROWS_MIN = (_rows_min_one, _rows_min_packed, _rows_scalar)
+_ROWS_MIN_BANDED = tuple(_in_bands(f, _mask_rows, INF) for f in _ROWS_MIN)
+
+
 def neighbor_rows_min(
     per_peer: jnp.ndarray, conns: jnp.ndarray, batch_factor: int = 1,
 ) -> jnp.ndarray:
@@ -458,16 +664,14 @@ def neighbor_rows_min(
     no slot is selected: no (N, C, C) iota mask exists, which inside a
     while_loop XLA would hoist as a loop invariant and keep in HBM (0.5 GB
     as pred at 100k x 40; ops/disseminate._converge_prefix). Same budget
-    dispatch as `_row_pull`; over it, XLA's scalar gather."""
-    if exceeds_budget(per_peer.dtype, conns.shape, batch_factor):
-        out = _rows_scalar(per_peer, conns)
-    elif batch_factor <= 1:
-        out = _rows_min_one(per_peer, conns)
-    else:
-        out = _lanes_in_the_row(
-            _rows_min_one, _rows_min_packed, _rows_scalar, batch_factor,
-            rank=2)(per_peer, conns)
-    return jnp.where(conns >= 0, out, INF)
+    dispatch as `_row_pull`; over it, XLA's scalar gather. `conns` may be
+    `Banded`."""
+    if isinstance(conns, Banded):
+        return _row_pull(per_peer, _flat(conns), _ROWS_MIN_BANDED,
+                         conns.shape, batch_factor, rank=2)
+    out = _row_pull(per_peer, (conns,), _ROWS_MIN, conns.shape, batch_factor,
+                    rank=2)
+    return _mask_rows(out, conns)
 
 
 def reciprocal_pull_min(
@@ -476,6 +680,10 @@ def reciprocal_pull_min(
 ) -> jnp.ndarray:
     """out[q, j] = vals[conns[q,j], rev[q,j]] for float vals; INF on invalid
     slots. Exactly-one-hot select via masked min (INF-safe: the fill value
-    is the identity of min and also the 'absent' sentinel)."""
-    out = _row_pull(vals, conns, rev, _PULL_MIN, batch_factor)
-    return jnp.where((conns >= 0) & (rev >= 0), out, INF)
+    is the identity of min and also the 'absent' sentinel). `conns` and
+    `rev` may be `Banded`."""
+    if isinstance(conns, Banded):
+        return _row_pull(vals, _flat(conns, rev), _PULL_MIN_BANDED,
+                         conns.shape, batch_factor)
+    out = _row_pull(vals, (conns, rev), _PULL_MIN, conns.shape, batch_factor)
+    return _mask_min(out, conns, rev)

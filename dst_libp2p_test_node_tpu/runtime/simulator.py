@@ -34,6 +34,7 @@ from ..ops.disseminate import (fixpoint_formulation, fragments_in_sequence,
                                valid_edge_of)
 from ..ops.graph import ConnGraph, build_connection_graph
 from ..ops.heartbeat import PULL_COUNTS, PULL_STAGES, run_heartbeats
+from ..ops.pull import make_pull_bands, pull_rows_share
 from ..ops.state import SimParams, graph_arrays, init_state
 from .logemit import LatenciesWriter
 from .profiling import count_device_read, counters, device_read, span
@@ -200,6 +201,7 @@ def _host(x, dtype=None):
 def record_from_result(
     res, *, msg_id: int, publisher: int, t0_ms: float,
     extra_delay_ms: float = 0.0, drop_self=None, lanes_in_pull: int = 1,
+    pull_rows_share: float = 100.0,
 ) -> "MessageRecord":
     """Build a MessageRecord from a DisseminationResult (shared by the
     single-topic and multi-topic publish paths). `drop_self`: peer id (or
@@ -246,6 +248,7 @@ def record_from_result(
         lanes_hinted=lanes_hinted,
         lanes_uncertified=lanes_uncertified,
         lanes_in_pull=lanes_in_pull,
+        pull_rows_share=pull_rows_share,
         alive=alive,
         under_dlow=under_dlow,
     )
@@ -305,6 +308,9 @@ class MessageRecord:
     # this publish's fixpoints carried (a trace-time constant of its shape,
     # no device read)
     lanes_in_pull: int = 1
+    # ops/pull.pull_rows_share: 100 x the rows one pull of this publish
+    # gathered / (peers x slots): 100 without the bands (make_pull_bands)
+    pull_rows_share: float = 100.0
     # DisseminationResult.alive / under_dlow: under churn, the peers that
     # could send at this publish and those of them under D_low valid mesh
     # members (`stats<i>.json` "churn"); None without churn
@@ -419,6 +425,9 @@ class Simulator:
                 if self._ans_tables is not None:
                     self._ans_tables = jax.tree_util.tree_map(
                         lambda x: reshard_rows(x, mesh), self._ans_tables)
+            # so are the two bands of a publish's row pulls, where this
+            # graph and shape admit them (ops/pull.make_pull_bands)
+            self._pull_bands = self._compute_pull_bands()
             # neighbor alive&subscribed validity is publish-invariant between
             # membership changes: maintained here (set_subscribed recomputes,
             # churn disables the hoist — heartbeats mutate alive on device)
@@ -472,6 +481,17 @@ class Simulator:
 
             self.mix_params = MixParams(num_mix=cfg.num_mix, mix_d=cfg.mix_d)
             self.mix_params.validate()
+
+    def _compute_pull_bands(self):
+        """The hoisted bands of a publish's row pulls over the graph in
+        `self.arrays` (slots [0, C1) of every row, the rest of the heavy
+        rows), or None where the whole-width pull stays: a small or skewed
+        graph, a mesh, past the gather budget (ops/pull.make_pull_bands)."""
+        t = self._ans_tables
+        return make_pull_bands(
+            self.arrays["conns"], self.arrays["rev"],
+            None if t is None else t.conns_sorted,
+            None if t is None else t.rev_sorted, mesh=self.mesh)
 
     def _compute_valid_edge(self):
         """Hoisted per-edge delivery validity (connected AND the neighbor
@@ -651,6 +671,7 @@ class Simulator:
                 self._ans_tables = jax.tree_util.tree_map(
                     lambda x: reshard_rows(x, self.mesh), self._ans_tables)
             warm = reshard_rows(warm, self.mesh)
+        self._pull_bands = self._compute_pull_bands()
         self.state = self.state.replace(warm_offset_ms=warm)
         if not self._churny:
             self._valid_edge = self._compute_valid_edge()
@@ -813,6 +834,7 @@ class Simulator:
                     ans_tables=self._ans_tables,
                     valid_edge=valid_edge,
                     censor_edge=censor_edge,
+                    pull_bands=self._pull_bands,
                     # unsubscribed publisher -> gossipsub v1.1 fanout publish
                     with_fanout=not bool(self._subscribed_np[publisher]),
                 )
@@ -845,6 +867,7 @@ class Simulator:
                     ] or None,
                     lanes_in_pull=lanes_in_pull(
                         a["conns"].shape, cfg.topo.num_frags, self.mesh),
+                    pull_rows_share=pull_rows_share(self._pull_bands),
                 )
                 self.records.append(rec)
             # the publish's counters, with the shape of its fixpoint loops
@@ -866,6 +889,7 @@ class Simulator:
                 in_sequence=int(fragments_in_sequence(
                     a["conns"].shape, cfg.topo.num_frags, self.mesh)),
                 lanes_in_pull=rec.lanes_in_pull,
+                pull_rows_share=rec.pull_rows_share,
                 **({} if rec.alive is None else
                    {"alive": rec.alive, "under_dlow": rec.under_dlow}))
         return rec

@@ -12,7 +12,9 @@ chip time. Shapes are the 100,000-peer headline config's
     collective the design rests on is in the HLO and the per-device memory
     fits a v5e;
   - both halves of the heartbeat scan, and `ops/kad.find_node` at the
-    kad-10k cell's probe tick (10,000 peers): what its response sorts.
+    kad-10k cell's probe tick (10,000 peers): what its response sorts;
+  - a publish's row pull through its two bands (ops/pull.make_pull_bands),
+    one lane and four: the gathered rows the compiler keeps are the bands'.
 
 Everything that touches the topology lives in fixtures of THIS file (one
 xdist worker loads the TPU library, only after a test here has started);
@@ -133,6 +135,41 @@ def test_sharded_fixpoint_compiles_for_four_chips(peer_mesh, capacity):
     assert _device_bytes(compiled) < V5E_HBM_BYTES
     t_out, inc_out, _, _ = compiled.output_shardings
     assert t_out.spec == P("peers") and inc_out.spec == P("peers")
+
+
+@pytest.mark.parametrize("lanes", [1, 4])
+def test_banded_pull_compiles_for_v5e_and_keeps_fewer_rows(
+        one_chip, capacity, lanes):
+    """ISSUE 50: the pull through slots [0, 24) of every row and slots
+    [24, 40) of 12,504 heavy rows compiles for the chip with three gathers
+    and no kernel, and its temporaries (the padded gathered rows: 2.05 GB a
+    whole pull, 4.1 GB for four lanes) shrink with the rows, 65 % of them."""
+    from dst_libp2p_test_node_tpu.ops import pull
+
+    c1, m = pull.band_shape((N, capacity))
+    assert (c1, m) == (24, 12504)
+    vals = one_chip(((lanes,) if lanes > 1 else ()) + (N, capacity),
+                    jnp.float32)
+    whole = (one_chip((N, capacity), jnp.int32),) * 2
+    back = one_chip((N,), jnp.int32)
+    bands = tuple(
+        pull.Banded(one_chip((N, c1), jnp.int32),
+                    one_chip((m, capacity - c1), jnp.int32), back)
+        for _ in range(2))
+
+    def fn(v, conns, rev):
+        if lanes == 1:
+            return pull.reciprocal_pull_min(v, conns, rev)
+        return jax.vmap(lambda x: pull.reciprocal_pull_min(
+            x, conns, rev, batch_factor=lanes))(v)
+
+    temp = {}
+    for name, index in (("whole", whole), ("bands", bands)):
+        compiled = jax.jit(fn).lower(vals, *index).compile()
+        assert "tpu_custom_call" not in compiled.as_text()
+        temp[name] = compiled.memory_analysis().temp_size_in_bytes
+    assert temp["whole"] > 2.0e9 * (2 if lanes > 1 else 1)
+    assert temp["bands"] < 0.72 * temp["whole"], temp
 
 
 @pytest.mark.parametrize("churn", [1e-4, 0.0])

@@ -1,4 +1,5 @@
 import numpy as np
+import pytest
 import jax.numpy as jnp
 
 from dst_libp2p_test_node_tpu.config.topology import Topology, TopoParams
@@ -542,3 +543,101 @@ def test_lost_tx_counts_network_losses_only_not_graylist_drops():
     lost = int(np.asarray(res_l.lost_tx).sum())
     sent = int(np.asarray(res_l.sends).sum())
     assert 0.2 <= lost / sent <= 0.4, (lost, sent)
+
+
+# ------------------------------------ the two bands of a publish's row pulls
+
+def _banded_net(**over):
+    """A 2,000-peer network with its hoisted tables and the bands of its
+    pulls, forced under the size test through the maker's own arguments
+    (`band_shape` at (2000, 40): C1 = 24, room for 256 heavy rows)."""
+    from dst_libp2p_test_node_tpu.ops.disseminate import (
+        answer_tables, edge_tables)
+    from dst_libp2p_test_node_tpu.ops.pull import make_pull_bands
+
+    g, params, state, a, (stage, lat, bw) = mesh_setup(
+        n=2000, seed=5, **over)
+    conns, rev = a["conns"], a["rev"]
+    lat_edge, _ = edge_tables(stage, lat, conns, rev, None)
+    ans = answer_tables(lat_edge, conns, rev)
+    assert make_pull_bands(conns, rev) is None        # under the size test
+    bands = make_pull_bands(
+        conns, rev, ans.conns_sorted, ans.rev_sorted, min_bytes=0)
+    heavy = int((np.asarray(bands.back) < bands.tails["conns"].shape[0]).sum())
+    assert 100 < heavy <= 256 and bands.heads["rev_sorted"].shape == (2000, 24)
+    mesh_only = make_pull_bands(conns, rev, min_bytes=0)
+    return (params, state, conns, rev, stage, lat, bw, lat_edge, ans,
+            bands, mesh_only)
+
+
+_BANDED_PUBLISHES = {
+    # name: (network's SimParams overrides, disseminate's keywords, refines?)
+    "gossip_f1": ({}, dict(payload_bytes=15000), None),
+    "gossip_f4_refined": ({}, dict(payload_bytes=131072, fragments=4), True),
+    "meshonly_f1": ({}, dict(payload_bytes=15000, with_gossip=False), False),
+    "meshonly_f4": ({}, dict(payload_bytes=15000, fragments=4,
+                             with_gossip=False), False),
+    "loss_tcp_f4": ({}, dict(payload_bytes=15000, fragments=4,
+                             loss_mode="tcp", lossy=True), None),
+    "churn_dead_neighbours": (
+        dict(churn_down_per_hb=1e-3, churn_up_per_hb=5e-4),
+        dict(payload_bytes=15000, fragments=4, dead_neighbours=True), None),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_BANDED_PUBLISHES))
+def test_publish_through_the_bands_is_the_publish(name):
+    """ISSUE 50: `disseminate` with `pull_bands` returns every leaf of the
+    result and of the next state that it returns without, bit for bit:
+    gossip on and off, one fragment and four joint lanes, tcp loss draws, a
+    publisher whose neighbours are dead under churn (its own validity
+    pull), and the refined branch."""
+    over, kw, refines = _BANDED_PUBLISHES[name]
+    kw = dict(kw)
+    (params, state, conns, rev, stage, lat, bw, lat_edge, ans, bands,
+     mesh_only) = _banded_net(**over)
+    gossip = kw.get("with_gossip", True)
+    if kw.pop("lossy", False):
+        kw["loss_stage"] = jnp.full((6, 6), 0.2, jnp.float32)
+    publisher = 3
+    if kw.pop("dead_neighbours", False):
+        alive = np.asarray(state.alive).copy()
+        assert not alive.all()                  # the churned scan killed some
+        nbrs = np.asarray(conns)[publisher]
+        alive[nbrs[nbrs >= 0][::2]] = False
+        alive[publisher] = True
+        state = state.replace(alive=jnp.asarray(alive))
+    common = dict(publisher=publisher, t0_ms=float(state.t_ms), params=params,
+                  ans_tables=ans if gossip else None, **kw)
+    if "loss_stage" not in kw:
+        common["lat_edge"] = lat_edge
+    want = disseminate(state, conns, rev, stage, lat, bw, **common)
+    got = disseminate(state, conns, rev, stage, lat, bw, **common,
+                      pull_bands=bands if gossip else mesh_only)
+    import jax
+
+    leaves_w, tree_w = jax.tree_util.tree_flatten(want)
+    leaves_g, tree_g = jax.tree_util.tree_flatten(got)
+    assert tree_w == tree_g
+    for w, g in zip(leaves_w, leaves_g):
+        assert np.asarray(w).tobytes() == np.asarray(g).tobytes()
+    res = want[0]
+    assert bool(np.asarray(res.received).sum() > 1000)
+    if refines is not None:
+        assert bool(res.refined) is refines
+    if name == "churn_dead_neighbours":
+        assert int(res.alive) < 2000
+
+
+def test_pull_bands_are_for_the_row_pull_formulation(monkeypatch):
+    """Past the gather budget no row is pulled ("recv"): bands there are a
+    caller's error, said at trace time."""
+    import dst_libp2p_test_node_tpu.ops.pull as pull
+
+    (params, state, conns, rev, stage, lat, bw, lat_edge, ans, bands,
+     _) = _banded_net()
+    monkeypatch.setattr(pull, "_MAX_INTERMEDIATE_BYTES", 1)
+    with pytest.raises(ValueError, match="row_pull"):
+        disseminate(state, conns, rev, stage, lat, bw, publisher=3,
+                    t0_ms=float(state.t_ms), params=params,
+                    payload_bytes=14000, pull_bands=bands)

@@ -508,3 +508,194 @@ def test_vmapped_trials_of_one_graph_keep_their_bits(edges):
             if jnp.issubdtype(y.dtype, jax.dtypes.prng_key):
                 x, y = jax.random.key_data(x), jax.random.key_data(y)
             assert np.asarray(x[k]).tobytes() == np.asarray(y).tobytes()
+
+
+# ------------------------------------------- the two bands of a publish's pull
+
+BC, BC1 = 16, 8     # slots and the cut of the hand-made graphs below
+
+
+def _graph_of(n, c, pairs):
+    """conns / rev of the undirected `pairs`, a row's slots filled from the
+    front in the order given (build_connection_graph's layout)."""
+    conns = np.full((n, c), -1, np.int32)
+    rev = np.full((n, c), -1, np.int32)
+    deg = np.zeros(n, np.int64)
+    for p, q in pairs:
+        i, j = deg[p], deg[q]
+        conns[p, i], rev[p, i], conns[q, j], rev[q, j] = q, j, p, i
+        deg[p] += 1
+        deg[q] += 1
+    return conns, rev
+
+
+def _cut(conns, rev, p, i):
+    """Clear slot i of row p and its reverse slot: a hole where it lies in
+    front of a filled slot (what ops/connmanager and ops/repair leave)."""
+    q, j = conns[p, i], rev[p, i]
+    conns[p, i] = rev[p, i] = conns[q, j] = rev[q, j] = -1
+
+
+@pytest.fixture(scope="module", params=["degrees", "holes"])
+def banded(request):
+    """A 40-peer graph whose rows have degree C (peer 0), exactly C1 (1),
+    C1 + 1 (2) and 0 (39); `holes`: with cleared slots in front of filled
+    ones, one of which leaves peer 2 with C1 connections and one of them
+    still in slot C1 (heavy by its slots, not by its degree). With it the
+    lat-sorted tables and the bands of all four index arrays."""
+    from dst_libp2p_test_node_tpu.ops.disseminate import answer_tables
+
+    pairs = ([(0, q) for q in range(1, BC + 1)]
+             + [(1, q) for q in range(17, 17 + BC1 - 1)]
+             + [(2, q) for q in range(17, 17 + BC1)]
+             + [(30, 31), (31, 32), (5, 33)])
+    conns, rev = _graph_of(40, BC, pairs)
+    deg = (conns >= 0).sum(axis=-1)
+    assert [deg[0], deg[1], deg[2], deg[39]] == [BC, BC1, BC1 + 1, 0]
+    if request.param == "holes":
+        _cut(conns, rev, 0, 2)
+        _cut(conns, rev, 2, 0)
+        _cut(conns, rev, 18, 0)
+        assert (conns[2] >= 0).sum() == BC1 and conns[2, BC1] >= 0
+    conns, rev = jnp.asarray(conns), jnp.asarray(rev)
+    lat_edge = jnp.where(
+        conns >= 0,
+        40.0 + 90.0 * jax.random.uniform(jax.random.PRNGKey(5), conns.shape),
+        0.0)
+    tabs = answer_tables(lat_edge, conns, rev)
+    bands = pull.make_pull_bands(
+        conns, rev, tabs.conns_sorted, tabs.rev_sorted,
+        min_bytes=0, c1=BC1, rows=4)
+    assert bands is not None
+    return conns, rev, tabs, bands
+
+
+# what is pulled, through which two index arrays (None: no reverse map)
+_BANDED_PULLS = {
+    "min": (pull.reciprocal_pull_min, "f32", "conns", "rev"),
+    "bool": (pull.reciprocal_pull_bool, "bool", "conns", "rev"),
+    "min_lat": (pull.reciprocal_pull_min, "f32", "conns", "rev_sorted"),
+    "rows_min": (pull.neighbor_rows_min, "peer", "conns", None),
+    "rows_min_lat": (pull.neighbor_rows_min, "peer", "conns_sorted", None),
+    "neighbor_min": (pull.neighbor_pull_min, "peer", "conns", "rev"),
+    "neighbor_bool": (pull.neighbor_pull_bool, "peer_bool", "conns", "rev"),
+}
+
+
+def _banded_case(name, banded, lanes):
+    """(fn, vals, whole index, banded index) of a `_BANDED_PULLS` case,
+    `vals` with a leading axis of `lanes` where lanes > 1."""
+    conns, rev, tabs, bands = banded
+    fn, kind, *names = _BANDED_PULLS[name]
+    arrays = {"conns": conns, "rev": rev, "conns_sorted": tabs.conns_sorted,
+              "rev_sorted": tabs.rev_sorted}
+    n, c = conns.shape
+    lead = (lanes,) if lanes > 1 else ()
+    key = jax.random.PRNGKey(len(name) + lanes)
+    if kind in ("f32", "bool"):
+        vals = _rows_of(kind, key, int(np.prod(lead + (n,))), c).reshape(
+            lead + (n, c))
+    else:
+        u = jax.random.uniform(key, lead + (n,))
+        vals = (u < 0.5 if kind == "peer_bool"
+                else jnp.where(u < 0.7, u * 1e6, pull.INF))
+    names = [x for x in names if x is not None]
+    return (fn, vals, [arrays[x] for x in names],
+            [bands.of(x) for x in names])
+
+
+@pytest.mark.parametrize("lanes", [1, 4])
+@pytest.mark.parametrize("name", sorted(_BANDED_PULLS))
+def test_banded_pull_is_the_whole_pull(banded, name, lanes):
+    """ISSUE 50: every pull of the publish through the two bands of its
+    index (slots [0, C1) of every row, the rest of the heavy rows) returns
+    the whole-width pull's bits: rows of degree 0, C1, C1 + 1 and C, rows
+    with holes, the slot and the lat-sorted layout, one lane and four
+    declared lanes under vmap, eager and jitted."""
+    fn, vals, whole, bands = _banded_case(name, banded, lanes)
+
+    def run(index):
+        if lanes == 1:
+            return fn(vals, *index)
+        return jax.vmap(lambda v: fn(v, *index, batch_factor=lanes))(vals)
+
+    want = np.asarray(run(whole))
+    got = run(bands)
+    assert got.shape == want.shape and got.dtype == want.dtype
+    assert np.asarray(got).tobytes() == want.tobytes()
+    assert np.asarray(jax.jit(run)(bands)).tobytes() == want.tobytes()
+    # the heavy rows are where the test says they are: band B is not idle
+    conns = np.asarray(banded[0])
+    assert (conns[:, BC1:] >= 0).any(axis=-1).sum() in (2, 3)
+
+
+def _gathers(jaxpr):
+    """(output shape, batched?) of every gather in a printed jaxpr."""
+    import re
+
+    return [(tuple(int(d) for d in m.group(1).split(",")),
+             "operand_batching_dims=()" not in m.group(2))
+            for m in re.finditer(
+                r"\w+\[([\d,]+)\] = gather\[(.*?)\n\s+\] ", str(jaxpr), re.S)]
+
+
+@pytest.mark.parametrize("name", ["min", "bool", "rows_min"])
+def test_four_lanes_share_one_gather_a_band(banded, name):
+    """Four declared lanes under vmap: ONE gather a band with every lane in
+    the gathered row and one for band B's way back, none of them XLA's own
+    batching of a one-lane gather (388 ms against 25.7 at 100k peers); the
+    index shapes are (N, C1) and (M, C - C1), then (N,)."""
+    fn, vals, _, bands = _banded_case(name, banded, 4)
+    n, c, m = 40, BC, 4
+    jaxpr = jax.make_jaxpr(
+        jax.vmap(lambda v: fn(v, *bands, batch_factor=4)))(vals)
+    width = 4 if name == "rows_min" else 4 * c
+    assert _gathers(jaxpr) == [
+        ((n, BC1, width), False), ((m, c - BC1, width), False),
+        ((n, 4 * (c - BC1)), False)]
+    # and one lane: the same three, one table wide
+    one = jax.make_jaxpr(lambda v: fn(v, *bands))(vals[0])
+    width = BC1 if name == "rows_min" else c
+    assert [g[0] for g in _gathers(one)][:2] == [
+        (n, BC1, width), (m, c - BC1, c - BC1 if name == "rows_min" else c)]
+
+
+@pytest.mark.parametrize("why", ["size", "heavy", "mesh", "budget", "cut"])
+def test_no_bands_where_the_whole_pull_stays(banded, monkeypatch, why):
+    """The maker returns None under the size test (the small shapes keep
+    the one program they had), with more than M heavy rows (a skewed or
+    capped graph), on a mesh, past the gather budget and where the cut
+    leaves no second band."""
+    conns, rev, tabs, _ = banded
+    kw = dict(min_bytes=0, c1=BC1, rows=4)
+    if why == "size":
+        kw.pop("min_bytes")
+    elif why == "heavy":
+        kw["rows"] = 1
+    elif why == "mesh":
+        kw["mesh"] = object()
+    elif why == "budget":
+        monkeypatch.setattr(pull, "_MAX_INTERMEDIATE_BYTES", 1)
+    else:
+        kw["c1"] = BC
+    assert pull.make_pull_bands(conns, rev, **kw) is None
+    assert pull.pull_rows_share(None) == 100.0
+
+
+def test_band_shape_is_static_and_the_reference_graph_fits_it():
+    """(C1, M) comes from the shape alone, (24, 12504) at (100000, 40), 65 %
+    of the rows; the graph of the 100,000-peer cells is front-compacted,
+    half pads, and 8.4 % of its rows are heavy, so the bands exist there."""
+    assert pull.band_shape((100000, 40)) == (24, 12504)
+    assert pull.band_shape((2000, 40)) == (24, 256)
+    g = build_connection_graph(100000, 10, seed=3, max_degree=40)
+    filled = g.conns >= 0
+    assert 0.49 < filled.mean() < 0.51
+    assert (filled[:, :-1] >= filled[:, 1:]).all()      # front-compacted
+    heavy = int(filled[:, 24:].any(axis=-1).sum())
+    assert 7000 < heavy < 10000
+    bands = pull.make_pull_bands(jnp.asarray(g.conns), jnp.asarray(g.rev))
+    assert bands.heads["conns"].shape == (100000, 24)
+    assert bands.tails["rev"].shape == (12504, 16)
+    assert int((np.asarray(bands.back) < 12504).sum()) == heavy
+    assert pull.pull_rows_share(bands) == pytest.approx(65.0016)

@@ -371,3 +371,93 @@ def test_taking_a_publishs_plan_compiles_nothing_more(monkeypatch):
         retraces.events
     for got, want in zip(captured, plain):
         np.testing.assert_array_equal(got, want)
+
+
+# ------------------------------------ the two bands of a publish's row pulls
+
+def _spy_on_disseminate(monkeypatch):
+    """Record the `pull_bands` every publish of a Simulator passes."""
+    from dst_libp2p_test_node_tpu.runtime import simulator as simmod
+
+    original, seen = simmod.disseminate, []
+
+    def spy(*args, **kw):
+        seen.append(kw.get("pull_bands"))
+        return original(*args, **kw)
+
+    monkeypatch.setattr(simmod, "disseminate", spy)
+    return seen
+
+
+def test_a_small_simulator_publishes_through_the_whole_index(monkeypatch):
+    """Under the size test (a pull is microseconds) a Simulator makes no
+    bands, passes None, states 100 % on its records, and None IS the
+    program without the argument: the lowered text is the same."""
+    from dst_libp2p_test_node_tpu.ops.disseminate import disseminate
+
+    seen = _spy_on_disseminate(monkeypatch)
+    sim = Simulator(small_cfg())
+    assert sim._pull_bands is None
+    sim.warmup()
+    rec = sim.publish(4)
+    assert seen == [None] and rec.pull_rows_share == 100.0
+    a = sim.arrays
+    args = (sim.state, a["conns"], a["rev"], sim._stage, sim._lat, sim._bw)
+    kw = dict(publisher=4, t0_ms=1.0, params=sim.params, payload_bytes=15000,
+              lat_edge=sim._lat_edge, ans_tables=sim._ans_tables)
+    assert (disseminate.lower(*args, **kw).as_text()
+            == disseminate.lower(*args, **kw, pull_bands=None).as_text())
+
+
+@pytest.mark.parametrize("with_gossip", [True, False])
+def test_a_simulator_hoists_the_bands_and_rebind_graph_makes_them_again(
+        monkeypatch, with_gossip):
+    """Where the maker admits them (forced here at 2,000 peers) a Simulator
+    hoists the bands in `build/tables`, every publish passes them, and its
+    records are those of the whole-width publishes; `rebind_graph` derives
+    them from the graph it adopts, so a stale table cannot survive it."""
+    import functools
+
+    from dst_libp2p_test_node_tpu.ops.pull import make_pull_bands
+    from dst_libp2p_test_node_tpu.runtime import simulator as simmod
+
+    cfg = small_cfg(topo=dataclasses.replace(BASE, network_size=2000,
+                                             messages=2),
+                    seed=5, with_gossip=with_gossip)
+    plain = Simulator(cfg)
+    plain.run()
+    assert plain._pull_bands is None
+    monkeypatch.setattr(simmod, "make_pull_bands",
+                        functools.partial(make_pull_bands, min_bytes=0))
+    seen = _spy_on_disseminate(monkeypatch)
+    sim = Simulator(cfg)
+    bands = sim._pull_bands
+    assert bands is not None
+    assert set(bands.heads) == ({"conns", "rev", "conns_sorted", "rev_sorted"}
+                                if with_gossip else {"conns", "rev"})
+    sim.run()
+    assert len(seen) == 2 and all(b is bands for b in seen)
+    for got, want in zip(sim.records, plain.records):
+        assert got.pull_rows_share == pytest.approx(100 * (24 * 2000 + 16 * 256)
+                                                    / (40 * 2000))
+        assert got.delays_ms.tobytes() == want.delays_ms.tobytes()
+        assert got.sends.tobytes() == want.sends.tobytes()
+        assert (got.fast_iters, got.refine_passes) == (
+            want.fast_iters, want.refine_passes)
+    # a graph that lost an edge: row p's slot i and its reverse are holes
+    conns = np.asarray(sim.arrays["conns"]).copy()
+    rev = np.asarray(sim.arrays["rev"]).copy()
+    p = int(np.flatnonzero((conns[:, 24:] >= 0).any(axis=-1))[0])
+    q, j = conns[p, 0], rev[p, 0]
+    conns[p, 0] = rev[p, 0] = conns[q, j] = rev[q, j] = -1
+    sim.rebind_graph(conns, rev, np.asarray(sim.arrays["out_mask"]))
+    again = sim._pull_bands
+    assert again is not bands
+    np.testing.assert_array_equal(np.asarray(again.heads["conns"]),
+                                  conns[:, :24])
+    assert int(np.asarray(again.back)[p]) < again.tails["conns"].shape[0]
+    np.testing.assert_array_equal(
+        np.asarray(again.tails["rev"])[int(np.asarray(again.back)[p])],
+        rev[p, 24:])
+    sim.publish(4)
+    assert seen[-1] is again

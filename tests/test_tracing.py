@@ -172,8 +172,10 @@ def test_stats_json_spans_and_publishes_are_strict_json(two_turns):
             assert set(p) == {"fast_iters", "refine_passes", "refined",
                               "fell_back", "converged", "refined_serial",
                               "refine_lane_passes", "lanes_hinted",
-                              "lanes_uncertified", "lanes_in_pull"}
+                              "lanes_uncertified", "lanes_in_pull",
+                              "pull_rows_share"}
             assert p["lanes_in_pull"] == 1      # one fragment: no lane axis
+            assert p["pull_rows_share"] == 100.0    # under the size test
             assert isinstance(p["fast_iters"], int) and p["fast_iters"] > 0
             assert p["converged"] is True and p["fell_back"] is False
 
@@ -652,3 +654,34 @@ def test_a_program_outside_a_turn_names_the_span_it_fell_in(fresh_process):
         "import_to_main_s": None,
         "backend_s": got["spans"]["setup/backend"]["total_s"],
         "import_to_first_turn_s": None}
+
+
+@pytest.mark.parametrize("banded", [False, True])
+def test_pull_rows_share_on_the_counters_and_in_stats_json(
+        tmp_path, monkeypatch, banded):
+    """ISSUE 50: the share of an (N, C) index's rows one pull of the publish
+    gathers, stated on the `sim:publish/counters` annotation (what the
+    benchmark's `publish.pull_rows_share` reads) and in `stats<i>.json`
+    "publishes": 100 where the maker refuses bands (200 peers: under the
+    size test), the bands' share where it makes them (forced here)."""
+    import functools
+
+    from dst_libp2p_test_node_tpu.ops.pull import make_pull_bands
+    from dst_libp2p_test_node_tpu.runtime import simulator as simmod
+
+    seen = []
+    monkeypatch.setattr(
+        simmod, "counters", lambda name, **values: seen.append((name, values)))
+    if banded:
+        monkeypatch.setattr(
+            simmod, "make_pull_bands",
+            functools.partial(make_pull_bands, min_bytes=0, rows=64))
+    rc = cli.main(["run", "1", "200", "15000", "1", "2", "50",
+                   "150", "40", "130", "5", "0.0", "4", "0", "4000", "--seed",
+                   "3", "--stats-json", "--out-prefix", str(tmp_path) + os.sep])
+    assert rc == 0
+    want = 100.0 * (200 * 24 + 64 * 16) / (200 * 40) if banded else 100.0
+    on_publish = [v for name, v in seen if name == "publish/counters"]
+    assert [v["pull_rows_share"] for v in on_publish] == [want] * 2
+    publishes = _strict(tmp_path / "stats1.json")["publishes"]
+    assert [p["pull_rows_share"] for p in publishes] == [want] * 2
