@@ -186,6 +186,7 @@ def _publish_counts(records) -> list[dict]:
     """`stats<i>.json` "publishes": a message how much work its fixpoints
     did and which branches ran (MessageRecord)."""
     return [{"fast_iters": r.fast_iters,
+             "fast_sparse_iters": r.fast_sparse_iters,
              "refine_passes": r.refine_passes,
              "refined": r.refined,
              "fell_back": r.fell_back,

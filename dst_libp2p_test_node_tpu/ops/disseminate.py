@@ -108,8 +108,10 @@ from .pull import (
     neighbor_pull_min,
     neighbor_rows_min,
     permute_rows,
+    pull_moved_min,
     reciprocal_pull_bool,
     reciprocal_pull_min,
+    relax_route,
 )
 from .state import SimParams, SimState
 
@@ -236,18 +238,19 @@ class DisseminationResult:
     #                            pass-count budget of the exactness
     #                            certificate pins this on canonical
     #                            topologies (tests/test_exact_prefix.py).
-    counters: jnp.ndarray      # (9,) int32 — [fast_iters, refine_passes,
+    counters: jnp.ndarray      # (10,) int32 — [fast_iters, refine_passes,
     #                            refined, fell_back, converged,
     #                            refined_serial, refine_lane_passes,
-    #                            lanes_hinted, lanes_uncertified], and
-    #                            under churn (11,): [..., alive,
+    #                            lanes_hinted, lanes_uncertified,
+    #                            fast_sparse_iters], and
+    #                            under churn (12,): [..., alive,
     #                            under_dlow]: how much
     #                            work the publish's fixpoints did and which
     #                            branches ran, packed so that the host
     #                            takes them in ONE device->host read
     #                            (runtime/simulator.record_from_result) and
-    #                            the jit returns one leaf more, not eight.
-    #                            The seven that are no field of their own
+    #                            the jit returns one leaf more, not nine.
+    #                            The eight that are no field of their own
     #                            are the properties below.
 
     @property
@@ -300,11 +303,20 @@ class DisseminationResult:
         return self.counters[..., 8]
 
     @property
+    def fast_sparse_iters(self):
+        """() int32 — of `fast_iters`, the iterations that delivered the
+        offers of the rows that moved into the carried offer matrix
+        (ops/pull.pull_moved_min) instead of pulling every row's: max over
+        the fragment lanes, which take that side together. 0 off "row_pull"
+        and under ops/pull.relax_route's size."""
+        return self.counters[..., 9]
+
+    @property
     def alive(self):
         """() int32 — peers that could send at this publish (alive and
         subscribed, and the fanout publisher). Under churn only
         (`SimParams.churn_*_per_hb`); None without."""
-        return (self.counters[..., 9] if self.counters.shape[-1] > 9
+        return (self.counters[..., 10] if self.counters.shape[-1] > 10
                 else None)
 
     @property
@@ -312,7 +324,7 @@ class DisseminationResult:
         """() int32 — of those, the peers whose valid mesh degree was under
         D_low at this publish: what the heartbeats' repair had not yet
         mended. Under churn only; None without."""
-        return (self.counters[..., 10] if self.counters.shape[-1] > 9
+        return (self.counters[..., 11] if self.counters.shape[-1] > 10
                 else None)
 
 
@@ -1160,14 +1172,38 @@ def disseminate(
         carry) may undershoot and stick; callers verify the returned
         self-consistency certificate (see phases_fast) and fall back cold.
 
-        Returns (t, inc, ok, iters): the fixpoint, the deliver-only
+        Returns (t, inc, ok, iters, few): the fixpoint, the deliver-only
         incoming-offer matrix of the loop's LAST pass — the no-change
         confirmation pass evaluates it at the final times, so the matrix
         the first-sender attribution and the certificate need rides out of
         the loop for FREE instead of costing another offers()+pull — the
         convergence bit (False = the iteration cap cut the loop and `inc`
-        is one pass stale), and the loop's iteration count (all three
-        engines report it: DisseminationResult.fast_iters)."""
+        is one pass stale), the loop's iteration count (all three
+        engines report it: DisseminationResult.fast_iters), and how many
+        of those iterations delivered the moved rows' offers
+        (DisseminationResult.fast_sparse_iters; 0 off "row_pull").
+
+        The moved rows ("row_pull", where ops/pull.relax_route says the
+        dense pull is worth avoiding). An entry inc[q, j] is a function of
+        ONE sender's time, t[conns[q, j]], and of loop-invariant tables, and
+        the relaxation is monotone, so an iteration changes the entries of
+        the senders whose time moved in the iteration before and no other.
+        The carry holds that mask, `moved`, and the body asks
+        ops/pull.pull_moved_min for the pull: with at most K moved rows it
+        evaluates the offers of those K rows and writes them into the
+        carried `inc` at (conns[p, i], rev[p, i]); with more it pulls every
+        row's, as ever. `inc` stays the dense body's at every iteration, by
+        induction: before iteration 0 it is all INF and `moved` is t0 < INF
+        (the publisher alone when cold; everybody under a `t_init`, so a
+        warm or phase-2 loop starts dense), which is the dense pull at t0
+        restricted to the rows that can offer (a sender at INF offers INF);
+        if `inc` is the pull of the offers at the times before an
+        iteration's update and `moved` the rows that update lowered, then
+        overwriting the moved rows' entries with their offers at the new
+        times gives the pull at the new times, the other entries being
+        functions of times that did not change. So `t`, the returned `inc`,
+        the convergence bit and the count are the dense loop's bit for bit,
+        the cap's "one pass stale" included."""
         t0 = (jnp.full((n,), INF) if t_init is None else t_init
               ).at[publisher].set(t_pub)
         # arrival times are about DELIVERY: lost copies never relax an edge
@@ -1187,7 +1223,8 @@ def disseminate(
                 lat_deliver=ld, ld_gossip=_ld_ans(frag_idx),
             )
             with jax.named_scope("fixpoint"):
-                return converge_sharded(t0, c, params.max_relax_iters, mesh)
+                return converge_sharded(
+                    t0, c, params.max_relax_iters, mesh) + (jnp.int32(0),)
         if formulation == "recv":
             # large N (1M-peer class): the row-gather pull would blow the
             # memory budget and its 2-index fallback costs ~0.7 s/iteration —
@@ -1203,7 +1240,8 @@ def disseminate(
                 lat_deliver=ld, ld_gossip=_ld_ans(frag_idx),
             )
             with jax.named_scope("fixpoint"):
-                return converge_recv(t0, c, params.max_relax_iters)
+                return converge_recv(
+                    t0, c, params.max_relax_iters) + (jnp.int32(0),)
         # single device below the budget: sender-major offers (loop-invariant
         # parts hoisted here), row-gather pull per iteration — ~2.5x the
         # per-iteration speed of a receiver-side index gather (ops/pull.py)
@@ -1213,13 +1251,13 @@ def disseminate(
         g_base = jnp.where(
             g_deliver & can_send[:, None],
             2.0 * lat_edge + _ld_ans(frag_idx) + tx_ms[:, None], INF)
+        senders = (uplink, a_base) + (
+            (hb_phase, g_off, g_base) if with_gossip else ())
 
-        def cond(carry):
-            _, _, changed, it = carry
-            return changed & (it < params.max_relax_iters)
-
-        def body(carry):
-            t_rx, _, _, it = carry
+        def cand_of(t_rx, uplink, a_base, hb_phase=None, g_off=None,
+                    g_base=None):
+            """The offers of the rows given: row p from `t_rx[p]` and row p
+            of each table alone."""
             live = (t_rx < INF)[:, None]
             base = t_rx + params.proc_delay_ms
             start = jnp.maximum(base, uplink)
@@ -1231,12 +1269,32 @@ def disseminate(
                     jnp.where(live,
                               jnp.maximum(hb[:, None] + g_off,
                                           uplink[:, None]) + g_base, INF))
-            inc = pull(cand)
+            return cand
+
+        # where the dense pull is worth avoiding (ops/pull.relax_route) the
+        # carry holds the rows that moved, and how often they were few
+        by_rows = relax_route(conns.shape)
+
+        def cond(carry):
+            _, _, changed, it = carry[:4]
+            return changed & (it < params.max_relax_iters)
+
+        def body(carry):
+            t_rx, inc, _, it = carry[:4]
+            if by_rows:
+                moved, few = carry[4:]
+                inc, sparse = pull_moved_min(
+                    cand_of, t_rx, inc, moved, senders, conns, rev,
+                    p_conns, p_rev, batch_factor=lanes)
+            else:
+                inc = pull(cand_of(t_rx, *senders))
             # downlink clamp (max distributes over the row min, so clamping
             # the min equals clamping every candidate)
             t_new = jnp.minimum(
                 t_rx, jnp.maximum(inc.min(axis=-1), rx_const))
-            return t_new, inc, jnp.any(t_new < t_rx), it + 1
+            moved = t_new < t_rx
+            out = t_new, inc, jnp.any(moved), it + 1
+            return out + ((moved, few + sparse) if by_rows else ())
 
         # (a mesh-only pre-relaxation before the full loop was measured
         # NET-WORSE here r4: the per-iteration cost is pull-dominated, so
@@ -1245,11 +1303,12 @@ def disseminate(
         # iteration counter carries a STRONG int32: a Python-int carry is
         # weak-typed and re-promotes on feed-back (graft-audit GA-J002)
         with jax.named_scope("fixpoint"):
-            t_rx, inc, changed, it = jax.lax.while_loop(
+            t_rx, inc, changed, it, *rows = jax.lax.while_loop(
                 cond, body,
                 (t0, jnp.full(conns.shape, INF), jnp.bool_(True),
-                 jnp.int32(0)))
-        return t_rx, inc, ~changed, it
+                 jnp.int32(0))
+                + ((t0 < INF, jnp.int32(0)) if by_rows else ()))
+        return t_rx, inc, ~changed, it, rows[1] if by_rows else jnp.int32(0)
 
     def _converge_floor(rank, k_p, frag_idx, t_pub, send_mask, g_floor,
                         t_init):
@@ -1554,12 +1613,13 @@ def disseminate(
         publish).
 
         Returns (t, rank, k, send_mask, g_abs, req_any, drain, inc, wait,
-        hint, mixed, ok, bad, iters) — `wait` is the fold's max answer-queue
+        hint, mixed, ok, bad, iters, few) — `wait` is the fold's max answer-queue
         wait at the final times (always FINITE; `mixed` separately flags the
         interleaved-rounds corner where the fold's per-round exactness
         precondition fails), `ok` the fixpoint-convergence bit, `bad` the
         warm-seed certificate violation, `iters` the loop iterations of the
-        phases' fixpoints, summed."""
+        phases' fixpoints, summed, `few` those of them that delivered the
+        moved rows' offers (_converge_dyn)."""
         tgt_f = queue_drop(tgt, frag_idx)
         rank1 = _ranks_f32(jnp.where(tgt_f, rprio, INF))
         k1 = tgt_f.sum(axis=-1).astype(jnp.float32)
@@ -1568,8 +1628,8 @@ def disseminate(
             seed = jnp.where(
                 (w < WARM_VALID) & (w[publisher] < WARM_VALID),
                 t_pub + w + w[publisher] + params.heartbeat_ms, INF)
-            t1, inc1, ok1, it1 = _converge_dyn(rank1, k1, frag_idx, t_pub,
-                                               tgt_f, t_init=seed)
+            t1, inc1, ok1, it1, few1 = _converge_dyn(
+                rank1, k1, frag_idx, t_pub, tgt_f, t_init=seed)
             supported = jnp.maximum(inc1.min(axis=-1), rx_const)
             # t1 <= supported holds at any loop exit; strict < means the
             # seed undershot and stuck (or a phantom: a finite seed on a
@@ -1578,8 +1638,8 @@ def disseminate(
             # certify either.
             bad = jnp.any((t1 < supported) & (t1 < INF) & ~is_pub) | ~ok1
         else:
-            t1, inc1, ok1, it1 = _converge_dyn(rank1, k1, frag_idx, t_pub,
-                                               tgt_f)
+            t1, inc1, ok1, it1, few1 = _converge_dyn(
+                rank1, k1, frag_idx, t_pub, tgt_f)
             bad = jnp.bool_(False)
         if with_gossip and params.serialize_answers:
             with jax.named_scope("fold"):
@@ -1590,15 +1650,15 @@ def disseminate(
                                    deliver_only=True, g_abs=ga1))
                 hint = _diverged(t1, inc2, mixed1)
                 return (t1, rank1, k1, tgt_f, g1, req1, drain1, inc2,
-                        wait1, hint, mixed1, ok1, bad, it1)
+                        wait1, hint, mixed1, ok1, bad, it1, few1)
             inc1p = pull(offers(t1, rank1, k1, frag_idx, tgt_f,
                                 deliver_only=True, g_abs=ga1))
             rank2, k2, send_mask = _phase2_masks_from_inc(
                 inc1p, t1, rank1, k1, tgt_f)
             # phase-2 costs are pointwise <= phase-1 (a send slot was
             # removed from every queue), so t1 is a valid warm start
-            t2, _, ok2, it2 = _converge_dyn(rank2, k2, frag_idx, t_pub,
-                                            send_mask, t_init=t1)
+            t2, _, ok2, it2, few2 = _converge_dyn(
+                rank2, k2, frag_idx, t_pub, send_mask, t_init=t1)
             with jax.named_scope("fold"):
                 g2, req2, drain2, mixed2, wait2 = gossip_fold(t2, frag_idx)
             inc2 = pull(offers(t2, rank2, k2, frag_idx, send_mask,
@@ -1610,19 +1670,20 @@ def disseminate(
             # t1 fold fed the first-sender attribution)
             return (t2, rank2, k2, send_mask, g2, req2, drain2, inc2,
                     jnp.maximum(wait1, wait2), hint, mixed1 | mixed2,
-                    ok1 & ok2, bad, it1 + it2)
+                    ok1 & ok2, bad, it1 + it2, few1 + few2)
         # bounded / no-gossip: attribution from the loop's own matrix
         if not params.exclude_first_sender:
-            t_fin, inc_fin, ok, iters = t1, inc1, ok1, it1
+            t_fin, inc_fin, ok, iters, few = t1, inc1, ok1, it1, few1
             rank_o, k_o, mask_o = rank1, k1, tgt_f
         else:
             rank2, k2, send_mask = _phase2_masks_from_inc(
                 inc1, t1, rank1, k1, tgt_f)
             # t1 is a valid (guaranteed) upper bound for phase 2 — no
             # certificate needed
-            t2, inc2, ok2, it2 = _converge_dyn(rank2, k2, frag_idx, t_pub,
-                                               send_mask, t_init=t1)
+            t2, inc2, ok2, it2, few2 = _converge_dyn(
+                rank2, k2, frag_idx, t_pub, send_mask, t_init=t1)
             t_fin, inc_fin, ok, iters = t2, inc2, ok1 & ok2, it1 + it2
+            few = few1 + few2
             rank_o, k_o, mask_o = rank2, k2, send_mask
         if with_gossip:
             with jax.named_scope("fold"):
@@ -1634,7 +1695,7 @@ def disseminate(
             drain_f = jnp.zeros((n,), jnp.float32)
             mixed_o, wait_o = jnp.bool_(False), jnp.float32(0.0)
         return (t_fin, rank_o, k_o, mask_o, g_f, req_f, drain_f, inc_fin,
-                wait_o, jnp.bool_(False), mixed_o, ok, bad, iters)
+                wait_o, jnp.bool_(False), mixed_o, ok, bad, iters, few)
 
     def phases_serial(frag_idx, t_pub, t_seed):
         """SERIALIZED pipeline: exact answer queues inside the delivery
@@ -1753,6 +1814,9 @@ def disseminate(
     # loop iterations of the kept fast pipeline: summed over its phases
     # (phases_fast), max over fragment lanes
     fast_iters = jnp.max(fast[13])
+    # and those of them that delivered the moved rows' offers (the fixpoint
+    # says where)
+    fast_sparse_iters = jnp.max(fast[14])
     # bounded-mode error bar: the max time any requested answer waited
     # queued at the final estimates — in exact mode the repair (below)
     # drives the actual delivery error to zero and this reports 0.
@@ -2043,7 +2107,7 @@ def disseminate(
             fast_iters, refine_passes, refined.astype(jnp.int32),
             fell_back.astype(jnp.int32), converged.astype(jnp.int32),
             refined_serial.astype(jnp.int32), refine_lane_passes,
-            lanes_hinted, lanes_uncertified]
+            lanes_hinted, lanes_uncertified, fast_sparse_iters]
         if params.churn_down_per_hb > 0.0 or params.churn_up_per_hb > 0.0:
             # under churn only (a churn-free publish stays the program it
             # was): who could send at this publish, and how many of them

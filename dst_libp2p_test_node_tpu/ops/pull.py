@@ -128,6 +128,64 @@ F = 1 and 30.5 -> 21.5 ms for four joint lanes, a refinement pass (two
 pulls) 21.5 -> 16.0 and 46.1 -> 35.4 ms. Band A is still 17.4 % pads and the
 heavy rows' tails 86 %: what fetches filled slots alone would gather 50 %.
 
+Moved rows against time (PR 51; `python scripts/relax_moved_bench.py`: same
+shape and index, f32, a step of a 20-step `fori_loop` round the body of
+ops/disseminate._converge_dyn with gossip, the bands above on the dense
+side, one lane and four vmapped lanes). An iteration of a monotone fixpoint
+changes the offers of the senders whose time moved in the iteration before
+and of no other, and the offers are in the loop's carry, so with at most K
+moved senders a step compacts their ids (sending_rows), evaluates the
+offers of those K rows and scatters them into the carried matrix
+(pull_moved_min); no row is gathered through the (N, C) index. The census
+(XLA:CPU, whose results are the chip's bit for bit; `cli run 1 100000 15000
+<F> 1 50 150 40 130 5 0.0 4 0 4000 --seed 7`): rows moved by iteration,
+phase 1 from the publisher alone 18, 303, 4,896, 54,012, 83,764, 67,253,
+39,900, 12,424, 1,488, 69, 1, 0 and phase 2 from phase 1's times 44,517,
+42,136, 40,562, 35,906, 24,439, 10,369, 2,086, 157, 3, 0 at F = 1 (the four
+lanes of F = 4 move in step and finish within one iteration of each other),
+so of 22 iterations a publish 5 / 7 / 7 / 8 / 9 / 10 fit K = 128 / 512 /
+1,024 / 2,048 / 4,096 / 8,192 at F = 1 and 4 / 6 / 7 / 7 / 8 / 10 in every
+lane at F = 4.
+A step, ms (TPU v5 lite, chiprun call 214; median of 4 timed calls; the
+sparse side with 3 / K/2 / K moved rows):
+      step                      one lane            four vmapped lanes
+      the dense body             6.01                17.09
+      the loop's tail alone      0.06                 0.07
+      `inc` handed back through
+        a cond                   0.09                 0.18
+      K =   128              0.32 / 0.32 / 0.32   1.20 / 1.18 / 1.16
+      K =   512              0.44 / 0.42 / 0.42   1.83 / 1.75 / 1.67
+      K = 1,024              0.58 / 0.56 / 0.55   2.69 / 2.54 / 2.37
+      K = 2,048              0.96 / 0.93 / 0.88   4.55 / 4.30 / 4.04
+      K = 4,096              1.71 / 1.64 / 1.56   7.91 / 7.68 / 7.44
+      every row moved (the dense side through the cond, any K)
+                             6.10-6.12            17.25-17.38
+      the parts, one lane, K = 128 / 512 / 1,024 / 2,048 / 4,096 (each
+      with the tail's 0.06):
+        sending_rows, compare_all   0.17 / 0.19 / 0.26 / 0.40 / 0.72
+        sending_rows, scan          0.13 / 0.19 / 0.24 / 0.36 / 0.61
+        sending_rows, sort          0.82 / 0.83 / 0.83 / 0.85 / 0.87
+        the K-row offers (seven gathers and the arithmetic)
+                                    0.11 / 0.12 / 0.14 / 0.20 / 0.28
+        the scatter of K x C        0.15 / 0.23 / 0.35 / 0.56 / 1.00
+      four lanes: the scatter 0.77 / 1.12 / 1.63 / 2.70 / 4.73; the other
+      two parts write one element of the vmapped carry to stay alive and
+      stand on the 3.4 ms that costs (3.63-5.12 and 3.57-4.66): they say
+      how a part grows with K, not what it costs
+The count of senders moves nothing (fewer real updates cost slightly MORE:
+a dropped update is not cheaper); cost follows K, through the scatter
+first (0.2 ms a thousand rows of one lane, 1.0 ms of four: four lanes'
+scatter is one custom fusion over the flat 16M offers and costs four
+lanes' updates and then some), the compaction second (`compare_all` and
+`scan` alike, `sort` flat at 0.8). The cond is free on the dense side
+(+0.1 ms at F = 1, +0.2 at F = 4) and passing `inc` through it costs
+0.03 and 0.12 ms. `_RELAX_ROWS` is the largest K whose step costs under a
+quarter of the dense body's at F = 1 (1.50 ms) AND at F = 4 (4.27 ms):
+1,024 (2.69 ms; 2,048 reads 4.55 at F = 4). In the publish (traced pair,
+`runsh-100k-frag4.headline`, PR 51): 5.67 of 21.0 joint iterations a
+publish took the sparse side and `fast` fell 1.381 -> 1.116 s with 0.039 s
+more outside every scope (PERF.md section 6 has what that leaves open).
+
 The sharded fixpoint (parallel/exchange.py converge_sharded) deliberately
 does NOT use this: its per-iteration cross-shard traffic is the (N,) time
 vector alone, and the pull there is against receiver-local constants.
@@ -168,6 +226,9 @@ _LANE = 128
 # so small shapes keep the one program they had.
 _SPARSE_ROWS = 128
 _SPARSE_MIN_DENSE_BYTES = 128 * 1024**2
+# K of `pull_moved_min`: the most moved rows an iteration of a float fixpoint
+# delivers from the rows themselves (the table in the module docstring)
+_RELAX_ROWS = 1024
 
 
 def intermediate_bytes(dtype, conns_shape, batch_factor: int = 1) -> int:
@@ -497,15 +558,17 @@ def rows_route(count) -> jnp.ndarray:
     return (count > 0).astype(jnp.int32) + (count > _SPARSE_ROWS)
 
 
-def sending_rows(row_mask: jnp.ndarray, k: int | None = None) -> jnp.ndarray:
+def sending_rows(row_mask: jnp.ndarray, k: int | None = None,
+                 method: str = "compare_all") -> jnp.ndarray:
     """The indices of the first k (`_SPARSE_ROWS` where not given) True
     entries of an (N,) mask, ascending, N (one past the end) beyond their
-    count: the k-th sender is the first row whose running count reaches k."""
+    count: the k-th sender is the first row whose running count reaches k
+    (`method`: `jnp.searchsorted`'s)."""
     k = _SPARSE_ROWS if k is None else k
     running = jnp.cumsum(row_mask.astype(jnp.int32))
     return jnp.searchsorted(
         running, jnp.arange(1, k + 1, dtype=jnp.int32), side="left",
-        method="compare_all").astype(jnp.int32)
+        method=method).astype(jnp.int32)
 
 
 def _deliver(into, senders, marks, values, conns, rev):
@@ -521,6 +584,89 @@ def _deliver(into, senders, marks, values, conns, rev):
     q = jnp.where(ok, cn, n + jnp.arange(k, dtype=jnp.int32)[:, None])
     r = jnp.where(ok, rv, jnp.arange(c, dtype=jnp.int32)[None, :])
     return into.at[q, r].set(values, mode="drop", unique_indices=True)
+
+
+def relax_route(conns_shape) -> bool:
+    """The trace-time half of `pull_moved_min`'s dispatch, for a caller
+    whose pulls are row pulls: a static shape whose dense float pull (the
+    f32 test `make_pull_bands` makes) costs more than the sparse side's
+    fixed cost. Under it a fixpoint keeps the one program it had."""
+    return (len(conns_shape) == 2
+            and intermediate_bytes(jnp.float32, conns_shape)
+            >= _SPARSE_MIN_DENSE_BYTES)
+
+
+def _moved_step(sparse, dense, lanes: int, index: int):
+    """`sparse(*args)` where the moved rows (args[2], a mask over the rows)
+    fit `_RELAX_ROWS`, else `dense(*args)`: a `lax.cond` on that scalar, with
+    a batching rule of its own, because under a vmap a `cond` on a lane's
+    own count lowers to a select and both sides run. A vmap `lanes` wide
+    that batches none of the last `index` arguments (the fragment lanes of
+    a publish: one graph) takes the same step over all its lanes, sparse
+    where EVERY lane's rows fit, on one scalar predicate, each lane
+    delivering its own rows into its own matrix. Any other vmap (a graph a
+    lane, a width nobody declared, a vmap around the lanes') keeps
+    `dense`."""
+    step = custom_vmap(lambda *args: jax.lax.cond(
+        jnp.all(args[2].sum(axis=-1, dtype=jnp.int32) <= _RELAX_ROWS),
+        sparse, dense, *args))
+
+    @step.def_vmap
+    def rule(axis_size, in_batched, *args):
+        axes = tuple(0 if b else None for b in in_batched)
+        over = [jax.vmap(f, in_axes=axes) for f in (sparse, dense)]
+        if axis_size == lanes and not any(in_batched[len(args) - index:]):
+            return _moved_step(*over, 0, index)(*args), (True, True)
+        return over[1](*args), (True, True)
+
+    return step
+
+
+def pull_moved_min(offer, t, inc, moved, operands, conns, rev,
+                   p_conns=None, p_rev=None, batch_factor: int = 1):
+    """`reciprocal_pull_min(offer(t, *operands), ...)`, bit for bit, given
+    `inc`, that pull as it stood before the rows in `moved` (N,) took their
+    new `t`, where row p of `offer` is a function of `t[p]` and of row p of
+    each of `operands` ((N,) or (N, C)) alone: `inc` changes exactly at the
+    slots that point at a moved row, (conns[p, i], rev[p, i]) for every
+    valid slot i of a moved p. With at most `_RELAX_ROWS` moved rows (the
+    first and the last iterations of a monotone fixpoint) that is a
+    compaction of their ids, `offer` on those K rows of `t` and of the
+    operands, and one K x C scatter into `inc`: no row is gathered through
+    the (N, C) index. With more it is the dense pull, its offers computed
+    inside that branch (an operand of a `cond` is not free in the branch
+    that ignores it). `p_conns`, `p_rev`: what the dense pull goes through
+    where that is `Banded`. `batch_factor`: the enclosing vmap over
+    everything but the index that the caller declares (`_moved_step` says
+    what such lanes get).
+
+    Returns (inc, sparse): `sparse` int32, 1 where the rows were delivered,
+    0 where they were pulled."""
+    leaves, tree = jax.tree_util.tree_flatten(
+        (conns, rev, conns if p_conns is None else p_conns,
+         rev if p_rev is None else p_rev))
+    n_ops = len(operands)
+
+    def dense(t, inc, moved, *rest):
+        _, _, via, via_rev = jax.tree_util.tree_unflatten(tree, rest[n_ops:])
+        with jax.named_scope("dense"):
+            return (reciprocal_pull_min(offer(t, *rest[:n_ops]), via,
+                                        via_rev, batch_factor),
+                    jnp.int32(0))
+
+    def sparse(t, inc, moved, *rest):
+        conns, rev, _, _ = jax.tree_util.tree_unflatten(tree, rest[n_ops:])
+        with jax.named_scope("sparse"):
+            senders = sending_rows(moved, _RELAX_ROWS)
+            # a sentinel (one past the end) reads the last row: _deliver
+            # drops what it would send
+            at = jnp.minimum(senders, moved.shape[0] - 1)
+            values = offer(t[at], *(x[at] for x in rest[:n_ops]))
+            return (_deliver(inc, senders, True, values, conns, rev),
+                    jnp.int32(1))
+
+    return _moved_step(sparse, dense, batch_factor, len(leaves))(
+        t, inc, moved, *operands, *leaves)
 
 
 def _tally(count, routed: bool) -> jnp.ndarray:

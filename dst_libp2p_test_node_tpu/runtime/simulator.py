@@ -217,13 +217,13 @@ def record_from_result(
     # run (multitopic's per-topic projection, a publish_batch column) carry
     # none: their records read converged and no refinement
     packed = getattr(res, "counters", None)
-    values = ([0, 0, 0, 0, 1, 0, 0, 0, 0] if packed is None
+    values = ([0, 0, 0, 0, 1, 0, 0, 0, 0, 0] if packed is None
               else [int(v) for v in _host(packed)])
     (fast_iters, refine_passes, refined, fell_back, converged,
      refined_serial, refine_lane_passes, lanes_hinted,
-     lanes_uncertified) = values[:9]
+     lanes_uncertified, fast_sparse_iters) = values[:10]
     # under churn two more: who could send, and who of them sat under D_low
-    alive, under_dlow = values[9:] or (None, None)
+    alive, under_dlow = values[10:] or (None, None)
     return MessageRecord(
         msg_id=msg_id,
         publisher=publisher,
@@ -240,6 +240,7 @@ def record_from_result(
             getattr(res, "answer_wait_max_ms", 0.0))),
         converged=bool(converged),
         fast_iters=fast_iters,
+        fast_sparse_iters=fast_sparse_iters,
         refine_passes=refine_passes,
         refined=bool(refined),
         fell_back=bool(fell_back),
@@ -291,12 +292,14 @@ class MessageRecord:
     # the fixpoints this record rode (not checkpointed; views that carry no
     # bit read True)
     converged: bool = True
-    # DisseminationResult.fast_iters / refine_passes / refined / fell_back /
-    # refined_serial / refine_lane_passes / lanes_hinted / lanes_uncertified:
+    # DisseminationResult.fast_iters / fast_sparse_iters / refine_passes /
+    # refined / fell_back / refined_serial / refine_lane_passes /
+    # lanes_hinted / lanes_uncertified:
     # how much work the publish's fixpoints did, which branches ran, which
     # engine refined and what the fragment lanes added (`stats<i>.json`
     # "publishes"; not checkpointed, views read 0 / False)
     fast_iters: int = 0
+    fast_sparse_iters: int = 0
     refine_passes: int = 0
     refined: bool = False
     fell_back: bool = False
@@ -875,7 +878,9 @@ class Simulator:
             # bytes), on a zero-length annotation
             counters(
                 "publish/counters", message=len(self.records) - 1,
-                fast_iters=rec.fast_iters, refine_passes=rec.refine_passes,
+                fast_iters=rec.fast_iters,
+                fast_sparse_iters=rec.fast_sparse_iters,
+                refine_passes=rec.refine_passes,
                 refined=int(rec.refined), fell_back=int(rec.fell_back),
                 converged=int(rec.converged),
                 refined_serial=int(rec.refined_serial),

@@ -14,7 +14,10 @@ chip time. Shapes are the 100,000-peer headline config's
   - both halves of the heartbeat scan, and `ops/kad.find_node` at the
     kad-10k cell's probe tick (10,000 peers): what its response sorts;
   - a publish's row pull through its two bands (ops/pull.make_pull_bands),
-    one lane and four: the gathered rows the compiler keeps are the bands'.
+    one lane and four: the gathered rows the compiler keeps are the bands';
+  - a step of the fast fixpoint that delivers the moved rows' offers
+    (ops/pull.pull_moved_min), one lane and four: one conditional, a
+    scatter on one side of it, the banded pull on the other.
 
 Everything that touches the topology lives in fixtures of THIS file (one
 xdist worker loads the TPU library, only after a test here has started);
@@ -137,6 +140,19 @@ def test_sharded_fixpoint_compiles_for_four_chips(peer_mesh, capacity):
     assert t_out.spec == P("peers") and inc_out.spec == P("peers")
 
 
+def _band_structs(one_chip, capacity):
+    """`Banded` conns and rev of the 100,000-peer shape, as shapes."""
+    from dst_libp2p_test_node_tpu.ops import pull
+
+    c1, m = pull.band_shape((N, capacity))
+    assert (c1, m) == (24, 12504)
+    back = one_chip((N,), jnp.int32)
+    return tuple(
+        pull.Banded(one_chip((N, c1), jnp.int32),
+                    one_chip((m, capacity - c1), jnp.int32), back)
+        for _ in range(2))
+
+
 @pytest.mark.parametrize("lanes", [1, 4])
 def test_banded_pull_compiles_for_v5e_and_keeps_fewer_rows(
         one_chip, capacity, lanes):
@@ -146,16 +162,10 @@ def test_banded_pull_compiles_for_v5e_and_keeps_fewer_rows(
     whole pull, 4.1 GB for four lanes) shrink with the rows, 65 % of them."""
     from dst_libp2p_test_node_tpu.ops import pull
 
-    c1, m = pull.band_shape((N, capacity))
-    assert (c1, m) == (24, 12504)
     vals = one_chip(((lanes,) if lanes > 1 else ()) + (N, capacity),
                     jnp.float32)
     whole = (one_chip((N, capacity), jnp.int32),) * 2
-    back = one_chip((N,), jnp.int32)
-    bands = tuple(
-        pull.Banded(one_chip((N, c1), jnp.int32),
-                    one_chip((m, capacity - c1), jnp.int32), back)
-        for _ in range(2))
+    bands = _band_structs(one_chip, capacity)
 
     def fn(v, conns, rev):
         if lanes == 1:
@@ -170,6 +180,52 @@ def test_banded_pull_compiles_for_v5e_and_keeps_fewer_rows(
         temp[name] = compiled.memory_analysis().temp_size_in_bytes
     assert temp["whole"] > 2.0e9 * (2 if lanes > 1 else 1)
     assert temp["bands"] < 0.72 * temp["whole"], temp
+
+
+@pytest.mark.parametrize("lanes", [1, 4])
+def test_moved_rows_step_compiles_for_v5e_with_both_sides(
+        one_chip, capacity, lanes):
+    """ISSUE 51: a step of a fast fixpoint at the 100,000-peer shape, one
+    lane and four vmapped lanes through the bands: ONE conditional holds
+    the delivery (a scatter into the carried offers) on one side and the
+    banded pull's gathers on the other, no kernel, and the step keeps no
+    more than the dense pull's temporaries beside a copy of the offers."""
+    from dst_libp2p_test_node_tpu.ops import pull
+
+    lead = (lanes,) if lanes > 1 else ()
+    edge = one_chip((N, capacity), jnp.int32)
+    bands = _band_structs(one_chip, capacity)
+
+    def offer(t, busy, cost):
+        return jnp.where((t < pull.INF)[:, None],
+                         jnp.maximum(t, busy)[:, None] + cost, pull.INF)
+
+    def step(t, inc, moved, busy, cost, conns, rev, via, via_rev):
+        def lane(t, inc, moved, cost):
+            return pull.pull_moved_min(
+                offer, t, inc, moved, (busy, cost), conns, rev, via, via_rev,
+                batch_factor=lanes)
+        return (lane if lanes == 1 else jax.vmap(lane))(t, inc, moved, cost)
+
+    def dense(t, busy, cost, via, via_rev):
+        def lane(t, cost):
+            return pull.reciprocal_pull_min(offer(t, busy, cost), via,
+                                            via_rev, lanes)
+        return (lane if lanes == 1 else jax.vmap(lane))(t, cost)
+
+    t = one_chip(lead + (N,), jnp.float32)
+    table = one_chip(lead + (N, capacity), jnp.float32)
+    busy = one_chip((N,), jnp.float32)
+    compiled = jax.jit(step).lower(
+        t, table, one_chip(lead + (N,), jnp.bool_), busy, table, edge, edge,
+        *bands).compile()
+    text = compiled.as_text()
+    assert text.count(" conditional(") == 1
+    assert " scatter(" in text and "tpu_custom_call" not in text
+    whole = jax.jit(dense).lower(t, busy, table, *bands).compile()
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            < whole.memory_analysis().temp_size_in_bytes
+            + 2.5 * lanes * N * capacity * 4)
 
 
 @pytest.mark.parametrize("churn", [1e-4, 0.0])

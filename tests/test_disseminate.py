@@ -585,13 +585,9 @@ _BANDED_PUBLISHES = {
 }
 
 
-@pytest.mark.parametrize("name", sorted(_BANDED_PUBLISHES))
-def test_publish_through_the_bands_is_the_publish(name):
-    """ISSUE 50: `disseminate` with `pull_bands` returns every leaf of the
-    result and of the next state that it returns without, bit for bit:
-    gossip on and off, one fragment and four joint lanes, tcp loss draws, a
-    publisher whose neighbours are dead under churn (its own validity
-    pull), and the refined branch."""
+def _publish_case(name):
+    """(arguments, keywords with the network's bands, refines?) of a
+    `_BANDED_PUBLISHES` case."""
     over, kw, refines = _BANDED_PUBLISHES[name]
     kw = dict(kw)
     (params, state, conns, rev, stage, lat, bw, lat_edge, ans, bands,
@@ -608,19 +604,38 @@ def test_publish_through_the_bands_is_the_publish(name):
         alive[publisher] = True
         state = state.replace(alive=jnp.asarray(alive))
     common = dict(publisher=publisher, t0_ms=float(state.t_ms), params=params,
-                  ans_tables=ans if gossip else None, **kw)
+                  ans_tables=ans if gossip else None,
+                  pull_bands=bands if gossip else mesh_only, **kw)
     if "loss_stage" not in kw:
         common["lat_edge"] = lat_edge
-    want = disseminate(state, conns, rev, stage, lat, bw, **common)
-    got = disseminate(state, conns, rev, stage, lat, bw, **common,
-                      pull_bands=bands if gossip else mesh_only)
+    return (state, conns, rev, stage, lat, bw), common, refines
+
+
+def _same_leaves(want, got, but=None):
+    """Every leaf of two results equal bit for bit (`but(leaf)`: what to
+    compare of a leaf of either side)."""
     import jax
 
     leaves_w, tree_w = jax.tree_util.tree_flatten(want)
     leaves_g, tree_g = jax.tree_util.tree_flatten(got)
     assert tree_w == tree_g
     for w, g in zip(leaves_w, leaves_g):
+        if but is not None:
+            w, g = but(w), but(g)
         assert np.asarray(w).tobytes() == np.asarray(g).tobytes()
+
+
+@pytest.mark.parametrize("name", sorted(_BANDED_PUBLISHES))
+def test_publish_through_the_bands_is_the_publish(name):
+    """ISSUE 50: `disseminate` with `pull_bands` returns every leaf of the
+    result and of the next state that it returns without, bit for bit:
+    gossip on and off, one fragment and four joint lanes, tcp loss draws, a
+    publisher whose neighbours are dead under churn (its own validity
+    pull), and the refined branch."""
+    args, common, refines = _publish_case(name)
+    want = disseminate(*args, **{**common, "pull_bands": None})
+    got = disseminate(*args, **common)
+    _same_leaves(want, got)
     res = want[0]
     assert bool(np.asarray(res.received).sum() > 1000)
     if refines is not None:
@@ -641,3 +656,109 @@ def test_pull_bands_are_for_the_row_pull_formulation(monkeypatch):
         disseminate(state, conns, rev, stage, lat, bw, publisher=3,
                     t0_ms=float(state.t_ms), params=params,
                     payload_bytes=14000, pull_bands=bands)
+
+
+# ------------------- the fast fixpoint relaxes the rows whose senders moved
+
+@pytest.fixture
+def route_by_rows(monkeypatch):
+    """`route(min_bytes)`: the size constant that decides whether the fast
+    fixpoint carries the moved rows (ops/pull.relax_route), set for the
+    traces that follow, with K = 64 rows at these 2,000 peers. The program
+    has no option for it: the jit's cache is cleared around each choice, so
+    no other test meets a program traced under this one's constants."""
+    import dst_libp2p_test_node_tpu.ops.pull as pull
+
+    monkeypatch.setattr(pull, "_RELAX_ROWS", 64)
+
+    def route(min_bytes):
+        monkeypatch.setattr(pull, "_SPARSE_MIN_DENSE_BYTES", min_bytes)
+        disseminate.clear_cache()
+
+    yield route
+    disseminate.clear_cache()
+
+
+@pytest.mark.parametrize("name", sorted(_BANDED_PUBLISHES))
+def test_publish_relaxing_the_moved_rows_is_the_publish(name, route_by_rows):
+    """ISSUE 51: a whole `disseminate` whose fast fixpoints deliver the
+    offers of the rows that moved (the route forced through the size
+    constant) returns every leaf of the result and of the next state that
+    the dense program returns, bit for bit, but the counter that says it
+    engaged: gossip on and off, one fragment and four joint lanes, loss
+    draws a lane, churn, and the refined branch (whose loops stay dense)."""
+    args, common, refines = _publish_case(name)
+    route_by_rows(128 * 1024**2)
+    want = disseminate(*args, **common)
+    route_by_rows(0)
+    got = disseminate(*args, **common)
+    dense, sparse = want[0], got[0]
+    assert int(dense.fast_sparse_iters) == 0
+    # a cold phase 1 starts from the publisher alone and every phase ends
+    # on a pass that moves nothing
+    assert 4 <= int(sparse.fast_sparse_iters) < int(sparse.fast_iters)
+    assert int(sparse.fast_iters) == int(dense.fast_iters)
+    width = dense.counters.shape
+    _same_leaves(want, got, but=lambda x: (
+        x.at[9].set(0) if x.shape == width and x.dtype == jnp.int32 else x))
+    assert bool(np.asarray(dense.received).sum() > 1000)
+    if refines is not None:
+        assert bool(dense.refined) is refines
+    if name == "churn_dead_neighbours":
+        assert int(sparse.alive) == int(dense.alive) < 2000
+        assert sparse.counters.shape == (12,)
+
+
+def test_the_vmapped_fast_pipeline_conds_on_a_scalar(route_by_rows):
+    """Four fragment lanes under `_per_fragment`'s vmap: each fast fixpoint
+    holds ONE `cond` between the delivery and the pull, on a scalar (every
+    lane's moved rows fit, or none is delivered); no scatter into the
+    (F, N, C) offers outside it, which a select of the two sides would
+    leave in the loop's body."""
+    import jax
+    from test_pull import _eqns
+
+    args, common, _ = _publish_case("gossip_f4_refined")
+    route_by_rows(0)
+    jaxpr = jax.make_jaxpr(
+        lambda *a: disseminate(*a, **common))(*args).jaxpr
+    eqns = list(_eqns(jaxpr))
+
+    def between(eqn):
+        sides = [{name for name, _, _ in _eqns(b.jaxpr)}
+                 for b in eqn.params["branches"]]
+        return sorted("scatter" in s for s in sides) == [False, True]
+
+    conds = [e for name, e, inside in eqns
+             if name == "cond" and "while" in inside and between(e)]
+    # phase 1 and phase 2 (and once more each in the warm seed's cold rerun)
+    assert len(conds) == (4 if common["params"].warm_start else 2)
+    assert all(e.invars[0].aval.shape == () for e in conds)
+    n, c = args[1].shape
+    loose = [e for name, e, inside in eqns
+             if name == "scatter" and "while" in inside
+             and "cond" not in inside[inside.index("while"):]
+             and e.outvars[0].aval.shape[-2:] == (n, c)]
+    assert not loose
+
+
+def test_small_shapes_keep_the_dense_program(route_by_rows):
+    """At (1000, 40) the dense pull is under the size test: the lowered
+    text of `disseminate` is the text with the route forced off, loops,
+    carries and all (and not the text with it forced on)."""
+    g, params, state, a, (stage, lat, bw) = mesh_setup(n=1000, seed=5)
+    assert a["conns"].shape == (1000, 40)
+
+    def text(fragments):
+        return disseminate.lower(
+            state, a["conns"], a["rev"], stage, lat, bw, publisher=3,
+            t0_ms=float(state.t_ms), params=params, payload_bytes=15000,
+            fragments=fragments).as_text()
+
+    asis = [text(f) for f in (1, 4)]
+    route_by_rows(2**62)
+    assert [text(f) for f in (1, 4)] == asis
+    route_by_rows(0)
+    forced = [text(f) for f in (1, 4)]
+    assert all(a != b for a, b in zip(asis, forced))
+    assert all("stablehlo.case" in t or "stablehlo.if" in t for t in forced)

@@ -164,7 +164,7 @@ def test_lane_counters(fragments):
         assert lane == deepest and int(res.lanes_hinted) == 1
     assert 1 <= int(res.lanes_hinted) <= fragments
     assert int(res.lanes_uncertified) == 0
-    assert np.asarray(res.counters).tolist()[6:] == [
+    assert np.asarray(res.counters).tolist()[6:9] == [
         lane, int(res.lanes_hinted), 0]
 
 
@@ -198,7 +198,7 @@ def test_no_refinement_counts_no_lane():
         t0_ms=float(state.t_ms), params=params, payload_bytes=BLOB,
         with_gossip=False, fragments=4)
     assert not bool(res.refined)
-    assert np.asarray(res.counters).tolist()[6:] == [0, 0, 0]
+    assert np.asarray(res.counters).tolist()[6:9] == [0, 0, 0]
 
 
 # ------------------------------------------------ the publisher's own receipt

@@ -699,3 +699,232 @@ def test_band_shape_is_static_and_the_reference_graph_fits_it():
     assert bands.tails["rev"].shape == (12504, 16)
     assert int((np.asarray(bands.back) < 12504).sum()) == heavy
     assert pull.pull_rows_share(bands) == pytest.approx(65.0016)
+
+
+# ------------------------------------- the moved rows of a float fixpoint --
+
+RK = 8      # `_RELAX_ROWS` for these tests
+
+
+@pytest.fixture(scope="module")
+def relax_at_k():
+    """K = 8 moved rows (module-wide: the jitted loops below bake it in)."""
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(pull, "_RELAX_ROWS", RK)
+        yield
+
+
+@pytest.fixture(scope="module", params=["pads", "holes"])
+def relaxed(request, relax_at_k):
+    """A random 300-peer graph (half of its slots pads); `holes`: with 60
+    connections cut, some in front of filled slots. With it the bands of
+    its index and per-edge costs for four lanes."""
+    g = build_connection_graph(300, 6, seed=9)
+    a = graph_arrays(g)
+    conns, rev = np.array(a["conns"]), np.array(a["rev"])
+    if request.param == "holes":
+        rng = np.random.default_rng(4)
+        for p in rng.choice(300, 60, replace=False):
+            slots = np.flatnonzero(conns[p] >= 0)
+            if slots.size:
+                _cut(conns, rev, p, rng.choice(slots))
+        filled = conns >= 0
+        assert (filled[:, :-1] < filled[:, 1:]).any()   # a hole in front
+    conns, rev = jnp.asarray(conns), jnp.asarray(rev)
+    c = conns.shape[1]
+    bands = pull.make_pull_bands(conns, rev, min_bytes=0, c1=c // 2, rows=300)
+    assert bands is not None
+    key = jax.random.PRNGKey(2)
+    cost = jnp.where(conns >= 0, 1.0 + 9.0 * jax.random.uniform(
+        key, (4,) + conns.shape), pull.INF)
+    busy = 5.0 * jax.random.uniform(jax.random.fold_in(key, 1), (300,))
+    return conns, rev, bands, cost, busy
+
+
+def _offer(t, busy, cost):
+    """Row p from t[p] and row p of the tables alone, INF from a row that
+    has not been reached: the shape of ops/disseminate's offers."""
+    return jnp.where((t < pull.INF)[:, None],
+                     jnp.maximum(t + 0.5, busy)[:, None] + cost, pull.INF)
+
+
+def _relax(net, t0, cost, cap, lanes, by_rows, banded=True):
+    """ops/disseminate._converge_dyn's loop over `_offer`: (t, inc, ok,
+    iterations, iterations that delivered the moved rows)."""
+    conns, rev, bands, _, busy = net
+    via = (bands.of("conns"), bands.of("rev")) if banded else (conns, rev)
+
+    def body(carry):
+        t, inc, _, it, moved, few = carry
+        if by_rows:
+            inc, sparse = pull.pull_moved_min(
+                _offer, t, inc, moved, (busy, cost), conns, rev, *via,
+                batch_factor=lanes)
+        else:
+            inc = pull.reciprocal_pull_min(_offer(t, busy, cost), *via, lanes)
+            sparse = 0
+        t_new = jnp.minimum(t, inc.min(axis=-1))
+        moved = t_new < t
+        return t_new, inc, jnp.any(moved), it + 1, moved, few + sparse
+
+    t, inc, changed, it, _, few = jax.lax.while_loop(
+        lambda carry: carry[2] & (carry[3] < cap), body,
+        (t0, jnp.full(conns.shape, pull.INF), jnp.bool_(True), jnp.int32(0),
+         t0 < pull.INF, jnp.int32(0)))
+    return t, inc, ~changed, it, few
+
+
+def _relax_lanes(net, t0, cap, lanes, by_rows, banded=True):
+    cost = net[3]
+    if lanes == 1:
+        return _relax(net, t0[0], cost[0], cap, 1, by_rows, banded)
+    return jax.vmap(lambda t, a: _relax(net, t, a, cap, lanes, by_rows,
+                                        banded))(t0, cost)
+
+
+def _same_bits(want, got):
+    for w, g in zip(want, got):
+        w, g = np.asarray(w), np.asarray(g)
+        assert w.shape == g.shape and w.dtype == g.dtype
+        assert w.tobytes() == g.tobytes()
+
+
+@pytest.mark.parametrize("lanes", [1, 4])
+@pytest.mark.parametrize("rows", [0, 1, RK, RK + 1])
+def test_moved_rows_delivered_are_the_dense_pull(relaxed, rows, lanes):
+    """ISSUE 51, one step: given the pull of the offers at `t` and `rows`
+    rows that then moved, pull_moved_min returns the pull of the offers at
+    the new times, bit for bit, and says which side ran: sparse with up to
+    K rows, in EVERY lane where there are four (lane 1 moves no row, lane 2
+    one; a lane with K + 1 sends all four to the dense side). Pads and
+    holes, the whole index and its bands."""
+    conns, rev, bands, cost, busy = relaxed
+    n = conns.shape[0]
+    rng = np.random.default_rng(rows + lanes)
+    t = jnp.asarray(np.where(rng.random((4, n)) < 0.8,
+                             50.0 * rng.random((4, n)), np.inf)
+                    .astype(np.float32)).clip(max=pull.INF)
+    moved = np.zeros((4, n), bool)
+    for lane, count in enumerate((rows, 0, 1, rows)):
+        moved[lane, rng.choice(n, count, replace=False)] = True
+    # a moved row may come from INF (a row reached for the first time)
+    t_new = jnp.where(moved, 40.0 * rng.random((4, n)).astype(np.float32), t)
+    moved = jnp.asarray(moved)
+
+    def step(t, t_new, moved, cost, via):
+        inc = pull.reciprocal_pull_min(_offer(t, busy, cost), conns, rev,
+                                       lanes)
+        return pull.pull_moved_min(_offer, t_new, inc, moved, (busy, cost),
+                                   conns, rev, *via, batch_factor=lanes)
+
+    def fresh(t_new, cost):
+        return pull.reciprocal_pull_min(_offer(t_new, busy, cost), conns,
+                                        rev, lanes)
+
+    for via in ((), (bands.of("conns"), bands.of("rev"))):
+        if lanes == 1:
+            got, sparse = jax.jit(lambda *a: step(*a, via))(
+                t[0], t_new[0], moved[0], cost[0])
+            want = fresh(t_new[0], cost[0])
+        else:
+            got, sparse = jax.jit(jax.vmap(lambda *a: step(*a, via)))(
+                t, t_new, moved, cost)
+            want = jax.vmap(fresh)(t_new, cost)
+        _same_bits([want], [got])
+        assert np.asarray(sparse).tolist() == (
+            int(rows <= RK) if lanes == 1 else [int(rows <= RK)] * 4)
+    if rows:
+        assert (np.asarray(want) < np.asarray(pull.INF)).any()
+
+
+@pytest.mark.parametrize("cap", [3, 64])
+@pytest.mark.parametrize("start", ["cold", "warm"])
+@pytest.mark.parametrize("lanes", [1, 4])
+def test_relaxing_the_moved_rows_is_the_fixpoint(relaxed, lanes, start, cap):
+    """ISSUE 51, the loop: `t`, the carried offer matrix, the convergence
+    bit and the iteration count of a Bellman-Ford relaxation that delivers
+    the moved rows' offers are the dense loop's bit for bit: from a cold
+    start (one finite row, a publisher a lane) and from a `t_init` (an
+    upper bound on every row: the first iteration is dense), converged and
+    cut by the iteration cap ("one pass stale" included)."""
+    n = relaxed[0].shape[0]
+    t0 = jnp.full((4, n), pull.INF).at[jnp.arange(4), jnp.array(
+        [3, 77, 150, 299])].set(jnp.arange(4.0))
+    if start == "warm":
+        fix = _relax_lanes(relaxed, t0, 64, 4, False)[0]
+        t0 = jnp.where(fix < pull.INF, fix * 1.5 + 3.0, pull.INF).at[
+            jnp.arange(4), jnp.array([3, 77, 150, 299])].set(jnp.arange(4.0))
+    want = jax.jit(lambda t: _relax_lanes(relaxed, t, cap, lanes, False))(t0)
+    got = jax.jit(lambda t: _relax_lanes(relaxed, t, cap, lanes, True))(t0)
+    _same_bits(want[:4], got[:4])
+    its, few = np.asarray(got[3]), np.asarray(got[4])
+    assert bool(np.all(np.asarray(got[2]))) is (cap == 64)
+    assert np.all(few <= its)
+    if cap == 64:
+        # the first iterations (cold) and the last ones deliver rows
+        assert np.all(few >= (2 if start == "cold" else 1)), (few, its)
+        assert np.all(few < its)
+    assert np.all(np.asarray(want[4]) == 0)
+    # the whole index in place of the bands: the same loop
+    plain = jax.jit(lambda t: _relax_lanes(
+        relaxed, t, cap, lanes, True, banded=False))(t0)
+    _same_bits(want[:4], plain[:4])
+
+
+def _eqns(jaxpr, inside=()):
+    """(primitive name, eqn, names of the enclosing eqns) of every equation
+    of a jaxpr and of the jaxprs in its equations' params."""
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name, eqn, inside
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub, inside + (eqn.primitive.name,))
+
+
+def test_lanes_relax_on_one_scalar_predicate(relaxed):
+    """Four declared lanes take ONE `cond`, its predicate a scalar (every
+    lane's rows fit, or none is delivered), the scatter in one of its
+    branches and the gathers of the pull in the other: no select of the two
+    sides. A vmap nobody declared, and a vmap around the lanes', keep the
+    dense body: no cond, no scatter."""
+    n = relaxed[0].shape[0]
+    t0 = jnp.full((4, n), pull.INF).at[:, 5].set(0.0)
+
+    def conds(fn, *args):
+        eqns = list(_eqns(jax.make_jaxpr(fn)(*args).jaxpr))
+        found = [e for name, e, _ in eqns if name == "cond"]
+        loose = [e for name, e, inside in eqns
+                 if name == "scatter" and "cond" not in inside]
+        return found, loose
+
+    found, loose = conds(lambda t: _relax_lanes(relaxed, t, 64, 4, True), t0)
+    assert len(found) == 1 and not loose
+    (cond,) = found
+    assert cond.invars[0].aval.shape == ()
+    sides = [{name for name, _, _ in _eqns(b.jaxpr)}
+             for b in cond.params["branches"]]
+    assert sorted("scatter" in s for s in sides) == [False, True]
+    assert sorted("gather" in s and "scatter" not in s for s in sides) == [
+        False, True]
+    # lanes nobody declared (three of a declared four), and trials around
+    # the four lanes: dense
+    for fn, arg in (
+            (lambda t: jax.vmap(lambda t, a: _relax(
+                relaxed, t, a, 64, 4, True))(t, relaxed[3][:3]), t0[:3]),
+            (jax.vmap(lambda t: _relax_lanes(relaxed, t, 64, 4, True)),
+             jnp.stack([t0, t0]))):
+        found, loose = conds(fn, arg)
+        assert not found and not loose
+    want = _relax_lanes(relaxed, t0, 64, 4, False)
+    got = jax.vmap(lambda t: _relax_lanes(relaxed, t, 64, 4, True))(
+        jnp.stack([t0, t0]))
+    _same_bits(want[:4], [x[1] for x in got[:4]])
+    assert not np.asarray(got[4]).any()
+
+
+def test_relax_route_is_the_size_test_of_the_bands():
+    """The trace-time half: the f32 test `make_pull_bands` makes, so 1,000
+    and 2,048 peers keep the dense program and 10,000 pass."""
+    assert not pull.relax_route((1000, 40))
+    assert not pull.relax_route((2048, 40))
+    assert pull.relax_route((10000, 40))
+    assert pull.relax_route((100000, 40))

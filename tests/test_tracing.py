@@ -169,7 +169,8 @@ def test_stats_json_spans_and_publishes_are_strict_json(two_turns):
             assert entry["count"] >= 1 and entry["total_s"] >= 0.0, name
         assert len(stats["publishes"]) == MESSAGES
         for p in stats["publishes"]:
-            assert set(p) == {"fast_iters", "refine_passes", "refined",
+            assert set(p) == {"fast_iters", "fast_sparse_iters",
+                              "refine_passes", "refined",
                               "fell_back", "converged", "refined_serial",
                               "refine_lane_passes", "lanes_hinted",
                               "lanes_uncertified", "lanes_in_pull",
@@ -177,6 +178,7 @@ def test_stats_json_spans_and_publishes_are_strict_json(two_turns):
             assert p["lanes_in_pull"] == 1      # one fragment: no lane axis
             assert p["pull_rows_share"] == 100.0    # under the size test
             assert isinstance(p["fast_iters"], int) and p["fast_iters"] > 0
+            assert p["fast_sparse_iters"] == 0      # and under relax_route's
             assert p["converged"] is True and p["fell_back"] is False
 
 
@@ -340,7 +342,8 @@ def test_counters_on_the_prefix_cases(kw, over, passes_prefix,
             int(res.fast_iters), int(res.refine_passes), int(res.refined),
             int(res.fell_back), int(res.converged),
             int(res.refined_serial), int(res.refine_lane_passes),
-            int(res.lanes_hinted), int(res.lanes_uncertified)]
+            int(res.lanes_hinted), int(res.lanes_uncertified),
+            int(res.fast_sparse_iters)]
     # which engine refined: the one chosen
     assert not bool(res_p.refined_serial) and bool(res_s.refined_serial)
     # the fast pipeline is the same program under both engines
