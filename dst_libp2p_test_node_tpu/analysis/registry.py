@@ -23,6 +23,11 @@ The registered surface:
                           over stacked seed columns, disseminate/cold's 2
                           conds surviving in the body plus the padding
                           active-mask cond (3 total)
+  runs/disseminate        one message in every run of a batch (ISSUE 52,
+                          ops/runs.py): lax.map of disseminate/cold over
+                          the runs' axis, its 2 conds alive in the body
+  runs/run_heartbeats     the batch's scan: lax.map of run_heartbeats, the
+                          step's 4 skips alive in the inner scan's body
   heartbeat_step          one mesh-maintenance round (4 steady-state skips)
   run_heartbeats          the simulator scan step (conds must survive the
                           scan body)
@@ -151,6 +156,29 @@ def _publish_batch_spec() -> TraceSpec:
                     fragments=1, with_gossip=True, loss_stage=None,
                     loss_mode="tcp", lat_edge=None, loss_edge=None,
                     ans_tables=None, valid_edge=None, with_fanout=False))
+
+
+def _runs_spec(program: str) -> TraceSpec:
+    """ops/runs.py's batched programs on two stacked runs of the canonical
+    network: every leaf a run owns with the runs' axis in front."""
+    import jax
+    import jax.numpy as jnp
+
+    from ..ops import runs
+
+    g, params, state, a, (stage, lat, bw) = _single_topic()
+    states, a = jax.tree_util.tree_map(
+        lambda x: jnp.stack([x, x]), (state, a))
+    if program == "_run_heartbeats":
+        return TraceSpec(
+            fn=runs._run_heartbeats,
+            args=(states, a["conns"], a["rev"], a["out_mask"]),
+            kwargs=dict(params=params, steps=4))
+    return TraceSpec(
+        fn=runs.disseminate,
+        args=(states, a["conns"], a["rev"], stage, lat, bw),
+        kwargs=dict(publisher=3, t0_ms=0.0, params=params,
+                    payload_bytes=15000))
 
 
 def _heartbeat_spec(fn_name: str, **params_over) -> TraceSpec:
@@ -821,6 +849,26 @@ def default_contracts() -> list[EntrypointContract]:
                   "static batch width free (a select_n there would publish "
                   "the padding columns); the carried SimState must feed "
                   "back aval-stable so every pump round is a cache hit"),
+        EntrypointContract(
+            name="runs/disseminate",
+            build=lambda: _runs_spec("disseminate"),
+            expected_conds=2,
+            feedback=[(_new_state_of, _state_arg_of)],
+            notes="one message in every run of a batch (ISSUE 52): lax.map "
+                  "of disseminate/cold over the runs' axis — its 2 conds "
+                  "must survive inside the map's body (a vmap over runs "
+                  "would turn them into select_n and run the serial "
+                  "refiner in every run of every publish); the stacked "
+                  "states must feed back aval-stable, a message after a "
+                  "message"),
+        EntrypointContract(
+            name="runs/run_heartbeats",
+            build=lambda: _runs_spec("_run_heartbeats"),
+            expected_conds=4,
+            feedback=[(_first_out, _state_arg_of)],
+            notes="the batch's scan: lax.map of run_heartbeats over the "
+                  "runs' axis, the step's 4 skips alive in the inner scan's "
+                  "body"),
         EntrypointContract(
             name="heartbeat_step",
             build=lambda: _heartbeat_spec("heartbeat_step"),

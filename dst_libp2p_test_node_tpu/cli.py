@@ -58,6 +58,7 @@ Usage:
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import os
 import sys
@@ -200,6 +201,20 @@ def _publish_counts(records) -> list[dict]:
             for r in records]
 
 
+def _batch_refusal(a) -> str | None:
+    """Why `run <runs> ...` with runs > 1 makes these runs one by one and
+    not as one batch (runtime/run_batch.py), or None where it batches them:
+    the mix routes a message on the host between two device calls, and a
+    churned publish reads its publisher's liveness before it dispatches.
+    (`--checkpoint` and `--resume` are refused at runs > 1 before this.)"""
+    if a.use_mix:
+        return "--use-mix routes every message on the host, a run at a time"
+    if a.churn != (0.0, 0.0):
+        return ("--churn reads the publisher's liveness before a publish, "
+                "a run at a time")
+    return None
+
+
 def cmd_run(argv: list[str]) -> int:
     # flags appended after the 14 positionals tune the TPU backend
     p = argparse.ArgumentParser(
@@ -208,6 +223,12 @@ def cmd_run(argv: list[str]) -> int:
         "<min_bandwidth> <max_bandwidth> <min_latency> <max_latency> "
         "<anchor_stages> <packet_loss> <publisher_id> <publisher_rotation> "
         "<inter_message_delay> [--seed N] [--warmup-s S] ...",
+        epilog="runs > 1 is simulated as ONE batch (runtime/run_batch.py): "
+        "the runs' networks on the device together, one warm-up scan and "
+        "one dispatch a message for all of them, and run i (--seed s+i-1) "
+        "writes the latencies<i> and shadowlog<i> it writes alone, byte "
+        "for byte; --use-mix and --churn keep the loop, a run at a time, "
+        "and stats<i>.json \"batch\" says which it was.",
     )
     for name in RUN_SH_PARAMS:
         p.add_argument(name)
@@ -302,8 +323,39 @@ def cmd_run(argv: list[str]) -> int:
 
     topo = _topo_from_fields(vars(a), muxer=a.muxer)
     t = None
-    for i in range(1, int(a.runs) + 1):
-        with turn(seed=a.seed + i - 1, turn=i) as spans:
+    runs = int(a.runs)
+    # runs > 1: ONE batched experiment (runtime/run_batch.py), made in turn
+    # 1, unless the call is one the batch does not take; `kept_loop` says
+    # why the runs are made one by one, in `stats<i>.json` "batch"
+    kept_loop = _batch_refusal(a) if runs > 1 else None
+    batch = None
+
+    def config_of(i: int):
+        return ExperimentConfig(
+            topo=topo,
+            connect_to=a.connect_to,
+            # the reference nodes read GOSSIPSUB_* inside the simulation,
+            # so the driver honors the same env surface
+            # (main.nim:252-306)
+            gossipsub=gossipsub_params_from_env(),
+            publisher_id=int(a.publisher_id),
+            publisher_rotation=bool(int(a.publisher_rotation)),
+            warmup_s=a.warmup_s,
+            seed=a.seed + i - 1,
+            with_gossip=not a.no_gossip,
+            churn_down_per_hb=a.churn[0],
+            churn_up_per_hb=a.churn[1],
+            uses_mix=a.use_mix,
+            num_mix=a.num_mix,
+            mix_d=a.mix_d,
+            msgid_mode=a.msgid_mode,
+            loss_mode=a.loss_mode,
+            serialize_answers=(a.delivery_mode == "exact"),
+        )
+
+    for i in range(1, runs + 1):
+        with turn(seed=a.seed + i - 1, turn=i) as spans, \
+                contextlib.ExitStack() as emit:
             # what this turn's `shadow.yaml` took (no file written: zeros)
             artifacts = {"yaml_hosts_dumped": 0, "yaml_alias_lines": 0}
             if i == 1 and a.gml:
@@ -326,46 +378,51 @@ def cmd_run(argv: list[str]) -> int:
                     artifacts = t.write_shadow_yaml(a.out_prefix + "shadow.yaml")
             large = topo.msg_size_bytes >= 1000
             print(f"Running for turn {i}")
-            cfg = ExperimentConfig(
-                topo=topo,
-                connect_to=a.connect_to,
-                # the reference nodes read GOSSIPSUB_* inside the simulation,
-                # so the driver honors the same env surface
-                # (main.nim:252-306)
-                gossipsub=gossipsub_params_from_env(),
-                publisher_id=int(a.publisher_id),
-                publisher_rotation=bool(int(a.publisher_rotation)),
-                warmup_s=a.warmup_s,
-                seed=a.seed + i - 1,
-                with_gossip=not a.no_gossip,
-                churn_down_per_hb=a.churn[0],
-                churn_up_per_hb=a.churn[1],
-                uses_mix=a.use_mix,
-                num_mix=a.num_mix,
-                mix_d=a.mix_d,
-                msgid_mode=a.msgid_mode,
-                loss_mode=a.loss_mode,
-                serialize_answers=(a.delivery_mode == "exact"),
-            )
-            with span("run/simulator_init"):
-                if a.resume:
-                    from .runtime.checkpoint import load_checkpoint
+            cfg = config_of(i)
+            if i == 1 and runs > 1 and kept_loop is None:
+                from .runtime.run_batch import NotBatchable, RunBatch
 
-                    sim = load_checkpoint(a.resume)
-                    if sim.cfg != cfg:
-                        p.error(
-                            "--resume checkpoint was created with a "
-                            "different configuration than these arguments; "
-                            "re-run with the original parameters"
-                        )
+                try:
+                    with span("run/simulator_init"):
+                        batch = RunBatch(
+                            [config_of(j) for j in range(1, runs + 1)], t)
+                except NotBatchable as e:
+                    kept_loop = str(e)
                 else:
-                    sim = Simulator(cfg, topology=t)
-            with span("run/simulate"):
-                sim.run(checkpoint_path=a.checkpoint,
-                        checkpoint_every=a.checkpoint_every)
-            # the program's one clock: build + run, from the spans
-            wall = (spans.seconds("run/simulator_init")
-                    + spans.seconds("run/simulate"))
+                    with span("run/simulate"):
+                        batch.run()
+                    # the batch's one clock, which every run's report
+                    # and `stats<i>.json` give: build + run of all R
+                    batch_wall = (spans.seconds("run/simulator_init")
+                                  + spans.seconds("run/simulate"))
+            if batch is not None:
+                sim, wall = batch.runs[i - 1], batch_wall
+            else:
+                with span("run/simulator_init"):
+                    if a.resume:
+                        from .runtime.checkpoint import load_checkpoint
+
+                        sim = load_checkpoint(a.resume)
+                        if sim.cfg != cfg:
+                            p.error(
+                                "--resume checkpoint was created with a "
+                                "different configuration than these "
+                                "arguments; re-run with the original "
+                                "parameters"
+                            )
+                    else:
+                        sim = Simulator(cfg, topology=t)
+                with span("run/simulate"):
+                    sim.run(checkpoint_path=a.checkpoint,
+                            checkpoint_every=a.checkpoint_every)
+                # the program's one clock: build + run, from the spans
+                wall = (spans.seconds("run/simulator_init")
+                        + spans.seconds("run/simulate"))
+            if batch is not None:
+                # a run of a batch: its emit under one span more (to the
+                # turn's end), so that a profile tells the R runs' emit
+                # from the batch before it
+                emit.enter_context(span("batch/emit"))
             with span("run/write_latencies"):
                 n_lines = sim.write_latencies(f"{a.out_prefix}latencies{i}")
             with span("run/write_shadowlog"):
@@ -412,6 +469,19 @@ def cmd_run(argv: list[str]) -> int:
                             # turn, and the device backend's start
                             **({"process": process_summary()}
                                if spans.number == 1 else {}),
+                            # runs > 1 only: whether this run shared its
+                            # dispatches with the others (then `wall_s`,
+                            # `spans` and `compile` are the batch's, all
+                            # of it in turn 1's, and `batch` counts the
+                            # experiment's publish dispatches and
+                            # device->host reads), or why the runs were
+                            # made one by one
+                            **({"batch": {
+                                "runs": runs, "index": i,
+                                "batched": batch is not None,
+                                **(batch.counts if batch is not None
+                                   else {"kept_loop": kept_loop})}}
+                               if runs > 1 else {}),
                             # lines of latencies<i> and shadowlog<i>, and how
                             # many blocks of each the native formatter took
                             # (0: the Python one wrote them)

@@ -301,9 +301,17 @@ class SimState:
 def init_state(params: SimParams, seed: int = 0) -> SimState:
     import jax
 
+    return state_from_key(params, jax.random.PRNGKey(seed))
+
+
+def state_from_key(params: SimParams, key) -> SimState:
+    """`init_state` of a run whose `PRNGKey(seed)` is `key`: the one leaf a
+    seed decides besides the key is `hb_phase` (traceable: ops/runs.py maps
+    it over the keys of a batch's runs)."""
+    import jax
+
     params.validate()
     n, c = params.n, params.capacity
-    key = jax.random.PRNGKey(seed)
     key, k_phase = jax.random.split(key)
     state = SimState(
         mesh_mask=jnp.zeros((n, c), dtype=bool),

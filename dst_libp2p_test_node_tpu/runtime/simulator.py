@@ -35,6 +35,7 @@ from ..ops.disseminate import (fixpoint_formulation, fragments_in_sequence,
 from ..ops.graph import ConnGraph, build_connection_graph
 from ..ops.heartbeat import PULL_COUNTS, PULL_STAGES, run_heartbeats
 from ..ops.pull import make_pull_bands, pull_rows_share
+from ..ops.runs import disseminate as _disseminate_runs_program
 from ..ops.state import SimParams, graph_arrays, init_state
 from .logemit import LatenciesWriter
 from .profiling import count_device_read, counters, device_read, span
@@ -145,16 +146,20 @@ class ExperimentConfig:
     msgid_mode: str = "nim"
 
 
-def scheduled_publishers(cfg: ExperimentConfig) -> list[int]:
-    """The peers `Simulator.run` publishes through, in order of first use:
+def message_publisher(cfg: ExperimentConfig, i: int) -> int:
+    """The peer message i of the schedule is published through:
     publisher_id, or with rotation one peer on for every message
     (run.sh:16-17, 34-35)."""
-    n = cfg.topo.network_size
-    first = cfg.publisher_id % n
-    if not cfg.publisher_rotation:
-        return [first]
+    return ((cfg.publisher_id + i * cfg.publisher_rotation)
+            % cfg.topo.network_size)
+
+
+def scheduled_publishers(cfg: ExperimentConfig) -> list[int]:
+    """The peers `Simulator.run` publishes through, in order of first
+    use."""
+    messages = cfg.topo.messages if cfg.publisher_rotation else 1
     return list(dict.fromkeys(
-        (first + i) % n for i in range(cfg.topo.messages)))
+        message_publisher(cfg, i) for i in range(messages)))
 
 
 def graph_capacity(cfg: ExperimentConfig) -> int:
@@ -175,8 +180,18 @@ def disseminate(*args, return_plan: bool = False, **kw):
     than the one it had timed. The plan is what `sample` drew anyway (send
     sets, priorities, gossip targets, loss draws) plus five per-peer
     vectors: returning it keeps about 50 MB of intermediates alive to the
-    end of a publish at (100000, 40) and costs no operation."""
-    res, state, plan = _disseminate_program(*args, return_plan=True, **kw)
+    end of a publish at (100000, 40) and costs no operation.
+
+    An index of rank 3, (R, N, C), is the R runs of a batch
+    (runtime/run_batch.py): the call goes to ops/runs.disseminate, every
+    leaf a run owns with the runs' axis in front, under the same contract
+    and this same name, so that whoever wraps the name for the length of a
+    call (the benchmark's reference check) sees a batch's publishes as it
+    sees a Simulator's."""
+    if np.ndim(args[1]) == 3:
+        res, state, plan = _disseminate_runs_program(*args, **kw)
+    else:
+        res, state, plan = _disseminate_program(*args, return_plan=True, **kw)
     return (res, state, plan) if return_plan else (res, state)
 
 
@@ -349,8 +364,24 @@ class Simulator:
         regression node's, from kad-dht discovery) in place of the shuffle
         dials of `build_connection_graph`. Params, state, device arrays and
         every hoisted per-edge table are made from it here, in one place."""
-        import jax.numpy as jnp
+        self._build_host(cfg, topology, mesh, graph)
+        with span("build/tables"):
+            self._build_device()
 
+    @classmethod
+    def host_side(cls, cfg: ExperimentConfig, topology: Topology):
+        """One run of a batch (runtime/run_batch.py): everything a Simulator
+        keeps on the host (its seed's graph, the params, the message ids,
+        the records and what is emitted from them) and nothing on the
+        device. `RunBatch` holds the R runs' states, index arrays and tables
+        stacked, fills `records` as it publishes and leaves `state` when the
+        schedule ends; whatever steps or publishes is not this object's."""
+        run = cls.__new__(cls)
+        run._build_host(cfg, topology, None, None)
+        run.state = None
+        return run
+
+    def _build_host(self, cfg, topology, mesh, graph) -> None:
         cfg.topo.validate()
         cfg.gossipsub.validate()
         if cfg.msgid_mode not in ("nim", "go"):
@@ -377,82 +408,27 @@ class Simulator:
                     max_degree=graph_capacity(cfg),
                 )
             self.graph = graph
-        with span("build/tables"):
-            proc_ms = (cfg.proc_delay_ms if cfg.proc_delay_ms is not None
-                       else MUXER_PROC_MS.get(cfg.topo.muxer.lower(), 2.0))
-            self.params = SimParams.from_gossipsub(
-                n,
-                self.graph.capacity,
-                cfg.gossipsub,
-                proc_delay_ms=proc_ms,
-                churn_down_per_hb=cfg.churn_down_per_hb,
-                churn_up_per_hb=cfg.churn_up_per_hb,
-                serialize_answers=cfg.serialize_answers,
-                answer_queue_mode=cfg.answer_queue_mode,
-                warm_start=cfg.warm_start,
-            )
-            self.state = init_state(self.params, seed=cfg.seed)
-            self.arrays = graph_arrays(self.graph)
-            self._stage = jnp.asarray(self.topology.stage_of_peer)
-            self._lat = jnp.asarray(self.topology.latency_ms)
-            self._bw = jnp.asarray(self.topology.bw_up_mbit)
-            # per-stage-pair packet loss (topogen -l); None keeps the lossless
-            # fast path out of the compiled step entirely
-            self._loss = (
-                jnp.asarray(self.topology.packet_loss)
-                if float(np.max(self.topology.packet_loss)) > 0.0 else None
-            )
-            # stage-pair edge tables are experiment constants: build them once
-            # here instead of 70 ms/publish inside disseminate (ops edge_tables)
-            from ..ops.disseminate import answer_tables, edge_tables
-
-            self._lat_edge, self._loss_edge = edge_tables(
-                self._stage, self._lat, self.arrays["conns"], self.arrays["rev"],
-                self._loss)
-            # so are the lat-sorted answer-queue service tables (two stable
-            # argsorts per publish otherwise — the r5 bench's accounting bill)
-            self._ans_tables = (
-                answer_tables(self._lat_edge, self.arrays["conns"],
-                              self.arrays["rev"])
-                if cfg.with_gossip else None)
-            if mesh is not None:
-                from ..parallel.sharding import place_simulation, reshard_rows
-
-                (self.state, self.arrays, self._stage, self._lat, self._bw,
-                 self._loss) = place_simulation(
-                    self.state, self.arrays, self._stage, self._lat, self._bw,
-                    self._loss, mesh)
-                self._lat_edge = reshard_rows(self._lat_edge, mesh)
-                if self._loss_edge is not None:
-                    self._loss_edge = reshard_rows(self._loss_edge, mesh)
-                if self._ans_tables is not None:
-                    self._ans_tables = jax.tree_util.tree_map(
-                        lambda x: reshard_rows(x, mesh), self._ans_tables)
-            # so are the two bands of a publish's row pulls, where this
-            # graph and shape admit them (ops/pull.make_pull_bands)
-            self._pull_bands = self._compute_pull_bands()
-            # neighbor alive&subscribed validity is publish-invariant between
-            # membership changes: maintained here (set_subscribed recomputes,
-            # churn disables the hoist — heartbeats mutate alive on device)
-            self._churny = (cfg.churn_down_per_hb > 0.0
-                            or cfg.churn_up_per_hb > 0.0)
-            self._valid_edge = None if self._churny else self._compute_valid_edge()
-            # the node the injector publishes through does not churn (the
-            # reference's injector POSTs to a named pod; a message from a
-            # dead node measures nothing): the churn draw spares the peers
-            # run() publishes through. Absent, not all-false, with churn
-            # off: no churn-free program sees an argument more
-            self.spared_peers: list[int] = []
-            self._spared = None
-            if self._churny:
-                self.spared_peers = scheduled_publishers(cfg)
-                spared = np.zeros(n, dtype=bool)
-                spared[self.spared_peers] = True
-                self._spared = jnp.asarray(spared)
-                if mesh is not None:
-                    from ..parallel.sharding import reshard_rows
-
-                    self._spared = reshard_rows(self._spared, mesh)
+        proc_ms = (cfg.proc_delay_ms if cfg.proc_delay_ms is not None
+                   else MUXER_PROC_MS.get(cfg.topo.muxer.lower(), 2.0))
+        self.params = SimParams.from_gossipsub(
+            n,
+            self.graph.capacity,
+            cfg.gossipsub,
+            proc_delay_ms=proc_ms,
+            churn_down_per_hb=cfg.churn_down_per_hb,
+            churn_up_per_hb=cfg.churn_up_per_hb,
+            serialize_answers=cfg.serialize_answers,
+            answer_queue_mode=cfg.answer_queue_mode,
+            warm_start=cfg.warm_start,
+        )
+        self._churny = (cfg.churn_down_per_hb > 0.0
+                        or cfg.churn_up_per_hb > 0.0)
+        # the node the injector publishes through does not churn (the
+        # reference's injector POSTs to a named pod; a message from a
+        # dead node measures nothing): the churn draw spares the peers
+        # run() publishes through
+        self.spared_peers: list[int] = (
+            scheduled_publishers(cfg) if self._churny else [])
         # host mirror of state.subscribed: publish() picks the fanout code
         # path (static arg) without a device sync; keep in sync via
         # set_subscribed()
@@ -484,6 +460,69 @@ class Simulator:
 
             self.mix_params = MixParams(num_mix=cfg.num_mix, mix_d=cfg.mix_d)
             self.mix_params.validate()
+
+    def _build_device(self) -> None:
+        """State, device copies of the graph and every table hoisted out of
+        the publishes (the span `build/tables`)."""
+        import jax.numpy as jnp
+
+        cfg, mesh, n = self.cfg, self.mesh, self.params.n
+        self.state = init_state(self.params, seed=cfg.seed)
+        self.arrays = graph_arrays(self.graph)
+        self._stage = jnp.asarray(self.topology.stage_of_peer)
+        self._lat = jnp.asarray(self.topology.latency_ms)
+        self._bw = jnp.asarray(self.topology.bw_up_mbit)
+        # per-stage-pair packet loss (topogen -l); None keeps the lossless
+        # fast path out of the compiled step entirely
+        self._loss = (
+            jnp.asarray(self.topology.packet_loss)
+            if float(np.max(self.topology.packet_loss)) > 0.0 else None
+        )
+        # stage-pair edge tables are experiment constants: build them once
+        # here instead of 70 ms/publish inside disseminate (ops edge_tables)
+        from ..ops.disseminate import answer_tables, edge_tables
+
+        self._lat_edge, self._loss_edge = edge_tables(
+            self._stage, self._lat, self.arrays["conns"], self.arrays["rev"],
+            self._loss)
+        # so are the lat-sorted answer-queue service tables (two stable
+        # argsorts per publish otherwise — the r5 bench's accounting bill)
+        self._ans_tables = (
+            answer_tables(self._lat_edge, self.arrays["conns"],
+                          self.arrays["rev"])
+            if cfg.with_gossip else None)
+        if mesh is not None:
+            from ..parallel.sharding import place_simulation, reshard_rows
+
+            (self.state, self.arrays, self._stage, self._lat, self._bw,
+             self._loss) = place_simulation(
+                self.state, self.arrays, self._stage, self._lat, self._bw,
+                self._loss, mesh)
+            self._lat_edge = reshard_rows(self._lat_edge, mesh)
+            if self._loss_edge is not None:
+                self._loss_edge = reshard_rows(self._loss_edge, mesh)
+            if self._ans_tables is not None:
+                self._ans_tables = jax.tree_util.tree_map(
+                    lambda x: reshard_rows(x, mesh), self._ans_tables)
+        # so are the two bands of a publish's row pulls, where this
+        # graph and shape admit them (ops/pull.make_pull_bands)
+        self._pull_bands = self._compute_pull_bands()
+        # neighbor alive&subscribed validity is publish-invariant between
+        # membership changes: maintained here (set_subscribed recomputes,
+        # churn disables the hoist — heartbeats mutate alive on device)
+        self._valid_edge = None if self._churny else self._compute_valid_edge()
+        # the spared peers as the churn draw reads them. Absent, not
+        # all-false, with churn off: no churn-free program sees an
+        # argument more
+        self._spared = None
+        if self._churny:
+            spared = np.zeros(n, dtype=bool)
+            spared[self.spared_peers] = True
+            self._spared = jnp.asarray(spared)
+            if mesh is not None:
+                from ..parallel.sharding import reshard_rows
+
+                self._spared = reshard_rows(self._spared, mesh)
 
     def _compute_pull_bands(self):
         """The hoisted bands of a publish's row pulls over the graph in
@@ -843,19 +882,9 @@ class Simulator:
                 )
             # every device->host read: the host waits for the publish here
             with span("publish/read"):
-                if cfg.msgid_mode == "go":
-                    # Go/Rust key messages by the embedded LE64 ns timestamp. The
-                    # sim clock is float32-coarse, so back-to-back publishes could
-                    # collide where real nodes' nanosecond clocks would not —
-                    # enforce strict monotonicity the way distinct real publishes
-                    # always have distinct timestamps.
-                    msg_id = max(int(t0_ms * 1e6), self._last_msg_id + 1)
-                    self._last_msg_id = msg_id
-                else:
-                    msg_id = int(self._msg_rng.integers(0, 2**63, dtype=np.int64))
                 rec = record_from_result(
                     res,
-                    msg_id=msg_id,
+                    msg_id=self._next_msg_id(t0_ms),
                     publisher=origin,
                     t0_ms=t0_ms,
                     extra_delay_ms=mix_delay,
@@ -873,31 +902,50 @@ class Simulator:
                     pull_rows_share=pull_rows_share(self._pull_bands),
                 )
                 self.records.append(rec)
-            # the publish's counters, with the shape of its fixpoint loops
-            # (what a reader of the profile needs to turn iterations into
-            # bytes), on a zero-length annotation
-            counters(
-                "publish/counters", message=len(self.records) - 1,
-                fast_iters=rec.fast_iters,
-                fast_sparse_iters=rec.fast_sparse_iters,
-                refine_passes=rec.refine_passes,
-                refined=int(rec.refined), fell_back=int(rec.fell_back),
-                converged=int(rec.converged),
-                refined_serial=int(rec.refined_serial),
-                refine_lane_passes=rec.refine_lane_passes,
-                lanes_hinted=rec.lanes_hinted,
-                lanes_uncertified=rec.lanes_uncertified,
-                peers=self.params.n, slots=self.params.capacity,
-                fragments=cfg.topo.num_frags,
-                rounds=self.params.history_gossip if cfg.with_gossip else 0,
-                formulation=fixpoint_formulation(a["conns"].shape, self.mesh),
-                in_sequence=int(fragments_in_sequence(
-                    a["conns"].shape, cfg.topo.num_frags, self.mesh)),
-                lanes_in_pull=rec.lanes_in_pull,
-                pull_rows_share=rec.pull_rows_share,
-                **({} if rec.alive is None else
-                   {"alive": rec.alive, "under_dlow": rec.under_dlow}))
+            self._note_publish(rec)
         return rec
+
+    def _next_msg_id(self, t0_ms: float) -> int:
+        """The id of the message published at `t0_ms`, next in this run's
+        stream."""
+        if self.cfg.msgid_mode == "go":
+            # Go/Rust key messages by the embedded LE64 ns timestamp. The
+            # sim clock is float32-coarse, so back-to-back publishes could
+            # collide where real nodes' nanosecond clocks would not —
+            # enforce strict monotonicity the way distinct real publishes
+            # always have distinct timestamps.
+            msg_id = max(int(t0_ms * 1e6), self._last_msg_id + 1)
+            self._last_msg_id = msg_id
+            return msg_id
+        return int(self._msg_rng.integers(0, 2**63, dtype=np.int64))
+
+    def _note_publish(self, rec: MessageRecord) -> None:
+        """The counters of the publish that `rec`, the newest record, is
+        of, with the shape of its fixpoint loops (what a reader of the
+        profile needs to turn iterations into bytes), on a zero-length
+        annotation."""
+        cfg, shape = self.cfg, (self.params.n, self.params.capacity)
+        counters(
+            "publish/counters", message=len(self.records) - 1,
+            fast_iters=rec.fast_iters,
+            fast_sparse_iters=rec.fast_sparse_iters,
+            refine_passes=rec.refine_passes,
+            refined=int(rec.refined), fell_back=int(rec.fell_back),
+            converged=int(rec.converged),
+            refined_serial=int(rec.refined_serial),
+            refine_lane_passes=rec.refine_lane_passes,
+            lanes_hinted=rec.lanes_hinted,
+            lanes_uncertified=rec.lanes_uncertified,
+            peers=self.params.n, slots=self.params.capacity,
+            fragments=cfg.topo.num_frags,
+            rounds=self.params.history_gossip if cfg.with_gossip else 0,
+            formulation=fixpoint_formulation(shape, self.mesh),
+            in_sequence=int(fragments_in_sequence(
+                shape, cfg.topo.num_frags, self.mesh)),
+            lanes_in_pull=rec.lanes_in_pull,
+            pull_rows_share=rec.pull_rows_share,
+            **({} if rec.alive is None else
+               {"alive": rec.alive, "under_dlow": rec.under_dlow}))
 
     def publish_batch(
         self,
@@ -959,14 +1007,9 @@ class Simulator:
         ys_np = {k: np.asarray(v) for k, v in ys.items()}
         recs = []
         for i, pub in enumerate(pubs):
-            if cfg.msgid_mode == "go":
-                msg_id = max(int(t0_ms * 1e6), self._last_msg_id + 1)
-                self._last_msg_id = msg_id
-            else:
-                msg_id = int(self._msg_rng.integers(0, 2**63, dtype=np.int64))
             recs.append(record_from_result(
                 _BatchColumn(ys_np, i),
-                msg_id=msg_id,
+                msg_id=self._next_msg_id(t0_ms),
                 publisher=pub,
                 t0_ms=t0_ms,
                 drop_self=(
@@ -990,22 +1033,16 @@ class Simulator:
         `load_checkpoint(path).run()` continues the remaining schedule
         bit-exactly."""
         cfg = self.cfg
-        n = cfg.topo.network_size
         done = len(self.records)  # >0 when resumed from a checkpoint
         if done == 0:
             with span("warmup"):    # dispatch of the warm-up scan
                 self.warmup()
         delay_ms = cfg.topo.delay_seconds * 1000.0
-        pub = cfg.publisher_id % n
-        if cfg.publisher_rotation:
-            pub = (pub + done) % n
         for i in range(done, cfg.topo.messages):
             if i > 0:
                 with span("advance"):
                     self.advance(delay_ms)
-            self.publish(pub)
-            if cfg.publisher_rotation:
-                pub = (pub + 1) % n  # next message from the next peer (run.sh:16-17)
+            self.publish(message_publisher(cfg, i))
             if checkpoint_path is not None and (
                 (i + 1) % max(checkpoint_every, 1) == 0
                 or i == cfg.topo.messages - 1

@@ -52,6 +52,10 @@ def _run(tmp, *flags, runs=1, nodes=200, seed=3, capture=None):
 
     if capture is not None:
         profiling.turn = capturing
+    # this file looks at the turns of the loop, one whole experiment a turn
+    # (tests/test_run_batch.py looks at a batch's): runs > 1 keeps it here
+    refusal = cli._batch_refusal
+    cli._batch_refusal = lambda a: "tests/test_tracing.py keeps the loop"
     try:
         rc = cli.main(["run", str(runs), str(nodes), "15000", "1",
                        str(MESSAGES), "50", "150", "40", "130", "5", "0.0",
@@ -59,6 +63,7 @@ def _run(tmp, *flags, runs=1, nodes=200, seed=3, capture=None):
                        "--out-prefix", str(tmp) + os.sep, *flags])
     finally:
         profiling.turn = sound
+        cli._batch_refusal = refusal
     assert rc == 0
 
 
