@@ -189,6 +189,7 @@ def _publish_counts(records) -> list[dict]:
     return [{"fast_iters": r.fast_iters,
              "fast_sparse_iters": r.fast_sparse_iters,
              "refine_passes": r.refine_passes,
+             "refine_sparse_passes": r.refine_sparse_passes,
              "refined": r.refined,
              "fell_back": r.fell_back,
              "refined_serial": r.refined_serial,

@@ -107,6 +107,7 @@ from .pull import (
     neighbor_pull_bool,
     neighbor_pull_min,
     neighbor_rows_min,
+    neighbor_update_min,
     permute_rows,
     pull_moved_min,
     reciprocal_pull_bool,
@@ -238,19 +239,19 @@ class DisseminationResult:
     #                            pass-count budget of the exactness
     #                            certificate pins this on canonical
     #                            topologies (tests/test_exact_prefix.py).
-    counters: jnp.ndarray      # (10,) int32 — [fast_iters, refine_passes,
+    counters: jnp.ndarray      # (11,) int32 — [fast_iters, refine_passes,
     #                            refined, fell_back, converged,
     #                            refined_serial, refine_lane_passes,
     #                            lanes_hinted, lanes_uncertified,
-    #                            fast_sparse_iters], and
-    #                            under churn (12,): [..., alive,
+    #                            fast_sparse_iters, refine_sparse_passes],
+    #                            and under churn (13,): [..., alive,
     #                            under_dlow]: how much
     #                            work the publish's fixpoints did and which
     #                            branches ran, packed so that the host
     #                            takes them in ONE device->host read
     #                            (runtime/simulator.record_from_result) and
-    #                            the jit returns one leaf more, not nine.
-    #                            The eight that are no field of their own
+    #                            the jit returns one leaf more, not ten.
+    #                            The nine that are no field of their own
     #                            are the properties below.
 
     @property
@@ -312,11 +313,21 @@ class DisseminationResult:
         return self.counters[..., 9]
 
     @property
+    def refine_sparse_passes(self):
+        """() int32 — of `refine_passes`, the parallel-prefix passes that
+        delivered their fold's receivers' times from the peers that moved
+        in the pass before (ops/pull.neighbor_update_min) and gathered no
+        row for them: max over the fragment lanes, which take that side
+        together. 0 under ops/pull.relax_route's size and where the
+        global-sort engine is the one chosen."""
+        return self.counters[..., 10]
+
+    @property
     def alive(self):
         """() int32 — peers that could send at this publish (alive and
         subscribed, and the fanout publisher). Under churn only
         (`SimParams.churn_*_per_hb`); None without."""
-        return (self.counters[..., 10] if self.counters.shape[-1] > 10
+        return (self.counters[..., 11] if self.counters.shape[-1] > 11
                 else None)
 
     @property
@@ -324,7 +335,7 @@ class DisseminationResult:
         """() int32 — of those, the peers whose valid mesh degree was under
         D_low at this publish: what the heartbeats' repair had not yet
         mended. Under churn only; None without."""
-        return (self.counters[..., 11] if self.counters.shape[-1] > 10
+        return (self.counters[..., 12] if self.counters.shape[-1] > 11
                 else None)
 
 
@@ -962,7 +973,18 @@ def disseminate(
         return (permute_rows(g_sorted, inv_lat),
                 permute_rows(req_any_s, inv_lat), drain, mixed, wait_max)
 
-    def gossip_fold_sorted(t_rx, sv_s, lda_s):
+    def _receiver_times(t_rx):
+        """t_rx[conns_sorted]: every slot's receiver's time, lat order
+        (pads are never sampled, so what a pad reads is never used): a
+        per-peer lookup, which a row pull does for a third of the scalar
+        gather's price (ops/pull.py) where that pull is in budget and the
+        table on one device."""
+        if formulation == "row_pull":
+            return neighbor_rows_min(
+                t_rx, p_conns_sorted, batch_factor=lanes)
+        return t_rx[jnp.clip(p_conns_sorted, 0)]
+
+    def gossip_fold_sorted(t_rx, sv_s, lda_s, q_t_s=None):
         """Exact serialized gossip-answer offers via the per-round fold.
 
         A peer answering several IWANTs serializes the answers on its
@@ -981,7 +1003,10 @@ def disseminate(
         answer WOULD arrive if requested — which is self-consistent
         because an offer can only bind for a still-lacking receiver.
 
-        `sv_s`, `lda_s`: _fold_consts of the fragment. The whole fold
+        `sv_s`, `lda_s`: _fold_consts of the fragment. `q_t_s`: the
+        receivers' times in lat order, from a caller that holds them
+        (_converge_prefix's loop, which carries them); every other
+        caller's fold fetches its own. The whole fold
         works in the LAT-SORTED layout and returns it: (g_sorted,
         req_any_s, drain, mixed, wait_max) — per-edge absolute offers (INF
         where no sampled live edge) and answered flags, both by position
@@ -993,15 +1018,8 @@ def disseminate(
         base = t_rx + params.proc_delay_ms
         tick = _next_heartbeat(base, hb_phase, params.heartbeat_ms)  # (N,)
         live = can_send & (t_rx < INF)
-        # receiver times, lat order (pads are never sampled, so what a pad
-        # reads is never used): a per-peer lookup, which a row pull does
-        # for a third of the scalar gather's price (ops/pull.py) where that
-        # pull is in budget and the table on one device
-        if formulation == "row_pull":
-            q_t_s = neighbor_rows_min(
-                t_rx, p_conns_sorted, batch_factor=lanes)
-        else:
-            q_t_s = t_rx[jnp.clip(p_conns_sorted, 0)]
+        if q_t_s is None:
+            q_t_s = _receiver_times(t_rx)
         txp = tx_ms[:, None]
         busy = uplink                               # (N,) queue busy carry
         g_sorted = jnp.full((n, c), INF)
@@ -1417,7 +1435,17 @@ def disseminate(
         global argsort) gives every edge's serialized answer offer, the
         hoisted mesh bases give the uplink-queue offers, and ONE merged
         pull yields t_{k+1} = max(min incoming offer, downlink clamp) with
-        the publisher pinned. Because each estimate is recomputed FRESH
+        the publisher pinned. The fold's receivers' times,
+        t_k[conns_sorted], are a second exchange through the same index
+        (ops/pull.neighbor_rows_min: 5.5 of a pass's 16 ms at 100,000
+        peers, PERF.md, PR 53). They ride in the carry: a pass changes them
+        exactly at the slots that point at a peer whose time moved in the
+        pass before, so where at most ops/pull._RELAX_ROWS moved (the last
+        passes of a loop: 155, 3 and 0 rows of 100,000) the new times of
+        those peers are delivered into the carried matrix
+        (ops/pull.neighbor_update_min) and no row is gathered for them;
+        the times the fold reads are the lookup's bit for bit, every pass.
+        Because each estimate is recomputed FRESH
         (not min-folded into the previous one), the iteration handles the
         system's non-monotonicity in both directions — raising an
         announcer's estimate delays its IHAVE and may REMOVE a requested
@@ -1435,7 +1463,8 @@ def disseminate(
         argsort + a full from-INF mesh relaxation (~graph-diameter pulls)
         per outer pass.
 
-        Returns (t, g_abs, req, drain, mixed, converged, passes) — the
+        Returns (t, g_abs, req, drain, mixed, converged, passes, few) —
+        `few` the passes that delivered their receivers' times; the
         gossip triple and `mixed` are the FINAL evaluation's (the
         no-change pass ran the fold at the fixpoint, so they ride out for
         free); `mixed` or ~converged sends the caller to the global-sort
@@ -1459,14 +1488,26 @@ def disseminate(
         t0 = t_seed.at[publisher].set(t_pub)
         not_pub = jnp.arange(n) != publisher
 
+        # where the dense lookup is worth avoiding (ops/pull.relax_route)
+        # the carry holds the receivers' times, the rows that moved in the
+        # pass before, and how often they were few
+        by_rows = relax_route(conns.shape)
+
         def cond(carry):
-            changed, it = carry[-2], carry[-1]
+            changed, it = carry[5], carry[6]
             return changed & (it < params.max_relax_iters)
 
         def body(carry):
-            t_g, _, _, _, _, _, it = carry
+            t_g, _, _, _, _, _, it = carry[:7]
+            if by_rows:
+                q_s, moved, few = carry[7:]
+                q_s, sparse = neighbor_update_min(
+                    q_s, t_g, moved, conns, ans_tables.rev_sorted,
+                    p_conns_sorted, batch_factor=lanes)
+            else:
+                q_s = None
             g_sorted, req_s, drain, mixed, _ = gossip_fold_sorted(
-                t_g, sv_s, lda_s)
+                t_g, sv_s, lda_s, q_s)
             # merged candidates: mesh offers + SV-masked serialized answer
             # offers (every sampled surviving edge offers, matching the
             # serial path — an offer only binds for a still-lacking, hence
@@ -1481,20 +1522,25 @@ def disseminate(
             t_new = jnp.where(
                 not_pub,
                 jnp.maximum(inc.min(axis=-1), rx_const), t_pub)
-            return (t_new, g_sorted, req_s, drain, mixed,
-                    jnp.any(t_new != t_g), it + 1)
+            moved = t_new != t_g
+            out = (t_new, g_sorted, req_s, drain, mixed, jnp.any(moved),
+                   it + 1)
+            return out + ((q_s, moved, few + sparse) if by_rows else ())
 
         with jax.named_scope("fixpoint"):
-            t, g_sorted, req_s, drain, mixed, changed, it = (
+            t, g_sorted, req_s, drain, mixed, changed, it, *rows = (
                 jax.lax.while_loop(
                     cond, body,
                     (t0, jnp.full((n, c), INF), jnp.zeros((n, c), bool),
                      jnp.zeros((n,), jnp.float32), jnp.bool_(False),
-                     jnp.bool_(True), jnp.int32(0))))
+                     jnp.bool_(True), jnp.int32(0))
+                    + ((jnp.full((n, c), INF), jnp.ones((n,), bool),
+                        jnp.int32(0)) if by_rows else ())))
         # the caller's tuple is in the slot layout: ONE un-permutation,
         # after the loop
         return (t, permute_rows(g_sorted, inv_lat),
-                permute_rows(req_s, inv_lat), drain, mixed, ~changed, it)
+                permute_rows(req_s, inv_lat), drain, mixed, ~changed, it,
+                rows[2] if by_rows else jnp.int32(0))
 
     def queue_drop(tgt_mask, frag_idx):
         """Priority-queue drop model (main.nim:264-299). The reference's
@@ -1747,14 +1793,17 @@ def disseminate(
 
         Returns the phases_serial 10-tuple with element 8 = the COMBINED
         certificate (both phases reached a bitwise F(t)==t pass AND
-        neither's final fold saw interleaved announce rounds). A False
+        neither's final fold saw interleaved announce rounds), and an
+        eleventh element: of the passes (element 9), those that delivered
+        their fold's receivers' times from the rows that moved
+        (_converge_prefix). A False
         certificate means the prefix times are NOT certified exact —
         the caller's nested cond reruns the global-sort pipeline, whose
         sort-order exactness covers the interleaved corner."""
         tgt_f = queue_drop(tgt, frag_idx)
         rank1 = _ranks_f32(jnp.where(tgt_f, rprio, INF))
         k1 = tgt_f.sum(axis=-1).astype(jnp.float32)
-        t1, g1, req1, drain1, mixed1, conv1, it1 = _converge_prefix(
+        t1, g1, req1, drain1, mixed1, conv1, it1, few1 = _converge_prefix(
             rank1, k1, frag_idx, t_pub, tgt_f, t_seed)
 
         def pull_lat(cand):
@@ -1773,16 +1822,16 @@ def disseminate(
                                g_abs=jnp.where(req1, g1, INF)))
         if not params.exclude_first_sender:
             return (t1, rank1, k1, tgt_f, g1, req1, drain1, inc1,
-                    conv1 & ~mixed1, it1)
+                    conv1 & ~mixed1, it1, few1)
         rank2, k2, send_mask = _phase2_masks_from_inc(
             inc1, t1, rank1, k1, tgt_f)
-        t2, g2, req2, drain2, mixed2, conv2, it2 = _converge_prefix(
+        t2, g2, req2, drain2, mixed2, conv2, it2, few2 = _converge_prefix(
             rank2, k2, frag_idx, t_pub, send_mask, t1)
         inc2 = pull_lat(offers(t2, rank2, k2, frag_idx, send_mask,
                                deliver_only=True,
                                g_abs=jnp.where(req2, g2, INF)))
         return (t2, rank2, k2, send_mask, g2, req2, drain2, inc2,
-                conv1 & conv2 & ~mixed1 & ~mixed2, it1 + it2)
+                conv1 & conv2 & ~mixed1 & ~mixed2, it1 + it2, few1 + few2)
 
     # publisher emits fragments back-to-back (main.nim:177-179)
     with jax.named_scope("sample"):
@@ -1827,7 +1876,7 @@ def disseminate(
     answer_wait = jnp.max(wait_f)
     answer_interleaved = jnp.sum(mixed_f.astype(jnp.int32))
     converged = jnp.all(ok_f)
-    refine_passes = refine_lane_passes = jnp.int32(0)
+    refine_passes = refine_lane_passes = refine_sparse_passes = jnp.int32(0)
     lanes_hinted = lanes_uncertified = jnp.int32(0)
     refined = fell_back = refined_serial = jnp.bool_(False)
     if with_gossip and params.serialize_answers:
@@ -1866,17 +1915,23 @@ def disseminate(
             return _per_fragment(phases_serial, frag_ids, t_pubs, seed,
                                  batched=False)
 
-        # what a branch that did not fall back appends to its 10-tuple:
-        # the fell-back bit and the count of uncertified lanes
+        # what a branch the prefix engine did not run appends to its
+        # 10-tuple, as phases_prefix's eleventh element: no lane's pass
+        # delivered its receivers' times; and what one that did not fall
+        # back appends after it: the fell-back bit and the count of
+        # uncertified lanes
+        no_sparse = (jnp.zeros((fragments,), jnp.int32),)
         no_fallback = (jnp.bool_(False), jnp.int32(0))
 
         def _slow(fr):
-            """The taken branch: fr[:10] refined, then the fell-back bit
-            and how many lanes the prefix engine left uncertified."""
+            """The taken branch: fr[:10] refined, every lane's passes that
+            delivered their receivers' times (phases_prefix), then the
+            fell-back bit and how many lanes the prefix engine left
+            uncertified."""
             t_fast = fr[0]
             if not use_prefix:
                 # the global-sort engine is the one chosen: no fallback
-                return _serial_all(t_fast) + no_fallback
+                return _serial_all(t_fast) + no_sparse + no_fallback
             # phases_prefix holds no lax.cond, so its lanes vmap like the
             # fast pipeline's: F(t) == t is a fixed point of further passes
             # and the batched while_loop holds a finished lane's carry, so
@@ -1894,7 +1949,7 @@ def disseminate(
             def _legacy(p):
                 with jax.named_scope("legacy"):
                     leg = _serial_all(p[0])
-                return leg[:9] + (p[9] + leg[9], jnp.bool_(True),
+                return leg[:9] + (p[9] + leg[9], p[10], jnp.bool_(True),
                                   jnp.sum(~p[8], dtype=jnp.int32))
 
             return jax.lax.cond(
@@ -1908,10 +1963,11 @@ def disseminate(
         refined = jnp.any(hint_f)
         with jax.named_scope("refine"):
             kept = jax.lax.cond(
-                refined, _slow, lambda fr: fr + no_fallback,
+                refined, _slow, lambda fr: fr + no_sparse + no_fallback,
                 fast_results + (ok_f, jnp.zeros((fragments,), jnp.int32)))
         fast_results, conv_f, passes_f = kept[:8], kept[8], kept[9]
-        fell_back, lanes_uncertified = kept[10], kept[11]
+        refine_sparse_passes = jnp.max(kept[10])
+        fell_back, lanes_uncertified = kept[11], kept[12]
         # which engine the kept refinement is from: the global-sort one
         # where it was the one chosen, or after the fallback to it
         refined_serial = (refined & fell_back) if use_prefix else refined
@@ -2107,7 +2163,8 @@ def disseminate(
             fast_iters, refine_passes, refined.astype(jnp.int32),
             fell_back.astype(jnp.int32), converged.astype(jnp.int32),
             refined_serial.astype(jnp.int32), refine_lane_passes,
-            lanes_hinted, lanes_uncertified, fast_sparse_iters]
+            lanes_hinted, lanes_uncertified, fast_sparse_iters,
+            refine_sparse_passes]
         if params.churn_down_per_hb > 0.0 or params.churn_up_per_hb > 0.0:
             # under churn only (a churn-free publish stays the program it
             # was): who could send at this publish, and how many of them

@@ -186,6 +186,62 @@ quarter of the dense body's at F = 1 (1.50 ms) AND at F = 4 (4.27 ms):
 publish took the sparse side and `fast` fell 1.381 -> 1.116 s with 0.039 s
 more outside every scope (PERF.md section 6 has what that leaves open).
 
+A rider in the gathered row, measured and not taken (PR 53, TPU v5 lite,
+chiprun call 320; same shape, index and bands, f32, median of 8 timed
+calls, 4 for a loop; the primitive, its tests and its bench rows are in git
+history, taken out at that PR's review). A pass of the prefix refinement
+(ops/disseminate._converge_prefix) makes two exchanges through the same
+index: its receivers' times t[conns] before its fold, the offers after it.
+The rider fetched the per-peer vector as one column more of the table whose
+rows a pull gathers anyway ((N, C + 1); four packed lanes (N, 4 * (C + 1)) =
+164 columns, still two tiles), so that a pass made ONE gather, folded with
+the times a pass late and certified on a pass that read its own (one pass
+more a loop, every byte the same).
+A step of a 20-step loop through the bands, ms (one lane / four packed):
+      the pull alone                          5.88 / 17.07
+      the pull and the lookup it replaces    11.43 / 22.38
+      the pull with the rider                 8.13 / 25.07
+        and permute_rows of the rider        10.13 / 26.21
+      the rider read over the whole lane      7.70 / 26.77
+      the rider as a plain pick               8.42 / 41.00 (a second copy of
+                                              the gathered rows: 7.6 GB)
+      whole index: pull 8.30 / 24.90, two 16.65 / 33.16, rider 11.22 / 36.70
+      standalone: rider 9.29 / 25.72 (whole 12.46 / 37.48), two 12.42 / 23.27
+The rider costs 2.25 ms at F = 1 and 8.0 ms for four lanes, not a column:
+a row of 41 (164) columns is dearer to gather than one of 40 (160), whatever
+reads it (every width PR 41 priced was a multiple of 8); the permutation
+that brings it to the lat order costs 2.0 ms inside the loop, not the 0.28 of
+a standalone call. One lane would save 1.3 ms a pass and pay two confirming
+passes of 16 for it; four lanes lose 3.8 ms a pass. In the publish (pairs on
+shared seeds, every byte equal): runsh-100k.headline 2.072 -> 2.207 s and
+2.037 -> 2.188, a pass 15.89 -> 16.74 ms (22 for 20 of them); the blob cell
+3.965 -> 4.379 s, a joint pass 35.60 -> 38.21; regression-10k 1.179 -> 1.286.
+Not tried: a lane padded to 48 columns, the riders packed behind the lanes, a
+pull through the lat-sorted index (no permutation after it).
+What runs instead (neighbor_update_min): the receivers' times are a carry
+of the loop, and a pass changes them exactly at the slots that point at a
+peer whose time moved in the pass before: with few such peers their new
+times are scattered into the carry and no row is gathered for them.
+A step of a 20-step loop (chiprun call 323; one lane / four vmapped
+lanes, K = 1,024 for both): 3 moved peers 0.52 / 2.05 ms, 1,024 moved
+0.51 / 1.97, every peer moved (the banded lookup through the cond) 5.72 /
+5.83 (call 341, _deliver's flat scatter: 0.51 / 2.06, 0.50 / 1.98, 5.73 /
+5.83; at (10000, 40) 0.29 / 1.00). In the publish the gain is UNRESOLVED:
+six pairs on shared seeds (every byte equal) read runsh-100k.headline 2.091
+-> 2.035, 2.054 -> 2.032, 2.074 -> 2.030 s (call 323) and 2.040 -> 2.030,
+2.057 -> 2.003, 2.041 -> 1.991 (call 341): better in all six, by 0.5 to
+2.7 %, median 2.3 %, where the parent's own runs spread by 0.8 to 1.8 %; the
+driver's pairs decide (PERF.md section 6, PR 53). 12 to 14 of an
+experiment's 60 passes go by the rows (the last 2 of a loop's 10: 155 and 3
+peers moved before them).
+A larger K for one lane was measured and not taken (chiprun call 329): at
+K = 4,096 the step costs 1.39-1.46 ms whatever moved (5.94 for four lanes),
+and regression-10k read 1.174 -> 1.294 s in one run, of a kind that reads
+5 % slow whatever the tree (a process that loads some programs from the
+compile cache and compiles others: PERF.md section 7): unresolved. K and
+the size under which the route is off (_SPARSE_MIN_DENSE_BYTES) are PR
+51's, priced for pull_moved_min and not measured again for this lookup.
+
 The sharded fixpoint (parallel/exchange.py converge_sharded) deliberately
 does NOT use this: its per-iteration cross-shard traffic is the (N,) time
 vector alone, and the pull there is against receiver-local constants.
@@ -575,15 +631,25 @@ def _deliver(into, senders, marks, values, conns, rev):
     """into[conns[p,i], rev[p,i]] = values[k,i] for every marked slot i of
     sender p = senders[k]. The involution makes the targets of distinct
     (p, i) distinct; slots that are unmarked, invalid or a sentinel row's
-    are sent past the end, each to an index of its own, and dropped."""
+    are sent past the end, each to an index of its own, and dropped.
+
+    The scatter is written over the matrix flattened column by column, the
+    one XLA:TPU makes of a scatter at (row, column) pairs itself (an (N, C)
+    matrix lies column-major there, so the transposes are no copies): the
+    compiler's own rewrite leaves the scatter and its index arithmetic
+    without the op_name that carries the caller's `jax.named_scope`, and a
+    device trace then counts them under no scope."""
     n, c = conns.shape
     k = senders.shape[0]
     cn = conns.at[senders].get(mode="fill", fill_value=-1)
     rv = rev.at[senders].get(mode="fill", fill_value=-1)
     ok = marks & (cn >= 0) & (rv >= 0)
-    q = jnp.where(ok, cn, n + jnp.arange(k, dtype=jnp.int32)[:, None])
-    r = jnp.where(ok, rv, jnp.arange(c, dtype=jnp.int32)[None, :])
-    return into.at[q, r].set(values, mode="drop", unique_indices=True)
+    at = jnp.where(ok, rv * n + cn,
+                   n * c + jnp.arange(k * c, dtype=jnp.int32).reshape(k, c))
+    flat = into.T.reshape(-1).at[at.reshape(-1)].set(
+        jnp.broadcast_to(values, (k, c)).reshape(-1), mode="drop",
+        unique_indices=True)
+    return flat.reshape(c, n).T
 
 
 def relax_route(conns_shape) -> bool:
@@ -667,6 +733,48 @@ def pull_moved_min(offer, t, inc, moved, operands, conns, rev,
 
     return _moved_step(sparse, dense, batch_factor, len(leaves))(
         t, inc, moved, *operands, *leaves)
+
+
+def neighbor_update_min(nbr, per_peer, moved, conns, rev, via=None,
+                        batch_factor: int = 1):
+    """`neighbor_rows_min(per_peer, via)`, bit for bit, given `nbr`, that
+    lookup as it stood before the peers in `moved` (N,) took their new
+    values: `nbr` changes exactly at the slots that point at a moved peer,
+    so with at most `_RELAX_ROWS` of them (the last passes of a refinement)
+    it is a compaction of their ids and one K x C scatter of their values
+    into `nbr`, no row gathered; with more, the lookup. `conns`, `rev`:
+    where peer p's value lands, row conns[p, i] at position rev[p, i] for
+    every valid slot i (the reverse slot; for a lookup through the lat
+    order `conns_sorted`, the reverse slot's position in it, `rev_sorted`).
+    `via`: the index of the lookup, whole or `Banded` (None: `conns`).
+    `batch_factor`, and what lanes get: as `pull_moved_min`.
+
+    Returns (nbr, sparse): `sparse` int32, 1 where the values were
+    delivered, 0 where they were looked up."""
+    leaves, tree = jax.tree_util.tree_flatten(
+        (conns, rev, conns if via is None else via))
+
+    def dense(per_peer, nbr, moved, *rest):
+        index = jax.tree_util.tree_unflatten(tree, rest)[2]
+        with jax.named_scope("dense"):
+            return (neighbor_rows_min(per_peer, index, batch_factor),
+                    jnp.int32(0))
+
+    def sparse(per_peer, nbr, moved, *rest):
+        conns, rev, _ = jax.tree_util.tree_unflatten(tree, rest)
+        with jax.named_scope("sparse"):
+            senders = sending_rows(moved, _RELAX_ROWS)
+            # a sentinel (one past the end) reads the last peer: _deliver
+            # drops what it would send
+            values = per_peer[jnp.minimum(senders, moved.shape[0] - 1)]
+            return (_deliver(
+                nbr, senders, True,
+                jnp.broadcast_to(values[:, None],
+                                 (_RELAX_ROWS, conns.shape[-1])),
+                conns, rev), jnp.int32(1))
+
+    return _moved_step(sparse, dense, batch_factor, len(leaves))(
+        per_peer, nbr, moved, *leaves)
 
 
 def _tally(count, routed: bool) -> jnp.ndarray:

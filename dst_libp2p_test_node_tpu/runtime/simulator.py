@@ -232,13 +232,14 @@ def record_from_result(
     # run (multitopic's per-topic projection, a publish_batch column) carry
     # none: their records read converged and no refinement
     packed = getattr(res, "counters", None)
-    values = ([0, 0, 0, 0, 1, 0, 0, 0, 0, 0] if packed is None
+    values = ([0, 0, 0, 0, 1, 0, 0, 0, 0, 0, 0] if packed is None
               else [int(v) for v in _host(packed)])
     (fast_iters, refine_passes, refined, fell_back, converged,
      refined_serial, refine_lane_passes, lanes_hinted,
-     lanes_uncertified, fast_sparse_iters) = values[:10]
+     lanes_uncertified, fast_sparse_iters,
+     refine_sparse_passes) = values[:11]
     # under churn two more: who could send, and who of them sat under D_low
-    alive, under_dlow = values[10:] or (None, None)
+    alive, under_dlow = values[11:] or (None, None)
     return MessageRecord(
         msg_id=msg_id,
         publisher=publisher,
@@ -257,6 +258,7 @@ def record_from_result(
         fast_iters=fast_iters,
         fast_sparse_iters=fast_sparse_iters,
         refine_passes=refine_passes,
+        refine_sparse_passes=refine_sparse_passes,
         refined=bool(refined),
         fell_back=bool(fell_back),
         refined_serial=bool(refined_serial),
@@ -308,14 +310,15 @@ class MessageRecord:
     # bit read True)
     converged: bool = True
     # DisseminationResult.fast_iters / fast_sparse_iters / refine_passes /
-    # refined / fell_back / refined_serial / refine_lane_passes /
-    # lanes_hinted / lanes_uncertified:
+    # refine_sparse_passes / refined / fell_back / refined_serial /
+    # refine_lane_passes / lanes_hinted / lanes_uncertified:
     # how much work the publish's fixpoints did, which branches ran, which
     # engine refined and what the fragment lanes added (`stats<i>.json`
     # "publishes"; not checkpointed, views read 0 / False)
     fast_iters: int = 0
     fast_sparse_iters: int = 0
     refine_passes: int = 0
+    refine_sparse_passes: int = 0
     refined: bool = False
     fell_back: bool = False
     refined_serial: bool = False
@@ -930,6 +933,7 @@ class Simulator:
             fast_iters=rec.fast_iters,
             fast_sparse_iters=rec.fast_sparse_iters,
             refine_passes=rec.refine_passes,
+            refine_sparse_passes=rec.refine_sparse_passes,
             refined=int(rec.refined), fell_back=int(rec.fell_back),
             converged=int(rec.converged),
             refined_serial=int(rec.refined_serial),

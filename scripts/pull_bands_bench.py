@@ -5,6 +5,11 @@ max_degree=40)`, median of 8 timed calls after two warm ones.
 
     chiprun --chips 1 -- python scripts/pull_bands_bench.py
 
+`--update-only` (PR 53): the rows of the carried per-peer lookup brought
+up to date from the peers that moved (ops/pull.neighbor_update_min), a step
+of a 20-step loop, one lane and four, alone; to
+chiprun_out/pull_update_bench.json.
+
 Refuses to run off a TPU (a CPU timing is no device number); `--tiny` runs
 the candidates at 2,000 peers on any backend, for the control flow alone.
 Writes chiprun_out/pull_bands_bench.json and prints it."""
@@ -52,6 +57,40 @@ def scatter_spread(tail, ids, n, fill):
         tail, mode="drop", unique_indices=True)
 
 
+def update_rows(vals, vals4, t1, t4, index):
+    """ms a step of a 20-step loop of the carried lookup brought up to date
+    from the peers that moved (pull.neighbor_update_min: a refinement pass's
+    receivers' times), 3 / K / every peer moved (the last is the banded
+    lookup through the cond)."""
+    ms = {}
+    n = vals.shape[0]
+    rev = index["full"][1]
+    cn = index["AB"][0]
+
+    def update_loop(lanes):
+        def lane(nbr, t, moved):
+            return pull.neighbor_update_min(nbr, t, moved, index["full"][0],
+                                            rev, cn, lanes)[0]
+
+        def run(nbr, t, moved):
+            def body(_, x):
+                nbr, t = x
+                nbr = (lane if lanes == 1 else jax.vmap(lane))(nbr, t, moved)
+                return nbr, jnp.where(moved, t + 1.0, t)
+            return jax.lax.fori_loop(0, 20, body, (nbr, t))
+        return run
+
+    for name, count in (("3", 3), ("K", pull._RELAX_ROWS), ("all", n)):
+        moved = jnp.arange(n) % (n // count) == 0 if count < n else (
+            jnp.ones((n,), bool))
+        moved = moved & (jnp.cumsum(moved) <= count)
+        ms[f"loop20.update.{name}.AB.1"] = timed(
+            update_loop(1), vals, t1, moved, calls=4) / 20
+        ms[f"loop20.update.{name}.AB.4"] = timed(
+            update_loop(4), vals4, t4, jnp.stack([moved] * 4), calls=4) / 20
+    return ms
+
+
 def main():
     tiny = "--tiny" in sys.argv
     dev = jax.devices()[0]
@@ -74,6 +113,18 @@ def main():
     for c1 in (16, 20, 24, 28, 32):
         census[c1] = int((g.degree > c1).sum())
     rows["rows_with_more_than"] = census
+    if "--update-only" in sys.argv:
+        bands = pull.make_pull_bands(conns, rev, min_bytes=0)
+        rows["bands"] = [int(bands.heads["conns"].shape[1]),
+                         int(bands.tails["conns"].shape[0])]
+        index = {"full": (conns, rev),
+                 "AB": (bands.of("conns"), bands.of("rev"))}
+        rows["ms"] = update_rows(vals, vals4, t1, t4, index)
+        os.makedirs("chiprun_out", exist_ok=True)
+        with open("chiprun_out/pull_update_bench.json", "w") as f:
+            json.dump(rows, f, indent=1, allow_nan=False)
+        print(json.dumps(rows, indent=1, allow_nan=False))
+        return
 
     ms = {}
     # the full pull, one lane and four

@@ -17,7 +17,10 @@ chip time. Shapes are the 100,000-peer headline config's
     one lane and four: the gathered rows the compiler keeps are the bands';
   - a step of the fast fixpoint that delivers the moved rows' offers
     (ops/pull.pull_moved_min), one lane and four: one conditional, a
-    scatter on one side of it, the banded pull on the other.
+    scatter on one side of it, the banded pull on the other;
+  - a step that brings a refinement pass's carried receivers' times up to
+    date (ops/pull.neighbor_update_min), one lane and four: one
+    conditional, a scatter on one side, the banded lookup on the other.
 
 Everything that touches the topology lives in fixtures of THIS file (one
 xdist worker loads the TPU library, only after a test here has started);
@@ -223,6 +226,53 @@ def test_moved_rows_step_compiles_for_v5e_with_both_sides(
     assert text.count(" conditional(") == 1
     assert " scatter(" in text and "tpu_custom_call" not in text
     whole = jax.jit(dense).lower(t, busy, table, *bands).compile()
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            < whole.memory_analysis().temp_size_in_bytes
+            + 2.5 * lanes * N * capacity * 4)
+
+
+@pytest.mark.parametrize("lanes", [1, 4])
+def test_receiver_times_step_compiles_for_v5e_with_both_sides(
+        one_chip, capacity, lanes):
+    """ISSUE 53: a step that brings a refinement pass's carried receivers'
+    times up to date (ops/pull.neighbor_update_min) at the 100,000-peer
+    shape, one lane and four vmapped lanes: ONE conditional between a
+    scatter into the carried (N, C) matrix and the banded lookup's
+    gathers, no kernel, and no more temporaries than the lookup's beside a
+    copy of the matrix. One lane's scatter keeps the caller's scope in its
+    op_name (ops/pull._deliver writes it flat: the compiler's own rewrite
+    of a scatter at (row, column) pairs drops the name, and a device trace
+    then counts the delivery under no scope); a vmapped one is rewritten
+    and loses it."""
+    from dst_libp2p_test_node_tpu.ops import pull
+
+    lead = (lanes,) if lanes > 1 else ()
+    edge = one_chip((N, capacity), jnp.int32)
+    via = _band_structs(one_chip, capacity)[0]
+
+    def step(nbr, t, moved, conns, rev, via):
+        def lane(nbr, t, moved):
+            return pull.neighbor_update_min(nbr, t, moved, conns, rev, via,
+                                            batch_factor=lanes)
+        with jax.named_scope("refine"):
+            return (lane if lanes == 1 else jax.vmap(lane))(nbr, t, moved)
+
+    def dense(t, via):
+        def lane(t):
+            return pull.neighbor_rows_min(t, via, lanes)
+        return (lane if lanes == 1 else jax.vmap(lane))(t)
+
+    t = one_chip(lead + (N,), jnp.float32)
+    compiled = jax.jit(step).lower(
+        one_chip(lead + (N, capacity), jnp.float32), t,
+        one_chip(lead + (N,), jnp.bool_), edge, edge, via).compile()
+    text = compiled.as_text()
+    assert text.count(" conditional(") == 1
+    assert " scatter(" in text and "tpu_custom_call" not in text
+    scatters = [line for line in text.splitlines() if " scatter(" in line]
+    assert all(('op_name="jit(step)/refine/' in line) == (lanes == 1)
+               for line in scatters)
+    whole = jax.jit(dense).lower(t, via).compile()
     assert (compiled.memory_analysis().temp_size_in_bytes
             < whole.memory_analysis().temp_size_in_bytes
             + 2.5 * lanes * N * capacity * 4)

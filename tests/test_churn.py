@@ -154,7 +154,7 @@ def test_the_packed_counters_count_alive_and_under_dlow(fragments):
         jnp.full((1,), 100.0), publisher=pub, t0_ms=float(state.t_ms),
         params=params, payload_bytes=15000, fragments=fragments,
         with_gossip=True)
-    assert res.counters.shape == (12,)
+    assert res.counters.shape == (13,)
     assert int(res.alive) == alive.sum() < params.n
     conns = np.asarray(a["conns"])
     valid = ((conns >= 0) & alive[:, None] & alive[np.clip(conns, 0, None)])
@@ -162,13 +162,13 @@ def test_the_packed_counters_count_alive_and_under_dlow(fragments):
     assert int(res.under_dlow) == (alive & (deg < params.d_low)).sum()
     # no dead peer receives
     assert not (np.asarray(res.received) & ~alive).any()
-    # churn off: the ten counters of every publish
+    # churn off: the eleven counters of every publish
     quiet, qstate, _ = _network(n=200)
     res, _ = disseminate(
         qstate, a["conns"], a["rev"], stage, jnp.full((1, 1), 50.0),
         jnp.full((1,), 100.0), publisher=pub, t0_ms=0.0, params=quiet,
         payload_bytes=15000, fragments=fragments, with_gossip=True)
-    assert res.counters.shape == (10,)
+    assert res.counters.shape == (11,)
     assert res.alive is None and res.under_dlow is None
 
 
@@ -290,7 +290,8 @@ def test_churn_free_programs_are_the_parents_but_for_scope_names(program):
     both texts as they were. The four-fragment publish is PR 41's, whose
     lanes share their gathers. Both publishes carry PR 51's tenth counter
     (nine lines: a zero at this shape, its max over the lanes, the wider
-    concatenate); their loops are the pinned parents'."""
+    concatenate) and PR 53's eleventh (another nine); their loops are the
+    pinned parents'."""
     with open(os.path.join(HERE, "fixtures", "lowered_churn_free.json")) as f:
         pinned = json.load(f)
     if pinned["jax"] != jax.__version__:

@@ -319,13 +319,14 @@ def test_sorted_layout_refinement_is_the_slot_layout_bits(
         dataclasses.replace(params, answer_queue_mode="serial"),
         fragments=fragments, **kw)
     (fast_iters, passes, refined, fell_back, converged, by_serial,
-     lane_passes, hinted, uncertified, few) = (
+     lane_passes, hinted, uncertified, few, few_passes) = (
         int(x) for x in np.asarray(res_p.counters))
     assert refined == 1 and passes > 0 and fell_back == 0 and converged == 1
     assert passes <= PASS_BUDGET
     assert passes <= lane_passes <= fragments * passes
     assert 1 <= hinted <= fragments and uncertified == 0
-    assert few == 0                     # under ops/pull.relax_route's size
+    # under ops/pull.relax_route's size: no iteration, no pass by the rows
+    assert few == 0 and few_passes == 0
     # the engines count their own passes and say which of them refined; the
     # other four are shared
     ref = np.asarray(res_s.counters)

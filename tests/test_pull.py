@@ -928,3 +928,104 @@ def test_relax_route_is_the_size_test_of_the_bands():
     assert not pull.relax_route((2048, 40))
     assert pull.relax_route((10000, 40))
     assert pull.relax_route((100000, 40))
+
+
+# ------------------- the receivers' times of a refinement pass, by the rows
+
+def _lat_tables(net):
+    """The lat-sorted index of a `relaxed` network and the bands of it
+    (ops/disseminate.answer_tables on random latencies)."""
+    from dst_libp2p_test_node_tpu.ops.disseminate import answer_tables
+
+    conns, rev = net[0], net[1]
+    lat = jnp.where(conns >= 0, 40.0 + 90.0 * jax.random.uniform(
+        jax.random.PRNGKey(8), conns.shape), 0.0)
+    tabs = answer_tables(lat, conns, rev)
+    c = conns.shape[1]
+    bands = pull.make_pull_bands(conns, rev, tabs.conns_sorted,
+                                 tabs.rev_sorted, min_bytes=0, c1=c // 2,
+                                 rows=300)
+    return tabs, bands
+
+
+@pytest.mark.parametrize("layout", ["slot", "lat"])
+@pytest.mark.parametrize("lanes", [1, 4])
+@pytest.mark.parametrize("rows", [0, 1, RK, RK + 1])
+def test_moved_peers_delivered_are_the_per_peer_lookup(
+        relaxed, rows, lanes, layout):
+    """ISSUE 53: given `neighbor_rows_min` of a per-peer vector and `rows`
+    peers whose value then moved (up or down), neighbor_update_min returns
+    the lookup of the new vector, bit for bit, and says which side ran:
+    the delivery with up to K moved peers in EVERY lane, the lookup with
+    more; through the slot layout (`conns`, `rev`) and through the lat
+    order (`conns_sorted`, looked up whole or banded, delivered through
+    `conns` and `rev_sorted`); pads and holes."""
+    conns, rev = relaxed[0], relaxed[1]
+    tabs, bands = _lat_tables(relaxed)
+    if layout == "slot":
+        via, by, back = conns, conns, rev
+        p_via = bands.of("conns")
+    else:
+        via, by, back = tabs.conns_sorted, conns, tabs.rev_sorted
+        p_via = bands.of("conns_sorted")
+    n = conns.shape[0]
+    key = jax.random.PRNGKey(rows + lanes)
+    u = jax.random.uniform(key, (4, n))
+    old = jnp.where(u < 0.8, u * 1e5, pull.INF)[:lanes]
+    counts = [rows, 0, 1, min(rows, 2)][:lanes]
+    moved = jnp.stack([
+        jnp.zeros((n,), bool).at[jax.random.choice(
+            jax.random.fold_in(key, k), n, (cnt,), replace=False)].set(True)
+        for k, cnt in enumerate(counts)])
+    # a moved peer rises, falls, or becomes reached
+    new = jnp.where(moved, jnp.where(old < pull.INF, old * 0.5 + 7.0, 3.0),
+                    old)
+
+    def step(nbr, t, m):
+        return pull.neighbor_update_min(nbr, t, m, by, back, p_via, lanes)
+
+    def lookup(t):
+        return pull.neighbor_rows_min(t, via)
+
+    if lanes == 1:
+        got, side = jax.jit(step)(lookup(old[0]), new[0], moved[0])
+        want = lookup(new[0])
+    else:
+        got, side = jax.jit(jax.vmap(step))(
+            jnp.stack([lookup(t) for t in old]), new, moved)
+        want = jnp.stack([lookup(t) for t in new])
+    _same_bits([want], [got])
+    assert np.all(np.asarray(side) == (1 if rows <= RK else 0))
+    # and the plain index in place of the bands on the dense side
+    plain, _ = pull.neighbor_update_min(
+        lookup(old[0]), new[0], moved[0], by, back, via)
+    _same_bits([lookup(new[0])], [plain])
+
+
+def test_lanes_update_on_one_scalar_predicate(relaxed):
+    """Four declared lanes take ONE `cond` on a scalar between the scatter
+    of the moved peers' values and the lookup's gathers; a vmap nobody
+    declared keeps the lookup."""
+    conns = relaxed[0]
+    tabs, bands = _lat_tables(relaxed)
+    n = conns.shape[0]
+    t = jnp.zeros((4, n))
+    nbr = jnp.zeros((4,) + conns.shape)
+    moved = jnp.zeros((4, n), bool)
+
+    def step(lanes):
+        return jax.vmap(lambda a, b, m: pull.neighbor_update_min(
+            a, b, m, conns, tabs.rev_sorted, bands.of("conns_sorted"),
+            lanes))
+
+    eqns = list(_eqns(jax.make_jaxpr(step(4))(nbr, t, moved).jaxpr))
+    conds = [e for name, e, _ in eqns if name == "cond"]
+    assert len(conds) == 1 and conds[0].invars[0].aval.shape == ()
+    sides = [{name for name, _, _ in _eqns(b.jaxpr)}
+             for b in conds[0].params["branches"]]
+    assert sorted("scatter" in s for s in sides) == [False, True]
+    assert not [e for name, e, inside in eqns
+                if name == "scatter" and "cond" not in inside]
+    names = {name for name, _, _ in _eqns(
+        jax.make_jaxpr(step(3))(nbr, t, moved).jaxpr)}
+    assert "cond" not in names and "scatter" not in names

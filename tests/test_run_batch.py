@@ -176,7 +176,9 @@ def _solo_programs() -> dict:
                                      "jit__run_heartbeats"])
 def test_one_run_lowers_the_parents_programs(program):
     """tests/fixtures/lowered_run1.json: `_solo_programs` on the parent of
-    the PR that added the batch (f4a2339), with the jax named there."""
+    the PR that added the batch (f4a2339), with the jax named there; the
+    publish re-pinned at PR 53 for its eleventh counter (the fixture says
+    which lines)."""
     with open(os.path.join(HERE, "fixtures", "lowered_run1.json")) as f:
         pinned = json.load(f)
     if pinned["jax"] != jax.__version__:

@@ -175,7 +175,8 @@ def test_stats_json_spans_and_publishes_are_strict_json(two_turns):
         assert len(stats["publishes"]) == MESSAGES
         for p in stats["publishes"]:
             assert set(p) == {"fast_iters", "fast_sparse_iters",
-                              "refine_passes", "refined",
+                              "refine_passes", "refine_sparse_passes",
+                              "refined",
                               "fell_back", "converged", "refined_serial",
                               "refine_lane_passes", "lanes_hinted",
                               "lanes_uncertified", "lanes_in_pull",
@@ -184,6 +185,7 @@ def test_stats_json_spans_and_publishes_are_strict_json(two_turns):
             assert p["pull_rows_share"] == 100.0    # under the size test
             assert isinstance(p["fast_iters"], int) and p["fast_iters"] > 0
             assert p["fast_sparse_iters"] == 0      # and under relax_route's
+            assert p["refine_sparse_passes"] == 0
             assert p["converged"] is True and p["fell_back"] is False
 
 
@@ -348,7 +350,7 @@ def test_counters_on_the_prefix_cases(kw, over, passes_prefix,
             int(res.fell_back), int(res.converged),
             int(res.refined_serial), int(res.refine_lane_passes),
             int(res.lanes_hinted), int(res.lanes_uncertified),
-            int(res.fast_sparse_iters)]
+            int(res.fast_sparse_iters), int(res.refine_sparse_passes)]
     # which engine refined: the one chosen
     assert not bool(res_p.refined_serial) and bool(res_s.refined_serial)
     # the fast pipeline is the same program under both engines
